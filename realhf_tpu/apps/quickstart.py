@@ -32,8 +32,10 @@ def parse_overrides(tokens):
 
 def main(argv=None):
     import realhf_tpu.experiments as experiments
+    from realhf_tpu.base.backend import enable_compile_cache
     from realhf_tpu.base.importing import import_usercode
 
+    enable_compile_cache()  # a config update: touches no device
     import_usercode()  # REALHF_TPU_PACKAGE_PATH custom registrations
 
     argv = argv if argv is not None else sys.argv[1:]
@@ -53,10 +55,8 @@ def main(argv=None):
     spec.n_model_workers = cfg.n_model_workers
     spec.worker_assignment = cfg.parsed_worker_assignment()
     if cfg.allocation_mode in ("heuristic", "search", "search_profiled"):
-        # default_devices respects REALHF_TPU_BACKEND and never probes
-        # the default (TPU) backend from the launcher process -- TPU
-        # init here could block and would hold the chip the spawned
-        # workers need.
+        # A distributed launcher stays off JAX: a process that
+        # initialises the TPU backend holds the chips its workers need.
         if cfg.n_devices is not None:
             n = cfg.n_devices
         elif cfg.mode == "distributed":

@@ -9,11 +9,10 @@ import os
 
 
 def main_worker(args):
-    # Backend selection must happen before jax initializes. Workers in
-    # CPU tests are spawned with REALHF_TPU_BACKEND=cpu.
-    if os.environ.get("REALHF_TPU_BACKEND") == "cpu":
-        from realhf_tpu.base.backend import force_cpu_backend
-        force_cpu_backend()
+    # The platform comes from JAX_PLATFORMS (CPU tests spawn workers
+    # with JAX_PLATFORMS=cpu); each worker process owns its chips.
+    from realhf_tpu.base.backend import enable_compile_cache
+    enable_compile_cache()
 
     from realhf_tpu.base import cluster, logging, name_resolve
     from realhf_tpu.base.importing import import_usercode

@@ -122,8 +122,8 @@ def run_train_minibatches(engine, minibatch_samples, build_sb, loss_fn,
 
     By default the WHOLE loop runs inside one jitted dispatch
     (``Engine.train_minibatches``: lax.scan threads params/opt state
-    through the per-minibatch step), so a remote-attached chip pays one
-    dispatch+sync round-trip instead of one per minibatch -- identical
+    through the per-minibatch step): one dispatch and one host sync
+    instead of one per minibatch -- identical
     update order and numerics to sequential ``train_batch`` calls.
     ``REALHF_TPU_FUSE_MINIBATCHES=0`` restores the sequential calls
     (e.g. when length-skewed minibatches would over-pad the common

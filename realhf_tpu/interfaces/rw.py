@@ -60,22 +60,29 @@ class PairedRewardInterface(model_api.ModelInterface):
     output_scaling: float = 1.0
     output_bias: float = 0.0
 
-    def _score_batch(self, model, input_: SequenceSample) -> np.ndarray:
-        """Value at the final token of every sequence (flattened)."""
-        seqlens = common.flat_seqlens(input_)
-        sb = common.build_stream_batch(
-            seqlens,
-            token_keys=dict(input_ids=input_.data["packed_input_ids"]),
-            n_streams=model.engine.n_streams)
-        values = np.asarray(model.engine.forward_values(
-            sb.arrays["input_ids"], sb.arrays["seg_ids"]))
-        scores = packing.per_seq_gather(
-            sb.info, values, [l - 1 for l in seqlens])
+    def _score_batch(self, model, input_: SequenceSample,
+                     n_mbs: Optional[int] = None) -> np.ndarray:
+        """Value at the final token of every sequence (flattened).
+        ``n_mbs`` chunks the batch (contiguous, order-preserving) so
+        packed rows stay short, as in PPOActorInterface.inference."""
+        pieces = []
+        for chunk in common.split_minibatches(input_, n_mbs or 1):
+            seqlens = common.flat_seqlens(chunk)
+            sb = common.build_stream_batch(
+                seqlens,
+                token_keys=dict(
+                    input_ids=chunk.data["packed_input_ids"]),
+                n_streams=model.engine.n_streams)
+            values = np.asarray(model.engine.forward_values(
+                sb.arrays["input_ids"], sb.arrays["seg_ids"]))
+            pieces.append(packing.per_seq_gather(
+                sb.info, values, [l - 1 for l in seqlens]))
+        scores = np.concatenate(pieces)
         return (scores - self.output_bias) * self.output_scaling
 
     def inference(self, model: model_api.Model, input_: SequenceSample,
                   n_mbs: Optional[int] = None) -> SequenceSample:
-        scores = self._score_batch(model, input_)
+        scores = self._score_batch(model, input_, n_mbs)
         # One score per batch element: elements holding multiple
         # sequences (paired data) keep per-sequence scores concatenated.
         n_per_elem = [len(l) for l in input_.seqlens["packed_input_ids"]]

@@ -24,6 +24,11 @@ def _config_from_hf_llama(d: Dict[str, Any], is_critic: bool,
                           ) -> TransformerConfig:
     nq = d["num_attention_heads"]
     hidden = d["hidden_size"]
+    # qwen2 publishes a window beside `use_sliding_window: false`
+    # (Qwen2.5-0.5B: 32768); a window taken from there would send every
+    # attention call past the flash kernel's gate for nothing.
+    window = d.get("sliding_window") \
+        if d.get("use_sliding_window", True) else None
     return TransformerConfig(
         n_layers=d["num_hidden_layers"],
         n_kv_heads=d.get("num_key_value_heads", nq),
@@ -44,7 +49,7 @@ def _config_from_hf_llama(d: Dict[str, Any], is_critic: bool,
         rotary_base=d.get("rope_theta", 10000.0),
         scale_attn_by_inverse_layer_idx=False,
         tied_embedding=d.get("tie_word_embeddings", False),
-        sliding_window=d.get("sliding_window"),
+        sliding_window=window,
         is_critic=is_critic,
     )
 

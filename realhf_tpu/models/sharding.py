@@ -247,9 +247,17 @@ def residual_pspec(sequence_parallel: bool) -> P:
 def activation_constraint(mesh: Mesh, sequence_parallel: bool):
     """The per-block residual-stream constraint fed to
     ``transformer.forward(activation_constraint=...)``."""
-    sharding = NamedSharding(mesh, residual_pspec(sequence_parallel))
+    spec = residual_pspec(sequence_parallel)
+    sharding = NamedSharding(mesh, spec)
 
     def constrain(x):
+        # Inside the pipeline's shard_map (manual over "pipe") the
+        # constraint must name the context mesh, whose "pipe" axis is
+        # Manual; the engine's own mesh types every axis Auto.
+        ctx_mesh = jax.sharding.get_abstract_mesh()
+        if ctx_mesh.manual_axes:
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(ctx_mesh, spec))
         return jax.lax.with_sharding_constraint(x, sharding)
 
     return constrain

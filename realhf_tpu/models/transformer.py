@@ -313,18 +313,11 @@ def forward(
             "KV-cache prefill on a pipeline-parallel mesh is not "
             "supported; allocate generation MFCs on a dp/tp layout "
             "(decoupled allocation).")
-        from realhf_tpu.parallel import smap as _smap
         from realhf_tpu.parallel.pipeline import pipeline_blocks
-
-        # Old-jax fallback lowers the pipeline shard_map FULLY manual
-        # (parallel/smap.py) -- GSPMD sharding constraints are invalid
-        # inside, and semantically no-ops there (the fallback only
-        # exists for meshes whose non-pipe axes are trivial).
-        pconstrain = constrain if _smap.NEW_SHARD_MAP else (lambda t: t)
 
         def pblock(lp, layer_idx, carry, seg, cos_, sin_):
             y, _, aux = _block(cfg, lp, layer_idx, carry, seg, cos_,
-                               sin_, pconstrain, attention_fn,
+                               sin_, constrain, attention_fn,
                                moe_constraint)
             return y, aux
 

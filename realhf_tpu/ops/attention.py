@@ -102,14 +102,13 @@ def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
     if use_flash:
         assert sliding_window is None, \
             "flash kernel has no sliding-window support yet"
-        try:
-            from realhf_tpu.ops.flash_attention import flash_attention
-        except ImportError:
-            flash_attention = None
-        if flash_attention is not None:
-            return flash_attention(q, k, v, seg_ids, causal=causal,
-                                   scale=scale,
-                                   logits_soft_cap=logits_soft_cap)
+        from realhf_tpu.ops.flash_attention import flash_attention
+
+        # raises above FLASH_MAX_LEN: a row the chip's compiler would
+        # refuse never drops to the O(L^2) XLA path in silence
+        return flash_attention(q, k, v, seg_ids, causal=causal,
+                               scale=scale,
+                               logits_soft_cap=logits_soft_cap)
     return packed_attention_xla(q, k, v, seg_ids, causal=causal, scale=scale,
                                 logits_soft_cap=logits_soft_cap,
                                 sliding_window=sliding_window)
@@ -210,18 +209,14 @@ def decode_attention(
     # None = no kernel partitioning applies -> the XLA path below,
     # which GSPMD partitions itself.
     if pallas_enabled() and hd >= 64 and logits_soft_cap is None:
-        try:
-            from realhf_tpu.ops.decode_attention import (
-                run_decode_kernels,
-            )
-            out = run_decode_kernels(
-                mesh, q, (k_cache, v_cache), valid_mask, slot, None,
-                stacked=False, scale=scale,
-                sliding_window=sliding_window)
-            if out is not None:
-                return out
-        except ImportError:
-            pass
+        from realhf_tpu.ops.decode_attention import run_decode_kernels
+
+        out = run_decode_kernels(
+            mesh, q, (k_cache, v_cache), valid_mask, slot, None,
+            stacked=False, scale=scale,
+            sliding_window=sliding_window)
+        if out is not None:
+            return out
 
     scale = scale if scale is not None else hd ** -0.5
 

@@ -27,7 +27,6 @@ should be a multiple of 128 on real TPUs. S is padded to the K block.
 """
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -43,27 +42,8 @@ NEG_INF = -2.0 ** 30
 SUBLANES = 8
 
 
-def _default_bk() -> int:
-    """K-block rows per kernel step; REALHF_TPU_DECODE_BK overrides
-    for on-chip tuning sweeps (scripts/sweep_decode_bk.py) without a
-    code edit. Validated here so a malformed value fails at the knob,
-    not as a ZeroDivisionError deep inside the kernel."""
-    raw = os.environ.get("REALHF_TPU_DECODE_BK")
-    if not raw:
-        return 512
-    try:
-        v = int(raw)
-    except ValueError as e:
-        raise ValueError(
-            f"REALHF_TPU_DECODE_BK={raw!r} is not an integer") from e
-    if v < 128 or v % 128:
-        raise ValueError(
-            "REALHF_TPU_DECODE_BK must be a positive multiple of 128 "
-            f"(lane tiling), got {v}")
-    return v
-
-
-DEFAULT_BK = _default_bk()
+#: K-block rows per kernel step (a multiple of 128: lane tiling).
+DEFAULT_BK = 512
 
 
 def _decode_body(q, k_at, v_at, keep_at, o_ref, *, scale, bk, s,

@@ -55,17 +55,10 @@ def kernel_dispositions() -> Dict[str, Dict[str, Any]]:
     base = _base_mode()
     out: Dict[str, Dict[str, Any]] = {k: dict(base) for k in KERNELS}
 
-    # The fused ring kernel has two extra gates: a jax-version feature
-    # probe and an explicit opt-in (validated-on-silicon policy).
-    from realhf_tpu.ops.ring_attention_fused import (
-        FUSED_RING_SUPPORTED,
-        FUSED_RING_UNSUPPORTED_REASON,
-    )
+    # The fused ring kernel has one extra gate: an explicit opt-in
+    # (validated-on-silicon policy).
     fused = out["ring_attention_fused"]
-    if not FUSED_RING_SUPPORTED:
-        fused.update(mode="xla", engaged=False,
-                     reason=FUSED_RING_UNSUPPORTED_REASON)
-    elif os.environ.get("REALHF_TPU_FUSED_RING") != "1":
+    if os.environ.get("REALHF_TPU_FUSED_RING") != "1":
         fused.update(mode="xla", engaged=False,
                      reason="REALHF_TPU_FUSED_RING unset (kernel is "
                             "opt-in until validated on multi-chip "

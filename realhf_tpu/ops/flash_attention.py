@@ -14,9 +14,13 @@ attention (flash-attention-2 schedule) with
 Layout contract: q [B, L, nq, hd], k/v [B, L, nkv, hd], seg_ids [B, L]
 (0 = padding). L must be a multiple of the Q block; hd should be a
 multiple of 128 for MXU tiling (128 for llama-family models). K and V
-are kept whole in VMEM per (batch, head) -- fine to L ~= 8k at
-hd=128/bf16; longer contexts will stream KV via DMA (future work,
-alongside ring attention over a context-parallel mesh axis).
+(forward, dq) and Q, dO, lse, delta (dkv) are kept whole in VMEM per
+(batch, head), which bounds L: ``FLASH_MAX_LEN`` below is what the
+v5e compiler accepts for forward AND backward at the head sizes of
+the supported families; ``flash_attention`` raises above it. Longer
+rows need more microbatches (shorter packed rows) or a
+context-parallel mesh (ring attention); streaming KV by DMA is future
+work.
 
 Mosaic requires the last two dims of every block to be (8, 128)-tile
 aligned, so 1D row metadata rides wider layouts: q-side segment ids
@@ -34,6 +38,12 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
+#: Longest packed row the kernel takes. Asked of the v5e compiler
+#: (libtpu 0.0.34, bf16, tests/ops/test_chip_compile.py): the backward
+#: compiles to L = 5120 at (14 q, 2 kv, hd 64) and to 6144 at
+#: (32, 8, 128) and runs out of VMEM one kilotoken above either; the
+#: forward alone compiles to 8192 and is refused at 16384.
+FLASH_MAX_LEN = 4096
 NEG_INF = -2.0 ** 30
 LANES = 128
 SUBLANES = 8
@@ -345,6 +355,14 @@ def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
         raise NotImplementedError(
             "soft cap not yet supported by the flash kernel; use the XLA "
             "path (packed_attention(..., use_flash=False)).")
+    if q.shape[1] > FLASH_MAX_LEN:
+        raise ValueError(
+            f"flash_attention: packed row of {q.shape[1]} tokens exceeds "
+            f"FLASH_MAX_LEN={FLASH_MAX_LEN}, the longest row whose "
+            "forward and backward kernels fit the chip's VMEM. Split "
+            "the batch into more microbatches (the MFC's n_mbs) so "
+            "packed rows get shorter, or shard the sequence over a "
+            "context-parallel mesh (ring attention).")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_attention(q, k, v, seg_ids.astype(jnp.int32),
                             float(scale), causal, block_q, block_k)

@@ -33,7 +33,11 @@ def shifted_logprobs_from_hidden(
 
     Computed in chunks along L so the full [S, L, V] logits tensor is
     never materialized (the fused-CE trick; reference gathers shifted
-    logprobs after a full logits pass, functional.py:165).
+    logprobs after a full logits pass, functional.py:165). The chunk
+    body is rematerialized under autodiff: without that the backward
+    pass keeps every chunk's fp32 logits as scan residuals, which IS
+    the [S, L, V] tensor (2.5 GB and more for a 4096-token row at
+    V = 151936, asked of the v5e compiler).
 
     Returns [S, L] fp32; position t holds the logprob of token t+1.
     The last position of each segment (and pads) hold 0.
@@ -81,7 +85,7 @@ def shifted_logprobs_from_hidden(
         logp = jax.nn.log_softmax(logits, axis=-1)
         return None, jnp.take_along_axis(logp, lc[..., None], axis=-1)[..., 0]
 
-    _, lp = jax.lax.scan(body, None, xs)
+    _, lp = jax.lax.scan(jax.checkpoint(body), None, xs)
     lp = lp.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]
     return jnp.where(valid, lp, 0.0)
 

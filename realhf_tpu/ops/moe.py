@@ -129,8 +129,7 @@ def ragged_dispatch_enabled(cfg: TransformerConfig) -> bool:
     dispatch path is active for this config."""
     return (cfg.mlp_type == "moe" and cfg.moe is not None
             and cfg.moe.capacity_factor is None
-            and cfg.moe.use_grouped_gemm
-            and hasattr(jax.lax, "ragged_dot"))
+            and cfg.moe.use_grouped_gemm)
 
 
 def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,

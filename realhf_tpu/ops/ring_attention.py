@@ -23,10 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -2.0 ** 30
@@ -171,9 +168,7 @@ def ring_attention(
             # the loop body mixes in q/k/v, which vary over all of them.
             axes = tuple(a for a in (axis, data_ax, model_ax)
                          if a is not None)
-            if hasattr(jax.lax, "pvary"):
-                return jax.lax.pvary(x, axes)
-            return x
+            return jax.lax.pcast(x, axes, to="varying")
 
         m = _vary(jnp.full((b, nq, lc), NEG_INF, jnp.float32))
         lsum = _vary(jnp.zeros((b, nq, lc), jnp.float32))

@@ -52,34 +52,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-try:
-    from jax import shard_map  # check_vma-era API (jax >= 0.6)
-except ImportError:  # older jax spells it check_rep under experimental
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from realhf_tpu.ops.ring_attention import ring_attention
-
-# The kernel needs the CompilerParams/InterpretParams-era Pallas TPU
-# API (remote-DMA interpret emulation in particular). On older jax the
-# module still imports -- callers gate on FUSED_RING_SUPPORTED and the
-# entry point raises with the reason instead of an AttributeError deep
-# inside pallas_call.
-FUSED_RING_UNSUPPORTED_REASON = None
-if not hasattr(pltpu, "CompilerParams"):
-    FUSED_RING_UNSUPPORTED_REASON = (
-        "jax.experimental.pallas.tpu lacks CompilerParams "
-        "(has_side_effects/collective_id); jax too old for the fused "
-        "ring kernel.")
-elif not hasattr(pltpu, "InterpretParams"):
-    FUSED_RING_UNSUPPORTED_REASON = (
-        "jax.experimental.pallas.tpu lacks InterpretParams (remote-DMA "
-        "interpret emulation); jax too old for the fused ring kernel.")
-FUSED_RING_SUPPORTED = FUSED_RING_UNSUPPORTED_REASON is None
 
 NEG_INF = -2.0 ** 30
 LANES = 128
@@ -443,8 +419,6 @@ def ring_attention_fused(
     both ICI ring directions carry traffic and per-round transfer time
     halves; falls back to one direction when a half would not tile.
     """
-    if not FUSED_RING_SUPPORTED:
-        raise NotImplementedError(FUSED_RING_UNSUPPORTED_REASON)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     n = mesh.shape[axis]
     if n == 1:

@@ -20,7 +20,6 @@ Axis convention (stable across the framework):
 
 import contextlib
 import dataclasses
-import os
 import threading
 from typing import Dict, List, Optional, Sequence
 
@@ -135,14 +134,9 @@ def parse_parallelism(name: str) -> ParallelismConfig:
 
 
 def default_devices() -> List:
-    """Device fleet used when no explicit slice is given.
-
-    ``REALHF_TPU_BACKEND`` overrides the platform (tests set it to
-    "cpu" to get the virtual 8-device CPU mesh even when a TPU plugin
-    is registered as the default backend).
-    """
-    backend = os.environ.get("REALHF_TPU_BACKEND")
-    return list(jax.devices(backend) if backend else jax.devices())
+    """Device fleet used when no explicit slice is given: every device
+    of the process's platform (``JAX_PLATFORMS``)."""
+    return list(jax.devices())
 
 
 def make_mesh(parallel: ParallelismConfig,

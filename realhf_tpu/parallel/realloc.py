@@ -64,7 +64,12 @@ def reallocate(
     """
     t0 = time.monotonic()
     params = _repad_for_target(cfg, src_params, dst_engine.ctx.tp_size)
-    moved = jax.device_put(params, dst_engine._param_shardings)
+    # may_alias=False: a leaf whose layout is the same on both meshes
+    # (the replicated norm scales) would otherwise BE the source's
+    # buffer, and the next train step donates that buffer away from
+    # under the replica.
+    moved = jax.device_put(params, dst_engine._param_shardings,
+                           may_alias=False)
     if eta != 1.0:
         moved = _ema_lerp(moved, dst_engine.params,
                           jnp.asarray(eta, jnp.float32))
@@ -119,10 +124,13 @@ def install_param_chunks(cfg: TransformerConfig, dst_engine, n_chunks: int,
 
 
 def offload_to_host(params: Any) -> Any:
-    """Move a pytree to host memory (reference async_offload,
-    real_llm_api.py:274 -- pinned-CPU offload)."""
-    cpu = jax.devices("cpu")[0]
-    return jax.device_put(params, cpu)
+    """Move a pytree to pinned host memory (reference async_offload,
+    real_llm_api.py:274), keeping every leaf's sharding: each shard
+    lands in the host memory of its own device, so this needs no CPU
+    backend beside the TPU's (``JAX_PLATFORMS=tpu``). A ``device_put``
+    onto the device shardings brings it back."""
+    return jax.device_put(params, jax.tree.map(
+        lambda x: x.sharding.with_memory_kind("pinned_host"), params))
 
 
 class ReplicaManager:

@@ -38,7 +38,7 @@ autodiff:
 
 The schedule needs the same mesh contract as GPipe: blocks sharded
 P("pipe") on the leading layer axis, activations pipe-replicated,
-manual over "pipe" only (parallel/smap.py picks the shard_map API).
+manual over "pipe" only (parallel/smap.py).
 Rotary phase inputs (cos/sin) receive zero cotangents -- they are
 functions of integer positions, so no real gradient path exists
 through them.

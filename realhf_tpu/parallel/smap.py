@@ -1,84 +1,31 @@
-"""shard_map compatibility layer for the pipeline schedules.
+"""shard_map helpers for the pipeline schedules.
 
 The pipeline schedules (parallel/pipeline.py GPipe, parallel/schedule.py
-1F1B) run inside a shard_map that is MANUAL over the "pipe" mesh axis
-only -- data/ctx/model axes stay under GSPMD so tensor parallelism
-inside each stage needs no hand-written collectives. Two jax API
-generations express that:
-
-- New jax exposes ``jax.shard_map(..., axis_names={"pipe"})`` plus the
-  varying-manual-axes type system (``jax.lax.pcast``). Used verbatim
-  when present.
-- Older jax (<= 0.4.x) only has
-  ``jax.experimental.shard_map.shard_map`` whose partial-manual mode
-  (``auto=...``) hard-crashes XLA's SPMD partitioner on any collective
-  in the manual region (``Check failed: IsManualSubgroup`` -- a process
-  abort, not an exception). The only safe lowering there is FULLY
-  manual, which is valid precisely when every non-pipe axis is trivial
-  (size 1): nothing is left for GSPMD to partition. pp-only meshes --
-  the CPU-CI configuration -- therefore work on old jax; pp x tp / pp
-  x dp meshes raise ``NotImplementedError`` up front instead of
-  aborting the process.
+1F1B) run inside a ``jax.shard_map`` that is MANUAL over the "pipe"
+mesh axis only -- data/ctx/model axes stay under GSPMD so tensor
+parallelism inside each stage needs no hand-written collectives.
 """
 
 from functools import partial
-from typing import Any, Optional
+from typing import Any
 
 import jax
 
 from realhf_tpu.parallel.mesh import PIPE_AXIS
 
-#: new-API probe: ``jax.shard_map`` (vma era) vs experimental shard_map
-NEW_SHARD_MAP = hasattr(jax, "shard_map")
-#: pcast landed after jax.shard_map; probe independently
-HAS_PCAST = hasattr(jax.lax, "pcast")
-
-
-def mesh_supported(mesh) -> Optional[str]:
-    """None when the pipeline shard_map can lower on this jax for this
-    mesh, else a human-readable reason string."""
-    if NEW_SHARD_MAP:
-        return None
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    bad = {a: n for a, n in sorted(sizes.items())
-           if a != PIPE_AXIS and n > 1}
-    if bad:
-        return (
-            "this jax has no partial-manual shard_map (jax.shard_map); "
-            "the fully-manual fallback needs every non-pipe mesh axis "
-            f"to be size 1, got {bad}. Use a pp-only mesh or a newer "
-            "jax for pp x tp / pp x dp layouts.")
-    return None
-
 
 def pipe_shard_map(f=None, *, mesh, in_specs, out_specs):
-    """shard_map manual over the "pipe" axis only, on whichever API
-    this jax provides. Usable as a decorator
-    (``@partial(pipe_shard_map, mesh=..., in_specs=..., out_specs=...)``)
-    exactly like the raw APIs."""
+    """shard_map manual over the "pipe" axis only. Usable as a
+    decorator (``@partial(pipe_shard_map, mesh=..., in_specs=...,
+    out_specs=...)``) exactly like ``jax.shard_map``."""
     if f is None:
         return partial(pipe_shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
-    if NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, axis_names={PIPE_AXIS},
-                             in_specs=in_specs, out_specs=out_specs)
-    reason = mesh_supported(mesh)
-    if reason is not None:
-        def _raise(*a, **k):
-            raise NotImplementedError(f"pipeline shard_map: {reason}")
-        return _raise
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # Fully manual (no auto axes exist to partition); check_rep off:
-    # the old checker predates partial replication over trivial axes
-    # and the P() outputs here are genuinely psum-replicated already.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, axis_names={PIPE_AXIS},
+                         in_specs=in_specs, out_specs=out_specs)
 
 
 def to_varying(x: Any):
     """Mark a pipe-replicated value as device-varying over "pipe" so it
-    can mix with rotated state under the new vma type system; identity
-    on old jax (no varying types in fully-manual mode)."""
-    if NEW_SHARD_MAP and HAS_PCAST:
-        return jax.lax.pcast(x, (PIPE_AXIS,), to="varying")
-    return x
+    can mix with rotated state under the vma type system."""
+    return jax.lax.pcast(x, (PIPE_AXIS,), to="varying")

@@ -27,6 +27,7 @@ from realhf_tpu.api.config import ModelInterfaceType
 from realhf_tpu.api.dfg import DFG
 from realhf_tpu.api.experiment import ExperimentSpec
 from realhf_tpu.base import constants, logging, recover, seeding, timeutil
+from realhf_tpu.base.backend import enable_compile_cache
 from realhf_tpu.obs import metrics, tracing
 from realhf_tpu.system.model_host import ModelHost
 
@@ -37,6 +38,7 @@ class InlineRunner:
 
     def __init__(self, spec: ExperimentSpec, recover_mode: str = "disabled"):
         self.spec = spec
+        enable_compile_cache()
         constants.set_experiment_trial_names(spec.experiment_name,
                                              spec.trial_name)
         # REALHF_TPU_TRACE=1 gives the single-process runner the same

@@ -37,7 +37,6 @@ def run_demo(steps: int = 2) -> dict:
     import tempfile
 
     os.environ["REALHF_TPU_TRACE"] = "1"
-    os.environ.setdefault("REALHF_TPU_BACKEND", "cpu")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     root = tempfile.mkdtemp(prefix="trace_report_demo_")
     os.environ["REALHF_TPU_ROOT"] = root

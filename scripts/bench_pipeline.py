@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from realhf_tpu.base.backend import (  # noqa: E402
-    enable_persistent_compilation_cache,
+    enable_compile_cache,
     force_cpu_backend,
 )
 
@@ -130,7 +130,7 @@ def main(argv=None):
         ap.error("--layers must divide evenly into --stages")
 
     force_cpu_backend(n_devices=max(args.stages, 1))
-    enable_persistent_compilation_cache()
+    enable_compile_cache()
     out = run(args.stages, args.microbatches, args.layers, args.hidden,
               args.seqlen, args.reps, args.stream_mult)
     print(json.dumps(out))

@@ -20,8 +20,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from realhf_tpu.base.backend import enable_persistent_compilation_cache  # noqa: E402
-enable_persistent_compilation_cache()
+from realhf_tpu.base.backend import enable_compile_cache  # noqa: E402
+enable_compile_cache()
 
 
 def build_spec():

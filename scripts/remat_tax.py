@@ -22,8 +22,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from realhf_tpu.base.backend import enable_persistent_compilation_cache  # noqa: E402
-enable_persistent_compilation_cache()
+from realhf_tpu.base.backend import enable_compile_cache  # noqa: E402
+enable_compile_cache()
 
 
 def timed_step(remat: bool, args):

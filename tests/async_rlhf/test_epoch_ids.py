@@ -19,7 +19,7 @@ from tiny_model import TINY, write_jsonl  # noqa: E402
 from realhf_tpu.api import data as data_api  # noqa: E402
 
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "PYTHONPATH": "/root/repo",
@@ -41,6 +41,7 @@ def test_epoch_qualified_ids_round_trip():
     assert q0.data["packed_prompts"] is s.data["packed_prompts"]
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_two_epoch_concurrent_run_has_no_id_collisions(tmp_path):
     """SFT over 2 epochs with max_concurrent_batches=2: the epoch
     boundary keeps batches of BOTH epochs live at once (the exact

@@ -1,7 +1,8 @@
-"""bench.py cold-window contract: the PPO headline is recorded FIRST,
-the payload file is flushed incrementally (partial file on disk before
-any non-headline phase runs), and --headline-only prints a valid
-headline JSON line without touching the non-headline phases."""
+"""bench.py contract: it needs a TPU (exits non-zero without one), the
+PPO headline is recorded FIRST, the payload file is flushed after every
+phase, --headline-only prints a valid headline JSON line without
+touching the later phases, and a failed phase exits non-zero with the
+record flushed so far left on disk."""
 
 import json
 import os
@@ -15,12 +16,17 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__),
 
 @pytest.fixture()
 def bench_mod(monkeypatch, tmp_path):
+    """bench.py with the device check steered from the test: the CPU
+    box passes for a v5e, and the PPO phase is a stub."""
     monkeypatch.syspath_prepend(REPO)
-    monkeypatch.setenv("REALHF_BENCH_FORCE_CPU", "1")
-    monkeypatch.setenv("REALHF_TPU_COMPILE_CACHE", "0")
     monkeypatch.setenv("REALHF_BENCH_PAYLOAD",
                        str(tmp_path / "BENCH_partial.json"))
     import bench
+    monkeypatch.setattr(bench, "require_tpu",
+                        lambda: (197e12, 819e9))
+    monkeypatch.setattr(
+        bench, "bench_ppo",
+        lambda: (_headline(), {"ppo_step_time_s": 1.0}, object()))
     return bench
 
 
@@ -37,10 +43,6 @@ def _read_payload():
 def test_headline_only_prints_and_skips_nonheadline_phases(
         bench_mod, monkeypatch, capsys):
     ran = []
-    monkeypatch.setattr(
-        bench_mod, "bench_ppo",
-        lambda on_tpu: (_headline(), {"ppo_step_time_s": 1.0},
-                        object()))
 
     def forbidden(name):
         def _f(*a, **k):
@@ -52,18 +54,6 @@ def test_headline_only_prints_and_skips_nonheadline_phases(
     monkeypatch.setattr(bench_mod, "bench_sft", forbidden("sft"))
     monkeypatch.setattr(bench_mod, "_reshard_metrics",
                         forbidden("reshard"))
-    monkeypatch.setattr(bench_mod, "_bench_pipeline_schedules",
-                        forbidden("pipeline"))
-    monkeypatch.setattr(bench_mod, "_bench_serving_hotpath",
-                        forbidden("serving"))
-    monkeypatch.setattr(bench_mod, "_bench_kv_pool",
-                        forbidden("kv_pool"))
-    monkeypatch.setattr(bench_mod, "_bench_async",
-                        forbidden("async"))
-    monkeypatch.setattr(bench_mod, "_bench_agentic",
-                        forbidden("agentic"))
-    monkeypatch.setattr(bench_mod, "_bench_trace_report",
-                        forbidden("trace_report"))
     monkeypatch.setattr(sys, "argv", ["bench.py", "--headline-only"])
     bench_mod.main()
     assert ran == []
@@ -75,6 +65,7 @@ def test_headline_only_prints_and_skips_nonheadline_phases(
     assert rec["metric"] == "ppo_tokens_per_sec_per_chip"
     assert rec["extra"]["headline_only"] is True
     assert rec["extra"]["time_to_first_headline_s"] >= 0
+    assert set(rec["extra"]["device"]) == {"platform", "kind", "count"}
 
     payload = _read_payload()
     assert payload["phases_done"] == ["ppo_headline",
@@ -86,14 +77,8 @@ def test_headline_only_prints_and_skips_nonheadline_phases(
 def test_partial_payload_flushed_before_each_nonheadline_phase(
         bench_mod, monkeypatch, capsys):
     """The full run flushes after EVERY phase; each later phase can
-    observe the previous flush on disk -- a window dying mid-phase
-    always leaves the newest complete record."""
+    observe the previous flush on disk."""
     seen_phases = {}
-
-    monkeypatch.setattr(
-        bench_mod, "bench_ppo",
-        lambda on_tpu: (_headline(), {"ppo_step_time_s": 1.0},
-                        object()))
 
     def spy(name, ret=None, mutate=None):
         def _f(*a, **k):
@@ -103,20 +88,6 @@ def test_partial_payload_flushed_before_each_nonheadline_phase(
             return ret
         return _f
 
-    monkeypatch.setattr(bench_mod, "_bench_pipeline_schedules",
-                        spy("pipeline", ret={"stages": 4}))
-    monkeypatch.setattr(bench_mod, "_bench_serving_hotpath",
-                        spy("serving", ret={"shared": {}}))
-    monkeypatch.setattr(bench_mod, "_bench_kv_pool",
-                        spy("kv_pool",
-                            ret={"max_concurrent_improvement": 2.5}))
-    monkeypatch.setattr(bench_mod, "_bench_async",
-                        spy("async", ret={"async_speedup": 1.1}))
-    monkeypatch.setattr(bench_mod, "_bench_agentic",
-                        spy("agentic", ret={"serving": {}}))
-    monkeypatch.setattr(bench_mod, "_bench_trace_report",
-                        spy("trace_report",
-                            ret={"n_steps": 2, "goodput": 0.8}))
     monkeypatch.setattr(
         bench_mod, "_reshard_metrics",
         spy("reshard",
@@ -129,30 +100,13 @@ def test_partial_payload_flushed_before_each_nonheadline_phase(
 
     # headline (and disposition) were on disk before the first
     # non-headline phase ran
-    assert seen_phases["pipeline"] == ["ppo_headline",
-                                       "kernel_disposition"]
-    assert seen_phases["serving"][-1] == "pipeline_schedules"
-    assert seen_phases["kv_pool"][-1] == "serving_bench"
-    assert seen_phases["async"][-1] == "kv_pool_bench"
-    assert seen_phases["agentic"][-1] == "async_bench"
-    assert seen_phases["trace_report"][-1] == "agentic_bench"
-    assert seen_phases["reshard"][-1] == "trace_report"
+    assert seen_phases["reshard"] == ["ppo_headline",
+                                      "kernel_disposition"]
     assert seen_phases["sft"][-1] == "reshard"
 
     final = _read_payload()
     assert final["phases_done"] == [
-        "ppo_headline", "kernel_disposition", "pipeline_schedules",
-        "serving_bench", "kv_pool_bench", "async_bench",
-        "agentic_bench", "trace_report", "reshard", "sft",
-        "overhead_probe"]
-    assert final["extra"]["pipeline_schedule_bench"] == {"stages": 4}
-    assert final["extra"]["serving_bench"] == {"shared": {}}
-    assert final["extra"]["kv_pool_bench"] == {
-        "max_concurrent_improvement": 2.5}
-    assert final["extra"]["async_bench"] == {"async_speedup": 1.1}
-    assert final["extra"]["agentic_bench"] == {"serving": {}}
-    assert final["extra"]["trace_report"] == {"n_steps": 2,
-                                              "goodput": 0.8}
+        "ppo_headline", "kernel_disposition", "reshard", "sft"]
     assert final["extra"]["sft_mfu"] == 0.5
     # final stdout line is the full headline record
     out_lines = [l for l in capsys.readouterr().out.splitlines()
@@ -161,38 +115,33 @@ def test_partial_payload_flushed_before_each_nonheadline_phase(
     assert rec["extra"]["reshard_latency_s"] == 0.1
 
 
-def test_nonheadline_phase_failure_never_voids_headline(
+def test_failed_phase_or_missing_tpu_exits_nonzero(
         bench_mod, monkeypatch, capsys):
-    monkeypatch.setattr(
-        bench_mod, "bench_ppo",
-        lambda on_tpu: (_headline(), {"ppo_step_time_s": 1.0},
-                        object()))
-
+    """No phase catches its failure: the run dies (non-zero exit) with
+    the payload flushed so far on disk and no final record printed.
+    With the real device check the CPU box is refused outright."""
     def boom(*a, **k):
-        raise RuntimeError("window died")
+        raise RuntimeError("phase died")
 
-    monkeypatch.setattr(bench_mod, "_bench_pipeline_schedules", boom)
-    monkeypatch.setattr(bench_mod, "_bench_serving_hotpath",
-                        lambda: {"shared": {}})
-    monkeypatch.setattr(bench_mod, "_bench_kv_pool",
-                        lambda: {"ok": True})
-    monkeypatch.setattr(bench_mod, "_bench_async",
-                        lambda: {"async_speedup": 1.0})
-    monkeypatch.setattr(bench_mod, "_bench_agentic",
-                        lambda: {"serving": {}})
-    # the trace_report phase honors the same property: its failure
-    # degrades to an error note, never voids the headline
-    monkeypatch.setattr(bench_mod, "_bench_trace_report", boom)
+    monkeypatch.setattr(bench_mod, "_reshard_metrics", boom)
     monkeypatch.setattr(bench_mod, "bench_sft",
-                        lambda on_tpu: {"sft_mfu": 0.5})
-    monkeypatch.setattr(bench_mod, "_reshard_metrics",
-                        lambda runner, extra: None)
+                        lambda: {"sft_mfu": 0.5})
     monkeypatch.setattr(sys, "argv", ["bench.py"])
-    bench_mod.main()
+    with pytest.raises(RuntimeError, match="phase died"):
+        bench_mod.main()
     payload = _read_payload()
-    assert "error" in payload["extra"]["pipeline_schedule_bench"]
-    assert "error" in payload["extra"]["trace_report"]
-    assert payload["phases_done"][-1] == "overhead_probe"
+    assert payload["phases_done"] == ["ppo_headline",
+                                      "kernel_disposition"]
+    assert "sft_mfu" not in payload["extra"]
+    assert not [l for l in capsys.readouterr().out.splitlines()
+                if l.startswith("{")]
+
+    monkeypatch.undo()  # the real require_tpu: this box has no TPU
+    import bench
+    with pytest.raises(SystemExit) as exc:
+        bench.require_tpu()
+    assert exc.value.code not in (0, None)
+    assert "needs a TPU" in str(exc.value.code)
 
 
 def test_bench_pipeline_script_payload_shape(monkeypatch):
@@ -212,5 +161,7 @@ def test_bench_pipeline_script_payload_shape(monkeypatch):
     assert out["schedules"]["1f1b"]["computed_stage_steps"] == 8
     for sched in ("gpipe", "1f1b"):
         assert out["schedules"][sched]["step_s"] > 0
-    assert -1.0 < out["measured_bubble_fraction"] < 1.0
+    # 1 - t_1f1b / t_gpipe of two single timed steps: below 1 by
+    # construction, and as far below 0 as the host's noise takes it
+    assert out["measured_bubble_fraction"] < 1.0
     json.dumps(out)  # payload-serializable

@@ -1,4 +1,4 @@
-"""Bundled host<->device transfer paths (round-5 relay-latency work):
+"""Bundled host<->device transfer paths:
 GenerationOutput.to_host materializes every field in one device_get,
 and Engine._globalize_tree uploads a whole pytree in one device_put.
 Parity-checked against the per-leaf paths they replace."""

@@ -59,13 +59,21 @@ def _one_decode_step(cfg, params, mesh):
     ids = jnp.asarray(rng.integers(1, 120, size=(b, lp)), jnp.int32)
     seg = jnp.ones((b, lp), jnp.int32)
     pos = jnp.tile(jnp.arange(lp, dtype=jnp.int32), (b, 1))
-    hidden, cache = T.prefill(cfg, params, ids, seg, pos,
-                              total_len=lp + 8)
     tok = jnp.asarray(rng.integers(1, 120, size=(b,)), jnp.int32)
-    new_hidden, _ = T.decode_step(cfg, params, cache, tok,
-                                  jnp.full((b,), lp, jnp.int32),
-                                  uniform_slot=True, mesh=mesh)
-    return np.asarray(new_hidden)
+
+    # one jitted program, as generation runs it (eagerly, the
+    # interpret-mode kernels under shard_map run op by op); traced
+    # inside the caller's interpret-mode context and env
+    @jax.jit
+    def step(params, ids, seg, pos, tok):
+        _, cache = T.prefill(cfg, params, ids, seg, pos,
+                             total_len=lp + 8)
+        new_hidden, _ = T.decode_step(cfg, params, cache, tok,
+                                      jnp.full((b,), lp, jnp.int32),
+                                      uniform_slot=True, mesh=mesh)
+        return new_hidden
+
+    return np.asarray(step(params, ids, seg, pos, tok))
 
 
 @pytest.mark.parametrize("dp,tp,path", [(4, 2, "heads"), (2, 4, "seq")])

@@ -139,36 +139,29 @@ class TestHFParity:
 
 
 def test_fp64_exact_parity(saved_hf_model):
-    """Run the llama comparison in a subprocess with x64 enabled: fp64
-    logits must match HF to float-noise level, pinning structural
-    equivalence (x64 is a process-global jax flag, hence subprocess)."""
-    import subprocess
-    import sys
-
-    family, _, path = saved_hf_model
+    """The llama comparison with x64 enabled: fp64 logits must match
+    HF to float-noise level, pinning structural equivalence. x64 is
+    scoped to this test by the context manager (a child process would
+    pay the torch/transformers import a second time)."""
+    family, model, path = saved_hf_model
     if family != "llama":
         pytest.skip("fp64 pinning uses llama only")
-    code = f"""
-from realhf_tpu.base.backend import force_cpu_backend
-force_cpu_backend()
-import jax
-jax.config.update("jax_enable_x64", True)
-import numpy as np, torch, transformers, jax.numpy as jnp
-from realhf_tpu.models import hf as hfreg
-from realhf_tpu.models import transformer as T
-model = transformers.AutoModelForCausalLM.from_pretrained({path!r}).eval().double()
-cfg, params = hfreg.load_hf_checkpoint({path!r}, "llama")
-cfg.compute_dtype = cfg.param_dtype = "float64"
-params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float64), params)
-rng = np.random.default_rng(0)
-ids = rng.integers(0, cfg.vocab_size, size=(2, 24)).astype(np.int32)
-with torch.no_grad():
-    theirs = model(input_ids=torch.from_numpy(ids).long()).logits.numpy()
-h, _ = T.forward(cfg, params, jnp.asarray(ids), jnp.ones((2, 24), jnp.int32))
-ours = np.asarray(T.lm_logits(cfg, params, h))
-assert np.abs(ours - theirs).max() < 1e-5, np.abs(ours - theirs).max()
-print("FP64 PARITY OK")
-"""
-    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=600)
-    assert "FP64 PARITY OK" in res.stdout, res.stdout + res.stderr
+    import copy
+
+    model64 = copy.deepcopy(model).double()
+    rng = np.random.default_rng(0)
+    with jax.enable_x64(True):
+        cfg, params = hf_registry.load_hf_checkpoint(path, "llama")
+        cfg.compute_dtype = cfg.param_dtype = "float64"
+        params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float64),
+                              params)
+        ids = rng.integers(0, cfg.vocab_size,
+                           size=(2, 24)).astype(np.int32)
+        with torch.no_grad():
+            theirs = model64(
+                input_ids=torch.from_numpy(ids).long()).logits.numpy()
+        h, _ = T.forward(cfg, params, jnp.asarray(ids),
+                         jnp.ones((2, 24), jnp.int32))
+        ours = np.asarray(T.lm_logits(cfg, params, h))
+    assert np.abs(ours - theirs).max() < 1e-5, \
+        np.abs(ours - theirs).max()

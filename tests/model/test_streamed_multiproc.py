@@ -29,6 +29,7 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_streamed_roundtrip_two_processes(tmp_path):
     # ~29M params (~115 MB fp32): big enough that a full-model host
     # materialization visibly breaks the child's RSS bound, small

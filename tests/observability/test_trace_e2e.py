@@ -23,7 +23,7 @@ TINY = dict(n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
             use_mlp_bias=False, activation_function="silu")
 
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "PYTHONPATH": "/root/repo",

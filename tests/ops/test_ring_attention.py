@@ -186,7 +186,8 @@ def test_blockwise_gradients_match_dense():
             o = ring_attention(q_, k_, v_, seg, mesh, "ctx",
                                **fn_kwargs)
             return (o.astype(jnp.float32) ** 2).sum()
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        # jitted: an eager shard_map runs the nested scans op by op
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
 
     gd = loss(dict(block_q=1024, block_k=1024))
     gb = loss(dict(block_q=8, block_k=16))
@@ -227,6 +228,7 @@ def test_long_context_8k_forward_backward():
                            block_q=512, block_k=512)
         return (o.astype(jnp.float32) ** 2).mean()
 
-    loss, grads = jax.value_and_grad(f, argnums=(0,))(q, k, v)
+    loss, grads = jax.jit(
+        jax.value_and_grad(f, argnums=(0,)))(q, k, v)
     assert np.isfinite(float(loss))
     assert np.isfinite(np.asarray(grads[0])).all()

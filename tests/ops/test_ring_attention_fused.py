@@ -10,21 +10,14 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from realhf_tpu.ops.ring_attention import ring_attention
-from realhf_tpu.ops.ring_attention_fused import (
-    FUSED_RING_SUPPORTED,
-    FUSED_RING_UNSUPPORTED_REASON,
-    ring_attention_fused,
-)
-
-pytestmark = pytest.mark.skipif(
-    not FUSED_RING_SUPPORTED, reason=FUSED_RING_UNSUPPORTED_REASON or "")
+from realhf_tpu.ops.ring_attention_fused import ring_attention_fused
 
 
 def ctx_mesh(n=4):
     return Mesh(np.array(jax.devices()[:n]).reshape(n), ("ctx",))
 
 
-def make_inputs(b=2, l=64, nq=4, nkv=2, hd=8, seed=0, n_seqs=2):
+def make_inputs(b=1, l=64, nq=2, nkv=1, hd=8, seed=0, n_seqs=2):
     rng = np.random.default_rng(seed)
     q = rng.normal(size=(b, l, nq, hd)).astype(np.float32)
     k = rng.normal(size=(b, l, nkv, hd)).astype(np.float32)
@@ -45,7 +38,9 @@ def make_inputs(b=2, l=64, nq=4, nkv=2, hd=8, seed=0, n_seqs=2):
 @pytest.mark.parametrize("causal", [True, False])
 def test_fused_matches_ppermute(causal):
     mesh = ctx_mesh(4)
-    q, k, v, seg = make_inputs()
+    # the one case with a batch of two rows; interpret-mode cost grows
+    # with the grid, so the other cases keep one
+    q, k, v, seg = make_inputs(b=2 if causal else 1)
     ref = jax.jit(lambda *a: ring_attention(
         *a, mesh=mesh, causal=causal))(q, k, v, seg)
     got = jax.jit(lambda *a: ring_attention_fused(
@@ -69,11 +64,14 @@ def test_fused_ring8_blocked():
     """8-way ring with a local shard bigger than one block (several
     inner k-blocks per round) and uneven GQA grouping."""
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("ctx",))
-    q, k, v, seg = make_inputs(b=1, l=256, nq=8, nkv=2, seed=5)
+    # the emulated ring costs ~6 s per (head, q-block) cell on eight
+    # devices, whatever the block holds: two heads sharing one KV
+    # head, one q-block, two k-blocks a round
+    q, k, v, seg = make_inputs(b=1, l=128, nq=2, nkv=1, seed=5)
     ref = jax.jit(lambda *a: ring_attention(
         *a, mesh=mesh))(q, k, v, seg)
     got = jax.jit(lambda *a: ring_attention_fused(
-        *a, mesh=mesh, block_q=16, block_k=16,
+        *a, mesh=mesh, block_q=16, block_k=8,
         interpret=True))(q, k, v, seg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -114,7 +112,7 @@ def test_engine_wiring_flag(monkeypatch):
     )
 
     cfg = TransformerConfig(
-        n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
+        n_layers=1, n_kv_heads=1, n_q_heads=2, hidden_dim=32,
         intermediate_dim=64, vocab_size=128, apply_rotary=True,
         layer_norm_type="rms", mlp_type="llama",
         use_attention_bias=False, use_attn_proj_bias=False,
@@ -124,7 +122,7 @@ def test_engine_wiring_flag(monkeypatch):
                             context_parallel_size=4)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     ids = np.random.default_rng(0).integers(
-        1, 100, size=(2, 64)).astype(np.int32)
+        1, 100, size=(2, 32)).astype(np.int32)
     seg = np.ones_like(ids)
 
     def build(flag):

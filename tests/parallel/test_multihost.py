@@ -121,6 +121,7 @@ print(f"MULTIHOST_OK pid={pid} reshard_to_tp={dt1:.3f}s "
 """.replace("pytest_approx_ref", "ref_sum")
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_two_process_multihost(tmp_path):
     outs = run_two_procs(WORKER_CODE, tmp_path, "MULTIHOST_OK",
                          timeout=300)
@@ -191,6 +192,7 @@ print(f"MULTIHOST_TRAIN_OK pid={pid} losses="
 """
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_two_process_sft_train_step(tmp_path):
     """A full SFT train step (forward+backward+AdamW, dp=2 x tp=4 with
     sequence parallelism) jitted over a mesh SPANNING TWO OS PROCESSES
@@ -259,6 +261,7 @@ print(f"MULTIHOST_PP_GEN_OK pid={pid} "
 """
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_two_process_pp_generation_decode_view(tmp_path):
     """Generation on a pipe mesh SPANNING TWO OS PROCESSES: the
     collapsed decode view is itself a multi-process engine (every

@@ -2,9 +2,8 @@
 equivalence vs the GPipe path and the single-mesh scan, bounded VJP
 residual memory, and the schedule analytics the cost model consumes.
 
-Runs on pp-only meshes so the fully-manual shard_map fallback
-(parallel/smap.py) lowers on any jax; pp x dp/tp layout parity lives
-in test_pipeline.py (needs the partial-manual jax.shard_map API).
+Runs on pp-only meshes; pp x dp/tp layout parity lives in
+test_pipeline.py.
 """
 
 import dataclasses

@@ -25,7 +25,7 @@ from realhf_tpu.base.cluster import HOST_ID_ENV  # noqa: E402
 from realhf_tpu.system import pod  # noqa: E402
 
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "PYTHONPATH": "/root/repo",

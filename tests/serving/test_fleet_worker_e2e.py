@@ -23,7 +23,6 @@ TINY = dict(n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
 
 
 def _worker_proc(record_root, spec_path, worker_type, index):
-    os.environ["REALHF_TPU_BACKEND"] = "cpu"
     from realhf_tpu.base.backend import force_cpu_backend
     force_cpu_backend()
     from realhf_tpu.base import name_resolve

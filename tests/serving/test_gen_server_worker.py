@@ -10,6 +10,7 @@ import pickle
 import time
 
 import numpy as np
+import pytest
 
 TINY = dict(n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
             intermediate_dim=64, vocab_size=97, apply_rotary=True,
@@ -20,7 +21,6 @@ TINY = dict(n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
 
 def _worker_proc(record_root, spec_path):
     # separate OS process: CPU backend must be forced before jax init
-    os.environ["REALHF_TPU_BACKEND"] = "cpu"
     from realhf_tpu.base.backend import force_cpu_backend
     force_cpu_backend()
     from realhf_tpu.base import name_resolve
@@ -53,6 +53,7 @@ def _make_spec(exp, trial):
                          greedy=True)))
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_gen_server_worker_process(tmp_path):
     from realhf_tpu.base import name_resolve
     from realhf_tpu.serving.server import RolloutClient

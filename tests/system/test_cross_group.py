@@ -22,7 +22,7 @@ from realhf_tpu.parallel.mesh import ParallelismConfig
 from tiny_model import TINY, write_jsonl
 
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "PYTHONPATH": "/root/repo",
@@ -42,6 +42,7 @@ def prompt_data(tmp_path):
     return str(path)
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_cross_group_actor_gen(prompt_data):
     """actor-train on worker 0, actor-gen on worker 1."""
     # The wall-clock overlap assertion at the end is sensitive to CPU

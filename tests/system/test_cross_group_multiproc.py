@@ -40,7 +40,7 @@ from tiny_model import TINY, write_jsonl
 
 # 2 virtual CPU devices per worker process; a 3-process world has 6.
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
     "REALHF_TPU_LOCAL_DEVICE_COUNT": "2",
@@ -76,6 +76,7 @@ def _base_cfg(prompt_data, name):
     return cfg
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_cross_group_from_multiproc_primary(prompt_data):
     """Actor trains on a TWO-PROCESS mesh (workers [0,1], d2t2);
     actor_gen executes on worker [2] with a different layout (d2t1).
@@ -130,6 +131,7 @@ def test_cross_group_from_multiproc_primary(prompt_data):
     assert versions[1] >= 1   # second rollout: post-train weights
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_cross_group_ema_ref_different_role(prompt_data):
     """Different-ROLE receiver: ref_inf repointed at the actor role
     (ppo_ref_ema recipe) but placed on its OWN worker group [1] with
@@ -179,6 +181,7 @@ def test_cross_group_ema_ref_different_role(prompt_data):
     assert versions[1] >= 1  # EMA install happened after actor trained
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_cross_group_to_multiproc_receiver(prompt_data):
     """Actor trains on worker [0]; actor_gen executes on a replica
     mesh SPANNING workers [1, 2] (d2t2 over two processes). Both

@@ -32,11 +32,13 @@ def test_visualize_dfg(tmp_path):
     assert '"actor_gen" -> "actor_train"' in dot
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_load_and_eval_rw_demo():
     out = _run("load_and_eval_rw.py")
     assert "OK (random-init demo)" in out
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_ppo_ref_ema():
     out = _run("ppo_ref_ema.py")
     assert "EMA (eta=0.5) actor-replica reference" in out

@@ -23,7 +23,7 @@ from tiny_model import TINY, write_jsonl
 # each worker process gets 4 virtual CPU devices; the 2-process world
 # has 8 global devices for the d2t4 mesh
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
     "REALHF_TPU_LOCAL_DEVICE_COUNT": "4",
@@ -45,6 +45,7 @@ def sft_data(tmp_path):
     return str(path)
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_sft_worker_group_spanning_two_processes(sft_data):
     from realhf_tpu.apps.main import main_start
     from realhf_tpu.base import constants
@@ -87,6 +88,7 @@ def test_sft_worker_group_spanning_two_processes(sft_data):
     assert os.path.exists(os.path.join(save_dir, "optimizer_state.npz"))
 
 
+@pytest.mark.slow  # multi-process trial, >15 s alone (CHANGES.md, PR 22)
 def test_ppo_actor_group_with_single_worker_roles(tmp_path):
     """The 6-MFC PPO graph with the ACTOR spanning a 2-process worker
     group (d2t4 over 8 global devices) while critic/ref/reward stay on

@@ -9,6 +9,7 @@ Engine.decode_engine -> rollout/train weight-version tracking
 import json
 
 import numpy as np
+import pytest
 
 from realhf_tpu.base.testing import IntegerTokenizer
 from realhf_tpu.engine.optim import OptimizerConfig
@@ -19,6 +20,7 @@ from realhf_tpu.parallel.mesh import ParallelismConfig
 from tiny_model import TINY
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_ppo_pp_actor_decode_view(tmp_path):
     from realhf_tpu.system.inline import InlineRunner
 

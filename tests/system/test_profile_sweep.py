@@ -16,6 +16,7 @@ def sweep_file(tmp_path):
     return str(path)
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_profile_sweep_ranks_setups(sweep_file, tmp_path, capsys):
     sys.path.insert(0, "/root/repo/scripts")
     import profile_sweep

@@ -161,6 +161,7 @@ def test_quickstart_cli(sft_data, monkeypatch):
         "a.b": "1", "c": "x"}
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_ppo_decoupled_allocation(prompt_data):
     """PPO with actor_gen and ref_inf on different layouts than the
     trainable models: weight replicas must stay in sync through
@@ -266,6 +267,7 @@ def test_ppo_auto_offload(prompt_data):
     assert not runner.models["critic"].engine.offloaded
 
 
+@pytest.mark.slow  # full trial / multi-process, ~8-20 s (CHANGES.md, PR 22)
 def test_profile_mode_end_to_end():
     """Profile/mock mode (reference profile_exp.py:61): the 6-MFC PPO
     graph runs on fully synthetic data (random models + random

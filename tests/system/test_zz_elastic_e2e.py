@@ -17,7 +17,7 @@ import pytest
 from tiny_model import TINY, write_jsonl
 
 WORKER_ENV = {
-    "REALHF_TPU_BACKEND": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "PYTHONPATH": "/root/repo",
