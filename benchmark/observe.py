@@ -1,20 +1,34 @@
 """Watching a run from outside: compiles, whole steps, and in the
-traced run each MFC and each reshard on a blocked clock.
+traced steps each MFC and each reshard on a blocked clock.
 
 Nothing of the program changes. The observers wrap
 ``InlineRunner.run_step``, ``Engine.train_batch``,
 ``ModelHost.execute`` and ``ReplicaManager.ensure_fresh``, and take the
 wrappers off again. A step always ends blocked; an MFC and a reshard
-are blocked only with tracing on (an MFC on what it returned and on
-every role's weights), so that the end-to-end run keeps the program's
-own overlap and still says, on its step lines, which MFC a
-slow step was slow in. ``CompileWatch`` and the shape of
-``watched_run_step`` were copied from ``chip_smoke.py``.
+are blocked only in a traced run or stretch (an MFC on what it returned
+and on every role's weights), so that the end-to-end window keeps the
+program's own overlap and still says, on its step lines, which MFC a
+slow step was slow in. Tracing itself is the program's:
+``realhf_tpu.obs.tracing.start(trace_dir, sync=MFC_SYNC)`` starts the
+profiler and the program's own spans, ``stop()`` ends both.
+``CompileWatch`` and the shape of ``watched_run_step`` were copied from
+``chip_smoke.py``.
 """
 
 import logging
+import shutil
 import threading
 import time
+
+
+#: the program's spans that end blocked while the profiler records:
+#: exactly where the outside clocks block (an MFC on what it returned
+#: and the role's weights, a reshard on the replica), so the profiled
+#: steps are those of PR 23's traced run. Waiting after every engine
+#: program as well (``sync=True``) exposes host work that an interface
+#: hides behind the device (0.25 s a step in the four-chip cell:
+#: PERF.md), so that is done in steps of its own, without the profiler.
+MFC_SYNC = ("compute:", "realloc")
 
 
 class WindowOver(Exception):
@@ -113,7 +127,16 @@ class Observer:
     later. With ``trace_dir`` set, steps ``trace_from`` .. ``trace_from
     + trace_steps - 1`` are recorded by ``jax.profiler`` and every MFC
     and reshard is timed blocked (without it, unblocked: the wall until
-    the interface returned).
+    the interface returned). With ``after_window`` as well, the window
+    runs exactly as without ``trace_dir``; only when it has closed are
+    ``trace_steps`` more steps run, traced and blocked, then
+    ``trace_steps`` more with the profiler off and every span of the
+    program synced (the capture the span readers take), and then the
+    run is ended.
+
+    Tracing starts when the step before the first traced one has ended
+    and stops when the step after the last has begun, so that the
+    program's ``step`` spans lie whole in the capture.
 
     ``before_first_step(runner)`` runs once, inside set-up, when the
     runner has loaded every role and is about to take its first step.
@@ -121,7 +144,7 @@ class Observer:
 
     def __init__(self, seconds, watch, before_first_step=None,
                  trace_dir=None, trace_from=2, trace_steps=2,
-                 say=lambda **kw: None):
+                 say=lambda **kw: None, after_window=False):
         self.seconds = seconds
         self.watch = watch
         self.before_first_step = before_first_step
@@ -129,6 +152,8 @@ class Observer:
         self.trace_from = trace_from
         self.trace_steps = trace_steps
         self.say = say
+        self.after_window = after_window
+        self.window_last = None  # index of the step that closed the window
         self.runner = None
         self.steps = []       # one dict a finished step
         self.failed = 0       # steps that raised
@@ -139,10 +164,19 @@ class Observer:
         self.setup_compile_secs = None  # backend seconds up to step 0's end
         self.tracing = False
         self.traced = []      # indices of the steps in the trace
+        self.synced = None    # indices of the steps after it, every
+        #                       span of the program synced (a list then)
         self._lock = threading.Lock()
         self._undo = []
 
     # -- what is blocked on ----------------------------------------------
+    @property
+    def blocking(self):
+        """MFCs and reshards end blocked: in the whole of a traced run,
+        or once the window of a run that traces afterwards has closed."""
+        return bool(self.trace_dir) and (
+            not self.after_window or self.window_last is not None)
+
     def _weights(self, runner):
         models = list(runner.models.values()) \
             + list(runner.replicas.values())
@@ -162,6 +196,7 @@ class Observer:
         from jax.profiler import TraceAnnotation
 
         from realhf_tpu.engine.engine import Engine
+        from realhf_tpu.obs import tracing
         from realhf_tpu.parallel.realloc import ReplicaManager
         from realhf_tpu.system.inline import InlineRunner
         from realhf_tpu.system.model_host import ModelHost
@@ -188,8 +223,17 @@ class Observer:
                     obs.first_step_entry = time.monotonic()
                     if obs.before_first_step is not None:
                         obs.before_first_step(runner)
-                if obs.trace_dir and index == obs.trace_from:
-                    obs._start_trace()
+                if obs.tracing and len(obs.traced) == obs.trace_steps:
+                    obs._stop_trace()
+                    if obs.after_window:
+                        # this step's `step` span is open and finishes
+                        # into the new capture, whole
+                        tracing.start(sync=True)
+                        obs.synced = []
+                elif obs.synced is not None \
+                        and len(obs.synced) == obs.trace_steps:
+                    tracing.stop()
+                    raise WindowOver()
                 mark = obs.watch.mark()
                 start = time.monotonic()
                 try:
@@ -203,8 +247,8 @@ class Observer:
                 programs, compile_secs = obs.watch.since(mark)
                 if obs.tracing:
                     obs.traced.append(index)
-                    if len(obs.traced) == obs.trace_steps:
-                        obs._stop_trace()
+                elif obs.synced is not None:
+                    obs.synced.append(index)
                 step = dict(
                     index=index, start=start, end=end,
                     tokens=batch.total_len("packed_input_ids"),
@@ -219,10 +263,17 @@ class Observer:
                                   for i, n, s, e in obs.mfcs if i == index},
                         compiles=step["compiles"],
                         programs=step["programs"][:8])
-                done_tracing = not obs.trace_dir or (
-                    obs.traced and not obs.tracing)
-                if index >= 1 and done_tracing \
-                        and end - obs.steps[1]["start"] >= obs.seconds:
+                window_over = obs.window_last is None and index >= 1 \
+                    and end - obs.steps[1]["start"] >= obs.seconds
+                if obs.after_window:
+                    if window_over:  # its numbers are taken: now trace
+                        obs.window_last = index
+                        obs._start_trace()
+                    return stats
+                if obs.trace_dir and index == obs.trace_from - 1:
+                    obs._start_trace()
+                to_trace = obs.trace_dir and (obs.tracing or not obs.traced)
+                if window_over and not to_trace:
                     raise WindowOver()
                 return stats
             return run_step
@@ -232,7 +283,7 @@ class Observer:
                 start = time.monotonic()
                 with TraceAnnotation(f"bench:mfc:{node_name}"):
                     out = orig(host, node_name, inp)
-                    if obs.trace_dir:
+                    if obs.blocking:
                         jax.block_until_ready(
                             (obs._returned(out), obs._weights(host)))
                 with obs._lock:
@@ -248,7 +299,7 @@ class Observer:
                 before, start = synced(), time.monotonic()
                 with TraceAnnotation("bench:reshard"):
                     out = orig(mgr, role, primary, replica, *a, **kw)
-                    if obs.trace_dir:
+                    if obs.blocking:
                         jax.block_until_ready(replica.engine.params)
                 if synced() != before:
                     with obs._lock:
@@ -263,28 +314,40 @@ class Observer:
         patch(ReplicaManager, "ensure_fresh", watched_ensure_fresh)
 
     def uninstall(self):
+        from realhf_tpu.obs import tracing
         if self.tracing:
             self._stop_trace()
+        elif self.synced is not None and tracing.enabled():
+            tracing.stop()  # the run raised inside the synced steps
         for cls, name, orig in reversed(self._undo):
             setattr(cls, name, orig)
         self._undo = []
 
     def _start_trace(self):
-        import jax
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0  # spans by name are enough
-        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        from realhf_tpu.obs import tracing
+        if self.after_window:
+            # the profiler's first start in a process costs seconds:
+            # they are paid into a trace that is thrown away
+            first = self.trace_dir + ".first"
+            tracing.start(first)
+            tracing.stop()
+            shutil.rmtree(first, ignore_errors=True)
+        tracing.start(self.trace_dir, sync=MFC_SYNC)
         self.tracing = True
 
     def _stop_trace(self):
-        import jax
-        jax.profiler.stop_trace()
+        from realhf_tpu.obs import tracing
+        tracing.stop()
         self.tracing = False
 
     # -- what came of it -------------------------------------------------
     def window_steps(self):
-        """The measured steps: every finished step after the warm-up."""
-        return self.steps[1:]
+        """The measured steps: every finished step after the warm-up,
+        up to the one that closed the window (steps traced after it
+        are not among them)."""
+        last = len(self.steps) if self.window_last is None \
+            else self.window_last + 1
+        return self.steps[1:last]
 
     def step_record(self, index, categories):
         """One step's blocked walls by category, the reshard, and the

@@ -2,7 +2,7 @@
 """Run one cell of the benchmark once, in one process.
 
     python3 benchmark/run.py --workload <cell> --seed <n> \
-        --seconds <s> --trace <0|1>
+        --seconds <s> --trace <0|1|2>
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration under a traffic mix. The run makes weights, tokenizer and
@@ -10,7 +10,11 @@ data from the seed, enters the program as a user does
 (``realhf_tpu.apps.quickstart.main``), takes one whole warm-up step
 (everything up to its end is ``setup_s``), then whole steps until
 ``--seconds`` have passed since the first measured step began, and
-prints one JSON object as its last line. README.md has the rest.
+prints one JSON object as its last line. ``--trace 2`` is a ``--trace
+0`` run that, once its window has closed, traces ``TRACE_STEPS`` more
+steps in the same process (and runs as many again with the profiler off
+and every span of the program synced), and prints both kinds of metric.
+README.md has the rest, trace_in_run.md what ``--trace 2`` adds.
 
 Without a TPU, with fewer chips than the cell asks for, or with a
 device kind that ``arith.PEAKS`` does not list, it exits non-zero and
@@ -39,7 +43,8 @@ if ROOT not in sys.path:
 #: minibatch (generation's log-probabilities against training's)
 ON_POLICY_TOL = 0.05
 #: the steps the traced run records: two steady ones, after one
-#: measured step without the profiler
+#: measured step without the profiler (``--trace 1``) or after the
+#: window has closed (``--trace 2``)
 TRACE_FROM, TRACE_STEPS = 2, 2
 
 
@@ -147,7 +152,10 @@ def check_reference(cell, runner, ckpt, seed):
 def run_cell(cell, seed, seconds, trace, work, peaks,
              expect_kernels=True):
     """One run of one cell. ``work`` is an empty directory for what the
-    run writes. Returns the result line as a dict."""
+    run writes. ``trace`` is 0 (end-to-end metrics), 1 (a traced run
+    of its own: per-layer metrics) or 2 (the run of 0, then
+    ``TRACE_STEPS`` traced steps: both). Returns the result line as a
+    dict."""
     import jax
 
     from benchmark import generate, observe, trace_reduce
@@ -186,7 +194,8 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
     trace_dir = os.path.join(work, "trace") if trace else None
     obs = observe.Observer(
         seconds, watch, before_first_step, trace_dir=trace_dir,
-        trace_from=TRACE_FROM, trace_steps=TRACE_STEPS, say=say)
+        trace_from=TRACE_FROM, trace_steps=TRACE_STEPS, say=say,
+        after_window=trace == 2)
     obs.install()
     t_main = time.monotonic()
     try:
@@ -208,11 +217,14 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
     wall = steps[-1]["end"] - steps[0]["start"]
     tokens = sum(s["tokens"] for s in steps)
     step_secs = [s["end"] - s["start"] for s in steps]
-    window_compiles = sum(s["compiles"] for s in steps)
+    # programs lowered after the warm-up, the traced stretch included
+    window_compiles = sum(s["compiles"] for s in obs.steps[1:])
 
     bad_steps = set()
     first_minibatch = {}
     for index, stats in obs.opt_steps:
+        if index > steps[-1]["index"]:
+            break  # traced after the window: not what is judged
         first_minibatch.setdefault(index, stats)
         if not (math.isfinite(stats["loss"])
                 and math.isfinite(stats["grad_norm"])):
@@ -220,7 +232,7 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
     checks["finite"] = not bad_steps
     if kind.ON_POLICY:
         weights = [first_minibatch[s["index"]]["importance_weight"]
-                   for s in obs.steps]
+                   for s in obs.steps[:len(steps) + 1]]
         checks["importance_weight"] = dict(
             first_minibatch=weights,
             ok=all(abs(w - 1.0) < ON_POLICY_TOL for w in weights))
@@ -255,14 +267,15 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
                       bad_steps & {s["index"] for s in steps}))
     units = {m["name"]: m["unit"] for group in ("end_to_end", "per_layer")
              for m in cell["manifest"][group]}
-    if not trace:
+    result["metrics"] = {}
+    if trace != 1:
         values = dict(tokens_per_s=tokens / wall / chips,
                       step_max_s=max(step_secs), setup_s=setup_s)
         wanted = metrics_of(cell["manifest"], "end_to_end", cell["name"])
-        result["metrics"] = {
-            m["name"]: dict(value=values[m["name"]], unit=m["unit"])
-            for m in wanted}
-    else:
+        result["metrics"].update(
+            (m["name"], dict(value=values[m["name"]], unit=m["unit"]))
+            for m in wanted)
+    if trace:
         t = time.monotonic()
         files = sorted(glob.glob(os.path.join(
             trace_dir, "**", "*.xplane.pb"), recursive=True))
@@ -272,11 +285,14 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
         say(phase="trace", secs=round(time.monotonic() - t, 2),
             files=[(os.path.basename(f), os.path.getsize(f))
                    for f in files],
-            traced_steps=obs.traced,
+            traced_steps=obs.traced, synced_steps=obs.synced,
             reduced={k: v for k, v in (reduced or {}).items()
                      if k != "breakdown"})
-        step_records = [obs.step_record(s["index"], kind.MFCS)
-                        for s in steps]
+        # the steps whose MFCs were timed blocked: the whole window of
+        # a traced run, the traced stretch after an untraced window
+        step_records = [obs.step_record(i, kind.MFCS)
+                        for i in (obs.traced if trace == 2 else
+                                  [s["index"] for s in steps])]
         record = dict(
             steps=step_records,
             medians={k: statistics.median(r[k] for r in step_records)
@@ -288,7 +304,6 @@ def run_cell(cell, seed, seconds, trace, work, peaks,
             work=kind.work(family, hf, meta, cell["traffic"]),
             chips=chips, peaks=peaks)
         say(phase="mfcs", steps=record["steps"])
-        result["metrics"] = {}
         for name, reader in cell["readers"].items():
             value = reader.read(record)
             if value is not None:
@@ -307,7 +322,7 @@ def main():
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
-    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = p.parse_args()
 
     manifest = os.path.join(ROOT, "BENCHMARK.json")
@@ -335,7 +350,7 @@ def main():
     os.makedirs(work)
     os.environ["REALHF_TPU_ROOT"] = os.path.join(work, "root")
     try:
-        result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
+        result = run_cell(cell, args.seed, args.seconds, args.trace,
                           work, peaks)
     finally:
         shutil.rmtree(work, ignore_errors=True)

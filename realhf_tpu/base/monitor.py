@@ -1,18 +1,15 @@
-"""FLOPs accounting, timing marks, and device memory statistics.
+"""FLOPs accounting, per-MFC profiler dumps, and device memory
+statistics.
 
 Parity with reference ``realhf/base/monitor.py``: the FLOP formulas
-(:277-353) used by the master to log per-step TFLOP/s, a lightweight
-span-timing facility (the reference uses CUDA events; here spans wrap
-blocking host calls since XLA dispatch is async -- callers must
-`jax.block_until_ready` the result inside the span for true timings),
-and accelerator memory stats via JAX device APIs.
+(:277-353) used by the master to log per-step TFLOP/s, and accelerator
+memory stats via JAX device APIs. Timing lives in ``obs/tracing.py``
+(the reference's CUDA time marks): one span an MFC.
 """
 
 import os
 import contextlib
-import dataclasses
 import time
-from collections import defaultdict
 from typing import Dict, List
 
 
@@ -88,52 +85,6 @@ def generation_flops(
     return prefill + decode
 
 
-@dataclasses.dataclass
-class TimeMark:
-    name: str
-    start: float
-    end: float
-
-    @property
-    def elapsed(self):
-        return self.end - self.start
-
-
-class TimeMarkDB:
-    """Process-local span recorder (reference cuda_tmark, :375-427)."""
-
-    def __init__(self):
-        self.marks: Dict[str, List[TimeMark]] = defaultdict(list)
-
-    @contextlib.contextmanager
-    def mark(self, name: str):
-        st = time.monotonic()
-        try:
-            yield
-        finally:
-            self.marks[name].append(TimeMark(name, st, time.monotonic()))
-
-    def total(self, name: str) -> float:
-        return sum(m.elapsed for m in self.marks[name])
-
-    def summary(self) -> Dict[str, float]:
-        return {k: self.total(k) for k in self.marks}
-
-    def clear(self):
-        self.marks.clear()
-
-
-_tmark_db = TimeMarkDB()
-
-
-def tmark(name: str):
-    return _tmark_db.mark(name)
-
-
-def tmark_db() -> TimeMarkDB:
-    return _tmark_db
-
-
 def device_memory_stats(device=None) -> Dict[str, int]:
     """Per-chip HBM stats (replaces nvml polling, reference :255)."""
     import jax
@@ -163,10 +114,9 @@ def trace_dir(sub: str = "") -> str:
 
 @contextlib.contextmanager
 def mfc_profile_region(name: str):
-    """Wrap one MFC execution:
+    """Wrap one MFC execution (inside its ``compute:<name>`` span,
+    which times and names it):
 
-    - always: a wall-clock span in the TimeMarkDB and an XLA trace
-      annotation (shows up as a named region in any enclosing profile);
     - REALHF_TPU_DUMP_TRACE=1: a full ``jax.profiler.trace`` dumped to
       ``{log}/trace/{name}/`` (TensorBoard/perfetto-readable -- the
       reference's per-MFC chrome traces);
@@ -178,12 +128,8 @@ def mfc_profile_region(name: str):
     dump_trace = os.environ.get(DUMP_TRACE_ENV, "") == "1"
     dump_memory = os.environ.get(DUMP_MEMORY_ENV, "") == "1"
     safe = name.replace("/", "_")
-    ctx = contextlib.ExitStack()
-    with ctx:
-        if dump_trace:
-            ctx.enter_context(jax.profiler.trace(trace_dir(safe)))
-        ctx.enter_context(jax.profiler.TraceAnnotation(f"mfc:{name}"))
-        ctx.enter_context(_tmark_db.mark(f"mfc/{name}"))
+    with (jax.profiler.trace(trace_dir(safe)) if dump_trace
+          else contextlib.nullcontext()):
         yield
     if dump_memory:
         path = os.path.join(trace_dir(safe),

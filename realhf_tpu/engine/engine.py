@@ -35,6 +35,7 @@ from realhf_tpu.engine.optim import OptimizerConfig, make_optimizer
 from realhf_tpu.models import sharding as shard_rules
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.obs import metrics, tracing
 from realhf_tpu.ops import functional as F
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
@@ -272,6 +273,8 @@ class Engine:
             self._opt_shardings = None
             self._grad_shardings = None
 
+        # engine_compiles_total / engine_compile_secs_total from here on
+        metrics.watch_compiles()
         self._train_step_cache: Dict[Any, Callable] = {}
         self._generate_cache: Dict[Any, Callable] = {}
         # program name -> (jitted fn, abstract args, static kwargs) of
@@ -295,7 +298,15 @@ class Engine:
         abstract signature for :meth:`compiled_text`."""
         self._last_call[name] = (fn, jax.tree.map(_abstract, args),
                                  static)
-        return fn(*args, **static)
+        if not tracing.enabled():
+            return fn(*args, **static)
+        # engine:<name> holds the dispatch and, in a synced stretch,
+        # the wait for the outputs
+        with tracing.span(f"engine:{name}") as sp:
+            lowered = fn._cache_size()
+            out = sp.result(fn(*args, **static))
+            sp.set_attribute("compiled", fn._cache_size() > lowered)
+            return out
 
     def compiled_text(self, name: str) -> str:
         """Optimized HLO of the program last run under ``name``
@@ -388,8 +399,8 @@ class Engine:
         ``_build_train_seq`` (a lax.scan over minibatches inside one
         dispatch)."""
 
-        def step(params, opt_state, mbs: Dict[str, jnp.ndarray],
-                 mb_weights: jnp.ndarray):
+        def train_step(params, opt_state, mbs: Dict[str, jnp.ndarray],
+                       mb_weights: jnp.ndarray):
             """mbs: dict of stacked arrays with leading dim n_mbs;
             mb_weights: [n_mbs] relative weight (e.g. token counts) used
             to average gradients exactly as one large batch would."""
@@ -403,7 +414,8 @@ class Engine:
             def accum(carry, x):
                 gsum = carry
                 mb, w = x
-                (loss, stats), grads = grad_fn(params, mb)
+                with jax.named_scope("forward_backward"):
+                    (loss, stats), grads = grad_fn(params, mb)
                 gsum = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32) * w, gsum, grads)
                 if self._grad_shardings is not None:
@@ -414,14 +426,17 @@ class Engine:
             wsum = mb_weights.sum()
             gsum, (losses, stats) = jax.lax.scan(
                 accum, zero, (mbs, mb_weights / wsum))
-            updates, new_opt = self._tx.update(gsum, opt_state, params)
-            if self._opt_shardings is not None:
-                # keep the ZeRO-1 moment shardings stable across steps
-                # (donated buffers must alias exactly)
-                new_opt = jax.tree.map(
-                    lambda s, sh: jax.lax.with_sharding_constraint(s, sh),
-                    new_opt, self._opt_shardings)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = self._tx.update(gsum, opt_state,
+                                                   params)
+                if self._opt_shardings is not None:
+                    # keep the ZeRO-1 moment shardings stable across
+                    # steps (donated buffers must alias exactly)
+                    new_opt = jax.tree.map(
+                        lambda s, sh:
+                        jax.lax.with_sharding_constraint(s, sh),
+                        new_opt, self._opt_shardings)
+                new_params = optax.apply_updates(params, updates)
             gnorm = optax.global_norm(gsum)
             mean_stats = jax.tree.map(
                 lambda s: (s * mb_weights / wsum).sum(), stats)
@@ -445,7 +460,7 @@ class Engine:
             mean_loss = (losses * mb_weights / wsum).sum()
             return new_params, new_opt, mean_loss, mean_stats, gnorm
 
-        return step
+        return train_step
 
     def _train_out_shardings(self, extra_outs: int):
         """Pin the params/opt-state OUTPUTS of a train jit to their
@@ -473,7 +488,7 @@ class Engine:
         calling train_batch once per minibatch."""
         body = self._train_step_body(loss_fn)
 
-        def seq(params, opt_state, all_mbs, all_weights):
+        def train_seq(params, opt_state, all_mbs, all_weights):
             def outer(carry, x):
                 p, o = carry
                 mbs, w = x
@@ -484,7 +499,7 @@ class Engine:
                 outer, (params, opt_state), (all_mbs, all_weights))
             return params, opt_state, losses, stats, gnorms
 
-        return jax.jit(seq, donate_argnums=(0, 1),
+        return jax.jit(train_seq, donate_argnums=(0, 1),
                        out_shardings=self._train_out_shardings(3))
 
     def train_batch(self, microbatches: List[Dict[str, np.ndarray]],
@@ -615,7 +630,7 @@ class Engine:
     # ------------------------------------------------------------------
     def forward_hidden(self, input_ids, seg_ids):
         if self._jit_forward_hidden is None:
-            def f(params, ids, seg):
+            def hidden(params, ids, seg):
                 h, _ = T.forward(self.cfg, params, ids, seg,
                                  activation_constraint=self._constrain,
                                  attention_fn=self._infer_attention_fn,
@@ -623,7 +638,7 @@ class Engine:
                                  pipeline=self.pipeline_ctx_infer)
                 return h
             self._jit_forward_hidden = jax.jit(
-                f, out_shardings=self._out_replicated())
+                hidden, out_shardings=self._out_replicated())
         ids, seg = self._globalize_tree((input_ids, seg_ids))
         return self._run("hidden", self._jit_forward_hidden,
                          self.params, ids, seg)
@@ -633,7 +648,7 @@ class Engine:
         """Next-token logprobs [S, L] (the reference's `inference` MFC
         on actor/ref models, ppo_interface.py:255)."""
         if self._jit_logprobs is None:
-            def f(params, ids, seg, mask, temp, has_mask):
+            def logprobs(params, ids, seg, mask, temp, has_mask):
                 h, _ = T.forward(self.cfg, params, ids, seg,
                                  activation_constraint=self._constrain,
                                  attention_fn=self._infer_attention_fn,
@@ -643,7 +658,7 @@ class Engine:
                     self.cfg, params, h, ids, seg, temperature=temp,
                     logits_mask=mask if has_mask else None)
             self._jit_logprobs = jax.jit(
-                f, static_argnames=("temp", "has_mask"),
+                logprobs, static_argnames=("temp", "has_mask"),
                 out_shardings=self._out_replicated())
         ids, seg, mask = self._globalize_tree(
             (input_ids, seg_ids,
@@ -657,7 +672,7 @@ class Engine:
         """Critic/reward scalar outputs [S, L]."""
         assert self.cfg.is_critic
         if self._jit_values is None:
-            def f(params, ids, seg):
+            def values(params, ids, seg):
                 h, _ = T.forward(self.cfg, params, ids, seg,
                                  activation_constraint=self._constrain,
                                  attention_fn=self._infer_attention_fn,
@@ -665,7 +680,7 @@ class Engine:
                                  pipeline=self.pipeline_ctx_infer)
                 return T.critic_values(self.cfg, params, h)
             self._jit_values = jax.jit(
-                f, out_shardings=self._out_replicated())
+                values, out_shardings=self._out_replicated())
         ids, seg = self._globalize_tree((input_ids, seg_ids))
         return self._run("values", self._jit_values, self.params, ids,
                          seg)

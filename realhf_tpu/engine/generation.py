@@ -70,11 +70,12 @@ def generate(
     b, lp = prompt_ids.shape
     prompt_lens = (prompt_seg != 0).sum(-1).astype(jnp.int32)
 
-    hidden, cache = T.prefill(cfg, params, prompt_ids, prompt_seg, prompt_pos,
-                              total_len=lp + gconfig.max_new_tokens,
-                              activation_constraint=activation_constraint,
-                              attention_fn=attention_fn,
-                              moe_constraint=moe_constraint)
+    with jax.named_scope("prefill"):
+        hidden, cache = T.prefill(
+            cfg, params, prompt_ids, prompt_seg, prompt_pos,
+            total_len=lp + gconfig.max_new_tokens,
+            activation_constraint=activation_constraint,
+            attention_fn=attention_fn, moe_constraint=moe_constraint)
     last_hidden = hidden[:, -1]  # left padding => last column is last token
 
     def sample_step(logits, step_idx, unfinished, k):
@@ -109,17 +110,20 @@ def generate(
 
     def step_once(last_hidden, cache, unfinished, emitted, step_idx, k):
         """One decode step, shared by the scan and while-loop drivers."""
-        logits = T.lm_logits(cfg, params, last_hidden)
         was_unfinished = unfinished
-        tokens, logprob, mask, unfinished = sample_step(
-            logits, step_idx, unfinished, k)
+        with jax.named_scope("decode"):  # the vocabulary head
+            logits = T.lm_logits(cfg, params, last_hidden)
+        with jax.named_scope("sample"):
+            tokens, logprob, mask, unfinished = sample_step(
+                logits, step_idx, unfinished, k)
         emitted = emitted + was_unfinished.astype(jnp.int32)
         pos = prompt_lens + step_idx
         # all streams share the padded prompt length, so cache writes
         # land in one uniform slot (dynamic_update_slice fast path)
-        new_hidden, cache = T.decode_step(cfg, params, cache, tokens, pos,
-                                          moe_constraint, uniform_slot=True,
-                                          mesh=mesh)
+        with jax.named_scope("decode"):
+            new_hidden, cache = T.decode_step(
+                cfg, params, cache, tokens, pos, moe_constraint,
+                uniform_slot=True, mesh=mesh)
         return new_hidden, cache, unfinished, emitted, tokens, logprob, mask
 
     want_mask = not gconfig.force_no_logits_mask
@@ -212,6 +216,9 @@ def build_generate_fn(cfg: TransformerConfig,
 
     def run(params, prompt_ids, prompt_seg, prompt_pos, key):
         return fn(params, prompt_ids, prompt_seg, prompt_pos, key)
+
+    # XLA names a module for its function: jit_generate in a trace
+    run.__name__ = "generate"
 
     # out_sharding: replicated outputs on multi-process meshes so every
     # worker-group member can read the generated tokens.

@@ -3,7 +3,7 @@
 Parity with reference ``realhf/experiments/benchmark/profile_exp.py``
 (+ ``ModelInterface.mock``): run the 6-MFC PPO dataflow graph with
 random-init models and random prompts through the real runtime (inline
-or distributed), with per-MFC timing from the TimeMarkDB and optional
+or distributed), with per-MFC timing from the tracer's spans and optional
 ``jax.profiler`` trace dumps (REALHF_TPU_DUMP_TRACE=1 /
 REALHF_TPU_DUMP_MEMORY=1, base/monitor.py). Serves as both a system
 test (everything wired, nothing real needed) and the measurement rig
@@ -15,7 +15,7 @@ for allocation decisions.
 """
 
 import dataclasses
-from typing import Dict
+from typing import Any, Callable, Dict, Tuple
 
 from realhf_tpu.api.config import DatasetAbstraction
 from realhf_tpu.api.experiment import ExperimentSpec, ModelSpec
@@ -85,9 +85,19 @@ class ProfileConfig(PPOConfig):
 register_experiment("profile", ProfileConfig)
 
 
-def mfc_timing_summary() -> Dict[str, float]:
-    """Per-MFC wall-clock totals recorded by the runtime's
-    mfc_profile_region spans (seconds)."""
-    from realhf_tpu.base import monitor
-    return {k: v for k, v in monitor.tmark_db().summary().items()
-            if k.startswith("mfc/")}
+def mfc_timing_summary(run: Callable[[], Any]
+                       ) -> Tuple[Any, Dict[str, float]]:
+    """``run()`` (a runner's ``run``) under a synced capture of its
+    own: what it returned, and per-MFC wall-clock totals in seconds
+    (MFC name -> the sum of its ``mfc:<name>`` spans)."""
+    from realhf_tpu.obs import tracing
+    tracing.start(sync=True)
+    try:
+        out = run()
+    finally:
+        capture = tracing.stop()
+    secs: Dict[str, float] = {}
+    for s in capture.named("mfc:"):
+        name = s["name"][len("mfc:"):]
+        secs[name] = secs.get(name, 0.0) + s["end"] - s["start"]
+    return out, secs

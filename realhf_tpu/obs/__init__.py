@@ -25,7 +25,8 @@ Three cooperating pieces (docs/observability.md):
 :func:`configure_from_env` is the one call every process entry point
 makes (``worker_base.Worker``, the inline runner, quickstart): it
 labels the default tracer/registry/recorder with the process name and
-turns file export on when ``REALHF_TPU_TRACE=1``.
+turns file export on when ``REALHF_TPU_TRACE=1``. In a live process
+the control is ``tracing.start()`` / ``tracing.stop()``.
 """
 
 from typing import Optional
@@ -62,9 +63,9 @@ def configure_from_env(process_name: str,
     try:
         if trace_on:
             tracing.configure(
-                enabled=True,
                 path=tracing.trace_file_path(process_name, experiment,
                                              trial))
+            tracing.start()  # unsynced: the run keeps its overlap
         if metrics_env not in ("", "0") and metrics_env != "1":
             metrics.default_registry().attach_jsonl(metrics_env)
         elif trace_on or metrics_env == "1":

@@ -91,6 +91,12 @@ def _clip(intervals: List[Interval], lo: float, hi: float
             if min(e, hi) > max(s, lo)]
 
 
+def covered_seconds(window: Interval,
+                    intervals: List[Interval]) -> float:
+    """Seconds of ``window`` that the union of ``intervals`` covers."""
+    return _measure(_clip(_merge(list(intervals)), *window))
+
+
 # ----------------------------------------------------------------------
 # Loading.
 # ----------------------------------------------------------------------

@@ -516,6 +516,29 @@ def flush_final():
     _default.flush_final()
 
 
+_watching_compiles = False
+
+
+def watch_compiles():
+    """Count, from now to the end of the process, every program the
+    backend compiles or loads from the persistent cache
+    (``engine_compiles_total``) and the seconds that took
+    (``engine_compile_secs_total``). JAX cannot drop a listener, so
+    there is one a process, registered by the first engine."""
+    global _watching_compiles
+    if _watching_compiles:
+        return
+    from jax import monitoring
+
+    def on_secs(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            inc("engine_compiles_total")
+            inc("engine_compile_secs_total", secs)
+
+    monitoring.register_event_duration_secs_listener(on_secs)
+    _watching_compiles = True
+
+
 def metrics_file_path(process_name: str,
                       experiment: Optional[str] = None,
                       trial: Optional[str] = None) -> str:

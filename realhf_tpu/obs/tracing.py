@@ -13,15 +13,40 @@ Propagation: a span's :class:`SpanContext` serializes to a plain dict
 in the serving submit envelope; the receiving process ``extract``\\ s it
 and parents its spans there, so causality survives process hops.
 
-The tracer is OFF by default (every call is a cheap no-op). It turns
-on either programmatically (:func:`configure`) or through the
-``REALHF_TPU_TRACE=1`` env switch honored by every worker process,
-the inline runner, and quickstart (:func:`configure_from_env`). When a
-file path is configured, finished spans stream to it as JSON lines
-(one Chrome event per line); :func:`merge_traces` folds every
-per-process file of a run into one ``merged_trace.json``.
+The tracer is OFF by default (every call is a cheap no-op). The
+control is :func:`start` / :func:`stop`, callable any number of times
+in a running process: ``start`` turns spans on and, given a directory,
+starts ``jax.profiler`` too; ``stop`` returns the :class:`Capture`
+(spans and counter deltas), which stays readable as
+:func:`last_capture` (and the few before it as :func:`captures`).
+The ``REALHF_TPU_TRACE=1`` env switch honored by every worker
+process, the inline runner, and quickstart
+(:func:`configure_from_env`) is a caller of ``start``. When a file path
+is configured, finished spans stream to it as JSON lines (one Chrome
+event per line); :func:`merge_traces` folds every per-process file of
+a run into one ``merged_trace.json``. Without a path they stay in
+memory until ``stop``.
+
+Clocks: a span takes ``time.monotonic()`` (steady, and the clock the
+benchmark's harness reads); :data:`EPOCH_OFFSET`, taken once a
+process, turns it into wall-clock time at Chrome export only, so the
+files of several processes still line up. While ``start`` has a
+profile recording, entering a scoped span also enters a
+``jax.profiler.TraceAnnotation`` of the same name: the span then lies
+in the ``.xplane.pb`` beside the device's operations, on their clock.
+
+Synced: a span may be handed what its work produced
+(:meth:`Span.result`). Under ``start(sync=True)`` leaving the span
+first waits for that value (``jax.block_until_ready``), so the span
+holds the device work it caused; otherwise it ends at the enqueue and
+the program keeps its own overlap. ``sync`` may also be a tuple of name
+prefixes: only those spans wait (``("compute:", "realloc")`` ends every
+MFC and reshard blocked and leaves the overlap inside an MFC alone;
+waiting after each engine program can expose host work that the
+program hides behind the device). ``REALHF_TPU_TRACE=1`` is unsynced.
 """
 
+import collections
 import contextlib
 import dataclasses
 import json
@@ -30,7 +55,16 @@ import threading
 import time
 import uuid
 import zlib
-from typing import Any, Dict, Iterator, List, Optional
+from typing import (
+    Any,
+    Deque,
+    Dict,
+    Iterator,
+    List,
+    Optional,
+    Tuple,
+    Union,
+)
 
 from realhf_tpu.base import logging
 
@@ -40,6 +74,25 @@ TRACE_ENV = "REALHF_TPU_TRACE"
 
 #: file name of the per-run merged Chrome trace (Perfetto-loadable)
 MERGED_TRACE_NAME = "merged_trace.json"
+
+#: wall-clock seconds since the epoch at this process's
+#: ``time.monotonic() == 0``: added to a span's times where they leave
+#: the process (Chrome export, ``to_epoch``), nowhere else
+EPOCH_OFFSET = time.time() - time.monotonic()
+
+#: how many captures stay readable after their ``stop`` (a traced
+#: stretch may be several: profiled steps, then synced ones)
+KEPT_CAPTURES = 8
+
+#: counters whose deltas a capture reports (docs/observability.md)
+CAPTURE_COUNTERS = ("realloc_bytes_total", "engine_compiles_total",
+                    "engine_compile_secs_total")
+
+
+def to_epoch(monotonic_secs: float) -> float:
+    """A reading of this process's ``time.monotonic()`` as wall-clock
+    seconds, comparable across processes."""
+    return monotonic_secs + EPOCH_OFFSET
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +123,8 @@ class Span:
     like a serving request); ``finish()`` records it."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "end", "attributes", "_tracer", "_finished")
+                 "end", "thread", "attributes", "_tracer", "_finished",
+                 "_result")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: Optional[SpanContext], attributes: Dict):
@@ -79,10 +133,12 @@ class Span:
         self.trace_id = parent.trace_id if parent else _new_id()
         self.span_id = _new_id()
         self.parent_id = parent.span_id if parent else None
-        self.start = time.time()
+        self.start = time.monotonic()
         self.end: Optional[float] = None
+        self.thread = threading.get_ident()
         self.attributes = dict(attributes)
         self._finished = False
+        self._result = None
 
     @property
     def context(self) -> SpanContext:
@@ -91,12 +147,30 @@ class Span:
     def set_attribute(self, key: str, value: Any):
         self.attributes[key] = value
 
+    def result(self, value):
+        """Hand the span what its work produced and get it back. A
+        synced tracer waits for it before the span ends."""
+        self._result = value
+        return value
+
     def finish(self, end_time: Optional[float] = None):
+        """``end_time`` is a reading of ``time.monotonic()``."""
         if self._finished:
             return
         self._finished = True
-        self.end = end_time if end_time is not None else time.time()
+        if self._result is not None:
+            if self._tracer.waits_for(self.name):
+                import jax
+                jax.block_until_ready(self._result)
+            self._result = None
+        self.end = end_time if end_time is not None else time.monotonic()
         self._tracer._record(self)
+
+    def as_dict(self) -> Dict[str, Any]:
+        return dict(name=self.name, start=self.start, end=self.end,
+                    span_id=self.span_id, parent_id=self.parent_id,
+                    trace_id=self.trace_id, thread=self.thread,
+                    attributes=dict(self.attributes))
 
 
 class _NoopSpan:
@@ -110,6 +184,9 @@ class _NoopSpan:
 
     def set_attribute(self, key, value):
         pass
+
+    def result(self, value):
+        return value
 
     def finish(self, end_time=None):
         pass
@@ -126,7 +203,77 @@ class _ThreadBuffer(threading.local):
     def __init__(self, register):
         self.spans: List[Span] = []
         self.stack: List[Span] = []
+        #: parent of this thread's top-level spans (Tracer.attach)
+        self.inherited: Optional[SpanContext] = None
         register(self.spans)
+
+
+@dataclasses.dataclass
+class Capture:
+    """What one ``start`` .. ``stop`` recorded. ``spans`` are dicts
+    (``name``, ``start``, ``end`` on this process's
+    ``time.monotonic()``, ``span_id``, ``parent_id``, ``trace_id``,
+    ``thread``, ``attributes``) in order of their start; ``counters``
+    maps ``name{label=value,...}`` of every :data:`CAPTURE_COUNTERS`
+    series to its growth in between. With a file path configured the
+    spans already flushed to the file are not here as well."""
+    spans: List[Dict[str, Any]]
+    counters: Dict[str, float]
+    start: float
+    end: float
+    sync: Union[bool, Tuple[str, ...]] = False
+    profile_dir: Optional[str] = None
+
+    def named(self, prefix: str) -> List[Dict[str, Any]]:
+        """Spans called ``prefix`` or ``prefix<something>`` where the
+        prefix ends in a colon (``mfc:``)."""
+        if prefix.endswith(":"):
+            return [s for s in self.spans
+                    if s["name"].startswith(prefix)]
+        return [s for s in self.spans if s["name"] == prefix]
+
+    def children(self, span: Dict[str, Any]) -> List[Dict[str, Any]]:
+        return [s for s in self.spans
+                if s["parent_id"] == span["span_id"]]
+
+    def descendants(self, span: Dict[str, Any]) -> List[Dict[str, Any]]:
+        out, level = [], self.children(span)
+        while level:
+            out.extend(level)
+            level = [c for s in level for c in self.children(s)]
+        return out
+
+    def self_seconds(self, span: Dict[str, Any], cover=None) -> float:
+        """The span's duration less the part of it that its children
+        cover (their union: children in several threads overlap).
+        With ``cover``, a predicate on a span, less the part that the
+        DESCENDANTS it holds for cover: an ``mfc:*`` span's time
+        outside every ``engine:*`` span beneath it."""
+        from realhf_tpu.obs import analyze
+        below = self.children(span) if cover is None else [
+            s for s in self.descendants(span) if cover(s)]
+        return span["end"] - span["start"] - analyze.covered_seconds(
+            (span["start"], span["end"]),
+            [(s["start"], s["end"]) for s in below])
+
+    def counter(self, name: str, **labels) -> float:
+        return self.counters.get(_series(name, labels), 0.0)
+
+
+def _series(name: str, labels) -> str:
+    inner = ",".join(f"{k}={v}" for k, v in sorted(dict(labels).items()))
+    return f"{name}{{{inner}}}" if inner else name
+
+
+def _counter_values() -> Dict[str, float]:
+    from realhf_tpu.obs import metrics
+    out = {}
+    for name, m in metrics.snapshot().items():
+        if name in CAPTURE_COUNTERS:
+            for labels, value in m["values"].items():
+                out[_series(name, json.loads(labels) if labels
+                            else {})] = value
+    return out
 
 
 class Tracer:
@@ -137,6 +284,18 @@ class Tracer:
         self.process_name = process_name
         self.enabled = enabled
         self.path = path
+        #: leaving a span first waits for its result: True for every
+        #: span, or the name prefixes of those that wait (start(sync=))
+        self.sync: Union[bool, Tuple[str, ...]] = False
+        #: a profile that start() began is recording: scoped spans
+        #: also enter this class (jax.profiler.TraceAnnotation)
+        self._annotation = None
+        self._profile_dir: Optional[str] = None
+        self._started: Optional[float] = None
+        self._counters_at_start: Dict[str, float] = {}
+        #: what the last few start .. stop pairs recorded, newest last
+        self._captures: Deque[Capture] = collections.deque(
+            maxlen=KEPT_CAPTURES)
         self._buffers: List[List[Span]] = []
         self._buffers_lock = threading.Lock()
         self._file_lock = threading.Lock()
@@ -159,6 +318,70 @@ class Tracer:
         with self._buffers_lock:
             self._buffers.append(buf)
 
+    # -- the control ----------------------------------------------------
+    def waits_for(self, name: str) -> bool:
+        return self.sync is True or bool(
+            self.sync and name.startswith(self.sync))
+
+    def start(self, profile_dir: Optional[str] = None,
+              sync: Union[bool, Tuple[str, ...]] = False):
+        """Turn spans on, in a process that may have run for hours.
+        With ``profile_dir``, also start ``jax.profiler`` into it
+        (python tracer off: the spans carry the names) and write every
+        scoped span into that profile as a ``TraceAnnotation``. With
+        ``sync``, spans wait for their results before they end (True:
+        all of them; a tuple of name prefixes: those). A ``start``
+        while started stops the earlier capture first."""
+        if self._started is not None:
+            self.stop()
+        self.drain()  # what an earlier configure(enabled=True) left
+        self._counters_at_start = _counter_values()
+        self.sync = sync if isinstance(sync, bool) else tuple(sync)
+        if profile_dir is not None:
+            import jax
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(profile_dir,
+                                     profiler_options=options)
+            self._annotation = jax.profiler.TraceAnnotation
+            self._profile_dir = profile_dir
+        self._started = time.monotonic()
+        self.enabled = True
+
+    def stop(self) -> Optional[Capture]:
+        """Turn spans (and the profile) off and return what was
+        recorded since ``start``; None if not started. Spans still
+        open are not in it."""
+        if self._started is None:
+            return None
+        self.enabled = False
+        profile_dir, self._profile_dir = self._profile_dir, None
+        if self._annotation is not None:
+            self._annotation = None
+            import jax
+            jax.profiler.stop_trace()
+        spans = self.drain()
+        self._write(spans)
+        now = _counter_values()
+        deltas = {k: v - self._counters_at_start.get(k, 0.0)
+                  for k, v in now.items()}
+        capture = Capture(
+            spans=sorted((s.as_dict() for s in spans),
+                         key=lambda s: s["start"]),
+            counters={k: v for k, v in deltas.items() if v},
+            start=self._started, end=time.monotonic(), sync=self.sync,
+            profile_dir=profile_dir)
+        self._started, self.sync = None, False
+        self._captures.append(capture)
+        return capture
+
+    def captures(self) -> List[Capture]:
+        """The last :data:`KEPT_CAPTURES` captures, newest last."""
+        return list(self._captures)
+
+    def last_capture(self) -> Optional[Capture]:
+        return self._captures[-1] if self._captures else None
+
     @property
     def pid(self) -> int:
         """Stable integer process id for Chrome events: derived from
@@ -174,7 +397,19 @@ class Tracer:
 
     def current_context(self) -> Optional[SpanContext]:
         cur = self.current_span()
-        return cur.context if cur is not None else None
+        return cur.context if cur is not None else self._tl.inherited
+
+    @contextlib.contextmanager
+    def attach(self, parent: Optional[SpanContext]):
+        """Carry on a caller's work in another thread: top-level spans
+        this thread opens inside become children of ``parent`` (what
+        ``current_context()`` gave in the caller's thread)."""
+        previous = self._tl.inherited
+        self._tl.inherited = parent
+        try:
+            yield
+        finally:
+            self._tl.inherited = previous
 
     def inject(self) -> Optional[Dict[str, str]]:
         """Current span context as a payload-ready dict (None when no
@@ -209,16 +444,25 @@ class Tracer:
             return
         sp = self.start_span(name, parent=parent, **attributes)
         self._tl.stack.append(sp)
+        annotation = self._annotation
+        if annotation is not None:
+            annotation = annotation(name)
+            annotation.__enter__()
         try:
             yield sp
         except BaseException as e:
             sp.set_attribute("error", repr(e))
+            sp._result = None  # nothing to wait for
             raise
         finally:
             stack = self._tl.stack
             if stack and stack[-1] is sp:
                 stack.pop()
-            sp.finish()
+            try:
+                sp.finish()
+            finally:
+                if annotation is not None:
+                    annotation.__exit__(None, None, None)
 
     # -- recording / export ---------------------------------------------
     def _record(self, span: Span):
@@ -243,9 +487,9 @@ class Tracer:
             args["parent_id"] = span.parent_id
         return {
             "name": span.name, "ph": "X", "cat": "span",
-            "ts": span.start * 1e6,
+            "ts": to_epoch(span.start) * 1e6,
             "dur": max(0.0, (span.end or span.start) - span.start) * 1e6,
-            "pid": self.pid, "tid": threading.get_ident() & 0x7FFFFFFF,
+            "pid": self.pid, "tid": span.thread & 0x7FFFFFFF,
             "args": args,
         }
 
@@ -260,11 +504,15 @@ class Tracer:
         return events
 
     def flush(self):
-        """Drain buffered spans; when a file path is configured,
-        append them to it as JSON lines. Serialization happens outside
-        any span-recording path, so instrumented code never blocks on
+        """When a file path is configured, drain buffered spans and
+        append them to it as JSON lines; without one they stay in
+        memory for ``stop``. Serialization happens outside any
+        span-recording path, so instrumented code never blocks on
         file IO."""
-        spans = self.drain()
+        if self.path:
+            self._write(self.drain())
+
+    def _write(self, spans: List[Span]):
         if not spans or not self.path:
             return
         lines = [json.dumps(e, default=str)
@@ -302,7 +550,25 @@ def configure(process_name: Optional[str] = None,
 def reset_default():
     """Fresh default tracer (test isolation)."""
     global _default
+    _default.stop()  # a profile left recording would outlive it
     _default = Tracer()
+
+
+def start(profile_dir: Optional[str] = None,
+          sync: Union[bool, Tuple[str, ...]] = False):
+    _default.start(profile_dir=profile_dir, sync=sync)
+
+
+def stop() -> Optional[Capture]:
+    return _default.stop()
+
+
+def last_capture() -> Optional[Capture]:
+    return _default.last_capture()
+
+
+def captures() -> List[Capture]:
+    return _default.captures()
 
 
 def enabled() -> bool:
@@ -320,6 +586,16 @@ def start_span(name: str, parent: Optional[SpanContext] = None,
 
 def current_context() -> Optional[SpanContext]:
     return _default.current_context()
+
+
+def current_span():
+    """The calling thread's innermost open span; the no-op span where
+    there is none, so a callee can annotate its caller's span."""
+    return _default.current_span() or NOOP_SPAN
+
+
+def attach(parent: Optional[SpanContext]):
+    return _default.attach(parent)
 
 
 def inject() -> Optional[Dict[str, str]]:

@@ -254,6 +254,7 @@ def flash_decode_attention(
     res = pl.pallas_call(
         kernel, out_shape=out_shape, grid=(b, nkv),
         in_specs=in_specs, out_specs=out_specs, interpret=interpret,
+        name="decode_attn",
     )(qg, k_cache, v_cache, keep_b)
     return _trim_stats(res, return_stats, b, nq, group)
 
@@ -537,6 +538,6 @@ def flash_decode_attention_stacked(
         in_specs=in_specs, out_specs=out_specs)
     res = pl.pallas_call(
         kernel, out_shape=out_shape, grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=interpret, name="decode_attn_stacked",
     )(lidx, qg, k_all, v_all, keep_b)
     return _trim_stats(res, return_stats, b, nq, group)

@@ -157,6 +157,7 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
             pl.BlockSpec((1, 1, bq, hd), lambda bi, h, qi: (bi, h, qi, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda bi, h, qi: (bi, h, qi, 0)),
         ),
+        name="flash_fwd",
     )(qt, kt, vt, segq, segk)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -288,6 +289,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
         ],
         out_specs=pl.BlockSpec((1, 1, bq_, hd),
                                lambda bi, h, qi: (bi, h, qi, 0)),
+        name="flash_bwd_dq",
     )(qt, kt, vt, segq, segk, dot, lse, delta)
 
     grid_k = (b, nq, l // bk_)
@@ -315,6 +317,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
             pl.BlockSpec((1, 1, bk_, hd), lambda bi, h, ki: (bi, h, ki, 0)),
             pl.BlockSpec((1, 1, bk_, hd), lambda bi, h, ki: (bi, h, ki, 0)),
         ),
+        name="flash_bwd_dkv",
     )(qt, kt, vt, segq, segk, dot, lse, delta)
 
     # Sum q-head partials within each KV group.

@@ -384,6 +384,7 @@ def _fused_local(q, k, v, seg, *, mesh, axis, n, scale, causal,
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=(pltpu.InterpretParams() if interpret else False),
+        name="ring_attn_fused",
     )(qt, segq, kt, vt, segk)
 
     o = out[0].reshape(b, nq, lc, hd).transpose(0, 2, 1, 3)

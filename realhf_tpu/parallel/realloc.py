@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from realhf_tpu.base import logging
 from realhf_tpu.models import sharding as shard_rules
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.obs import metrics, tracing
+from realhf_tpu.parallel import param_stream
 
 logger = logging.getLogger("param_realloc", "benchmark")
 
@@ -51,28 +53,44 @@ def _ema_lerp(src, dst, eta):
         src, dst)
 
 
+def tree_bytes(params: Any) -> int:
+    """Logical bytes of a tree: every leaf's global shape times its
+    item size. No device query; not the bytes that land on each chip
+    (a replicated leaf lands once a chip)."""
+    return sum(param_stream.leaf_nbytes(x) for x in jax.tree.leaves(params))
+
+
 def reallocate(
     cfg: TransformerConfig,
     src_params: Any,
     dst_engine,
     eta: float = 1.0,
+    role: str = "",
 ) -> float:
     """Move (or EMA-merge) src weights onto dst_engine's mesh.
 
     Returns the wall-clock seconds of the resharding transfer (the
-    north-star reshard-latency metric).
+    north-star reshard-latency metric). The caller's span (``realloc``)
+    gets the bytes moved; ``realloc_bytes_total{role}`` counts them.
     """
     t0 = time.monotonic()
-    params = _repad_for_target(cfg, src_params, dst_engine.ctx.tp_size)
-    # may_alias=False: a leaf whose layout is the same on both meshes
-    # (the replicated norm scales) would otherwise BE the source's
-    # buffer, and the next train step donates that buffer away from
-    # under the replica.
-    moved = jax.device_put(params, dst_engine._param_shardings,
-                           may_alias=False)
+    with tracing.span("realloc:repad"):
+        params = _repad_for_target(cfg, src_params,
+                                   dst_engine.ctx.tp_size)
+    nbytes = tree_bytes(params)
+    metrics.inc("realloc_bytes_total", nbytes, role=role)
+    tracing.current_span().set_attribute("bytes", nbytes)
+    with tracing.span("realloc:put", bytes=nbytes) as sp:
+        # may_alias=False: a leaf whose layout is the same on both
+        # meshes (the replicated norm scales) would otherwise BE the
+        # source's buffer, and the next train step donates that buffer
+        # away from under the replica.
+        moved = sp.result(jax.device_put(
+            params, dst_engine._param_shardings, may_alias=False))
     if eta != 1.0:
-        moved = _ema_lerp(moved, dst_engine.params,
-                          jnp.asarray(eta, jnp.float32))
+        with tracing.span("realloc:ema", eta=eta) as sp:
+            moved = sp.result(_ema_lerp(
+                moved, dst_engine.params, jnp.asarray(eta, jnp.float32)))
     jax.block_until_ready(moved)
     dt = time.monotonic() - t0
     dst_engine.set_params(moved, already_sharded=True)
@@ -88,8 +106,6 @@ def install_param_chunks(cfg: TransformerConfig, dst_engine, n_chunks: int,
     (layer-range, shard) step, comm/param_realloc.py:312).
 
     Returns (seconds, bytes_received)."""
-    from realhf_tpu.parallel import param_stream
-
     t0 = time.monotonic()
     tp = dst_engine.ctx.tp_size
     pdt = jnp.dtype(cfg.param_dtype)
@@ -157,9 +173,14 @@ class ReplicaManager:
         rid = id(replica_model)
         if synced.get(rid) == pv:
             return
-        dt = reallocate(primary_model.config,
-                        primary_model.engine.params,
-                        replica_model.engine, eta=eta)
+        with tracing.span(
+                "realloc", role=role,
+                src=str(primary_model.engine.ctx.parallel),
+                dst=str(replica_model.engine.ctx.parallel)) as sp:
+            dt = reallocate(primary_model.config,
+                            primary_model.engine.params,
+                            replica_model.engine, eta=eta, role=role)
+            sp.result(replica_model.engine.params)
         self.last_reshard_secs = dt
         synced[rid] = pv
         logger.info(

@@ -11,17 +11,19 @@ hooks around each call (reference ``model_worker.handle_all_pre_hooks``
 
 import dataclasses as _dc
 import os
+import time
 from typing import Dict, List, Optional
 
 from realhf_tpu.api import data as data_api
 from realhf_tpu.api import model as model_api
 from realhf_tpu.api.config import ModelInterfaceType, ModelName
 from realhf_tpu.api.dfg import MFCDef, OffloadHook, ParamReallocHook
-from realhf_tpu.base import constants, logging, seeding
+from realhf_tpu.base import constants, logging, monitor, seeding
 from realhf_tpu.engine.engine import Engine
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.models.hf import load_hf_checkpoint
+from realhf_tpu.obs import tracing
 from realhf_tpu.parallel.mesh import MeshContext, make_mesh
 from realhf_tpu.parallel.realloc import ReplicaManager
 
@@ -471,7 +473,8 @@ class ModelHost:
         from realhf_tpu.parallel.realloc import reallocate
         model = self.replicas[node_name]
         model.engine.ensure_on_device()
-        dt = reallocate(model.config, host_params, model.engine, eta=eta)
+        dt = reallocate(model.config, host_params, model.engine, eta=eta,
+                        role=self.nodes[node_name].role)
         self.replica_mgr.last_reshard_secs = dt
         self.node_param_version[node_name] = version
         logger.info("Installed params v%d on %s in %.3fs.", version,
@@ -516,8 +519,13 @@ class ModelHost:
         calls serialize on the role's lock (shared Engine); cross-role
         calls run concurrently (execute_level)."""
         node = self.nodes[node_name]
-        with self._role_lock(node.role):
-            return self._execute_locked(node_name, node, inp)
+        with tracing.span(f"mfc:{node_name}", mfc=node_name,
+                          role=node.role,
+                          kind=node.interface_type.value) as sp:
+            t0 = time.monotonic()
+            with self._role_lock(node.role):
+                sp.set_attribute("waited_s", time.monotonic() - t0)
+                return self._execute_locked(node_name, node, inp)
 
     def _execute_locked(self, node_name: str, node: MFCDef,
                         inp: data_api.SequenceSample):
@@ -545,17 +553,15 @@ class ModelHost:
             inp.remap_keys_(node.input_key_remap)
 
         itf = self.interfaces[node_name]
-        import time as _time
-
-        from realhf_tpu.base import monitor
-        from realhf_tpu.obs import tracing
-        t_start = _time.time()
-        # host-side span around the interface call (nests under the
-        # worker's mfc:* request span in the merged timeline); the
-        # TraceAnnotation inside mfc_profile_region covers the XLA view
-        with tracing.span(f"compute:{node_name}", mfc=node_name,
-                          role=node.role,
-                          kind=node.interface_type.value):
+        # The one clock pair of an MFC: the span's own clock, read
+        # around it so that exec_infos has it with tracing off too. In
+        # a synced stretch the span ends when what the interface
+        # returned and the role's weights are ready.
+        t_start = time.monotonic()
+        with tracing.span(f"compute:{node_name}", mfc=node_name) as sp:
+            if tracing.enabled():
+                sp.set_attribute("tokens_in", max(
+                    inp.total_len(k) for k in inp.keys))
             with monitor.mfc_profile_region(node_name):
                 if node.interface_type == ModelInterfaceType.GENERATE:
                     out = itf.generate(model, inp, n_mbs=node.n_mbs)
@@ -565,7 +571,8 @@ class ModelHost:
                     out = itf.train_step(model, inp, n_mbs=node.n_mbs)
                 else:
                     raise NotImplementedError(node.interface_type)
-        t_end = _time.time()
+            sp.result((getattr(out, "data", out), model.engine.params))
+        t_end = time.monotonic()
         # Per-MFC device stats (reference __log_gpu_stats,
         # model_worker.py:999-1094): wall span + HBM over this
         # process's mesh devices. JAX exposes no per-region peak
@@ -601,7 +608,8 @@ class ModelHost:
         # self.last_exec_info back to fill exec_infos would let a
         # concurrent execute_level thread clobber it in between and
         # attribute the wrong node's secs/HBM to this node.
-        info = dict(node=node_name, start=t_start, end=t_end,
+        info = dict(node=node_name, start=tracing.to_epoch(t_start),
+                    end=tracing.to_epoch(t_end),
                     secs=round(t_end - t_start, 4),
                     hbm_bytes_in_use=int(now),
                     proc_peak_hbm_bytes=int(peak))
@@ -663,15 +671,13 @@ class ModelHost:
             return [self.execute(n, i) for n, i in named_inputs]
         from concurrent.futures import ThreadPoolExecutor
 
-        from realhf_tpu.obs import tracing
-
         # pool threads have their own (empty) span stacks, so the
-        # caller's context is captured here and re-attached per MFC --
-        # the level's spans stay nested under the step span
+        # caller's context is captured here and attached per thread --
+        # each MFC's own mfc:* span stays nested under the step span
         ctx = tracing.current_context()
 
         def run_one(n, i):
-            with tracing.span(f"mfc:{n}", parent=ctx, mfc=n):
+            with tracing.attach(ctx):
                 return self.execute(n, i)
 
         with ThreadPoolExecutor(max_workers=len(named_inputs)) as ex:

@@ -24,7 +24,7 @@ Usage::
 
 Output: one JSON line per setup -- the overrides, end-to-end step
 seconds, and per-MFC wall-clock totals from the runtime's
-mfc_profile_region spans -- plus a ranked table on stdout.
+mfc:* spans -- plus a ranked table on stdout.
 """
 
 import argparse
@@ -34,7 +34,7 @@ import time
 
 
 def run_setup(base_overrides, line_overrides, index):
-    from realhf_tpu.base import monitor, name_resolve
+    from realhf_tpu.base import name_resolve
     from realhf_tpu.experiments.common import apply_overrides
     from realhf_tpu.experiments.profile_exp import (
         ProfileConfig,
@@ -50,14 +50,12 @@ def run_setup(base_overrides, line_overrides, index):
     apply_overrides(cfg, merged)
     spec = cfg.build()
 
-    monitor.tmark_db().clear()
     runner = InlineRunner(spec)
     t0 = time.monotonic()
-    runner.run()
+    _, mfc_secs = mfc_timing_summary(runner.run)
     wall = time.monotonic() - t0
     steps = max(spec.ctl.benchmark_steps or 1, 1)
-    mfc = {k.removeprefix("mfc/"): round(v / steps, 4)
-           for k, v in mfc_timing_summary().items()}
+    mfc = {k: round(v / steps, 4) for k, v in mfc_secs.items()}
     return dict(setup=line_overrides, step_secs=round(wall / steps, 4),
                 mfc_secs=mfc, benchmark_steps=steps)
 

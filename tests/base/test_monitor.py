@@ -12,12 +12,24 @@ import jax.numpy as jnp
 from realhf_tpu.base import constants, monitor
 
 
-def test_mfc_profile_region_records_span():
-    monitor.tmark_db().clear()
-    with monitor.mfc_profile_region("actor_gen"):
-        jnp.sum(jnp.ones((64, 64))).block_until_ready()
-    s = monitor.tmark_db().summary()
-    assert "mfc/actor_gen" in s and s["mfc/actor_gen"] > 0
+def test_mfc_is_timed_by_its_span_and_the_region_adds_none():
+    """One clock an MFC: the ``compute:<name>`` span around
+    ``mfc_profile_region`` times it; the region records nothing."""
+    from realhf_tpu.obs import tracing
+    tracing.reset_default()
+    try:
+        tracing.start()
+        with tracing.span("compute:actor_gen", mfc="actor_gen"):
+            with monitor.mfc_profile_region("actor_gen"):
+                jnp.sum(jnp.ones((64, 64))).block_until_ready()
+        capture = tracing.stop()
+    finally:
+        tracing.reset_default()
+    [span] = capture.spans
+    assert span["name"] == "compute:actor_gen"
+    assert span["end"] > span["start"]
+    # the module keeps no marks of its own beside the span
+    assert not [n for n in dir(monitor) if "mark" in n.lower()]
 
 
 def test_trace_dump(monkeypatch, tmp_path):

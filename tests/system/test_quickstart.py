@@ -272,14 +272,12 @@ def test_profile_mode_end_to_end():
     """Profile/mock mode (reference profile_exp.py:61): the 6-MFC PPO
     graph runs on fully synthetic data (random models + random
     prompts) through the real runtime, recording per-MFC timings."""
-    from realhf_tpu.base import monitor
     from realhf_tpu.experiments.profile_exp import (
         ProfileConfig,
         mfc_timing_summary,
     )
     from realhf_tpu.system.inline import InlineRunner
 
-    monitor.tmark_db().clear()
     cfg = ProfileConfig(experiment_name="proftest", trial_name="t0",
                         benchmark_steps=1)
     apply_overrides(cfg, {
@@ -302,11 +300,10 @@ def test_profile_mode_end_to_end():
         mspec.parallel = ParallelismConfig(data_parallel_size=2,
                                            tensor_parallel_size=4)
     runner = InlineRunner(spec)
-    stats = runner.run()
+    stats, timings = mfc_timing_summary(runner.run)
     assert np.isfinite(stats["actor_train"]["actor_loss"])
-    timings = mfc_timing_summary()
-    # every MFC of the graph was timed by the profiler spans
-    assert {f"mfc/{n.name}" for n in spec.mfcs} <= set(timings)
+    # every MFC of the graph was timed by its mfc:* span
+    assert {n.name for n in spec.mfcs} == set(timings)
     assert all(v > 0 for v in timings.values())
 
 
