@@ -85,8 +85,8 @@ EPOCH_OFFSET = time.time() - time.monotonic()
 KEPT_CAPTURES = 8
 
 #: counters whose deltas a capture reports (docs/observability.md)
-CAPTURE_COUNTERS = ("realloc_bytes_total", "engine_compiles_total",
-                    "engine_compile_secs_total")
+CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
+                    "engine_compiles_total", "engine_compile_secs_total")
 
 
 def to_epoch(monotonic_secs: float) -> float:

@@ -6,10 +6,31 @@ TPU-native replacement for the reference's signature feature
 pair is sliced out of a flat buffer and NCCL-broadcast between groups.
 Here a model's weights are one sharded pytree, and moving them between
 two `jax.sharding.Mesh`es -- different dp/tp degrees, overlapping or
-disjoint device sets -- is a single `jax.device_put` onto the target
-shardings: XLA computes the minimal device-to-device transfer plan
-(the interval arithmetic the reference implements by hand in
-``param_intervals_from_keys``, flatten_param.py:301).
+disjoint device sets -- is one call, on one of two paths that
+`reallocate` chooses from what the source tree shows:
+
+- **device**: every leaf is a committed `jax.Array` in device memory
+  on the target mesh's devices, listed in the same order (a role's
+  d2t2 primary and its d4t1 replica, both from `mesh.make_mesh`). Each
+  leaf goes through a compiled program, the identity with
+  ``out_shardings`` = the leaf's target sharding, so the SPMD
+  partitioner emits the all-gathers and slices over the interconnect
+  (the interval arithmetic the reference implements by hand in
+  ``param_intervals_from_keys``, flatten_param.py:301).
+- **host**: anything else (numpy trees, another device set or order,
+  offloaded sources) is `jax.device_put` onto the target shardings.
+
+Why not `device_put` throughout (jax 0.9.0): in
+``jax/_src/dispatch.py:_device_put_sharding_impl`` the only on-device
+branch for fully addressable arrays (``_different_device_order_reshard``,
+itself a jitted identity) is taken when the device ORDER differs. With
+the same devices in the same order the call falls to
+``_DeferredShardArg``, and ``jax/_src/array.py:_array_shard_arg`` sends
+every leaf whose shard indices change to
+``shard_sharded_device_array_slow_path``: the whole array to numpy on
+the host, sliced there and put again, one leaf after another (2.28 GB
+from d2t2 to d4t1 on four v5e chips: 4.7 s there, 0.034 s on the
+device path; PERF.md, PR 25).
 
 EMA reallocation (``target = eta*src + (1-eta)*target``, reference
 ``patch_reparallelization``, real_llm_api.py:762) runs as a jitted
@@ -20,6 +41,7 @@ different tp degrees carry different Megatron-style vocab padding,
 so wte/head are unpadded/repadded in transit.
 """
 
+import functools
 import time
 from typing import Any, Dict, Optional
 
@@ -53,6 +75,55 @@ def _ema_lerp(src, dst, eta):
         src, dst)
 
 
+def _devices_in_order(sharding) -> Optional[tuple]:
+    """The devices of a mesh sharding as a jitted program lists them;
+    None for a sharding without a mesh."""
+    mesh = getattr(sharding, "mesh", None)
+    return None if mesh is None else tuple(mesh.devices.flat)
+
+
+def _on_target_devices(params: Any, shardings: Any) -> bool:
+    """Whether every leaf is a committed `jax.Array` in the memory and
+    on the devices of its target sharding, in the same order: what a
+    `jax.jit` asks of its arguments, and where `jax.device_put` goes
+    through the host (module docstring)."""
+    def placed(x, target):
+        if not (isinstance(x, jax.Array) and x.committed):
+            return False
+        devices = _devices_in_order(x.sharding)
+        return (devices is not None
+                and devices == _devices_in_order(target)
+                and x.sharding.memory_kind == target.memory_kind)
+
+    leaves, targets = jax.tree.leaves(params), jax.tree.leaves(shardings)
+    return len(leaves) == len(targets) and all(map(placed, leaves, targets))
+
+
+def _identity(x):
+    return x
+
+
+@functools.lru_cache(maxsize=256)
+def _reshard_program(sharding):
+    """The jitted identity onto one leaf's target sharding, held as
+    long as the layout is in use: a `jax.jit` made anew on every call
+    would lower anew on every step. The leaf's shape and source
+    sharding are part of jit's own key. Nothing is donated, and
+    explicit ``out_shardings`` switch off jit's forwarding of inputs,
+    so the output is a fresh buffer (`reallocate`).
+
+    One program a leaf, not one for the tree: the partitioner gathers
+    into a temporary and copies that into the output, so a program's
+    temporaries are as large as its outputs (compiled for a v5e 2x2:
+    2.28 GB beside 2.28 GB of outputs for four layers of Mistral-7B),
+    and leaf by leaf they are one leaf's, at no cost in time (0.032
+    against 0.034 s on four v5e chips; PERF.md, PR 25). Each program
+    also holds ONE collective: XLA:CPU executables loaded from the
+    persistent cache deadlock on two independent ones
+    (tests/conftest.py)."""
+    return jax.jit(_identity, out_shardings=sharding)
+
+
 def tree_bytes(params: Any) -> int:
     """Logical bytes of a tree: every leaf's global shape times its
     item size. No device query; not the bytes that land on each chip
@@ -71,22 +142,33 @@ def reallocate(
 
     Returns the wall-clock seconds of the resharding transfer (the
     north-star reshard-latency metric). The caller's span (``realloc``)
-    gets the bytes moved; ``realloc_bytes_total{role}`` counts them.
+    gets the bytes moved; ``realloc_bytes_total{role}`` counts them,
+    ``realloc_puts_total{role,path}`` the path taken (module
+    docstring).
     """
     t0 = time.monotonic()
     with tracing.span("realloc:repad"):
         params = _repad_for_target(cfg, src_params,
                                    dst_engine.ctx.tp_size)
     nbytes = tree_bytes(params)
+    shardings = dst_engine._param_shardings
+    on_device = _on_target_devices(params, shardings)
+    path = "device" if on_device else "host"
     metrics.inc("realloc_bytes_total", nbytes, role=role)
+    metrics.inc("realloc_puts_total", role=role, path=path)
     tracing.current_span().set_attribute("bytes", nbytes)
-    with tracing.span("realloc:put", bytes=nbytes) as sp:
-        # may_alias=False: a leaf whose layout is the same on both
-        # meshes (the replicated norm scales) would otherwise BE the
-        # source's buffer, and the next train step donates that buffer
-        # away from under the replica.
-        moved = sp.result(jax.device_put(
-            params, dst_engine._param_shardings, may_alias=False))
+    with tracing.span("realloc:put", bytes=nbytes, path=path) as sp:
+        # Fresh buffers on both paths (may_alias=False; an undonated
+        # input of a program is never an output): a leaf whose layout
+        # is the same on both meshes (the replicated norm scales)
+        # would otherwise BE the source's buffer, and the next train
+        # step donates that buffer away from under the replica.
+        if on_device:
+            moved = jax.tree.map(lambda x, s: _reshard_program(s)(x),
+                                 params, shardings)
+        else:
+            moved = jax.device_put(params, shardings, may_alias=False)
+        sp.result(moved)
     if eta != 1.0:
         with tracing.span("realloc:ema", eta=eta) as sp:
             moved = sp.result(_ema_lerp(
