@@ -37,6 +37,7 @@ from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.obs import metrics, tracing
 from realhf_tpu.ops import functional as F
+from realhf_tpu.ops import moe as moe_ops
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
@@ -126,8 +127,7 @@ class Engine:
         # clear message instead of after a full-model transfer.
         self.moe_constraint = shard_rules.moe_ep_constraint(cfg, self.mesh)
         if self.moe_constraint is not None:
-            from realhf_tpu.ops.moe import ragged_dispatch_enabled as _rde
-            if _rde(cfg):
+            if moe_ops.dispatch_mode(cfg) == "ragged":
                 raise ValueError(
                     "MoEConfig.expert_parallel requires the capacity "
                     "or dense dispatch mode (set capacity_factor, or "
@@ -214,11 +214,13 @@ class Engine:
         else:
             self.attention_fn = None
 
-        from realhf_tpu.ops.moe import ragged_dispatch_enabled
-        if (cfg.mlp_type == "moe" and cfg.moe is not None
-                and cfg.moe.capacity_factor is None
-                and cfg.moe.num_experts > 4
-                and not ragged_dispatch_enabled(cfg)):
+        # which dispatch a sparse model's programs take: on every
+        # engine:* span, and the label of moe_routed_pairs_total
+        mode = moe_ops.dispatch_mode(cfg)
+        self._moe_attrs: Dict[str, Any] = {} if mode is None else dict(
+            moe_dispatch=mode, experts=cfg.moe.num_experts,
+            top_k=cfg.moe.top_k)
+        if mode == "dense" and cfg.moe.num_experts > 4:
             logger.warning(
                 "MoE model running in dense dispatch (capacity_factor "
                 "unset, grouped GEMM disabled): every expert processes "
@@ -299,14 +301,45 @@ class Engine:
         self._last_call[name] = (fn, jax.tree.map(_abstract, args),
                                  static)
         if not tracing.enabled():
+            self._last_span = tracing.NOOP_SPAN
             return fn(*args, **static)
         # engine:<name> holds the dispatch and, in a synced stretch,
         # the wait for the outputs
-        with tracing.span(f"engine:{name}") as sp:
+        with tracing.span(f"engine:{name}", **self._moe_attrs) as sp:
+            self._last_span = sp
             lowered = fn._cache_size()
             out = sp.result(fn(*args, **static))
             sp.set_attribute("compiled", fn._cache_size() > lowered)
             return out
+
+    def _count_routed_pairs(self, seg_ids, decode_tokens: int = 0):
+        """``moe_routed_pairs_total{role,dispatch}``: the (token,
+        expert) pairs the program about to run routes, counted on the
+        host from its batch: valid tokens (plus the tokens a decode
+        loop is asked for) x top_k x layers. A device array is not
+        read: all its positions count (pads are routed like tokens)."""
+        if not self._moe_attrs:
+            return
+        tokens = int(np.count_nonzero(seg_ids)) \
+            if isinstance(seg_ids, np.ndarray) else int(seg_ids.size)
+        metrics.inc("moe_routed_pairs_total",
+                    (tokens + decode_tokens) * self.cfg.moe.top_k
+                    * self.cfg.n_layers,
+                    role=str(self.ctx.model_name.role),
+                    dispatch=self._moe_attrs["moe_dispatch"])
+
+    def _report_moe_load(self, stats: Dict[str, Any]):
+        """The train step's load statistic (``ops.moe.LOAD_STAT``: the
+        worst layer of the worst microbatch) as gauge
+        ``moe_load_max_over_mean{role}`` and as an attribute of the
+        ``engine:train*`` span that has just ended."""
+        load = stats.get(moe_ops.LOAD_STAT)
+        if load is None:
+            return
+        load = float(np.max(load))
+        metrics.set_gauge("moe_load_max_over_mean", load,
+                          role=str(self.ctx.model_name.role))
+        self._last_span.set_attribute(moe_ops.LOAD_STAT, load)
 
     def compiled_text(self, name: str) -> str:
         """Optimized HLO of the program last run under ``name``
@@ -440,6 +473,9 @@ class Engine:
             gnorm = optax.global_norm(gsum)
             mean_stats = jax.tree.map(
                 lambda s: (s * mb_weights / wsum).sum(), stats)
+            if moe_ops.LOAD_STAT in stats:  # the worst microbatch's
+                mean_stats[moe_ops.LOAD_STAT] = \
+                    stats[moe_ops.LOAD_STAT].max()
             # Reserved stat "__skip_update__": when any microbatch sets
             # it > 0, the whole optimizer step is discarded -- params,
             # optimizer moments, and step count stay untouched (PPO
@@ -543,6 +579,7 @@ class Engine:
         stacked, weights = self._globalize_tree(
             (host_batch, np.asarray(loss_weights, np.float32)))
 
+        self._count_routed_pairs(host_batch["seg_ids"])
         self.params, self.opt_state, loss, stats, gnorm = self._run(
             "train", step, self.params, self.opt_state, stacked, weights)
         self.version += 1
@@ -561,6 +598,7 @@ class Engine:
         # ONE batched host fetch for all scalar stats: converting each
         # scalar with float() would issue a separate blocking D2H sync.
         loss, stats, gnorm = jax.device_get((loss, stats, gnorm))
+        self._report_moe_load(stats)
         out = {k: float(v) for k, v in stats.items()}
         out["loss"] = float(loss)
         out["grad_norm"] = float(gnorm)
@@ -604,6 +642,7 @@ class Engine:
         stacked, weights = self._globalize_tree(
             (host_batch, np.asarray(loss_weights, np.float32)))
 
+        self._count_routed_pairs(host_batch["seg_ids"])
         self.params, self.opt_state, losses, stats, gnorms = self._run(
             "train_seq", step, self.params, self.opt_state, stacked,
             weights)
@@ -617,6 +656,7 @@ class Engine:
             jax.block_until_ready(self.opt_state)
             self._opt_offloaded = True
         losses, stats, gnorms = jax.device_get((losses, stats, gnorms))
+        self._report_moe_load(stats)
         out = []
         for i in range(len(minibatches)):
             d = {k: float(v[i]) for k, v in stats.items()}
@@ -639,6 +679,7 @@ class Engine:
                 return h
             self._jit_forward_hidden = jax.jit(
                 hidden, out_shardings=self._out_replicated())
+        self._count_routed_pairs(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
         return self._run("hidden", self._jit_forward_hidden,
                          self.params, ids, seg)
@@ -660,6 +701,7 @@ class Engine:
             self._jit_logprobs = jax.jit(
                 logprobs, static_argnames=("temp", "has_mask"),
                 out_shardings=self._out_replicated())
+        self._count_routed_pairs(seg_ids)
         ids, seg, mask = self._globalize_tree(
             (input_ids, seg_ids,
              logits_mask if logits_mask is not None
@@ -681,6 +723,7 @@ class Engine:
                 return T.critic_values(self.cfg, params, h)
             self._jit_values = jax.jit(
                 values, out_shardings=self._out_replicated())
+        self._count_routed_pairs(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
         return self._run("values", self._jit_values, self.params, ids,
                          seg)
@@ -805,6 +848,9 @@ class Engine:
                 out_sharding=self._out_replicated(),
                 mesh=self.mesh, attention_fn=self.attention_fn)
         fn = self._generate_cache[cache_key]
+        self._count_routed_pairs(
+            prompt_seg,
+            decode_tokens=prompt_seg.shape[0] * gconfig.max_new_tokens)
         ids, seg, pos, key = self._globalize_tree(
             (prompt_ids, prompt_seg, prompt_pos, key))
         return self._run("generate", fn, self.params, ids, seg, pos, key)

@@ -15,6 +15,7 @@ import numpy as np
 from realhf_tpu.api.data import SequenceSample
 from realhf_tpu.base.datapack import flat2d
 from realhf_tpu.engine import packing
+from realhf_tpu.ops.moe import aux_loss  # noqa: F401  (for the loss fns)
 
 
 def seqlens_of(input_: SequenceSample, key: str = "packed_input_ids") -> List[int]:
@@ -74,13 +75,15 @@ def split_minibatches(input_: SequenceSample, n: int,
 
 def forward_with_aux(cfg, params, input_ids, seg_ids, attention_fn=None,
                      pipeline=None, moe_constraint=None):
-    """Model forward returning (hidden, aux-loss dict). For MoE models
+    """Model forward returning (hidden, aux dict). For MoE models
     the dict carries router load-balancing/z losses that MUST be added
-    to the training objective (the reference applies them automatically
-    via MoEAuxLossAutoScaler, utils/moe.py:395); dense models return
-    an empty dict. ``pipeline`` is the engine's PipelineContext when
-    the model mesh is pipeline-parallel; ``moe_constraint`` is the
-    engine's expert-parallel sharding hook."""
+    to the training objective through ``aux_loss(aux)`` (the reference
+    applies them automatically via MoEAuxLossAutoScaler,
+    utils/moe.py:395), and the load statistic, which goes into the
+    step's statistics with them (``**aux``) and never into the loss;
+    dense models return an empty dict. ``pipeline`` is the engine's
+    PipelineContext when the model mesh is pipeline-parallel;
+    ``moe_constraint`` is the engine's expert-parallel sharding hook."""
     from realhf_tpu.models import transformer as _T
     if cfg.mlp_type == "moe":
         h, _, aux = _T.forward(cfg, params, input_ids, seg_ids,

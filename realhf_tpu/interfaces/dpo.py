@@ -60,7 +60,7 @@ def _make_loss_fn(cfg, n_seqs: int, beta: float, attention_fn=None,
         pos_score = (beta * (pi_pos - ref_pos) * valid).sum() / denom
         neg_score = (beta * (pi_neg - ref_neg) * valid).sum() / denom
         kl = (-(pi_pos - ref_pos + pi_neg - ref_neg) * valid).sum() / denom
-        return loss + sum(aux.values()), {
+        return loss + common.aux_loss(aux), {
             "loss": loss, "pos_score": pos_score,
             "neg_score": neg_score, "kl": kl, **aux}
 

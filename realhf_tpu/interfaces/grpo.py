@@ -218,7 +218,7 @@ class GRPOInterface(PPOActorInterface):
             diff = mb["ref_logp"] - lp
             kl = (jnp.where(m > 0, jnp.exp(diff) - diff - 1.0, 0.0)).sum() \
                 / jnp.maximum(m.sum(), 1.0)
-            total = loss + kl_coef * kl + sum(aux.values())
+            total = loss + kl_coef * kl + common.aux_loss(aux)
             return total, dict(
                 grpo_loss=loss, grpo_kl=kl,
                 importance_weight=stats["importance_weight"],

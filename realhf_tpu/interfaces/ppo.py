@@ -418,7 +418,7 @@ class PPOActorInterface(model_api.ModelInterface):
                 **stale_stats, **aux)
             if early_imp is not None or early_kl is not None:
                 out_stats["__skip_update__"] = skip
-            return loss + sum(aux.values()), out_stats
+            return loss + common.aux_loss(aux), out_stats
 
         loss_key = ("ppo_actor", has_mask, temperature, eps_clip,
                     early_kl, early_imp, has_stale, is_clip)
@@ -610,7 +610,7 @@ class PPOCriticInterface(model_api.ModelInterface):
                 value=new_values, old_value=mb["old_values"],
                 target_value=mb["returns"], value_eps_clip=eps,
                 loss_mask=mb["loss_mask"] > 0)
-            return loss + sum(aux.values()), dict(
+            return loss + common.aux_loss(aux), dict(
                 value_loss=loss,
                 value_clip_ratio=stats["value_clip_ratio"], **aux)
 

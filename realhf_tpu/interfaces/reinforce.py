@@ -176,7 +176,7 @@ class ReinforceInterface(PPOActorInterface):
             m = mb["loss_mask"]
             denom = jnp.maximum(m.sum(), 1.0)
             pg = -(mb["advantages"] * lp * m).sum() / denom
-            total = pg + sum(aux.values())
+            total = pg + common.aux_loss(aux)
             stats = dict(reinforce_loss=pg, **aux)
             if has_ref:
                 diff = mb["ref_logp"] - lp

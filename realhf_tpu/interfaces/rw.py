@@ -43,7 +43,7 @@ def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
         losses = -jax.nn.log_sigmoid(pos - neg)
         loss = (losses * valid).sum() / denom
         acc = ((pos > neg) & (valid > 0)).sum() / denom
-        return loss + sum(aux.values()), {
+        return loss + common.aux_loss(aux), {
             "loss": loss,
             "acc": acc.astype(jnp.float32),
             "pos_score": (pos * valid).sum() / denom,

@@ -43,7 +43,7 @@ def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
         mask = next_same & ~next_is_prompt
         denom = jnp.maximum(mask.sum(), 1)
         nll = -(lp * mask).sum() / denom
-        loss = nll + sum(aux.values())
+        loss = nll + common.aux_loss(aux)
         return loss, {"nll": nll, "n_tokens": denom.astype(jnp.float32),
                       **aux}
 

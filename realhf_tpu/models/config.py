@@ -2,7 +2,7 @@
 
 Field-level parity with reference ``realhf/api/core/model_api.py:144``
 (ReaLModelConfig): one config class describes every supported family
-(llama/qwen2/mistral/gpt2/gemma/mixtral, actor or critic). The critic
+(llama/qwen2/mistral/gpt2/gemma/mixtral/olmoe, actor or critic). The critic
 variant replaces the LM head with a scalar value head (`is_critic`).
 """
 
@@ -16,6 +16,10 @@ class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
     routing_type: str = "aux_loss"  # aux_loss | sinkhorn | none
+    # Divide the top-k softmax gates by their sum (Mixtral). False
+    # takes them as the softmax over ALL experts gave them, so a
+    # token's gates sum to less than 1 (OLMoE, ``norm_topk_prob``).
+    norm_topk_prob: bool = True
     aux_loss_coeff: float = 1e-3
     z_loss_coeff: float = 0.0
     input_jitter_eps: Optional[float] = None
@@ -75,6 +79,11 @@ class TransformerConfig:
     do_layernorm_before: bool = True
     tied_embedding: bool = False
     sliding_window: Optional[int] = None
+    # RMSNorm of the query and key projections, each with a scale of
+    # its own. "full": over the WHOLE projected width (all heads
+    # together), before the split into heads and before the rotary
+    # embedding (OLMoE).
+    qk_norm: Optional[str] = None
     moe: Optional[MoEConfig] = None
     is_critic: bool = False
 
@@ -105,6 +114,8 @@ class TransformerConfig:
             (self.n_q_heads, self.n_kv_heads)
         if self.mlp_type == "moe":
             assert self.moe is not None
+        if self.qk_norm not in (None, "full"):
+            raise NotImplementedError(f"qk_norm={self.qk_norm!r}")
         if self.rotary_scaling_type is not None:
             if self.rotary_scaling is None:
                 raise ValueError(
@@ -122,13 +133,17 @@ class TransformerConfig:
         return self.mlp_type in ("llama", "moe")
 
     def n_params(self) -> int:
-        """Approximate dense parameter count (for FLOPs/memory estimates)."""
+        """Approximate parameter count (for FLOPs/memory estimates):
+        every matrix, the router and the query/key norms; biases and
+        the layer norms' scales are left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
         attn = h * (self.n_q_heads + 2 * self.n_kv_heads) * self.head_dim \
             + self.n_q_heads * self.head_dim * h
+        if self.qk_norm is not None:
+            attn += (self.n_q_heads + self.n_kv_heads) * self.head_dim
         mlp = (3 if self.gated_mlp else 2) * h * f
         if self.mlp_type == "moe":
-            mlp *= self.moe.num_experts
+            mlp = mlp * self.moe.num_experts + h * self.moe.num_experts
         embed = v * h if self.tied_embedding else 2 * v * h
         if self.is_critic:
             embed = v * h + h

@@ -615,3 +615,34 @@ def unstack_layers(arr: np.ndarray, pattern: str, out: StateDict,
     for i in range(arr.shape[0]):
         w = arr[i]
         out[pattern.format(i)] = np.ascontiguousarray(w.T if transpose else w)
+
+
+def moe_mlp_from_hf(state: StateDict, cfg: TransformerConfig, prefix: str,
+                    expert_weights) -> Dict[str, np.ndarray]:
+    """The ``blocks.mlp`` leaves of a sparse family: the router
+    ``<prefix>gate.weight`` as [nl, H, E] and, for each (leaf, HF name)
+    of ``expert_weights``, the per-expert HF Linear weights
+    ``<prefix>experts.<e>.<HF name>.weight`` (each (out, in)) stacked
+    into [nl, E, in, out]. ``prefix`` holds ``{}`` for the layer."""
+    nl, ne = cfg.n_layers, cfg.moe.num_experts
+    mlp = {"router": stack_layers(state, prefix + "gate.weight", nl,
+                                  transpose=True)}
+    for leaf, hf_w in expert_weights:
+        mlp[leaf] = np.stack([
+            np.stack([state[f"{prefix.format(i)}experts.{e}.{hf_w}.weight"].T
+                      for e in range(ne)], axis=0)
+            for i in range(nl)], axis=0)
+    return mlp
+
+
+def moe_mlp_to_hf(mlp: Dict[str, np.ndarray], prefix: str, expert_weights,
+                  out: StateDict):
+    """Inverse of :func:`moe_mlp_from_hf`."""
+    unstack_layers(mlp["router"], prefix + "gate.weight", out,
+                   transpose=True)
+    for leaf, hf_w in expert_weights:
+        arr = mlp[leaf]
+        for i in range(arr.shape[0]):
+            for e in range(arr.shape[1]):
+                out[f"{prefix.format(i)}experts.{e}.{hf_w}.weight"] = \
+                    np.ascontiguousarray(arr[i, e].T)

@@ -78,6 +78,10 @@ def param_pspecs(cfg: TransformerConfig,
         a["bq"], a["bk"], a["bv"] = col_b, col_b, col_b
     if cfg.use_attn_proj_bias:
         specs["blocks"]["attn"]["bo"] = rep2
+    if cfg.qk_norm is not None:
+        # scales of the column-parallel projections' outputs
+        specs["blocks"]["attn"]["q_norm"] = col_b
+        specs["blocks"]["attn"]["k_norm"] = col_b
     if cfg.use_mlp_bias and cfg.mlp_type is None:
         mlp["bu"] = col_b
         mlp["bd"] = rep2

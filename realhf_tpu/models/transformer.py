@@ -103,6 +103,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                                      zeros((nl, nkv * hd)))
     if cfg.use_attn_proj_bias:
         params["blocks"]["attn"]["bo"] = zeros((nl, h))
+    if cfg.qk_norm is not None:
+        params["blocks"]["attn"]["q_norm"] = ones((nl, nq * hd))
+        params["blocks"]["attn"]["k_norm"] = ones((nl, nkv * hd))
     if cfg.use_mlp_bias and cfg.mlp_type is None:
         mlp["bu"] = zeros((nl, f))
         mlp["bd"] = zeros((nl, h))
@@ -164,8 +167,9 @@ def _mlp(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
 def _mlp_with_aux(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
                   seg_ids: Optional[jnp.ndarray] = None,
                   moe_constraint=None):
-    """MLP returning (output, aux-loss dict) -- non-empty only for MoE
-    (router load-balancing / z losses, reference utils/moe.py:395).
+    """MLP returning (output, aux dict) -- non-empty only for MoE
+    (router load-balancing / z losses, reference utils/moe.py:395,
+    and the load statistic ``ops.moe.LOAD_STAT``).
     ``seg_ids`` masks padding out of MoE routing/capacity/losses."""
     cdt = jnp.dtype(cfg.compute_dtype)
     m = lp["mlp"]
@@ -205,6 +209,12 @@ def _qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray):
         q = q + a["bq"].astype(cdt)
         k = k + a["bk"].astype(cdt)
         v = v + a["bv"].astype(cdt)
+    if cfg.qk_norm == "full":
+        # over the whole projected width, before the head split and the
+        # rotary embedding; under tensor parallelism that width is
+        # sharded and the partitioner reduces the mean of squares
+        q = _norm(cfg, q, a["q_norm"], None)
+        k = _norm(cfg, k, a["k_norm"], None)
     q = q.reshape(*lead, cfg.n_q_heads, cfg.head_dim)
     k = k.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
@@ -313,12 +323,16 @@ def forward(
             "KV-cache prefill on a pipeline-parallel mesh is not "
             "supported; allocate generation MFCs on a dp/tp layout "
             "(decoupled allocation).")
+        from realhf_tpu.ops.moe import LOAD_STAT
         from realhf_tpu.parallel.pipeline import pipeline_blocks
 
         def pblock(lp, layer_idx, carry, seg, cos_, sin_):
             y, _, aux = _block(cfg, lp, layer_idx, carry, seg, cos_,
                                sin_, constrain, attention_fn,
                                moe_constraint)
+            # the schedules add every aux entry up over ticks and
+            # stages: right for the losses, not for a maximum
+            aux.pop(LOAD_STAT, None)
             return y, aux
 
         # Nested remat for the 1F1B-class memory profile: each block
@@ -386,8 +400,8 @@ def forward(
                                   (params["blocks"], layer_ids))
     x = _norm(cfg, x, params["ln_f"]["scale"], params["ln_f"].get("bias"))
     if return_aux:
-        aux = {k: v.sum() for k, v in (auxs or {}).items()}
-        return x, kvs, aux
+        from realhf_tpu.ops.moe import reduce_layers
+        return x, kvs, reduce_layers(auxs or {})
     return x, kvs
 
 

@@ -86,7 +86,10 @@ KEPT_CAPTURES = 8
 
 #: counters whose deltas a capture reports (docs/observability.md)
 CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
-                    "engine_compiles_total", "engine_compile_secs_total")
+                    "engine_compiles_total", "engine_compile_secs_total",
+                    "moe_routed_pairs_total")
+#: gauges whose last values a capture reports
+CAPTURE_GAUGES = ("moe_load_max_over_mean",)
 
 
 def to_epoch(monotonic_secs: float) -> float:
@@ -215,14 +218,17 @@ class Capture:
     ``time.monotonic()``, ``span_id``, ``parent_id``, ``trace_id``,
     ``thread``, ``attributes``) in order of their start; ``counters``
     maps ``name{label=value,...}`` of every :data:`CAPTURE_COUNTERS`
-    series to its growth in between. With a file path configured the
-    spans already flushed to the file are not here as well."""
+    series to its growth in between, ``gauges`` every
+    :data:`CAPTURE_GAUGES` series to its value at ``stop``. With a
+    file path configured the spans already flushed to the file are not
+    here as well."""
     spans: List[Dict[str, Any]]
     counters: Dict[str, float]
     start: float
     end: float
     sync: Union[bool, Tuple[str, ...]] = False
     profile_dir: Optional[str] = None
+    gauges: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def named(self, prefix: str) -> List[Dict[str, Any]]:
         """Spans called ``prefix`` or ``prefix<something>`` where the
@@ -265,11 +271,11 @@ def _series(name: str, labels) -> str:
     return f"{name}{{{inner}}}" if inner else name
 
 
-def _counter_values() -> Dict[str, float]:
+def _metric_values(names=CAPTURE_COUNTERS) -> Dict[str, float]:
     from realhf_tpu.obs import metrics
     out = {}
     for name, m in metrics.snapshot().items():
-        if name in CAPTURE_COUNTERS:
+        if name in names:
             for labels, value in m["values"].items():
                 out[_series(name, json.loads(labels) if labels
                             else {})] = value
@@ -335,7 +341,7 @@ class Tracer:
         if self._started is not None:
             self.stop()
         self.drain()  # what an earlier configure(enabled=True) left
-        self._counters_at_start = _counter_values()
+        self._counters_at_start = _metric_values()
         self.sync = sync if isinstance(sync, bool) else tuple(sync)
         if profile_dir is not None:
             import jax
@@ -362,7 +368,7 @@ class Tracer:
             jax.profiler.stop_trace()
         spans = self.drain()
         self._write(spans)
-        now = _counter_values()
+        now = _metric_values()
         deltas = {k: v - self._counters_at_start.get(k, 0.0)
                   for k, v in now.items()}
         capture = Capture(
@@ -370,7 +376,8 @@ class Tracer:
                          key=lambda s: s["start"]),
             counters={k: v for k, v in deltas.items() if v},
             start=self._started, end=time.monotonic(), sync=self.sync,
-            profile_dir=profile_dir)
+            profile_dir=profile_dir,
+            gauges=_metric_values(CAPTURE_GAUGES))
         self._started, self.sync = None, False
         self._captures.append(capture)
         return capture

@@ -3,26 +3,33 @@
 TPU-native replacement for reference ``realhf/impl/model/modules/moe/``
 (TopKRouter router.py:24, MoETokenDispatcher token_dispatcher.py:17,
 GroupedMLP experts.py:98) and ``impl/model/utils/moe.py`` (aux losses
-:13-166). Instead of permute/unpermute + grouped GEMM, dispatch is
-expressed as dense one-hot einsums over a static expert-capacity axis
-(XLA-friendly static shapes); expert GEMMs are one batched einsum over
-the stacked [E, H, F] weights, which GSPMD shards over the "model"
-axis (TP-sharded experts, the reference's layout) and can shard over
-an expert axis for true EP.
+:13-166). Expert weights are stacked [E, H, F] / [E, F, H]; GSPMD
+shards them over the "model" axis (TP-sharded experts, the
+reference's layout) and can shard the expert axis for true EP.
 
-Three dispatch modes:
-- ``capacity_factor=None`` + ``use_grouped_gemm`` (default): RAGGED
-  mode -- (token, k) pairs sorted by expert feed
-  ``jax.lax.ragged_dot`` grouped GEMMs (the true grouped-GEMM
-  equivalent of reference experts.py:98 GroupedMLP, lowered to TPU
-  ragged matmuls). Exact (no token dropping), top-k cost only.
-- ``capacity_factor=None`` + ``use_grouped_gemm=False``: dense mode --
-  every expert sees every token, weighted by its gate (exact; E/topk
-  times the FLOPs; the correctness reference for tests).
-- ``capacity_factor=c``: capacity dispatch -- each expert processes at
-  most c * T * topk / E tokens; overflow tokens are dropped from that
-  expert (standard Switch/GShard semantics, reference
-  topk_softmax_with_capacity, utils/moe.py:310).
+Three dispatch modes (``dispatch_mode``):
+- ``ragged`` (``capacity_factor=None`` + ``use_grouped_gemm``, the
+  default): (token, k) pairs sorted by expert feed one
+  ``jax.lax.ragged_dot`` a projection, a grouped GEMM over the
+  stacked weights (the equivalent of reference experts.py:98
+  GroupedMLP; XLA:TPU lowers it to a grouped-matmul kernel of its
+  own, PERF.md), then a float32 scatter-add back to the tokens.
+  Exact (no token dropping), top-k cost only.
+- ``dense`` (``capacity_factor=None`` + ``use_grouped_gemm=False``):
+  every expert sees every token through one batched einsum a
+  projection, weighted by its gate (exact; E/topk times the FLOPs;
+  the correctness reference for tests).
+- ``capacity`` (``capacity_factor=c``): dispatch by dense one-hot
+  einsums over a static expert-capacity axis -- each expert processes
+  at most c * T * topk / E tokens through the batched einsums;
+  overflow tokens are dropped from that expert (standard Switch/GShard
+  semantics, reference topk_softmax_with_capacity, utils/moe.py:310).
+
+Beside the auxiliary LOSSES the layer returns one statistic,
+``LOAD_STAT``: the largest expert's load over the mean load. It is
+never added to a loss (``aux_loss``), is reduced by max, not by sum,
+over layers and microbatches, and comes back with the train step's
+statistics.
 """
 
 from typing import Dict, Optional, Tuple
@@ -33,14 +40,36 @@ import jax.numpy as jnp
 from realhf_tpu.models.config import MoEConfig, TransformerConfig
 
 
+#: key, in the layer's auxiliary dict, of the one entry that is a
+#: statistic and not a loss: max over experts of the (token, k) pairs
+#: an expert received over the mean (T * k / E), pads included (they
+#: are computed like any token)
+LOAD_STAT = "moe_load_max_over_mean"
+
+
+def aux_loss(aux: Dict[str, jnp.ndarray]):
+    """What a training objective adds of a forward's auxiliary dict:
+    the losses, not the statistic."""
+    return sum(v for k, v in aux.items() if k != LOAD_STAT)
+
+
+def reduce_layers(auxs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Per-layer auxiliary entries [n_layers] -> scalars: losses add
+    up, the statistic is the worst layer's."""
+    return {k: v.max() if k == LOAD_STAT else v.sum()
+            for k, v in auxs.items()}
+
+
 def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
                  key: Optional[jax.Array] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """[T, E] logits -> (top-k probs [T, k], indices [T, k]).
 
     Default (aux_loss/none): softmax over all experts, take top-k,
-    renormalize (Mixtral semantics, equivalent to the reference's
-    topk_softmax_with_capacity). Sinkhorn routing selects indices from
+    and with ``norm_topk_prob`` renormalize (Mixtral semantics,
+    equivalent to the reference's topk_softmax_with_capacity);
+    without it the k gates stay the softmax's own values, whose sum
+    is under 1 (OLMoE). Sinkhorn routing selects indices from
     the sinkhorn-normalized logits WITHOUT gradient, while gate values
     come from the raw logits (sigmoid for k=1, softmax for k>1) --
     matching reference router.py:53-76.
@@ -63,8 +92,9 @@ def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
         return top_probs, top_idx
     probs = jax.nn.softmax(logits, axis=-1)
     top_probs, top_idx = jax.lax.top_k(probs, cfg_moe.top_k)
-    top_probs = top_probs / jnp.maximum(
-        top_probs.sum(-1, keepdims=True), 1e-9)
+    if cfg_moe.norm_topk_prob:
+        top_probs = top_probs / jnp.maximum(
+            top_probs.sum(-1, keepdims=True), 1e-9)
     return top_probs, top_idx
 
 
@@ -114,8 +144,9 @@ def z_loss(logits: jnp.ndarray,
 
 def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
                 ) -> jnp.ndarray:
-    """Batched expert MLP: xs [E, C, H] -> [E, C, H] through stacked
-    [E, H, F] weights (one einsum per projection = the grouped GEMM)."""
+    """Batched expert MLP of the dense and capacity modes: xs
+    [E, C, H] -> [E, C, H] through stacked [E, H, F] weights, one
+    batched einsum per projection (every expert over C rows)."""
     from realhf_tpu.models.transformer import _activation
     cdt = xs.dtype
     gate = jnp.einsum("ech,ehf->ecf", xs, m["wg"].astype(cdt))
@@ -124,32 +155,31 @@ def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
                       m["wd"].astype(cdt))
 
 
-def ragged_dispatch_enabled(cfg: TransformerConfig) -> bool:
-    """Single source of truth for whether the grouped-GEMM (ragged)
-    dispatch path is active for this config."""
-    return (cfg.mlp_type == "moe" and cfg.moe is not None
-            and cfg.moe.capacity_factor is None
-            and cfg.moe.use_grouped_gemm)
+def dispatch_mode(cfg: TransformerConfig) -> Optional[str]:
+    """Single source of truth for the dispatch a config's MoE layers
+    take: "ragged", "dense" or "capacity"; None for a dense model."""
+    if cfg.mlp_type != "moe" or cfg.moe is None:
+        return None
+    if cfg.moe.capacity_factor is not None:
+        return "capacity"
+    return "ragged" if cfg.moe.use_grouped_gemm else "dense"
 
 
 def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
-                top_probs: jnp.ndarray, top_idx: jnp.ndarray
-                ) -> jnp.ndarray:
+                top_probs: jnp.ndarray, top_idx: jnp.ndarray,
+                group_sizes: jnp.ndarray) -> jnp.ndarray:
     """Grouped-GEMM dispatch: sort (token, k) pairs by expert, run
     ``jax.lax.ragged_dot`` per projection over the stacked [E, H, F]
     weights, scatter-add gate-weighted outputs back. Exact top-k MoE
     (reference GroupedMLP, experts.py:98) with static shapes."""
     from realhf_tpu.models.transformer import _activation
     t, h = xt.shape
-    e = cfg.moe.num_experts
     k = cfg.moe.top_k
     cdt = xt.dtype
 
-    flat_expert = top_idx.reshape(-1)                 # [T*k]
-    order = jnp.argsort(flat_expert)                  # sort by expert
+    order = jnp.argsort(top_idx.reshape(-1))          # sort by expert
     tok_idx = order // k
     xs = xt[tok_idx]                                  # [T*k, H] sorted
-    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
 
     gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), group_sizes)
     up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), group_sizes)
@@ -194,8 +224,11 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     top_probs = top_probs * valid[:, None]
 
     e = moe.num_experts
+    # (token, k) pairs an expert receives, pads among them
+    load = jnp.bincount(top_idx.reshape(-1), length=e).astype(jnp.int32)
     ep = ep_constraint if ep_constraint is not None else (lambda a: a)
-    if ragged_dispatch_enabled(cfg):
+    mode = dispatch_mode(cfg)
+    if mode == "ragged":
         if ep_constraint is not None:
             raise ValueError(
                 "expert_parallel requires the capacity or dense "
@@ -203,8 +236,8 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                 "group dim (set capacity_factor or "
                 "use_grouped_gemm=False).")
         out = _ragged_moe(cfg, m, xt.astype(x.dtype), top_probs,
-                          top_idx)
-    elif moe.capacity_factor is None:
+                          top_idx, load)
+    elif mode == "dense":
         # Dense mode: every expert over all tokens, gate-weighted.
         xs = ep(jnp.broadcast_to(xt[None], (e, t, h)).astype(x.dtype))
         expert_out = ep(_expert_ffn(cfg, m, xs))  # [E, T, H]
@@ -236,7 +269,8 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
         out = jnp.einsum("ech,tec->th", expert_out.astype(jnp.float32),
                          combine)
 
-    losses = {}
+    losses = {LOAD_STAT: load.max().astype(jnp.float32)
+              * (e / (t * moe.top_k))}
     if moe.routing_type == "aux_loss" and moe.aux_loss_coeff:
         losses["moe_aux_loss"] = moe.aux_loss_coeff * load_balancing_loss(
             probs_full, top_idx, e, moe.top_k, valid=valid)

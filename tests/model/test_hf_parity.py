@@ -45,10 +45,24 @@ def _hf_model(family):
             n_layer=3, n_head=4, n_embd=64, n_positions=128, vocab_size=200,
             embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0)
         return transformers.GPT2LMHeadModel(cfg)
+    if family == "olmoe":
+        cfg = transformers.OlmoeConfig(
+            hidden_size=64, intermediate_size=32, num_hidden_layers=3,
+            num_attention_heads=4, num_key_value_heads=4, vocab_size=200,
+            max_position_embeddings=128, num_experts=8,
+            num_experts_per_tok=2, norm_topk_prob=False,
+            rms_norm_eps=1e-5, rope_theta=10000.0)
+        model = transformers.OlmoeForCausalLM(cfg)
+        with torch.no_grad():  # norm scales off 1, q/k norms among them
+            for name, p in model.named_parameters():
+                if "norm" in name:
+                    p.add_(0.2 * torch.randn_like(p))
+        return model
     raise NotImplementedError(family)
 
 
-@pytest.fixture(scope="module", params=["llama", "qwen2", "mistral", "gpt2"])
+@pytest.fixture(scope="module", params=["llama", "qwen2", "mistral", "gpt2",
+                                        "olmoe"])
 def saved_hf_model(request, tmp_path_factory):
     family = request.param
     torch.manual_seed(5)

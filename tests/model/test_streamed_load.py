@@ -38,6 +38,14 @@ def _cfg(family, vocab=96):
             use_attention_bias=False, use_attn_proj_bias=False,
             use_mlp_bias=False,
             moe=MoEConfig(num_experts=4, top_k=2), **base)
+    if family == "olmoe":
+        return TransformerConfig(
+            layer_norm_type="rms", mlp_type="moe",
+            activation_function="silu", apply_rotary=True,
+            use_attention_bias=False, use_attn_proj_bias=False,
+            use_mlp_bias=False, qk_norm="full",
+            moe=MoEConfig(num_experts=4, top_k=2, norm_topk_prob=False),
+            **dict(base, n_kv_heads=4))
     if family == "gemma":
         return TransformerConfig(
             layer_norm_type="gemma", mlp_type="llama",
@@ -52,7 +60,8 @@ def _cfg(family, vocab=96):
         use_mlp_bias=False, **base)
 
 
-@pytest.mark.parametrize("family", ["llama", "gpt2", "mixtral", "gemma"])
+@pytest.mark.parametrize("family", ["llama", "gpt2", "mixtral", "gemma",
+                                    "olmoe"])
 def test_streamed_matches_eager(family, tmp_path):
     cfg = _cfg(family)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -232,7 +241,7 @@ def test_streamed_vocab_padding_roundtrip(tmp_path):
         np.asarray(host["head"]["w"], np.float32), rtol=1e-6, atol=1e-7)
 
 
-@pytest.mark.parametrize("family", ["llama", "mixtral"])
+@pytest.mark.parametrize("family", ["llama", "mixtral", "olmoe"])
 def test_streamed_save_roundtrip(family, tmp_path):
     """save_hf_checkpoint_streamed (one shard per layer, sliced from
     sharded device arrays) produces a directory the EAGER loader reads

@@ -526,6 +526,7 @@ class _Batch:
 class _FakeEngine:
     from realhf_tpu.engine.engine import Engine
     _run = Engine._run
+    _moe_attrs = {}  # a dense model: no moe_* span attributes
     params = ()
 
     def __init__(self):

@@ -237,3 +237,89 @@ class TestRaggedGroupedGEMM:
         for a, b in zip(jax.tree.leaves(gr), jax.tree.leaves(gd)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-5, rtol=2e-5)
+
+
+class TestGateNormalisation:
+    """``MoEConfig.norm_topk_prob``: Mixtral divides the k gates by
+    their sum, OLMoE takes them as the softmax over all experts gave
+    them."""
+
+    LOGITS = np.random.default_rng(5).normal(size=(12, 16)).astype(
+        np.float32)
+
+    @pytest.mark.parametrize("top_k,n_experts", [(2, 8), (8, 16), (1, 4)])
+    def test_off_returns_the_softmaxs_own_values(self, top_k, n_experts):
+        cfg = MoEConfig(num_experts=n_experts, top_k=top_k,
+                        norm_topk_prob=False)
+        logits = self.LOGITS[:, :n_experts]
+        probs, idx = moe_ops.router_probs(cfg, jnp.asarray(logits))
+        full = np.asarray(jax.nn.softmax(jnp.asarray(logits), axis=-1))
+        want_idx = np.argsort(-full, axis=-1)[:, :top_k]
+        np.testing.assert_array_equal(np.asarray(idx), want_idx)
+        np.testing.assert_array_equal(
+            np.asarray(probs), np.take_along_axis(full, want_idx, -1))
+        assert (np.asarray(probs).sum(-1) < 1.0 - 1e-3).all()
+
+    @pytest.mark.parametrize("top_k,n_experts", [(2, 8), (8, 16), (1, 4)])
+    def test_on_divides_by_the_sum_as_before(self, top_k, n_experts):
+        logits = jnp.asarray(self.LOGITS[:, :n_experts])
+        on, idx_on = moe_ops.router_probs(
+            MoEConfig(num_experts=n_experts, top_k=top_k), logits)
+        off, idx_off = moe_ops.router_probs(
+            MoEConfig(num_experts=n_experts, top_k=top_k,
+                      norm_topk_prob=False), logits)
+        np.testing.assert_array_equal(np.asarray(idx_on),
+                                      np.asarray(idx_off))
+        np.testing.assert_allclose(np.asarray(on).sum(-1), 1.0, rtol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(on), np.asarray(off / off.sum(-1, keepdims=True)),
+            rtol=1e-6)
+
+    @pytest.mark.parametrize("mode", ["ragged", "dense", "capacity"])
+    def test_layer_output_scales_with_the_gate_mass(self, mode):
+        """Every dispatch mode honours the flag: the un-renormalised
+        layer's output is the renormalised one's times each token's
+        gate mass."""
+        def cfg(norm):
+            c = moe_cfg(capacity=4.0 if mode == "capacity" else None,
+                        top_k=2, n_experts=4)
+            c.moe.use_grouped_gemm = mode == "ragged"
+            c.moe.norm_topk_prob = norm
+            return c
+        assert moe_ops.dispatch_mode(cfg(True)) == mode
+        params = T.init_params(cfg(True), jax.random.PRNGKey(2))
+        m = jax.tree.map(lambda a: a[0], params["blocks"])["mlp"]
+        x = jnp.asarray(np.random.default_rng(1).normal(
+            size=(2, 6, 32)).astype(np.float32))
+        on, _ = moe_ops.moe_mlp_with_losses(cfg(True), m, x)
+        off, aux = moe_ops.moe_mlp_with_losses(cfg(False), m, x)
+        probs = jax.nn.softmax(
+            x.reshape(12, 32) @ m["router"], axis=-1)
+        mass = jax.lax.top_k(probs, 2)[0].sum(-1).reshape(2, 6, 1)
+        np.testing.assert_allclose(np.asarray(off), np.asarray(on * mass),
+                                   rtol=2e-5, atol=1e-7)
+        # the statistic beside the losses: 12 x 2 pairs over 4 experts
+        load = float(aux[moe_ops.LOAD_STAT])
+        assert 1.0 <= load <= 4.0 and abs(load * 6 - round(load * 6)) < 1e-5
+        assert moe_ops.aux_loss(aux) == sum(
+            v for k, v in aux.items() if k != moe_ops.LOAD_STAT)
+
+
+def test_load_statistic_is_the_worst_layers_not_a_sum():
+    cfg = moe_cfg()
+    params = T.init_params(cfg, jax.random.PRNGKey(3))
+    ids = jnp.asarray(np.random.default_rng(2).integers(
+        0, 64, size=(2, 16)), jnp.int32)
+    _, _, aux = T.forward(cfg, params, ids, jnp.ones_like(ids),
+                          return_aux=True)
+    per_layer = []
+    for i in range(cfg.n_layers):
+        one = TransformerConfig(**{**cfg.__dict__, "n_layers": i + 1})
+        sub = jax.tree.map(lambda a: a[:i + 1], params["blocks"])
+        _, _, a = T.forward(one, {**params, "blocks": sub}, ids,
+                            jnp.ones_like(ids), return_aux=True)
+        per_layer.append(float(a[moe_ops.LOAD_STAT]))
+    # the stack's statistic is a running maximum over its layers
+    assert float(aux[moe_ops.LOAD_STAT]) == per_layer[-1] \
+        == max(per_layer)
+    assert float(aux["moe_aux_loss"]) > 0
