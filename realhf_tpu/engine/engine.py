@@ -38,9 +38,11 @@ from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.obs import metrics, tracing
 from realhf_tpu.ops import functional as F
 from realhf_tpu.ops import moe as moe_ops
+from realhf_tpu.ops.attention import flash_takes
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
+from realhf_tpu.ops.flash_attention import block_counts
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host
@@ -152,6 +154,10 @@ class Engine:
         # Context parallelism: attention becomes a ring over the "ctx"
         # mesh axis; the rest of the model shards L via GSPMD.
         self.attention_fn_inference = None
+        # whether packed rows go to the flash kernel (their length
+        # decides the rest, call by call): what flash_kv_blocks_total
+        # counts. The ring and the pipeline's XLA path do not.
+        self._flash_rows = False
         if ctx.parallel.context_parallel_size > 1:
             from realhf_tpu.ops.ring_attention import ring_attention
             mesh = self.mesh
@@ -211,8 +217,10 @@ class Engine:
                     make_sharded_attention,
                 )
                 self.attention_fn = make_sharded_attention(self.mesh)
+                self._flash_rows = True
         else:
             self.attention_fn = None
+            self._flash_rows = _pallas_enabled()
 
         # which dispatch a sparse model's programs take: on every
         # engine:* span, and the label of moe_routed_pairs_total
@@ -295,9 +303,11 @@ class Engine:
     # ------------------------------------------------------------------
     # Compiled-program introspection
     # ------------------------------------------------------------------
-    def _run(self, name: str, fn: Callable, *args, **static):
+    def _run(self, name: str, fn: Callable, attrs: Dict[str, Any],
+             *args, **static):
         """Call one of this engine's jitted programs, remembering its
-        abstract signature for :meth:`compiled_text`."""
+        abstract signature for :meth:`compiled_text`. ``attrs``
+        (:meth:`_count_batch`) go on its ``engine:*`` span."""
         self._last_call[name] = (fn, jax.tree.map(_abstract, args),
                                  static)
         if not tracing.enabled():
@@ -305,12 +315,41 @@ class Engine:
             return fn(*args, **static)
         # engine:<name> holds the dispatch and, in a synced stretch,
         # the wait for the outputs
-        with tracing.span(f"engine:{name}", **self._moe_attrs) as sp:
+        with tracing.span(f"engine:{name}", **self._moe_attrs,
+                          **attrs) as sp:
             self._last_span = sp
             lowered = fn._cache_size()
             out = sp.result(fn(*args, **static))
             sp.set_attribute("compiled", fn._cache_size() > lowered)
             return out
+
+    def _count_batch(self, seg_ids, decode_tokens: int = 0
+                     ) -> Dict[str, Any]:
+        """What the program about to run does with its batch, counted
+        on the host from the segment ids: the counters, and what of
+        them its ``engine:*`` span carries (:meth:`_run`)."""
+        self._count_routed_pairs(seg_ids, decode_tokens)
+        return self._count_flash_blocks(seg_ids)
+
+    def _count_flash_blocks(self, seg_ids) -> Dict[str, float]:
+        """``flash_kv_blocks_total{role,kind}``: the (query block, key
+        block) pairs the flash forward kernel visits over these packed
+        rows (``visited``) and the pairs under the rows' causal
+        diagonals (``causal``), one head's, x layers
+        (``ops.flash_attention.block_counts``). Their ratio is the
+        span's ``flash_block_share``. Nothing where the rows do not
+        go to the kernel, or are on the device already."""
+        if not (self._flash_rows and isinstance(seg_ids, np.ndarray)
+                and flash_takes(seg_ids.shape[-1], self.cfg.head_dim,
+                                sliding_window=self.cfg.sliding_window)
+                and not self.cfg.scale_attn_by_inverse_layer_idx):
+            return {}
+        counts = dict(zip(("visited", "causal"), block_counts(seg_ids)))
+        for kind, n in counts.items():
+            metrics.inc("flash_kv_blocks_total", n * self.cfg.n_layers,
+                        role=str(self.ctx.model_name.role), kind=kind)
+        return dict(
+            flash_block_share=counts["visited"] / counts["causal"])
 
     def _count_routed_pairs(self, seg_ids, decode_tokens: int = 0):
         """``moe_routed_pairs_total{role,dispatch}``: the (token,
@@ -579,9 +618,10 @@ class Engine:
         stacked, weights = self._globalize_tree(
             (host_batch, np.asarray(loss_weights, np.float32)))
 
-        self._count_routed_pairs(host_batch["seg_ids"])
+        attrs = self._count_batch(host_batch["seg_ids"])
         self.params, self.opt_state, loss, stats, gnorm = self._run(
-            "train", step, self.params, self.opt_state, stacked, weights)
+            "train", step, attrs, self.params, self.opt_state, stacked,
+            weights)
         self.version += 1
         if self._decode_view is not None:
             # the view's gen-layout weight copy is now stale (params
@@ -642,10 +682,10 @@ class Engine:
         stacked, weights = self._globalize_tree(
             (host_batch, np.asarray(loss_weights, np.float32)))
 
-        self._count_routed_pairs(host_batch["seg_ids"])
+        attrs = self._count_batch(host_batch["seg_ids"])
         self.params, self.opt_state, losses, stats, gnorms = self._run(
-            "train_seq", step, self.params, self.opt_state, stacked,
-            weights)
+            "train_seq", step, attrs, self.params, self.opt_state,
+            stacked, weights)
         self.version += len(minibatches)
         if self._decode_view is not None:
             self._decode_view.params = None
@@ -679,9 +719,9 @@ class Engine:
                 return h
             self._jit_forward_hidden = jax.jit(
                 hidden, out_shardings=self._out_replicated())
-        self._count_routed_pairs(seg_ids)
+        attrs = self._count_batch(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
-        return self._run("hidden", self._jit_forward_hidden,
+        return self._run("hidden", self._jit_forward_hidden, attrs,
                          self.params, ids, seg)
 
     def forward_logprobs(self, input_ids, seg_ids, temperature: float = 1.0,
@@ -701,13 +741,13 @@ class Engine:
             self._jit_logprobs = jax.jit(
                 logprobs, static_argnames=("temp", "has_mask"),
                 out_shardings=self._out_replicated())
-        self._count_routed_pairs(seg_ids)
+        attrs = self._count_batch(seg_ids)
         ids, seg, mask = self._globalize_tree(
             (input_ids, seg_ids,
              logits_mask if logits_mask is not None
              else np.zeros((1,), bool)))
-        return self._run("logprobs", self._jit_logprobs, self.params,
-                         ids, seg, mask, temp=temperature,
+        return self._run("logprobs", self._jit_logprobs, attrs,
+                         self.params, ids, seg, mask, temp=temperature,
                          has_mask=logits_mask is not None)
 
     def forward_values(self, input_ids, seg_ids):
@@ -723,10 +763,10 @@ class Engine:
                 return T.critic_values(self.cfg, params, h)
             self._jit_values = jax.jit(
                 values, out_shardings=self._out_replicated())
-        self._count_routed_pairs(seg_ids)
+        attrs = self._count_batch(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
-        return self._run("values", self._jit_values, self.params, ids,
-                         seg)
+        return self._run("values", self._jit_values, attrs, self.params,
+                         ids, seg)
 
     # ------------------------------------------------------------------
     # Generation
@@ -848,12 +888,13 @@ class Engine:
                 out_sharding=self._out_replicated(),
                 mesh=self.mesh, attention_fn=self.attention_fn)
         fn = self._generate_cache[cache_key]
-        self._count_routed_pairs(
+        attrs = self._count_batch(
             prompt_seg,
             decode_tokens=prompt_seg.shape[0] * gconfig.max_new_tokens)
         ids, seg, pos, key = self._globalize_tree(
             (prompt_ids, prompt_seg, prompt_pos, key))
-        return self._run("generate", fn, self.params, ids, seg, pos, key)
+        return self._run("generate", fn, attrs, self.params, ids, seg,
+                         pos, key)
 
     # ------------------------------------------------------------------
     def _cast_param_dtype(self, params):

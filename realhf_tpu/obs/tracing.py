@@ -87,7 +87,7 @@ KEPT_CAPTURES = 8
 #: counters whose deltas a capture reports (docs/observability.md)
 CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "engine_compiles_total", "engine_compile_secs_total",
-                    "moe_routed_pairs_total")
+                    "moe_routed_pairs_total", "flash_kv_blocks_total")
 #: gauges whose last values a capture reports
 CAPTURE_GAUGES = ("moe_load_max_over_mean",)
 

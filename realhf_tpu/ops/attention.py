@@ -83,6 +83,15 @@ def packed_attention_xla(
     return out.reshape(b, l, nq, hd).astype(q.dtype)
 
 
+def flash_takes(row_len: int, head_dim: int, *, scale=None,
+                logits_soft_cap=None, sliding_window=None) -> bool:
+    """Whether a packed row meets the flash kernel's gate: its tiling,
+    a static python scale, no soft cap, no sliding window."""
+    return (row_len % 128 == 0 and head_dim >= 64
+            and logits_soft_cap is None and sliding_window is None
+            and (scale is None or isinstance(scale, (int, float))))
+
+
 def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
                      logits_soft_cap=None, sliding_window=None,
                      use_flash: Optional[bool] = None):
@@ -92,13 +101,10 @@ def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
     meet the kernel's tiling constraints, XLA otherwise (CPU tests).
     """
     if use_flash is None:
-        use_flash = (pallas_enabled()
-                     and q.shape[1] % 128 == 0 and q.shape[3] >= 64
-                     # the flash kernel requires a static python scale
-                     # and has no soft-cap / sliding-window support
-                     and logits_soft_cap is None
-                     and sliding_window is None
-                     and (scale is None or isinstance(scale, (int, float))))
+        use_flash = pallas_enabled() and flash_takes(
+            q.shape[1], q.shape[3], scale=scale,
+            logits_soft_cap=logits_soft_cap,
+            sliding_window=sliding_window)
     if use_flash:
         assert sliding_window is None, \
             "flash kernel has no sliding-window support yet"

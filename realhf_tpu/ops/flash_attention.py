@@ -6,21 +6,46 @@ attention (flash-attention-2 schedule) with
 
 - causal masking,
 - segment-id masking for packed variable-length sequences (the
-  cu_seqlens equivalent),
+  cu_seqlens equivalent), and loops that visit only the blocks a
+  block's own segments reach,
 - GQA (query-head groups share KV heads),
 - a custom VJP with Pallas backward kernels (dq and dkv passes),
   recomputing probabilities from the saved log-sum-exp.
 
 Layout contract: q [B, L, nq, hd], k/v [B, L, nkv, hd], seg_ids [B, L]
-(0 = padding). L must be a multiple of the Q block; hd should be a
-multiple of 128 for MXU tiling (128 for llama-family models). K and V
-(forward, dq) and Q, dO, lse, delta (dkv) are kept whole in VMEM per
-(batch, head), which bounds L: ``FLASH_MAX_LEN`` below is what the
-v5e compiler accepts for forward AND backward at the head sizes of
-the supported families; ``flash_attention`` raises above it. Longer
-rows need more microbatches (shorter packed rows) or a
-context-parallel mesh (ring attention); streaming KV by DMA is future
-work.
+(0 = padding). One segment id is ONE contiguous run of a row, as
+``engine/packing.py:segment_ids`` lays sequences out (ids in no
+order: the packer places the longest first) and as
+``models/transformer.py:positions_from_segments`` and the window test
+of ``ops/attention.py:_segment_mask`` already assume. L must be a
+multiple of the Q block; hd should be a multiple of 128 for MXU
+tiling (128 for llama-family models).
+
+Which blocks are visited. A token attends inside its own segment
+only, so a query block needs the key blocks from the lowest start to
+the highest end of the segments its non-padding tokens belong to, cut
+at its causal diagonal, and a key block the query blocks likewise
+(``block_ranges``). Both ranges are computed from ``seg_ids`` inside
+the jitted program, handed to the kernels by scalar prefetch and made
+the bounds of their loops: forward and dq over ``[kv_lo, kv_hi)`` of
+their query block, dkv over ``[q_lo, q_hi)`` of its key block. A
+block of another segment is never computed; a block that is visited
+is masked exactly as before, so the numbers are those of visiting
+every block up to the diagonal: a block left out contributed
+``p = 0``, or garbage that the next rescaling by ``alpha = 0`` wiped.
+A row of one segment visits the whole causal triangle; a block of
+padding alone visits nothing. The range runs from the first to the
+last block that holds an unmasked pair; only padding that fills whole
+blocks between two segments of one block leaves a masked block inside
+it.
+
+What is still whole in VMEM. K and V (forward, dq) and Q, dO, lse,
+delta (dkv) are kept whole per (batch, head) whatever the ranges say,
+which bounds L: ``FLASH_MAX_LEN`` below is what the v5e compiler
+accepts for forward AND backward at the head sizes of the supported
+families; ``flash_attention`` raises above it. Longer rows need more
+microbatches (shorter packed rows) or a context-parallel mesh (ring
+attention); streaming KV by DMA is future work.
 
 Mosaic requires the last two dims of every block to be (8, 128)-tile
 aligned, so 1D row metadata rides wider layouts: q-side segment ids
@@ -34,7 +59,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
@@ -60,14 +87,96 @@ def _blocks(l: int, bq: int, bk: int):
 
 
 # ----------------------------------------------------------------------
+# Which blocks a block's segments reach
+# ----------------------------------------------------------------------
+def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp):
+    """The key blocks each query block has to visit and the query
+    blocks each key block has to visit, from the segment ids alone:
+    ``(kv_lo, kv_hi) [B, L // bq]`` and ``(q_lo, q_hi) [B, L // bk]``,
+    int32, ``hi`` one past the last block.
+
+    A token attends only inside its own segment, and a segment is ONE
+    contiguous run of its id, so the tokens of a block reach no
+    further than from the lowest start to the highest end of the runs
+    that its non-padding tokens belong to (run starts as
+    ``models/transformer.py:positions_from_segments`` finds them).
+    Causality cuts that span at the block's own diagonal. A block of
+    padding alone gets an empty range (``lo >= hi``); a row of one
+    segment gets the whole causal triangle. ``xp`` is ``jnp`` inside a
+    program and ``np`` for :func:`block_counts`: one rule for both."""
+    b, l = seg_ids.shape
+    idx = xp.arange(l, dtype=xp.int32)[None, :]
+    edge = seg_ids[:, 1:] != seg_ids[:, :-1]
+    true = xp.ones((b, 1), bool)
+    # (not xp.maximum.accumulate inside a program: jnp's is a
+    # sequential scan, L steps of a while loop on the device)
+    if xp is np:
+        cummax = functools.partial(np.maximum.accumulate, axis=1)
+
+        def cummin_reverse(x):
+            return np.minimum.accumulate(x[:, ::-1], axis=1)[:, ::-1]
+    else:
+        cummax = functools.partial(jax.lax.cummax, axis=1)
+        cummin_reverse = functools.partial(jax.lax.cummin, axis=1,
+                                           reverse=True)
+    start = cummax(xp.where(xp.concatenate([true, edge], axis=1), idx, 0))
+    end = cummin_reverse(
+        xp.where(xp.concatenate([edge, true], axis=1), idx + 1, l))
+    valid = seg_ids != 0
+    start = xp.where(valid, start, l)
+    end = xp.where(valid, end, 0)
+
+    def span(block):
+        return (start.reshape(b, l // block, block).min(-1),
+                end.reshape(b, l // block, block).max(-1))
+
+    q_start, q_end = span(bq)
+    k_start, k_end = span(bk)
+    if causal:
+        # keys at or before the query block's last row; queries at or
+        # after the key block's first column
+        q_end = xp.minimum(
+            q_end, (xp.arange(l // bq, dtype=xp.int32) + 1) * bq)
+        k_start = xp.maximum(
+            k_start, xp.arange(l // bk, dtype=xp.int32) * bk)
+    return ((q_start // bk, -(-q_end // bk)),
+            (k_start // bq, -(-k_end // bq)))
+
+
+def block_counts(seg_ids: np.ndarray, bq: int = DEFAULT_BQ,
+                 bk: int = DEFAULT_BK):
+    """``(visited, causal)``: the (query block, key block) pairs the
+    causal forward kernel visits over packed rows ``seg_ids [..., L]``
+    (one head, one layer), and the pairs under the row's causal
+    diagonal that it would visit if each row were one segment. On the
+    host, in numpy, by the kernels' own rule (:func:`block_ranges`);
+    the engine's counter ``flash_kv_blocks_total`` adds these up."""
+    seg_ids = np.asarray(seg_ids)
+    seg_ids = seg_ids.reshape(-1, seg_ids.shape[-1])
+    bq, bk = _blocks(seg_ids.shape[1], bq, bk)
+    (lo, hi), _ = block_ranges(seg_ids, bq, bk, xp=np)
+    (_, diag), _ = block_ranges(np.ones_like(seg_ids[:1]), bq, bk, xp=np)
+    return (int(np.maximum(hi - lo, 0).sum()),
+            int(diag.sum()) * seg_ids.shape[0])
+
+
+# ----------------------------------------------------------------------
 # Forward
 # ----------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
+def _block_range(lo_ref, hi_ref):
+    """This grid step's loop bounds out of the prefetched scalars
+    (``[B * blocks]``, flat: a 2-D array in SMEM pads its last axis to
+    128 words)."""
+    i = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
+    return lo_ref[i], hi_ref[i]
+
+
+def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
+                q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
                 o_ref, lse_ref,  # outputs
                 *, scale: float, bk: int, causal: bool):
     qi = pl.program_id(2)
     bq, hd = q_ref.shape[-2], q_ref.shape[-1]
-    l = k_ref.shape[-2]
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, hd]
     seg_q = segq_ref[0, :, 0]  # [BQ]
@@ -76,8 +185,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     acc0 = jnp.zeros((bq, hd), jnp.float32)
-
-    n_kv = pl.cdiv((qi + 1) * bq, bk) if causal else l // bk
 
     def body(j, carry):
         m, l_sum, acc = carry
@@ -103,7 +210,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l_sum, acc = jax.lax.fori_loop(0, n_kv, body, (m0, l0, acc0))
+    # only the key blocks this query block's segments reach
+    # (block_ranges): a block left out held no unmasked pair
+    m, l_sum, acc = jax.lax.fori_loop(
+        *_block_range(kv_lo_ref, kv_hi_ref), body, (m0, l0, acc0))
     # Rows that never saw a valid key (all-padding rows) keep
     # m == NEG_INF: their p = exp(NEG_INF - NEG_INF) = 1 garbage must be
     # zeroed here. (Fully-masked *blocks* of otherwise-valid rows
@@ -125,6 +235,36 @@ def _expand_segments(seg_ids):
     return segq, segk
 
 
+def _index_maps(group: int):
+    """Index maps of a (batch, q head, block) grid step, each taking
+    the prefetched scalars after the grid indices: the step's block
+    (``row``) or the whole length (``whole``) of a [B, nq, L, .] array,
+    the same of a [B, nkv, L, .] array (``kv_row``, ``kv_whole``), and
+    of the segment views: ``seg_row`` of [B, L, LANES], ``seg_whole``
+    of either view."""
+    return dict(
+        row=lambda bi, h, i, *_: (bi, h, i, 0),
+        whole=lambda bi, h, i, *_: (bi, h, 0, 0),
+        kv_row=lambda bi, h, i, *_: (bi, h // group, i, 0),
+        kv_whole=lambda bi, h, i, *_: (bi, h // group, 0, 0),
+        seg_row=lambda bi, h, i, *_: (bi, i, 0),
+        seg_whole=lambda bi, h, i, *_: (bi, 0, 0))
+
+
+def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
+                 out_shape, *args):
+    """``pallas_call`` with a grid step's loop bounds ``(lo, hi)
+    [B, grid[2]]`` prefetched as scalars; index maps get both after
+    the grid indices."""
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        name=name,
+    )(*(x.reshape(-1) for x in bounds), *args)
+
+
 def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
     b, l, nq, hd = q.shape
     nkv = k.shape[2]
@@ -135,42 +275,37 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
     kt = k.transpose(0, 2, 1, 3)  # [B, nkv, L, hd]
     vt = v.transpose(0, 2, 1, 3)
     segq, segk = _expand_segments(seg_ids)
+    kv_range, _ = block_ranges(seg_ids, bq, bk, causal)
 
-    grid = (b, nq, l // bq)
-    out, lse = pl.pallas_call(
+    at = _index_maps(group)
+
+    out, lse = _ranged_call(
         functools.partial(_fwd_kernel, scale=scale, bk=bk, causal=causal),
-        out_shape=(
-            jax.ShapeDtypeStruct(qt.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, l, hd),
-                         lambda bi, h, qi, g=group: (bi, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, l, hd),
-                         lambda bi, h, qi, g=group: (bi, h // g, 0, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda bi, h, qi: (bi, qi, 0)),
-            pl.BlockSpec((1, SUBLANES, l), lambda bi, h, qi: (bi, 0, 0)),
+        "flash_fwd", (b, nq, l // bq), kv_range,
+        [
+            pl.BlockSpec((1, 1, bq, hd), at["row"]),
+            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, bq, LANES), at["seg_row"]),
+            pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
         ],
-        out_specs=(
-            pl.BlockSpec((1, 1, bq, hd), lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq, LANES), lambda bi, h, qi: (bi, h, qi, 0)),
-        ),
-        name="flash_fwd",
-    )(qt, kt, vt, segq, segk)
+        (pl.BlockSpec((1, 1, bq, hd), at["row"]),
+         pl.BlockSpec((1, 1, bq, LANES), at["row"])),
+        (jax.ShapeDtypeStruct(qt.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
+        qt, kt, vt, segq, segk)
     return out.transpose(0, 2, 1, 3), lse
 
 
 # ----------------------------------------------------------------------
 # Backward
 # ----------------------------------------------------------------------
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
+def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
+                   q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                    lse_ref, delta_ref, dq_ref,
                    *, scale: float, bk: int, causal: bool):
     qi = pl.program_id(2)
     bq, hd = q_ref.shape[-2], q_ref.shape[-1]
-    l = k_ref.shape[-2]
 
     q = q_ref[0, 0].astype(jnp.float32) * scale
     do = do_ref[0, 0].astype(jnp.float32)
@@ -178,8 +313,6 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
     delta = delta_ref[0, 0, :, 0]
     seg_q = segq_ref[0, :, 0]
     q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-
-    n_kv = pl.cdiv((qi + 1) * bq, bk) if causal else l // bk
 
     def body(j, dq):
         k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
@@ -199,24 +332,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, n_kv, body, jnp.zeros((bq, hd), jnp.float32))
+    dq = jax.lax.fori_loop(*_block_range(kv_lo_ref, kv_hi_ref), body,
+                           jnp.zeros((bq, hd), jnp.float32))
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
+def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
+                    q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref,
                     *, scale: float, bq: int, causal: bool):
     ki = pl.program_id(2)
     bk, hd = k_ref.shape[-2], k_ref.shape[-1]
-    l = q_ref.shape[-2]
 
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     seg_k = segk_ref[0, 0, pl.ds(ki * bk, bk)]
     k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-
-    start_q = (ki * bk) // bq if causal else 0
-    n_q = l // bq
 
     def body(j, carry):
         dk, dv = carry
@@ -243,7 +374,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
 
     dk0 = jnp.zeros((bk, hd), jnp.float32)
     dv0 = jnp.zeros((bk, hd), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, n_q, body, (dk0, dv0))
+    dk, dv = jax.lax.fori_loop(*_block_range(q_lo_ref, q_hi_ref), body,
+                               (dk0, dv0))
     # Per-q-head partials; summed over each KV group outside (race-free).
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
@@ -267,58 +399,47 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
     delta = (ot.astype(jnp.float32) * dot.astype(jnp.float32)).sum(-1)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, l, LANES))
 
-    grid_q = (b, nq, l // bq_)
-    dq = pl.pallas_call(
+    kv_range, q_range = block_ranges(seg_ids, bq_, bk_, causal)
+
+    at = _index_maps(group)
+
+    dq = _ranged_call(
         functools.partial(_bwd_dq_kernel, scale=scale, bk=bk_,
                           causal=causal),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
-        grid=grid_q,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq_, hd), lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, l, hd),
-                         lambda bi, h, qi, g_=group: (bi, h // g_, 0, 0)),
-            pl.BlockSpec((1, 1, l, hd),
-                         lambda bi, h, qi, g_=group: (bi, h // g_, 0, 0)),
-            pl.BlockSpec((1, bq_, LANES), lambda bi, h, qi: (bi, qi, 0)),
-            pl.BlockSpec((1, SUBLANES, l), lambda bi, h, qi: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, bq_, hd), lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq_, LANES),
-                         lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq_, LANES),
-                         lambda bi, h, qi: (bi, h, qi, 0)),
+        "flash_bwd_dq", (b, nq, l // bq_), kv_range,
+        [
+            pl.BlockSpec((1, 1, bq_, hd), at["row"]),
+            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, bq_, LANES), at["seg_row"]),
+            pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
+            pl.BlockSpec((1, 1, bq_, hd), at["row"]),
+            pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
+            pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq_, hd),
-                               lambda bi, h, qi: (bi, h, qi, 0)),
-        name="flash_bwd_dq",
-    )(qt, kt, vt, segq, segk, dot, lse, delta)
+        pl.BlockSpec((1, 1, bq_, hd), at["row"]),
+        jax.ShapeDtypeStruct(qt.shape, jnp.float32),
+        qt, kt, vt, segq, segk, dot, lse, delta)
 
-    grid_k = (b, nq, l // bk_)
-    dk_partial, dv_partial = pl.pallas_call(
+    dk_partial, dv_partial = _ranged_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq_,
                           causal=causal),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32),
-        ),
-        grid=grid_k,
-        in_specs=[
-            pl.BlockSpec((1, 1, l, hd), lambda bi, h, ki: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk_, hd),
-                         lambda bi, h, ki, g_=group: (bi, h // g_, ki, 0)),
-            pl.BlockSpec((1, 1, bk_, hd),
-                         lambda bi, h, ki, g_=group: (bi, h // g_, ki, 0)),
-            pl.BlockSpec((1, l, LANES), lambda bi, h, ki: (bi, 0, 0)),
-            pl.BlockSpec((1, SUBLANES, l), lambda bi, h, ki: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, l, hd), lambda bi, h, ki: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, l, LANES), lambda bi, h, ki: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, l, LANES), lambda bi, h, ki: (bi, h, 0, 0)),
+        "flash_bwd_dkv", (b, nq, l // bk_), q_range,
+        [
+            pl.BlockSpec((1, 1, l, hd), at["whole"]),
+            pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),
+            pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),
+            pl.BlockSpec((1, l, LANES), at["seg_whole"]),
+            pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
+            pl.BlockSpec((1, 1, l, hd), at["whole"]),
+            pl.BlockSpec((1, 1, l, LANES), at["whole"]),
+            pl.BlockSpec((1, 1, l, LANES), at["whole"]),
         ],
-        out_specs=(
-            pl.BlockSpec((1, 1, bk_, hd), lambda bi, h, ki: (bi, h, ki, 0)),
-            pl.BlockSpec((1, 1, bk_, hd), lambda bi, h, ki: (bi, h, ki, 0)),
-        ),
-        name="flash_bwd_dkv",
-    )(qt, kt, vt, segq, segk, dot, lse, delta)
+        (pl.BlockSpec((1, 1, bk_, hd), at["row"]),
+         pl.BlockSpec((1, 1, bk_, hd), at["row"])),
+        (jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32),
+         jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32)),
+        qt, kt, vt, segq, segk, dot, lse, delta)
 
     # Sum q-head partials within each KV group.
     dk = dk_partial.reshape(b, nkv, group, l, hd).sum(2).transpose(0, 2, 1, 3)

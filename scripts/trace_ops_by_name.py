@@ -17,7 +17,9 @@ calls. Beside that it writes under ``chiprun_out/``:
   line there carries ``op_name`` metadata, which says what a
   ``fusion.597`` is; and ``memory_<cell>_<program>.json``: the
   compiler's own count of the program's arguments, outputs and
-  temporaries.
+  temporaries;
+- ``spans_<cell>.json``: the counters and the ``engine:*`` spans, with
+  their attributes, of every capture the run made.
 
 ``--summarize <cell>`` needs no chip: it reads those files back and
 prints the window's seconds by kind of operation.
@@ -93,9 +95,27 @@ def install(cell):
 
 def run(argv):
     from benchmark import run as bench_run
-    install(workload_of(argv))
+    cell = workload_of(argv)
+    install(cell)
     sys.argv = ["benchmark/run.py"] + argv
-    return bench_run.main()
+    rc = bench_run.main()
+    keep_spans(cell)
+    return rc
+
+
+def keep_spans(cell):
+    """``spans_<cell>.json``: every capture's counters and its
+    ``engine:*`` spans with their attributes (``flash_block_share``,
+    ``moe_dispatch``, ``compiled``), which no reader takes."""
+    from realhf_tpu.obs import tracing
+    kept = [dict(profiled=c.profile_dir is not None, sync=c.sync,
+                 counters=c.counters,
+                 spans=[dict(name=s["name"], secs=s["end"] - s["start"],
+                             **s["attributes"])
+                        for s in c.spans if s["name"].startswith("engine:")])
+            for c in tracing.captures()]
+    with open(os.path.join(OUT, f"spans_{cell}.json"), "w") as f:
+        json.dump(kept, f)
 
 
 def summarize(cell, program="train"):

@@ -115,3 +115,66 @@ def test_decode_step_stacked_scan_path(dp, tp, monkeypatch):
     with pltpu.force_tpu_interpret_mode():
         got = _one_decode_step(cfg, params, mesh=_mesh(dp, tp))
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
+
+
+def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
+    """A packed batch through ``train_batch`` and ``forward_logprobs``
+    with the flash kernels engaged: each ``engine:*`` span carries
+    ``flash_block_share`` and ``flash_kv_blocks_total{kind}`` grows by
+    ``block_counts`` x layers. An engine whose rows take the XLA path
+    counts nothing."""
+    from realhf_tpu.api.config import ModelName
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.engine.optim import OptimizerConfig
+    from realhf_tpu.obs import tracing
+    from realhf_tpu.ops import functional as F
+    from realhf_tpu.ops.flash_attention import block_counts
+    from realhf_tpu.parallel.mesh import MeshContext
+
+    cfg = _cfg()
+    par = ParallelismConfig()
+    ctx = MeshContext(ModelName("default", 0), _mesh(1, 1), par)
+    rng = np.random.default_rng(0)
+
+    def engine():  # its own weights: the train step donates them
+        return Engine(cfg, ctx, T.init_params(cfg, jax.random.PRNGKey(0)),
+                      optimizer=OptimizerConfig())
+
+    # rows of 1024 at the default blocks of 256 x 512: four sequences
+    # of 256 a row, and one of 512 beside padding
+    seg = np.zeros((2, 1, 1024), np.int32)
+    seg[0, 0] = np.repeat([2, 4, 1, 3], 256)
+    seg[1, 0, :512] = 1
+    ids = rng.integers(1, 120, size=seg.shape).astype(np.int32)
+    assert block_counts(seg) == (4 + 2, 6 + 6)
+
+    def loss_fn(params, mb):
+        h, _ = T.forward(cfg, params, mb["input_ids"], mb["seg_ids"])
+        lp = F.shifted_logprobs_from_hidden(
+            cfg, params, h, mb["input_ids"], mb["seg_ids"])
+        return -lp.mean(), {}
+
+    def run(engine):
+        tracing.start()
+        engine.train_batch(
+            [dict(input_ids=ids[i], seg_ids=seg[i]) for i in range(2)],
+            loss_fn, loss_fn_key="nll")
+        engine.forward_logprobs(ids[0], seg[0])
+        return tracing.stop()
+
+    xla = run(engine())
+    assert not any(k.startswith("flash_kv_blocks_total")
+                   for k in xla.counters)
+    assert "flash_block_share" not in xla.named(
+        "engine:train")[0]["attributes"]
+
+    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
+    with pltpu.force_tpu_interpret_mode():
+        capture = run(engine())
+    [train] = capture.named("engine:train")
+    [logprobs] = capture.named("engine:logprobs")
+    assert train["attributes"]["flash_block_share"] == 6 / 12
+    assert logprobs["attributes"]["flash_block_share"] == 4 / 6
+    for kind, blocks in (("visited", 6 + 4), ("causal", 12 + 6)):
+        assert capture.counter("flash_kv_blocks_total", role="default",
+                               kind=kind) == blocks * cfg.n_layers

@@ -543,7 +543,7 @@ class _FakeInterface:
 
     def inference(self, model, inp, n_mbs=None):
         import numpy as np
-        return dict(out=model.engine._run("logprobs", self.fn,
+        return dict(out=model.engine._run("logprobs", self.fn, {},
                                           np.ones(4, np.float32)))
 
 

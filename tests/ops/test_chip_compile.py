@@ -19,9 +19,10 @@ it but cannot be read back without a chip).
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from realhf_tpu.ops.attention import packed_attention
+from realhf_tpu.ops.attention import make_sharded_attention, packed_attention
 from realhf_tpu.ops.decode_attention import (
     flash_decode_attention,
     flash_decode_attention_stacked,
@@ -102,6 +103,35 @@ def test_flash_compiles_at_its_stated_limit(one_chip, nq, nkv, hd):
     args = _qkv(one_chip, 1, FLASH_MAX_LEN, nq, nkv, hd)
     _compile(flash_attention, *args)
     _compile(_flash_grads, *args)
+
+
+def test_flash_compiles_under_shard_map(topo):
+    """Cell 3's layout: rows over "data", heads over "model" on a 2x2
+    mesh, each shard's kernels taking their ranges from the local
+    segment ids (Mistral's heads, the cell's rows of 2048)."""
+    import numpy as np
+
+    from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2),
+                (DATA_AXIS, MODEL_AXIS))
+    heads = NamedSharding(mesh, P(DATA_AXIS, None, MODEL_AXIS, None))
+    rows = NamedSharding(mesh, P(DATA_AXIS, None))
+    q, k, v, seg = _qkv(None, 2, 2048, 32, 8, 128)
+    q, k, v = (jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=heads)
+               for x in (q, k, v))
+    seg = jax.ShapeDtypeStruct(seg.shape, seg.dtype, sharding=rows)
+    attn = make_sharded_attention(
+        mesh, inner=lambda *a, **kw: flash_attention(
+            *a, causal=kw["causal"], scale=kw["scale"]))
+
+    def grads(q, k, v, seg):
+        def loss(q, k, v):
+            return attn(q, k, v, seg).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(grads, q, k, v, seg).as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
 
 
 def test_row_above_the_limit_raises_not_xla():

@@ -120,3 +120,154 @@ def test_dispatch_guards():
     f(jnp.float32(0.1))
     # soft cap: must not raise NotImplementedError
     out(logits_soft_cap=30.0)
+
+
+# ----------------------------------------------------------------------
+# Block ranges from the segment ids
+# ----------------------------------------------------------------------
+def packed_rows(rng, l, align, kind):
+    """[4, l] segment ids as the packer may lay them: each id one
+    contiguous run, ids in no order, boundaries on multiples of
+    ``align``; ``kind`` says where the padding is. Row 3 is padding
+    alone."""
+    seg = np.zeros((4, l), np.int32)
+    for row in range(3):
+        cuts = np.sort(rng.choice(np.arange(1, l // align),
+                                  size=min(5, l // align - 1),
+                                  replace=False)) * align
+        bounds = np.concatenate([[0], cuts, [l]])
+        ids = rng.permutation(len(bounds) - 1) + 1
+        for i, (a, b) in enumerate(zip(bounds[:-1], bounds[1:])):
+            seg[row, a:b] = ids[i]
+        if kind in ("tail", "both"):
+            seg[row, bounds[-2]:] = 0
+        if kind in ("between", "both"):
+            seg[row, bounds[2]:bounds[3]] = 0
+    return seg
+
+
+def needed_blocks(seg, bq, bk, causal):
+    """[B, L // bq, L // bk] bool: the block holds an unmasked pair."""
+    b, l = seg.shape
+    mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0)
+    if causal:
+        mask &= np.tril(np.ones((l, l), bool))[None]
+    return mask.reshape(b, l // bq, bq, l // bk, bk).any(axis=(2, 4))
+
+
+def visited_blocks(ranges, n_q, n_k):
+    """The same shape from ``block_ranges``' two answers: what the
+    forward and dq loops visit, and what the dkv loop visits."""
+    (kv_lo, kv_hi), (q_lo, q_hi) = [
+        [np.asarray(x) for x in r] for r in ranges]
+    kj, qi = np.arange(n_k), np.arange(n_q)
+    by_q = (kv_lo[:, :, None] <= kj) & (kj < kv_hi[:, :, None])
+    by_k = (q_lo[:, None, :] <= qi[:, None]) & (qi[:, None] < q_hi[:, None, :])
+    return by_q, by_k
+
+
+def _hull(needed, axis):
+    """True from the first to the last True along ``axis``."""
+    after_first = np.maximum.accumulate(needed, axis=axis)
+    before_last = np.flip(np.maximum.accumulate(
+        np.flip(needed, axis), axis=axis), axis)
+    return after_first & before_last
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("kind", ["none", "tail", "between", "both"])
+@pytest.mark.parametrize("bq,bk,align", [(32, 64, 64), (64, 32, 64),
+                                         (32, 64, 1), (64, 64, 8)])
+def test_block_ranges_against_brute_force(bq, bk, align, kind, causal):
+    """No block that holds an unmasked pair is left out, and where the
+    segments' boundaries fall on the blocks nothing else is visited."""
+    l = 512
+    rng = np.random.default_rng(abs(hash((bq, bk, align, kind))) % 2**31)
+    seg = packed_rows(rng, l, align, kind)
+    needed = needed_blocks(seg, bq, bk, causal)
+    assert not needed[3].any() and needed[:3].any()
+
+    on_host = fa.block_ranges(seg, bq, bk, causal, xp=np)
+    in_program = jax.jit(fa.block_ranges, static_argnums=(1, 2, 3))(
+        jnp.asarray(seg), bq, bk, causal)
+    for a, b in zip(jax.tree.leaves(on_host), jax.tree.leaves(in_program)):
+        assert a.dtype == b.dtype == np.int32
+        np.testing.assert_array_equal(a, np.asarray(b))
+
+    for visited in visited_blocks(on_host, l // bq, l // bk):
+        assert not (needed & ~visited).any()
+        assert not visited[3].any()  # padding alone: an empty range
+        if align % max(bq, bk) == 0:
+            np.testing.assert_array_equal(visited, needed)
+    # wherever the boundaries fall, a range runs from the first to the
+    # last block that is needed, no further
+    by_q, by_k = visited_blocks(on_host, l // bq, l // bk)
+    np.testing.assert_array_equal(by_q, _hull(needed, axis=2))
+    np.testing.assert_array_equal(by_k, _hull(needed, axis=1))
+
+
+BENCHMARK_ROWS = {  # cell: (sequences a row, their length, row, counts)
+    "qwen2.5-0.5b.sft": (4, 1024, 4096, (24, 72)),
+    "qwen2.5-0.5b.grpo": (8, 512, 4096, (16, 72)),
+    "mistral-7b-v0.3-l4.grpo-realloc": (4, 512, 2048, (8, 20)),
+    "olmoe-1b-7b-0125-l1.sft-2k": (1, 2048, 2048, (20, 20)),
+}
+
+
+@pytest.mark.parametrize("cell", BENCHMARK_ROWS)
+def test_block_counts_of_the_benchmark_rows(cell):
+    n, length, row, want = BENCHMARK_ROWS[cell]
+    seg = np.repeat(np.arange(1, n + 1, dtype=np.int32), length)
+    assert seg.shape == (row,)
+    assert fa.block_counts(seg[None]) == want
+    # a stack of microbatches of rows counts each row
+    assert fa.block_counts(np.tile(seg, (3, 2, 1))) == (
+        6 * want[0], 6 * want[1])
+
+
+def _dense_ranges(seg, bq, bk, causal=True, ranges=fa.block_ranges):
+    """The ranges of rows of ONE segment: every key block up to the
+    causal diagonal (or the row's end), as before the ranges existed."""
+    return ranges(jnp.ones_like(seg), bq, bk, causal)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("kind,align", [("both", 1), ("tail", 64),
+                                        ("between", 8)])
+def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch):
+    """Forward and all three gradients with the loops bounded by the
+    segments' ranges are bitwise what the same kernels give when they
+    visit every block, and within the XLA path's tolerances."""
+    rng = np.random.default_rng(7)
+    b, l, nq, nkv, hd = 3, 256, 2, 1, 32  # a small grid: interpreted
+    seg_np = packed_rows(rng, l, align, kind)[1:]
+    seg = jnp.asarray(seg_np)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((b, l, n, hd)),
+                              jnp.float32) for n in (nq, nkv, nkv, nq))
+    valid = jnp.asarray(seg_np != 0)[..., None, None]
+
+    def run(attn):
+        def loss(q, k, v):
+            out = attn(q, k, v, seg, causal=causal)
+            return (jnp.where(valid, out, 0.0) * w).sum(), out
+        # one program: interpreted grid steps run op by op otherwise
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return [np.asarray(x) for x in (out,) + grads]
+
+    flash = functools.partial(fa.flash_attention, block_q=32, block_k=64)
+    with pltpu.force_tpu_interpret_mode():
+        by_segment = run(flash)
+        with monkeypatch.context() as patch:
+            patch.setattr(fa, "block_ranges", _dense_ranges)
+            dense = run(flash)
+    reference = run(packed_attention_xla)
+    # fewer blocks were visited than the dense loops visit
+    visited, diagonal = fa.block_counts(seg_np, 32, 64)
+    assert visited < diagonal
+    for name, got, same, ref in zip(("out", "dq", "dk", "dv"), by_segment,
+                                    dense, reference):
+        assert np.array_equal(got, same), name
+        keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
+        np.testing.assert_allclose(got[keep], ref[keep], rtol=5e-3,
+                                   atol=5e-3, err_msg=name)
