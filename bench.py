@@ -487,8 +487,7 @@ def bench_sft():
         axis=1)
     mb = dict(input_ids=ids, seg_ids=seg)
 
-    def loss_fn(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+    def loss_fn(p, h, mb):
         lp = F.shifted_logprobs_from_hidden(
             cfg, p, h, mb["input_ids"], mb["seg_ids"])
         seg_ = mb["seg_ids"]

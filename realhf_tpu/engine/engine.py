@@ -20,7 +20,7 @@ All methods consume/produce device arrays in [S, L] stream layout;
 the algorithm interfaces do SequenceSample <-> stream packing.
 """
 
-import os
+import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -49,7 +49,9 @@ from realhf_tpu.parallel.realloc import offload_to_host
 
 logger = logging.getLogger("engine")
 
-LossFn = Callable[[Any, Dict[str, jnp.ndarray]],
+# (params, hidden [B, L, H] after the final norm, microbatch)
+#   -> (loss, statistics): a head and an objective (Engine._objective)
+LossFn = Callable[[Any, jnp.ndarray, Dict[str, jnp.ndarray]],
                   Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]]
 
 
@@ -98,8 +100,7 @@ class Engine:
         # instruction streams, custom-VJP backward, bounded residuals)
         # with GPipe (parallel/pipeline.py) as the selectable fallback;
         # inference-only forwards always use the GPipe rotation (see
-        # pipeline_ctx_infer -- there is no backward to schedule and
-        # the rotation scan saves nothing).
+        # _forward).
         if ctx.pp_size > 1:
             from realhf_tpu.parallel.pipeline import PipelineContext
             from realhf_tpu.parallel.schedule import default_microbatches
@@ -116,19 +117,19 @@ class Engine:
                 or "1f1b"
             n_mb = ctx.parallel.pipeline_microbatches \
                 or default_microbatches(ctx.pp_size, sched)
-            self.pipeline_ctx = PipelineContext(
+            self._pipeline_ctx = PipelineContext(
                 mesh=self.mesh, n_stages=ctx.pp_size,
                 n_microbatches=n_mb, schedule=sched)
         else:
-            self.pipeline_ctx = None
+            self._pipeline_ctx = None
 
         # Expert parallelism: expert weights E-sharded over the data
         # axis; this constraint turns dispatch/combine into all-to-alls
         # (models/sharding.py moe_ep_constraint). Validated BEFORE the
         # device_put below so invalid configs fail instantly with a
         # clear message instead of after a full-model transfer.
-        self.moe_constraint = shard_rules.moe_ep_constraint(cfg, self.mesh)
-        if self.moe_constraint is not None:
+        self._moe_constraint = shard_rules.moe_ep_constraint(cfg, self.mesh)
+        if self._moe_constraint is not None:
             if moe_ops.dispatch_mode(cfg) == "ragged":
                 raise ValueError(
                     "MoEConfig.expert_parallel requires the capacity "
@@ -153,7 +154,6 @@ class Engine:
             self.mesh, ctx.parallel.sequence_parallel)
         # Context parallelism: attention becomes a ring over the "ctx"
         # mesh axis; the rest of the model shards L via GSPMD.
-        self.attention_fn_inference = None
         # whether packed rows go to the flash kernel (their length
         # decides the rest, call by call): what flash_kv_blocks_total
         # counts. The ring and the pipeline's XLA path do not.
@@ -168,32 +168,7 @@ class Engine:
                                       causal=causal, scale=scale,
                                       sliding_window=sliding_window)
 
-            self.attention_fn = _ring
-            # REALHF_TPU_FUSED_RING=1: single-Pallas-kernel ring with
-            # the KV RDMA overlapped against flash compute
-            # (ops/ring_attention_fused.py) -- INFERENCE jits only:
-            # training keeps the shard_map formulation because a
-            # side-effecting kernel cannot live inside the
-            # jax.checkpoint regions gradient_checkpointing wraps
-            # around every block. Off by default until validated on
-            # multi-chip hardware; on CPU it runs the interpret-mode
-            # emulation (CI wiring coverage).
-            if os.environ.get("REALHF_TPU_FUSED_RING") == "1":
-                from realhf_tpu.ops.ring_attention_fused import (
-                    ring_attention_fused,
-                )
-                # interpret-mode emulation only where the mesh itself
-                # is not made of TPU devices
-                interp = mesh.devices.flat[0].platform != "tpu"
-
-                def _ring_fused(q, k, v, seg, causal=True, scale=None,
-                                sliding_window=None):
-                    return ring_attention_fused(
-                        q, k, v, seg, mesh, "ctx", causal=causal,
-                        scale=scale, sliding_window=sliding_window,
-                        interpret=interp)
-
-                self.attention_fn_inference = _ring_fused
+            self._attention_fn = _ring
         elif _pallas_enabled() and _mesh_nontrivial(self.mesh):
             if ctx.pp_size > 1:
                 # Inside the pipe-manual shard_map a bare pallas_call
@@ -207,7 +182,7 @@ class Engine:
                         q, k, v, seg, causal=causal, scale=scale,
                         sliding_window=sliding_window)
 
-                self.attention_fn = _xla_attn
+                self._attention_fn = _xla_attn
             else:
                 # Partition the Pallas flash kernel over dp x tp: a
                 # bare pallas_call has no GSPMD rule and would gather
@@ -216,10 +191,10 @@ class Engine:
                 from realhf_tpu.ops.attention import (
                     make_sharded_attention,
                 )
-                self.attention_fn = make_sharded_attention(self.mesh)
+                self._attention_fn = make_sharded_attention(self.mesh)
                 self._flash_rows = True
         else:
-            self.attention_fn = None
+            self._attention_fn = None
             self._flash_rows = _pallas_enabled()
 
         # which dispatch a sparse model's programs take: on every
@@ -434,49 +409,81 @@ class Engine:
         return self._replicated_sharding if self._multiproc else None
 
     @property
-    def _infer_attention_fn(self):
-        """Attention for the inference-only jits (forward_hidden /
-        forward_logprobs / forward_values): the fused-RDMA ring when
-        enabled, else the same train-safe fn the loss closures
-        capture. Generation never sees it -- on a ctx mesh it runs on
-        the collapsed dp x tp decode view, where no ring exists."""
-        return self.attention_fn_inference or self.attention_fn
-
-    @property
-    def pipeline_ctx_infer(self):
-        """Pipeline context for inference-only forwards: always the
-        GPipe rotation -- with no backward to schedule, the 1F1B
-        machinery (input saving, custom VJP) is pure overhead."""
-        if self.pipeline_ctx is None \
-                or self.pipeline_ctx.schedule == "gpipe":
-            return self.pipeline_ctx
-        import dataclasses as _dc
-        return _dc.replace(self.pipeline_ctx, schedule="gpipe")
-
-    @property
     def n_streams(self) -> int:
         """Preferred [S, L] stream-batch rows: one per dp rank, times
         the pipeline microbatch count when pp > 1 (each pipeline
         microbatch then carries dp streams)."""
-        if self.pipeline_ctx is not None:
-            return self.ctx.dp_size * self.pipeline_ctx.n_microbatches
+        if self._pipeline_ctx is not None:
+            return self.ctx.dp_size * self._pipeline_ctx.n_microbatches
         return self.ctx.dp_size
+
+    # ------------------------------------------------------------------
+    # The model's forward
+    # ------------------------------------------------------------------
+    def _forward(self, params, input_ids, seg_ids, *, train: bool):
+        """Final hidden states [B, L, H] and the auxiliary dict of this
+        engine's model: the one call of ``T.forward`` on an engine's
+        behalf (generation's prefill and decode steps are
+        ``engine/generation.py``'s and ``inflight.py``'s own). Training
+        and inference share the attention function and the
+        expert-parallel constraint, and differ in three ways:
+
+        - the auxiliary dict (a sparse model's router losses and load
+          statistic) is computed for training only; else it is ``{}``;
+        - training takes the mesh's own pipeline schedule (1F1B by
+          default), inference always the GPipe rotation: with no
+          backward, 1F1B's input saving and custom VJP are overhead;
+        - inference constrains the residual stream's sharding,
+          training does NOT. Nobody chose that: ROADMAP D14.
+        """
+        with_aux = train and self.cfg.mlp_type == "moe"
+        pipeline = self._pipeline_ctx
+        if train:
+            constrain = None
+        else:
+            constrain = self._constrain
+            if pipeline is not None:
+                pipeline = dataclasses.replace(pipeline, schedule="gpipe")
+        out = T.forward(self.cfg, params, input_ids, seg_ids,
+                        return_aux=with_aux,
+                        activation_constraint=constrain,
+                        attention_fn=self._attention_fn,
+                        moe_constraint=self._moe_constraint,
+                        pipeline=pipeline)
+        return out[0], (out[2] if with_aux else {})
 
     # ------------------------------------------------------------------
     # Training
     # ------------------------------------------------------------------
+    def _objective(self, loss_fn: LossFn) -> Callable:
+        """``(params, microbatch) -> (loss, statistics)``, what a train
+        step differentiates: the forward on the microbatch's
+        ``input_ids`` and ``seg_ids``, ``loss_fn``'s head and objective
+        on its hidden states, and a sparse model's auxiliary losses
+        added to the loss, their entries and the load statistic to the
+        statistics (never by ``loss_fn``)."""
+
+        def objective(params, mb):
+            h, aux = self._forward(params, mb["input_ids"],
+                                   mb["seg_ids"], train=True)
+            loss, stats = loss_fn(params, h, mb)
+            return loss + moe_ops.aux_loss(aux), {**stats, **aux}
+
+        return objective
+
     def _train_step_body(self, loss_fn: LossFn) -> Callable:
         """The un-jitted one-optimizer-step body shared by
         ``_build_train_step`` (one minibatch per dispatch) and
         ``_build_train_seq`` (a lax.scan over minibatches inside one
         dispatch)."""
+        objective = self._objective(loss_fn)
 
         def train_step(params, opt_state, mbs: Dict[str, jnp.ndarray],
                        mb_weights: jnp.ndarray):
             """mbs: dict of stacked arrays with leading dim n_mbs;
             mb_weights: [n_mbs] relative weight (e.g. token counts) used
             to average gradients exactly as one large batch would."""
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+            grad_fn = jax.value_and_grad(objective, has_aux=True)
             zero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             if self._grad_shardings is not None:
@@ -585,6 +592,9 @@ class Engine:
 
         All microbatches must share array shapes (the packer pads them
         to a common bucket); they are stacked and scanned on-device.
+        Every microbatch holds ``input_ids`` and ``seg_ids`` [S, L]:
+        the engine runs the model on them and hands ``loss_fn`` the
+        final hidden states (:meth:`_objective`).
 
         ``loss_fn_key`` caches the compiled step: it MUST uniquely
         identify the loss closure INCLUDING every hyperparameter the
@@ -711,11 +721,7 @@ class Engine:
     def forward_hidden(self, input_ids, seg_ids):
         if self._jit_forward_hidden is None:
             def hidden(params, ids, seg):
-                h, _ = T.forward(self.cfg, params, ids, seg,
-                                 activation_constraint=self._constrain,
-                                 attention_fn=self._infer_attention_fn,
-                                 moe_constraint=self.moe_constraint,
-                                 pipeline=self.pipeline_ctx_infer)
+                h, _ = self._forward(params, ids, seg, train=False)
                 return h
             self._jit_forward_hidden = jax.jit(
                 hidden, out_shardings=self._out_replicated())
@@ -730,11 +736,7 @@ class Engine:
         on actor/ref models, ppo_interface.py:255)."""
         if self._jit_logprobs is None:
             def logprobs(params, ids, seg, mask, temp, has_mask):
-                h, _ = T.forward(self.cfg, params, ids, seg,
-                                 activation_constraint=self._constrain,
-                                 attention_fn=self._infer_attention_fn,
-                                 moe_constraint=self.moe_constraint,
-                                 pipeline=self.pipeline_ctx_infer)
+                h, _ = self._forward(params, ids, seg, train=False)
                 return F.shifted_logprobs_from_hidden(
                     self.cfg, params, h, ids, seg, temperature=temp,
                     logits_mask=mask if has_mask else None)
@@ -755,11 +757,7 @@ class Engine:
         assert self.cfg.is_critic
         if self._jit_values is None:
             def values(params, ids, seg):
-                h, _ = T.forward(self.cfg, params, ids, seg,
-                                 activation_constraint=self._constrain,
-                                 attention_fn=self._infer_attention_fn,
-                                 moe_constraint=self.moe_constraint,
-                                 pipeline=self.pipeline_ctx_infer)
+                h, _ = self._forward(params, ids, seg, train=False)
                 return T.critic_values(self.cfg, params, h)
             self._jit_values = jax.jit(
                 values, out_shardings=self._out_replicated())
@@ -780,16 +778,19 @@ class Engine:
         reference streams tokens through PP stages instead
         (``pipe_runner.py:847``, ``static_schedule.py:195``
         GenerateSchedule). The TPU-first equivalent: reshard the weights
-        onto a collapsed dp x tp mesh over the SAME devices (one
-        cross-mesh ``device_put`` riding ICI, amortized over the whole
-        rollout and refreshed only when the weights change) and run the
-        fast dp/tp decode there. ``ParallelismConfig.gen_tp_size``
+        onto a collapsed dp x tp mesh over the SAME devices (amortized
+        over the whole rollout and refreshed only when the weights
+        change) and run the fast dp/tp decode there. The move is one
+        cross-mesh ``device_put`` (``set_params``), which jax 0.9.0
+        takes leaf by leaf through the host: 0.49 GB/s on the v5e
+        (PERF.md, PR 25), not the interconnect's rate (ROADMAP D13).
+        ``ParallelismConfig.gen_tp_size``
         ("g" in the allocation shorthand, e.g. ``d2t2p2g4``) picks the
         decode tensor-parallel degree; default is the train tp, giving
         pp*dp-way decode data parallelism for free.
         """
         gen_tp = self.ctx.parallel.gen_tp_size or self.ctx.tp_size
-        if (self.pipeline_ctx is None
+        if (self._pipeline_ctx is None
                 and self.ctx.parallel.context_parallel_size == 1
                 and gen_tp == self.ctx.tp_size):
             return self
@@ -865,9 +866,8 @@ class Engine:
                 f"{ndev} devices.")
         if gen_tp == self.ctx.parallel.gen_tp_size:
             return
-        import dataclasses as _dc
-        self.ctx.parallel = _dc.replace(self.ctx.parallel,
-                                        gen_tp_size=gen_tp)
+        self.ctx.parallel = dataclasses.replace(self.ctx.parallel,
+                                                gen_tp_size=gen_tp)
         self._decode_view = None
         self._decode_view_src = None
 
@@ -884,9 +884,9 @@ class Engine:
             self._generate_cache[cache_key] = gen_mod.build_generate_fn(
                 self.cfg, gconfig, eos_token_id, pad_token_id,
                 activation_constraint=self._constrain,
-                moe_constraint=self.moe_constraint,
+                moe_constraint=self._moe_constraint,
                 out_sharding=self._out_replicated(),
-                mesh=self.mesh, attention_fn=self.attention_fn)
+                mesh=self.mesh, attention_fn=self._attention_fn)
         fn = self._generate_cache[cache_key]
         attrs = self._count_batch(
             prompt_seg,
@@ -895,6 +895,18 @@ class Engine:
             (prompt_ids, prompt_seg, prompt_pos, key))
         return self._run("generate", fn, attrs, self.params, ids, seg,
                          pos, key)
+
+    def inflight_generator(self, gconfig: GenerationHyperparameters,
+                           **kwargs):
+        """A continuous-batching generator (``engine/inflight.py``) on
+        this engine's mesh, weights and attention; ``kwargs`` are the
+        generator's own (slots, prompt length, end and pad tokens).
+        Call it on :meth:`decode_engine`."""
+        from realhf_tpu.engine.inflight import InflightBatchingGenerator
+        return InflightBatchingGenerator(
+            self.cfg, self.params, gconfig,
+            moe_constraint=self._moe_constraint, mesh=self.mesh,
+            attention_fn=self._attention_fn, **kwargs)
 
     # ------------------------------------------------------------------
     def _cast_param_dtype(self, params):

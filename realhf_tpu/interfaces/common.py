@@ -15,7 +15,6 @@ import numpy as np
 from realhf_tpu.api.data import SequenceSample
 from realhf_tpu.base.datapack import flat2d
 from realhf_tpu.engine import packing
-from realhf_tpu.ops.moe import aux_loss  # noqa: F401  (for the loss fns)
 
 
 def seqlens_of(input_: SequenceSample, key: str = "packed_input_ids") -> List[int]:
@@ -71,29 +70,6 @@ def split_minibatches(input_: SequenceSample, n: int,
     if n <= 1:
         return [input_]
     return input_.split(n, min_size=min_size)
-
-
-def forward_with_aux(cfg, params, input_ids, seg_ids, attention_fn=None,
-                     pipeline=None, moe_constraint=None):
-    """Model forward returning (hidden, aux dict). For MoE models
-    the dict carries router load-balancing/z losses that MUST be added
-    to the training objective through ``aux_loss(aux)`` (the reference
-    applies them automatically via MoEAuxLossAutoScaler,
-    utils/moe.py:395), and the load statistic, which goes into the
-    step's statistics with them (``**aux``) and never into the loss;
-    dense models return an empty dict. ``pipeline`` is the engine's
-    PipelineContext when the model mesh is pipeline-parallel;
-    ``moe_constraint`` is the engine's expert-parallel sharding hook."""
-    from realhf_tpu.models import transformer as _T
-    if cfg.mlp_type == "moe":
-        h, _, aux = _T.forward(cfg, params, input_ids, seg_ids,
-                               return_aux=True, attention_fn=attention_fn,
-                               moe_constraint=moe_constraint,
-                               pipeline=pipeline)
-        return h, aux
-    h, _ = _T.forward(cfg, params, input_ids, seg_ids,
-                      attention_fn=attention_fn, pipeline=pipeline)
-    return h, {}
 
 
 def run_train_microbatched(engine, sample: SequenceSample, build_sb,

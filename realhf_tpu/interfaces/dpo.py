@@ -18,7 +18,6 @@ from realhf_tpu.api import model as model_api
 from realhf_tpu.api.data import SequenceSample
 from realhf_tpu.base import logging
 from realhf_tpu.interfaces import common
-from realhf_tpu.models import transformer as T
 from realhf_tpu.ops import functional as F
 
 logger = logging.getLogger("DPOInterface")
@@ -37,13 +36,9 @@ def _answer_masks(sb: common.StreamBatch, seqlens: List[int],
     return mask
 
 
-def _make_loss_fn(cfg, n_seqs: int, beta: float, attention_fn=None,
-                  pipeline=None, moe_constraint=None):
+def _make_loss_fn(cfg, n_seqs: int, beta: float):
 
-    def loss_fn(params, mb):
-        h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                         mb["seg_ids"], attention_fn,
-                                         pipeline, moe_constraint)
+    def loss_fn(params, h, mb):
         lp = F.shifted_logprobs_from_hidden(
             cfg, params, h, mb["input_ids"], mb["seg_ids"])
         masked = (lp * mb["answer_mask"]).reshape(-1)
@@ -60,9 +55,8 @@ def _make_loss_fn(cfg, n_seqs: int, beta: float, attention_fn=None,
         pos_score = (beta * (pi_pos - ref_pos) * valid).sum() / denom
         neg_score = (beta * (pi_neg - ref_neg) * valid).sum() / denom
         kl = (-(pi_pos - ref_pos + pi_neg - ref_neg) * valid).sum() / denom
-        return loss + common.aux_loss(aux), {
-            "loss": loss, "pos_score": pos_score,
-            "neg_score": neg_score, "kl": kl, **aux}
+        return loss, {"loss": loss, "pos_score": pos_score,
+                      "neg_score": neg_score, "kl": kl}
 
     return loss_fn
 
@@ -156,9 +150,7 @@ class DPOInterface(model_api.ModelInterface):
                 b.arrays[k] = np.pad(v, (0, npair - v.shape[0]))
         stats = engine.train_batch(
             [b.arrays for b in batches],
-            _make_loss_fn(model.config, n_seqs_max, self.beta,
-                          engine.attention_fn,
-                          engine.pipeline_ctx, engine.moe_constraint),
+            _make_loss_fn(model.config, n_seqs_max, self.beta),
             loss_weights=weights, loss_fn_key=("dpo", n_seqs_max, self.beta))
         model.inc_version()
         return stats

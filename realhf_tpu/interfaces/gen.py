@@ -68,9 +68,6 @@ class GenerationInterface(model_api.ModelInterface):
             # version, engine.decode_engine) -- same path the batch
             # generate takes.
             eng = model.engine.decode_engine()
-            from realhf_tpu.engine.inflight import (
-                InflightBatchingGenerator,
-            )
             from realhf_tpu.engine.inflight import _bucket
             # bucket the cache size so slowly-growing prompt lengths
             # reuse the compiled decode/prefill programs instead of
@@ -85,15 +82,10 @@ class GenerationInterface(model_api.ModelInterface):
                 # than the first one sized the cache for, or (with
                 # inflight_slots=0 = "track batch size") a different
                 # prompt count than the slots were built for
-                self._inflight = InflightBatchingGenerator(
-                    model.config, eng.params, self.gconfig,
-                    n_slots=n_slots,
-                    max_prompt_len=need,
+                self._inflight = eng.inflight_generator(
+                    self.gconfig, n_slots=n_slots, max_prompt_len=need,
                     eos_token_id=tok.eos_token_id,
-                    pad_token_id=tok.pad_token_id,
-                    moe_constraint=eng.moe_constraint,
-                    mesh=eng.mesh,
-                    attention_fn=eng.attention_fn)
+                    pad_token_id=tok.pad_token_id)
             self._inflight.params = eng.params  # fresh weights
             finished = self._inflight.generate_all(prompts, key)
             # do not pin the weights pytree (train_batch donates its

@@ -195,16 +195,10 @@ class GRPOInterface(PPOActorInterface):
         temperature = self.gconfig.temperature
         eps_clip = self.eps_clip
         kl_coef = self.kl_coef
-        attention_fn = engine.attention_fn
-        pipeline = engine.pipeline_ctx
-        moe_constraint = engine.moe_constraint
 
-        def loss_fn(params, mb):
+        def loss_fn(params, h, mb):
             import jax.numpy as jnp
             from realhf_tpu.ops import functional as F
-            h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                             mb["seg_ids"], attention_fn,
-                                             pipeline, moe_constraint)
             lp = F.shifted_logprobs_from_hidden(
                 cfg, params, h, mb["input_ids"], mb["seg_ids"],
                 temperature=temperature)
@@ -218,11 +212,10 @@ class GRPOInterface(PPOActorInterface):
             diff = mb["ref_logp"] - lp
             kl = (jnp.where(m > 0, jnp.exp(diff) - diff - 1.0, 0.0)).sum() \
                 / jnp.maximum(m.sum(), 1.0)
-            total = loss + kl_coef * kl + common.aux_loss(aux)
-            return total, dict(
+            return loss + kl_coef * kl, dict(
                 grpo_loss=loss, grpo_kl=kl,
                 importance_weight=stats["importance_weight"],
-                clip_ratio=stats["clip_ratio"], **aux)
+                clip_ratio=stats["clip_ratio"])
 
         def build_sb(minibatch):
             mb_lens = common.flat_seqlens(minibatch)

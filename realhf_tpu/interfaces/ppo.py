@@ -360,17 +360,9 @@ class PPOActorInterface(model_api.ModelInterface):
         eps_clip = self.eps_clip
         early_kl = self.early_stop_kl
         early_imp = self.early_stop_imp_ratio
-
-        attention_fn = engine.attention_fn
-        pipeline = engine.pipeline_ctx
-        moe_constraint = engine.moe_constraint
-
         is_clip = self.staleness_is_clip
 
-        def loss_fn(params, mb):
-            h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                             mb["seg_ids"], attention_fn,
-                                             pipeline, moe_constraint)
+        def loss_fn(params, h, mb):
             lmask = mb.get("logits_mask")
             lp = F.shifted_logprobs_from_hidden(
                 cfg, params, h, mb["input_ids"], mb["seg_ids"],
@@ -415,10 +407,10 @@ class PPOActorInterface(model_api.ModelInterface):
                 ppo_approx_kl=stats["approx_kl"],
                 actor_clip_ratio=stats["clip_ratio"],
                 importance_weight=stats["importance_weight"],
-                **stale_stats, **aux)
+                **stale_stats)
             if early_imp is not None or early_kl is not None:
                 out_stats["__skip_update__"] = skip
-            return loss + common.aux_loss(aux), out_stats
+            return loss, out_stats
 
         loss_key = ("ppo_actor", has_mask, temperature, eps_clip,
                     early_kl, early_imp, has_stale, is_clip)
@@ -597,22 +589,15 @@ class PPOCriticInterface(model_api.ModelInterface):
         cfg = model.config
         eps = self.value_eps_clip
 
-        attention_fn = engine.attention_fn
-        pipeline = engine.pipeline_ctx
-        moe_constraint = engine.moe_constraint
-
-        def loss_fn(params, mb):
-            h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                             mb["seg_ids"], attention_fn,
-                                             pipeline, moe_constraint)
+        def loss_fn(params, h, mb):
             new_values = T.critic_values(cfg, params, h)
             loss, stats = ppo_functional.critic_loss_fn(
                 value=new_values, old_value=mb["old_values"],
                 target_value=mb["returns"], value_eps_clip=eps,
                 loss_mask=mb["loss_mask"] > 0)
-            return loss + common.aux_loss(aux), dict(
+            return loss, dict(
                 value_loss=loss,
-                value_clip_ratio=stats["value_clip_ratio"], **aux)
+                value_clip_ratio=stats["value_clip_ratio"])
 
         def build_sb(minibatch):
             mb_lens = common.flat_seqlens(minibatch)

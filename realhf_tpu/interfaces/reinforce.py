@@ -159,25 +159,19 @@ class ReinforceInterface(PPOActorInterface):
         cfg = model.config
         temperature = self.gconfig.temperature
         kl_coef = self.kl_coef
-        attention_fn = engine.attention_fn
-        pipeline = engine.pipeline_ctx
-        moe_constraint = engine.moe_constraint
 
-        def loss_fn(params, mb):
+        def loss_fn(params, h, mb):
             import jax.numpy as jnp
 
             from realhf_tpu.ops import functional as F
-            h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                             mb["seg_ids"], attention_fn,
-                                             pipeline, moe_constraint)
             lp = F.shifted_logprobs_from_hidden(
                 cfg, params, h, mb["input_ids"], mb["seg_ids"],
                 temperature=temperature)
             m = mb["loss_mask"]
             denom = jnp.maximum(m.sum(), 1.0)
             pg = -(mb["advantages"] * lp * m).sum() / denom
-            total = pg + common.aux_loss(aux)
-            stats = dict(reinforce_loss=pg, **aux)
+            total = pg
+            stats = dict(reinforce_loss=pg)
             if has_ref:
                 diff = mb["ref_logp"] - lp
                 kl = (jnp.where(m > 0, jnp.exp(diff) - diff - 1.0,

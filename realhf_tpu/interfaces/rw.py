@@ -25,13 +25,9 @@ from realhf_tpu.models import transformer as T
 logger = logging.getLogger("PairedRewardInterface")
 
 
-def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
-                  moe_constraint=None):
+def _make_loss_fn(cfg):
 
-    def loss_fn(params, mb):
-        h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                         mb["seg_ids"], attention_fn,
-                                         pipeline, moe_constraint)
+    def loss_fn(params, h, mb):
         values = T.critic_values(cfg, params, h)  # [S, L]
         # Gather per-pair (pos, neg) end-of-sequence scores via (row,
         # col) coordinates (stable under stream padding), plus a pair
@@ -43,12 +39,11 @@ def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
         losses = -jax.nn.log_sigmoid(pos - neg)
         loss = (losses * valid).sum() / denom
         acc = ((pos > neg) & (valid > 0)).sum() / denom
-        return loss + common.aux_loss(aux), {
+        return loss, {
             "loss": loss,
             "acc": acc.astype(jnp.float32),
             "pos_score": (pos * valid).sum() / denom,
             "neg_score": (neg * valid).sum() / denom,
-            **aux,
         }
 
     return loss_fn
@@ -140,8 +135,7 @@ class PairedRewardInterface(model_api.ModelInterface):
                 b.arrays[k] = np.pad(v, (0, npair - v.shape[0]))
         stats = engine.train_batch(
             [b.arrays for b in batches],
-            _make_loss_fn(model.config, engine.attention_fn,
-                          engine.pipeline_ctx, engine.moe_constraint),
+            _make_loss_fn(model.config),
             loss_weights=weights, loss_fn_key="paired_rw")
         model.inc_version()
         return stats

@@ -15,19 +15,14 @@ from realhf_tpu.api import model as model_api
 from realhf_tpu.api.data import SequenceSample
 from realhf_tpu.base import logging
 from realhf_tpu.interfaces import common
-from realhf_tpu.models import transformer as T
 from realhf_tpu.ops import functional as F
 
 logger = logging.getLogger("SFTInterface")
 
 
-def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
-                  moe_constraint=None):
+def _make_loss_fn(cfg):
 
-    def loss_fn(params, mb):
-        h, aux = common.forward_with_aux(cfg, params, mb["input_ids"],
-                                         mb["seg_ids"], attention_fn,
-                                         pipeline, moe_constraint)
+    def loss_fn(params, h, mb):
         lp = F.shifted_logprobs_from_hidden(
             cfg, params, h, mb["input_ids"], mb["seg_ids"])
         # loss_mask[t] gates predicting token t+1: valid next-token
@@ -43,9 +38,7 @@ def _make_loss_fn(cfg, attention_fn=None, pipeline=None,
         mask = next_same & ~next_is_prompt
         denom = jnp.maximum(mask.sum(), 1)
         nll = -(lp * mask).sum() / denom
-        loss = nll + common.aux_loss(aux)
-        return loss, {"nll": nll, "n_tokens": denom.astype(jnp.float32),
-                      **aux}
+        return nll, {"nll": nll, "n_tokens": denom.astype(jnp.float32)}
 
     return loss_fn
 
@@ -78,8 +71,7 @@ class SFTInterface(model_api.ModelInterface):
             weights = [float(b.n_tokens) for b in batches]
         stats = engine.train_batch(
             [b.arrays for b in batches],
-            _make_loss_fn(model.config, engine.attention_fn,
-                          engine.pipeline_ctx, engine.moe_constraint),
+            _make_loss_fn(model.config),
             loss_weights=weights, loss_fn_key="sft")
         model.inc_version()
         return stats

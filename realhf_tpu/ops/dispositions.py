@@ -22,7 +22,6 @@ KERNELS = (
     "flash_attention",               # ops/flash_attention.py (packed fwd/bwd)
     "flash_decode_attention",        # ops/decode_attention.py per-layer
     "flash_decode_attention_stacked",  # scalar-prefetch stacked decode
-    "ring_attention_fused",          # ops/ring_attention_fused.py
 )
 
 
@@ -53,15 +52,4 @@ def kernel_dispositions() -> Dict[str, Dict[str, Any]]:
     returns {kernel: {mode, engaged, reason}} (keys sorted for a
     stable payload diff)."""
     base = _base_mode()
-    out: Dict[str, Dict[str, Any]] = {k: dict(base) for k in KERNELS}
-
-    # The fused ring kernel has one extra gate: an explicit opt-in
-    # (validated-on-silicon policy).
-    fused = out["ring_attention_fused"]
-    if os.environ.get("REALHF_TPU_FUSED_RING") != "1":
-        fused.update(mode="xla", engaged=False,
-                     reason="REALHF_TPU_FUSED_RING unset (kernel is "
-                            "opt-in until validated on multi-chip "
-                            "hardware); shard_map ring runs instead")
-
-    return {k: out[k] for k in sorted(out)}
+    return {k: dict(base) for k in sorted(KERNELS)}

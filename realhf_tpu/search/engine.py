@@ -534,8 +534,7 @@ def calibrate_cost_model(
         seg = np.ones_like(ids)
         mb = dict(input_ids=ids, seg_ids=seg)
 
-        def loss_fn(p, mb):
-            h, _ = T.forward(probe, p, mb["input_ids"], mb["seg_ids"])
+        def loss_fn(p, h, mb):
             lp = F.shifted_logprobs_from_hidden(
                 probe, p, h, mb["input_ids"], mb["seg_ids"])
             return -lp.mean(), {}

@@ -102,8 +102,7 @@ class TestTrainEngine:
             return [dict(input_ids=ids[i], seg_ids=seg[i]) for i in range(2)]
         mbs = batch()
 
-        def loss_fn(params, mb):
-            h, _ = T.forward(cfg, params, mb["input_ids"], mb["seg_ids"])
+        def loss_fn(params, h, mb):
             lp = F.shifted_logprobs_from_hidden(
                 cfg, params, h, mb["input_ids"], mb["seg_ids"])
             valid = jnp.concatenate(
@@ -124,8 +123,7 @@ class TestTrainEngine:
         ids = rng.integers(0, 64, size=(4, 16)).astype(np.int32)
         seg = np.ones((4, 16), np.int32)
 
-        def loss_fn(params, mb):
-            h, _ = T.forward(cfg, params, mb["input_ids"], mb["seg_ids"])
+        def loss_fn(params, h, mb):
             lp = F.shifted_logprobs_from_hidden(
                 cfg, params, h, mb["input_ids"], mb["seg_ids"])
             valid = mb["seg_ids"][:, 1:] != 0

@@ -45,8 +45,7 @@ def make_engine(cfg, seed=0):
 
 
 def sft_loss(cfg):
-    def loss_fn(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+    def loss_fn(p, h, mb):
         lp = F.shifted_logprobs_from_hidden(cfg, p, h, mb["input_ids"],
                                             mb["seg_ids"])
         return -lp.mean(), {"nll": -lp.mean()}
@@ -108,8 +107,7 @@ class TestFusedMinibatchParity:
         # __skip_update__ stat
         cfg = tiny_cfg()
 
-        def loss_fn(p, mb):
-            h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+        def loss_fn(p, h, mb):
             lp = F.shifted_logprobs_from_hidden(
                 cfg, p, h, mb["input_ids"], mb["seg_ids"])
             loss = -lp.mean()

@@ -36,8 +36,7 @@ def _engine(cfg, seed=0):
 
 
 def _loss(cfg):
-    def f(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+    def f(p, h, mb):
         return (T.lm_logits(cfg, p, h) ** 2).mean(), {}
     return f
 

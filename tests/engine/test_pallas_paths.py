@@ -148,8 +148,7 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
     ids = rng.integers(1, 120, size=seg.shape).astype(np.int32)
     assert block_counts(seg) == (4 + 2, 6 + 6)
 
-    def loss_fn(params, mb):
-        h, _ = T.forward(cfg, params, mb["input_ids"], mb["seg_ids"])
+    def loss_fn(params, h, mb):
         lp = F.shifted_logprobs_from_hidden(
             cfg, params, h, mb["input_ids"], mb["seg_ids"])
         return -lp.mean(), {}

@@ -90,7 +90,7 @@ class TestDecodeView:
         np.testing.assert_allclose(rl, pl, atol=1e-5)
         view = pp.decode_engine()
         assert view is not pp
-        assert view.pipeline_ctx is None
+        assert view.ctx.pp_size == 1
         assert view.ctx.dp_size == 4 and view.ctx.tp_size == 2
         # second call reuses the cached view (no rebuild)
         assert pp.decode_engine() is view
@@ -166,7 +166,6 @@ class TestDecodeView:
         assert (t0 != t1).any()  # different weights, different tokens
 
     def test_inflight_on_pp_mesh(self):
-        from realhf_tpu.engine.inflight import InflightBatchingGenerator
         cfg = tiny_cfg()
         prompts = prompts_small()
         gcfg = GenerationHyperparameters(
@@ -176,11 +175,9 @@ class TestDecodeView:
             data_parallel_size=2, tensor_parallel_size=2,
             pipeline_parallel_size=2))
         eng = pp.decode_engine()
-        gen = InflightBatchingGenerator(
-            cfg, eng.params, gcfg, n_slots=2, max_prompt_len=16,
-            eos_token_id=None, pad_token_id=0,
-            moe_constraint=eng.moe_constraint, mesh=eng.mesh,
-            attention_fn=eng.attention_fn)
+        gen = eng.inflight_generator(
+            gcfg, n_slots=2, max_prompt_len=16, eos_token_id=None,
+            pad_token_id=0)
         finished = gen.generate_all(prompts, jax.random.PRNGKey(3))
         assert len(finished) == len(prompts)
         ref = make_engine(cfg, ParallelismConfig(

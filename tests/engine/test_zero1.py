@@ -65,8 +65,7 @@ def test_moments_shard_over_dp(dp, tp):
 
 
 def _loss_fn(cfg):
-    def f(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+    def f(p, h, mb):
         logits = T.lm_logits(cfg, p, h)
         tgt = jnp.roll(mb["input_ids"], -1, axis=1)
         lp = jax.nn.log_softmax(logits, -1)

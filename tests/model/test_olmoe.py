@@ -277,6 +277,12 @@ def test_slot_engine_generation_matches_reference(model):
         assert np.abs(out.logprobs - want).max() < LOGPROB_TOL
 
 
+def _sft_objective(cfg, params):
+    """What an SFT train step differentiates: the engine's forward and
+    auxiliary terms around the interface's head and loss."""
+    return _engine(cfg, params)._objective(sft._make_loss_fn(cfg))
+
+
 def _sft_case(model, n_docs, doc_len, prompt_len):
     """One SFT microbatch: (program's loss, stats, gradient under HF's
     names), (reference's loss, parts, gradient)."""
@@ -293,7 +299,7 @@ def _sft_case(model, n_docs, doc_len, prompt_len):
     mb = dict(input_ids=jnp.asarray(ids), seg_ids=jnp.asarray(seg),
               prompt_mask=jnp.asarray(prompt))
     (loss, stats), grads = jax.jit(jax.value_and_grad(
-        sft._make_loss_fn(cfg), has_aux=True))(params, mb)
+        _sft_objective(cfg, params), has_aux=True))(params, mb)
     got = hf_models.params_to_hf(
         "olmoe", jax.tree.map(np.asarray, grads), cfg)
     want = family.sft_loss_and_grad(model["hf"], model["tensors"], docs,
@@ -354,7 +360,8 @@ def test_load_statistic_is_the_reference_routings_worst_expert(model):
     mb = dict(input_ids=jnp.asarray(docs.reshape(1, 64)),
               seg_ids=jnp.asarray(np.repeat([[1, 2]], 32, axis=1)),
               prompt_mask=jnp.zeros((1, 64), bool))
-    _, stats = jax.jit(sft._make_loss_fn(cfg))(model["params"], mb)
+    _, stats = jax.jit(_sft_objective(cfg, model["params"]))(
+        model["params"], mb)
     worst = 0.0
     for layer in range(cfg.n_layers):
         routed, _ = family.top_k_sets(hf, model["tensors"], docs, layer)

@@ -11,8 +11,6 @@ disposition table (ops/dispositions.kernel_dispositions) reports the
 same gates into every BENCH payload.
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -57,8 +55,7 @@ def test_compiled_kernel_matches_reference(kernel):
         # flash_attention has no interpret switch: off-TPU it cannot
         # run at all, which is exactly what the skip above encodes
         got = flash_attention(q, k, v, seg, causal=True)
-    elif kernel in ("flash_decode_attention",
-                    "flash_decode_attention_stacked"):
+    else:  # flash_decode_attention, flash_decode_attention_stacked
         from realhf_tpu.ops.attention import decode_attention
         from realhf_tpu.ops.decode_attention import (
             flash_decode_attention,
@@ -82,26 +79,6 @@ def test_compiled_kernel_matches_reference(kernel):
         else:
             got = flash_decode_attention_stacked(
                 q, ks, vs, valid, jnp.int32(li), interpret=False)
-    else:  # ring_attention_fused
-        from realhf_tpu.ops.ring_attention import ring_attention
-        from realhf_tpu.ops.ring_attention_fused import (
-            ring_attention_fused,
-        )
-        n = min(4, len(jax.devices()))
-        if n < 2:
-            pytest.skip("ring_attention_fused needs >= 2 devices for "
-                        f"the ctx ring; backend exposes {n}")
-        from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()[:n]).reshape(n), ("ctx",))
-        b, l, nq, nkv, hd = 2, 64 * n, 4, 2, 128
-        q = jnp.asarray(rng.standard_normal((b, l, nq, hd)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((b, l, nkv, hd)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((b, l, nkv, hd)), jnp.float32)
-        seg = jnp.asarray(np.ones((b, l), np.int32))
-        ref = jax.jit(lambda *a: ring_attention(
-            *a, mesh=mesh, causal=True))(q, k, v, seg)
-        got = jax.jit(lambda *a: ring_attention_fused(
-            *a, mesh=mesh, causal=True, interpret=False))(q, k, v, seg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-2, rtol=2e-2)
 
@@ -136,11 +113,9 @@ def test_disposition_reflects_backend_and_overrides(monkeypatch):
         assert jax.default_backend() in \
             disp["flash_decode_attention"]["reason"]
 
-    # the fused ring kernel stays opt-in even where pallas engages
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    monkeypatch.delenv("REALHF_TPU_FUSED_RING", raising=False)
     disp = kernel_dispositions()
-    assert not disp["ring_attention_fused"]["engaged"]
+    assert all(d["engaged"] for d in disp.values())
 
 
 def test_disposition_lands_in_bench_payload_shape():

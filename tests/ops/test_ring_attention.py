@@ -33,15 +33,20 @@ def make_inputs(rng, b=2, l=64, nq=4, nkv=2, hd=16):
 
 @pytest.mark.parametrize("n_ctx", [2, 4, 8])
 @pytest.mark.parametrize("causal", [True, False])
-def test_matches_reference(n_ctx, causal):
+# a window shorter than the smallest shard (64 / 8 positions): the mask
+# is on GLOBAL positions, across shard boundaries
+@pytest.mark.parametrize("sliding_window", [None, 5])
+def test_matches_reference(n_ctx, causal, sliding_window):
     rng = np.random.default_rng(0)
     q, k, v, seg = make_inputs(rng)
-    ref = packed_attention_xla(q, k, v, seg, causal=causal)
+    ref = packed_attention_xla(q, k, v, seg, causal=causal,
+                               sliding_window=sliding_window)
     mesh = ctx_mesh(n_ctx)
 
     @jax.jit
     def run(q, k, v, seg):
-        return ring_attention(q, k, v, seg, mesh, "ctx", causal=causal)
+        return ring_attention(q, k, v, seg, mesh, "ctx", causal=causal,
+                              sliding_window=sliding_window)
 
     got = run(q, k, v, seg)
     valid = np.asarray(seg) != 0
@@ -131,9 +136,7 @@ def test_engine_ctx_parallel_matches_and_trains():
                                np.asarray(ref.forward_logprobs(ids, seg)),
                                rtol=1e-4, atol=1e-5)
 
-    def loss_fn(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
-                         attention_fn=eng.attention_fn)
+    def loss_fn(p, h, mb):
         lp = F.shifted_logprobs_from_hidden(cfg, p, h, mb["input_ids"],
                                             mb["seg_ids"])
         return -lp.mean(), {}

@@ -62,8 +62,7 @@ def _train_once(engine, cfg, ids=None, seg=None):
             0, VOCAB, size=(2, 16)).astype(np.int32)
         seg = np.ones_like(ids)
 
-    def loss_fn(p, mb):
-        h, _ = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])
+    def loss_fn(p, h, mb):
         lp = F.shifted_logprobs_from_hidden(cfg, p, h, mb["input_ids"],
                                             mb["seg_ids"])
         return -lp.mean(), {}

@@ -182,8 +182,6 @@ def test_sft_trains_on_pipeline_mesh():
                         lr=1e-3, warmup_steps_proportion=0.0,
                         lr_scheduler_type="constant"),
                     total_train_steps=10)
-    assert engine.pipeline_ctx is not None
-    assert engine.pipeline_ctx.schedule == "1f1b"  # train default
     assert engine.n_streams == 2 * 8  # dp * 4*pp microbatches (1f1b)
     model = model_api.Model(ModelName("actor", 0), engine, None)
 
@@ -226,7 +224,7 @@ def test_generation_on_pipeline_mesh_uses_decode_view():
                           eos_token_id=None, pad_token_id=0)
     assert np.asarray(out.tokens).shape[1] == 4
     view = engine.decode_engine()
-    assert view is not engine and view.pipeline_ctx is None
+    assert view is not engine and view.ctx.pp_size == 1
     assert view.ctx.dp_size == 8 and view.ctx.tp_size == 1
 
 

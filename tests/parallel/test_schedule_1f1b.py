@@ -352,18 +352,27 @@ def test_engine_default_schedule_and_infer_ctx():
     mesh = make_mesh(parallel, devices=jax.devices("cpu")[:2])
     ctx = MeshContext(ModelName("actor", 0), mesh, parallel)
     engine = Engine(cfg, ctx, params)
-    assert engine.pipeline_ctx.schedule == "1f1b"
-    assert engine.pipeline_ctx.n_microbatches == 8  # 4 * pp
-    assert engine.pipeline_ctx_infer.schedule == "gpipe"
-    assert engine.pipeline_ctx_infer.n_microbatches == 8
+
+    def schedule(eng, train):
+        """Which schedule the engine's forward traces: only 1F1B has a
+        backward pipeline of its own (a custom VJP)."""
+        ids = np.ones((eng.n_streams, 8), np.int32)
+        text = str(jax.make_jaxpr(
+            lambda p: eng._forward(p, ids, ids, train=train)[0])(
+                eng.params))
+        return "1f1b" if "custom_vjp_call" in text else "gpipe"
+
+    assert engine.n_streams == 8  # 4 * pp microbatches
+    assert schedule(engine, train=True) == "1f1b"
+    assert schedule(engine, train=False) == "gpipe"
 
     gp = dataclasses.replace(parallel, pipeline_schedule="gpipe")
     engine2 = Engine(cfg, MeshContext(ModelName("actor", 0),
                                       make_mesh(gp, jax.devices("cpu")[:2]),
                                       gp), params)
-    assert engine2.pipeline_ctx.schedule == "gpipe"
-    assert engine2.pipeline_ctx.n_microbatches == 4  # 2 * pp
-    assert engine2.pipeline_ctx_infer is engine2.pipeline_ctx
+    assert engine2.n_streams == 4  # 2 * pp
+    assert schedule(engine2, train=True) == "gpipe"
+    assert schedule(engine2, train=False) == "gpipe"
 
     with pytest.raises(ValueError):
         ParallelismConfig(pipeline_schedule="zigzag")

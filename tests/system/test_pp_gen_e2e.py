@@ -75,7 +75,7 @@ def test_ppo_pp_actor_decode_view(tmp_path):
     view = eng._decode_view
     assert view is not None, "decode view never engaged"
     assert view.ctx.tp_size == 4 and view.ctx.dp_size == 2
-    assert view.pipeline_ctx is None
+    assert view.ctx.pp_size == 1
     # drop_decode_view_after_rollout: the view's weight copy was freed
     # after the last generate MFC (steady-state HBM = one copy)
     assert eng.decode_view_param_bytes() == 0
