@@ -36,6 +36,7 @@ from realhf_tpu.models import sharding as shard_rules
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.obs import metrics, tracing
+from realhf_tpu.ops import decode_attention as decode_ops
 from realhf_tpu.ops import functional as F
 from realhf_tpu.ops import moe as moe_ops
 from realhf_tpu.ops.attention import flash_takes
@@ -262,6 +263,9 @@ class Engine:
         metrics.watch_compiles()
         self._train_step_cache: Dict[Any, Callable] = {}
         self._generate_cache: Dict[Any, Callable] = {}
+        # generate program (its cache key, prompt batch shape) -> what
+        # its compiled text says of the decode loop; see _decode_attrs
+        self._decode_attrs_cache: Dict[Any, Dict[str, Any]] = {}
         # program name -> (jitted fn, abstract args, static kwargs) of
         # its last call; see compiled_text
         self._last_call: Dict[str, tuple] = {}
@@ -354,6 +358,30 @@ class Engine:
         metrics.set_gauge("moe_load_max_over_mean", load,
                           role=str(self.ctx.model_name.role))
         self._last_span.set_attribute(moe_ops.LOAD_STAT, load)
+
+    def _decode_attrs(self, program_key, b: int, lp: int,
+                      max_new_tokens: int) -> Dict[str, Any]:
+        """What the generate program that has just run does with the
+        KV cache inside its decode loop, read ONCE from its compiled
+        text and carried by every ``engine:generate`` span of it:
+        ``decode_kernel`` (``stacked``: the Pallas kernel reads the
+        stacked cache in place; ``xla``: the einsum path on a layer
+        sliced out) and ``decode_layer_copies``
+        (``ops.decode_attention.decode_layer_copies``: 0 where nothing
+        slices or relayouts a layer's cache)."""
+        key = (program_key, b, lp)
+        if key not in self._decode_attrs_cache:
+            text = self.compiled_text("generate")
+            cfg = self.cfg
+            shape = decode_ops.local_layer_shape(
+                self.mesh, b, cfg.n_q_heads, cfg.n_kv_heads,
+                T.round_cache_len(lp + max_new_tokens), cfg.head_dim)
+            self._decode_attrs_cache[key] = dict(
+                decode_kernel=("stacked" if decode_ops.KERNEL_NAME in text
+                               else "xla"),
+                decode_layer_copies=decode_ops.decode_layer_copies(
+                    text, shape))
+        return self._decode_attrs_cache[key]
 
     def compiled_text(self, name: str) -> str:
         """Optimized HLO of the program last run under ``name``
@@ -893,8 +921,12 @@ class Engine:
             decode_tokens=prompt_seg.shape[0] * gconfig.max_new_tokens)
         ids, seg, pos, key = self._globalize_tree(
             (prompt_ids, prompt_seg, prompt_pos, key))
-        return self._run("generate", fn, attrs, self.params, ids, seg,
-                         pos, key)
+        out = self._run("generate", fn, attrs, self.params, ids, seg,
+                        pos, key)
+        for k, v in self._decode_attrs(cache_key, *prompt_seg.shape,
+                                       gconfig.max_new_tokens).items():
+            self._last_span.set_attribute(k, v)
+        return out
 
     def inflight_generator(self, gconfig: GenerationHyperparameters,
                            **kwargs):

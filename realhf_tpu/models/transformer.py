@@ -442,9 +442,11 @@ def critic_values(cfg: TransformerConfig, params: Params,
 # transpose on the hot path. The slot axis is pre-padded to a multiple
 # of the kernel's K block so per-token calls never concat-pad.
 _CACHE_LEN_MULTIPLE = 128
-# Below this depth the decode layer loop is unrolled (static layer
-# indices = free views into the stacked cache); deeper models use a
-# lax.scan with a scalar-prefetch kernel to keep compile time O(1).
+# Below this depth the decode layer loop is unrolled (XLA schedules
+# across layers); deeper models use a lax.scan to keep compile time
+# O(1). Both hand the attention kernel the WHOLE stacked cache and a
+# layer index: a static index into the stack is no free view on the
+# chip (see decode_step).
 _DECODE_UNROLL_MAX_LAYERS = 48
 
 
@@ -528,31 +530,29 @@ def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
 
 def _stacked_decode_attention(q, k_all, v_all, valid, layer_idx, *,
                               scale, sliding_window, slot, mesh=None):
-    """Decode attention against the FULL stacked cache at a traced
-    layer index. TPU: scalar-prefetch Pallas kernel (streams exactly
+    """Decode attention against the FULL stacked cache at
+    ``layer_idx``, a Python int (unrolled layer loop) or a traced
+    scalar (scan). TPU: scalar-prefetch Pallas kernel (streams exactly
     one layer's rows from HBM, no slice copy), shard_map-partitioned
     over dp x tp meshes. A traced scale (deep
     scale_attn_by_inverse_layer_idx models) pre-multiplies q so the
-    kernel still runs with a static scale -- falling back to slicing
-    the layer out would re-materialize a full layer-cache copy per
-    token, the very bottleneck this kernel removes. The XLA slice
-    path remains for CPU tests only."""
+    kernel still runs with a static scale -- slicing the layer out
+    instead re-materializes a full layer-cache copy per token, the
+    very bottleneck this kernel removes. The XLA slice path remains
+    where the kernel does not apply: CPU, heads under 64, a mesh on
+    which neither heads nor cache slots divide (GSPMD partitions the
+    einsums itself)."""
     hd = q.shape[-1]
     if pallas_enabled() and hd >= 64:
         from realhf_tpu.ops.decode_attention import run_decode_kernels
         out = run_decode_kernels(
             mesh, q, (k_all, v_all), valid, slot, layer_idx,
-            stacked=True, scale=scale, sliding_window=sliding_window)
+            scale=scale, sliding_window=sliding_window)
         if out is not None:
             return out
-        # fall through: no kernel partitioning applies; the sliced
-        # decode_attention below re-enters the dispatcher flat, gets
-        # the same None, and takes its GSPMD-partitioned XLA path
-    k_l = jax.lax.dynamic_index_in_dim(k_all, layer_idx, 0, keepdims=False)
-    v_l = jax.lax.dynamic_index_in_dim(v_all, layer_idx, 0, keepdims=False)
-    return decode_attention(q, k_l, v_l, valid, scale=scale,
-                            sliding_window=sliding_window, slot=slot,
-                            mesh=mesh)
+    return decode_attention(q, k_all[layer_idx], v_all[layer_idx], valid,
+                            scale=scale, sliding_window=sliding_window,
+                            slot=slot)
 
 
 def decode_step(
@@ -575,9 +575,15 @@ def decode_step(
     aliases in place inside the decode scan) -- threading them through
     a `lax.scan` as xs/ys would re-materialize the entire cache as a
     fresh stacked output every token, ~3x the roofline's intended HBM
-    traffic. Shallow models unroll the layer loop (static layer index
-    = free view of the stacked cache); deep models scan with a
-    scalar-prefetch attention kernel.
+    traffic. Shallow models unroll the layer loop, deep models scan;
+    either way the scalar-prefetch attention kernel takes the whole
+    stack and the layer index. ``k_all[l]`` at a static ``l`` looks
+    like a free view and is not one on the chip: XLA made it a slice
+    plus a transposing copy of the layer's cache, 2 x 24 times a
+    token on Qwen2.5-0.5B, and chose a slot-minor layout for the
+    stack that made the token's write 13 times dearer; together 43%
+    of the generate program's device time (PERF.md, PR 30). With the
+    kernel as the cache's consumer the loop's carry stays row-major.
 
     ``uniform_slot``: promise that every stream writes the SAME cache
     slot (true for the batch generate path, where prefill fills a
@@ -614,13 +620,13 @@ def decode_step(
         valid = cache["valid"].at[jnp.arange(b), slot].set(True)
     new_len = slot + 1
 
-    def layer_body(x, k_all, v_all, lp, layer_idx, static_l=None):
+    def layer_body(x, k_all, v_all, lp, l):
+        # l: the layer, a Python int (unrolled) or a traced scalar
         ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
         q, k, v = _qkv(cfg, lp, ln1)  # q: [B, nq, hd]; k/v: [B, nkv, hd]
         if cfg.apply_rotary:
             q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
             k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
-        l = layer_idx if static_l is None else static_l
         if uniform_slot:
             kw = k[None, :, :, None, :].astype(k_all.dtype)  # [1,B,nkv,1,hd]
             vw = v[None, :, :, None, :].astype(v_all.dtype)
@@ -634,19 +640,13 @@ def decode_step(
         base = cfg.head_dim ** -0.5 if cfg.scale_attn_weights else 1.0
         if not cfg.scale_attn_by_inverse_layer_idx:
             scale = base
-        elif static_l is not None:
-            scale = base / (static_l + 1)
+        elif isinstance(l, int):
+            scale = base / (l + 1)
         else:
-            scale = _attn_scale(cfg, layer_idx)  # traced scalar
-        if static_l is not None:
-            attn = decode_attention(q, k_all[static_l], v_all[static_l],
-                                    valid, scale=scale,
-                                    sliding_window=cfg.sliding_window,
-                                    slot=slot, mesh=mesh)
-        else:
-            attn = _stacked_decode_attention(
-                q, k_all, v_all, valid, layer_idx, scale=scale,
-                sliding_window=cfg.sliding_window, slot=slot, mesh=mesh)
+            scale = _attn_scale(cfg, l)  # traced scalar
+        attn = _stacked_decode_attention(
+            q, k_all, v_all, valid, l, scale=scale,
+            sliding_window=cfg.sliding_window, slot=slot, mesh=mesh)
         proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(x.dtype)
@@ -659,14 +659,10 @@ def decode_step(
     if cfg.n_layers <= _DECODE_UNROLL_MAX_LAYERS:
         for li in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
-            x, k_all, v_all = layer_body(x, k_all, v_all, lp, li,
-                                         static_l=li)
+            x, k_all, v_all = layer_body(x, k_all, v_all, lp, li)
     else:
         def body(carry, layer):
-            xc, kc, vc = carry
-            lp, layer_idx = layer
-            xc, kc, vc = layer_body(xc, kc, vc, lp, layer_idx)
-            return (xc, kc, vc), None
+            return layer_body(*carry, *layer), None
 
         layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
         (x, k_all, v_all), _ = jax.lax.scan(

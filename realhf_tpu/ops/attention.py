@@ -194,9 +194,13 @@ def decode_attention(
     sliding_window: Optional[int] = None,
     slot: Optional[jnp.ndarray] = None,  # [B] int32 current write index,
                                          # required with sliding_window
-    mesh=None,  # partition the pallas kernel over a dp x tp mesh
 ) -> jnp.ndarray:
-    """Single-step decode attention against a padded KV cache.
+    """Single-step decode attention against ONE layer's padded KV
+    cache, in plain XLA (GSPMD partitions it on a mesh): the reference
+    of the Pallas kernel, which reads the stacked cache in place
+    (``ops/decode_attention.py``; ``models/transformer.py`` routes),
+    and the path where the kernel does not apply (CPU, heads under 64,
+    a mesh nothing divides).
 
     The caller has already written the new token's K/V (and marked its
     slot valid). Replaces `flash_attn_with_kvcache`
@@ -207,23 +211,6 @@ def decode_attention(
     b, nq, hd = q.shape
     nkv, s = k_cache.shape[1], k_cache.shape[2]
     group = nq // nkv
-
-    # Pallas flash-decode on TPU: single tiled pass over the cache, no
-    # [B, nq, S] score tensor. Routing (bare / head-sharded /
-    # KV-sequence-split shard_map) lives in one dispatcher shared with
-    # the stacked path (ops/decode_attention.run_decode_kernels);
-    # None = no kernel partitioning applies -> the XLA path below,
-    # which GSPMD partitions itself.
-    if pallas_enabled() and hd >= 64 and logits_soft_cap is None:
-        from realhf_tpu.ops.decode_attention import run_decode_kernels
-
-        out = run_decode_kernels(
-            mesh, q, (k_cache, v_cache), valid_mask, slot, None,
-            stacked=False, scale=scale,
-            sliding_window=sliding_window)
-        if out is not None:
-            return out
-
     scale = scale if scale is not None else hd ** -0.5
 
     qg = q.reshape(b, nkv, group, hd)

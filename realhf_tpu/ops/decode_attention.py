@@ -9,24 +9,32 @@ K/V per step, with all query heads of a KV group (GQA) sharing each
 loaded block.
 
 Layout contract (HEAD-MAJOR, so no transpose sits on the hot path):
-q [B, nq, hd], per-layer caches [B, nkv, S, hd], keep-mask [B, S]
-(validity AND the sliding window -- precomputed in XLA, it is O(B*S)
-elementwise). Two entry points:
+q [B, nq, hd], the FULL stacked caches [nl, B, nkv, S, hd], keep-mask
+[B, S] (validity AND the sliding window -- precomputed in XLA, it is
+O(B*S) elementwise).
 
-- ``flash_decode_attention``: per-layer caches (unrolled decode loop;
-  a static layer index into the stacked cache is a free view).
-- ``flash_decode_attention_stacked``: the FULL stacked caches
-  [nl, B, nkv, S, hd] plus a (traced) layer index, delivered to the
-  kernel through scalar prefetch so only layer ``l``'s rows are ever
-  streamed from HBM. This keeps the `lax.scan`-over-layers decode
-  path at O(1) compile time without copying a layer's cache out per
-  token (the round-3 decode bottleneck).
+One kernel, ``flash_decode_attention_stacked``: it takes the whole
+stack plus a layer index (a Python int of an unrolled layer loop or
+the traced index of a `lax.scan` over layers), delivered through
+scalar prefetch so the index map picks layer ``l``'s rows and only
+they are streamed from HBM; every layer shares one Mosaic body. The
+kernel, not a slice, is the cache's consumer inside the decode loop:
+its operand constraint keeps the loop's carry row-major, so the
+token's write is ``B * nkv`` row writes in place. Slicing a layer out
+first (``k_all[l]``) is NOT a free view on the chip: it was a slice
+and a transposing copy of the layer's cache for every layer and
+token, and it left XLA free to lay the stack out slot-minor, which
+made the one-token write a strided write into every tile (PERF.md,
+PR 30). ``decode_layer_copies`` counts such copies in a compiled
+program.
 
 The query-group axis is padded up to the fp32 sublane count (8); hd
-should be a multiple of 128 on real TPUs. S is padded to the K block.
+should be a multiple of 128 on real TPUs. S is a multiple of the K
+block (caches are allocated so: ``transformer.round_cache_len``).
 """
 
 import functools
+import re
 from typing import Optional
 
 import jax
@@ -41,6 +49,10 @@ logger = logging.getLogger("decode_attention")
 NEG_INF = -2.0 ** 30
 SUBLANES = 8
 
+
+#: the kernel's name: a device trace's operation (``decode_attn_stacked.N``)
+#: and the mark of it in a compiled program's text
+KERNEL_NAME = "decode_attn_stacked"
 
 #: K-block rows per kernel step (a multiple of 128: lane tiling).
 DEFAULT_BK = 512
@@ -91,42 +103,12 @@ def _decode_body(q, k_at, v_at, keep_at, o_ref, *, scale, bk, s,
         l_ref[...] = l_sum.reshape(l_ref.shape)
 
 
-def _layer_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, *, scale, bk):
-    s = k_ref.shape[-2]
-    _decode_body(
-        q_ref[0, 0],
-        lambda j: k_ref[0, 0, pl.ds(j * bk, bk), :],
-        lambda j: v_ref[0, 0, pl.ds(j * bk, bk), :],
-        lambda j: keep_ref[0, 0, pl.ds(j * bk, bk)],
-        o_ref, scale=scale, bk=bk, s=s)
-
-
-def _layer_kernel_stats(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref,
-                        l_ref, *, scale, bk):
-    s = k_ref.shape[-2]
-    _decode_body(
-        q_ref[0, 0],
-        lambda j: k_ref[0, 0, pl.ds(j * bk, bk), :],
-        lambda j: v_ref[0, 0, pl.ds(j * bk, bk), :],
-        lambda j: keep_ref[0, 0, pl.ds(j * bk, bk)],
-        o_ref, scale=scale, bk=bk, s=s, m_ref=m_ref, l_ref=l_ref)
-
-
-def _stacked_kernel(lidx_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, *,
-                    scale, bk):
+def _stacked_kernel(lidx_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+                    *stat_refs, scale, bk):
     # lidx_ref is the scalar-prefetch operand; the index_map already
-    # consumed it to select the layer block, so the body is identical.
-    s = k_ref.shape[-2]
-    _decode_body(
-        q_ref[0, 0],
-        lambda j: k_ref[0, 0, 0, pl.ds(j * bk, bk), :],
-        lambda j: v_ref[0, 0, 0, pl.ds(j * bk, bk), :],
-        lambda j: keep_ref[0, 0, pl.ds(j * bk, bk)],
-        o_ref, scale=scale, bk=bk, s=s)
-
-
-def _stacked_kernel_stats(lidx_ref, q_ref, k_ref, v_ref, keep_ref,
-                          o_ref, m_ref, l_ref, *, scale, bk):
+    # consumed it to select the layer block. stat_refs: the (m, l)
+    # outputs of a return_stats call, else empty.
+    m_ref, l_ref = stat_refs or (None, None)
     s = k_ref.shape[-2]
     _decode_body(
         q_ref[0, 0],
@@ -134,21 +116,6 @@ def _stacked_kernel_stats(lidx_ref, q_ref, k_ref, v_ref, keep_ref,
         lambda j: v_ref[0, 0, 0, pl.ds(j * bk, bk), :],
         lambda j: keep_ref[0, 0, pl.ds(j * bk, bk)],
         o_ref, scale=scale, bk=bk, s=s, m_ref=m_ref, l_ref=l_ref)
-
-
-def _with_stats(kernel, kernel_stats, return_stats, o_shape, o_dtype,
-                o_spec, stat_spec, **kw):
-    """Pick the (kernel, out_shape, out_specs) triple for a decode
-    pallas_call with or without the (m, l) stats outputs -- shared by
-    the flat and stacked wrappers so their call setup cannot drift."""
-    b, nkv, gp = o_shape[0], o_shape[1], o_shape[2]
-    if return_stats:
-        stat = jax.ShapeDtypeStruct((b, nkv, gp), jnp.float32)
-        return (functools.partial(kernel_stats, **kw),
-                (jax.ShapeDtypeStruct(o_shape, o_dtype), stat, stat),
-                (o_spec, stat_spec, stat_spec))
-    return (functools.partial(kernel, **kw),
-            jax.ShapeDtypeStruct(o_shape, o_dtype), o_spec)
 
 
 def _trim_stats(res, return_stats, b, nq, group):
@@ -170,9 +137,9 @@ _BK_LADDER = (4096, 2048, 1024, 512, 384, 256, 128)
 
 def _pick_bk(s: int, block_k: int = DEFAULT_BK) -> int:
     """Largest K-block <= block_k that divides s (cache lengths are
-    allocated as multiples of 128, so this normally succeeds and the
-    concat-pad fallback never runs on the hot path). The ladder spans
-    past 512 so a raised DEFAULT_BK actually takes effect."""
+    allocated as multiples of 128, so this succeeds on the generation
+    paths; the kernel refuses a length nothing divides). The ladder
+    spans past 512 so a raised DEFAULT_BK actually takes effect."""
     if s <= block_k:
         return s
     for bk in _BK_LADDER:
@@ -205,72 +172,17 @@ def _pad_group(q, nkv, group, gp):
     return qg
 
 
-def flash_decode_attention(
-    q: jnp.ndarray,        # [B, nq, hd]
-    k_cache: jnp.ndarray,  # [B, nkv, S, hd]
-    v_cache: jnp.ndarray,
-    valid_mask: jnp.ndarray,  # [B, S] bool
-    *,
-    scale: Optional[float] = None,
-    sliding_window: Optional[int] = None,
-    slot: Optional[jnp.ndarray] = None,  # [B] int32, with sliding_window
-    block_k: int = DEFAULT_BK,
-    interpret: bool = False,
-    return_stats: bool = False,  # also return (m, l) softmax partials
-) -> jnp.ndarray:
-    b, nq, hd = q.shape
-    nkv, s = k_cache.shape[1], k_cache.shape[2]
-    group = nq // nkv
-    scale = float(scale) if scale is not None else hd ** -0.5
-
-    keep = _window_keep(valid_mask, sliding_window, slot)
-
-    bk = _pick_bk(s, block_k)
-    pad_s = (-s) % bk
-    if pad_s:
-        zpad = jnp.zeros((b, nkv, pad_s, hd), k_cache.dtype)
-        k_cache = jnp.concatenate([k_cache, zpad], axis=2)
-        v_cache = jnp.concatenate([v_cache, zpad], axis=2)
-        keep = jnp.concatenate(
-            [keep, jnp.zeros((b, pad_s), jnp.int32)], axis=1)
-    s += pad_s
-
-    gp = max(SUBLANES, group)  # pad query group to the sublane tile
-    qg = _pad_group(q, nkv, group, gp)
-    keep_b = jnp.broadcast_to(keep[:, None, :], (b, SUBLANES, s))
-
-    in_specs = [
-        pl.BlockSpec((1, 1, gp, hd), lambda bi, h: (bi, h, 0, 0)),
-        pl.BlockSpec((1, 1, s, hd), lambda bi, h: (bi, h, 0, 0)),
-        pl.BlockSpec((1, 1, s, hd), lambda bi, h: (bi, h, 0, 0)),
-        pl.BlockSpec((1, SUBLANES, s), lambda bi, h: (bi, 0, 0)),
-    ]
-    o_spec = pl.BlockSpec((1, 1, gp, hd), lambda bi, h: (bi, h, 0, 0))
-    kernel, out_shape, out_specs = _with_stats(
-        _layer_kernel, _layer_kernel_stats, return_stats,
-        (b, nkv, gp, hd), q.dtype, o_spec,
-        pl.BlockSpec((1, 1, gp), lambda bi, h: (bi, h, 0)),
-        scale=scale, bk=bk)
-    res = pl.pallas_call(
-        kernel, out_shape=out_shape, grid=(b, nkv),
-        in_specs=in_specs, out_specs=out_specs, interpret=interpret,
-        name="decode_attn",
-    )(qg, k_cache, v_cache, keep_b)
-    return _trim_stats(res, return_stats, b, nq, group)
-
-
-def sharded_decode_attention(
-    fn, mesh, q, caches, valid_mask, slot, layer_index=None, *,
-    stacked: bool,
-):
-    """Partition a decode-attention kernel over a dp x tp mesh with
+def sharded_decode_attention(fn, mesh, q, caches, valid_mask, slot,
+                             layer_index):
+    """Partition the decode-attention kernel over a dp x tp mesh with
     `shard_map` (manual over the data/model axes): a bare pallas_call
     under GSPMD has no partitioning rule, so without this wrapper XLA
     would gather the full KV cache onto every device -- fatal for the
     tp16 70B decode story (docs/distributed.md).
-    ``fn(q, k, v, valid, slot, lidx)`` runs on LOCAL shards: B over
-    "data", heads over "model" (GQA grouping survives because nq and
-    nkv shard together).
+    ``fn(q, k, v, valid, slot, lidx)`` runs on LOCAL shards of the
+    stacked caches: B over "data", heads over "model" (GQA grouping
+    survives because nq and nkv shard together); the layer axis and
+    ``layer_index`` (a scalar array) are replicated.
 
     Callers must check `decode_shardable` (B % dp, nq % tp, nkv % tp)
     and fall back to the XLA path otherwise."""
@@ -280,8 +192,7 @@ def sharded_decode_attention(
 
     from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-    layer_lead = (None,) if stacked else ()
-    kv_spec = P(*layer_lead, DATA_AXIS, MODEL_AXIS, None, None)
+    kv_spec = P(None, DATA_AXIS, MODEL_AXIS, None, None)
     slot_spec = P(DATA_AXIS) if slot is not None else P()
     has_slot = slot is not None
     # decode requires pipe=ctx=1, so go FULLY manual (partial-auto
@@ -302,8 +213,7 @@ def sharded_decode_attention(
     k_all, v_all = caches
     return run(q, k_all, v_all, valid_mask,
                slot if has_slot else jnp.zeros((), jnp.int32),
-               (layer_index if layer_index is not None
-                else jnp.zeros((), jnp.int32)))
+               layer_index)
 
 
 def mesh_nontrivial(mesh) -> bool:
@@ -369,67 +279,49 @@ def choose_decode_partitioning(mesh, b: int, nq: int, nkv: int,
 
 
 def run_decode_kernels(mesh, q, caches, valid_mask, slot, layer_index,
-                       *, stacked: bool, scale=None,
-                       sliding_window=None):
+                       *, scale=None, sliding_window=None):
     """Single dispatcher for one decode-attention call onto the Pallas
-    kernels: bare kernel on trivial meshes, head-sharded or
-    KV-sequence-split shard_map per ``choose_decode_partitioning``.
-    Returns ``None`` when no kernel partitioning applies -- the caller
-    then takes its GSPMD/XLA fallback. Shared by the flat
-    (``ops/attention.decode_attention``) and stacked
-    (``models/transformer._stacked_decode_attention``) paths so the
-    routing cannot drift between them. Traced scales (deep
-    scale_attn_by_inverse_layer_idx models) fold into q here, since
-    the kernels need a python-static scale."""
+    kernel, against the FULL stacked caches at ``layer_index`` (a
+    Python int or a traced scalar): bare kernel on trivial meshes,
+    head-sharded or KV-sequence-split shard_map per
+    ``choose_decode_partitioning``. Returns ``None`` when no kernel
+    partitioning applies -- the caller then takes its GSPMD/XLA
+    fallback. Traced scales (deep scale_attn_by_inverse_layer_idx
+    models) fold into q here, since the kernel needs a python-static
+    scale."""
     if not (scale is None or isinstance(scale, (int, float))):
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
         scale = 1.0
     b, nq = q.shape[0], q.shape[1]
-    if stacked:
-        nkv, s = caches[0].shape[2], caches[0].shape[3]
+    nkv, s = caches[0].shape[2], caches[0].shape[3]
+    layer_index = jnp.asarray(layer_index, jnp.int32)
 
-        def plain(q_, k_, v_, valid_, slot_, lidx):
-            return flash_decode_attention_stacked(
-                q_, k_, v_, valid_, lidx, scale=scale,
-                sliding_window=sliding_window, slot=slot_)
+    def plain(q_, k_, v_, valid_, slot_, lidx):
+        return flash_decode_attention_stacked(
+            q_, k_, v_, valid_, lidx, scale=scale,
+            sliding_window=sliding_window, slot=slot_)
 
-        def stats(q_, k_, v_, keep_, lidx):
-            return flash_decode_attention_stacked(
-                q_, k_, v_, keep_.astype(bool), lidx, scale=scale,
-                return_stats=True)
-    else:
-        nkv, s = caches[0].shape[1], caches[0].shape[2]
-
-        def plain(q_, k_, v_, valid_, slot_, lidx):
-            return flash_decode_attention(
-                q_, k_, v_, valid_, scale=scale,
-                sliding_window=sliding_window, slot=slot_)
-
-        def stats(q_, k_, v_, keep_, lidx):
-            return flash_decode_attention(
-                q_, k_, v_, keep_.astype(bool), scale=scale,
-                return_stats=True)
+    def stats(q_, k_, v_, keep_, lidx):
+        return flash_decode_attention_stacked(
+            q_, k_, v_, keep_.astype(bool), lidx, scale=scale,
+            return_stats=True)
 
     if not mesh_nontrivial(mesh):
         return plain(q, caches[0], caches[1], valid_mask, slot,
-                     (layer_index if layer_index is not None
-                      else jnp.zeros((), jnp.int32)))
+                     layer_index)
     part = choose_decode_partitioning(mesh, b, nq, nkv, s)
     if part == "heads":
         return sharded_decode_attention(
-            plain, mesh, q, caches, valid_mask, slot, layer_index,
-            stacked=stacked)
+            plain, mesh, q, caches, valid_mask, slot, layer_index)
     if part == "seq":
         keep = window_keep(valid_mask, sliding_window, slot)
         return sharded_decode_attention_seqsplit(
-            stats, mesh, q, caches, keep, layer_index, stacked=stacked)
+            stats, mesh, q, caches, keep, layer_index)
     return None
 
 
-def sharded_decode_attention_seqsplit(
-    fn_stats, mesh, q, caches, keep, layer_index=None, *,
-    stacked: bool,
-):
+def sharded_decode_attention_seqsplit(fn_stats, mesh, q, caches, keep,
+                                      layer_index):
     """KV-SEQUENCE-split decode for GQA at tp > n_kv_heads (the
     LLaMA-70B tp16 case, docs/distributed.md): heads cannot shard
     16-ways, so each "model" shard instead holds a SLICE OF THE CACHE
@@ -451,8 +343,7 @@ def sharded_decode_attention_seqsplit(
 
     from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-    layer_lead = (None,) if stacked else ()
-    kv_spec = P(*layer_lead, DATA_AXIS, None, MODEL_AXIS, None)
+    kv_spec = P(None, DATA_AXIS, None, MODEL_AXIS, None)
     axis_names = {a for a in mesh.axis_names}
 
     @_partial(jax.shard_map, mesh=mesh,
@@ -478,9 +369,7 @@ def sharded_decode_attention_seqsplit(
         return out.astype(q_l.dtype)
 
     k_all, v_all = caches
-    return run(q, k_all, v_all, keep,
-               (layer_index if layer_index is not None
-                else jnp.zeros((), jnp.int32)))
+    return run(q, k_all, v_all, keep, layer_index)
 
 
 def flash_decode_attention_stacked(
@@ -488,7 +377,7 @@ def flash_decode_attention_stacked(
     k_all: jnp.ndarray,    # [nl, B, nkv, S, hd] -- the FULL stacked cache
     v_all: jnp.ndarray,
     valid_mask: jnp.ndarray,  # [B, S] bool
-    layer_index: jnp.ndarray,  # scalar int32 (traced OK)
+    layer_index,           # scalar int32: a Python int or traced
     *,
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
@@ -497,21 +386,22 @@ def flash_decode_attention_stacked(
     interpret: bool = False,
     return_stats: bool = False,  # also return (m, l) softmax partials
 ) -> jnp.ndarray:
-    """Same math as `flash_decode_attention` but reads layer
-    ``layer_index`` of the stacked cache directly via a scalar-prefetch
-    index map -- HBM traffic is exactly one layer's K/V rows, with no
-    per-layer slice copy. S must be a multiple of ``block_k`` (the
-    generation path allocates caches pre-padded; see
-    `transformer.init_kv_cache`)."""
+    """One query token per stream against layer ``layer_index`` of the
+    stacked cache, read in place through a scalar-prefetch index map:
+    HBM traffic is exactly one layer's K/V rows, with no per-layer
+    slice copy, and one Mosaic body serves every layer. S must be a
+    multiple of the K block (the generation path allocates caches
+    pre-padded; see `transformer.init_kv_cache`)."""
     b, nq, hd = q.shape
     nl, _, nkv, s = k_all.shape[:4]
     group = nq // nkv
     scale = float(scale) if scale is not None else hd ** -0.5
 
     bk = _pick_bk(s, block_k)
-    assert s % bk == 0, (
-        f"stacked decode cache length {s} must be a multiple of the "
-        f"K block {bk}; pad the cache at allocation time")
+    if s % bk:
+        raise ValueError(
+            f"stacked decode cache length {s} must be a multiple of "
+            f"the K block {bk}; pad the cache at allocation time")
 
     keep = _window_keep(valid_mask, sliding_window, slot)
     gp = max(SUBLANES, group)
@@ -528,16 +418,72 @@ def flash_decode_attention_stacked(
         pl.BlockSpec((1, SUBLANES, s), lambda bi, h, lr: (bi, 0, 0)),
     ]
     o_spec = pl.BlockSpec((1, 1, gp, hd), lambda bi, h, lr: (bi, h, 0, 0))
-    kernel, out_shape, out_specs = _with_stats(
-        _stacked_kernel, _stacked_kernel_stats, return_stats,
-        (b, nkv, gp, hd), q.dtype, o_spec,
-        pl.BlockSpec((1, 1, gp), lambda bi, h, lr: (bi, h, 0)),
-        scale=scale, bk=bk)
+    out_shape = jax.ShapeDtypeStruct((b, nkv, gp, hd), q.dtype)
+    out_specs = o_spec
+    if return_stats:
+        stat = jax.ShapeDtypeStruct((b, nkv, gp), jnp.float32)
+        stat_spec = pl.BlockSpec((1, 1, gp), lambda bi, h, lr: (bi, h, 0))
+        out_shape = (out_shape, stat, stat)
+        out_specs = (o_spec, stat_spec, stat_spec)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(b, nkv),
         in_specs=in_specs, out_specs=out_specs)
     res = pl.pallas_call(
-        kernel, out_shape=out_shape, grid_spec=grid_spec,
-        interpret=interpret, name="decode_attn_stacked",
+        functools.partial(_stacked_kernel, scale=scale, bk=bk),
+        out_shape=out_shape, grid_spec=grid_spec,
+        interpret=interpret, name=KERNEL_NAME,
     )(lidx, qg, k_all, v_all, keep_b)
     return _trim_stats(res, return_stats, b, nq, group)
+
+
+_HLO_CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*->.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\]\S*\s+([\w\-]+)\(")
+#: instructions that move nothing on the device
+_HLO_FREE = frozenset(
+    ("parameter", "get-tuple-element", "bitcast", "constant"))
+
+
+def decode_layer_copies(hlo_text: str, layer_shape) -> int:
+    """How many device operations of a compiled program produce an
+    array of one layer's cache shape ``[B, nkv, S, hd]`` (as one
+    device holds it): the slices and relayout copies of a layer that
+    a decode loop makes when it takes ``k_all[l]`` out of the stack.
+    0 where attention reads the stack in place. A pure function of
+    the optimized HLO text (``Engine.compiled_text``): instructions
+    inside fusion bodies are no operations of their own and are not
+    counted, nor are those that move nothing (parameters, tuple
+    elements, bitcasts)."""
+    dims = ",".join(str(int(d)) for d in layer_shape)
+    lines = hlo_text.splitlines()
+    fused = {m.group(1) for m in map(_HLO_CALLS.search, lines) if m}
+    count, in_fusion = 0, False
+    for line in lines:
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            in_fusion = head.group(1) in fused
+            continue
+        if in_fusion:
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m and m.group(1) == dims and m.group(2) not in _HLO_FREE:
+            count += 1
+    return count
+
+
+def local_layer_shape(mesh, b: int, nq: int, nkv: int, s: int, hd: int):
+    """One layer's cache ``[B, nkv, S, hd]`` as ONE device of ``mesh``
+    holds it under `choose_decode_partitioning`: what
+    `decode_layer_copies` looks for in an SPMD program."""
+    if not mesh_nontrivial(mesh):
+        return (b, nkv, s, hd)
+    from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    dp = mesh.shape.get(DATA_AXIS, 1)
+    tp = mesh.shape.get(MODEL_AXIS, 1)
+    part = choose_decode_partitioning(mesh, b, nq, nkv, s)
+    if part == "seq":
+        return (b // dp, nkv, s // tp, hd)
+    # heads, or GSPMD's own choice: batch and heads where they divide
+    return (b // dp if b % dp == 0 else b,
+            nkv // tp if nkv % tp == 0 else nkv, s, hd)

@@ -20,8 +20,7 @@ from typing import Any, Dict
 
 KERNELS = (
     "flash_attention",               # ops/flash_attention.py (packed fwd/bwd)
-    "flash_decode_attention",        # ops/decode_attention.py per-layer
-    "flash_decode_attention_stacked",  # scalar-prefetch stacked decode
+    "flash_decode_attention_stacked",  # ops/decode_attention.py
 )
 
 

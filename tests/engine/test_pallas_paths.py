@@ -36,15 +36,15 @@ pytestmark = pytest.mark.skipif(
            "be exercised on this jax; XLA fallbacks covered elsewhere")
 
 
-def _cfg():
+def _cfg(**kw):
     # head_dim 64: the kernel gates require hd >= 64
-    return TransformerConfig(
+    return TransformerConfig(**dict(dict(
         n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=256,
         head_dim=64, intermediate_dim=512, vocab_size=128,
         apply_rotary=True, layer_norm_type="rms", mlp_type="llama",
         use_attention_bias=False, use_attn_proj_bias=False,
         use_mlp_bias=False, activation_function="silu",
-        compute_dtype="float32")
+        compute_dtype="float32"), **kw))
 
 
 def _mesh(dp, tp):
@@ -53,7 +53,7 @@ def _mesh(dp, tp):
     return make_mesh(par, devices=jax.devices("cpu")[:par.world_size])
 
 
-def _one_decode_step(cfg, params, mesh):
+def _one_decode_step(cfg, params, mesh, uniform_slot=True):
     rng = np.random.default_rng(0)
     b, lp = 4, 8
     ids = jnp.asarray(rng.integers(1, 120, size=(b, lp)), jnp.int32)
@@ -68,9 +68,12 @@ def _one_decode_step(cfg, params, mesh):
     def step(params, ids, seg, pos, tok):
         _, cache = T.prefill(cfg, params, ids, seg, pos,
                              total_len=lp + 8)
+        if not uniform_slot:
+            # the slot engine's streams: each at its own write slot
+            cache["length"] = cache["length"] + jnp.arange(b) % 3
         new_hidden, _ = T.decode_step(cfg, params, cache, tok,
-                                      jnp.full((b,), lp, jnp.int32),
-                                      uniform_slot=True, mesh=mesh)
+                                      cache["length"],
+                                      uniform_slot=uniform_slot, mesh=mesh)
         return new_hidden
 
     return np.asarray(step(params, ids, seg, pos, tok))
@@ -99,6 +102,40 @@ def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch):
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
 
 
+@pytest.mark.parametrize("uniform_slot", [True, False],
+                         ids=["uniform_slot", "slot_per_stream"])
+@pytest.mark.parametrize("hd,nq,window,mesh", [
+    (64, 14, None, None), (128, 8, None, None),
+    (64, 14, 4, (2, 2)), (128, 8, 4, (1, 1))],
+    ids=["hd64_group7", "hd128_group4", "hd64_group7_window_d2t2",
+         "hd128_group4_window_d1t1"])
+def test_decode_step_unrolled_reads_the_stack_in_place(
+        hd, nq, window, mesh, uniform_slot, monkeypatch):
+    """The unrolled layer loop (every model of 48 layers or fewer)
+    hands the stacked kernel the whole cache and a static layer
+    index: equal to the XLA path on a layer sliced out, at the
+    benchmark models' head size and query group, for the batch
+    generate path's one write slot and the slot engine's slot a
+    stream, with and without a sliding window, bare and under
+    ``shard_map``."""
+    cfg = _cfg(head_dim=hd, n_q_heads=nq, sliding_window=window)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    ref = _one_decode_step(cfg, params, None, uniform_slot)  # XLA path
+
+    calls = []
+    from realhf_tpu.ops import decode_attention as D
+    kernel = D.flash_decode_attention_stacked
+    monkeypatch.setattr(
+        D, "flash_decode_attention_stacked",
+        lambda *a, **kw: calls.append(a[4]) or kernel(*a, **kw))
+    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
+    with pltpu.force_tpu_interpret_mode():
+        got = _one_decode_step(cfg, params, mesh and _mesh(*mesh),
+                               uniform_slot)
+    np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
+    assert len(calls) == cfg.n_layers  # the kernel, once a layer
+
+
 @pytest.mark.parametrize("dp,tp", [(4, 2), (2, 4)])
 def test_decode_step_stacked_scan_path(dp, tp, monkeypatch):
     """Deep-model wiring: dropping the unroll threshold forces the
@@ -115,6 +152,52 @@ def test_decode_step_stacked_scan_path(dp, tp, monkeypatch):
     with pltpu.force_tpu_interpret_mode():
         got = _one_decode_step(cfg, params, mesh=_mesh(dp, tp))
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("dp,tp", [(1, 1), (2, 2)])
+def test_generate_span_says_how_the_decode_loop_reads_the_cache(
+        dp, tp, monkeypatch):
+    """Every ``engine:generate`` span carries ``decode_kernel`` and
+    ``decode_layer_copies``, read once from the program's compiled
+    text: ``stacked`` and 0 where the kernel reads the stack in
+    place, ``xla`` on the einsum path."""
+    from realhf_tpu.api.config import ModelName
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.obs import tracing
+    from realhf_tpu.ops.sampling import GenerationHyperparameters
+    from realhf_tpu.parallel.mesh import MeshContext
+
+    cfg = _cfg()
+    par = ParallelismConfig(data_parallel_size=dp, tensor_parallel_size=tp)
+    ctx = MeshContext(ModelName("default", 0), _mesh(dp, tp), par)
+    b, lp = 4, 8
+    ids = np.random.default_rng(0).integers(
+        1, 120, size=(b, lp)).astype(np.int32)
+    seg = np.ones((b, lp), np.int32)
+    pos = np.tile(np.arange(lp, dtype=np.int32), (b, 1))
+    g = GenerationHyperparameters(max_new_tokens=3, min_new_tokens=3,
+                                  greedy=True, force_no_logits_mask=True)
+
+    def run():
+        engine = Engine(cfg, ctx, T.init_params(cfg, jax.random.PRNGKey(0)))
+        tracing.start()
+        tokens = [np.asarray(engine.generate(
+            ids, seg, pos, jax.random.PRNGKey(0), g, None, 0).tokens)
+            for _ in range(2)]
+        spans = tracing.stop().named("engine:generate")
+        np.testing.assert_array_equal(*tokens)
+        return tokens[0], [s["attributes"] for s in spans]
+
+    ref, xla = run()
+    assert [a["decode_kernel"] for a in xla] == ["xla", "xla"]
+    assert [a["compiled"] for a in xla] == [True, False]
+    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
+    with pltpu.force_tpu_interpret_mode():
+        got, stacked = run()
+    np.testing.assert_array_equal(got, ref)
+    for attrs in stacked:
+        assert attrs["decode_kernel"] == "stacked"
+        assert attrs["decode_layer_copies"] == 0
 
 
 def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):

@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from realhf_tpu.ops.attention import make_sharded_attention, packed_attention
 from realhf_tpu.ops.decode_attention import (
-    flash_decode_attention,
+    KERNEL_NAME,
+    decode_layer_copies,
     flash_decode_attention_stacked,
 )
 from realhf_tpu.ops.flash_attention import FLASH_MAX_LEN, flash_attention
@@ -143,22 +144,80 @@ def test_row_above_the_limit_raises_not_xla():
             lambda *a: packed_attention(*a, use_flash=True), q, k, v, seg)
 
 
-def _decode_args(sharding, stacked):
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    cache = (GEN_BATCH, NKV, CACHE_LEN, HD)
-    if stacked:
-        cache = (N_LAYERS,) + cache
-    return (sds((GEN_BATCH, NQ, HD), jnp.bfloat16),
-            sds(cache, jnp.bfloat16), sds(cache, jnp.bfloat16),
-            sds((GEN_BATCH, CACHE_LEN), jnp.bool_))
-
-
-def test_decode_per_layer_compiles(one_chip):
-    _compile(flash_decode_attention, *_decode_args(one_chip, False))
-
-
 def test_decode_stacked_compiles(one_chip):
-    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cache = (N_LAYERS, GEN_BATCH, NKV, CACHE_LEN, HD)
     _compile(flash_decode_attention_stacked,
-             *_decode_args(one_chip, True), layer)
+             sds((GEN_BATCH, NQ, HD), jnp.bfloat16),
+             sds(cache, jnp.bfloat16), sds(cache, jnp.bfloat16),
+             sds((GEN_BATCH, CACHE_LEN), jnp.bool_), sds((), jnp.int32))
+
+
+@pytest.mark.parametrize("chips,nq,nkv,hd,hidden", [
+    (1, NQ, NKV, HD, 896), (4, 32, 8, 128, 4096)],
+    ids=["qwen_one_chip", "mistral_d4t1"])
+def test_decode_loop_touches_the_stacked_cache_in_place(
+        topo, monkeypatch, chips, nq, nkv, hd, hidden):
+    """``decode_step`` as ``generate`` runs it, four tokens of a scan
+    over an unrolled two-layer model at the cells' attention widths
+    (cell 1 on one chip, cell 3's d4t1 replica under ``shard_map``):
+    the kernel is the cache's consumer, so the compiler keeps the
+    loop's carry row-major, writes the token's rows in place, and
+    makes no operation of one layer's cache shape. Before PR 30 the
+    loop sliced ``k_all[l]`` out: a slice and a transposing copy a
+    layer and a token, and a slot-minor stack."""
+    import re
+
+    import numpy as np
+
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.models.config import TransformerConfig
+    from realhf_tpu.parallel.mesh import DATA_AXIS
+
+    monkeypatch.setattr(T, "pallas_enabled", lambda: True)
+    cfg = TransformerConfig(
+        n_layers=2, n_kv_heads=nkv, n_q_heads=nq, hidden_dim=hidden,
+        head_dim=hd, intermediate_dim=1024, vocab_size=1024,
+        apply_rotary=True, layer_norm_type="rms", mlp_type="llama",
+        use_attention_bias=False, use_attn_proj_bias=False,
+        use_mlp_bias=False, activation_function="silu",
+        param_dtype="bfloat16", compute_dtype="bfloat16")
+    if chips == 1:
+        mesh = None
+        rep = SingleDeviceSharding(topo.devices[0])
+        shard_rows = lambda cache: cache
+    else:
+        mesh = Mesh(np.array(topo.devices[:chips]).reshape(chips, 1),
+                    (DATA_AXIS, "model"))
+        rep = NamedSharding(mesh, P())
+
+        def shard_rows(cache):  # the batch axis over "data"
+            return {k: jax.lax.with_sharding_constraint(a, NamedSharding(
+                mesh, P(*([None] * (a.ndim == 5)), DATA_AXIS)))
+                for k, a in cache.items()}
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+
+    def loop(params):
+        # the cache is born inside the program, as generate's is in its
+        # prefill: its layout is the compiler's to choose
+        cache = shard_rows(T.init_kv_cache(cfg, GEN_BATCH, CACHE_LEN))
+
+        def step(cache, token):
+            hidden, cache = T.decode_step(
+                cfg, params, cache, token, cache["length"],
+                uniform_slot=True, mesh=mesh)
+            return cache, hidden[:, 0]
+        return jax.lax.scan(
+            step, cache, jnp.ones((4, GEN_BATCH), jnp.int32))[1]
+
+    text = _compile(loop, params).as_text()
+    assert KERNEL_NAME in text
+    local = (GEN_BATCH // chips, nkv, CACHE_LEN, hd)
+    assert decode_layer_copies(text, local) == 0
+    stack = ",".join(map(str, (cfg.n_layers,) + local))
+    layouts = set(re.findall(rf"bf16\[{stack}\]{{([\d,]+)", text))
+    assert layouts == {"4,3,2,1,0"}

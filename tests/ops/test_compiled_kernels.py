@@ -55,10 +55,9 @@ def test_compiled_kernel_matches_reference(kernel):
         # flash_attention has no interpret switch: off-TPU it cannot
         # run at all, which is exactly what the skip above encodes
         got = flash_attention(q, k, v, seg, causal=True)
-    else:  # flash_decode_attention, flash_decode_attention_stacked
+    else:  # flash_decode_attention_stacked
         from realhf_tpu.ops.attention import decode_attention
         from realhf_tpu.ops.decode_attention import (
-            flash_decode_attention,
             flash_decode_attention_stacked,
         )
         b, s, nq, nkv, hd, nl = 4, 256, 8, 2, 128, 2
@@ -73,12 +72,8 @@ def test_compiled_kernel_matches_reference(kernel):
         valid = jnp.asarray(valid)
         li = 1
         ref = decode_attention(q, ks[li], vs[li], valid)
-        if kernel == "flash_decode_attention":
-            got = flash_decode_attention(q, ks[li], vs[li], valid,
-                                         interpret=False)
-        else:
-            got = flash_decode_attention_stacked(
-                q, ks, vs, valid, jnp.int32(li), interpret=False)
+        got = flash_decode_attention_stacked(
+            q, ks, vs, valid, jnp.int32(li), interpret=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-2, rtol=2e-2)
 
@@ -103,15 +98,15 @@ def test_disposition_reflects_backend_and_overrides(monkeypatch):
     disp = kernel_dispositions()
     assert all(not d["engaged"] for d in disp.values())
     assert "REALHF_TPU_DISABLE_PALLAS" in \
-        disp["flash_decode_attention"]["reason"]
+        disp["flash_decode_attention_stacked"]["reason"]
 
     monkeypatch.delenv("REALHF_TPU_DISABLE_PALLAS", raising=False)
     if jax.default_backend() != "tpu":
         # off-TPU the default is the XLA path with the backend named
         disp = kernel_dispositions()
-        assert disp["flash_decode_attention"]["mode"] == "xla"
+        assert disp["flash_decode_attention_stacked"]["mode"] == "xla"
         assert jax.default_backend() in \
-            disp["flash_decode_attention"]["reason"]
+            disp["flash_decode_attention_stacked"]["reason"]
 
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     disp = kernel_dispositions()

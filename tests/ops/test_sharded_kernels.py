@@ -22,7 +22,6 @@ from realhf_tpu.ops.attention import (
 )
 from realhf_tpu.ops.decode_attention import (
     decode_shardable,
-    flash_decode_attention,
     flash_decode_attention_stacked,
     sharded_decode_attention,
 )
@@ -130,12 +129,13 @@ def test_sharded_decode_kernel_matches_xla():
     ref = decode_attention(q, k, v, valid)
 
     def fn(q_l, k_l, v_l, valid_l, slot_l, lidx):
-        return flash_decode_attention(q_l, k_l, v_l, valid_l,
-                                      interpret=True)
+        return flash_decode_attention_stacked(q_l, k_l, v_l, valid_l,
+                                              lidx, interpret=True)
 
+    # one layer's cache is a stack of one
     got = jax.jit(lambda *a: sharded_decode_attention(
-        fn, mesh, a[0], (a[1], a[2]), a[3], None, stacked=False))(
-            q, k, v, valid)
+        fn, mesh, a[0], (a[1][None], a[2][None]), a[3], None,
+        jnp.zeros((), jnp.int32)))(q, k, v, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -159,8 +159,8 @@ def test_sharded_stacked_decode_kernel_matches_xla():
                                               lidx, interpret=True)
 
     got = jax.jit(lambda *a: sharded_decode_attention(
-        fn, mesh, a[0], (a[1], a[2]), a[3], None, a[4],
-        stacked=True))(q, k_all, v_all, valid, layer)
+        fn, mesh, a[0], (a[1], a[2]), a[3], None, a[4]))(
+            q, k_all, v_all, valid, layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -182,6 +182,12 @@ def test_choose_decode_partitioning():
     assert choose_decode_partitioning(mesh, 4, 8, 2, 2304) is None
     # 4096/4 = 1024 local: fine (128 multiple)
     assert choose_decode_partitioning(mesh, 4, 8, 2, 4096) == "seq"
+
+
+def _stats_of_one_layer(q_l, k_l, v_l, keep_l, lidx):
+    return flash_decode_attention_stacked(
+        q_l, k_l, v_l, keep_l.astype(bool), lidx, interpret=True,
+        return_stats=True)
 
 
 def test_seqsplit_decode_matches_xla():
@@ -207,15 +213,10 @@ def test_seqsplit_decode_matches_xla():
 
     ref = decode_attention(q, k, v, valid)
 
-    def fn_stats(q_l, k_l, v_l, keep_l, lidx):
-        return flash_decode_attention(q_l, k_l, v_l,
-                                      keep_l.astype(bool),
-                                      interpret=True, return_stats=True)
-
     keep = window_keep(valid, None, None)
     got = jax.jit(lambda *a: sharded_decode_attention_seqsplit(
-        fn_stats, mesh, a[0], (a[1], a[2]), a[3], stacked=False))(
-            q, k, v, keep)
+        _stats_of_one_layer, mesh, a[0], (a[1][None], a[2][None]), a[3],
+        jnp.zeros((), jnp.int32)))(q, k, v, keep)
     # rows 0-2 must match dense attention; row 3's cache is fully
     # empty -- a don't-care (prefill always writes >= 1 token) where
     # the flash kernels emit zeros while XLA softmax degenerates to
@@ -246,16 +247,11 @@ def test_seqsplit_decode_sliding_window():
     ref = decode_attention(q, k, v, valid, sliding_window=window,
                            slot=slot)
 
-    def fn_stats(q_l, k_l, v_l, keep_l, lidx):
-        # window applied via the precomputed GLOBAL keep mask
-        return flash_decode_attention(q_l, k_l, v_l,
-                                      keep_l.astype(bool),
-                                      interpret=True, return_stats=True)
-
+    # window applied via the precomputed GLOBAL keep mask
     keep = window_keep(valid, window, slot)
     got = jax.jit(lambda *a: sharded_decode_attention_seqsplit(
-        fn_stats, mesh, a[0], (a[1], a[2]), a[3], stacked=False))(
-            q, k, v, keep)
+        _stats_of_one_layer, mesh, a[0], (a[1][None], a[2][None]), a[3],
+        jnp.zeros((), jnp.int32)))(q, k, v, keep)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -280,15 +276,10 @@ def test_seqsplit_stacked_decode_matches_xla():
 
     ref = decode_attention(q, k_all[2], v_all[2], valid)
 
-    def fn_stats(q_l, k_l, v_l, keep_l, lidx):
-        return flash_decode_attention_stacked(
-            q_l, k_l, v_l, keep_l.astype(bool), lidx,
-            interpret=True, return_stats=True)
-
     keep = window_keep(valid, None, None)
     got = jax.jit(lambda *a: sharded_decode_attention_seqsplit(
-        fn_stats, mesh, a[0], (a[1], a[2]), a[3], a[4],
-        stacked=True))(q, k_all, v_all, keep, layer)
+        _stats_of_one_layer, mesh, a[0], (a[1], a[2]), a[3], a[4]))(
+            q, k_all, v_all, keep, layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -302,9 +293,10 @@ def test_flash_decode_return_stats_consistency():
     k = jnp.asarray(rng.standard_normal((b, nkv, s, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, nkv, s, hd)), jnp.float32)
     valid = jnp.asarray(np.ones((b, s), bool))
-    plain = flash_decode_attention(q, k, v, valid, interpret=True)
-    out, m, l = flash_decode_attention(q, k, v, valid, interpret=True,
-                                       return_stats=True)
+    plain = flash_decode_attention_stacked(q, k[None], v[None], valid, 0,
+                                           interpret=True)
+    out, m, l = flash_decode_attention_stacked(
+        q, k[None], v[None], valid, 0, interpret=True, return_stats=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(plain),
                                atol=1e-6)
     assert np.asarray(l).min() > 0 and np.isfinite(np.asarray(m)).all()
