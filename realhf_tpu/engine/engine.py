@@ -103,6 +103,9 @@ class Engine:
         # inference-only forwards always use the GPipe rotation (see
         # _forward).
         if ctx.pp_size > 1:
+            cfg.require_one_block(
+                "pipeline parallelism (parallel/pipeline.py, "
+                "schedule.py)")
             from realhf_tpu.parallel.pipeline import PipelineContext
             from realhf_tpu.parallel.schedule import default_microbatches
             if cfg.n_layers % ctx.pp_size != 0:
@@ -198,12 +201,23 @@ class Engine:
             self._attention_fn = None
             self._flash_rows = _pallas_enabled()
 
-        # which dispatch a sparse model's programs take: on every
-        # engine:* span, and the label of moe_routed_pairs_total
+        # what this model's programs run, on every engine:* span: the
+        # dispatch a sparse model takes (also the label of
+        # moe_routed_pairs_total), a patterned model's layers
         mode = moe_ops.dispatch_mode(cfg)
-        self._moe_attrs: Dict[str, Any] = {} if mode is None else dict(
+        self._model_attrs: Dict[str, Any] = {} if mode is None else dict(
             moe_dispatch=mode, experts=cfg.moe.num_experts,
             top_k=cfg.moe.top_k)
+        if cfg.layer_pattern is not None:
+            self._model_attrs.update(
+                layer_pattern=cfg.pattern_string,
+                conv_layers=len(cfg.conv_layers),
+                dense_layers=cfg.n_layers - cfg.n_moe_layers)
+            if mode is not None:
+                self._model_attrs.update(
+                    experts_held=cfg.moe.n_held,
+                    router=cfg.moe.score_fn + (
+                        "_bias" if cfg.moe.use_expert_bias else ""))
         if mode == "dense" and cfg.moe.num_experts > 4:
             logger.warning(
                 "MoE model running in dense dispatch (capacity_factor "
@@ -294,7 +308,7 @@ class Engine:
             return fn(*args, **static)
         # engine:<name> holds the dispatch and, in a synced stretch,
         # the wait for the outputs
-        with tracing.span(f"engine:{name}", **self._moe_attrs,
+        with tracing.span(f"engine:{name}", **self._model_attrs,
                           **attrs) as sp:
             self._last_span = sp
             lowered = fn._cache_size()
@@ -325,7 +339,8 @@ class Engine:
             return {}
         counts = dict(zip(("visited", "causal"), block_counts(seg_ids)))
         for kind, n in counts.items():
-            metrics.inc("flash_kv_blocks_total", n * self.cfg.n_layers,
+            metrics.inc("flash_kv_blocks_total",
+                        n * len(self.cfg.attention_layers),
                         role=str(self.ctx.model_name.role), kind=kind)
         return dict(
             flash_block_share=counts["visited"] / counts["causal"])
@@ -334,30 +349,58 @@ class Engine:
         """``moe_routed_pairs_total{role,dispatch}``: the (token,
         expert) pairs the program about to run routes, counted on the
         host from its batch: valid tokens (plus the tokens a decode
-        loop is asked for) x top_k x layers. A device array is not
-        read: all its positions count (pads are routed like tokens)."""
-        if not self._moe_attrs:
+        loop is asked for) x top_k x sparse layers, over ALL the
+        router's experts whatever share of them is held. A device
+        array is not read: all its positions count (pads are routed
+        like tokens). ``conv_tokens_total{role}`` likewise: tokens x
+        conv layers of a patterned model."""
+        tokens = decode_tokens + (
+            int(np.count_nonzero(seg_ids))
+            if isinstance(seg_ids, np.ndarray) else int(seg_ids.size))
+        if self.cfg.conv_layers:
+            metrics.inc("conv_tokens_total",
+                        tokens * len(self.cfg.conv_layers),
+                        role=str(self.ctx.model_name.role))
+        if "moe_dispatch" not in self._model_attrs:
             return
-        tokens = int(np.count_nonzero(seg_ids)) \
-            if isinstance(seg_ids, np.ndarray) else int(seg_ids.size)
         metrics.inc("moe_routed_pairs_total",
-                    (tokens + decode_tokens) * self.cfg.moe.top_k
-                    * self.cfg.n_layers,
+                    tokens * self.cfg.moe.top_k * self.cfg.n_moe_layers,
                     role=str(self.ctx.model_name.role),
-                    dispatch=self._moe_attrs["moe_dispatch"])
+                    dispatch=self._model_attrs["moe_dispatch"])
 
     def _report_moe_load(self, stats: Dict[str, Any]):
-        """The train step's load statistic (``ops.moe.LOAD_STAT``: the
-        worst layer of the worst microbatch) as gauge
-        ``moe_load_max_over_mean{role}`` and as an attribute of the
-        ``engine:train*`` span that has just ended."""
-        load = stats.get(moe_ops.LOAD_STAT)
-        if load is None:
-            return
-        load = float(np.max(load))
-        metrics.set_gauge("moe_load_max_over_mean", load,
-                          role=str(self.ctx.model_name.role))
-        self._last_span.set_attribute(moe_ops.LOAD_STAT, load)
+        """The train step's load statistics (``ops.moe.STATS``) as
+        attributes of the ``engine:train*`` span that has just ended
+        and as metrics: ``LOAD_STAT`` (the worst layer of the worst
+        microbatch) as gauge ``moe_load_max_over_mean{role}``; of a
+        model that holds a share of its experts also
+        ``moe_held_load_max_over_mean{role}`` and counters
+        ``moe_held_pairs_total{role}`` and
+        ``moe_share_overflow_total{role}``."""
+        role = str(self.ctx.model_name.role)
+
+        def worst(name):
+            load = float(np.max(stats[name]))
+            self._last_span.set_attribute(name, load)
+            return load
+
+        if moe_ops.LOAD_STAT in stats:
+            metrics.set_gauge("moe_load_max_over_mean",
+                              worst(moe_ops.LOAD_STAT), role=role)
+        if moe_ops.HELD_LOAD_STAT in stats:
+            metrics.set_gauge("moe_held_load_max_over_mean",
+                              worst(moe_ops.HELD_LOAD_STAT), role=role)
+        if moe_ops.HELD_PAIRS_STAT in stats:
+            # the pairs the held experts' products multiplied: only
+            # the device knows them
+            held = float(np.sum(stats[moe_ops.HELD_PAIRS_STAT]))
+            metrics.inc("moe_held_pairs_total", held, role=role)
+            self._last_span.set_attribute(moe_ops.HELD_PAIRS_STAT, held)
+            # the (layer, microbatch) whose held pairs passed the fast
+            # path's rows: each cost a whole layer's grouped products
+            slow = float(np.sum(stats[moe_ops.SHARE_OVERFLOW_STAT]))
+            metrics.inc("moe_share_overflow_total", slow, role=role)
+            self._last_span.set_attribute(moe_ops.SHARE_OVERFLOW_STAT, slow)
 
     def _decode_attrs(self, program_key, b: int, lp: int,
                       max_new_tokens: int) -> Dict[str, Any]:
@@ -464,7 +507,7 @@ class Engine:
         - inference constrains the residual stream's sharding,
           training does NOT. Nobody chose that: ROADMAP D14.
         """
-        with_aux = train and self.cfg.mlp_type == "moe"
+        with_aux = train and self.cfg.n_moe_layers > 0
         pipeline = self._pipeline_ctx
         if train:
             constrain = None
@@ -547,9 +590,9 @@ class Engine:
             gnorm = optax.global_norm(gsum)
             mean_stats = jax.tree.map(
                 lambda s: (s * mb_weights / wsum).sum(), stats)
-            if moe_ops.LOAD_STAT in stats:  # the worst microbatch's
-                mean_stats[moe_ops.LOAD_STAT] = \
-                    stats[moe_ops.LOAD_STAT].max()
+            for name, reduce in moe_ops.STATS.items():
+                if name in stats:  # the worst microbatch's, or all's
+                    mean_stats[name] = reduce(stats[name])
             # Reserved stat "__skip_update__": when any microbatch sets
             # it > 0, the whole optimizer step is discarded -- params,
             # optimizer moments, and step count stay untouched (PPO
@@ -926,6 +969,15 @@ class Engine:
         for k, v in self._decode_attrs(cache_key, *prompt_seg.shape,
                                        gconfig.max_new_tokens).items():
             self._last_span.set_attribute(k, v)
+        if self.cfg.layer_pattern is not None:
+            # the two kinds of state the decode loop carried
+            self._last_span.set_attribute(
+                "kv_layers", len(self.cfg.attention_layers))
+            self._last_span.set_attribute(
+                "conv_state_bytes",
+                int(np.prod(T.conv_state_shape(
+                    self.cfg, prompt_seg.shape[0])))
+                * jnp.dtype(self.cfg.compute_dtype).itemsize)
         return out
 
     def inflight_generator(self, gconfig: GenerationHyperparameters,
@@ -935,6 +987,8 @@ class Engine:
         generator's own (slots, prompt length, end and pad tokens).
         Call it on :meth:`decode_engine`."""
         from realhf_tpu.engine.inflight import InflightBatchingGenerator
+        self.cfg.require_one_block(
+            "the slot engine (engine/inflight.py)")
         return InflightBatchingGenerator(
             self.cfg, self.params, gconfig,
             moe_constraint=self._moe_constraint, mesh=self.mesh,

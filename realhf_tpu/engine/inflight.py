@@ -104,6 +104,7 @@ class InflightBatchingGenerator:
                 "inflight batching does not produce the PPO logits "
                 "mask; set force_no_logits_mask=True or use the batch "
                 "generate path.")
+        cfg.require_one_block("the slot engine (engine/inflight.py)")
         self.cfg = cfg
         self.params = params
         self.g = gconfig

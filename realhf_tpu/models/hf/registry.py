@@ -38,6 +38,13 @@ class HFFamily:
     # stacked pytree <-> HF flat state dict of numpy arrays
     params_from_hf: Callable[[StateDict, TransformerConfig], Dict[str, Any]]
     params_to_hf: Callable[[Dict[str, Any], TransformerConfig], StateDict]
+    # A family whose layers are not all of one kind
+    # (TransformerConfig.layer_pattern) also converts ONE layer:
+    # (state, cfg, i) -> params["layers"][str(i)], and
+    # (that tree, cfg, i, out) writing layer i's HF tensors into out.
+    # The streamed load and save go through these.
+    layer_from_hf: Optional[Callable[..., Dict[str, Any]]] = None
+    layer_to_hf: Optional[Callable[..., None]] = None
 
 
 HF_FAMILIES: Dict[str, HFFamily] = {}
@@ -288,6 +295,23 @@ def load_hf_checkpoint_streamed(path: str, mesh, family: Optional[str] = None,
     def put_full(leaf, sh):
         return jax.device_put(np.asarray(leaf).astype(tdt, copy=False), sh)
 
+    if cfg.layer_pattern is not None:
+        # a tree a layer: nothing is stacked, so each layer's leaves go
+        # to the device as they are read and the host holds one layer
+        params = shard_rules.normalize_vocab_padding(
+            cfg, params_from_hf(family, state, _without_layers(cfg)), tp)
+        params = jax.tree.map(
+            put_full, params,
+            {k: {} if k == "layers" else v
+             for k, v in shardings.items() if k in params})
+        convert = HF_FAMILIES[family].layer_from_hf
+        for i in range(cfg.n_layers):
+            params["layers"][str(i)] = jax.tree.map(
+                put_full, convert(state, cfg, i),
+                shardings["layers"][str(i)])
+        return cfg, _with_value_head(params, path, cfg, is_critic,
+                                     put_full, shardings)
+
     write_cache: Dict[Any, Any] = {}
 
     def write_slice(buf, sl, i, sh):
@@ -347,19 +371,37 @@ def load_hf_checkpoint_streamed(path: str, mesh, family: Optional[str] = None,
             params = jax.tree_util.tree_unflatten(
                 jax.tree_util.tree_structure(params), new_leaves)
 
+    return cfg, _with_value_head(params, path, cfg, is_critic, put_full,
+                                 shardings)
+
+
+def _without_layers(cfg: TransformerConfig) -> TransformerConfig:
+    """A patterned config's twin with no layers: what a family's
+    whole-model converters make of it is the embedding, the final norm
+    and the head alone."""
+    import copy
+    cfg0 = copy.copy(cfg)
+    cfg0.n_layers, cfg0.layer_pattern = 0, ()
+    return cfg0
+
+
+def _with_value_head(params, path, cfg, is_critic, put_full, shardings):
+    """The streamed load's last step: a critic's value head from its
+    file, or a fresh one."""
+    if not is_critic:
+        return params
+    import safetensors.numpy
     vh_path = os.path.join(path, _VALUE_HEAD_NAME)
-    if is_critic:
-        import safetensors.numpy
-        if os.path.exists(vh_path):
-            vh = safetensors.numpy.load_file(vh_path)
-            w = vh["value_head.weight"]
-        else:
-            rng = np.random.RandomState(0)
-            w = rng.normal(0, 0.02,
-                           size=(cfg.hidden_dim, 1)).astype(np.float32)
-            logger.info("Initialized critic value head from scratch.")
-        params["head"] = {"w": put_full(w, shardings["head"]["w"])}
-    return cfg, params
+    if os.path.exists(vh_path):
+        vh = safetensors.numpy.load_file(vh_path)
+        w = vh["value_head.weight"]
+    else:
+        rng = np.random.RandomState(0)
+        w = rng.normal(0, 0.02,
+                       size=(cfg.hidden_dim, 1)).astype(np.float32)
+        logger.info("Initialized critic value head from scratch.")
+    params["head"] = {"w": put_full(w, shardings["head"]["w"])}
+    return params
 
 
 def save_hf_checkpoint(path: str, family: str, cfg: TransformerConfig,
@@ -508,7 +550,8 @@ def save_hf_checkpoint_streamed(path: str, family: str,
     nonlayer_host = {}
     from realhf_tpu.models.sharding import repad_vocab_leaf
     for kp, leaf in flat:
-        if not (kp and getattr(kp[0], "key", None) == "blocks"):
+        if not (kp and getattr(kp[0], "key", None) in ("blocks",
+                                                        "layers")):
             keypath = tuple(e.key for e in kp)
             # checkpoints store the true vocab; the device copy is
             # Megatron-padded for its tp (repad to tp=1 == unpad)
@@ -553,7 +596,25 @@ def save_hf_checkpoint_streamed(path: str, family: str,
         k: np.zeros((1,) * v.ndim, v.dtype)
         for k, v in nonlayer_host.items()}
 
-    for i in range(cfg.n_layers):
+    stacked_layers = range(cfg.n_layers)
+    if cfg.layer_pattern is not None:
+        # a tree a layer: each layer's leaves to the host and into a
+        # file of its own, then the rest through the family's
+        # whole-model converter on the layerless twin
+        stacked_layers = ()
+        convert = HF_FAMILIES[family].layer_to_hf
+        for i in range(cfg.n_layers):
+            layer_state: StateDict = {}
+            convert(jax.tree.map(to_host, params["layers"][str(i)]),
+                    cfg, i, layer_state)
+            write_file(i, layer_state)
+        rest: Dict[str, Any] = {"layers": {}}
+        for keypath, leaf in nonlayer_host.items():
+            rest.setdefault(keypath[0], {})[keypath[1]] = leaf
+        write_file(cfg.n_layers,
+                   params_to_hf(family, rest, _without_layers(cfg)))
+
+    for i in stacked_layers:
         leaves = []
         for kp, leaf in flat:
             if kp and getattr(kp[0], "key", None) == "blocks":

@@ -36,6 +36,10 @@ def param_pspecs(cfg: TransformerConfig,
     real_llm_parallel.py:342); embedding/head/final-norm stay
     pipe-replicated and run outside the pipeline loop.
     """
+    if cfg.layer_pattern is not None:
+        if pipeline_parallel:
+            cfg.require_one_block("pipeline parallelism")
+        return _pattern_pspecs(cfg)
     lead = PIPE_AXIS if pipeline_parallel else None
     col = P(lead, None, MODEL_AXIS)      # [nl, H, out_sharded]
     row = P(lead, MODEL_AXIS, None)      # [nl, in_sharded, H]
@@ -89,6 +93,46 @@ def param_pspecs(cfg: TransformerConfig,
         specs["blocks"]["ln1"]["bias"] = rep2
         specs["blocks"]["ln2"]["bias"] = rep2
         specs["ln_f"]["bias"] = P(None)
+    if cfg.is_critic:
+        specs["head"] = {"w": P(None, None)}
+    elif not cfg.tied_embedding:
+        specs["head"] = {"w": P(None, MODEL_AXIS)}
+    return specs
+
+
+def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
+    """``param_pspecs`` of a patterned model: the same rules with no
+    layer axis, a tree a layer. The convolution is tensor parallel
+    like a feed-forward: ``w_in`` by column (GSPMD moves its three
+    parts to a sharding by channel after the split), the taps by
+    channel, ``w_out`` by row."""
+    col, row = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
+    layers = {}
+    for i, (op, ff) in enumerate(cfg.layer_pattern):
+        lp: Dict[str, Any] = {"ln1": {"scale": P(None)},
+                              "ln2": {"scale": P(None)}}
+        if op == "conv":
+            lp["conv"] = {"w_in": col, "w": col, "w_out": row}
+        else:
+            lp["attn"] = {"wq": col, "wk": col, "wv": col, "wo": row}
+            if cfg.qk_norm == "head":  # one head's width: on every shard
+                lp["attn"].update(q_norm=P(None), k_norm=P(None))
+            elif cfg.qk_norm is not None:
+                lp["attn"].update(q_norm=P(MODEL_AXIS),
+                                  k_norm=P(MODEL_AXIS))
+        if ff == "moe":
+            lp["mlp"] = {"router": P(None, None),
+                         "wg": P(None, None, MODEL_AXIS),
+                         "wu": P(None, None, MODEL_AXIS),
+                         "wd": P(None, MODEL_AXIS, None)}
+            if cfg.moe.use_expert_bias:
+                lp["mlp"]["expert_bias"] = P(None)
+        else:
+            lp["mlp"] = {"wg": col, "wu": col, "wd": row}
+        layers[str(i)] = lp
+    specs: Dict[str, Any] = {"embed": {"wte": P(MODEL_AXIS, None)},
+                             "layers": layers,
+                             "ln_f": {"scale": P(None)}}
     if cfg.is_critic:
         specs["head"] = {"w": P(None, None)}
     elif not cfg.tied_embedding:
@@ -273,7 +317,7 @@ def moe_ep_constraint(cfg: TransformerConfig, mesh: Mesh):
     turns the GShard dispatch/combine einsums into all-to-alls instead
     of letting XLA all-gather the expert weights. Returns None for
     non-EP configs (the common case)."""
-    if not (cfg.mlp_type == "moe" and cfg.moe is not None
+    if not (cfg.n_moe_layers and cfg.moe is not None
             and cfg.moe.expert_parallel):
         return None
 

@@ -17,6 +17,16 @@ Design (idiomatic JAX, not a torch translation):
 - Generation uses a per-layer KV cache pytree and a single-token
   decode step; the jitted decode loop replaces CUDA-graph capture
   (reference ``nn/real_llm_generate.py:214``).
+- A model whose layers are NOT all of one kind
+  (``TransformerConfig.layer_pattern``: gated short convolutions among
+  attention layers, a dense lead before sparse layers) cannot stack
+  its weights: it holds a tree a layer under ``params["layers"]``
+  (``{"0": ..., "1": ...}``) and every layer loop below is unrolled
+  over the pattern, each layer computing what its (operator,
+  feed-forward) says. Its cache is two kinds of state side by side:
+  K and V for the attention layers alone (stacked over THOSE), and
+  the last ``conv_kernel - 1`` rows of the convolution's input for
+  each conv layer. A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
 0 = embedding, 1..n_layers = blocks, n_layers+1 = head -- used by HF
@@ -43,6 +53,8 @@ KVCache = Dict[str, jnp.ndarray]
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     """Random-normal init (std 0.02, projection layers scaled by
     1/sqrt(2*n_layers) as in GPT-2/llama lineage)."""
+    if cfg.layer_pattern is not None:
+        return _init_pattern_params(cfg, key)
     pdt = jnp.dtype(cfg.param_dtype)
     h, f, v = cfg.hidden_dim, cfg.intermediate_dim, cfg.vocab_size
     nl, hd = cfg.n_layers, cfg.head_dim
@@ -121,6 +133,59 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     return params
 
 
+def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
+    """``init_params`` of a patterned model: the same distributions,
+    one tree a layer, each with the leaves of its own kind only."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    h, f, v = cfg.hidden_dim, cfg.intermediate_dim, cfg.vocab_size
+    nq, nkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
+    std = 0.02
+    proj_std = std / (2 * cfg.n_layers) ** 0.5
+    keys = iter(jax.random.split(key, 16 * cfg.n_layers + 4))
+
+    def norm(shape, s=std):
+        return (s * jax.random.normal(next(keys), shape)).astype(pdt)
+
+    def ones(shape):
+        return jnp.ones(shape, dtype=pdt)
+
+    layers = {}
+    for i, (op, ff) in enumerate(cfg.layer_pattern):
+        lp = {"ln1": {"scale": ones((h,))}, "ln2": {"scale": ones((h,))}}
+        if op == "conv":
+            lp["conv"] = {"w_in": norm((h, 3 * h)),
+                          "w": norm((cfg.conv_kernel, h)),
+                          "w_out": norm((h, h), proj_std)}
+        else:
+            lp["attn"] = {"wq": norm((h, nq * hd)),
+                          "wk": norm((h, nkv * hd)),
+                          "wv": norm((h, nkv * hd)),
+                          "wo": norm((nq * hd, h), proj_std)}
+            if cfg.qk_norm is not None:
+                heads = (1, 1) if cfg.qk_norm == "head" else (nq, nkv)
+                lp["attn"]["q_norm"] = ones((heads[0] * hd,))
+                lp["attn"]["k_norm"] = ones((heads[1] * hd,))
+        if ff == "moe":
+            ne, nh = cfg.moe.num_experts, cfg.moe.n_held
+            fe = cfg.moe.intermediate_dim or f
+            lp["mlp"] = {"router": norm((h, ne)),
+                         "wg": norm((nh, h, fe)), "wu": norm((nh, h, fe)),
+                         "wd": norm((nh, fe, h), proj_std)}
+            if cfg.moe.use_expert_bias:
+                lp["mlp"]["expert_bias"] = jnp.zeros((ne,), pdt)
+        else:
+            lp["mlp"] = {"wg": norm((h, f)), "wu": norm((h, f)),
+                         "wd": norm((f, h), proj_std)}
+        layers[str(i)] = lp
+    params: Params = {"embed": {"wte": norm((v, h))}, "layers": layers,
+                      "ln_f": {"scale": ones((h,))}}
+    if cfg.is_critic:
+        params["head"] = {"w": norm((h, 1))}
+    elif not cfg.tied_embedding:
+        params["head"] = {"w": norm((h, v))}
+    return params
+
+
 # ----------------------------------------------------------------------
 # Building blocks
 # ----------------------------------------------------------------------
@@ -159,21 +224,27 @@ def _activation(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _mlp(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
-         moe_constraint=None) -> jnp.ndarray:
-    out, _ = _mlp_with_aux(cfg, lp, x, None, moe_constraint)
+         moe_constraint=None, sparse: Optional[bool] = None
+         ) -> jnp.ndarray:
+    out, _ = _mlp_with_aux(cfg, lp, x, None, moe_constraint, sparse)
     return out
 
 
 def _mlp_with_aux(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
                   seg_ids: Optional[jnp.ndarray] = None,
-                  moe_constraint=None):
+                  moe_constraint=None, sparse: Optional[bool] = None):
     """MLP returning (output, aux dict) -- non-empty only for MoE
     (router load-balancing / z losses, reference utils/moe.py:395,
-    and the load statistic ``ops.moe.LOAD_STAT``).
-    ``seg_ids`` masks padding out of MoE routing/capacity/losses."""
+    and the statistics of ``ops.moe.STATS``).
+    ``seg_ids`` masks padding out of MoE routing/capacity/losses.
+    ``sparse``: whether THIS layer's feed-forward is the mixture of
+    experts; a patterned model says it a layer, a model of one block
+    by ``mlp_type``."""
     cdt = jnp.dtype(cfg.compute_dtype)
     m = lp["mlp"]
-    if cfg.mlp_type == "moe":
+    if sparse is None:
+        sparse = cfg.mlp_type == "moe"
+    if sparse:
         from realhf_tpu.ops.moe import moe_mlp_with_losses
         squeeze = x.ndim == 2  # decode step: [B, H]
         x3 = x[:, None, :] if squeeze else x
@@ -218,7 +289,54 @@ def _qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray):
     q = q.reshape(*lead, cfg.n_q_heads, cfg.head_dim)
     k = k.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm == "head":
+        # over each head's own values, one scale of width head_dim for
+        # all heads, before the rotary embedding
+        q = _norm(cfg, q, a["q_norm"], None)
+        k = _norm(cfg, k, a["k_norm"], None)
     return q, k, v
+
+
+def _short_conv(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+                seg_ids: jnp.ndarray):
+    """The gated short convolution over packed rows: u [B, L, H] (the
+    normed residual) -> (its output [B, L, H], s [B, L, H]).
+
+    ``[b, g, z] = split3(u W_in)``, ``s = b * z``, a depthwise causal
+    convolution of ``conv_kernel`` taps over s (tap ``w[K-1]`` on the
+    token itself, ``w[K-1-d]`` on the one d before it), gated by g,
+    then ``W_out``. A token's window stops at its DOCUMENT's first
+    token: s of another segment of the packed row, or of padding,
+    counts as 0 (``seg_ids``; each id one contiguous run)."""
+    cdt = u.dtype
+    k = cfg.conv_kernel
+    n = u.shape[1]
+    b_, g, z = jnp.split(u @ c["w_in"].astype(cdt), 3, axis=-1)
+    s = b_ * z
+    w = c["w"].astype(jnp.float32)
+    acc = s.astype(jnp.float32) * w[k - 1]
+    for d in range(1, k):
+        before = jnp.pad(s, ((0, 0), (d, 0), (0, 0)))[:, :n]
+        same = (seg_ids != 0) & (
+            seg_ids == jnp.pad(seg_ids, ((0, 0), (d, 0)))[:, :n])
+        acc = acc + jnp.where(same[..., None],
+                              before.astype(jnp.float32), 0.0) * w[k - 1 - d]
+    return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), s
+
+
+def _short_conv_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+                     state: jnp.ndarray):
+    """One token of :func:`_short_conv`: u [B, H] and the stream's
+    last ``conv_kernel - 1`` rows of s, oldest first [B, K-1, H]
+    (zeros before the document's first token) -> (output [B, H], the
+    state moved on by one row)."""
+    cdt = u.dtype
+    b_, g, z = jnp.split(u @ c["w_in"].astype(cdt), 3, axis=-1)
+    window = jnp.concatenate(
+        [state, (b_ * z)[:, None].astype(state.dtype)], axis=1)
+    acc = (window.astype(jnp.float32)
+           * c["w"].astype(jnp.float32)[None]).sum(axis=1)
+    return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), window[:, 1:]
 
 
 def _attn_scale(cfg: TransformerConfig, layer_idx: jnp.ndarray) -> jnp.ndarray:
@@ -228,14 +346,12 @@ def _attn_scale(cfg: TransformerConfig, layer_idx: jnp.ndarray) -> jnp.ndarray:
     return scale
 
 
-def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
-           x: jnp.ndarray, seg_ids: jnp.ndarray, cos: jnp.ndarray,
-           sin: jnp.ndarray, constrain, attention_fn=None,
-           moe_constraint=None):
-    """One transformer block over packed streams [B, L, H]; returns
-    (residual output, (k, v), aux-losses) -- k/v feed prefill KV
-    caches; aux is non-empty for MoE."""
-    ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+def _attention_op(cfg: TransformerConfig, lp: Params,
+                  layer_idx: jnp.ndarray, ln1: jnp.ndarray,
+                  seg_ids: jnp.ndarray, cos: jnp.ndarray,
+                  sin: jnp.ndarray, attention_fn=None):
+    """Attention over packed streams on the normed residual ``ln1``
+    [B, L, H] -> (its projected output [B, L, H], (k, v))."""
     q, k, v = _qkv(cfg, lp, ln1)
     if cfg.apply_rotary:
         q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
@@ -244,15 +360,37 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     attn = attn_impl(q, k, v, seg_ids, causal=True,
                      scale=_attn_scale(cfg, layer_idx),
                      sliding_window=cfg.sliding_window)
-    attn = attn.reshape(*x.shape[:-1], cfg.n_q_heads * cfg.head_dim)
-    proj = attn @ lp["attn"]["wo"].astype(x.dtype)
+    attn = attn.reshape(*ln1.shape[:-1], cfg.n_q_heads * cfg.head_dim)
+    proj = attn @ lp["attn"]["wo"].astype(ln1.dtype)
     if "bo" in lp["attn"]:
-        proj = proj + lp["attn"]["bo"].astype(x.dtype)
+        proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
+    return proj, (k, v)
+
+
+def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
+           x: jnp.ndarray, seg_ids: jnp.ndarray, cos: jnp.ndarray,
+           sin: jnp.ndarray, constrain, attention_fn=None,
+           moe_constraint=None, kind=None):
+    """One block over packed streams [B, L, H]; returns (residual
+    output, state, aux-losses). ``kind``: the layer's (operator,
+    feed-forward) in a patterned model, None for the one block of
+    ``mlp_type``. The state feeds prefill's caches: (k, v) of an
+    attention layer, the convolution's input s [B, L, H] of a conv
+    layer; aux is non-empty for MoE."""
+    op, sparse = ("attention", None) if kind is None \
+        else (kind[0], kind[1] == "moe")
+    ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+    if op == "conv":
+        proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
+    else:
+        proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
+                                    cos, sin, attention_fn)
     x = constrain(x + proj)
     ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
-    mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, seg_ids, moe_constraint)
+    mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, seg_ids, moe_constraint,
+                                 sparse)
     x = constrain(x + mlp_out)
-    return x, (k, v), aux
+    return x, state, aux
 
 
 def positions_from_segments(seg_ids: jnp.ndarray) -> jnp.ndarray:
@@ -315,6 +453,8 @@ def forward(
         sin = jnp.zeros((*positions.shape, half), jnp.float32)
 
     if pipeline is not None and pipeline.n_stages > 1:
+        cfg.require_one_block(
+            "pipeline parallelism (parallel/pipeline.py, schedule.py)")
         # Pipeline parallelism: blocks are stage-sharded over the
         # "pipe" mesh axis and run as a microbatch-rotation schedule
         # (parallel/pipeline.py). Embedding/rotary above and head/norm
@@ -323,7 +463,7 @@ def forward(
             "KV-cache prefill on a pipeline-parallel mesh is not "
             "supported; allocate generation MFCs on a dp/tp layout "
             "(decoupled allocation).")
-        from realhf_tpu.ops.moe import LOAD_STAT
+        from realhf_tpu.ops.moe import STATS
         from realhf_tpu.parallel.pipeline import pipeline_blocks
 
         def pblock(lp, layer_idx, carry, seg, cos_, sin_):
@@ -332,7 +472,8 @@ def forward(
                                moe_constraint)
             # the schedules add every aux entry up over ticks and
             # stages: right for the losses, not for a maximum
-            aux.pop(LOAD_STAT, None)
+            for stat in STATS:
+                aux.pop(stat, None)
             return y, aux
 
         # Nested remat for the 1F1B-class memory profile: each block
@@ -377,6 +518,13 @@ def forward(
             return x, None, aux
         return x, None
 
+    if cfg.layer_pattern is not None:
+        x, states, aux = _pattern_layers(
+            cfg, params["layers"], x, seg_ids, cos, sin, constrain,
+            attention_fn, moe_constraint, return_kv, return_aux)
+        x = _norm(cfg, x, params["ln_f"]["scale"], None)
+        return (x, states, aux) if return_aux else (x, states)
+
     def block_fn(lp, layer_idx, carry):
         # cfg/constrain are non-array closures; seg_ids/cos/sin are
         # array closures -- jax.checkpoint differentiates through
@@ -403,6 +551,46 @@ def forward(
         from realhf_tpu.ops.moe import reduce_layers
         return x, kvs, reduce_layers(auxs or {})
     return x, kvs
+
+
+def _pattern_layers(cfg, layers, x, seg_ids, cos, sin, constrain,
+                    attention_fn, moe_constraint, return_kv, return_aux):
+    """The layers of a patterned model, unrolled: x -> (x, states,
+    aux). ``states`` (for prefill): K and V stacked over the ATTENTION
+    layers [n_attn, B, L, nkv, hd] and the convolutions' inputs
+    stacked over the CONV layers [n_conv, B, L, H]; None unless
+    ``return_kv``. ``aux``: the sparse layers' entries reduced as
+    ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
+    ks, vs, convs, auxs = [], [], [], []
+    for i, kind in enumerate(cfg.layer_pattern):
+        def block_fn(lp, carry, i=i, kind=kind):
+            return _block(cfg, lp, jnp.int32(i), carry, seg_ids, cos,
+                          sin, constrain, attention_fn, moe_constraint,
+                          kind)
+
+        if cfg.gradient_checkpointing:
+            block_fn = jax.checkpoint(
+                block_fn,
+                policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
+        x, state, aux = block_fn(layers[str(i)], x)
+        if return_kv and kind[0] == "attention":
+            ks.append(state[0])
+            vs.append(state[1])
+        elif return_kv:
+            convs.append(state)
+        if aux:
+            auxs.append(aux)
+    states = None
+    if return_kv:
+        states = {name: jnp.stack(rows) if rows else None
+                  for name, rows in (("k", ks), ("v", vs),
+                                     ("conv", convs))}
+    aux = {}
+    if return_aux and auxs:
+        from realhf_tpu.ops.moe import reduce_layers
+        aux = reduce_layers({k: jnp.stack([a[k] for a in auxs])
+                             for k in auxs[0]})
+    return x, states, aux
 
 
 def lm_logits(cfg: TransformerConfig, params: Params,
@@ -463,13 +651,24 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     reference `prepare_generate_inputs` (real_llm_generate.py:179)."""
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     max_len = round_cache_len(max_len)
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
-    return {
+    shape = (len(cfg.attention_layers), batch, cfg.n_kv_heads, max_len,
+             cfg.head_dim)
+    cache = {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
         "valid": jnp.zeros((batch, max_len), bool),
         "length": jnp.zeros((batch,), jnp.int32),
     }
+    if cfg.conv_layers:
+        cache["conv"] = jnp.zeros(conv_state_shape(cfg, batch), dtype)
+    return cache
+
+
+def conv_state_shape(cfg: TransformerConfig, batch: int):
+    """The conv layers' decode state: for each conv layer and stream
+    the last ``conv_kernel - 1`` rows of the convolution's input."""
+    return (len(cfg.conv_layers), batch, cfg.conv_kernel - 1,
+            cfg.hidden_dim)
 
 
 def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
@@ -488,10 +687,25 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
                           activation_constraint=activation_constraint,
                           attention_fn=attention_fn,
                           moe_constraint=moe_constraint)
-    k, v = kvs  # [nl, B, L, nkv, hd]
+    b, lp = input_ids.shape
+    conv = None
+    if cfg.layer_pattern is None:
+        k, v = kvs  # [nl, B, L, nkv, hd]
+    else:
+        # K and V of the attention layers alone; of each conv layer
+        # the last rows of its input, 0 where the row is padding
+        k, v, conv = kvs["k"], kvs["v"], kvs["conv"]
+        if k is None:
+            k = v = jnp.zeros((0, b, lp, cfg.n_kv_heads, cfg.head_dim),
+                              hidden.dtype)
+        if conv is not None:
+            t = min(cfg.conv_kernel - 1, lp)
+            conv = jnp.where((seg_ids[:, lp - t:] != 0)[None, :, :, None],
+                             conv[:, :, lp - t:], 0)
+            conv = jnp.pad(conv, [(0, 0), (0, 0),
+                                  (cfg.conv_kernel - 1 - t, 0), (0, 0)])
     k = k.transpose(0, 1, 3, 2, 4)  # -> [nl, B, nkv, L, hd] head-major
     v = v.transpose(0, 1, 3, 2, 4)
-    b, lp = input_ids.shape
     valid = seg_ids != 0
     total = round_cache_len(total_len if total_len is not None else lp)
     pad = total - lp
@@ -506,6 +720,8 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
         "valid": valid,
         "length": jnp.full((b,), lp, jnp.int32),
     }
+    if conv is not None:
+        cache["conv"] = conv
     return hidden, cache
 
 
@@ -520,11 +736,11 @@ def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
     pad = lambda a: jnp.concatenate(
         [a, jnp.zeros((nl, b, nkv, extra, hd), a.dtype)], axis=3)
     return {
+        **cache,  # length, and a patterned model's conv state
         "k": pad(cache["k"]),
         "v": pad(cache["v"]),
         "valid": jnp.concatenate(
             [cache["valid"], jnp.zeros((b, extra), bool)], axis=1),
-        "length": cache["length"],
     }
 
 
@@ -620,7 +836,7 @@ def decode_step(
         valid = cache["valid"].at[jnp.arange(b), slot].set(True)
     new_len = slot + 1
 
-    def layer_body(x, k_all, v_all, lp, l):
+    def layer_body(x, k_all, v_all, lp, l, sparse=None):
         # l: the layer, a Python int (unrolled) or a traced scalar
         ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
         q, k, v = _qkv(cfg, lp, ln1)  # q: [B, nq, hd]; k/v: [B, nkv, hd]
@@ -652,11 +868,30 @@ def decode_step(
             proj = proj + lp["attn"]["bo"].astype(x.dtype)
         x = x + proj
         ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
-        x = x + _mlp(cfg, lp, ln2, moe_constraint)
+        x = x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
         return x, k_all, v_all
 
     k_all, v_all = cache["k"], cache["v"]
-    if cfg.n_layers <= _DECODE_UNROLL_MAX_LAYERS:
+    new_conv = []
+    if cfg.layer_pattern is not None:
+        # a layer of the pattern at a time: an attention layer reads
+        # and writes ITS slice of the K/V stack (the stack holds the
+        # attention layers alone), a conv layer its two rows of state
+        for i, (op, ff) in enumerate(cfg.layer_pattern):
+            lp = params["layers"][str(i)]
+            if op == "attention":
+                x, k_all, v_all = layer_body(
+                    x, k_all, v_all, lp, cfg.attention_layers.index(i),
+                    ff == "moe")
+                continue
+            ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
+            proj, state = _short_conv_step(
+                cfg, lp["conv"], ln1, cache["conv"][len(new_conv)])
+            new_conv.append(state)
+            x = x + proj
+            ln2 = _norm(cfg, x, lp["ln2"]["scale"], None)
+            x = x + _mlp(cfg, lp, ln2, moe_constraint, ff == "moe")
+    elif cfg.n_layers <= _DECODE_UNROLL_MAX_LAYERS:
         for li in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
             x, k_all, v_all = layer_body(x, k_all, v_all, lp, li)
@@ -669,4 +904,6 @@ def decode_step(
             body, (x, k_all, v_all), (params["blocks"], layer_ids))
     x = _norm(cfg, x, params["ln_f"]["scale"], params["ln_f"].get("bias"))
     new_cache = {"k": k_all, "v": v_all, "valid": valid, "length": new_len}
+    if new_conv:
+        new_cache["conv"] = jnp.stack(new_conv)
     return x, new_cache

@@ -149,19 +149,33 @@ class Gauge(_Metric):
     def __init__(self, name: str, help: str = ""):
         super().__init__(name, help)
         self._values: Dict[LabelKey, float] = {}
+        # how often each series was written: a value alone cannot say
+        # whether it is new (``writes``)
+        self._writes: Dict[LabelKey, int] = {}
 
     def set(self, value: float, **labels):
+        key = _label_key(labels)
         with self._lock:
-            self._values[_label_key(labels)] = float(value)
+            self._values[key] = float(value)
+            self._writes[key] = self._writes.get(key, 0) + 1
 
     def inc(self, amount: float = 1.0, **labels):
         key = _label_key(labels)
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
+            self._writes[key] = self._writes.get(key, 0) + 1
 
     def value(self, **labels) -> float:
         with self._lock:
             return self._values.get(_label_key(labels), 0.0)
+
+    def writes(self) -> Dict[str, int]:
+        """Writes so far of every series, keyed as ``snapshot_value``
+        keys them: two readings tell which series were written in
+        between, whatever their values."""
+        with self._lock:
+            return {json.dumps(dict(k)) if k else "": n
+                    for k, n in self._writes.items()}
 
     def prometheus_lines(self) -> List[str]:
         with self._lock:
@@ -396,6 +410,13 @@ class MetricsRegistry:
         return {name: dict(type=m.kind, values=m.snapshot_value())
                 for name, m in sorted(metrics.items())}
 
+    def gauge_writes(self) -> Dict[str, Dict[str, int]]:
+        """``Gauge.writes`` of every gauge, by name."""
+        with self._lock:
+            metrics = dict(self._metrics)
+        return {name: m.writes() for name, m in metrics.items()
+                if isinstance(m, Gauge)}
+
     def to_prometheus(self) -> str:
         with self._lock:
             metrics = [m for _, m in sorted(self._metrics.items())]
@@ -502,6 +523,10 @@ def event(name: str, **fields) -> Dict:
 
 def snapshot() -> Dict[str, Dict]:
     return _default.snapshot()
+
+
+def gauge_writes() -> Dict[str, Dict[str, int]]:
+    return _default.gauge_writes()
 
 
 def to_prometheus() -> str:

@@ -87,9 +87,13 @@ KEPT_CAPTURES = 8
 #: counters whose deltas a capture reports (docs/observability.md)
 CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "engine_compiles_total", "engine_compile_secs_total",
-                    "moe_routed_pairs_total", "flash_kv_blocks_total")
-#: gauges whose last values a capture reports
-CAPTURE_GAUGES = ("moe_load_max_over_mean",)
+                    "moe_routed_pairs_total", "flash_kv_blocks_total",
+                    "moe_held_pairs_total", "conv_tokens_total",
+                    "moe_share_overflow_total")
+#: gauges whose last values a capture reports, where they were
+#: written while it ran
+CAPTURE_GAUGES = ("moe_load_max_over_mean",
+                  "moe_held_load_max_over_mean")
 
 
 def to_epoch(monotonic_secs: float) -> float:
@@ -219,7 +223,8 @@ class Capture:
     ``thread``, ``attributes``) in order of their start; ``counters``
     maps ``name{label=value,...}`` of every :data:`CAPTURE_COUNTERS`
     series to its growth in between, ``gauges`` every
-    :data:`CAPTURE_GAUGES` series to its value at ``stop``. With a
+    :data:`CAPTURE_GAUGES` series WRITTEN in between to its value at
+    ``stop`` (not what an earlier model of the process left). With a
     file path configured the spans already flushed to the file are not
     here as well."""
     spans: List[Dict[str, Any]]
@@ -282,6 +287,14 @@ def _metric_values(names=CAPTURE_COUNTERS) -> Dict[str, float]:
     return out
 
 
+def _gauge_writes() -> Dict[str, int]:
+    """Series of ``CAPTURE_GAUGES`` -> how often written so far."""
+    from realhf_tpu.obs import metrics
+    return {_series(name, json.loads(labels) if labels else {}): n
+            for name, series in metrics.gauge_writes().items()
+            if name in CAPTURE_GAUGES for labels, n in series.items()}
+
+
 class Tracer:
     """Span factory + buffer + exporter for one logical process."""
 
@@ -299,6 +312,7 @@ class Tracer:
         self._profile_dir: Optional[str] = None
         self._started: Optional[float] = None
         self._counters_at_start: Dict[str, float] = {}
+        self._gauge_writes_at_start: Dict[str, int] = {}
         #: what the last few start .. stop pairs recorded, newest last
         self._captures: Deque[Capture] = collections.deque(
             maxlen=KEPT_CAPTURES)
@@ -342,6 +356,7 @@ class Tracer:
             self.stop()
         self.drain()  # what an earlier configure(enabled=True) left
         self._counters_at_start = _metric_values()
+        self._gauge_writes_at_start = _gauge_writes()
         self.sync = sync if isinstance(sync, bool) else tuple(sync)
         if profile_dir is not None:
             import jax
@@ -377,7 +392,12 @@ class Tracer:
             counters={k: v for k, v in deltas.items() if v},
             start=self._started, end=time.monotonic(), sync=self.sync,
             profile_dir=profile_dir,
-            gauges=_metric_values(CAPTURE_GAUGES))
+            # a gauge another model of this process left behind is
+            # not this capture's: only what was written meanwhile
+            gauges={k: v for k, v in
+                    _metric_values(CAPTURE_GAUGES).items()
+                    if _gauge_writes().get(k)
+                    != self._gauge_writes_at_start.get(k)})
         self._started, self.sync = None, False
         self._captures.append(capture)
         return capture

@@ -30,6 +30,25 @@ Beside the auxiliary LOSSES the layer returns one statistic,
 never added to a loss (``aux_loss``), is reduced by max, not by sum,
 over layers and microbatches, and comes back with the train step's
 statistics.
+
+**A rank's share** (``MoEConfig.experts_held = (first, count)``): the
+layer holds ``count`` experts' weights, routes over all
+``num_experts`` and computes the part of the result its own experts
+give, in the ragged mode only (``_ragged_share``). The (token, k)
+pairs are sorted with the held experts' first; the first ``rows`` of
+them are gathered, multiplied in ``count`` groups and scattered back;
+pairs of absent experts are never multiplied: what those experts would
+have added is left out. ``rows`` is ``SHARE_ROWS_OVER_MEAN`` times the
+pairs even routing would bring the held experts; where a batch routes
+MORE to them, a ``lax.cond`` takes the same path over all ``T x k``
+sorted rows instead, so no pair of a held expert is ever dropped,
+whatever the imbalance. There is no exchange and nothing stands in
+for the other ranks. With every expert held the same code is the
+uncut layer. Three more statistics then come back, ``HELD_PAIRS_STAT``
+(the pairs of held experts, added up over layers and microbatches),
+``HELD_LOAD_STAT`` (the busiest HELD expert over the mean of all,
+``T x k / E``; max) and ``SHARE_OVERFLOW_STAT`` (the layers, added up
+likewise, whose held pairs passed ``rows`` and took the slow path).
 """
 
 from typing import Dict, Optional, Tuple
@@ -45,25 +64,44 @@ from realhf_tpu.models.config import MoEConfig, TransformerConfig
 #: an expert received over the mean (T * k / E), pads included (they
 #: are computed like any token)
 LOAD_STAT = "moe_load_max_over_mean"
+#: of a layer that holds a share of the experts: the (token, k) pairs
+#: routed to the experts it holds (the rows its grouped products
+#: multiply), and the busiest held expert's pairs over T * k / E
+HELD_PAIRS_STAT = "moe_held_pairs"
+HELD_LOAD_STAT = "moe_held_load_max_over_mean"
+#: and whether its held pairs passed the fast path's rows (0 or 1: the
+#: layer then gathered and multiplied all T * k sorted rows)
+SHARE_OVERFLOW_STAT = "moe_share_overflows"
+#: entries of the auxiliary dict that are statistics: never in a loss;
+#: how each is reduced over layers and over a step's microbatches
+STATS = {LOAD_STAT: jnp.max, HELD_LOAD_STAT: jnp.max,
+         HELD_PAIRS_STAT: jnp.sum, SHARE_OVERFLOW_STAT: jnp.sum}
 
 
 def aux_loss(aux: Dict[str, jnp.ndarray]):
     """What a training objective adds of a forward's auxiliary dict:
-    the losses, not the statistic."""
-    return sum(v for k, v in aux.items() if k != LOAD_STAT)
+    the losses, not the statistics."""
+    return sum(v for k, v in aux.items() if k not in STATS)
 
 
 def reduce_layers(auxs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     """Per-layer auxiliary entries [n_layers] -> scalars: losses add
-    up, the statistic is the worst layer's."""
-    return {k: v.max() if k == LOAD_STAT else v.sum()
-            for k, v in auxs.items()}
+    up, a statistic by its own rule (``STATS``)."""
+    return {k: STATS.get(k, jnp.sum)(v) for k, v in auxs.items()}
 
 
 def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
-                 key: Optional[jax.Array] = None
+                 key: Optional[jax.Array] = None,
+                 expert_bias: Optional[jnp.ndarray] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """[T, E] logits -> (top-k probs [T, k], indices [T, k]).
+
+    ``score_fn="sigmoid"`` (LFM2-MoE): every expert's score is the
+    sigmoid of its logit; the k are the largest of score +
+    ``expert_bias`` [E], which moves the CHOICE and never the gate and
+    takes no gradient; the gates are the chosen scores, divided by
+    (their sum + 1e-6) under ``norm_topk_prob``, times
+    ``routed_scaling_factor``.
 
     Default (aux_loss/none): softmax over all experts, take top-k,
     and with ``norm_topk_prob`` renormalize (Mixtral semantics,
@@ -80,6 +118,16 @@ def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
             key, logits.shape, minval=1.0 - cfg_moe.input_jitter_eps,
             maxval=1.0 + cfg_moe.input_jitter_eps)
         logits = logits * noise
+    if cfg_moe.score_fn == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if expert_bias is None else scores \
+            + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+        _, top_idx = jax.lax.top_k(choice, cfg_moe.top_k)
+        top_probs = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if cfg_moe.norm_topk_prob:
+            top_probs = top_probs / (
+                top_probs.sum(-1, keepdims=True) + 1e-6)
+        return top_probs * cfg_moe.routed_scaling_factor, top_idx
     if cfg_moe.routing_type == "sinkhorn":
         routed = sinkhorn(jax.lax.stop_gradient(logits))
         _, top_idx = jax.lax.top_k(routed, cfg_moe.top_k)
@@ -158,7 +206,7 @@ def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
 def dispatch_mode(cfg: TransformerConfig) -> Optional[str]:
     """Single source of truth for the dispatch a config's MoE layers
     take: "ragged", "dense" or "capacity"; None for a dense model."""
-    if cfg.mlp_type != "moe" or cfg.moe is None:
+    if cfg.moe is None or not cfg.n_moe_layers:
         return None
     if cfg.moe.capacity_factor is not None:
         return "capacity"
@@ -190,6 +238,72 @@ def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
     return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(weighted)
 
 
+#: rows the fast path of a share gathers, over the pairs that even
+#: routing would bring its experts (T x k x held / E). XLA:TPU's
+#: grouped matmul does skip the row tiles past its last group (16,384
+#: sorted rows of which 8 groups cover 2,048 cost what 2,048 rows
+#: alone do), but it leaves those rows UNWRITTEN, zero only by chance,
+#: in the forward and in the backward's products alike: with group
+#: sizes over the held pairs alone the cell's forward agreed with the
+#: reference and its gradient norm read 185,709 against 0.78, the loss
+#: standing still (PERF.md, PR 31). So every gathered row lies in a
+#: group, and gathering a bounded number of rows first also spares
+#: three quarters of the gather and of the scatter-add.
+SHARE_ROWS_OVER_MEAN = 2
+
+
+def share_rows(cfg: TransformerConfig, t: int) -> int:
+    """The sorted rows a share's fast path gathers of ``t`` tokens'
+    ``t x k``: ``SHARE_ROWS_OVER_MEAN`` times what even routing brings
+    the held experts, and no more than all."""
+    k, e = cfg.moe.top_k, cfg.moe.num_experts
+    _, count = cfg.moe.experts_held
+    return min(-(-SHARE_ROWS_OVER_MEAN * t * k * count // e), t * k)
+
+
+def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
+                  top_probs: jnp.ndarray, top_idx: jnp.ndarray,
+                  held_sizes: jnp.ndarray) -> jnp.ndarray:
+    """``_ragged_moe`` for a rank that holds experts ``first .. first
+    + count - 1`` (``m``'s stacks are theirs alone; ``held_sizes``
+    [count] their loads): the pairs sorted with the held experts'
+    first, in the stacks' order, and only the first ``rows`` of them
+    gathered, multiplied and scattered back. Rows past the held pairs
+    are zeroed on the way in and counted into the last group, so that
+    every row lies in a group (``lax.ragged_dot`` leaves a row that
+    no group covers undefined on the chip: ``SHARE_ROWS_OVER_MEAN``)
+    and adds nothing, to the result or to a gradient. ``rows`` is static: the
+    fast path's where the held pairs fit it, else all ``T x k``."""
+    from realhf_tpu.models.transformer import _activation
+    t, h = xt.shape
+    k, e = cfg.moe.top_k, cfg.moe.num_experts
+    first, _ = cfg.moe.experts_held
+    cdt = xt.dtype
+    order = jnp.argsort(((top_idx - first) % e).reshape(-1))
+    n_held = held_sizes.sum()
+    gates_flat = top_probs.reshape(-1)
+
+    def part(rows):
+        sel = order[:rows]
+        tok_idx = sel // k
+        mine = jnp.arange(rows) < n_held
+        xs = jnp.where(mine[:, None], xt[tok_idx], 0)
+        sizes = held_sizes.at[-1].add(rows - n_held)
+        gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), sizes)
+        up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), sizes)
+        down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
+                                  m["wd"].astype(cdt), sizes)
+        gates = jnp.where(mine, gates_flat[sel], 0.0)
+        weighted = down.astype(jnp.float32) * gates[:, None]
+        return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(weighted)
+
+    rows = share_rows(cfg, t)
+    if rows == t * k:
+        return part(rows)
+    return jax.lax.cond(n_held <= rows, lambda: part(rows),
+                        lambda: part(t * k))
+
+
 def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                         rng: Optional[jax.Array] = None,
                         valid_mask: Optional[jnp.ndarray] = None,
@@ -219,7 +333,8 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     logits = (xt.astype(jnp.float32)
               @ m["router"].astype(jnp.float32))  # [T, E]
     probs_full = jax.nn.softmax(logits, axis=-1)
-    top_probs, top_idx = router_probs(moe, logits, rng)
+    top_probs, top_idx = router_probs(moe, logits, rng,
+                                      m.get("expert_bias"))
     # pads contribute nothing: zero their gates everywhere below
     top_probs = top_probs * valid[:, None]
 
@@ -228,6 +343,11 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     load = jnp.bincount(top_idx.reshape(-1), length=e).astype(jnp.int32)
     ep = ep_constraint if ep_constraint is not None else (lambda a: a)
     mode = dispatch_mode(cfg)
+    held = moe.experts_held
+    if held is not None and mode != "ragged":
+        raise NotImplementedError(
+            f"experts_held={held} of {e} needs the ragged dispatch "
+            f"mode, not {mode!r}")
     if mode == "ragged":
         if ep_constraint is not None:
             raise ValueError(
@@ -235,8 +355,12 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                 "dispatch mode; ragged grouped GEMMs cannot shard the "
                 "group dim (set capacity_factor or "
                 "use_grouped_gemm=False).")
-        out = _ragged_moe(cfg, m, xt.astype(x.dtype), top_probs,
-                          top_idx, load)
+        if held is None:
+            out = _ragged_moe(cfg, m, xt.astype(x.dtype), top_probs,
+                              top_idx, load)
+        else:
+            out = _ragged_share(cfg, m, xt.astype(x.dtype), top_probs,
+                                top_idx, load[held[0]:held[0] + held[1]])
     elif mode == "dense":
         # Dense mode: every expert over all tokens, gate-weighted.
         xs = ep(jnp.broadcast_to(xt[None], (e, t, h)).astype(x.dtype))
@@ -271,6 +395,13 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
 
     losses = {LOAD_STAT: load.max().astype(jnp.float32)
               * (e / (t * moe.top_k))}
+    if held is not None:
+        mine = load[held[0]:held[0] + held[1]]
+        losses[HELD_PAIRS_STAT] = mine.sum().astype(jnp.float32)
+        losses[HELD_LOAD_STAT] = mine.max().astype(jnp.float32) \
+            * (e / (t * moe.top_k))
+        losses[SHARE_OVERFLOW_STAT] = (
+            mine.sum() > share_rows(cfg, t)).astype(jnp.float32)
     if moe.routing_type == "aux_loss" and moe.aux_loss_coeff:
         losses["moe_aux_loss"] = moe.aux_loss_coeff * load_balancing_loss(
             probs_full, top_idx, e, moe.top_k, valid=valid)

@@ -511,6 +511,9 @@ def calibrate_cost_model(
     seen = set()
     for role, mspec in spec.models.items():
         cfg = _model_config_of(mspec)
+        cfg.require_one_block(
+            "the allocation search's calibration probe (its FLOPs are "
+            "one dense block times n_layers)")
         key = (cfg.hidden_dim, cfg.intermediate_dim, cfg.n_q_heads,
                cfg.n_kv_heads, cfg.vocab_size, cfg.mlp_type)
         if key in seen:
@@ -615,6 +618,9 @@ def workloads_from_spec(spec, gen_tokens: int = 256,
     out = []
     for node in dfg.nodes:
         cfg = _model_config_of(spec.models[node.role])
+        cfg.require_one_block(
+            "the allocation search's cost model (base/monitor.py "
+            "counts one dense block times n_layers)")
         seqlens = [avg_seqlen] * node.n_seqs
         fwd = monitor.transformer_forward_flops(
             n_layers=cfg.n_layers, hidden_dim=cfg.hidden_dim,

@@ -1,0 +1,89 @@
+#!/usr/bin/env python3
+"""A builder's tool, no chip: lower the programs of the benchmark's
+accepted families (qwen2, mistral, olmoe) under a checkout and write
+their StableHLO texts, to show that a change to shared model code left
+a model of one block the programs it had.
+
+    git archive <parent> | tar -x -C /root/scratch/parent
+    JAX_PLATFORMS=cpu python3 scripts/lowered_programs.py /root/scratch/parent /root/scratch/hlo_parent
+    JAX_PLATFORMS=cpu python3 scripts/lowered_programs.py . /root/scratch/hlo_change
+    for f in /root/scratch/hlo_parent/*; do cmp $f /root/scratch/hlo_change/$(basename $f); done
+
+The SAME file (this one) runs against both trees: it imports
+``realhf_tpu`` and ``benchmark`` from the tree given first. At the
+cells' real widths, with abstract parameters: one microbatch's SFT
+forward and backward (``T.forward``, the interface's head and loss, a
+sparse model's auxiliary terms) and the whole ``generate`` program;
+and the engine's own ``train`` and ``logprobs`` programs
+(``Engine._train_step_body``: accumulation, optimizer, statistics) at
+the tests' tiny widths, where ``Engine`` can hold real arrays. Equal
+lowered text is equal input to the compiler. What it cannot see: the
+Pallas kernels engage only on a TPU backend (PR 31 changed none).
+"""
+import os
+import sys
+
+root, out = os.path.abspath(sys.argv[1]), sys.argv[2]
+sys.path.insert(0, root)
+os.makedirs(out, exist_ok=True)
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from benchmark import generate
+from realhf_tpu.models import hf as hf_models, transformer as T
+from realhf_tpu.interfaces import sft
+from realhf_tpu.ops import moe as moe_ops
+from realhf_tpu.engine import generation as gen_mod
+from realhf_tpu.ops.sampling import GenerationHyperparameters
+
+def dump(name, fn, *args, **kw):
+    txt = jax.jit(fn, **kw).lower(*args).as_text()
+    open(os.path.join(out, name + ".txt"), "w").write(txt)
+    print(name, len(txt))
+
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048)):
+    hf, meta = generate.load_config(os.path.join(root, "benchmark/configs", cfgname + ".json"))
+    cfg = hf_models.config_from_hf(fam, hf)
+    cfg.param_dtype = cfg.compute_dtype = "bfloat16"
+    cfg.gradient_checkpointing = True
+    shapes = jax.eval_shape(lambda k: T.init_params(cfg, k), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16), shapes)
+    sds = jax.ShapeDtypeStruct
+    mb = dict(input_ids=sds((1, L), jnp.int32), seg_ids=sds((1, L), jnp.int32), prompt_mask=sds((1, L), jnp.bool_))
+    loss_fn = sft._make_loss_fn(cfg)
+    moe = cfg.mlp_type == "moe"
+    def objective(p, mb):
+        o = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"], return_aux=moe)
+        aux = o[2] if moe else {}
+        loss, stats = loss_fn(p, o[0], mb)
+        return loss + moe_ops.aux_loss(aux), {**stats, **aux}
+    dump(f"{cfgname}.train_grad", lambda p, mb: jax.value_and_grad(objective, has_aux=True)(p, mb), params, mb)
+    if fam != "olmoe":
+        g = GenerationHyperparameters(max_new_tokens=256, min_new_tokens=256, greedy=False, force_no_logits_mask=True)
+        b = 128 if fam == "qwen2" else 32
+        dump(f"{cfgname}.generate",
+             lambda p, i, s, pos, k: gen_mod.generate(cfg, p, i, s, pos, k, g, eos_token_id=None, pad_token_id=0),
+             params, sds((b, 256), jnp.int32), sds((b, 256), jnp.int32), sds((b, 256), jnp.int32),
+             jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+
+# the engine's own train step, inference programs, at the tests' tiny configs (real arrays)
+from realhf_tpu.api.config import ModelName
+from realhf_tpu.engine.engine import Engine
+from realhf_tpu.engine.optim import OptimizerConfig
+from realhf_tpu.parallel import mesh as mesh_lib
+for name, fam, path in (("tiny-qwen2", "qwen2", "tests/benchmark/configs/tiny-qwen2.json"), ("tiny-olmoe", "olmoe", "tests/benchmark/olmoe/configs/tiny-olmoe.json")):
+    hf, meta = generate.load_config(os.path.join(root, path))
+    cfg = hf_models.config_from_hf(fam, hf)
+    cfg.param_dtype = "bfloat16"
+    par = mesh_lib.ParallelismConfig()
+    ctx = mesh_lib.MeshContext(ModelName("default", 0), mesh_lib.make_mesh(par, jax.devices()[:1]), par)
+    eng = Engine(cfg, ctx, jax.tree.map(np.asarray, T.init_params(cfg, jax.random.PRNGKey(0))),
+                 optimizer=OptimizerConfig(lr=1e-4, warmup_steps_proportion=0.0, lr_scheduler_type="constant"), total_train_steps=10)
+    ids = np.ones((2, 64), np.int32); seg = np.ones((2, 64), np.int32)
+    mb = dict(input_ids=ids, seg_ids=seg, prompt_mask=np.zeros((2, 64), bool))
+    eng.train_batch([mb, mb], sft._make_loss_fn(cfg), loss_fn_key="sft")
+    eng.forward_logprobs(ids, seg)
+    for prog in ("train", "logprobs"):
+        fn, args, static = eng._last_call[prog]
+        open(os.path.join(out, f"{name}.engine_{prog}.txt"), "w").write(fn.lower(*args, **static).as_text())
+        print(name, prog)

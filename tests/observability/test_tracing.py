@@ -526,7 +526,7 @@ class _Batch:
 class _FakeEngine:
     from realhf_tpu.engine.engine import Engine
     _run = Engine._run
-    _moe_attrs = {}  # a dense model: no moe_* span attributes
+    _model_attrs = {}  # a dense model of one block: no such attributes
     params = ()
 
     def __init__(self):
@@ -662,3 +662,18 @@ def test_sync_by_name_prefix_waits_only_for_those_spans():
     by = {s["name"]: s for s in capture.spans}
     assert by["engine:train"]["end"] < outer.ready_at \
         <= by["compute:actor_train"]["end"]
+
+
+def test_capture_reports_only_gauges_written_while_it_ran():
+    """A gauge that an earlier model of the process left behind is not
+    this capture's, whatever its value; one written meanwhile is, even
+    with the value it had before."""
+    from realhf_tpu.obs import metrics
+    metrics.set_gauge("moe_held_load_max_over_mean", 3.0, role="earlier")
+    metrics.set_gauge("moe_load_max_over_mean", 2.0, role="default")
+    tracing.start()
+    metrics.set_gauge("moe_load_max_over_mean", 2.0, role="default")
+    capture = tracing.stop()
+    assert capture.gauges == {"moe_load_max_over_mean{role=default}": 2.0}
+    tracing.start()
+    assert tracing.stop().gauges == {}

@@ -323,3 +323,223 @@ def test_load_statistic_is_the_worst_layers_not_a_sum():
     assert float(aux[moe_ops.LOAD_STAT]) == per_layer[-1] \
         == max(per_layer)
     assert float(aux["moe_aux_loss"]) > 0
+
+
+# ----------------------------------------------------------------------
+# The sigmoid-and-bias router and an expert-parallel rank's share
+# ----------------------------------------------------------------------
+def share_cfg(held=None, **moe):
+    return TransformerConfig(
+        n_layers=1, n_kv_heads=2, n_q_heads=4, hidden_dim=32,
+        intermediate_dim=64, vocab_size=64, apply_rotary=True,
+        layer_norm_type="rms", mlp_type="moe", use_attention_bias=False,
+        use_attn_proj_bias=False, use_mlp_bias=False,
+        activation_function="silu", compute_dtype="float32",
+        moe=MoEConfig(**{**dict(
+            num_experts=16, top_k=4, routing_type="none",
+            score_fn="sigmoid", use_expert_bias=True,
+            intermediate_dim=24, experts_held=held), **moe}))
+
+
+def share_layer(seed=0, tokens=40):
+    """A 16-expert layer's leaves (every expert), a biased router and
+    an input [1, tokens, 32]."""
+    rng = np.random.default_rng(seed)
+    m = dict(router=rng.standard_normal((32, 16)) * 0.5,
+             expert_bias=rng.standard_normal((16,)) * 0.3,
+             wg=rng.standard_normal((16, 32, 24)) * 0.2,
+             wu=rng.standard_normal((16, 32, 24)) * 0.2,
+             wd=rng.standard_normal((16, 24, 32)) * 0.2)
+    m = {k: jnp.asarray(v, jnp.float32) for k, v in m.items()}
+    x = jnp.asarray(rng.standard_normal((1, tokens, 32)), jnp.float32)
+    return m, x
+
+
+def held_leaves(m, first, count):
+    return {k: (v[first:first + count] if k in ("wg", "wu", "wd") else v)
+            for k, v in m.items()}
+
+
+def sigmoid_oracle(m, x, norm=True, scaling=1.0, bias=True):
+    """The layer by a loop over tokens and their chosen experts."""
+    xt = np.asarray(x, np.float64)[0]
+    s = 1 / (1 + np.exp(-(xt @ np.asarray(m["router"], np.float64))))
+    choice = s + (np.asarray(m["expert_bias"], np.float64) if bias else 0)
+    out = np.zeros_like(xt)
+    for t in range(len(xt)):
+        idx = np.argsort(choice[t])[::-1][:4]
+        g = s[t][idx]
+        if norm:
+            g = g / (g.sum() + 1e-6)
+        for gate, e in zip(g * scaling, idx):
+            a = xt[t] @ np.asarray(m["wg"], np.float64)[e]
+            u = xt[t] @ np.asarray(m["wu"], np.float64)[e]
+            out[t] += gate * ((a / (1 + np.exp(-a)) * u)
+                              @ np.asarray(m["wd"], np.float64)[e])
+    return out
+
+
+@pytest.mark.parametrize("norm,scaling,bias", [
+    (True, 1.0, True), (False, 1.0, True), (True, 2.5, True),
+    (True, 1.0, False)], ids=["published", "not_renormalised",
+                              "scaled", "no_bias"])
+def test_sigmoid_router_matches_a_loop_over_tokens(norm, scaling, bias):
+    """Sigmoid scores, the bias in the choice and never in the gate,
+    the 1e-6 in the renormalisation, the scaling factor: ragged and
+    dense dispatch against the oracle, every expert held."""
+    m, x = share_layer()
+    if not bias:
+        m = {k: v for k, v in m.items() if k != "expert_bias"}
+    want = sigmoid_oracle(m, x, norm, scaling, bias)
+    for grouped in (True, False):
+        cfg = share_cfg(norm_topk_prob=norm, routed_scaling_factor=scaling,
+                        use_expert_bias=bias, use_grouped_gemm=grouped)
+        out, aux = moe_ops.moe_mlp_with_losses(cfg, m, x)
+        np.testing.assert_allclose(np.asarray(out)[0], want, rtol=2e-4,
+                                   atol=2e-6)
+        assert set(aux) == {moe_ops.LOAD_STAT}  # routing_type "none"
+    # the bias moved some token's choice, or the test shows nothing
+    if bias:
+        assert np.abs(want - sigmoid_oracle(m, x, norm, scaling,
+                                            False)).max() > 1e-3
+
+
+def test_no_gradient_reaches_the_expert_bias():
+    m, x = share_layer()
+    cfg = share_cfg()
+    grads = jax.grad(lambda m: moe_ops.moe_mlp_with_losses(
+        cfg, m, x)[0].sum())(m)
+    assert not np.asarray(grads["expert_bias"]).any()
+    assert np.asarray(grads["router"]).any()
+
+
+def test_eight_shares_add_up_to_the_whole_layer():
+    """The tie of the share to the model: eight ranks of 2 experts,
+    each routing over all 16, add up to what the layer gives with every
+    expert held (the same code) and to the loop over tokens; the pairs
+    they multiply add up to the pairs routed."""
+    m, x = share_layer(seed=1)
+    whole, aux = moe_ops.moe_mlp_with_losses(share_cfg(), m, x)
+    assert moe_ops.HELD_PAIRS_STAT not in aux
+    total, pairs, worst = 0.0, 0.0, 0.0
+    for rank in range(8):
+        cfg = share_cfg(held=(2 * rank, 2))
+        part, aux = moe_ops.moe_mlp_with_losses(
+            cfg, held_leaves(m, 2 * rank, 2), x)
+        total = total + part
+        pairs += float(aux[moe_ops.HELD_PAIRS_STAT])
+        worst = max(worst, float(aux[moe_ops.HELD_LOAD_STAT]))
+        assert float(aux[moe_ops.HELD_LOAD_STAT]) <= float(
+            aux[moe_ops.LOAD_STAT])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(whole)[0], sigmoid_oracle(m, x),
+                               rtol=2e-4, atol=2e-6)
+    assert pairs == 40 * 4
+    assert worst == pytest.approx(float(aux[moe_ops.LOAD_STAT]))
+    # every expert held as an explicit share is the uncut layer too
+    same, aux = moe_ops.moe_mlp_with_losses(share_cfg(held=(0, 16)), m, x)
+    np.testing.assert_allclose(np.asarray(same), np.asarray(whole),
+                               rtol=1e-6, atol=1e-7)
+    assert float(aux[moe_ops.HELD_PAIRS_STAT]) == 40 * 4
+    # with every expert held the fast path IS the whole: none overflow
+    assert moe_ops.share_rows(share_cfg(held=(0, 16)), 40) == 40 * 4
+    assert float(aux[moe_ops.SHARE_OVERFLOW_STAT]) == 0
+
+
+@pytest.mark.parametrize("pulled, slow", [(32, 0), (33, 1), (64, 1)])
+def test_overflow_statistic_is_the_branch_taken(pulled, slow):
+    """``SHARE_OVERFLOW_STAT`` reads 1 exactly where the held pairs
+    pass ``share_rows`` (64 of 64 x 4 here). The router reads one
+    input feature alone: it sends the first ``pulled`` tokens to the
+    two held experts and two more, the others to four absent ones.
+    Either branch equals the held experts' part of the whole layer."""
+    m, x = share_layer(seed=3, tokens=64)
+    cfg = share_cfg(held=(4, 2))
+    assert moe_ops.share_rows(cfg, 64) == 64
+    sign = np.zeros(16, np.float32)
+    sign[[4, 5, 9, 13]], sign[[0, 1, 2, 3]] = 1.0, -1.0
+    m = dict(m, expert_bias=jnp.zeros(16),
+             router=jnp.zeros((32, 16)).at[-1].set(50.0 * sign))
+    x = x.at[0, :, -1].set(jnp.where(jnp.arange(64) < pulled, 1.0, -1.0))
+    part, aux = moe_ops.moe_mlp_with_losses(cfg, held_leaves(m, 4, 2), x)
+    assert float(aux[moe_ops.HELD_PAIRS_STAT]) == 2 * pulled
+    assert float(aux[moe_ops.SHARE_OVERFLOW_STAT]) == slow
+    only = dict(m, wd=m["wd"].at[:4].set(0).at[6:].set(0))
+    want, _ = moe_ops.moe_mlp_with_losses(share_cfg(), only, x)
+    np.testing.assert_allclose(np.asarray(part), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(part)).max() > 1e-3
+
+
+@pytest.mark.parametrize("first", [0, 6, 14])
+def test_no_pair_of_a_held_expert_is_dropped_under_imbalance(first):
+    """A bias that sends EVERY token to the held experts (and to two
+    more): the share multiplies 2 x T pairs, far above T x k / E x 2,
+    and still equals those experts' part of the whole layer; a bias
+    that sends nothing to them gives exactly zero."""
+    m, x = share_layer(seed=2, tokens=64)
+    pull = np.zeros(16, np.float32)
+    pull[[first, first + 1, (first + 5) % 16, (first + 9) % 16]] = 10.0
+    m["expert_bias"] = jnp.asarray(pull)
+    cfg = share_cfg(held=(first, 2))
+    part, aux = moe_ops.moe_mlp_with_losses(cfg, held_leaves(m, first, 2), x)
+    assert float(aux[moe_ops.HELD_PAIRS_STAT]) == 2 * 64
+    assert float(aux[moe_ops.HELD_LOAD_STAT]) == 64 * 16 / (64 * 4)
+    # twice the fast path's rows: the slow path ran, and says so
+    assert moe_ops.share_rows(cfg, 64) == 64
+    assert float(aux[moe_ops.SHARE_OVERFLOW_STAT]) == 1
+    # the same two experts alone in the whole layer: the others zeroed
+    only = dict(m, wd=m["wd"].at[:first].set(0).at[first + 2:].set(0))
+    want, _ = moe_ops.moe_mlp_with_losses(share_cfg(), only, x)
+    np.testing.assert_allclose(np.asarray(part), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(part)).max() > 1e-3
+    m["expert_bias"] = jnp.asarray(-pull)
+    none, aux = moe_ops.moe_mlp_with_losses(cfg, held_leaves(m, first, 2), x)
+    assert float(aux[moe_ops.HELD_PAIRS_STAT]) == 0
+    assert float(aux[moe_ops.SHARE_OVERFLOW_STAT]) == 0
+    assert not np.asarray(none).any()
+    grads = jax.grad(lambda m: moe_ops.moe_mlp_with_losses(
+        cfg, m, x)[0].sum())(held_leaves(m, first, 2))
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads.values())
+
+
+def test_a_share_needs_the_ragged_mode_and_a_range_of_the_experts():
+    m, x = share_layer()
+    with pytest.raises(NotImplementedError, match="experts_held"):
+        moe_ops.moe_mlp_with_losses(
+            share_cfg(held=(0, 2), capacity_factor=2.0),
+            held_leaves(m, 0, 2), x)
+    with pytest.raises(ValueError, match="experts_held"):
+        MoEConfig(num_experts=16, experts_held=(12, 8))
+    with pytest.raises(NotImplementedError, match="score_fn"):
+        MoEConfig(score_fn="tanh")
+
+
+@pytest.mark.parametrize("pull", [0.0, 10.0], ids=["fast_path", "fallback"])
+def test_every_row_of_a_shares_grouped_products_lies_in_a_group(
+        monkeypatch, pull):
+    """On the chip ``lax.ragged_dot`` leaves a row that no group covers
+    unwritten, in the backward's products too (PERF.md, PR 31: the
+    forward agreed with the reference and the gradient was garbage),
+    and the CPU's zero there hides it. So the invariant itself is
+    held: the group sizes of every grouped product of a share add up
+    to its rows, on the fast path and on the fallback."""
+    m, x = share_layer(seed=3, tokens=64)
+    bias = np.asarray(m["expert_bias"]).copy()
+    bias[[4, 5]] += pull  # every token to the held experts, or not
+    m["expert_bias"] = jnp.asarray(bias)
+    seen = []
+    real = jax.lax.ragged_dot
+
+    def checked(lhs, rhs, group_sizes, **kw):
+        seen.append((lhs.shape[0], int(group_sizes.sum())))
+        return real(lhs, rhs, group_sizes, **kw)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", checked)
+    with jax.disable_jit():
+        moe_ops.moe_mlp_with_losses(share_cfg(held=(4, 2)),
+                                    held_leaves(m, 4, 2), x)
+    rows = 64 * 4 if pull else 2 * 64 * 4 * 2 // 16
+    assert seen == [(rows, rows)] * 3
