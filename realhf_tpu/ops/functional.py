@@ -4,8 +4,9 @@ Parity with reference ``realhf/impl/model/utils/functional.py``:
 next-token logprob gathering (:165), masked normalization (:227),
 logits masking (:214) -- expressed on the framework's [S, L] packed
 stream layout. The vocab-parallel cross entropy of the reference
-(``modules.py:1050``) is unnecessary: the head matmul + log_softmax
-under GSPMD shard the vocab dim and XLA inserts the reductions.
+(``modules.py:1050``) is unnecessary: the head matmul, the
+log-sum-exp and the label's select under GSPMD shard the vocab dim and
+XLA inserts the reductions (all-reduces of one number a position).
 """
 
 from typing import Optional
@@ -15,6 +16,33 @@ import jax.numpy as jnp
 
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.models.transformer import head_weight
+
+
+#: ``jax.named_scope`` of the head's chunk bodies: in the ``op_name`` of
+#: every operation they lower to, forward and transposed (a trace's
+#: events carry no scope: ``scripts/trace_ops_by_name.py`` reads it
+#: from the program's HLO)
+HEAD_SCOPE = "vocab_head"
+
+
+def _chunk_logits(cfg, w, hc, temperature):
+    """One chunk's float32 logits [S, C, V], the tp-padded vocabulary's
+    tail sliced away, over ``temperature``."""
+    logits = jnp.einsum("slh,hv->slv", hc, w,
+                        preferred_element_type=jnp.float32)
+    if logits.shape[-1] != cfg.vocab_size:  # tp-padded vocab
+        logits = logits[..., :cfg.vocab_size]
+    if temperature != 1.0:
+        logits = logits / temperature
+    return logits
+
+
+def _shift_and_sum_exp(logits):
+    """``z = logits - max`` and ``sum(exp(z))`` over the vocabulary:
+    ``log_softmax`` is ``z - log(sum)``, which no caller here needs
+    whole (its operations in its order, the max held constant)."""
+    z = logits - jax.lax.stop_gradient(logits.max(-1, keepdims=True))
+    return z, jnp.exp(z).sum(-1)
 
 
 def shifted_logprobs_from_hidden(
@@ -74,16 +102,18 @@ def shifted_logprobs_from_hidden(
         else:
             hc, lc = x
             mc = None
-        logits = jnp.einsum("slh,hv->slv", hc, w,
-                            preferred_element_type=jnp.float32)
-        if logits.shape[-1] != cfg.vocab_size:  # tp-padded vocab
-            logits = logits[..., :cfg.vocab_size]
-        if temperature != 1.0:
-            logits = logits / temperature
-        if mc is not None:
-            logits = jnp.where(mc, logits, -1e30)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return None, jnp.take_along_axis(logp, lc[..., None], axis=-1)[..., 0]
+        with jax.named_scope(HEAD_SCOPE):
+            logits = _chunk_logits(cfg, w, hc, temperature)
+            if mc is not None:
+                logits = jnp.where(mc, logits, -1e30)
+            z, s_exp = _shift_and_sum_exp(logits)
+            # the label's entry by a select, not a gather: a gather
+            # makes the compiler write the whole log-softmax for it to
+            # read, and its transpose scatters a one-hot of the chunk's
+            # size and copies it into the logits' layout
+            hit = (jax.lax.broadcasted_iota(lc.dtype, z.shape, 2)
+                   == lc[..., None])
+            return None, jnp.where(hit, z, 0.0).sum(-1) - jnp.log(s_exp)
 
     _, lp = jax.lax.scan(jax.checkpoint(body), None, xs)
     lp = lp.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]
@@ -141,12 +171,10 @@ def entropy_from_hidden(cfg, params, hidden, *, chunk: int = 1024,
     hidden_c = hidden.reshape(s, n_chunks, chunk, h).swapaxes(0, 1)
 
     def body(_, hc):
-        logits = jnp.einsum("slh,hv->slv", hc, w,
-                            preferred_element_type=jnp.float32) / temperature
-        if logits.shape[-1] != cfg.vocab_size:  # tp-padded vocab
-            logits = logits[..., :cfg.vocab_size]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return None, -(jnp.exp(logp) * logp).sum(-1)
+        with jax.named_scope(HEAD_SCOPE):
+            z, s_exp = _shift_and_sum_exp(
+                _chunk_logits(cfg, w, hc, temperature))
+            return None, jnp.log(s_exp) - (jnp.exp(z) * z).sum(-1) / s_exp
 
     _, ent = jax.lax.scan(body, None, hidden_c)
     return ent.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]

@@ -44,7 +44,8 @@ KINDS = [
     ("scatter-add", r"scatter"),
     ("gather", r"gather|dynamic_slice|take"),
     ("optimizer", r"/optimizer/"),
-    ("vocabulary head and loss", r"slh,hv->slv|log_softmax|logsumexp"),
+    ("vocabulary head and loss",
+     r"vocab_head|slh,hv->slv|log_softmax|logsumexp"),
     ("gradient accumulation", r"closed_call/add$|closed_call/add "),
     ("copies", r" copy$|/squeeze|/remat2"),
 ]

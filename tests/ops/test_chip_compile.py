@@ -147,6 +147,84 @@ def test_row_above_the_limit_raises_not_xla():
             lambda *a: packed_attention(*a, use_flash=True), q, k, v, seg)
 
 
+def _float32_arrays_made(text, elems):
+    """What the compiled program's instructions OUTSIDE fusion bodies
+    make of ``elems`` float32 elements: ``(products, others)``, the
+    names of the fusions around a product and the opcodes of the rest
+    (tuples, their elements and bitcasts move nothing and are left
+    out)."""
+    import math
+    import re
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None and line.startswith(" "):
+            bodies[name].append(line)
+    fused = set(re.findall(r"\bfusion\(.*calls=%?([\w.\-]+)", text))
+    products, others = [], []
+    for name, lines in bodies.items():
+        if name in fused:
+            continue
+        for line in lines:
+            inst = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+            if inst is None or not any(
+                    math.prod(map(int, dims.split(","))) == elems
+                    for dims in re.findall(r"f32\[([\d,]+)\]",
+                                           inst.group(2))):
+                continue
+            op = inst.group(3)
+            callee = re.search(r"calls=%?([\w.\-]+)", line)
+            if op == "fusion" and any(
+                    " convolution(" in l or " dot(" in l
+                    for l in bodies[callee.group(1)]):
+                products.append(inst.group(1))
+            elif op not in ("tuple", "get-tuple-element", "bitcast"):
+                others.append(op)
+    return products, others
+
+
+def test_head_makes_no_chunk_of_logits_but_its_products(one_chip):
+    """Loss and gradients over ``shifted_logprobs_from_hidden`` at
+    Qwen2.5-0.5B's head (896 x 151,936, tied, one row of 4096, chunks
+    of 1024): the label is picked by a select, so the program holds no
+    scatter, and outside fusion bodies only the forward's product and
+    the rematerialised one make a chunk's float32 logits (622 MB). With
+    ``log_softmax`` + ``take_along_axis`` (before PR 32) the forward
+    wrote the whole log-softmax for a gather to read, and the backward
+    a broadcast of zeros, a scatter into them and a relayout copy."""
+    from realhf_tpu.models.config import TransformerConfig
+    from realhf_tpu.ops.functional import shifted_logprobs_from_hidden
+
+    hidden, vocab, chunk = 896, 151936, 1024
+    cfg = TransformerConfig(
+        n_layers=N_LAYERS, n_kv_heads=NKV, n_q_heads=NQ, hidden_dim=hidden,
+        head_dim=HD, intermediate_dim=4864, vocab_size=vocab,
+        tied_embedding=True, param_dtype="bfloat16",
+        compute_dtype="bfloat16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(params, h, ids, seg):
+        lp = shifted_logprobs_from_hidden(cfg, params, h, ids, seg,
+                                          chunk=chunk)
+        return -lp.sum() / ROW_LEN
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        {"embed": {"wte": sds((vocab, hidden), jnp.bfloat16)}},
+        sds((1, ROW_LEN, hidden), jnp.bfloat16),
+        sds((1, ROW_LEN), jnp.int32), sds((1, ROW_LEN), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "scatter" not in text
+    products, others = _float32_arrays_made(text, chunk * vocab)
+    assert others == []
+    assert len(products) == 2
+
+
 def test_decode_stacked_compiles(one_chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -181,3 +181,126 @@ class TestFunctional:
         ent = np.asarray(F.entropy_from_hidden(cfg, params, h, chunk=4))
         assert ent.shape == (1, 8)
         assert (ent > 0).all() and (ent <= np.log(30) + 1e-5).all()
+
+
+# ----------------------------------------------------------------------
+# The vocabulary head picks the label by a select (PR 32): held to the
+# formulation it replaced, written out here on whole logits
+# ----------------------------------------------------------------------
+HEAD_V, HEAD_H, HEAD_L = 50, 32, 21   # 21: no chunk of 8 divides the row
+
+
+def _gather_logprobs(cfg, params, hidden, ids, seg, temperature, logits_mask):
+    """``log_softmax`` + ``take_along_axis`` on the row's whole logits:
+    what ``shifted_logprobs_from_hidden`` computed before PR 32."""
+    w = T.head_weight(cfg, params).astype(hidden.dtype)
+    logits = jnp.einsum("slh,hv->slv", hidden, w,
+                        preferred_element_type=jnp.float32)
+    logits = logits[..., :cfg.vocab_size]
+    if temperature != 1.0:
+        logits = logits / temperature
+    if logits_mask is not None:
+        logits = jnp.where(logits_mask, logits, -1e30)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    labels = jnp.concatenate([ids[:, 1:], jnp.zeros_like(ids[:, :1])], 1)
+    lp = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    valid = jnp.concatenate(
+        [(seg[:, 1:] == seg[:, :-1]) & (seg[:, 1:] != 0),
+         jnp.zeros_like(seg[:, :1], bool)], axis=1)
+    return jnp.where(valid, lp, 0.0)
+
+
+def _head_case(tied, padded, dtype, masked):
+    cfg = TransformerConfig(
+        n_layers=1, n_kv_heads=2, n_q_heads=4, hidden_dim=HEAD_H,
+        intermediate_dim=64, vocab_size=HEAD_V, apply_rotary=True,
+        layer_norm_type="rms", mlp_type="llama", use_attention_bias=False,
+        use_attn_proj_bias=False, use_mlp_bias=False,
+        activation_function="silu", tied_embedding=tied,
+        compute_dtype=dtype, param_dtype=dtype)
+    rng = np.random.default_rng(11)
+    vp = HEAD_V + 6 if padded else HEAD_V   # a tp-padded vocabulary
+    mat = jnp.asarray(rng.standard_normal((vp, HEAD_H)) * 0.3, dtype)
+    params = ({"embed": {"wte": mat}} if tied
+              else {"embed": {"wte": mat}, "head": {"w": mat.T}})
+    hidden = jnp.asarray(rng.standard_normal((2, HEAD_L, HEAD_H)), dtype)
+    ids = jnp.asarray(rng.integers(0, HEAD_V, (2, HEAD_L)), jnp.int32)
+    seg = jnp.asarray(np.concatenate(
+        [np.full((2, 9), 1), np.full((2, 10), 2), np.zeros((2, 2))], 1),
+        jnp.int32)
+    mask = None
+    if masked:
+        allowed = rng.random((2, HEAD_L, HEAD_V)) > 0.3
+        allowed[0, 3, int(ids[0, 4])] = False   # a masked LABEL
+        allowed[1, 12, int(ids[1, 13])] = False
+        mask = jnp.asarray(allowed)
+    return cfg, params, hidden, ids, seg, mask
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("padded", [False, True], ids=["exact", "tp_padded"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "mask"])
+@pytest.mark.parametrize("temperature", [1.0, 0.7])
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_head_select_matches_gather(tied, temperature, masked, padded, dtype):
+    """Values, and the gradients with respect to hidden states and head
+    weight, of the chunked select against the gather on whole logits:
+    the same operations in the same order, so equal in float32 up to
+    the products' own rounding; in bf16 the chunks round the weight's
+    gradient once a chunk where the whole row rounds it once."""
+    cfg, params, hidden, ids, seg, mask = _head_case(
+        tied, padded, dtype, masked)
+    weight = np.asarray(np.random.default_rng(5).standard_normal(
+        (2, HEAD_L)), np.float32)
+
+    def run(fn, **kw):
+        def loss(params, hidden):
+            lp = fn(cfg, params, hidden, ids, seg, **kw)
+            return (lp * weight).sum(), lp
+        (_, lp), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, hidden)
+        return lp, grads
+
+    lp, (gp, gh) = run(F.shifted_logprobs_from_hidden, chunk=8,
+                       temperature=temperature, logits_mask=mask)
+    lp0, (gp0, gh0) = run(_gather_logprobs, temperature=temperature,
+                          logits_mask=mask)
+    assert lp.dtype == jnp.float32 and lp.shape == (2, HEAD_L)
+    np.testing.assert_allclose(lp, lp0, rtol=1e-6, atol=1e-6)
+    if masked:  # a masked label reads the mask's -1e30, as before
+        assert lp[0, 3] < -1e29 and lp[1, 12] < -1e29
+    tol = dict(rtol=1e-5, atol=1e-6) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    np.testing.assert_allclose(f32(gh), f32(gh0), **tol)
+    head = (lambda p: p["embed"]["wte"]) if tied else (lambda p: p["head"]["w"])
+    gw = head(gp)
+    assert gw.shape == head(params).shape
+    np.testing.assert_allclose(f32(gw), f32(head(gp0)), **tol)
+    if padded:  # the padded columns take no gradient
+        pad = f32(gw)[HEAD_V:] if tied else f32(gw)[:, HEAD_V:]
+        assert (pad == 0).all()
+
+
+def test_head_select_traces_no_gather_or_scatter():
+    """The transposed program holds no gather and no scatter: the
+    label's cotangent is an elementwise select."""
+    cfg, params, hidden, ids, seg, _ = _head_case(True, False, "float32",
+                                                  False)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, h: F.shifted_logprobs_from_hidden(
+            cfg, p, h, ids, seg, chunk=8).sum(), argnums=(0, 1)))(
+                params, hidden)
+    text = str(jaxpr)
+    assert "gather" not in text and "scatter" not in text
+    assert "select_n" in text
+
+
+def test_entropy_matches_log_softmax_form():
+    cfg, params, hidden, *_ = _head_case(True, True, "float32", False)
+    ent = F.entropy_from_hidden(cfg, params, hidden, chunk=8,
+                                temperature=0.7)
+    logits = T.lm_logits(cfg, params, hidden) / 0.7
+    logp = jax.nn.log_softmax(logits, -1)
+    np.testing.assert_allclose(ent, -(jnp.exp(logp) * logp).sum(-1),
+                               rtol=1e-5, atol=1e-6)
