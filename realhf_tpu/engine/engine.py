@@ -20,6 +20,7 @@ All methods consume/produce device arrays in [S, L] stream layout;
 the algorithm interfaces do SequenceSample <-> stream packing.
 """
 
+import collections
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -218,6 +219,20 @@ class Engine:
                     experts_held=cfg.moe.n_held,
                     router=cfg.moe.score_fn + (
                         "_bias" if cfg.moe.use_expert_bias else ""))
+                if cfg.moe.shared_intermediate_dim is not None:
+                    self._model_attrs.update(
+                        shared_expert=cfg.moe.shared_intermediate_dim)
+            if cfg.window_layers:
+                self._model_attrs.update(
+                    window=cfg.sliding_window,
+                    window_layers=len(cfg.window_layers))
+            if cfg.layer_q_heads is not None:
+                self._model_attrs.update(q_heads=" ".join(
+                    str(cfg.q_heads(i)) for i in cfg.attention_layers))
+            if cfg.rotary_by_operator is not None:
+                self._model_attrs.update(rotary=" ".join(
+                    f"{op[0]}:{rc.describe()}" for op, rc in sorted(
+                        cfg.rotary_by_operator.items())))
         if mode == "dense" and cfg.moe.num_experts > 4:
             logger.warning(
                 "MoE model running in dense dispatch (capacity_factor "
@@ -328,19 +343,27 @@ class Engine:
         """``flash_kv_blocks_total{role,kind}``: the (query block, key
         block) pairs the flash forward kernel visits over these packed
         rows (``visited``) and the pairs under the rows' causal
-        diagonals (``causal``), one head's, x layers
-        (``ops.flash_attention.block_counts``). Their ratio is the
-        span's ``flash_block_share``. Nothing where the rows do not
-        go to the kernel, or are on the device already."""
+        diagonals (``causal``), one head's, added up over the
+        attention layers, EACH by its own rule
+        (``ops.flash_attention.block_counts``): ``visited`` under the
+        layer's window where it has one, ``causal`` without, so that a
+        stack of window and full layers adds up right. Their ratio is
+        the span's ``flash_block_share``. Nothing where the rows do
+        not go to the kernel, or are on the device already."""
+        cfg = self.cfg
         if not (self._flash_rows and isinstance(seg_ids, np.ndarray)
-                and flash_takes(seg_ids.shape[-1], self.cfg.head_dim,
-                                sliding_window=self.cfg.sliding_window)
-                and not self.cfg.scale_attn_by_inverse_layer_idx):
+                and flash_takes(seg_ids.shape[-1], cfg.head_dim)
+                and not cfg.scale_attn_by_inverse_layer_idx):
             return {}
-        counts = dict(zip(("visited", "causal"), block_counts(seg_ids)))
+        layers_of = collections.Counter(
+            cfg.layer_window(i) for i in cfg.attention_layers)
+        counts = dict(visited=0, causal=0)
+        for window, n in layers_of.items():
+            visited, causal = block_counts(seg_ids, sliding_window=window)
+            counts["visited"] += n * visited
+            counts["causal"] += n * causal
         for kind, n in counts.items():
-            metrics.inc("flash_kv_blocks_total",
-                        n * len(self.cfg.attention_layers),
+            metrics.inc("flash_kv_blocks_total", n,
                         role=str(self.ctx.model_name.role), kind=kind)
         return dict(
             flash_block_share=counts["visited"] / counts["causal"])

@@ -897,10 +897,7 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
         x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
 
     if cfg.apply_rotary:
-        cos, sin = T.rotary_freqs(positions, cfg.head_dim,
-                                  cfg.rotary_base, cfg.rotary_scaling,
-                                  cfg.rotary_scaling_type,
-                                  cfg.n_positions)
+        cos, sin = T.rotary_table(cfg, positions)
     else:
         half = cfg.head_dim // 2
         cos = jnp.ones((b, m, half), jnp.float32)

@@ -7,11 +7,64 @@ variant replaces the LM head with a scalar value head (`is_critic`).
 """
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
-#: what a layer of a patterned model is made of
-OPERATORS = ("conv", "attention")
+#: what a layer of a patterned model is made of: "attention" sees
+#: every earlier token of its document, "window" the last
+#: ``sliding_window`` of them
+OPERATORS = ("conv", "attention", "window")
+ATTENTION_OPERATORS = ("attention", "window")
 FEED_FORWARDS = ("dense", "moe")
+
+
+@dataclasses.dataclass
+class RotaryConfig:
+    """A rotary embedding: the ONE description a table is built from
+    (``models/transformer.py:rotary_table`` hands it to
+    ``ops/rotary.py:rotary_freqs``). A model has one, its ``rotary_*``
+    fields (``TransformerConfig.rotary_of`` puts them into this form),
+    or one a kind of attention layer
+    (``TransformerConfig.rotary_by_operator``)."""
+    base: float = 10000.0
+    # rotate the first ``partial_factor x head_dim`` values of a head,
+    # pass the rest through
+    partial_factor: float = 1.0
+    # None: plain frequencies ``base^(-2j/r)``. "linear": positions
+    # divided by ``factor``. "dynamic": NTK, the base grown where a
+    # sequence passes ``original_max_positions``. "yarn": ``ops/
+    # rotary.py:yarn_inv_freq`` over the r rotated values, cos and sin
+    # times ``attention_factor``
+    scaling_type: Optional[str] = None
+    factor: Optional[float] = None
+    # the context the frequencies were trained at ("dynamic", "yarn")
+    original_max_positions: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.scaling_type not in (None, "linear", "dynamic", "yarn"):
+            raise NotImplementedError(
+                f"rotary scaling type {self.scaling_type!r}")
+        if self.scaling_type is not None and self.factor is None:
+            raise ValueError(
+                f"rotary scaling type {self.scaling_type!r} needs its "
+                "factor (rotary_scaling)")
+        if self.scaling_type in ("dynamic", "yarn") \
+                and self.original_max_positions is None:
+            raise ValueError(
+                f"rotary scaling type {self.scaling_type!r} needs "
+                "original_max_positions (n_positions)")
+
+    def rotated(self, head_dim: int) -> int:
+        """r: how many values of a head are rotated."""
+        return int(head_dim * self.partial_factor)
+
+    def describe(self) -> str:
+        """``yarn64@500000/0.5``: for a span's attribute."""
+        kind = "plain" if self.scaling_type is None \
+            else f"{self.scaling_type}{self.factor:g}"
+        return f"{kind}@{self.base:g}/{self.partial_factor:g}"
 
 
 @dataclasses.dataclass
@@ -33,6 +86,15 @@ class MoEConfig:
     score_fn: str = "softmax"
     use_expert_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # what the sigmoid router adds to the k scores' sum before it
+    # divides by it (LFM2 1e-6, Laguna 1e-20)
+    norm_topk_eps: float = 1e-6
+    # Width of the SHARED expert: a dense gated feed-forward beside the
+    # routed ones that every token visits, added with weight 1, outside
+    # the sort and the grouped products (leaves ``mlp["shared"]``).
+    # Every rank of an expert-parallel deployment holds it whole. None:
+    # there is none.
+    shared_intermediate_dim: Optional[int] = None
     # Width of one expert where the model's dense feed-forward layers
     # have another (``TransformerConfig.intermediate_dim`` is theirs).
     intermediate_dim: Optional[int] = None
@@ -139,6 +201,18 @@ class TransformerConfig:
     # that ``mlp_type`` describes, stacked under ``params["blocks"]``.
     layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
     conv_kernel: int = 3
+    # What a patterned model's attention layers may differ in. Query
+    # heads a layer (an entry for EVERY layer, a conv layer's ignored;
+    # all share ``n_kv_heads`` and ``head_dim``, so the K/V stack keeps
+    # one shape). None: ``n_q_heads`` everywhere.
+    layer_q_heads: Optional[Tuple[int, ...]] = None
+    # The rotary embedding by kind of layer: {"attention": ...,
+    # "window": ...}. None: the model-wide ``rotary_*`` fields.
+    rotary_by_operator: Optional[Dict[str, RotaryConfig]] = None
+    # One output gate a head: ``g = sigmoid(u W_g)`` [.., n heads] from
+    # the layer's normed input, multiplied into each head's attention
+    # output before ``wo`` (leaf ``attn["w_gate"]`` [H, heads]).
+    attn_output_gate: bool = False
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -189,6 +263,10 @@ class TransformerConfig:
             if self.n_moe_layers and self.moe is None:
                 raise ValueError("layer_pattern has moe layers, moe is "
                                  "None")
+            if self.sliding_window is None and any(
+                    op == "window" for op, _ in self.layer_pattern):
+                raise ValueError("layer_pattern has window layers, "
+                                 "sliding_window is None")
             if not (self.layer_norm_type == "rms" and self.gated_mlp
                     and self.apply_rotary
                     and not self.use_attention_bias
@@ -198,13 +276,31 @@ class TransformerConfig:
                     "a layer_pattern model is RMSNorm, rotary, gated "
                     "feed-forward, without biases or per-layer "
                     "attention scale")
-        if self.rotary_scaling_type is not None:
-            if self.rotary_scaling is None:
+        if self.layer_pattern is None and (
+                self.layer_q_heads is not None
+                or self.rotary_by_operator is not None
+                or self.attn_output_gate):
+            raise NotImplementedError(
+                "layer_q_heads, rotary_by_operator and attn_output_gate "
+                "belong to a model with a layer_pattern")
+        if self.layer_q_heads is not None:
+            self.layer_q_heads = tuple(int(n) for n in self.layer_q_heads)
+            if len(self.layer_q_heads) != self.n_layers or any(
+                    n % self.n_kv_heads for n in self.layer_q_heads):
                 raise ValueError(
-                    "rotary_scaling must be set when rotary_scaling_type is.")
-            if self.rotary_scaling_type == "dynamic" and self.n_positions is None:
+                    f"layer_q_heads={self.layer_q_heads}: one multiple "
+                    f"of n_kv_heads={self.n_kv_heads} for each of the "
+                    f"{self.n_layers} layers")
+        if self.rotary_by_operator is not None:
+            missing = {op for op, _ in self.layer_pattern
+                       if op in ATTENTION_OPERATORS} \
+                - set(self.rotary_by_operator)
+            if missing or self.rotary_interleaved:
                 raise ValueError(
-                    "dynamic NTK rotary scaling requires n_positions.")
+                    f"rotary_by_operator lacks {sorted(missing)} "
+                    "(halves convention only)")
+        else:
+            self.rotary_of("attention")  # a RotaryConfig checks itself
 
     @property
     def uses_absolute_position(self) -> bool:
@@ -228,9 +324,42 @@ class TransformerConfig:
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
-        """The layers that have keys and values, in order."""
+        """The layers that have keys and values, in order: full and
+        window attention alike."""
         return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "attention")
+                     if op in ATTENTION_OPERATORS)
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """The layers whose attention sees ``sliding_window`` tokens."""
+        return tuple(i for i in self.attention_layers
+                     if self.layer_window(i) is not None)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """The window of layer ``i``'s attention, None where it sees
+        its whole document. A model of one block has ONE value,
+        ``sliding_window``, for every layer; a patterned model says it
+        a layer ("window" against "attention")."""
+        if self.layer_pattern is None:
+            return self.sliding_window
+        return self.sliding_window \
+            if self.layer_pattern[i][0] == "window" else None
+
+    def rotary_of(self, op: str) -> RotaryConfig:
+        """The rotary embedding of an ``op`` layer ("attention" or
+        "window"): its kind's where the model declares one a kind,
+        else the model-wide ``rotary_*`` fields as a RotaryConfig."""
+        if self.rotary_by_operator is not None:
+            return self.rotary_by_operator[op]
+        return RotaryConfig(
+            base=self.rotary_base, scaling_type=self.rotary_scaling_type,
+            factor=self.rotary_scaling,
+            original_max_positions=self.n_positions)
+
+    def q_heads(self, i: int) -> int:
+        """Query heads of layer ``i``."""
+        return self.n_q_heads if self.layer_q_heads is None \
+            else self.layer_q_heads[i]
 
     @property
     def conv_layers(self) -> Tuple[int, ...]:
@@ -239,7 +368,8 @@ class TransformerConfig:
 
     @property
     def pattern_string(self) -> str:
-        """``c a c c c``: every layer's operator by its first letter."""
+        """``c a c c c``, ``a w w w a``: every layer's operator by its
+        first letter (conv, attention, window)."""
         return " ".join(op[0] for op, _ in self.layer_kinds)
 
     def require_one_block(self, what: str):
@@ -251,21 +381,29 @@ class TransformerConfig:
                 f"pattern (layer_pattern '{self.pattern_string}': "
                 f"{len(self.conv_layers)} conv and "
                 f"{len(self.attention_layers)} attention layers, "
-                f"{self.n_moe_layers} of them sparse)")
+                f"{len(self.window_layers)} of those with a window, "
+                f"{self.n_moe_layers} layers sparse)")
 
     def n_params(self) -> int:
         """Approximate parameter count (for FLOPs/memory estimates),
         layer by layer of the pattern: every matrix, the convolutions'
         taps, the router (and its selection bias) over all experts,
-        the experts HELD, and the query/key norms; biases and the
-        layer norms' scales are left out."""
+        the experts HELD, the shared expert, the query/key norms and
+        the output gate, each attention layer at its own count of
+        query heads; biases and the layer norms' scales are left
+        out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
-        attn = h * (self.n_q_heads + 2 * self.n_kv_heads) * self.head_dim \
-            + self.n_q_heads * self.head_dim * h
-        if self.qk_norm == "full":
-            attn += (self.n_q_heads + self.n_kv_heads) * self.head_dim
-        elif self.qk_norm == "head":
-            attn += 2 * self.head_dim
+
+        def attn(i):
+            nq = self.q_heads(i)
+            n = h * (nq + 2 * self.n_kv_heads) * self.head_dim \
+                + nq * self.head_dim * h
+            if self.qk_norm == "full":
+                n += (nq + self.n_kv_heads) * self.head_dim
+            elif self.qk_norm == "head":
+                n += 2 * self.head_dim
+            return n + (h * nq if self.attn_output_gate else 0)
+
         conv = 4 * h * h + self.conv_kernel * h
         dense = (3 if self.gated_mlp else 2) * h * f
         moe = 0
@@ -275,10 +413,11 @@ class TransformerConfig:
                 * self.moe.n_held + h * self.moe.num_experts
             if self.moe.use_expert_bias:
                 moe += self.moe.num_experts
+            moe += 3 * h * (self.moe.shared_intermediate_dim or 0)
         embed = v * h if self.tied_embedding else 2 * v * h
         if self.is_critic:
             embed = v * h + h
         return embed + sum(
-            (attn if op == "attention" else conv)
+            (conv if op == "conv" else attn(i))
             + (moe if ff == "moe" else dense)
-            for op, ff in self.layer_kinds)
+            for i, (op, ff) in enumerate(self.layer_kinds))

@@ -115,6 +115,8 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
             lp["conv"] = {"w_in": col, "w": col, "w_out": row}
         else:
             lp["attn"] = {"wq": col, "wk": col, "wv": col, "wo": row}
+            if cfg.attn_output_gate:  # a gate a head: by head, as wq
+                lp["attn"]["w_gate"] = col
             if cfg.qk_norm == "head":  # one head's width: on every shard
                 lp["attn"].update(q_norm=P(None), k_norm=P(None))
             elif cfg.qk_norm is not None:
@@ -127,6 +129,8 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
                          "wd": P(None, MODEL_AXIS, None)}
             if cfg.moe.use_expert_bias:
                 lp["mlp"]["expert_bias"] = P(None)
+            if cfg.moe.shared_intermediate_dim is not None:
+                lp["mlp"]["shared"] = {"wg": col, "wu": col, "wd": row}
         else:
             lp["mlp"] = {"wg": col, "wu": col, "wd": row}
         layers[str(i)] = lp

@@ -26,7 +26,11 @@ Design (idiomatic JAX, not a torch translation):
   feed-forward) says. Its cache is two kinds of state side by side:
   K and V for the attention layers alone (stacked over THOSE), and
   the last ``conv_kernel - 1`` rows of the convolution's input for
-  each conv layer. A model of one block takes none of these paths.
+  each conv layer. Its attention layers may differ a layer: full or
+  over a window of ``sliding_window`` tokens (operator "window"),
+  their count of query heads (``layer_q_heads``; K and V keep one
+  shape), the rotary table of their kind (``rotary_by_operator``).
+  A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
 0 = embedding, 1..n_layers = blocks, n_layers+1 = head -- used by HF
@@ -157,6 +161,7 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                           "w": norm((cfg.conv_kernel, h)),
                           "w_out": norm((h, h), proj_std)}
         else:
+            nq = cfg.q_heads(i)
             lp["attn"] = {"wq": norm((h, nq * hd)),
                           "wk": norm((h, nkv * hd)),
                           "wv": norm((h, nkv * hd)),
@@ -165,6 +170,8 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                 heads = (1, 1) if cfg.qk_norm == "head" else (nq, nkv)
                 lp["attn"]["q_norm"] = ones((heads[0] * hd,))
                 lp["attn"]["k_norm"] = ones((heads[1] * hd,))
+            if cfg.attn_output_gate:
+                lp["attn"]["w_gate"] = norm((h, nq))
         if ff == "moe":
             ne, nh = cfg.moe.num_experts, cfg.moe.n_held
             fe = cfg.moe.intermediate_dim or f
@@ -173,6 +180,11 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                          "wd": norm((nh, fe, h), proj_std)}
             if cfg.moe.use_expert_bias:
                 lp["mlp"]["expert_bias"] = jnp.zeros((ne,), pdt)
+            fs = cfg.moe.shared_intermediate_dim
+            if fs is not None:
+                lp["mlp"]["shared"] = {
+                    "wg": norm((h, fs)), "wu": norm((h, fs)),
+                    "wd": norm((fs, h), proj_std)}
         else:
             lp["mlp"] = {"wg": norm((h, f)), "wu": norm((h, f)),
                          "wd": norm((f, h), proj_std)}
@@ -270,6 +282,9 @@ def _dense_mlp(cfg, m, x, cdt):
 
 
 def _qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray):
+    """q [..., heads, hd], k and v [..., n_kv_heads, hd] of the layer
+    ``lp``. ``heads`` is what the layer's own ``wq`` is wide: layers of
+    a patterned model may differ in it (``layer_q_heads``)."""
     cdt = jnp.dtype(cfg.compute_dtype)
     a = lp["attn"]
     *lead, _ = x.shape
@@ -286,7 +301,7 @@ def _qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray):
         # sharded and the partitioner reduces the mean of squares
         q = _norm(cfg, q, a["q_norm"], None)
         k = _norm(cfg, k, a["k_norm"], None)
-    q = q.reshape(*lead, cfg.n_q_heads, cfg.head_dim)
+    q = q.reshape(*lead, q.shape[-1] // cfg.head_dim, cfg.head_dim)
     k = k.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm == "head":
@@ -346,12 +361,25 @@ def _attn_scale(cfg: TransformerConfig, layer_idx: jnp.ndarray) -> jnp.ndarray:
     return scale
 
 
+def _head_gate(lp: Params, ln1: jnp.ndarray, attn: jnp.ndarray):
+    """``attn`` [..., heads, hd] times the layer's output gate, one a
+    head from the normed input: ``sigmoid(ln1 W_g)`` [..., heads]
+    (``attn_output_gate``); as it is where the layer has none."""
+    if "w_gate" not in lp["attn"]:
+        return attn
+    gate = jax.nn.sigmoid(
+        (ln1 @ lp["attn"]["w_gate"].astype(ln1.dtype)).astype(jnp.float32))
+    return attn * gate[..., None].astype(attn.dtype)
+
+
 def _attention_op(cfg: TransformerConfig, lp: Params,
                   layer_idx: jnp.ndarray, ln1: jnp.ndarray,
                   seg_ids: jnp.ndarray, cos: jnp.ndarray,
-                  sin: jnp.ndarray, attention_fn=None):
+                  sin: jnp.ndarray, attention_fn=None,
+                  window: Optional[int] = None):
     """Attention over packed streams on the normed residual ``ln1``
-    [B, L, H] -> (its projected output [B, L, H], (k, v))."""
+    [B, L, H] -> (its projected output [B, L, H], (k, v)). ``window``:
+    the tokens THIS layer sees (``cfg.layer_window``), None for all."""
     q, k, v = _qkv(cfg, lp, ln1)
     if cfg.apply_rotary:
         q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
@@ -359,8 +387,9 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
     attn_impl = attention_fn or packed_attention
     attn = attn_impl(q, k, v, seg_ids, causal=True,
                      scale=_attn_scale(cfg, layer_idx),
-                     sliding_window=cfg.sliding_window)
-    attn = attn.reshape(*ln1.shape[:-1], cfg.n_q_heads * cfg.head_dim)
+                     sliding_window=window)
+    attn = _head_gate(lp, ln1, attn)
+    attn = attn.reshape(*ln1.shape[:-1], -1)
     proj = attn @ lp["attn"]["wo"].astype(ln1.dtype)
     if "bo" in lp["attn"]:
         proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
@@ -370,11 +399,12 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
 def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
            x: jnp.ndarray, seg_ids: jnp.ndarray, cos: jnp.ndarray,
            sin: jnp.ndarray, constrain, attention_fn=None,
-           moe_constraint=None, kind=None):
+           moe_constraint=None, kind=None, window=None):
     """One block over packed streams [B, L, H]; returns (residual
     output, state, aux-losses). ``kind``: the layer's (operator,
     feed-forward) in a patterned model, None for the one block of
-    ``mlp_type``. The state feeds prefill's caches: (k, v) of an
+    ``mlp_type``; ``window``: its attention's window, None for the
+    whole document. The state feeds prefill's caches: (k, v) of an
     attention layer, the convolution's input s [B, L, H] of a conv
     layer; aux is non-empty for MoE."""
     op, sparse = ("attention", None) if kind is None \
@@ -384,13 +414,42 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
         proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
-                                    cos, sin, attention_fn)
+                                    cos, sin, attention_fn, window)
     x = constrain(x + proj)
     ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
     mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, seg_ids, moe_constraint,
                                  sparse)
     x = constrain(x + mlp_out)
     return x, state, aux
+
+
+def rotary_table(cfg: TransformerConfig, positions: jnp.ndarray,
+                 op: str = "attention"):
+    """(cos, sin) ``positions.shape + (r // 2,)`` of an ``op`` layer's
+    rotary embedding (``cfg.rotary_of(op)``), r the values of a head it
+    rotates."""
+    rc = cfg.rotary_of(op)
+    return rotary_freqs(
+        positions, rc.rotated(cfg.head_dim), rc.base, rc.factor,
+        rc.scaling_type, rc.original_max_positions,
+        beta_fast=rc.beta_fast, beta_slow=rc.beta_slow,
+        attention_factor=rc.attention_factor)
+
+
+def _rotary_tables(cfg: TransformerConfig, positions: jnp.ndarray):
+    """``{operator: (cos, sin)}``: ONE table for every layer (ones and
+    zeros without a rotary embedding) unless the model declares one a
+    kind of layer (``rotary_by_operator``)."""
+    if cfg.rotary_by_operator is not None:
+        return {op: rotary_table(cfg, positions, op)
+                for op in cfg.rotary_by_operator}
+    if cfg.apply_rotary:
+        table = rotary_table(cfg, positions)
+    else:
+        half = cfg.head_dim // 2
+        table = (jnp.ones((*positions.shape, half), jnp.float32),
+                 jnp.zeros((*positions.shape, half), jnp.float32))
+    return {"attention": table, "window": table}
 
 
 def positions_from_segments(seg_ids: jnp.ndarray) -> jnp.ndarray:
@@ -443,18 +502,12 @@ def forward(
         x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
     x = constrain(x)
 
-    if cfg.apply_rotary:
-        cos, sin = rotary_freqs(positions, cfg.head_dim, cfg.rotary_base,
-                                cfg.rotary_scaling, cfg.rotary_scaling_type,
-                                cfg.n_positions)
-    else:
-        half = cfg.head_dim // 2
-        cos = jnp.ones((*positions.shape, half), jnp.float32)
-        sin = jnp.zeros((*positions.shape, half), jnp.float32)
+    rotary = _rotary_tables(cfg, positions)
 
     if pipeline is not None and pipeline.n_stages > 1:
         cfg.require_one_block(
             "pipeline parallelism (parallel/pipeline.py, schedule.py)")
+        cos, sin = rotary["attention"]
         # Pipeline parallelism: blocks are stage-sharded over the
         # "pipe" mesh axis and run as a microbatch-rotation schedule
         # (parallel/pipeline.py). Embedding/rotary above and head/norm
@@ -469,7 +522,7 @@ def forward(
         def pblock(lp, layer_idx, carry, seg, cos_, sin_):
             y, _, aux = _block(cfg, lp, layer_idx, carry, seg, cos_,
                                sin_, constrain, attention_fn,
-                               moe_constraint)
+                               moe_constraint, window=cfg.sliding_window)
             # the schedules add every aux entry up over ticks and
             # stages: right for the losses, not for a maximum
             for stat in STATS:
@@ -520,17 +573,20 @@ def forward(
 
     if cfg.layer_pattern is not None:
         x, states, aux = _pattern_layers(
-            cfg, params["layers"], x, seg_ids, cos, sin, constrain,
+            cfg, params["layers"], x, seg_ids, rotary, constrain,
             attention_fn, moe_constraint, return_kv, return_aux)
         x = _norm(cfg, x, params["ln_f"]["scale"], None)
         return (x, states, aux) if return_aux else (x, states)
+
+    cos, sin = rotary["attention"]  # a model of one block has one table
 
     def block_fn(lp, layer_idx, carry):
         # cfg/constrain are non-array closures; seg_ids/cos/sin are
         # array closures -- jax.checkpoint differentiates through
         # closed-over arrays correctly.
         return _block(cfg, lp, layer_idx, carry, seg_ids, cos, sin,
-                      constrain, attention_fn, moe_constraint)
+                      constrain, attention_fn, moe_constraint,
+                      window=cfg.sliding_window)
 
     if cfg.gradient_checkpointing:
         block_fn = jax.checkpoint(
@@ -553,27 +609,31 @@ def forward(
     return x, kvs
 
 
-def _pattern_layers(cfg, layers, x, seg_ids, cos, sin, constrain,
+def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                     attention_fn, moe_constraint, return_kv, return_aux):
     """The layers of a patterned model, unrolled: x -> (x, states,
-    aux). ``states`` (for prefill): K and V stacked over the ATTENTION
+    aux). Each attention layer takes the window its operator says and
+    the rotary table of its kind (``rotary``: ``_rotary_tables``).
+    ``states`` (for prefill): K and V stacked over the ATTENTION
     layers [n_attn, B, L, nkv, hd] and the convolutions' inputs
     stacked over the CONV layers [n_conv, B, L, H]; None unless
     ``return_kv``. ``aux``: the sparse layers' entries reduced as
     ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
     ks, vs, convs, auxs = [], [], [], []
     for i, kind in enumerate(cfg.layer_pattern):
-        def block_fn(lp, carry, i=i, kind=kind):
+        cos, sin = rotary.get(kind[0], (None, None))
+
+        def block_fn(lp, carry, i=i, kind=kind, cos=cos, sin=sin):
             return _block(cfg, lp, jnp.int32(i), carry, seg_ids, cos,
                           sin, constrain, attention_fn, moe_constraint,
-                          kind)
+                          kind, cfg.layer_window(i))
 
         if cfg.gradient_checkpointing:
             block_fn = jax.checkpoint(
                 block_fn,
                 policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
         x, state, aux = block_fn(layers[str(i)], x)
-        if return_kv and kind[0] == "attention":
+        if return_kv and kind[0] != "conv":
             ks.append(state[0])
             vs.append(state[1])
         elif return_kv:
@@ -819,14 +879,7 @@ def decode_step(
     if cfg.normalize_embed:
         x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
 
-    if cfg.apply_rotary:
-        cos, sin = rotary_freqs(positions, cfg.head_dim, cfg.rotary_base,
-                                cfg.rotary_scaling, cfg.rotary_scaling_type,
-                                cfg.n_positions)
-    else:
-        half = cfg.head_dim // 2
-        cos = jnp.ones((b, half), jnp.float32)
-        sin = jnp.zeros((b, half), jnp.float32)
+    rotary = _rotary_tables(cfg, positions)
 
     if uniform_slot:
         s0 = slot[0]
@@ -836,8 +889,12 @@ def decode_step(
         valid = cache["valid"].at[jnp.arange(b), slot].set(True)
     new_len = slot + 1
 
-    def layer_body(x, k_all, v_all, lp, l, sparse=None):
-        # l: the layer, a Python int (unrolled) or a traced scalar
+    def layer_body(x, k_all, v_all, lp, l, sparse=None, op="attention",
+                   window=cfg.sliding_window):
+        # l: the layer's place in the K/V stack, a Python int
+        # (unrolled) or a traced scalar; op, window: its kind's rotary
+        # table and what it sees (a patterned model says them a layer)
+        cos, sin = rotary[op]
         ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
         q, k, v = _qkv(cfg, lp, ln1)  # q: [B, nq, hd]; k/v: [B, nkv, hd]
         if cfg.apply_rotary:
@@ -862,7 +919,8 @@ def decode_step(
             scale = _attn_scale(cfg, l)  # traced scalar
         attn = _stacked_decode_attention(
             q, k_all, v_all, valid, l, scale=scale,
-            sliding_window=cfg.sliding_window, slot=slot, mesh=mesh)
+            sliding_window=window, slot=slot, mesh=mesh)
+        attn = _head_gate(lp, ln1, attn)
         proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(x.dtype)
@@ -876,13 +934,15 @@ def decode_step(
     if cfg.layer_pattern is not None:
         # a layer of the pattern at a time: an attention layer reads
         # and writes ITS slice of the K/V stack (the stack holds the
-        # attention layers alone), a conv layer its two rows of state
+        # attention layers alone, window layers with EVERY row: the
+        # kernel masks what is past the window), a conv layer its two
+        # rows of state
         for i, (op, ff) in enumerate(cfg.layer_pattern):
             lp = params["layers"][str(i)]
-            if op == "attention":
+            if op != "conv":
                 x, k_all, v_all = layer_body(
                     x, k_all, v_all, lp, cfg.attention_layers.index(i),
-                    ff == "moe")
+                    ff == "moe", op, cfg.layer_window(i))
                 continue
             ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
             proj, state = _short_conv_step(

@@ -84,11 +84,12 @@ def packed_attention_xla(
 
 
 def flash_takes(row_len: int, head_dim: int, *, scale=None,
-                logits_soft_cap=None, sliding_window=None) -> bool:
+                logits_soft_cap=None) -> bool:
     """Whether a packed row meets the flash kernel's gate: its tiling,
-    a static python scale, no soft cap, no sliding window."""
+    a static python scale, no soft cap (a sliding window is the
+    kernel's own)."""
     return (row_len % 128 == 0 and head_dim >= 64
-            and logits_soft_cap is None and sliding_window is None
+            and logits_soft_cap is None
             and (scale is None or isinstance(scale, (int, float))))
 
 
@@ -103,18 +104,16 @@ def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
     if use_flash is None:
         use_flash = pallas_enabled() and flash_takes(
             q.shape[1], q.shape[3], scale=scale,
-            logits_soft_cap=logits_soft_cap,
-            sliding_window=sliding_window)
+            logits_soft_cap=logits_soft_cap)
     if use_flash:
-        assert sliding_window is None, \
-            "flash kernel has no sliding-window support yet"
         from realhf_tpu.ops.flash_attention import flash_attention
 
         # raises above FLASH_MAX_LEN: a row the chip's compiler would
         # refuse never drops to the O(L^2) XLA path in silence
         return flash_attention(q, k, v, seg_ids, causal=causal,
                                scale=scale,
-                               logits_soft_cap=logits_soft_cap)
+                               logits_soft_cap=logits_soft_cap,
+                               sliding_window=sliding_window)
     return packed_attention_xla(q, k, v, seg_ids, causal=causal, scale=scale,
                                 logits_soft_cap=logits_soft_cap,
                                 sliding_window=sliding_window)

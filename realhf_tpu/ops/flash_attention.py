@@ -4,7 +4,8 @@ TPU-native replacement for the reference's flash-attn varlen kernels
 (``realhf/impl/model/modules/attn.py:20-23``): tiled online-softmax
 attention (flash-attention-2 schedule) with
 
-- causal masking,
+- causal masking, and a sliding window (``sliding_window=W``: a
+  query sees the last W tokens of its document, itself among them),
 - segment-id masking for packed variable-length sequences (the
   cu_seqlens equivalent), and loops that visit only the blocks a
   block's own segments reach,
@@ -39,6 +40,18 @@ last block that holds an unmasked pair; only padding that fills whole
 blocks between two segments of one block leaves a masked block inside
 it.
 
+A window bounds both ranges a second time, from the row indices alone
+(inside a document the distance in the row IS the distance in the
+document): a query block starts no earlier than the block of its
+first row's oldest visible key, ``first row - (W - 1)``, and a key
+block ends no later than the block of the last query that sees its
+last column, ``last column + (W - 1)``; the kernels' masks add ``row
+- col < W``. With ``sliding_window=None`` ranges, masks and programs
+are what they are without this paragraph. The window takes nothing off
+VMEM: K and V are still whole a head, so ``FLASH_MAX_LEN`` stands; it
+takes blocks off the loops (a row of 4096 at W = 512 visits 30 of its
+72 causal block pairs).
+
 What is still whole in VMEM. K and V (forward, dq) and Q, dO, lse,
 delta (dkv) are kept whole per (batch, head) whatever the ranges say,
 which bounds L: ``FLASH_MAX_LEN`` below is what the v5e compiler
@@ -69,7 +82,9 @@ DEFAULT_BK = 512
 #: (libtpu 0.0.34, bf16, tests/ops/test_chip_compile.py): the backward
 #: compiles to L = 5120 at (14 q, 2 kv, hd 64) and to 6144 at
 #: (32, 8, 128) and runs out of VMEM one kilotoken above either; the
-#: forward alone compiles to 8192 and is refused at 16384.
+#: forward alone compiles to 8192 and is refused at 16384. A sliding
+#: window changes none of this (K and V stay whole a head in VMEM);
+#: the windowed backward at (64, 8, 128) x 4096 compiles too.
 FLASH_MAX_LEN = 4096
 NEG_INF = -2.0 ** 30
 LANES = 128
@@ -89,7 +104,8 @@ def _blocks(l: int, bq: int, bk: int):
 # ----------------------------------------------------------------------
 # Which blocks a block's segments reach
 # ----------------------------------------------------------------------
-def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp):
+def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp,
+                 sliding_window: Optional[int] = None):
     """The key blocks each query block has to visit and the query
     blocks each key block has to visit, from the segment ids alone:
     ``(kv_lo, kv_hi) [B, L // bq]`` and ``(q_lo, q_hi) [B, L // bk]``,
@@ -102,7 +118,10 @@ def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp):
     ``models/transformer.py:positions_from_segments`` finds them).
     Causality cuts that span at the block's own diagonal. A block of
     padding alone gets an empty range (``lo >= hi``); a row of one
-    segment gets the whole causal triangle. ``xp`` is ``jnp`` inside a
+    segment gets the whole causal triangle. A ``sliding_window`` of W
+    (with ``causal``) cuts the span again: no key before the query
+    block's first row less W - 1, no query after the key block's last
+    column plus W - 1. ``xp`` is ``jnp`` inside a
     program and ``np`` for :func:`block_counts`: one rule for both."""
     b, l = seg_ids.shape
     idx = xp.arange(l, dtype=xp.int32)[None, :]
@@ -139,22 +158,35 @@ def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp):
             q_end, (xp.arange(l // bq, dtype=xp.int32) + 1) * bq)
         k_start = xp.maximum(
             k_start, xp.arange(l // bk, dtype=xp.int32) * bk)
+    if sliding_window is not None:
+        assert causal, "a sliding window is a causal window"
+        # keys no older than W - 1 before the query block's first row;
+        # queries no later than W - 1 after the key block's last column
+        q_start = xp.maximum(
+            q_start, xp.arange(l // bq, dtype=xp.int32) * bq
+            - (sliding_window - 1))
+        k_end = xp.minimum(
+            k_end, (xp.arange(l // bk, dtype=xp.int32) + 1) * bk
+            + (sliding_window - 1))
     return ((q_start // bk, -(-q_end // bk)),
             (k_start // bq, -(-k_end // bq)))
 
 
 def block_counts(seg_ids: np.ndarray, bq: int = DEFAULT_BQ,
-                 bk: int = DEFAULT_BK):
+                 bk: int = DEFAULT_BK,
+                 sliding_window: Optional[int] = None):
     """``(visited, causal)``: the (query block, key block) pairs the
     causal forward kernel visits over packed rows ``seg_ids [..., L]``
-    (one head, one layer), and the pairs under the row's causal
-    diagonal that it would visit if each row were one segment. On the
+    (one head, one layer; under the layer's ``sliding_window``), and
+    the pairs under the row's causal diagonal that it would visit if
+    each row were one segment and there were no window. On the
     host, in numpy, by the kernels' own rule (:func:`block_ranges`);
     the engine's counter ``flash_kv_blocks_total`` adds these up."""
     seg_ids = np.asarray(seg_ids)
     seg_ids = seg_ids.reshape(-1, seg_ids.shape[-1])
     bq, bk = _blocks(seg_ids.shape[1], bq, bk)
-    (lo, hi), _ = block_ranges(seg_ids, bq, bk, xp=np)
+    (lo, hi), _ = block_ranges(seg_ids, bq, bk, xp=np,
+                               sliding_window=sliding_window)
     (_, diag), _ = block_ranges(np.ones_like(seg_ids[:1]), bq, bk, xp=np)
     return (int(np.maximum(hi - lo, 0).sum()),
             int(diag.sum()) * seg_ids.shape[0])
@@ -174,7 +206,8 @@ def _block_range(lo_ref, hi_ref):
 def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
                 q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
                 o_ref, lse_ref,  # outputs
-                *, scale: float, bk: int, causal: bool):
+                *, scale: float, bk: int, causal: bool,
+                window: Optional[int] = None):
     qi = pl.program_id(2)
     bq, hd = q_ref.shape[-2], q_ref.shape[-1]
 
@@ -199,6 +232,8 @@ def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
         mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
         if causal:
             mask &= q_idx >= k_idx
+        if window is not None:
+            mask &= q_idx - k_idx < window
         s = jnp.where(mask, s, NEG_INF)
 
         m_new = jnp.maximum(m, s.max(axis=1))
@@ -251,21 +286,45 @@ def _index_maps(group: int):
         seg_whole=lambda bi, h, i, *_: (bi, 0, 0))
 
 
+#: what a kernel may keep in VMEM before the compiler is asked for
+#: more: the scoped limit every Mosaic kernel gets by default on a v5e
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_limit(in_specs, out_specs, out_shape, args):
+    """``vmem_limit_bytes`` for a call whose blocks, each held twice
+    (Mosaic's pipeline double-buffers every operand), pass the default
+    scoped limit: those bytes and a quarter more for the kernel's own
+    values. None where they fit the default, which is every shape the
+    kernels had before a row of 4096 at heads of 128: such a call's
+    program is untouched."""
+    outs = jax.tree.leaves(out_shape)
+    specs = list(in_specs) + list(jax.tree.leaves(
+        out_specs, is_leaf=lambda x: isinstance(x, pl.BlockSpec)))
+    dtypes = [a.dtype for a in args] + [o.dtype for o in outs]
+    held = 2 * sum(int(np.prod(spec.block_shape)) * np.dtype(dt).itemsize
+                   for spec, dt in zip(specs, dtypes))
+    return None if held <= DEFAULT_SCOPED_VMEM else int(held * 1.25)
+
+
 def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
                  out_shape, *args):
     """``pallas_call`` with a grid step's loop bounds ``(lo, hi)
     [B, grid[2]]`` prefetched as scalars; index maps get both after
     the grid indices."""
+    limit = _vmem_limit(in_specs, out_specs, out_shape, args)
+    params = {} if limit is None else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit))
     return pl.pallas_call(
         kernel, out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_specs),
-        name=name,
+        name=name, **params,
     )(*(x.reshape(-1) for x in bounds), *args)
 
 
-def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
+def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
     b, l, nq, hd = q.shape
     nkv = k.shape[2]
     group = nq // nkv
@@ -275,12 +334,14 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
     kt = k.transpose(0, 2, 1, 3)  # [B, nkv, L, hd]
     vt = v.transpose(0, 2, 1, 3)
     segq, segk = _expand_segments(seg_ids)
-    kv_range, _ = block_ranges(seg_ids, bq, bk, causal)
+    kv_range, _ = block_ranges(seg_ids, bq, bk, causal,
+                               sliding_window=window)
 
     at = _index_maps(group)
 
     out, lse = _ranged_call(
-        functools.partial(_fwd_kernel, scale=scale, bk=bk, causal=causal),
+        functools.partial(_fwd_kernel, scale=scale, bk=bk, causal=causal,
+                          window=window),
         "flash_fwd", (b, nq, l // bq), kv_range,
         [
             pl.BlockSpec((1, 1, bq, hd), at["row"]),
@@ -303,7 +364,8 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
 def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
                    q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                    lse_ref, delta_ref, dq_ref,
-                   *, scale: float, bk: int, causal: bool):
+                   *, scale: float, bk: int, causal: bool,
+                   window: Optional[int] = None):
     qi = pl.program_id(2)
     bq, hd = q_ref.shape[-2], q_ref.shape[-1]
 
@@ -324,6 +386,8 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
         mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
         if causal:
             mask &= q_idx >= k_idx
+        if window is not None:
+            mask &= q_idx - k_idx < window
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -340,7 +404,8 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
 def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
                     q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref,
-                    *, scale: float, bq: int, causal: bool):
+                    *, scale: float, bq: int, causal: bool,
+                    window: Optional[int] = None):
     ki = pl.program_id(2)
     bk, hd = k_ref.shape[-2], k_ref.shape[-1]
 
@@ -362,6 +427,8 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
         mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
         if causal:
             mask &= q_idx >= k_idx
+        if window is not None:
+            mask &= q_idx - k_idx < window
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
         dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
@@ -381,7 +448,7 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, bq, bk):
+def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
     q, k, v, seg_ids, out, lse = res
     do = g
     b, l, nq, hd = q.shape
@@ -399,13 +466,14 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
     delta = (ot.astype(jnp.float32) * dot.astype(jnp.float32)).sum(-1)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, l, LANES))
 
-    kv_range, q_range = block_ranges(seg_ids, bq_, bk_, causal)
+    kv_range, q_range = block_ranges(seg_ids, bq_, bk_, causal,
+                                     sliding_window=window)
 
     at = _index_maps(group)
 
     dq = _ranged_call(
         functools.partial(_bwd_dq_kernel, scale=scale, bk=bk_,
-                          causal=causal),
+                          causal=causal, window=window),
         "flash_bwd_dq", (b, nq, l // bq_), kv_range,
         [
             pl.BlockSpec((1, 1, bq_, hd), at["row"]),
@@ -423,7 +491,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
 
     dk_partial, dv_partial = _ranged_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq_,
-                          causal=causal),
+                          causal=causal, window=window),
         "flash_bwd_dkv", (b, nq, l // bk_), q_range,
         [
             pl.BlockSpec((1, 1, l, hd), at["whole"]),
@@ -451,30 +519,32 @@ def _flash_bwd(res, g, scale, causal, bq, bk):
 # ----------------------------------------------------------------------
 # Public API
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_attention(q, k, v, seg_ids, scale, causal, bq, bk):
-    out, _ = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, seg_ids, scale, causal, bq, bk, window):
+    out, _ = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window)
     return out
 
 
-def _flash_attention_fwd(q, k, v, seg_ids, scale, causal, bq, bk):
-    out, lse = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk)
+def _flash_attention_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window):
+    out, lse = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window)
     return out, (q, k, v, seg_ids, out, lse)
 
 
 _flash_attention.defvjp(
     _flash_attention_fwd,
-    lambda scale, causal, bq, bk, res, g: _flash_bwd(
-        res, g, scale, causal, bq, bk))
+    lambda scale, causal, bq, bk, window, res, g: _flash_bwd(
+        res, g, scale, causal, bq, bk, window))
 
 
 def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
                     scale: Optional[float] = None,
                     logits_soft_cap: Optional[float] = None,
+                    sliding_window: Optional[int] = None,
                     block_q: int = DEFAULT_BQ,
                     block_k: int = DEFAULT_BK) -> jnp.ndarray:
     """Packed-segment flash attention; drop-in for
-    `ops.attention.packed_attention_xla` on TPU."""
+    `ops.attention.packed_attention_xla` on TPU. ``sliding_window=W``
+    (causal only): a query sees the last W tokens of its document."""
     if logits_soft_cap is not None:
         raise NotImplementedError(
             "soft cap not yet supported by the flash kernel; use the XLA "
@@ -487,6 +557,11 @@ def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
             "the batch into more microbatches (the MFC's n_mbs) so "
             "packed rows get shorter, or shard the sequence over a "
             "context-parallel mesh (ring attention).")
+    if sliding_window is not None and not (causal and sliding_window >= 1):
+        raise ValueError(
+            f"sliding_window={sliding_window} needs causal attention "
+            "and at least one token")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_attention(q, k, v, seg_ids.astype(jnp.int32),
-                            float(scale), causal, block_q, block_k)
+                            float(scale), causal, block_q, block_k,
+                            sliding_window)

@@ -44,7 +44,10 @@ MORE to them, a ``lax.cond`` takes the same path over all ``T x k``
 sorted rows instead, so no pair of a held expert is ever dropped,
 whatever the imbalance. There is no exchange and nothing stands in
 for the other ranks. With every expert held the same code is the
-uncut layer. Three more statistics then come back, ``HELD_PAIRS_STAT``
+uncut layer. A SHARED expert (``MoEConfig.shared_intermediate_dim``,
+leaves ``m["shared"]``) is no part of a share: every token visits it,
+every rank holds it whole, and it is added once, after the routed
+part, in every dispatch mode. Three more statistics then come back, ``HELD_PAIRS_STAT``
 (the pairs of held experts, added up over layers and microbatches),
 ``HELD_LOAD_STAT`` (the busiest HELD expert over the mean of all,
 ``T x k / E``; max) and ``SHARE_OVERFLOW_STAT`` (the layers, added up
@@ -100,7 +103,7 @@ def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
     sigmoid of its logit; the k are the largest of score +
     ``expert_bias`` [E], which moves the CHOICE and never the gate and
     takes no gradient; the gates are the chosen scores, divided by
-    (their sum + 1e-6) under ``norm_topk_prob``, times
+    (their sum + ``norm_topk_eps``) under ``norm_topk_prob``, times
     ``routed_scaling_factor``.
 
     Default (aux_loss/none): softmax over all experts, take top-k,
@@ -126,7 +129,7 @@ def router_probs(cfg_moe: MoEConfig, logits: jnp.ndarray,
         top_probs = jnp.take_along_axis(scores, top_idx, axis=-1)
         if cfg_moe.norm_topk_prob:
             top_probs = top_probs / (
-                top_probs.sum(-1, keepdims=True) + 1e-6)
+                top_probs.sum(-1, keepdims=True) + cfg_moe.norm_topk_eps)
         return top_probs * cfg_moe.routed_scaling_factor, top_idx
     if cfg_moe.routing_type == "sinkhorn":
         routed = sinkhorn(jax.lax.stop_gradient(logits))
@@ -392,6 +395,15 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                    * top_probs[:, :, None, None]).sum(axis=1)  # [T, E, C]
         out = jnp.einsum("ech,tec->th", expert_out.astype(jnp.float32),
                          combine)
+
+    if "shared" in m:
+        # the expert every token visits: a dense gated feed-forward
+        # beside the routed ones, weight 1, outside the sort. Every
+        # rank of an expert-parallel deployment holds it whole, so the
+        # shares' routed parts and THIS, once, add up to the layer.
+        from realhf_tpu.models.transformer import _dense_mlp
+        out = out + _dense_mlp(cfg, m["shared"], xt.astype(x.dtype),
+                               x.dtype).astype(jnp.float32)
 
     losses = {LOAD_STAT: load.max().astype(jnp.float32)
               * (e / (t * moe.top_k))}

@@ -1,44 +1,48 @@
 #!/usr/bin/env python3
-"""A builder's run on the chip for the ``lfm2_moe`` family, outside the
-benchmark: what sizes ``benchmark/families/lfm2_moe.py``'s TOLERANCE,
-the packed rows and the decode path held to the reference at the
-cell's widths, whether XLA:TPU's grouped matmul skips the rows past
-its last group, and one ``quickstart gen`` run on the same checkpoint.
+"""A builder's run on the chip for a patterned sparse family
+(``lfm2_moe``, ``laguna``), outside the benchmark: what sizes the
+family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
+rows and the decode path held to the reference at its cell's widths,
+and one ``quickstart gen`` run on the same checkpoint.
 
-    chiprun --chips 1 -- python3 scripts/chip_check_lfm2.py \
+    chiprun --chips 1 -- python3 scripts/chip_check.py laguna \
         --seeds 2713340771 3190554277 [--gen]
 
 One process (the chip belongs to it). Prints one JSON line a phase and
-writes them to ``chiprun_out/chip_check_lfm2.jsonl``:
+writes them to ``chiprun_out/chip_check_<family>.jsonl``:
 
-- ``ragged``: ``lax.ragged_dot`` of 16,384 sorted rows of 2048 against
-  8 experts of 2048 x 1536 with group sizes that cover 2,048 rows (an
-  eighth: the cell), 8,192 and all 16,384, of 2,048 and 4,096 rows
-  alone, and of 4,096 rows whose last group takes in 2,048 rows that
-  belong to no expert: milliseconds a call, every case compiled and
-  warmed before any is timed. The first costs what 2,048 rows alone
-  do: row tiles past the last group are skipped, and left UNWRITTEN
-  (``rows_past_last_group_are_zero`` is false where the memory was
-  not zero already; PERF.md, PR 31).
 - ``tolerance``: for each seed, the bf16 engine's log-probabilities on
   the benchmark's fixed 4 x 256 batch against the float32 reference,
   as a share of the reference's spread; the same for the reference at
   default matmul precision, with the EXPERT weights rounded to int8 by
   row and float8, with every matrix so rounded, and with each WRONG
-  equation: softmax in place of sigmoid, the bias left out of the
-  choice, gates not renormalised, whole-width query/key norm, the
-  convolution's taps reversed, the convolution crossing a document
-  boundary; and how many tokens change their set of 4 experts in the
-  first sparse layer when the bias is left out.
-- ``packed``: the same four documents as ONE packed row of 1,024 with
-  three boundaries inside, through the engine, against the reference's
-  four separate documents.
-- ``decode``: prefill of 192 tokens, then ``decode_step``s through K/V
-  and conv state, teacher-forced, bf16, against the reference's full
-  forward.
+  equation the family names (``family.WRONG``, and the variants of
+  ``FAMILIES`` below that are said by a config key).
+- ``packed``: documents as ONE packed row through the engine, each
+  against the reference's forward of that document alone. ``lfm2_moe``:
+  the fixed batch's four documents in a row of 1,024, three boundaries
+  for the convolution to stop at. ``laguna``: documents of 1,536,
+  1,024, 1,024 and 512 tokens in a row of 4,096, so that the window's
+  edge (512) lies inside three documents and three documents' edges
+  lie inside a window's reach of the next one's first tokens; and what
+  each wrong equation does to the longest of them (the fixed batch's
+  256 tokens never reach a window of 512).
+- ``decode``: a prefill, then ``decode_step``s through the caches,
+  teacher-forced, bf16, against the reference's full forward
+  (log-probabilities, not tokens). ``laguna``: 2 sequences of 768, a
+  prefill of 640: every decoded token's window (512) ends inside the
+  cache, which keeps every row in window layers too.
+- ``ragged`` (``lfm2_moe`` only): ``lax.ragged_dot`` of 16,384 sorted
+  rows of 2048 against 8 experts of 2048 x 1536 with group sizes that
+  cover 2,048 rows (an eighth: the cell), 8,192 and all 16,384, of
+  2,048 and 4,096 rows alone, and of 4,096 rows whose last group takes
+  in 2,048 rows that belong to no expert: milliseconds a call, every
+  case compiled and warmed before any is timed. The first costs what
+  2,048 rows alone do: row tiles past the last group are skipped, and
+  left UNWRITTEN (PERF.md, PR 31).
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
-  ``kv_layers`` and ``conv_state_bytes``.
+  their attributes.
 """
 
 import argparse
@@ -58,19 +62,36 @@ from chip_check_olmoe import (  # noqa: E402  (scripts/ is sys.path[0])
     share,
 )
 
-CELL = "lfm2-24b-a2b-l5-ep8.sft"
-OUT = os.path.join(ROOT, "chiprun_out", "chip_check_lfm2.jsonl")
+#: what differs by family: its cell, the tests' tiny cell
+#: (``--rehearse``), the wrong equations that are said by a key of the
+#: config (``family.WRONG`` names the others), the packed row's
+#: documents (None: the fixed batch's rows), the decode check's batch
+#: (rows, length, prefill; None: the fixed batch, three quarters of it
+#: prefilled)
+FAMILIES = {
+    "lfm2_moe": dict(
+        cell="lfm2-24b-a2b-l5-ep8.sft", tiny=("lfm2", "tiny-lfm2.sft"),
+        wrong_keys=dict(bias_left_out=dict(use_expert_bias=False),
+                        gates_not_renormalised=dict(norm_topk_prob=False)),
+        packed_docs=None, decode=None),
+    "laguna": dict(
+        cell="laguna-xs.2-l5-ep16.sft-4k",
+        tiny=("laguna", "tiny-laguna.sft"), wrong_keys={},
+        packed_docs=(1536, 1024, 1024, 512), decode=(2, 768, 640)),
+}
+FAMILY = None  # set by main: the family's name, for say's file
 
 
 def say(**fields):
     line = json.dumps(fields)
     print(line, flush=True)
-    os.makedirs(os.path.dirname(OUT), exist_ok=True)
-    with open(OUT, "a") as f:
+    out = os.path.join(ROOT, "chiprun_out", f"chip_check_{FAMILY}.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    with open(out, "a") as f:
         f.write(line + "\n")
 
 
-def one_chip_engine(ckpt):
+def one_chip_engine(ckpt, dtype="bfloat16"):
     import jax
 
     from realhf_tpu.api.config import ModelName
@@ -78,8 +99,8 @@ def one_chip_engine(ckpt):
     from realhf_tpu.models.hf import registry
     from realhf_tpu.parallel import mesh as mesh_lib
 
-    cfg, params = registry.load_hf_checkpoint(ckpt, "lfm2_moe")
-    cfg.param_dtype = "bfloat16"
+    cfg, params = registry.load_hf_checkpoint(ckpt, FAMILY)
+    cfg.param_dtype = cfg.compute_dtype = dtype
     par = mesh_lib.ParallelismConfig()
     ctx = mesh_lib.MeshContext(
         ModelName("default", 0),
@@ -160,7 +181,7 @@ def flipped_without_bias(family, hf, tensors, ids):
                     with_bias[..., held].sum() / with_bias.sum()))
 
 
-def tolerance(cell, seed, work):
+def tolerance(cell, seed, work, table=True):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -178,6 +199,10 @@ def tolerance(cell, seed, work):
     got = np.asarray(engine.forward_logprobs(ids, np.ones_like(ids)),
                      np.float32)[:, :-1]
     rows = dict(engine_bf16=share(got, want))
+    if not table:
+        say(phase="tolerance", seed=seed, tolerance=family.TOLERANCE,
+            secs=round(time.monotonic() - t, 1), **rows)
+        return ckpt, engine, tensors, ids, want
 
     with jax.default_matmul_precision("default"):
         # family.logprobs sets "highest" itself: run its pieces here
@@ -198,28 +223,59 @@ def tolerance(cell, seed, work):
     for wrong in family.WRONG:
         rows[f"wrong_{wrong}"] = share(
             family.logprobs(hf, tensors, ids, wrong=(wrong,)), want)
-    rows["wrong_bias_left_out"] = share(family.logprobs(
-        dict(hf, use_expert_bias=False), tensors, ids), want)
-    rows["wrong_gates_not_renormalised"] = share(family.logprobs(
-        dict(hf, norm_topk_prob=False), tensors, ids), want)
+    for wrong, keys in FAMILIES[FAMILY]["wrong_keys"].items():
+        rows[f"wrong_{wrong}"] = share(family.logprobs(
+            dict(hf, **keys), tensors, ids), want)
+    if FAMILY == "lfm2_moe":
+        rows["routing"] = flipped_without_bias(family, hf, tensors, ids)
     say(phase="tolerance", seed=seed, tolerance=family.TOLERANCE,
-        secs=round(time.monotonic() - t, 1),
-        routing=flipped_without_bias(family, hf, tensors, ids), **rows)
+        secs=round(time.monotonic() - t, 1), **rows)
     return ckpt, engine, tensors, ids, want
 
 
-def packed(cell, engine, ids, want):
-    """The batch's documents as ONE packed row: the convolution and the
-    flash kernel must stop at the three boundaries inside it."""
+def packed(cell, engine, tensors, docs, ckpt=None):
+    """``docs`` (1-D arrays of token ids) as ONE packed row through the
+    engine, each against the reference's forward of it alone: the
+    convolution, the flash kernels' ranges and windows, the rotary
+    positions must all stop at the boundaries inside the row."""
     import numpy as np
-    b, n = ids.shape
-    row = ids.reshape(1, b * n)
-    seg = np.repeat(np.arange(1, b + 1, dtype=np.int32), n)[None]
-    got = np.asarray(engine.forward_logprobs(row, seg), np.float32)
-    got = got.reshape(b, n)[:, :-1]
-    say(phase="packed", row=b * n, documents=b,
-        tolerance=cell["family"].TOLERANCE, engine_bf16=share(got, want),
-        first_two_tokens_after_a_boundary=share(got[1:, :2], want[1:, :2]))
+    family, hf = cell["family"], cell["hf"]
+    row = np.concatenate(docs)[None].astype(np.int32)
+    seg = np.concatenate([np.full(len(d), j + 1, np.int32)
+                          for j, d in enumerate(docs)])[None]
+    got = np.asarray(engine.forward_logprobs(row, seg), np.float32)[0]
+    rows, at = {}, 0
+    for j, doc in enumerate(docs):
+        want = family.logprobs(hf, tensors, doc[None].astype(np.int32))[0]
+        mine = got[at:at + len(doc) - 1]
+        rows[f"document_{j}_of_{len(doc)}"] = dict(
+            all=share(mine, want), first_two_tokens=share(mine[:2], want[:2]))
+        window = hf.get("sliding_window")
+        if window and len(doc) > window + 1:
+            rows[f"document_{j}_of_{len(doc)}"]["past_the_window"] = share(
+                mine[window:], want[window:])
+        at += len(doc)
+    # the fixed batch's documents (256 tokens) are shorter than a
+    # sliding window: what a window off by one, and every other wrong
+    # equation, does to a document LONGER than the window is read here
+    window = hf.get("sliding_window")
+    if window and len(docs[0]) > window + 1:
+        doc = docs[0][None].astype(np.int32)
+        want = family.logprobs(hf, tensors, doc)
+        rows["wrong_on_the_longest_document"] = {
+            wrong: share(family.logprobs(hf, tensors, doc, wrong=(wrong,)),
+                         want) for wrong in family.WRONG}
+        # and the program with float32 weights and products at the
+        # highest precision on that document: where bf16's noise hides
+        # a window off by one, this reading is what it is held against
+        import jax
+        with jax.default_matmul_precision("highest"):
+            exact = np.asarray(one_chip_engine(
+                ckpt, "float32").forward_logprobs(doc, np.ones_like(doc)),
+                np.float32)[:, :-1]
+        rows["engine_float32_on_the_longest_document"] = share(exact, want)
+    say(phase="packed", row=row.shape[1], documents=[len(d) for d in docs],
+        tolerance=family.TOLERANCE, **rows)
 
 
 def decode(cell, engine, ids, want, n_pre=192):
@@ -269,10 +325,10 @@ def gen(cell, ckpt, work, seed):
     tracing.start(sync=True)
     t = time.monotonic()
     quickstart.main([
-        "gen", "experiment_name=chip-check-lfm2", f"trial_name=s{seed}",
+        "gen", f"experiment_name=chip-check-{FAMILY}", f"trial_name=s{seed}",
         f"seed={seed}", "total_train_epochs=1",
         f"dataset.path={prompts}", "dataset.train_bs_n_seqs=128",
-        "dataset.max_seqlen=256", "model.type=lfm2_moe",
+        "dataset.max_seqlen=256", f"model.type={FAMILY}",
         f"model.path={ckpt}", "max_new_tokens=256", "min_new_tokens=256",
         f"output_file={os.path.join(work, 'gen.jsonl')}"])
     wall = time.monotonic() - t
@@ -286,44 +342,70 @@ def gen(cell, ckpt, work, seed):
 
 
 def main():
+    global FAMILY
     p = argparse.ArgumentParser()
+    p.add_argument("family", choices=sorted(FAMILIES))
     p.add_argument("--seeds", type=int, nargs="+", required=True)
     p.add_argument("--gen", action="store_true")
     p.add_argument("--only-ragged", action="store_true")
+    p.add_argument("--no-table", action="store_true",
+                   help="the engine's reading alone, no lower precision "
+                        "and no wrong equation")
     p.add_argument("--rehearse", action="store_true",
-                   help="the tests' tiny LFM2 cell, on any device: "
-                        "finds faults, measures nothing")
+                   help="the tests' tiny cell of the family, on any "
+                        "device: finds faults, measures nothing")
     args = p.parse_args()
+    FAMILY = args.family
+    spec = FAMILIES[FAMILY]
 
     import jax
+    import numpy as np
 
-    from benchmark import run
+    from benchmark import generate, run
     from realhf_tpu.base.backend import enable_compile_cache
 
     enable_compile_cache()
     dev = jax.devices()[0]
-    say(phase="start", platform=dev.platform, kind=dev.device_kind)
+    say(phase="start", family=FAMILY, platform=dev.platform,
+        kind=dev.device_kind)
     if args.rehearse:
+        sub, name = spec["tiny"]
         cell = run.load_cell(os.path.join(
-            ROOT, "tests", "benchmark", "lfm2", "manifest.json"),
-            "tiny-lfm2.sft")
+            ROOT, "tests", "benchmark", sub, "manifest.json"), name)
     elif dev.platform != "tpu":
         sys.exit("needs a TPU")
     else:
-        cell = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), CELL)
-        ragged(cell["hf"])
+        cell = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"),
+                             spec["cell"])
+        if FAMILY == "lfm2_moe":
+            ragged(cell["hf"])
         if args.only_ragged:
             return
-    work = os.path.join(ROOT, "benchmark", ".cache", "chip_check_lfm2")
+    work = os.path.join(ROOT, "benchmark", ".cache", f"chip_check_{FAMILY}")
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     try:
         for i, seed in enumerate(args.seeds):
-            ckpt, engine, tensors, ids, want = tolerance(cell, seed, work)
+            ckpt, engine, tensors, ids, want = tolerance(
+                cell, seed, work, table=not args.no_table)
             if i == 0:
-                packed(cell, engine, ids, want)
-                decode(cell, engine, ids, want,
-                       n_pre=ids.shape[1] * 3 // 4)
+                rng = np.random.default_rng(seed + 2)
+                vocab = cell["hf"]["vocab_size"]
+                lens = spec["packed_docs"]
+                if args.rehearse and lens:  # toy rows: an eighth
+                    lens = tuple(n // 8 for n in lens)
+                packed(cell, engine, tensors, list(ids) if lens is None
+                       else [rng.integers(0, vocab, n) for n in lens], ckpt)
+                if spec["decode"] is None:
+                    decode(cell, engine, ids, want,
+                           n_pre=ids.shape[1] * 3 // 4)
+                else:
+                    b, n, n_pre = spec["decode"]
+                    if args.rehearse:
+                        n, n_pre = n // 8, n_pre // 8
+                    long = generate.fixed_batch(cell["hf"], seed + 3, b, n)
+                    decode(cell, engine, long, cell["family"].logprobs(
+                        cell["hf"], tensors, long), n_pre=n_pre)
             del engine, tensors
         if args.gen:
             gen(cell, ckpt, work, args.seeds[-1])

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's tool, no chip: lower the programs of the benchmark's
-accepted families (qwen2, mistral, olmoe) under a checkout and write
+accepted families (qwen2, mistral, olmoe, lfm2_moe) under a checkout and write
 their StableHLO texts, to show that a change to shared model code left
 a model of one block the programs it had.
 
@@ -17,8 +17,13 @@ sparse model's auxiliary terms) and the whole ``generate`` program;
 and the engine's own ``train`` and ``logprobs`` programs
 (``Engine._train_step_body``: accumulation, optimizer, statistics) at
 the tests' tiny widths, where ``Engine`` can hold real arrays. Equal
-lowered text is equal input to the compiler. What it cannot see: the
-Pallas kernels engage only on a TPU backend (PR 31 changed none).
+lowered text is equal input to the compiler. The Pallas kernels engage
+only on a TPU backend, so those programs hold none; the flash kernels
+are written out by themselves instead, as the jaxpr of their forward
+and three gradients at the cells' heads and rows without a window
+(``flash.<heads>.jaxpr.txt``: the three ``pallas_call``s with their
+bodies, grids, block specs and the ranges' arithmetic; source
+locations, which move with any edit of the file, taken out).
 """
 import os
 import sys
@@ -41,7 +46,7 @@ def dump(name, fn, *args, **kw):
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
-for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048)):
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096)):
     hf, meta = generate.load_config(os.path.join(root, "benchmark/configs", cfgname + ".json"))
     cfg = hf_models.config_from_hf(fam, hf)
     cfg.param_dtype = cfg.compute_dtype = "bfloat16"
@@ -51,20 +56,34 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
     sds = jax.ShapeDtypeStruct
     mb = dict(input_ids=sds((1, L), jnp.int32), seg_ids=sds((1, L), jnp.int32), prompt_mask=sds((1, L), jnp.bool_))
     loss_fn = sft._make_loss_fn(cfg)
-    moe = cfg.mlp_type == "moe"
+    moe = bool(cfg.n_moe_layers)
     def objective(p, mb):
         o = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"], return_aux=moe)
         aux = o[2] if moe else {}
         loss, stats = loss_fn(p, o[0], mb)
         return loss + moe_ops.aux_loss(aux), {**stats, **aux}
     dump(f"{cfgname}.train_grad", lambda p, mb: jax.value_and_grad(objective, has_aux=True)(p, mb), params, mb)
-    if fam != "olmoe":
+    if fam in ("qwen2", "mistral"):
         g = GenerationHyperparameters(max_new_tokens=256, min_new_tokens=256, greedy=False, force_no_logits_mask=True)
         b = 128 if fam == "qwen2" else 32
         dump(f"{cfgname}.generate",
              lambda p, i, s, pos, k: gen_mod.generate(cfg, p, i, s, pos, k, g, eos_token_id=None, pad_token_id=0),
              params, sds((b, 256), jnp.int32), sds((b, 256), jnp.int32), sds((b, 256), jnp.int32),
              jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+
+# the flash kernels by themselves: (query heads, key/value heads, head size, row) of cells 1-2, 3, 4, 5
+import re  # noqa: E402
+from realhf_tpu.ops.flash_attention import flash_attention  # noqa: E402
+for nq, nkv, hd, L in ((14, 2, 64, 4096), (32, 8, 128, 2048), (16, 16, 128, 2048), (32, 8, 64, 4096)):
+    sds = jax.ShapeDtypeStruct
+    q, k, v = (sds((1, L, n, hd), jnp.bfloat16) for n in (nq, nkv, nkv))
+    def grads(q, k, v, seg):
+        return jax.value_and_grad(lambda q, k, v: flash_attention(q, k, v, seg).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    txt = str(jax.make_jaxpr(grads)(q, k, v, sds((1, L), jnp.int32)))
+    txt = re.sub(r"name_and_src_info=[^\n]*", "", re.sub(r" at [^ \n]*\.py:\d+", "", txt))
+    name = f"flash.{nq}x{nkv}x{hd}x{L}.jaxpr"
+    open(os.path.join(out, name + ".txt"), "w").write(txt)
+    print(name, len(txt), txt.count("pallas_call"))
 
 # the engine's own train step, inference programs, at the tests' tiny configs (real arrays)
 from realhf_tpu.api.config import ModelName

@@ -109,6 +109,97 @@ def test_flash_compiles_at_its_stated_limit(one_chip, nq, nkv, hd):
     _compile(_flash_grads, *args)
 
 
+@pytest.mark.parametrize("nq,window", [(64, 512), (48, None)],
+                         ids=["window_64x8x128", "full_48x8x128"])
+def test_flash_compiles_at_lagunas_two_kinds_of_layer(one_chip, nq,
+                                                      window):
+    """The sixth cell's rows of 4096 at both of Laguna-XS.2's layer
+    kinds: the WINDOWED forward and backward at 64 query and 8
+    key/value heads of 128 (a window takes nothing off VMEM: K and V
+    stay whole a head), and the full ones at 48."""
+    args = _qkv(one_chip, 1, FLASH_MAX_LEN, nq, 8, 128)
+
+    def fwd(q, k, v, seg):
+        return flash_attention(q, k, v, seg, sliding_window=window)
+
+    def grads(q, k, v, seg):
+        return jax.grad(lambda q, k, v: fwd(q, k, v, seg).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    _compile(fwd, *args)
+    text = _compile(grads, *args).as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+
+
+@pytest.mark.parametrize("limit", [True, False],
+                         ids=["as_it_is", "without_vmem_limit"])
+def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
+    """The sixth cell's train program as the chip compiles it: one
+    microbatch's SFT forward and backward of ALL FIVE of
+    ``laguna-xs.2-l5-ep16``'s layers at published widths, a row of
+    4096, bf16, rematerialised. The kernels alone (the test above) and
+    any stack of one or two layers fit the default 16 MiB of scoped
+    VMEM; inside the whole program the dkv pass at (48, 8, 128) asks
+    for 16.17 MB of it, because what XLA schedules around a kernel
+    takes from the same 16 MiB, and PR 33's first chip call died of
+    that. ``flash_attention._vmem_limit`` gives such a call its
+    ``vmem_limit_bytes``; ``without_vmem_limit`` takes it away and the
+    same program must be refused for VMEM: the day that case compiles,
+    ``_vmem_limit`` holds nothing up and can go."""
+    import json
+    import os
+
+    from benchmark import generate, run
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.models import hf as hf_models
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import flash_attention as fa
+    from realhf_tpu.ops import moe as moe_ops
+
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
+        config = next(c for c in json.load(f)["configs"]
+                      if c["name"] == "laguna-xs.2-l5-ep16")
+    hf, _ = generate.load_config(os.path.join(run.ROOT, config["file"]))
+    cfg = hf_models.config_from_hf("laguna", hf)
+    cfg.param_dtype = cfg.compute_dtype = "bfloat16"
+    cfg.gradient_checkpointing = True
+    if not limit:
+        monkeypatch.setattr(fa, "_vmem_limit", lambda *a: None)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    mb = dict(input_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
+              seg_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
+              prompt_mask=sds((1, FLASH_MAX_LEN), jnp.bool_))
+    loss_fn = sft._make_loss_fn(cfg)
+
+    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
+        return flash_attention(q, k, v, seg, causal=causal, scale=scale,
+                               sliding_window=sliding_window)
+
+    def objective(p, mb):
+        h, _, aux = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
+                              return_aux=True, attention_fn=attn)
+        loss, stats = loss_fn(p, h, mb)
+        return loss + moe_ops.aux_loss(aux), {**stats, **aux}
+
+    def step(p, mb):
+        return jax.value_and_grad(objective, has_aux=True)(p, mb)
+
+    if not limit:
+        with pytest.raises(Exception, match="(?i)vmem"):
+            _compile(step, params, mb)
+        return
+    text = _compile(step, params, mb).as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+
+
 def test_flash_compiles_under_shard_map(topo):
     """Cell 3's layout: rows over "data", heads over "model" on a 2x2
     mesh, each shard's kernels taking their ranges from the local
@@ -126,7 +217,8 @@ def test_flash_compiles_under_shard_map(topo):
     seg = jax.ShapeDtypeStruct(seg.shape, seg.dtype, sharding=rows)
     attn = make_sharded_attention(
         mesh, inner=lambda *a, **kw: flash_attention(
-            *a, causal=kw["causal"], scale=kw["scale"]))
+            *a, causal=kw["causal"], scale=kw["scale"],
+            sliding_window=kw["sliding_window"]))
 
     def grads(q, k, v, seg):
         def loss(q, k, v):

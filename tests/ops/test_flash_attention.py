@@ -225,7 +225,8 @@ def test_block_counts_of_the_benchmark_rows(cell):
         6 * want[0], 6 * want[1])
 
 
-def _dense_ranges(seg, bq, bk, causal=True, ranges=fa.block_ranges):
+def _dense_ranges(seg, bq, bk, causal=True, sliding_window=None,
+                  ranges=fa.block_ranges):
     """The ranges of rows of ONE segment: every key block up to the
     causal diagonal (or the row's end), as before the ranges existed."""
     return ranges(jnp.ones_like(seg), bq, bk, causal)
@@ -271,3 +272,173 @@ def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch):
         keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
         np.testing.assert_allclose(got[keep], ref[keep], rtol=5e-3,
                                    atol=5e-3, err_msg=name)
+
+
+# ----------------------------------------------------------------------
+# A sliding window inside the kernels
+# ----------------------------------------------------------------------
+def needed_blocks_windowed(seg, bq, bk, window):
+    b, l = seg.shape
+    idx = np.arange(l)
+    mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0) \
+        & (idx[:, None] >= idx[None, :])[None] \
+        & (idx[:, None] - idx[None, :] < window)[None]
+    return mask.reshape(b, l // bq, bq, l // bk, bk).any(axis=(2, 4))
+
+
+@pytest.mark.parametrize("kind", ["none", "both"])
+@pytest.mark.parametrize("window", [1, 31, 32, 33, 64, 100, 511, 512, 4096])
+@pytest.mark.parametrize("bq,bk,align", [(32, 64, 64), (64, 32, 1)])
+def test_block_ranges_with_a_window_leave_out_no_needed_block(
+        bq, bk, align, window, kind):
+    """Whatever the window against the blocks (below, at, above them,
+    the whole row): no block that holds an unmasked pair is left out,
+    the ranges on the host are those inside a program, and they run
+    from the first needed block to the last, no further."""
+    l = 512
+    rng = np.random.default_rng(abs(hash((bq, bk, align, window))) % 2**31)
+    seg = packed_rows(rng, l, align, kind)
+    needed = needed_blocks_windowed(seg, bq, bk, window)
+    assert not needed[3].any() and needed[:3].any()
+    on_host = fa.block_ranges(seg, bq, bk, True, xp=np,
+                              sliding_window=window)
+    in_program = jax.jit(
+        lambda s: fa.block_ranges(s, bq, bk, True, sliding_window=window))(
+        jnp.asarray(seg))
+    for a, b in zip(jax.tree.leaves(on_host), jax.tree.leaves(in_program)):
+        assert a.dtype == b.dtype == np.int32
+        np.testing.assert_array_equal(a, np.asarray(b))
+    by_q, by_k = visited_blocks(on_host, l // bq, l // bk)
+    for visited in (by_q, by_k):
+        assert not (needed & ~visited).any()
+        assert not visited[3].any()
+    np.testing.assert_array_equal(by_q, _hull(needed, axis=2))
+    np.testing.assert_array_equal(by_k, _hull(needed, axis=1))
+    # and a window changes nothing of the ranges without one
+    causal = fa.block_ranges(seg, bq, bk, True, xp=np)
+    if window >= l:
+        for a, b in zip(jax.tree.leaves(on_host), jax.tree.leaves(causal)):
+            np.testing.assert_array_equal(a, b)
+    else:
+        assert (on_host[0][0] >= causal[0][0]).all()
+        assert (on_host[1][1] <= causal[1][1]).all()
+
+
+def test_no_window_is_the_ranges_of_before():
+    """``sliding_window=None`` is the default everywhere and adds
+    nothing: the same ranges (the kernels' own programs without a
+    window are compared with the parent's by
+    ``scripts/lowered_programs.py``); a window needs causality."""
+    seg = packed_rows(np.random.default_rng(0), 512, 8, "both")
+    for a, b in zip(
+            jax.tree.leaves(fa.block_ranges(seg, 32, 64, xp=np)),
+            jax.tree.leaves(fa.block_ranges(seg, 32, 64, xp=np,
+                                            sliding_window=None))):
+        np.testing.assert_array_equal(a, b)
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention(*make_inputs(np.random.default_rng(0), l=128),
+                           causal=False, sliding_window=8)
+
+
+@pytest.mark.parametrize("row,window,want", [
+    (4096, 512, (30, 72)), (4096, None, (72, 72)), (4096, 4096, (72, 72)),
+    (2048, 512, (14, 20)), (4096, 1, (16, 72))])
+def test_block_counts_under_a_window(row, window, want):
+    """One document a row at the kernels' blocks (256 x 512): the
+    benchmark's sixth cell visits 30 of its 72 causal block pairs in a
+    window layer."""
+    seg = np.ones((1, row), np.int32)
+    assert fa.block_counts(seg, sliding_window=window) == want
+
+
+@pytest.mark.parametrize("window", [5, 32, 64, 70, 200])
+def test_windowed_kernels_match_the_xla_mask(window):
+    """Forward and all three gradients of the windowed kernels (in
+    interpret mode, blocks of 32 x 64: windows below, at and above
+    both) against the XLA path's explicit mask, on packed rows whose
+    documents' edges lie inside a window's reach."""
+    rng = np.random.default_rng(11)
+    b, l, nq, nkv, hd = 3, 256, 2, 1, 32
+    seg_np = packed_rows(rng, l, 1, "both")[1:]
+    seg = jnp.asarray(seg_np)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((b, l, n, hd)),
+                              jnp.float32) for n in (nq, nkv, nkv, nq))
+    valid = jnp.asarray(seg_np != 0)[..., None, None]
+
+    def run(attn):
+        def loss(q, k, v):
+            out = attn(q, k, v, seg, sliding_window=window)
+            return (jnp.where(valid, out, 0.0) * w).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return [np.asarray(x) for x in (out,) + grads]
+
+    with pltpu.force_tpu_interpret_mode():
+        got = run(functools.partial(fa.flash_attention, block_q=32,
+                                    block_k=64))
+    want = run(packed_attention_xla)
+    visited, diagonal = fa.block_counts(seg_np, 32, 64,
+                                        sliding_window=window)
+    assert visited < diagonal
+    for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
+        keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
+        np.testing.assert_allclose(a[keep], ref[keep], rtol=5e-3,
+                                   atol=5e-3, err_msg=name)
+    # a window of 1 more is another function where a document is
+    # longer than the window: the comparison can tell
+    longest = max(int(np.bincount(row)[1:].max()) for row in seg_np
+                  if row.any())
+    wider = np.asarray(packed_attention_xla(q, k, v, seg,
+                                            sliding_window=window + 1))
+    assert (np.abs(wider - want[0])[seg_np != 0].max() > 1e-2) \
+        == (window < longest)
+
+
+def test_packed_attention_hands_the_window_to_the_kernel(monkeypatch):
+    """The dispatcher no longer turns a windowed layer away from the
+    flash kernel: with Pallas on it gets the window."""
+    from realhf_tpu.ops import attention
+    seen = {}
+
+    def fake(q, k, v, seg, **kw):
+        seen.update(kw)
+        return q
+    monkeypatch.setattr(attention, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", fake)
+    q, k, v, seg = make_inputs(np.random.default_rng(0), l=128, hd=64)
+    attention.packed_attention(q, k, v, seg, sliding_window=17)
+    assert seen["sliding_window"] == 17
+    assert attention.flash_takes(128, 64) and not attention.flash_takes(
+        100, 64)
+
+
+@pytest.mark.parametrize("heads,row,asked", [
+    ((14, 2, 64), 4096, False), ((32, 8, 128), 2048, False),
+    ((16, 16, 128), 2048, False), ((32, 8, 64), 4096, False),
+    ((48, 8, 128), 4096, True), ((64, 8, 128), 4096, True)],
+    ids=["qwen", "mistral", "olmoe", "lfm2", "laguna_full",
+         "laguna_window"])
+def test_only_a_row_of_4096_at_heads_of_128_asks_for_more_vmem(
+        heads, row, asked, monkeypatch):
+    """The dkv pass keeps Q, dO, lse, delta and the query-side segment
+    ids whole a head: at heads of 128 and rows of 4096 that is past
+    the default scoped VMEM inside a whole train program (PERF.md, PR
+    33), so the call asks for what it holds and a quarter more. Every
+    shape the accepted cells run fits the default and passes NO
+    compiler parameter: its program is the parent's."""
+    limits = []
+    real = fa._vmem_limit
+    monkeypatch.setattr(fa, "_vmem_limit", lambda *a: limits.append(
+        real(*a)) or limits[-1])
+    nq, nkv, hd = heads
+    q, k, v = (jax.ShapeDtypeStruct((1, row, n, hd), jnp.bfloat16)
+               for n in (nq, nkv, nkv))
+    seg = jax.ShapeDtypeStruct((1, row), jnp.int32)
+    jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, s).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v))(q, k, v, seg)
+    fwd, dq, dkv = limits
+    assert fwd is None and dq is None
+    assert (dkv is not None) == asked
+    if asked:
+        assert fa.DEFAULT_SCOPED_VMEM < dkv < 2 * fa.DEFAULT_SCOPED_VMEM

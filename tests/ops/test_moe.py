@@ -543,3 +543,76 @@ def test_every_row_of_a_shares_grouped_products_lies_in_a_group(
                                     held_leaves(m, 4, 2), x)
     rows = 64 * 4 if pull else 2 * 64 * 4 * 2 // 16
     assert seen == [(rows, rows)] * 3
+
+
+# ----------------------------------------------------------------------
+# A shared expert beside the routed ones
+# ----------------------------------------------------------------------
+def shared_leaves(seed=5, width=20):
+    rng = np.random.default_rng(seed)
+    return {k: jnp.asarray(rng.standard_normal(shape) * 0.2, jnp.float32)
+            for k, shape in (("wg", (32, width)), ("wu", (32, width)),
+                             ("wd", (width, 32)))}
+
+
+def swiglu_oracle(s, x):
+    xt = np.asarray(x, np.float64)[0]
+    a = xt @ np.asarray(s["wg"], np.float64)
+    return (a / (1 + np.exp(-a)) * (xt @ np.asarray(s["wu"], np.float64))) \
+        @ np.asarray(s["wd"], np.float64)
+
+
+@pytest.mark.parametrize("grouped", [True, False], ids=["ragged", "dense"])
+def test_shared_expert_is_added_to_every_token_with_weight_one(grouped):
+    """The routed part (no bias, the 1e-20 of the family that has a
+    shared expert, a scaling factor) plus a dense SwiGLU over all
+    tokens, in both exact dispatch modes; no statistic changes."""
+    m, x = share_layer(seed=2)
+    m = {k: v for k, v in m.items() if k != "expert_bias"}
+    shared = shared_leaves()
+    cfg = share_cfg(use_expert_bias=False, routed_scaling_factor=2.5,
+                    norm_topk_eps=1e-20, shared_intermediate_dim=20,
+                    use_grouped_gemm=grouped)
+    routed, aux0 = moe_ops.moe_mlp_with_losses(cfg, m, x)
+    out, aux = moe_ops.moe_mlp_with_losses(cfg, {**m, "shared": shared}, x)
+    want = sigmoid_oracle(m, x, True, 2.5, False) + swiglu_oracle(shared, x)
+    np.testing.assert_allclose(np.asarray(out)[0], want, rtol=2e-4,
+                               atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(out - routed)[0], swiglu_oracle(shared, x), rtol=2e-4,
+        atol=2e-6)
+    assert set(aux) == set(aux0) == {moe_ops.LOAD_STAT}
+    assert float(aux[moe_ops.LOAD_STAT]) == float(aux0[moe_ops.LOAD_STAT])
+    grads = jax.grad(lambda s: moe_ops.moe_mlp_with_losses(
+        cfg, {**m, "shared": s}, x)[0].sum())(shared)
+    assert all(np.asarray(g).any() for g in grads.values())
+
+
+def test_sixteen_shares_and_the_shared_expert_once_add_up_to_the_layer():
+    """The tie of the share to the model where every rank also holds
+    the shared expert: sixteen ranks of ONE expert each, their ROUTED
+    parts (each rank's result less what the shared expert gives it)
+    added up, plus the shared expert counted once, are what the layer
+    gives with every expert held; adding the ranks' results as they
+    are would count the shared expert sixteen times."""
+    m, x = share_layer(seed=3)
+    shared = shared_leaves()
+    kw = dict(shared_intermediate_dim=20)
+    whole, _ = moe_ops.moe_mlp_with_losses(
+        share_cfg(**kw), {**m, "shared": shared}, x)
+    once = swiglu_oracle(shared, x)
+    routed, naive, pairs = 0.0, 0.0, 0.0
+    for rank in range(16):
+        part, aux = moe_ops.moe_mlp_with_losses(
+            share_cfg(held=(rank, 1), **kw),
+            {**held_leaves(m, rank, 1), "shared": shared}, x)
+        routed = routed + (np.asarray(part, np.float64)[0] - once)
+        naive = naive + np.asarray(part, np.float64)[0]
+        pairs += float(aux[moe_ops.HELD_PAIRS_STAT])
+    np.testing.assert_allclose(routed + once, np.asarray(whole)[0],
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(whole)[0],
+                               sigmoid_oracle(m, x) + once, rtol=2e-4,
+                               atol=2e-6)
+    assert np.abs(naive - np.asarray(whole)[0]).max() > 1.0
+    assert pairs == 40 * 4
