@@ -44,7 +44,7 @@ from realhf_tpu.ops.attention import flash_takes
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
-from realhf_tpu.ops.flash_attention import block_counts
+from realhf_tpu.ops.flash_attention import block_counts, flash_fwd_per_bwd
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host
@@ -295,6 +295,9 @@ class Engine:
         # generate program (its cache key, prompt batch shape) -> what
         # its compiled text says of the decode loop; see _decode_attrs
         self._decode_attrs_cache: Dict[Any, Dict[str, Any]] = {}
+        # (train program, step key, batch shape) -> what its compiled
+        # text says of the flash forward kernel's runs a backward
+        self._flash_fwd_per_bwd: Dict[Any, Optional[float]] = {}
         # program name -> (jitted fn, abstract args, static kwargs) of
         # its last call; see compiled_text
         self._last_call: Dict[str, tuple] = {}
@@ -448,6 +451,26 @@ class Engine:
                 decode_layer_copies=decode_ops.decode_layer_copies(
                     text, shape))
         return self._decode_attrs_cache[key]
+
+    def _report_flash_fwd_per_bwd(self, name: str, step_key,
+                                  attrs: Dict[str, Any], shape):
+        """What the train program that has just run under ``name``
+        does with the flash forward kernel in its backward, read ONCE
+        from its compiled text and set on every ``engine:train*`` span
+        of it: ``flash_fwd_per_bwd``
+        (``ops.flash_attention.flash_fwd_per_bwd``: 1.0 where the
+        rematerialised blocks keep the kernel's residuals, 2.0 where
+        they run it again). Nothing where the batch's rows do not go
+        to the kernels (``attrs``: :meth:`_count_flash_blocks`)."""
+        if "flash_block_share" not in attrs:
+            return
+        key = (name, step_key, tuple(shape))
+        if key not in self._flash_fwd_per_bwd:
+            self._flash_fwd_per_bwd[key] = flash_fwd_per_bwd(
+                self.compiled_text(name))
+        if self._flash_fwd_per_bwd[key] is not None:
+            self._last_span.set_attribute("flash_fwd_per_bwd",
+                                          self._flash_fwd_per_bwd[key])
 
     def compiled_text(self, name: str) -> str:
         """Optimized HLO of the program last run under ``name``
@@ -726,6 +749,8 @@ class Engine:
         self.params, self.opt_state, loss, stats, gnorm = self._run(
             "train", step, attrs, self.params, self.opt_state, stacked,
             weights)
+        self._report_flash_fwd_per_bwd("train", key, attrs,
+                                       host_batch["seg_ids"].shape)
         self.version += 1
         if self._decode_view is not None:
             # the view's gen-layout weight copy is now stale (params
@@ -790,6 +815,8 @@ class Engine:
         self.params, self.opt_state, losses, stats, gnorms = self._run(
             "train_seq", step, attrs, self.params, self.opt_state,
             stacked, weights)
+        self._report_flash_fwd_per_bwd("train_seq", key, attrs,
+                                       host_batch["seg_ids"].shape)
         self.version += len(minibatches)
         if self._decode_view is not None:
             self._decode_view.params = None

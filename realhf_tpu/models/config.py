@@ -226,7 +226,12 @@ class TransformerConfig:
     # jax.checkpoint_policies name used when gradient_checkpointing is
     # on. "nothing_saveable" = full recompute (min memory);
     # "dots_with_no_batch_dims_saveable" keeps matmul outputs (more
-    # HBM, measurably faster when the model fits).
+    # HBM, measurably faster when the model fits). WHATEVER the
+    # policy, a block also keeps the flash kernel's two residuals, its
+    # output and log-sum-exp (2 * head_dim + 4 bytes a (token, head);
+    # models/transformer.py:_remat): cheaper at every shape the
+    # kernels take than running flash_fwd again in the backward. The
+    # XLA attention path keeps nothing more.
     remat_policy: str = "nothing_saveable"
     # Pipeline-parallel remat granularity when gradient_checkpointing:
     # "tick" rematerializes each whole stage-slab evaluation, making

@@ -37,6 +37,7 @@ Layer indexing convention matches the reference (real_llm_base.py:394):
 conversion and (later) pipeline splitting.
 """
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -45,6 +46,7 @@ import jax.numpy as jnp
 from realhf_tpu.base.backend import pallas_enabled
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.ops.attention import decode_attention, packed_attention
+from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 
 Params = Dict[str, Any]
@@ -423,6 +425,34 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     return x, state, aux
 
 
+def _remat(cfg: TransformerConfig, block_fn):
+    """``block_fn`` rematerialised in the backward, where
+    ``cfg.gradient_checkpointing`` asks for it: it keeps what
+    ``cfg.remat_policy`` names and, whatever that is, the flash
+    kernel's output and log-sum-exp (``ops/flash_attention.py:
+    RESIDUAL_NAMES``). With both outputs of the kernel kept the
+    recomputed block's ``flash_fwd`` has no consumer and is not
+    emitted: the backward recomputes q, k and v and runs the kernel's
+    two backward passes, not its forward a second time. The XLA
+    attention path names nothing, so nothing more is kept there."""
+    if not cfg.gradient_checkpointing:
+        return block_fn
+    return jax.checkpoint(block_fn, policy=_remat_policy(cfg.remat_policy))
+
+
+@functools.lru_cache(maxsize=None)
+def _remat_policy(name: str):
+    """ONE policy object a name: every layer of an unrolled stack then
+    carries the same one, and what jax caches by a checkpoint's
+    parameters (a traced body's helper functions) is shared between
+    the layers as it is under a policy of ``jax.checkpoint_policies``
+    itself."""
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        getattr(policies, name),
+        policies.save_only_these_names(*RESIDUAL_NAMES))
+
+
 def rotary_table(cfg: TransformerConfig, positions: jnp.ndarray,
                  op: str = "attention"):
     """(cos, sin) ``positions.shape + (r // 2,)`` of an ``op`` layer's
@@ -534,13 +564,13 @@ def forward(
         # tick's whole slab evaluation checkpoints again, so the tick
         # scan's resident residuals are single boundary activations
         # while a tick's backward recompute holds only per-block
-        # inputs transiently.
+        # inputs transiently. The tick-level checkpoint
+        # (parallel/pipeline.py) keeps NOTHING, the flash kernel's
+        # residuals neither: it recomputes the whole slab by design,
+        # and the blocks of that recomputation keep theirs (_remat).
         remat_tick = (cfg.gradient_checkpointing
                       and cfg.pipeline_remat == "tick")
-        if cfg.gradient_checkpointing:
-            pblock = jax.checkpoint(
-                pblock,
-                policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
+        pblock = _remat(cfg, pblock)
 
         def block_step(slab, layer_ids, xc, segc, cosc, sinc):
             def body(carry, layer):
@@ -588,10 +618,7 @@ def forward(
                       constrain, attention_fn, moe_constraint,
                       window=cfg.sliding_window)
 
-    if cfg.gradient_checkpointing:
-        block_fn = jax.checkpoint(
-            block_fn,
-            policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
+    block_fn = _remat(cfg, block_fn)
 
     def scan_body(carry, layer):
         lp, layer_idx = layer
@@ -628,11 +655,7 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                           sin, constrain, attention_fn, moe_constraint,
                           kind, cfg.layer_window(i))
 
-        if cfg.gradient_checkpointing:
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
-        x, state, aux = block_fn(layers[str(i)], x)
+        x, state, aux = _remat(cfg, block_fn)(layers[str(i)], x)
         if return_kv and kind[0] != "conv":
             ks.append(state[0])
             vs.append(state[1])

@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from realhf_tpu.base import logging
+from realhf_tpu.ops.hlo_text import device_instructions
 
 logger = logging.getLogger("decode_attention")
 
@@ -436,10 +437,6 @@ def flash_decode_attention_stacked(
     return _trim_stats(res, return_stats, b, nq, group)
 
 
-_HLO_CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
-_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*->.*\{\s*$")
-_HLO_INSTRUCTION = re.compile(
-    r"^\s+(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\]\S*\s+([\w\-]+)\(")
 #: instructions that move nothing on the device
 _HLO_FREE = frozenset(
     ("parameter", "get-tuple-element", "bitcast", "constant"))
@@ -453,23 +450,12 @@ def decode_layer_copies(hlo_text: str, layer_shape) -> int:
     0 where attention reads the stack in place. A pure function of
     the optimized HLO text (``Engine.compiled_text``): instructions
     inside fusion bodies are no operations of their own and are not
-    counted, nor are those that move nothing (parameters, tuple
-    elements, bitcasts)."""
+    counted (``hlo_text.device_instructions``), nor are those that
+    move nothing (parameters, tuple elements, bitcasts)."""
     dims = ",".join(str(int(d)) for d in layer_shape)
-    lines = hlo_text.splitlines()
-    fused = {m.group(1) for m in map(_HLO_CALLS.search, lines) if m}
-    count, in_fusion = 0, False
-    for line in lines:
-        head = _HLO_COMPUTATION.match(line)
-        if head:
-            in_fusion = head.group(1) in fused
-            continue
-        if in_fusion:
-            continue
-        m = _HLO_INSTRUCTION.match(line)
-        if m and m.group(1) == dims and m.group(2) not in _HLO_FREE:
-            count += 1
-    return count
+    layer = re.compile(r"\w+\[" + dims + r"\]")  # an array, not a tuple
+    return sum(opcode not in _HLO_FREE and bool(layer.match(result))
+               for _, result, opcode in device_instructions(hlo_text))
 
 
 def local_layer_shape(mesh, b: int, nq: int, nkv: int, s: int, hd: int):

@@ -62,9 +62,10 @@ attention); streaming KV by DMA is future work.
 
 Mosaic requires the last two dims of every block to be (8, 128)-tile
 aligned, so 1D row metadata rides wider layouts: q-side segment ids
-and the saved lse/delta are broadcast over a 128-lane axis, k-side
-segment ids over an 8-sublane axis (same scheme as jax's bundled
-flash kernel).
+and lse/delta are broadcast over a 128-lane axis, k-side segment ids
+over an 8-sublane axis (same scheme as jax's bundled flash kernel).
+The SAVED lse is one float32 a (row, head), ``[B, nq, L]``; the
+backward broadcasts it again (``RESIDUAL_NAMES``).
 """
 
 import functools
@@ -73,8 +74,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from realhf_tpu.ops.hlo_text import device_instructions
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
@@ -89,6 +93,15 @@ FLASH_MAX_LEN = 4096
 NEG_INF = -2.0 ** 30
 LANES = 128
 SUBLANES = 8
+#: The two residuals of the backward that only the forward kernel can
+#: make, by the names ``_flash_attention_fwd`` gives them
+#: (``checkpoint_name``): the output, head-major ``[B, nq, L, hd]`` as
+#: the kernel writes it, and the log-sum-exp, one float32 a (row,
+#: head), ``[B, nq, L]``. A ``jax.checkpoint`` whose policy keeps both
+#: (``models/transformer.py:_remat``) recomputes q, k and v in the
+#: backward but not the kernel: ``2 hd + 4`` bytes a (token, head)
+#: against a second run of ``flash_fwd``.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _blocks(l: int, bq: int, bk: int):
@@ -325,6 +338,9 @@ def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
 
 
 def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
+    """The forward kernel's two outputs as it writes them: the output
+    head-major ``[B, nq, L, hd]`` and the lane-broadcast log-sum-exp
+    ``[B, nq, L, LANES]``."""
     b, l, nq, hd = q.shape
     nkv = k.shape[2]
     group = nq // nkv
@@ -355,7 +371,7 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
         (jax.ShapeDtypeStruct(qt.shape, q.dtype),
          jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
         qt, kt, vt, segq, segk)
-    return out.transpose(0, 2, 1, 3), lse
+    return out, lse
 
 
 # ----------------------------------------------------------------------
@@ -449,9 +465,12 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
 
 
 def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
-    q, k, v, seg_ids, out, lse = res
+    q, k, v, seg_ids, ot, lse = res
     do = g
     b, l, nq, hd = q.shape
+    # the kept log-sum-exp is one number a (row, head); the kernels
+    # read it over 128 lanes, as they do delta
+    lse = jnp.broadcast_to(lse[..., None], (b, nq, l, LANES))
     nkv = k.shape[2]
     group = nq // nkv
     bq_, bk_ = _blocks(l, bq, bk)
@@ -460,7 +479,6 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
-    ot = out.transpose(0, 2, 1, 3)
     segq, segk = _expand_segments(seg_ids)
 
     delta = (ot.astype(jnp.float32) * dot.astype(jnp.float32)).sum(-1)
@@ -516,18 +534,41 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
     return (dq_, dk.astype(k.dtype), dv.astype(v.dtype), None)
 
 
+def flash_fwd_per_bwd(hlo_text: str) -> Optional[float]:
+    """The ``flash_fwd`` custom calls of a compiled program over its
+    ``flash_bwd_dq`` ones: how often a layer's forward kernel runs for
+    each backward of it. 2.0 where a rematerialised block runs the
+    kernel again in the backward, 1.0 where the block keeps the
+    kernel's residuals (``RESIDUAL_NAMES``), in a scanned stack (one
+    pair in the loop bodies) and an unrolled one alike. None for a
+    program with no backward kernel. A pure function of the optimized
+    HLO text (``Engine.compiled_text``,
+    ``hlo_text.device_instructions``)."""
+    calls = [name for name, _, opcode in device_instructions(hlo_text)
+             if opcode == "custom-call"]
+    bwd = sum("flash_bwd_dq" in name for name in calls)
+    if not bwd:
+        return None
+    return sum("flash_fwd" in name for name in calls) / bwd
+
+
 # ----------------------------------------------------------------------
 # Public API
 # ----------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_attention(q, k, v, seg_ids, scale, causal, bq, bk, window):
     out, _ = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window)
-    return out
+    return out.transpose(0, 2, 1, 3)
 
 
 def _flash_attention_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window):
     out, lse = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window)
-    return out, (q, k, v, seg_ids, out, lse)
+    # the output as the kernel wrote it, head-major (the backward reads
+    # it that way, and keeping it costs no transposed copy), and ONE
+    # lane of the [B, nq, L, LANES] log-sum-exp: all hold the same number
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return out.transpose(0, 2, 1, 3), (q, k, v, seg_ids, out, lse)
 
 
 _flash_attention.defvjp(

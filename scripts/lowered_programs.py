@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's tool, no chip: lower the programs of the benchmark's
-accepted families (qwen2, mistral, olmoe, lfm2_moe) under a checkout and write
+accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna) under a checkout and write
 their StableHLO texts, to show that a change to shared model code left
 a model of one block the programs it had.
 
@@ -23,7 +23,9 @@ are written out by themselves instead, as the jaxpr of their forward
 and three gradients at the cells' heads and rows without a window
 (``flash.<heads>.jaxpr.txt``: the three ``pallas_call``s with their
 bodies, grids, block specs and the ranges' arithmetic; source
-locations, which move with any edit of the file, taken out).
+locations, which move with any edit of the file, taken out), and of
+the forward alone (``.fwd.jaxpr.txt``: what logprobs, values and
+prefill programs run of them).
 """
 import os
 import sys
@@ -46,7 +48,7 @@ def dump(name, fn, *args, **kw):
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
-for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096)):
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096)):
     hf, meta = generate.load_config(os.path.join(root, "benchmark/configs", cfgname + ".json"))
     cfg = hf_models.config_from_hf(fam, hf)
     cfg.param_dtype = cfg.compute_dtype = "bfloat16"
@@ -71,19 +73,20 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
              params, sds((b, 256), jnp.int32), sds((b, 256), jnp.int32), sds((b, 256), jnp.int32),
              jax.eval_shape(lambda: jax.random.PRNGKey(0)))
 
-# the flash kernels by themselves: (query heads, key/value heads, head size, row) of cells 1-2, 3, 4, 5
+# the flash kernels by themselves: (query heads, key/value heads, head size, row) of cells 1-2, 3, 4, 5, 6's full layers
 import re  # noqa: E402
 from realhf_tpu.ops.flash_attention import flash_attention  # noqa: E402
-for nq, nkv, hd, L in ((14, 2, 64, 4096), (32, 8, 128, 2048), (16, 16, 128, 2048), (32, 8, 64, 4096)):
+for nq, nkv, hd, L in ((14, 2, 64, 4096), (32, 8, 128, 2048), (16, 16, 128, 2048), (32, 8, 64, 4096), (48, 8, 128, 4096)):
     sds = jax.ShapeDtypeStruct
     q, k, v = (sds((1, L, n, hd), jnp.bfloat16) for n in (nq, nkv, nkv))
     def grads(q, k, v, seg):
         return jax.value_and_grad(lambda q, k, v: flash_attention(q, k, v, seg).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
-    txt = str(jax.make_jaxpr(grads)(q, k, v, sds((1, L), jnp.int32)))
-    txt = re.sub(r"name_and_src_info=[^\n]*", "", re.sub(r" at [^ \n]*\.py:\d+", "", txt))
-    name = f"flash.{nq}x{nkv}x{hd}x{L}.jaxpr"
-    open(os.path.join(out, name + ".txt"), "w").write(txt)
-    print(name, len(txt), txt.count("pallas_call"))
+    # (.fwd: the forward alone, what a program without a gradient runs)
+    for name, fn in ((f"flash.{nq}x{nkv}x{hd}x{L}.jaxpr", grads), (f"flash.{nq}x{nkv}x{hd}x{L}.fwd.jaxpr", flash_attention)):
+        txt = str(jax.make_jaxpr(fn)(q, k, v, sds((1, L), jnp.int32)))
+        txt = re.sub(r"name_and_src_info=[^\n]*", "", re.sub(r" at [^ \n]*\.py:\d+", "", txt))
+        open(os.path.join(out, name + ".txt"), "w").write(txt)
+        print(name, len(txt), txt.count("pallas_call"))
 
 # the engine's own train step, inference programs, at the tests' tiny configs (real arrays)
 from realhf_tpu.api.config import ModelName

@@ -19,7 +19,11 @@ calls. Beside that it writes under ``chiprun_out/``:
   compiler's own count of the program's arguments, outputs and
   temporaries;
 - ``spans_<cell>.json``: the counters and the ``engine:*`` spans, with
-  their attributes, of every capture the run made.
+  their attributes, of every capture the run made;
+- ``steps_<cell>.json``: every optimizer step's statistics as
+  ``Engine.train_batch`` returned them (loss, ``grad_norm``; warm-up
+  and traced steps included), every digit: two commits at one seed
+  are compared step by step with them.
 
 ``--summarize <cell>`` needs no chip: it reads those files back and
 prints the window's seconds by kind of operation.
@@ -49,6 +53,10 @@ KINDS = [
     ("gradient accumulation", r"closed_call/add$|closed_call/add "),
     ("copies", r" copy$|/squeeze|/remat2"),
 ]
+
+
+#: what every ``Engine.train_batch`` call of the run returned, in order
+OPT_STEPS = []
 
 
 def workload_of(argv):
@@ -90,8 +98,16 @@ def install(cell):
             f.write(text)
         return text
 
+    train_batch = Engine.train_batch
+
+    def train_and_keep(self, *args, **kw):
+        out = train_batch(self, *args, **kw)
+        OPT_STEPS.append(out)
+        return out
+
     trace_reduce.reduce = reduce_and_keep
     Engine.compiled_text = text_and_keep
+    Engine.train_batch = train_and_keep
 
 
 def run(argv):
@@ -117,6 +133,8 @@ def keep_spans(cell):
             for c in tracing.captures()]
     with open(os.path.join(OUT, f"spans_{cell}.json"), "w") as f:
         json.dump(kept, f)
+    with open(os.path.join(OUT, f"steps_{cell}.json"), "w") as f:
+        json.dump(OPT_STEPS, f)
 
 
 def summarize(cell, program="train"):

@@ -260,3 +260,67 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
     for kind, blocks in (("visited", 6 + 4), ("causal", 12 + 6)):
         assert capture.counter("flash_kv_blocks_total", role="default",
                                kind=kind) == blocks * cfg.n_layers
+
+
+@pytest.mark.parametrize("program,ratio", [("train", 1.0), ("train", 2.0),
+                                           ("train_seq", 1.0)])
+def test_train_span_says_how_often_the_forward_kernel_runs(
+        program, ratio, monkeypatch):
+    """Every ``engine:train`` / ``engine:train_seq`` span of a program
+    whose rows go to the flash kernels carries ``flash_fwd_per_bwd``,
+    read ONCE from the program's compiled text after its first call
+    (here a text of the chip's: the interpreter's CPU program holds no
+    custom call; ``tests/ops/test_chip_compile.py`` reads the real
+    ones). An engine whose rows take the XLA path reads no text."""
+    from realhf_tpu.api.config import ModelName
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.engine.optim import OptimizerConfig
+    from realhf_tpu.obs import tracing
+    from realhf_tpu.ops import functional as F
+    from realhf_tpu.parallel.mesh import MeshContext
+
+    cfg = _cfg()
+    ctx = MeshContext(ModelName("default", 0), _mesh(1, 1),
+                      ParallelismConfig())
+    seg = np.ones((2, 1, 512), np.int32)
+    ids = np.random.default_rng(0).integers(
+        1, 120, size=seg.shape).astype(np.int32)
+    mbs = [dict(input_ids=ids[i], seg_ids=seg[i]) for i in range(2)]
+    call = "custom-call(%q), custom_call_target=\"tpu_custom_call\"\n"
+    text = "%body (q: f32[8]) -> f32[8] {\n" \
+        + "".join(f"  %flash_fwd.{i} = f32[8]{{0}} {call}"
+                  for i in range(int(ratio))) \
+        + f"  ROOT %flash_bwd_dq.1 = f32[8]{{0}} {call}}}\n"
+    read = []
+
+    def compiled_text(self, name):
+        read.append(name)
+        return text
+
+    monkeypatch.setattr(Engine, "compiled_text", compiled_text)
+
+    def loss_fn(params, h, mb):
+        lp = F.shifted_logprobs_from_hidden(
+            cfg, params, h, mb["input_ids"], mb["seg_ids"])
+        return -lp.mean(), {}
+
+    def run():
+        engine = Engine(cfg, ctx, T.init_params(cfg, jax.random.PRNGKey(0)),
+                        optimizer=OptimizerConfig())
+        tracing.start()
+        for _ in range(2):
+            if program == "train":
+                engine.train_batch(mbs, loss_fn, loss_fn_key="nll")
+            else:
+                engine.train_minibatches([mbs[:1], mbs[1:]], loss_fn,
+                                         loss_fn_key="nll")
+        return [s["attributes"]
+                for s in tracing.stop().named(f"engine:{program}")]
+
+    assert all("flash_fwd_per_bwd" not in attrs for attrs in run())
+    assert read == []
+    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
+    with pltpu.force_tpu_interpret_mode():
+        spans = run()
+    assert [attrs["flash_fwd_per_bwd"] for attrs in spans] == [ratio] * 2
+    assert read == [program]

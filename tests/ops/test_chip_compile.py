@@ -16,6 +16,8 @@ persistent compile cache (a described-device executable is written to
 it but cannot be read back without a chip).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -132,6 +134,64 @@ def test_flash_compiles_at_lagunas_two_kinds_of_layer(one_chip, nq,
         assert kernel in text
 
 
+def _sft_microbatch(one_chip, config_name, family):
+    """``(step, params, mb)``: one microbatch's SFT forward and
+    backward of a benchmark configuration's WHOLE model at published
+    widths, a row of 4096, bf16, rematerialised as every experiment
+    runs it, with abstract arguments on the described chip."""
+    import json
+    import os
+
+    from benchmark import generate, run
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.models import hf as hf_models
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import moe as moe_ops
+
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
+        config = next(c for c in json.load(f)["configs"]
+                      if c["name"] == config_name)
+    hf, _ = generate.load_config(os.path.join(run.ROOT, config["file"]))
+    cfg = hf_models.config_from_hf(family, hf)
+    cfg.param_dtype = cfg.compute_dtype = "bfloat16"
+    cfg.gradient_checkpointing = True
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    mb = dict(input_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
+              seg_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
+              prompt_mask=sds((1, FLASH_MAX_LEN), jnp.bool_))
+    loss_fn = sft._make_loss_fn(cfg)
+    sparse = cfg.n_moe_layers > 0
+
+    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
+        return flash_attention(q, k, v, seg, causal=causal, scale=scale,
+                               sliding_window=sliding_window)
+
+    def objective(p, mb):
+        h, _, aux = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
+                              return_aux=True, attention_fn=attn)
+        aux = aux if sparse else {}
+        loss, stats = loss_fn(p, h, mb)
+        return loss + moe_ops.aux_loss(aux), {**stats, **aux}
+
+    def step(p, mb):
+        return jax.value_and_grad(objective, has_aux=True)(p, mb)
+
+    return step, params, mb
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_microbatch(one_chip, config_name, family):
+    """Compiled once a file: Laguna's takes half a minute here, and
+    two tests read it."""
+    return _compile(*_sft_microbatch(one_chip, config_name, family))
+
+
 @pytest.mark.parametrize("limit", [True, False],
                          ids=["as_it_is", "without_vmem_limit"])
 def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
@@ -147,57 +207,49 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
     ``vmem_limit_bytes``; ``without_vmem_limit`` takes it away and the
     same program must be refused for VMEM: the day that case compiles,
     ``_vmem_limit`` holds nothing up and can go."""
-    import json
-    import os
-
-    from benchmark import generate, run
-    from realhf_tpu.interfaces import sft
-    from realhf_tpu.models import hf as hf_models
-    from realhf_tpu.models import transformer as T
     from realhf_tpu.ops import flash_attention as fa
-    from realhf_tpu.ops import moe as moe_ops
 
-    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-        config = next(c for c in json.load(f)["configs"]
-                      if c["name"] == "laguna-xs.2-l5-ep16")
-    hf, _ = generate.load_config(os.path.join(run.ROOT, config["file"]))
-    cfg = hf_models.config_from_hf("laguna", hf)
-    cfg.param_dtype = cfg.compute_dtype = "bfloat16"
-    cfg.gradient_checkpointing = True
     if not limit:
         monkeypatch.setattr(fa, "_vmem_limit", lambda *a: None)
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: sds(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
-    mb = dict(input_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
-              seg_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
-              prompt_mask=sds((1, FLASH_MAX_LEN), jnp.bool_))
-    loss_fn = sft._make_loss_fn(cfg)
-
-    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
-        return flash_attention(q, k, v, seg, causal=causal, scale=scale,
-                               sliding_window=sliding_window)
-
-    def objective(p, mb):
-        h, _, aux = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
-                              return_aux=True, attention_fn=attn)
-        loss, stats = loss_fn(p, h, mb)
-        return loss + moe_ops.aux_loss(aux), {**stats, **aux}
-
-    def step(p, mb):
-        return jax.value_and_grad(objective, has_aux=True)(p, mb)
-
-    if not limit:
         with pytest.raises(Exception, match="(?i)vmem"):
-            _compile(step, params, mb)
+            _compile(*_sft_microbatch(one_chip, "laguna-xs.2-l5-ep16",
+                                      "laguna"))
         return
-    text = _compile(step, params, mb).as_text()
+    text = _compiled_microbatch(one_chip, "laguna-xs.2-l5-ep16",
+                                "laguna").as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert kernel in text
+
+
+@pytest.mark.parametrize("config,family,calls,gigabytes", [
+    ("laguna-xs.2-l5-ep16", "laguna", 5, 13.5),
+    ("qwen2.5-0.5b", "qwen2", 1, None),
+], ids=["laguna_unrolled_5", "qwen_scanned_24"])
+def test_rematerialised_stack_runs_the_forward_kernel_once_a_layer(
+        one_chip, config, family, calls, gigabytes):
+    """The blocks keep the flash kernel's output and log-sum-exp
+    (``models/transformer.py:_remat``), so the compiled backward holds
+    as many ``flash_fwd`` custom calls as ``flash_bwd_dq`` ones: five
+    and five in Laguna's unrolled stack, one and one in the loop
+    bodies of Qwen2.5-0.5B's scanned 24 layers. Before the residuals
+    were kept each held twice as many ``flash_fwd``. What Laguna's
+    program keeps for it (0.31 GB a microbatch) still leaves the
+    compiler's count of arguments and temporaries under the chip."""
+    from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
+    from realhf_tpu.ops.hlo_text import device_instructions
+
+    compiled = _compiled_microbatch(one_chip, config, family)
+    text = compiled.as_text()
+    # (under jax.grad an unrolled layer's forward is jvp_flash_fwd_.N)
+    names = [name for name, _, opcode in device_instructions(text)
+             if opcode == "custom-call"]
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(kernel in name for name in names) == calls, kernel
+    assert flash_fwd_per_bwd(text) == 1.0
+    if gigabytes is not None:
+        memory = compiled.memory_analysis()
+        assert (memory.argument_size_in_bytes
+                + memory.temp_size_in_bytes) < gigabytes * 1e9
 
 
 def test_flash_compiles_under_shard_map(topo):

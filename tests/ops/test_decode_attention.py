@@ -14,6 +14,7 @@ from realhf_tpu.ops.decode_attention import (
     decode_layer_copies,
     flash_decode_attention_stacked,
 )
+from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
 
 
 def make_inputs(rng, b=4, s=96, nq=8, nkv=2, hd=128, n_valid=None, nl=1):
@@ -184,3 +185,64 @@ def test_decode_layer_copies_counts_device_operations_of_a_layers_shape():
     assert decode_layer_copies(_IN_PLACE, (4, 2, 16, 64)) == 0
     # another device's share of the layer is another shape
     assert decode_layer_copies(_SLICING, (2, 2, 16, 64)) == 0
+
+
+# A scanned stack's two loop bodies as the chip's compiler prints them
+# (layouts and operands shortened): the forward's, and the backward's
+# with and without the recomputed block's own run of the forward
+# kernel. A tuple's elements and the fusions that read a kernel's
+# output name the kernel too; only the custom calls count.
+_FWD_BODY = """\
+%region_fwd.body (arg: (s32[], bf16[1,4,1024,64])) -> (s32[], bf16[1,4,1024,64]) {
+  %flash_fwd.6 = (bf16[1,4,1024,64]{3,2,1,0:T(8,128)(2,1)S(1)}, f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)}) custom-call(%lo, %hi, %q, %k, %v, %segq, %segk), custom_call_target="tpu_custom_call"
+  %pallas_call.42 = bf16[1,4,1024,64]{3,2,1,0:T(8,128)(2,1)S(1)} get-tuple-element(%flash_fwd.6), index=0
+  %pallas_call.43 = f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)} get-tuple-element(%flash_fwd.6), index=1
+  ROOT %tuple.1 = (s32[], bf16[1,4,1024,64]{3,2,1,0:T(8,128)(2,1)}) tuple(%i, %pallas_call.42)
+}
+"""
+_RECOMPUTED = """\
+  %flash_fwd.7 = (bf16[1,4,1024,64]{3,2,1,0:T(8,128)(2,1)S(1)}, f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)}) custom-call(%lo, %hi, %q, %k, %v, %segq, %segk), custom_call_target="tpu_custom_call"
+  %lse = f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)} get-tuple-element(%flash_fwd.7), index=1
+"""
+_KEPT = """\
+  %lse = f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)} fusion(%kept_lse), kind=kLoop, calls=%fused_computation.7
+"""
+_BWD_BODY = """\
+%fused_computation.7 (param_0.7: f32[1,4,1024]) -> f32[1,4,1024,128] {
+  %param_0.7 = f32[1,4,1024]{2,1,0:T(4,128)} parameter(0)
+  %flash_fwd.99 = f32[1,4,1024,128]{3,2,1,0:T(8,128)} custom-call(%param_0.7), custom_call_target="in_a_fusion_body"
+  ROOT %broadcast.7 = f32[1,4,1024,128]{3,2,1,0:T(8,128)} broadcast(%param_0.7), dimensions={0,1,2}
+}
+
+%region_bwd.body (arg: (s32[], f32[1,4,1024,64])) -> (s32[], f32[1,4,1024,64]) {
+<FORWARD>  %delta = f32[1,4,1024,128]{3,2,1,0:T(8,128)S(1)} fusion(%row_sums), kind=kLoop, calls=%fused_computation.7
+  %flash_bwd_dq.12 = f32[1,4,1024,64]{3,2,1,0:T(8,128)S(1)} custom-call(%lo, %hi, %q, %k, %v, %segq, %segk, %do, %lse, %delta), custom_call_target="tpu_custom_call"
+  %flash_bwd_dkv.12 = (f32[1,4,1024,64]{3,2,1,0:T(8,128)S(1)}, f32[1,4,1024,64]{3,2,1,0:T(8,128)S(1)}) custom-call(%lo2, %hi2, %q, %k, %v, %segq, %segk, %do, %lse, %delta), custom_call_target="tpu_custom_call"
+  %convert_bitcast_fusion.6 = f32[1,1024,4,64]{3,1,2,0:T(8,128)S(1)} fusion(%flash_bwd_dq.12), kind=kLoop, calls=%fused_computation.8
+  %custom-call.3 = f32[2,1,4,1024]{3,2,1,0:T(4,128)S(1)} custom-call(), custom_call_target="AllocateBuffer"
+  ROOT %tuple.2 = (s32[], f32[1,4,1024,64]{3,2,1,0:T(8,128)}) tuple(%i, %flash_bwd_dq.12)
+}
+"""
+
+
+@pytest.mark.parametrize("forward,want", [(_RECOMPUTED, 2.0), (_KEPT, 1.0)],
+                         ids=["recomputed", "kept"])
+def test_flash_fwd_per_bwd_counts_the_kernels_custom_calls(forward, want):
+    """The ``flash_fwd`` custom calls over the ``flash_bwd_dq`` ones: 2
+    where the backward's body runs the forward kernel again, 1 where
+    it reads the kept log-sum-exp. What a fusion body holds, a tuple's
+    elements, the consumers of a kernel's output and other custom
+    calls are not counted."""
+    text = "HloModule jit_train_step, is_scheduled=true\n\n" + _FWD_BODY \
+        + "\n" + _BWD_BODY.replace("<FORWARD>", forward)
+    assert flash_fwd_per_bwd(text) == want
+    # an unrolled stack of three layers holds the pair three times
+    assert flash_fwd_per_bwd(text * 3) == want
+
+
+def test_flash_fwd_per_bwd_of_a_program_without_a_backward_is_none():
+    """A logprobs program runs the forward kernel and no backward one;
+    a program without the kernels neither."""
+    assert flash_fwd_per_bwd(
+        "HloModule jit_logprobs, is_scheduled=true\n\n" + _FWD_BODY) is None
+    assert flash_fwd_per_bwd(_IN_PLACE) is None

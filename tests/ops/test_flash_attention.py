@@ -442,3 +442,34 @@ def test_only_a_row_of_4096_at_heads_of_128_asks_for_more_vmem(
     assert (dkv is not None) == asked
     if asked:
         assert fa.DEFAULT_SCOPED_VMEM < dkv < 2 * fa.DEFAULT_SCOPED_VMEM
+
+
+@pytest.mark.parametrize("nq,nkv,hd,window", [(4, 2, 32, None),
+                                              (2, 2, 64, None),
+                                              (4, 1, 128, 40)],
+                         ids=["gqa_hd32", "mha_hd64", "window_hd128"])
+def test_the_saved_log_sum_exp_broadcasts_back_to_the_kernels_own(
+        nq, nkv, hd, window):
+    """The forward rule keeps ONE float32 a (row, head) of the
+    kernel's lane-broadcast ``[B, nq, L, 128]`` log-sum-exp
+    (``RESIDUAL_NAMES``) and the backward broadcasts it again: bit for
+    bit the array the kernel wrote, padding rows' ``NEG_INF``
+    included, so the backward kernels read what they always read."""
+    rng = np.random.default_rng(7)
+    q, k, v, seg = make_inputs(rng, b=2, l=128, nq=nq, nkv=nkv, hd=hd)
+    with pltpu.force_tpu_interpret_mode():
+        out, lanes = fa._flash_fwd(q, k, v, seg, hd ** -0.5, True, 64, 64,
+                                   window)
+        kept_out, res = fa._flash_attention_fwd(
+            q, k, v, seg, hd ** -0.5, True, 64, 64, window)
+    assert lanes.shape == (2, nq, 128, fa.LANES)
+    kept = res[-1]
+    assert kept.shape == (2, nq, 128) and kept.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(jnp.broadcast_to(kept[..., None], lanes.shape)),
+        np.asarray(lanes))
+    assert (np.asarray(kept)[:, 0][np.asarray(seg) == 0] == fa.NEG_INF).all()
+    # the output is kept head-major, as the kernel wrote it
+    np.testing.assert_array_equal(np.asarray(res[-2]), np.asarray(out))
+    np.testing.assert_array_equal(
+        np.asarray(kept_out), np.asarray(out).transpose(0, 2, 1, 3))
