@@ -98,11 +98,16 @@ def device_memory_stats(device=None) -> Dict[str, int]:
 
 
 # ----------------------------------------------------------------------
-# Profiling / tracing (reference model_worker.py:664-721 per-MFC
-# profiler + REAL_DUMP_TRACE/REAL_DUMP_MEMORY, monitor.py:375-427)
+# Profiling (reference model_worker.py:664-721 per-MFC profiler +
+# REAL_DUMP_MEMORY, monitor.py:375-427). A profile of the device is
+# started in ONE way, ``obs.tracing.start(profile_dir)`` (the worker
+# command ``profiler`` calls it): it holds the spans and what the
+# compiled programs say of themselves; device time by what an
+# operation IS comes from ``python -m realhf_tpu.obs.parts``.
 # ----------------------------------------------------------------------
-DUMP_TRACE_ENV = "REALHF_TPU_DUMP_TRACE"
 DUMP_MEMORY_ENV = "REALHF_TPU_DUMP_MEMORY"
+#: read once, at import: the region sits on every MFC of the hot path
+_DUMP_MEMORY = os.environ.get(DUMP_MEMORY_ENV, "") == "1"
 
 
 def trace_dir(sub: str = "") -> str:
@@ -115,108 +120,16 @@ def trace_dir(sub: str = "") -> str:
 @contextlib.contextmanager
 def mfc_profile_region(name: str):
     """Wrap one MFC execution (inside its ``compute:<name>`` span,
-    which times and names it):
-
-    - REALHF_TPU_DUMP_TRACE=1: a full ``jax.profiler.trace`` dumped to
-      ``{log}/trace/{name}/`` (TensorBoard/perfetto-readable -- the
-      reference's per-MFC chrome traces);
-    - REALHF_TPU_DUMP_MEMORY=1: a device-memory profile (pprof) saved
-      after the MFC completes (the reference's CUDA memory snapshots).
-    """
-    import jax
-
-    dump_trace = os.environ.get(DUMP_TRACE_ENV, "") == "1"
-    dump_memory = os.environ.get(DUMP_MEMORY_ENV, "") == "1"
-    safe = name.replace("/", "_")
-    with (jax.profiler.trace(trace_dir(safe)) if dump_trace
-          else contextlib.nullcontext()):
-        yield
-    if dump_memory:
-        path = os.path.join(trace_dir(safe),
+    which times and names it). With REALHF_TPU_DUMP_MEMORY=1 in the
+    process's environment at import: a device-memory profile (pprof)
+    saved under ``{log}/trace/{name}/`` after the MFC completes (the
+    reference's CUDA memory snapshots)."""
+    yield
+    if _DUMP_MEMORY:
+        import jax
+        path = os.path.join(trace_dir(name.replace("/", "_")),
                             f"memory_{int(time.time())}.prof")
         try:
             jax.profiler.save_device_memory_profile(path)
         except Exception:  # noqa: BLE001 - profiling must never kill a run
             pass
-
-
-# ----------------------------------------------------------------------
-# Kernel-time classification from profiler traces (reference
-# kernelStatFromTrace + CUDAKernelTimeStat, base/monitor.py:517-699)
-# ----------------------------------------------------------------------
-#: substring -> category, first match wins (XLA kernel naming)
-KERNEL_CATEGORIES = (
-    ("all-reduce", "comm"), ("all-gather", "comm"),
-    ("reduce-scatter", "comm"), ("all-to-all", "comm"),
-    ("collective", "comm"), ("permute", "comm"), ("send", "comm"),
-    ("recv", "comm"),
-    ("copy", "mem"), ("transpose", "mem"), ("bitcast", "mem"),
-    ("reshape", "mem"), ("broadcast", "mem"), ("slice", "mem"),
-    ("concatenate", "mem"), ("pad", "mem"),
-    ("fusion", "compute"), ("dot", "compute"), ("conv", "compute"),
-    ("matmul", "compute"), ("custom-call", "compute"),
-    ("scatter", "compute"), ("gather", "compute"),
-    ("reduce", "compute"), ("rng", "compute"), ("cholesky", "compute"),
-    ("sort", "compute"), ("iota", "compute"),
-)
-
-
-def classify_kernel(name: str) -> str:
-    n = name.lower()
-    for sub, cat in KERNEL_CATEGORIES:
-        if sub in n:
-            return cat
-    return "misc"
-
-
-def kernel_stats_from_trace(trace_path: str) -> Dict[str, float]:
-    """Aggregate device-kernel time by category from a profiler dump.
-
-    ``trace_path`` is a chrome-trace ``*.trace.json(.gz)`` file or a
-    directory (the newest trace under it is used -- e.g. the dir that
-    ``mfc_profile_region`` wrote with REALHF_TPU_DUMP_TRACE=1).
-    Returns seconds per category (compute/comm/mem/misc) plus
-    ``total_busy`` and ``span`` (first-event to last-event extent of
-    the device tracks), the inputs of the reference's
-    compute/comm/idle breakdown.
-    """
-    import glob
-    import gzip
-    import json
-
-    if os.path.isdir(trace_path):
-        cands = sorted(glob.glob(
-            os.path.join(trace_path, "**", "*.trace.json.gz"),
-            recursive=True))
-        if not cands:
-            raise FileNotFoundError(
-                f"No *.trace.json.gz under {trace_path}")
-        trace_path = cands[-1]
-    opener = gzip.open if trace_path.endswith(".gz") else open
-    with opener(trace_path, "rt") as f:
-        trace = json.load(f)
-    events = trace.get("traceEvents", [])
-
-    # pid -> process name from metadata events; device tracks only
-    proc_names = {e.get("pid"): str(e.get("args", {}).get("name", ""))
-                  for e in events
-                  if e.get("ph") == "M" and e.get("name") == "process_name"}
-
-    def is_device(pid) -> bool:
-        n = proc_names.get(pid, "").lower()
-        return any(s in n for s in ("tpu", "gpu", "/device", "xla"))
-
-    out = {"compute": 0.0, "comm": 0.0, "mem": 0.0, "misc": 0.0}
-    t_lo, t_hi = None, None
-    for e in events:
-        if e.get("ph") != "X" or not is_device(e.get("pid")):
-            continue
-        dur = float(e.get("dur", 0.0)) * 1e-6  # us -> s
-        ts = float(e.get("ts", 0.0)) * 1e-6
-        out[classify_kernel(str(e.get("name", "")))] += dur
-        t_lo = ts if t_lo is None else min(t_lo, ts)
-        t_hi = ts + dur if t_hi is None else max(t_hi, ts + dur)
-    out["total_busy"] = sum(
-        out[k] for k in ("compute", "comm", "mem", "misc"))
-    out["span"] = (t_hi - t_lo) if t_lo is not None else 0.0
-    return out

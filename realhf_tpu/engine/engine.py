@@ -22,6 +22,7 @@ the algorithm interfaces do SequenceSample <-> stream packing.
 
 import collections
 import dataclasses
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -36,7 +37,7 @@ from realhf_tpu.engine.optim import OptimizerConfig, make_optimizer
 from realhf_tpu.models import sharding as shard_rules
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
-from realhf_tpu.obs import metrics, tracing
+from realhf_tpu.obs import metrics, parts, tracing
 from realhf_tpu.ops import decode_attention as decode_ops
 from realhf_tpu.ops import functional as F
 from realhf_tpu.ops import moe as moe_ops
@@ -62,6 +63,31 @@ def _abstract(x):
     argument: what ``lower`` needs, holding no buffer."""
     sharding = x.sharding if getattr(x, "committed", False) else None
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
+#: memory kinds of gauge ``engine_program_bytes`` <- the fields of
+#: the compiler's count (``obs/parts.py:MEMORY_FIELDS``)
+_MEMORY_KINDS = dict(arguments="argument_size_in_bytes",
+                     temporaries="temp_size_in_bytes",
+                     outputs="output_size_in_bytes",
+                     aliased="alias_size_in_bytes")
+#: spans an engine remembers whose program's text it has not read
+_UNREAD_SPANS = 1024
+#: the live engines: ``tracing.stop()`` asks each for its programs
+_ENGINES = weakref.WeakSet()
+
+
+def _programs_of_capture(spans, profiled: bool) -> Dict[str, Dict]:
+    """The ONE provider ``tracing.stop()`` calls, after the profile
+    has stopped: fingerprint -> ``ProgramFacts`` as plain dicts of
+    every program that ran under an ``engine:*`` span of ``spans``."""
+    out = {}
+    for engine in list(_ENGINES):
+        out.update(engine._capture_programs(spans, profiled))
+    return out
+
+
+tracing.set_program_provider(_programs_of_capture)
 
 
 class Engine:
@@ -292,15 +318,19 @@ class Engine:
         metrics.watch_compiles()
         self._train_step_cache: Dict[Any, Callable] = {}
         self._generate_cache: Dict[Any, Callable] = {}
-        # generate program (its cache key, prompt batch shape) -> what
-        # its compiled text says of the decode loop; see _decode_attrs
-        self._decode_attrs_cache: Dict[Any, Dict[str, Any]] = {}
-        # (train program, step key, batch shape) -> what its compiled
-        # text says of the flash forward kernel's runs a backward
-        self._flash_fwd_per_bwd: Dict[Any, Optional[float]] = {}
+        # (program name, what tells its compilations apart: loss key
+        # or sampling options, batch shape) -> what its compiled text
+        # says of itself, read once; see program_facts
+        self._facts: Dict[Any, parts.ProgramFacts] = {}
+        # span id -> (program name, key, call) of this engine's
+        # engine:* spans whose program's text had not been read when
+        # they ended: tracing.stop() of a profiled capture reads it
+        self._unread: Dict[str, tuple] = collections.OrderedDict()
         # program name -> (jitted fn, abstract args, static kwargs) of
         # its last call; see compiled_text
         self._last_call: Dict[str, tuple] = {}
+        self._last_key: Dict[str, Any] = {}
+        _ENGINES.add(self)
         # Generation view on pp/ctx meshes (decode_engine): a second
         # inference-only Engine on a collapsed dp x tp mesh over the
         # SAME devices; weights reshard into it when they change.
@@ -314,24 +344,38 @@ class Engine:
     # ------------------------------------------------------------------
     # Compiled-program introspection
     # ------------------------------------------------------------------
-    def _run(self, name: str, fn: Callable, attrs: Dict[str, Any],
+    def _run(self, name: str, key, fn: Callable, attrs: Dict[str, Any],
              *args, **static):
         """Call one of this engine's jitted programs, remembering its
         abstract signature for :meth:`compiled_text`. ``attrs``
-        (:meth:`_count_batch`) go on its ``engine:*`` span."""
+        (:meth:`_count_batch`) go on its ``engine:*`` span, beside
+        ``program`` (the XLA module's name, as a device trace prints
+        it) and, once the program's text has been read,
+        ``program_fingerprint``: span, device operation and
+        :meth:`program_facts` join on them. ``key``: what tells the
+        compilations under ``name`` apart (:meth:`_program_facts`)."""
         self._last_call[name] = (fn, jax.tree.map(_abstract, args),
                                  static)
+        self._last_key[name] = key
         if not tracing.enabled():
             self._last_span = tracing.NOOP_SPAN
             return fn(*args, **static)
         # engine:<name> holds the dispatch and, in a synced stretch,
         # the wait for the outputs
         with tracing.span(f"engine:{name}", **self._model_attrs,
-                          **attrs) as sp:
+                          program=f"jit_{fn.__name__}", **attrs) as sp:
             self._last_span = sp
             lowered = fn._cache_size()
             out = sp.result(fn(*args, **static))
             sp.set_attribute("compiled", fn._cache_size() > lowered)
+            facts = self._facts.get((name, key))
+            if facts is not None:
+                sp.set_attribute("program_fingerprint", facts.fingerprint)
+            else:
+                self._unread[sp.span_id] = (name, key,
+                                            self._last_call[name])
+                if len(self._unread) > _UNREAD_SPANS:
+                    self._unread.popitem(last=False)
             return out
 
     def _count_batch(self, seg_ids, decode_tokens: int = 0
@@ -428,59 +472,118 @@ class Engine:
             metrics.inc("moe_share_overflow_total", slow, role=role)
             self._last_span.set_attribute(moe_ops.SHARE_OVERFLOW_STAT, slow)
 
-    def _decode_attrs(self, program_key, b: int, lp: int,
-                      max_new_tokens: int) -> Dict[str, Any]:
-        """What the generate program that has just run does with the
-        KV cache inside its decode loop, read ONCE from its compiled
-        text and carried by every ``engine:generate`` span of it:
-        ``decode_kernel`` (``stacked``: the Pallas kernel reads the
-        stacked cache in place; ``xla``: the einsum path on a layer
-        sliced out) and ``decode_layer_copies``
-        (``ops.decode_attention.decode_layer_copies``: 0 where nothing
-        slices or relayouts a layer's cache)."""
-        key = (program_key, b, lp)
-        if key not in self._decode_attrs_cache:
-            text = self.compiled_text("generate")
-            cfg = self.cfg
-            shape = decode_ops.local_layer_shape(
-                self.mesh, b, cfg.n_q_heads, cfg.n_kv_heads,
-                T.round_cache_len(lp + max_new_tokens), cfg.head_dim)
-            self._decode_attrs_cache[key] = dict(
-                decode_kernel=("stacked" if decode_ops.KERNEL_NAME in text
-                               else "xla"),
-                decode_layer_copies=decode_ops.decode_layer_copies(
-                    text, shape))
-        return self._decode_attrs_cache[key]
-
-    def _report_flash_fwd_per_bwd(self, name: str, step_key,
-                                  attrs: Dict[str, Any], shape):
-        """What the train program that has just run under ``name``
-        does with the flash forward kernel in its backward, read ONCE
-        from its compiled text and set on every ``engine:train*`` span
-        of it: ``flash_fwd_per_bwd``
+    def _program_facts(self, name: str, key, call=None
+                       ) -> parts.ProgramFacts:
+        """What the program run under ``name`` says of itself
+        (``obs/parts.py:ProgramFacts``: its operations by part, pass
+        and opcode, the compiler's count of its memory), read ONCE a
+        ``(name, key)`` from its compiled text; ``key`` tells the
+        compilations under one name apart (loss or sampling options,
+        batch shape). ``call``: the call to read (default: the last
+        under ``name``). From the same read come the attributes of
+        the program's spans: ``flash_fwd_per_bwd`` of a train program
         (``ops.flash_attention.flash_fwd_per_bwd``: 1.0 where the
-        rematerialised blocks keep the kernel's residuals, 2.0 where
-        they run it again). Nothing where the batch's rows do not go
-        to the kernels (``attrs``: :meth:`_count_flash_blocks`)."""
-        if "flash_block_share" not in attrs:
-            return
-        key = (name, step_key, tuple(shape))
-        if key not in self._flash_fwd_per_bwd:
-            self._flash_fwd_per_bwd[key] = flash_fwd_per_bwd(
-                self.compiled_text(name))
-        if self._flash_fwd_per_bwd[key] is not None:
-            self._last_span.set_attribute("flash_fwd_per_bwd",
-                                          self._flash_fwd_per_bwd[key])
+        rematerialised blocks keep the flash kernel's residuals, 2.0
+        where they run it again; None off the kernels) and of a
+        generate program :meth:`_decode_facts`. Sets gauge
+        ``engine_program_bytes{role,program,kind}``."""
+        if (name, key) not in self._facts:
+            if name == "generate":
+                derive = self._decode_facts(key)
+            elif name.startswith("train"):
+                def derive(text):
+                    return dict(flash_fwd_per_bwd=flash_fwd_per_bwd(text))
+            else:
+                derive = None
+            facts = parts.read_program(self._compiled(name, call), derive)
+            self._facts[(name, key)] = facts
+            role = str(self.ctx.model_name.role)
+            for kind, field in _MEMORY_KINDS.items():
+                metrics.set_gauge("engine_program_bytes",
+                                  facts.memory[field], role=role,
+                                  program=facts.module, kind=kind)
+        return self._facts[(name, key)]
+
+    def _decode_facts(self, key):
+        """What a generate program's compiled text says of the KV cache
+        inside its decode loop: ``decode_kernel`` (``stacked``: the
+        Pallas kernel reads the stacked cache in place; ``xla``: the
+        einsum path on a layer sliced out) and ``decode_layer_copies``
+        (``ops.decode_attention.decode_layer_copies``: 0 where nothing
+        slices or relayouts a layer's cache). ``key``: (the program's
+        cache key, streams, prompt length)."""
+        (gconfig, _, _), b, lp = key
+        cfg = self.cfg
+        shape = decode_ops.local_layer_shape(
+            self.mesh, b, cfg.n_q_heads, cfg.n_kv_heads,
+            T.round_cache_len(lp + gconfig.max_new_tokens), cfg.head_dim)
+        return lambda text: dict(
+            decode_kernel=("stacked" if decode_ops.KERNEL_NAME in text
+                           else "xla"),
+            decode_layer_copies=decode_ops.decode_layer_copies(
+                text, shape))
+
+    def _read_facts_now(self, name: str, key):
+        """A train or generate program has just run under ``name``:
+        read its facts if this is its first call (the warm-up step,
+        inside set-up), and set on the ``engine:*`` span that has just
+        ended its fingerprint and what else came of the text
+        (``decode_kernel``, ``decode_layer_copies``,
+        ``flash_fwd_per_bwd``), as on every later span of it."""
+        facts = self._program_facts(name, key)
+        self._unread.pop(getattr(self._last_span, "span_id", None), None)
+        self._last_span.set_attribute("program_fingerprint",
+                                      facts.fingerprint)
+        for k, v in facts.attributes.items():
+            if v is not None:
+                self._last_span.set_attribute(k, v)
+
+    def program_facts(self, name: str) -> parts.ProgramFacts:
+        """The facts of the program LAST run under ``name`` ("train",
+        "train_seq", "hidden", "logprobs", "values", "generate"); its
+        text is read now where it has not been."""
+        return self._program_facts(name, self._last_key[name])
+
+    def _capture_programs(self, spans, profiled: bool
+                          ) -> Dict[str, Dict[str, Any]]:
+        """``tracing.stop()``'s question (``_programs_of_capture``):
+        fingerprint -> facts of every program of this engine that ran
+        under an ``engine:*`` span of ``spans``. A program whose text
+        has not been read (``logprobs``, ``values``, ``hidden``; a
+        train program off the flash kernels) is read now where the
+        capture had a profile, and its spans get their
+        ``program_fingerprint``; the capture's clock has stopped."""
+        mine = {f.fingerprint: f for f in self._facts.values()}
+        out = {}
+        for sp in spans:
+            unread = self._unread.pop(sp.span_id, None)
+            if unread is not None:
+                name, key, call = unread
+                if profiled or (name, key) in self._facts:
+                    facts = self._program_facts(name, key, call)
+                    mine[facts.fingerprint] = facts
+                    sp.set_attribute("program_fingerprint",
+                                     facts.fingerprint)
+            facts = mine.get(sp.attributes.get("program_fingerprint"))
+            if facts is not None and facts.fingerprint not in out \
+                    and sp.name.startswith("engine:"):
+                out[facts.fingerprint] = facts.as_dict()
+        return out
+
+    def _compiled(self, name: str, call=None):
+        """The ``jax.stages.Compiled`` of ``call`` (default: the
+        program last run under ``name``). With the compile cache on
+        this is a cache hit, not a second compile."""
+        fn, args, static = call or self._last_call[name]
+        return fn.lower(*args, **static).compile()
 
     def compiled_text(self, name: str) -> str:
         """Optimized HLO of the program last run under ``name``
         ("train", "train_seq", "hidden", "logprobs", "values",
         "generate"). Which kernels a run really used is read here --
         a ``tpu_custom_call`` in the text -- not from the backend gate,
-        which knows nothing of the shape gates. With the compile cache
-        on this is a cache hit, not a second compile."""
-        fn, args, static = self._last_call[name]
-        return fn.lower(*args, **static).compile().as_text()
+        which knows nothing of the shape gates."""
+        return self._compiled(name).as_text()
 
     # ------------------------------------------------------------------
     # Multi-process (worker-group) helpers
@@ -583,8 +686,11 @@ class Engine:
         def objective(params, mb):
             h, aux = self._forward(params, mb["input_ids"],
                                    mb["seg_ids"], train=True)
-            loss, stats = loss_fn(params, h, mb)
-            return loss + moe_ops.aux_loss(aux), {**stats, **aux}
+            # what the interface does after the picked
+            # log-probabilities; its head enters a scope of its own
+            with jax.named_scope(parts.LOSS):
+                loss, stats = loss_fn(params, h, mb)
+                return loss + moe_ops.aux_loss(aux), {**stats, **aux}
 
         return objective
 
@@ -601,28 +707,34 @@ class Engine:
             mb_weights: [n_mbs] relative weight (e.g. token counts) used
             to average gradients exactly as one large batch would."""
             grad_fn = jax.value_and_grad(objective, has_aux=True)
-            zero = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            if self._grad_shardings is not None:
-                zero = jax.tree.map(jax.lax.with_sharding_constraint,
-                                    zero, self._grad_shardings)
+            with jax.named_scope(parts.GRAD_ACCUM):
+                zero = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                if self._grad_shardings is not None:
+                    zero = jax.tree.map(jax.lax.with_sharding_constraint,
+                                        zero, self._grad_shardings)
 
             def accum(carry, x):
                 gsum = carry
                 mb, w = x
-                with jax.named_scope("forward_backward"):
+                with jax.named_scope(parts.FORWARD_BACKWARD):
                     (loss, stats), grads = grad_fn(params, mb)
-                gsum = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32) * w, gsum, grads)
-                if self._grad_shardings is not None:
-                    gsum = jax.tree.map(jax.lax.with_sharding_constraint,
-                                        gsum, self._grad_shardings)
+                with jax.named_scope(parts.GRAD_ACCUM):
+                    gsum = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32) * w,
+                        gsum, grads)
+                    if self._grad_shardings is not None:
+                        gsum = jax.tree.map(
+                            jax.lax.with_sharding_constraint,
+                            gsum, self._grad_shardings)
                 return gsum, (loss, stats)
 
-            wsum = mb_weights.sum()
+            with jax.named_scope(parts.GRAD_ACCUM):
+                wsum = mb_weights.sum()
+                weights = mb_weights / wsum
             gsum, (losses, stats) = jax.lax.scan(
-                accum, zero, (mbs, mb_weights / wsum))
-            with jax.named_scope("optimizer"):
+                accum, zero, (mbs, weights))
+            with jax.named_scope(parts.OPTIMIZER):
                 updates, new_opt = self._tx.update(gsum, opt_state,
                                                    params)
                 if self._opt_shardings is not None:
@@ -633,31 +745,32 @@ class Engine:
                         jax.lax.with_sharding_constraint(s, sh),
                         new_opt, self._opt_shardings)
                 new_params = optax.apply_updates(params, updates)
-            gnorm = optax.global_norm(gsum)
-            mean_stats = jax.tree.map(
-                lambda s: (s * mb_weights / wsum).sum(), stats)
-            for name, reduce in moe_ops.STATS.items():
-                if name in stats:  # the worst microbatch's, or all's
-                    mean_stats[name] = reduce(stats[name])
-            # Reserved stat "__skip_update__": when any microbatch sets
-            # it > 0, the whole optimizer step is discarded -- params,
-            # optimizer moments, and step count stay untouched (PPO
-            # early stopping must SKIP the update, not step with a
-            # zeroed loss: AdamW weight decay and MoE aux grads would
-            # otherwise still apply).
-            skip = mean_stats.pop("__skip_update__", None)
-            if skip is not None:
-                keep_old = skip > 0
-                new_params = jax.tree.map(
-                    lambda n, o: jnp.where(keep_old, o, n),
-                    new_params, params)
-                new_opt = jax.tree.map(
-                    lambda n, o: jnp.where(keep_old, o, n),
-                    new_opt, opt_state)
-                mean_stats["early_stop_skipped"] = keep_old.astype(
-                    jnp.float32)
-            mean_loss = (losses * mb_weights / wsum).sum()
-            return new_params, new_opt, mean_loss, mean_stats, gnorm
+                # grad_norm and the statistics go with it
+                gnorm = optax.global_norm(gsum)
+                mean_stats = jax.tree.map(
+                    lambda s: (s * mb_weights / wsum).sum(), stats)
+                for name, reduce in moe_ops.STATS.items():
+                    if name in stats:  # the worst microbatch's, or all's
+                        mean_stats[name] = reduce(stats[name])
+                # Reserved stat "__skip_update__": when any microbatch sets
+                # it > 0, the whole optimizer step is discarded -- params,
+                # optimizer moments, and step count stay untouched (PPO
+                # early stopping must SKIP the update, not step with a
+                # zeroed loss: AdamW weight decay and MoE aux grads would
+                # otherwise still apply).
+                skip = mean_stats.pop("__skip_update__", None)
+                if skip is not None:
+                    keep_old = skip > 0
+                    new_params = jax.tree.map(
+                        lambda n, o: jnp.where(keep_old, o, n),
+                        new_params, params)
+                    new_opt = jax.tree.map(
+                        lambda n, o: jnp.where(keep_old, o, n),
+                        new_opt, opt_state)
+                    mean_stats["early_stop_skipped"] = keep_old.astype(
+                        jnp.float32)
+                mean_loss = (losses * mb_weights / wsum).sum()
+                return new_params, new_opt, mean_loss, mean_stats, gnorm
 
         return train_step
 
@@ -746,11 +859,12 @@ class Engine:
             (host_batch, np.asarray(loss_weights, np.float32)))
 
         attrs = self._count_batch(host_batch["seg_ids"])
+        key = (key, host_batch["seg_ids"].shape)
         self.params, self.opt_state, loss, stats, gnorm = self._run(
-            "train", step, attrs, self.params, self.opt_state, stacked,
-            weights)
-        self._report_flash_fwd_per_bwd("train", key, attrs,
-                                       host_batch["seg_ids"].shape)
+            "train", key, step, attrs, self.params, self.opt_state,
+            stacked, weights)
+        if "flash_block_share" in attrs:  # the rows go to the kernels
+            self._read_facts_now("train", key)
         self.version += 1
         if self._decode_view is not None:
             # the view's gen-layout weight copy is now stale (params
@@ -812,11 +926,12 @@ class Engine:
             (host_batch, np.asarray(loss_weights, np.float32)))
 
         attrs = self._count_batch(host_batch["seg_ids"])
+        key = (key, host_batch["seg_ids"].shape)
         self.params, self.opt_state, losses, stats, gnorms = self._run(
-            "train_seq", step, attrs, self.params, self.opt_state,
+            "train_seq", key, step, attrs, self.params, self.opt_state,
             stacked, weights)
-        self._report_flash_fwd_per_bwd("train_seq", key, attrs,
-                                       host_batch["seg_ids"].shape)
+        if "flash_block_share" in attrs:
+            self._read_facts_now("train_seq", key)
         self.version += len(minibatches)
         if self._decode_view is not None:
             self._decode_view.params = None
@@ -848,8 +963,8 @@ class Engine:
                 hidden, out_shardings=self._out_replicated())
         attrs = self._count_batch(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
-        return self._run("hidden", self._jit_forward_hidden, attrs,
-                         self.params, ids, seg)
+        return self._run("hidden", ids.shape, self._jit_forward_hidden,
+                         attrs, self.params, ids, seg)
 
     def forward_logprobs(self, input_ids, seg_ids, temperature: float = 1.0,
                          logits_mask=None):
@@ -869,8 +984,10 @@ class Engine:
             (input_ids, seg_ids,
              logits_mask if logits_mask is not None
              else np.zeros((1,), bool)))
-        return self._run("logprobs", self._jit_logprobs, attrs,
-                         self.params, ids, seg, mask, temp=temperature,
+        return self._run("logprobs",
+                         (ids.shape, temperature, logits_mask is not None),
+                         self._jit_logprobs, attrs, self.params, ids, seg,
+                         mask, temp=temperature,
                          has_mask=logits_mask is not None)
 
     def forward_values(self, input_ids, seg_ids):
@@ -884,8 +1001,8 @@ class Engine:
                 values, out_shardings=self._out_replicated())
         attrs = self._count_batch(seg_ids)
         ids, seg = self._globalize_tree((input_ids, seg_ids))
-        return self._run("values", self._jit_values, attrs, self.params,
-                         ids, seg)
+        return self._run("values", ids.shape, self._jit_values, attrs,
+                         self.params, ids, seg)
 
     # ------------------------------------------------------------------
     # Generation
@@ -1014,11 +1131,10 @@ class Engine:
             decode_tokens=prompt_seg.shape[0] * gconfig.max_new_tokens)
         ids, seg, pos, key = self._globalize_tree(
             (prompt_ids, prompt_seg, prompt_pos, key))
-        out = self._run("generate", fn, attrs, self.params, ids, seg,
-                        pos, key)
-        for k, v in self._decode_attrs(cache_key, *prompt_seg.shape,
-                                       gconfig.max_new_tokens).items():
-            self._last_span.set_attribute(k, v)
+        program = (cache_key, *prompt_seg.shape)
+        out = self._run("generate", program, fn, attrs, self.params, ids,
+                        seg, pos, key)
+        self._read_facts_now("generate", program)
         if self.cfg.layer_pattern is not None:
             # the two kinds of state the decode loop carried
             self._last_span.set_attribute(

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.obs import parts
 from realhf_tpu.ops.sampling import (
     NEG_INF,
     GenerationHyperparameters,
@@ -68,15 +69,16 @@ def generate(
     """Functional generation; wrap in jax.jit with gconfig/eos/pad
     static. See `build_generate_fn` for the cached jitted wrapper."""
     b, lp = prompt_ids.shape
-    prompt_lens = (prompt_seg != 0).sum(-1).astype(jnp.int32)
 
-    with jax.named_scope("prefill"):
+    with jax.named_scope(parts.PREFILL):
+        prompt_lens = (prompt_seg != 0).sum(-1).astype(jnp.int32)
         hidden, cache = T.prefill(
             cfg, params, prompt_ids, prompt_seg, prompt_pos,
             total_len=lp + gconfig.max_new_tokens,
             activation_constraint=activation_constraint,
             attention_fn=attention_fn, moe_constraint=moe_constraint)
-    last_hidden = hidden[:, -1]  # left padding => last column is last token
+        # left padding => last column is last token
+        last_hidden = hidden[:, -1]
 
     def sample_step(logits, step_idx, unfinished, k):
         logits = logits.astype(jnp.float32)
@@ -106,21 +108,22 @@ def generate(
         return tokens, logprob, mask, unfinished
 
     t_max = gconfig.max_new_tokens
-    keys = jax.random.split(key, t_max)
+    with jax.named_scope(parts.SAMPLE):
+        keys = jax.random.split(key, t_max)
 
     def step_once(last_hidden, cache, unfinished, emitted, step_idx, k):
         """One decode step, shared by the scan and while-loop drivers."""
         was_unfinished = unfinished
-        with jax.named_scope("decode"):  # the vocabulary head
+        with jax.named_scope(parts.DECODE):  # the vocabulary head
             logits = T.lm_logits(cfg, params, last_hidden)
-        with jax.named_scope("sample"):
+        with jax.named_scope(parts.SAMPLE):
             tokens, logprob, mask, unfinished = sample_step(
                 logits, step_idx, unfinished, k)
-        emitted = emitted + was_unfinished.astype(jnp.int32)
-        pos = prompt_lens + step_idx
+            emitted = emitted + was_unfinished.astype(jnp.int32)
+            pos = prompt_lens + step_idx
         # all streams share the padded prompt length, so cache writes
         # land in one uniform slot (dynamic_update_slice fast path)
-        with jax.named_scope("decode"):
+        with jax.named_scope(parts.DECODE):
             new_hidden, cache = T.decode_step(
                 cfg, params, cache, tokens, pos, moe_constraint,
                 uniform_slot=True, mesh=mesh)
@@ -136,10 +139,11 @@ def generate(
         # preallocated output buffers. The reference terminates its
         # genstep loop the same way (real_llm_generate.py genstep
         # terminate check); lax.scan cannot early-exit.
-        tokens_buf = jnp.full((b, t_max), pad_token_id, jnp.int32)
-        logp_buf = jnp.zeros((b, t_max), jnp.float32)
-        mask_buf = (jnp.zeros((b, t_max, cfg.vocab_size), bool)
-                    if want_mask else jnp.zeros((1,), bool))
+        with jax.named_scope(parts.SAMPLE):  # what sampling writes into
+            tokens_buf = jnp.full((b, t_max), pad_token_id, jnp.int32)
+            logp_buf = jnp.zeros((b, t_max), jnp.float32)
+            mask_buf = (jnp.zeros((b, t_max, cfg.vocab_size), bool)
+                        if want_mask else jnp.zeros((1,), bool))
 
         def w_cond(c):
             step = c[0]
@@ -152,12 +156,14 @@ def generate(
             last_hidden, cache, unfinished, emitted, tok, lp, mask = \
                 step_once(last_hidden, cache, unfinished, emitted,
                           step, keys[step])
-            tb = jax.lax.dynamic_update_slice(tb, tok[:, None],
-                                              (0, step))
-            lb = jax.lax.dynamic_update_slice(lb, lp[:, None], (0, step))
-            if want_mask:
-                mb = jax.lax.dynamic_update_slice(
-                    mb, mask[:, None, :], (0, step, 0))
+            with jax.named_scope(parts.SAMPLE):
+                tb = jax.lax.dynamic_update_slice(tb, tok[:, None],
+                                                  (0, step))
+                lb = jax.lax.dynamic_update_slice(lb, lp[:, None],
+                                                  (0, step))
+                if want_mask:
+                    mb = jax.lax.dynamic_update_slice(
+                        mb, mask[:, None, :], (0, step, 0))
             return (step + 1, last_hidden, cache, unfinished, emitted,
                     (tb, lb, mb))
 

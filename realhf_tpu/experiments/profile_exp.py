@@ -3,9 +3,13 @@
 Parity with reference ``realhf/experiments/benchmark/profile_exp.py``
 (+ ``ModelInterface.mock``): run the 6-MFC PPO dataflow graph with
 random-init models and random prompts through the real runtime (inline
-or distributed), with per-MFC timing from the tracer's spans and optional
-``jax.profiler`` trace dumps (REALHF_TPU_DUMP_TRACE=1 /
-REALHF_TPU_DUMP_MEMORY=1, base/monitor.py). Serves as both a system
+or distributed), with per-MFC timing from the tracer's spans and,
+given a directory, a ``jax.profiler`` trace of the same steps
+(``mfc_timing_summary(run, profile_dir)``: ``obs.tracing.start``, the
+one way to start a profile; ``python -m realhf_tpu.obs.parts
+<profile_dir>`` then prints device time by part of the model).
+REALHF_TPU_DUMP_MEMORY=1 saves a device-memory profile after each MFC
+(base/monitor.py). Serves as both a system
 test (everything wired, nothing real needed) and the measurement rig
 for allocation decisions.
 
@@ -15,7 +19,7 @@ for allocation decisions.
 """
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from realhf_tpu.api.config import DatasetAbstraction
 from realhf_tpu.api.experiment import ExperimentSpec, ModelSpec
@@ -85,13 +89,16 @@ class ProfileConfig(PPOConfig):
 register_experiment("profile", ProfileConfig)
 
 
-def mfc_timing_summary(run: Callable[[], Any]
+def mfc_timing_summary(run: Callable[[], Any],
+                       profile_dir: Optional[str] = None
                        ) -> Tuple[Any, Dict[str, float]]:
     """``run()`` (a runner's ``run``) under a synced capture of its
     own: what it returned, and per-MFC wall-clock totals in seconds
-    (MFC name -> the sum of its ``mfc:<name>`` spans)."""
+    (MFC name -> the sum of its ``mfc:<name>`` spans). With
+    ``profile_dir`` the capture also records a ``jax.profiler`` trace
+    there, ``programs.json`` beside it."""
     from realhf_tpu.obs import tracing
-    tracing.start(sync=True)
+    tracing.start(profile_dir, sync=True)
     try:
         out = run()
     finally:

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from realhf_tpu.base.backend import pallas_enabled
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
 from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
@@ -260,13 +261,23 @@ def _mlp_with_aux(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
         sparse = cfg.mlp_type == "moe"
     if sparse:
         from realhf_tpu.ops.moe import moe_mlp_with_losses
-        squeeze = x.ndim == 2  # decode step: [B, H]
-        x3 = x[:, None, :] if squeeze else x
-        valid = None if seg_ids is None else (seg_ids != 0)
-        out, aux = moe_mlp_with_losses(cfg, m, x3, valid_mask=valid,
-                                       ep_constraint=moe_constraint)
-        return (out[:, 0] if squeeze else out), aux
-    return _dense_mlp(cfg, m, x, cdt), {}
+        with jax.named_scope(P.EXPERTS):
+            squeeze = x.ndim == 2  # decode step: [B, H]
+            x3 = x[:, None, :] if squeeze else x
+            valid = None if seg_ids is None else (seg_ids != 0)
+            out, aux = moe_mlp_with_losses(cfg, m, x3, valid_mask=valid,
+                                           ep_constraint=moe_constraint)
+            return (out[:, 0] if squeeze else out), aux
+    with jax.named_scope(P.MLP):
+        return _dense_mlp(cfg, m, x, cdt), {}
+
+
+def _ff_part(cfg: TransformerConfig, sparse: Optional[bool]) -> str:
+    """The part (``obs/parts.py``) a layer's feed-forward, the norm
+    before it and the residual's add after it are put down to."""
+    if sparse is None:
+        sparse = cfg.mlp_type == "moe"
+    return P.EXPERTS if sparse else P.MLP
 
 
 def _dense_mlp(cfg, m, x, cdt):
@@ -382,19 +393,22 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
     """Attention over packed streams on the normed residual ``ln1``
     [B, L, H] -> (its projected output [B, L, H], (k, v)). ``window``:
     the tokens THIS layer sees (``cfg.layer_window``), None for all."""
-    q, k, v = _qkv(cfg, lp, ln1)
-    if cfg.apply_rotary:
-        q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-        k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+    with jax.named_scope(P.ATTN_PROJ):
+        q, k, v = _qkv(cfg, lp, ln1)
+        if cfg.apply_rotary:
+            q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
+            k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
     attn_impl = attention_fn or packed_attention
-    attn = attn_impl(q, k, v, seg_ids, causal=True,
-                     scale=_attn_scale(cfg, layer_idx),
-                     sliding_window=window)
-    attn = _head_gate(lp, ln1, attn)
-    attn = attn.reshape(*ln1.shape[:-1], -1)
-    proj = attn @ lp["attn"]["wo"].astype(ln1.dtype)
-    if "bo" in lp["attn"]:
-        proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
+    with jax.named_scope(P.ATTN):
+        attn = attn_impl(q, k, v, seg_ids, causal=True,
+                         scale=_attn_scale(cfg, layer_idx),
+                         sliding_window=window)
+    with jax.named_scope(P.ATTN_PROJ):
+        attn = _head_gate(lp, ln1, attn)
+        attn = attn.reshape(*ln1.shape[:-1], -1)
+        proj = attn @ lp["attn"]["wo"].astype(ln1.dtype)
+        if "bo" in lp["attn"]:
+            proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
     return proj, (k, v)
 
 
@@ -411,17 +425,27 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     layer; aux is non-empty for MoE."""
     op, sparse = ("attention", None) if kind is None \
         else (kind[0], kind[1] == "moe")
-    ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+    # the norm before an operator and the residual's add after it go
+    # with the operator's projections, those around the feed-forward
+    # with the feed-forward (obs/parts.py)
+    mixer = P.CONV if op == "conv" else P.ATTN_PROJ
+    with jax.named_scope(mixer):
+        ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
     if op == "conv":
-        proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
+        with jax.named_scope(P.CONV):
+            proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
                                     cos, sin, attention_fn, window)
-    x = constrain(x + proj)
-    ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
+    with jax.named_scope(mixer):
+        x = constrain(x + proj)
+    ff = _ff_part(cfg, sparse)
+    with jax.named_scope(ff):
+        ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
     mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, seg_ids, moe_constraint,
                                  sparse)
-    x = constrain(x + mlp_out)
+    with jax.named_scope(ff):
+        x = constrain(x + mlp_out)
     return x, state, aux
 
 
@@ -521,18 +545,19 @@ def forward(
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     constrain = activation_constraint or (lambda t: t)
-    if positions is None:
-        positions = positions_from_segments(seg_ids)
+    with jax.named_scope(P.EMBED):
+        if positions is None:
+            positions = positions_from_segments(seg_ids)
+        x = params["embed"]["wte"].astype(cdt)[input_ids]
+        if cfg.uses_absolute_position:
+            x = x + params["embed"]["wpe"].astype(cdt)[
+                positions + cfg.abs_position_embedding_offset]
+        if cfg.normalize_embed:
+            x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
+        x = constrain(x)
 
-    x = params["embed"]["wte"].astype(cdt)[input_ids]
-    if cfg.uses_absolute_position:
-        x = x + params["embed"]["wpe"].astype(cdt)[
-            positions + cfg.abs_position_embedding_offset]
-    if cfg.normalize_embed:
-        x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
-    x = constrain(x)
-
-    rotary = _rotary_tables(cfg, positions)
+    with jax.named_scope(P.ATTN_PROJ):
+        rotary = _rotary_tables(cfg, positions)
 
     if pipeline is not None and pipeline.n_stages > 1:
         cfg.require_one_block(
@@ -577,7 +602,8 @@ def forward(
                 lp, li = layer
                 y, aux = pblock(lp, li, carry, segc, cosc, sinc)
                 return y, aux
-            y, auxs = jax.lax.scan(body, xc, (slab, layer_ids))
+            with jax.named_scope(P.LAYERS):
+                y, auxs = jax.lax.scan(body, xc, (slab, layer_ids))
             return y, {k: v.sum() for k, v in auxs.items()}
 
         if getattr(pipeline, "schedule", "gpipe") == "1f1b":
@@ -595,17 +621,17 @@ def forward(
                 pipeline, params["blocks"], cfg.n_layers, x, seg_ids,
                 cos, sin, block_step, return_aux=return_aux,
                 remat_tick=remat_tick)
-        x = _norm(cfg, x, params["ln_f"]["scale"],
-                  params["ln_f"].get("bias"))
+        x = _final_norm(cfg, params, x)
         if return_aux:
             return x, None, aux
         return x, None
 
     if cfg.layer_pattern is not None:
-        x, states, aux = _pattern_layers(
-            cfg, params["layers"], x, seg_ids, rotary, constrain,
-            attention_fn, moe_constraint, return_kv, return_aux)
-        x = _norm(cfg, x, params["ln_f"]["scale"], None)
+        with jax.named_scope(P.LAYERS):
+            x, states, aux = _pattern_layers(
+                cfg, params["layers"], x, seg_ids, rotary, constrain,
+                attention_fn, moe_constraint, return_kv, return_aux)
+        x = _final_norm(cfg, params, x)
         return (x, states, aux) if return_aux else (x, states)
 
     cos, sin = rotary["attention"]  # a model of one block has one table
@@ -626,14 +652,26 @@ def forward(
         return y, (kv if return_kv else None,
                    aux if return_aux else None)
 
-    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    x, (kvs, auxs) = jax.lax.scan(scan_body, x,
-                                  (params["blocks"], layer_ids))
-    x = _norm(cfg, x, params["ln_f"]["scale"], params["ln_f"].get("bias"))
+    # the scan's own operations (a layer's weights out of the stack,
+    # what the backward keeps a layer) lower under the scope it is
+    # called in; the blocks' parts nest inside
+    with jax.named_scope(P.LAYERS):
+        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        x, (kvs, auxs) = jax.lax.scan(scan_body, x,
+                                      (params["blocks"], layer_ids))
+    x = _final_norm(cfg, params, x)
     if return_aux:
         from realhf_tpu.ops.moe import reduce_layers
         return x, kvs, reduce_layers(auxs or {})
     return x, kvs
+
+
+def _final_norm(cfg: TransformerConfig, params: Params,
+                x: jnp.ndarray) -> jnp.ndarray:
+    """The norm after the last block: it goes with the head."""
+    with jax.named_scope(P.VOCAB_HEAD):
+        return _norm(cfg, x, params["ln_f"]["scale"],
+                     params["ln_f"].get("bias"))
 
 
 def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
@@ -680,12 +718,14 @@ def lm_logits(cfg: TransformerConfig, params: Params,
               hidden: jnp.ndarray) -> jnp.ndarray:
     """[..., H] -> [..., V] logits in fp32 (tp-padded vocab entries,
     if any, are sliced away so they are never sampled)."""
-    w = head_weight(cfg, params)
-    logits = jnp.einsum("...h,hv->...v", hidden, w.astype(hidden.dtype),
-                        preferred_element_type=jnp.float32)
-    if logits.shape[-1] != cfg.vocab_size:
-        logits = logits[..., :cfg.vocab_size]
-    return logits
+    with jax.named_scope(P.VOCAB_HEAD):
+        w = head_weight(cfg, params)
+        logits = jnp.einsum("...h,hv->...v", hidden,
+                            w.astype(hidden.dtype),
+                            preferred_element_type=jnp.float32)
+        if logits.shape[-1] != cfg.vocab_size:
+            logits = logits[..., :cfg.vocab_size]
+        return logits
 
 
 def head_weight(cfg: TransformerConfig, params: Params) -> jnp.ndarray:
@@ -700,9 +740,10 @@ def critic_values(cfg: TransformerConfig, params: Params,
                   hidden: jnp.ndarray) -> jnp.ndarray:
     """[..., H] -> [...] scalar values in fp32."""
     assert cfg.is_critic
-    w = params["head"]["w"]
-    return jnp.einsum("...h,ho->...o", hidden, w.astype(hidden.dtype),
-                      preferred_element_type=jnp.float32)[..., 0]
+    with jax.named_scope(P.VOCAB_HEAD):  # the critic's head is its head
+        w = params["head"]["w"]
+        return jnp.einsum("...h,ho->...o", hidden, w.astype(hidden.dtype),
+                          preferred_element_type=jnp.float32)[..., 0]
 
 
 # ----------------------------------------------------------------------
@@ -771,6 +812,14 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
                           attention_fn=attention_fn,
                           moe_constraint=moe_constraint)
     b, lp = input_ids.shape
+    with jax.named_scope(P.ATTN):  # the caches' layout is the kernels'
+        cache = _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len,
+                               hidden.dtype)
+    return hidden, cache
+
+
+def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
+    """``prefill``'s cache from the states ``forward`` returned."""
     conv = None
     if cfg.layer_pattern is None:
         k, v = kvs  # [nl, B, L, nkv, hd]
@@ -780,7 +829,7 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
         k, v, conv = kvs["k"], kvs["v"], kvs["conv"]
         if k is None:
             k = v = jnp.zeros((0, b, lp, cfg.n_kv_heads, cfg.head_dim),
-                              hidden.dtype)
+                              dtype)
         if conv is not None:
             t = min(cfg.conv_kernel - 1, lp)
             conv = jnp.where((seg_ids[:, lp - t:] != 0)[None, :, :, None],
@@ -805,7 +854,7 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
     }
     if conv is not None:
         cache["conv"] = conv
-    return hidden, cache
+    return cache
 
 
 def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
@@ -895,22 +944,25 @@ def decode_step(
     b = token.shape[0]
     slot = cache["length"]  # write position per stream
 
-    x = params["embed"]["wte"].astype(cdt)[token]
-    if cfg.uses_absolute_position:
-        x = x + params["embed"]["wpe"].astype(cdt)[
-            positions + cfg.abs_position_embedding_offset]
-    if cfg.normalize_embed:
-        x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
+    with jax.named_scope(P.EMBED):
+        x = params["embed"]["wte"].astype(cdt)[token]
+        if cfg.uses_absolute_position:
+            x = x + params["embed"]["wpe"].astype(cdt)[
+                positions + cfg.abs_position_embedding_offset]
+        if cfg.normalize_embed:
+            x = x * jnp.asarray(cfg.hidden_dim ** 0.5, dtype=cdt)
 
-    rotary = _rotary_tables(cfg, positions)
+    with jax.named_scope(P.ATTN_PROJ):
+        rotary = _rotary_tables(cfg, positions)
 
-    if uniform_slot:
-        s0 = slot[0]
-        valid = jax.lax.dynamic_update_slice(
-            cache["valid"], jnp.ones((b, 1), bool), (0, s0))
-    else:
-        valid = cache["valid"].at[jnp.arange(b), slot].set(True)
-    new_len = slot + 1
+    with jax.named_scope(P.ATTN):  # the cache's bookkeeping
+        if uniform_slot:
+            s0 = slot[0]
+            valid = jax.lax.dynamic_update_slice(
+                cache["valid"], jnp.ones((b, 1), bool), (0, s0))
+        else:
+            valid = cache["valid"].at[jnp.arange(b), slot].set(True)
+        new_len = slot + 1
 
     def layer_body(x, k_all, v_all, lp, l, sparse=None, op="attention",
                    window=cfg.sliding_window):
@@ -918,39 +970,50 @@ def decode_step(
         # (unrolled) or a traced scalar; op, window: its kind's rotary
         # table and what it sees (a patterned model says them a layer)
         cos, sin = rotary[op]
-        ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
-        q, k, v = _qkv(cfg, lp, ln1)  # q: [B, nq, hd]; k/v: [B, nkv, hd]
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-            k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
-        if uniform_slot:
-            kw = k[None, :, :, None, :].astype(k_all.dtype)  # [1,B,nkv,1,hd]
-            vw = v[None, :, :, None, :].astype(v_all.dtype)
-            k_all = jax.lax.dynamic_update_slice(k_all, kw, (l, 0, 0, s0, 0))
-            v_all = jax.lax.dynamic_update_slice(v_all, vw, (l, 0, 0, s0, 0))
-        else:
-            k_all = k_all.at[l, jnp.arange(b), :, slot].set(
-                k.astype(k_all.dtype))
-            v_all = v_all.at[l, jnp.arange(b), :, slot].set(
-                v.astype(v_all.dtype))
-        base = cfg.head_dim ** -0.5 if cfg.scale_attn_weights else 1.0
-        if not cfg.scale_attn_by_inverse_layer_idx:
-            scale = base
-        elif isinstance(l, int):
-            scale = base / (l + 1)
-        else:
-            scale = _attn_scale(cfg, l)  # traced scalar
-        attn = _stacked_decode_attention(
-            q, k_all, v_all, valid, l, scale=scale,
-            sliding_window=window, slot=slot, mesh=mesh)
-        attn = _head_gate(lp, ln1, attn)
-        proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
-        if "bo" in lp["attn"]:
-            proj = proj + lp["attn"]["bo"].astype(x.dtype)
-        x = x + proj
-        ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
-        x = x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
-        return x, k_all, v_all
+        with jax.named_scope(P.ATTN_PROJ):
+            ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+            # q: [B, nq, hd]; k/v: [B, nkv, hd]
+            q, k, v = _qkv(cfg, lp, ln1)
+            if cfg.apply_rotary:
+                q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
+                k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+        with jax.named_scope(P.ATTN):  # the token's write and the kernel
+            if uniform_slot:
+                # [1, B, nkv, 1, hd]
+                kw = k[None, :, :, None, :].astype(k_all.dtype)
+                vw = v[None, :, :, None, :].astype(v_all.dtype)
+                k_all = jax.lax.dynamic_update_slice(
+                    k_all, kw, (l, 0, 0, s0, 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    v_all, vw, (l, 0, 0, s0, 0))
+            else:
+                k_all = k_all.at[l, jnp.arange(b), :, slot].set(
+                    k.astype(k_all.dtype))
+                v_all = v_all.at[l, jnp.arange(b), :, slot].set(
+                    v.astype(v_all.dtype))
+            base = cfg.head_dim ** -0.5 if cfg.scale_attn_weights else 1.0
+            if not cfg.scale_attn_by_inverse_layer_idx:
+                scale = base
+            elif isinstance(l, int):
+                scale = base / (l + 1)
+            else:
+                scale = _attn_scale(cfg, l)  # traced scalar
+            attn = _stacked_decode_attention(
+                q, k_all, v_all, valid, l, scale=scale,
+                sliding_window=window, slot=slot, mesh=mesh)
+        with jax.named_scope(P.ATTN_PROJ):
+            attn = _head_gate(lp, ln1, attn)
+            proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
+            if "bo" in lp["attn"]:
+                proj = proj + lp["attn"]["bo"].astype(x.dtype)
+            x = x + proj
+        return _ff_step(x, lp, sparse), k_all, v_all
+
+    def _ff_step(x, lp, sparse):
+        # the norm, the feed-forward and the residual's add, one part
+        with jax.named_scope(_ff_part(cfg, sparse)):
+            ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
+            return x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
 
     k_all, v_all = cache["k"], cache["v"]
     new_conv = []
@@ -967,26 +1030,30 @@ def decode_step(
                     x, k_all, v_all, lp, cfg.attention_layers.index(i),
                     ff == "moe", op, cfg.layer_window(i))
                 continue
-            ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
-            proj, state = _short_conv_step(
-                cfg, lp["conv"], ln1, cache["conv"][len(new_conv)])
-            new_conv.append(state)
-            x = x + proj
-            ln2 = _norm(cfg, x, lp["ln2"]["scale"], None)
-            x = x + _mlp(cfg, lp, ln2, moe_constraint, ff == "moe")
+            with jax.named_scope(P.CONV):
+                ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
+                proj, state = _short_conv_step(
+                    cfg, lp["conv"], ln1, cache["conv"][len(new_conv)])
+                new_conv.append(state)
+                x = x + proj
+            x = _ff_step(x, lp, ff == "moe")
     elif cfg.n_layers <= _DECODE_UNROLL_MAX_LAYERS:
         for li in range(cfg.n_layers):
-            lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
+            with jax.named_scope(P.LAYERS):
+                lp = jax.tree_util.tree_map(lambda a: a[li],
+                                            params["blocks"])
             x, k_all, v_all = layer_body(x, k_all, v_all, lp, li)
     else:
         def body(carry, layer):
             return layer_body(*carry, *layer), None
 
-        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        (x, k_all, v_all), _ = jax.lax.scan(
-            body, (x, k_all, v_all), (params["blocks"], layer_ids))
-    x = _norm(cfg, x, params["ln_f"]["scale"], params["ln_f"].get("bias"))
+        with jax.named_scope(P.LAYERS):
+            layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, k_all, v_all), _ = jax.lax.scan(
+                body, (x, k_all, v_all), (params["blocks"], layer_ids))
+    x = _final_norm(cfg, params, x)
     new_cache = {"k": k_all, "v": v_all, "valid": valid, "length": new_len}
     if new_conv:
-        new_cache["conv"] = jnp.stack(new_conv)
+        with jax.named_scope(P.CONV):
+            new_cache["conv"] = jnp.stack(new_conv)
     return x, new_cache

@@ -16,8 +16,10 @@ and parents its spans there, so causality survives process hops.
 The tracer is OFF by default (every call is a cheap no-op). The
 control is :func:`start` / :func:`stop`, callable any number of times
 in a running process: ``start`` turns spans on and, given a directory,
-starts ``jax.profiler`` too; ``stop`` returns the :class:`Capture`
-(spans and counter deltas), which stays readable as
+starts ``jax.profiler`` too (the one way to start a profile); ``stop``
+returns the :class:`Capture` (spans, counter deltas, and what the
+compiled programs under the spans say of themselves), which stays
+readable as
 :func:`last_capture` (and the few before it as :func:`captures`).
 The ``REALHF_TPU_TRACE=1`` env switch honored by every worker
 process, the inline runner, and quickstart
@@ -93,7 +95,18 @@ CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",
-                  "moe_held_load_max_over_mean")
+                  "moe_held_load_max_over_mean",
+                  "engine_program_bytes")
+
+#: ``(finished spans, profiled) -> {fingerprint: facts}``: who knows
+#: the compiled programs that ran under the spans (the engines:
+#: ``engine/engine.py`` registers the one provider); see Capture.programs
+_program_provider = None
+
+
+def set_program_provider(provider):
+    global _program_provider
+    _program_provider = provider
 
 
 def to_epoch(monotonic_secs: float) -> float:
@@ -224,9 +237,14 @@ class Capture:
     maps ``name{label=value,...}`` of every :data:`CAPTURE_COUNTERS`
     series to its growth in between, ``gauges`` every
     :data:`CAPTURE_GAUGES` series WRITTEN in between to its value at
-    ``stop`` (not what an earlier model of the process left). With a
-    file path configured the spans already flushed to the file are not
-    here as well."""
+    ``stop`` (not what an earlier model of the process left).
+    ``programs`` maps the ``program_fingerprint`` of every
+    ``engine:*`` span to what that compiled program says of itself
+    (``obs/parts.py:ProgramFacts`` as a plain dict: ``module``,
+    ``ops``: instruction -> part, pass, opcode, phase; ``memory``);
+    with a ``profile_dir`` it is also ``programs.json`` beside the
+    profile. With a file path configured the spans already flushed to
+    the file are not here as well."""
     spans: List[Dict[str, Any]]
     counters: Dict[str, float]
     start: float
@@ -234,6 +252,8 @@ class Capture:
     sync: Union[bool, Tuple[str, ...]] = False
     profile_dir: Optional[str] = None
     gauges: Dict[str, float] = dataclasses.field(default_factory=dict)
+    programs: Dict[str, Dict[str, Any]] = dataclasses.field(
+        default_factory=dict)
 
     def named(self, prefix: str) -> List[Dict[str, Any]]:
         """Spans called ``prefix`` or ``prefix<something>`` where the
@@ -382,25 +402,49 @@ class Tracer:
             import jax
             jax.profiler.stop_trace()
         spans = self.drain()
-        self._write(spans)
+        end = time.monotonic()  # the capture's clock stops here
         now = _metric_values()
         deltas = {k: v - self._counters_at_start.get(k, 0.0)
                   for k, v in now.items()}
+        # a gauge another model of this process left behind is not
+        # this capture's: only what was written meanwhile (so not what
+        # reading a program's facts, below, writes after the end)
+        gauges = {k: v for k, v in _metric_values(CAPTURE_GAUGES).items()
+                  if _gauge_writes().get(k)
+                  != self._gauge_writes_at_start.get(k)}
+        programs = self._programs(spans, profile_dir)
+        self._write(spans)
         capture = Capture(
             spans=sorted((s.as_dict() for s in spans),
                          key=lambda s: s["start"]),
             counters={k: v for k, v in deltas.items() if v},
-            start=self._started, end=time.monotonic(), sync=self.sync,
-            profile_dir=profile_dir,
-            # a gauge another model of this process left behind is
-            # not this capture's: only what was written meanwhile
-            gauges={k: v for k, v in
-                    _metric_values(CAPTURE_GAUGES).items()
-                    if _gauge_writes().get(k)
-                    != self._gauge_writes_at_start.get(k)})
+            start=self._started, end=end, sync=self.sync,
+            profile_dir=profile_dir, programs=programs, gauges=gauges)
         self._started, self.sync = None, False
         self._captures.append(capture)
         return capture
+
+    @staticmethod
+    def _programs(spans: List[Span], profile_dir: Optional[str]
+                  ) -> Dict[str, Dict[str, Any]]:
+        """What the compiled programs under ``spans`` say of
+        themselves (the registered provider; it may read a program's
+        text now, where there was a profile, and set
+        ``program_fingerprint`` on its spans), written as
+        ``programs.json`` beside the profile. Never raises."""
+        if _program_provider is None:
+            return {}
+        try:
+            programs = _program_provider(spans, profile_dir is not None)
+            if profile_dir is not None and programs:
+                from realhf_tpu.obs import parts
+                with open(parts.programs_path(profile_dir), "w") as f:
+                    json.dump(programs, f)
+            return programs
+        except Exception as e:  # noqa: BLE001 - tracing must never
+            # kill the run
+            logger.warning("Reading the capture's programs failed: %s", e)
+            return {}
 
     def captures(self) -> List[Capture]:
         """The last :data:`KEPT_CAPTURES` captures, newest last."""

@@ -16,13 +16,13 @@ import jax.numpy as jnp
 
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.models.transformer import head_weight
+from realhf_tpu.obs import parts
 
-
-#: ``jax.named_scope`` of the head's chunk bodies: in the ``op_name`` of
-#: every operation they lower to, forward and transposed (a trace's
-#: events carry no scope: ``scripts/trace_ops_by_name.py`` reads it
-#: from the program's HLO)
-HEAD_SCOPE = "vocab_head"
+#: ``jax.named_scope`` of the head (``obs/parts.py``): in the
+#: ``op_name`` of every operation it lowers to, forward and transposed
+#: (a trace's events carry no scope: ``Engine.program_facts`` reads it
+#: from the program's compiled text)
+HEAD_SCOPE = parts.VOCAB_HEAD
 
 
 def _chunk_logits(cfg, w, hc, temperature):
@@ -70,6 +70,13 @@ def shifted_logprobs_from_hidden(
     Returns [S, L] fp32; position t holds the logprob of token t+1.
     The last position of each segment (and pads) hold 0.
     """
+    with jax.named_scope(HEAD_SCOPE):
+        return _shifted_logprobs(cfg, params, hidden, input_ids, seg_ids,
+                                 chunk, temperature, logits_mask)
+
+
+def _shifted_logprobs(cfg, params, hidden, input_ids, seg_ids, chunk,
+                      temperature, logits_mask):
     s, l, h = hidden.shape
     w = head_weight(cfg, params).astype(hidden.dtype)
 
@@ -102,18 +109,17 @@ def shifted_logprobs_from_hidden(
         else:
             hc, lc = x
             mc = None
-        with jax.named_scope(HEAD_SCOPE):
-            logits = _chunk_logits(cfg, w, hc, temperature)
-            if mc is not None:
-                logits = jnp.where(mc, logits, -1e30)
-            z, s_exp = _shift_and_sum_exp(logits)
-            # the label's entry by a select, not a gather: a gather
-            # makes the compiler write the whole log-softmax for it to
-            # read, and its transpose scatters a one-hot of the chunk's
-            # size and copies it into the logits' layout
-            hit = (jax.lax.broadcasted_iota(lc.dtype, z.shape, 2)
-                   == lc[..., None])
-            return None, jnp.where(hit, z, 0.0).sum(-1) - jnp.log(s_exp)
+        logits = _chunk_logits(cfg, w, hc, temperature)
+        if mc is not None:
+            logits = jnp.where(mc, logits, -1e30)
+        z, s_exp = _shift_and_sum_exp(logits)
+        # the label's entry by a select, not a gather: a gather makes
+        # the compiler write the whole log-softmax for it to read, and
+        # its transpose scatters a one-hot of the chunk's size and
+        # copies it into the logits' layout
+        hit = (jax.lax.broadcasted_iota(lc.dtype, z.shape, 2)
+               == lc[..., None])
+        return None, jnp.where(hit, z, 0.0).sum(-1) - jnp.log(s_exp)
 
     _, lp = jax.lax.scan(jax.checkpoint(body), None, xs)
     lp = lp.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]
@@ -162,6 +168,11 @@ def masked_mean(x: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
 def entropy_from_hidden(cfg, params, hidden, *, chunk: int = 1024,
                         temperature: float = 1.0) -> jnp.ndarray:
     """Per-position policy entropy, chunked like shifted logprobs."""
+    with jax.named_scope(HEAD_SCOPE):
+        return _entropy(cfg, params, hidden, chunk, temperature)
+
+
+def _entropy(cfg, params, hidden, chunk, temperature):
     s, l, h = hidden.shape
     w = head_weight(cfg, params).astype(hidden.dtype)
     n_chunks = max(1, (l + chunk - 1) // chunk)
@@ -171,10 +182,9 @@ def entropy_from_hidden(cfg, params, hidden, *, chunk: int = 1024,
     hidden_c = hidden.reshape(s, n_chunks, chunk, h).swapaxes(0, 1)
 
     def body(_, hc):
-        with jax.named_scope(HEAD_SCOPE):
-            z, s_exp = _shift_and_sum_exp(
-                _chunk_logits(cfg, w, hc, temperature))
-            return None, jnp.log(s_exp) - (jnp.exp(z) * z).sum(-1) / s_exp
+        z, s_exp = _shift_and_sum_exp(
+            _chunk_logits(cfg, w, hc, temperature))
+        return None, jnp.log(s_exp) - (jnp.exp(z) * z).sum(-1) / s_exp
 
     _, ent = jax.lax.scan(body, None, hidden_c)
     return ent.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from realhf_tpu.models.config import MoEConfig, TransformerConfig
+from realhf_tpu.obs import parts as P
 
 
 #: key, in the layer's auxiliary dict, of the one entry that is a
@@ -228,17 +229,20 @@ def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
     k = cfg.moe.top_k
     cdt = xt.dtype
 
-    order = jnp.argsort(top_idx.reshape(-1))          # sort by expert
-    tok_idx = order // k
-    xs = xt[tok_idx]                                  # [T*k, H] sorted
+    with jax.named_scope(P.GATHER):
+        order = jnp.argsort(top_idx.reshape(-1))      # sort by expert
+        tok_idx = order // k
+        xs = xt[tok_idx]                              # [T*k, H] sorted
 
-    gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), group_sizes)
-    up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), group_sizes)
-    down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
-                              m["wd"].astype(cdt), group_sizes)
-    gates_sorted = top_probs.reshape(-1)[order]       # pads carry 0
-    weighted = down.astype(jnp.float32) * gates_sorted[:, None]
-    return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(weighted)
+    with jax.named_scope(P.PRODUCTS):
+        gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), group_sizes)
+        up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), group_sizes)
+        down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
+                                  m["wd"].astype(cdt), group_sizes)
+    with jax.named_scope(P.COMBINE):
+        gates_sorted = top_probs.reshape(-1)[order]   # pads carry 0
+        weighted = down.astype(jnp.float32) * gates_sorted[:, None]
+        return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(weighted)
 
 
 #: rows the fast path of a share gathers, over the pairs that even
@@ -282,23 +286,28 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
     k, e = cfg.moe.top_k, cfg.moe.num_experts
     first, _ = cfg.moe.experts_held
     cdt = xt.dtype
-    order = jnp.argsort(((top_idx - first) % e).reshape(-1))
-    n_held = held_sizes.sum()
-    gates_flat = top_probs.reshape(-1)
+    with jax.named_scope(P.GATHER):
+        order = jnp.argsort(((top_idx - first) % e).reshape(-1))
+        n_held = held_sizes.sum()
+        gates_flat = top_probs.reshape(-1)
 
     def part(rows):
-        sel = order[:rows]
-        tok_idx = sel // k
-        mine = jnp.arange(rows) < n_held
-        xs = jnp.where(mine[:, None], xt[tok_idx], 0)
-        sizes = held_sizes.at[-1].add(rows - n_held)
-        gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), sizes)
-        up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), sizes)
-        down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
-                                  m["wd"].astype(cdt), sizes)
-        gates = jnp.where(mine, gates_flat[sel], 0.0)
-        weighted = down.astype(jnp.float32) * gates[:, None]
-        return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(weighted)
+        with jax.named_scope(P.GATHER):
+            sel = order[:rows]
+            tok_idx = sel // k
+            mine = jnp.arange(rows) < n_held
+            xs = jnp.where(mine[:, None], xt[tok_idx], 0)
+            sizes = held_sizes.at[-1].add(rows - n_held)
+        with jax.named_scope(P.PRODUCTS):
+            gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), sizes)
+            up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), sizes)
+            down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
+                                      m["wd"].astype(cdt), sizes)
+        with jax.named_scope(P.COMBINE):
+            gates = jnp.where(mine, gates_flat[sel], 0.0)
+            weighted = down.astype(jnp.float32) * gates[:, None]
+            return jnp.zeros((t, h), jnp.float32).at[tok_idx].add(
+                weighted)
 
     rows = share_rows(cfg, t)
     if rows == t * k:
@@ -332,18 +341,19 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
         valid = jnp.ones((t,), jnp.float32)
     else:
         valid = valid_mask.reshape(t).astype(jnp.float32)
-    n_valid = jnp.maximum(valid.sum(), 1.0)
-    logits = (xt.astype(jnp.float32)
-              @ m["router"].astype(jnp.float32))  # [T, E]
-    probs_full = jax.nn.softmax(logits, axis=-1)
-    top_probs, top_idx = router_probs(moe, logits, rng,
-                                      m.get("expert_bias"))
-    # pads contribute nothing: zero their gates everywhere below
-    top_probs = top_probs * valid[:, None]
-
     e = moe.num_experts
-    # (token, k) pairs an expert receives, pads among them
-    load = jnp.bincount(top_idx.reshape(-1), length=e).astype(jnp.int32)
+    with jax.named_scope(P.ROUTE):
+        n_valid = jnp.maximum(valid.sum(), 1.0)
+        logits = (xt.astype(jnp.float32)
+                  @ m["router"].astype(jnp.float32))  # [T, E]
+        probs_full = jax.nn.softmax(logits, axis=-1)
+        top_probs, top_idx = router_probs(moe, logits, rng,
+                                          m.get("expert_bias"))
+        # pads contribute nothing: zero their gates everywhere below
+        top_probs = top_probs * valid[:, None]
+        # (token, k) pairs an expert receives, pads among them
+        load = jnp.bincount(top_idx.reshape(-1),
+                            length=e).astype(jnp.int32)
     ep = ep_constraint if ep_constraint is not None else (lambda a: a)
     mode = dispatch_mode(cfg)
     held = moe.experts_held
@@ -402,9 +412,21 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
         # rank of an expert-parallel deployment holds it whole, so the
         # shares' routed parts and THIS, once, add up to the layer.
         from realhf_tpu.models.transformer import _dense_mlp
-        out = out + _dense_mlp(cfg, m["shared"], xt.astype(x.dtype),
-                               x.dtype).astype(jnp.float32)
+        with jax.named_scope(P.SHARED_EXPERT):
+            out = out + _dense_mlp(cfg, m["shared"], xt.astype(x.dtype),
+                                   x.dtype).astype(jnp.float32)
 
+    with jax.named_scope(P.ROUTE):  # the load statistics and losses
+        losses = _losses(cfg, logits, probs_full, top_idx, load, valid, t)
+    return out.reshape(b, l, h).astype(x.dtype), losses
+
+
+def _losses(cfg: TransformerConfig, logits, probs_full, top_idx, load,
+            valid, t: int) -> Dict[str, jnp.ndarray]:
+    """The layer's auxiliary dict: its statistics (``STATS``) and the
+    router's losses."""
+    moe = cfg.moe
+    e, held = moe.num_experts, moe.experts_held
     losses = {LOAD_STAT: load.max().astype(jnp.float32)
               * (e / (t * moe.top_k))}
     if held is not None:
@@ -419,4 +441,4 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
             probs_full, top_idx, e, moe.top_k, valid=valid)
     if moe.z_loss_coeff:
         losses["moe_z_loss"] = moe.z_loss_coeff * z_loss(logits, valid=valid)
-    return out.reshape(b, l, h).astype(x.dtype), losses
+    return losses

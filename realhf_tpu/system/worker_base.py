@@ -481,34 +481,35 @@ class Worker:
                 snapshot=metrics.snapshot(),
                 flight_events=len(flight.default_recorder()))
         if cmd == "profiler":
-            # jax.profiler start/stop on THIS process (the master
+            # tracing.start(path) / stop() on THIS process (the master
             # overrides this to broadcast to its model workers)
             return self._handle_profiler(**(kwargs or {}))
         raise ValueError(f"Unknown worker command {cmd}")
 
     def _handle_profiler(self, action: str = "start",
                          path: Optional[str] = None) -> Dict:
-        """Toggle a jax.profiler trace in this process; dumps land in
-        ``{run_log_path}/trace/jax`` (TensorBoard/Perfetto-readable)
-        unless ``path`` overrides."""
-        import jax
-
+        """Start or stop a profile of this process through the one
+        control, ``obs.tracing.start(path)`` / ``stop()``: spans on,
+        ``jax.profiler`` recording into ``{run_log_path}/trace/jax``
+        unless ``path`` overrides, every scoped span in the profile
+        beside the device's operations, and ``programs.json`` (what
+        the compiled programs say of themselves) written beside it at
+        the stop. A start while a capture is running stops that one
+        first (``tracing.start``)."""
         from realhf_tpu.base import monitor
         if action == "start":
             target = path or monitor.trace_dir("jax")
-            try:
-                jax.profiler.start_trace(target)
-            except RuntimeError as e:  # already running
-                return dict(ok=False, error=str(e))
+            tracing.start(target)
             flight.record("profiler_start", path=target)
             return dict(ok=True, path=target)
         if action == "stop":
-            try:
-                jax.profiler.stop_trace()
-            except RuntimeError as e:  # not running
-                return dict(ok=False, error=str(e))
+            capture = tracing.stop()
+            if capture is None:
+                return dict(ok=False, error="no capture is running")
             flight.record("profiler_stop")
-            return dict(ok=True)
+            return dict(ok=True, path=capture.profile_dir,
+                        spans=len(capture.spans),
+                        programs=len(capture.programs))
         raise ValueError(f"Unknown profiler action {action!r}")
 
     def run(self):

@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
 """A builder's tool: one ``benchmark/run.py`` run that also keeps what
 the benchmark's reducer drops, for reading a cell's device time by
-operation and by what each operation is.
+operation and by what the PROGRAM says each operation is.
 
     chiprun --chips 1 -- python3 scripts/trace_ops_by_name.py \
         --workload <cell> --seed <n> --seconds 45 --trace 2
+    python3 -m realhf_tpu.obs.parts chiprun_out/profile_<cell>
 
 Same arguments and same last line as ``benchmark/run.py``, which it
 calls. Beside that it writes under ``chiprun_out/``:
@@ -12,48 +13,27 @@ calls. Beside that it writes under ``chiprun_out/``:
 - ``ops_<cell>.json``: EVERY device operation of the traced window
   with its own seconds (``trace_reduce.reduce`` keeps ten), by the
   names the ledger's ``breakdown.device_ops`` uses;
-- ``hlo_<cell>_<program>.txt``: the optimized HLO of each program the
-  kind names (what ``Engine.compiled_text`` returns): an operation's
-  line there carries ``op_name`` metadata, which says what a
-  ``fusion.597`` is; and ``memory_<cell>_<program>.json``: the
-  compiler's own count of the program's arguments, outputs and
-  temporaries;
+- ``profile_<cell>/``: the profiled capture's ``.xplane.pb`` and the
+  ``programs.json`` that ``tracing.stop()`` wrote beside it (every
+  program's operations by part, pass and opcode and the compiler's
+  count of its memory): what ``python3 -m realhf_tpu.obs.parts`` reads,
+  here, without the chip;
 - ``spans_<cell>.json``: the counters and the ``engine:*`` spans, with
   their attributes, of every capture the run made;
 - ``steps_<cell>.json``: every optimizer step's statistics as
   ``Engine.train_batch`` returned them (loss, ``grad_norm``; warm-up
   and traced steps included), every digit: two commits at one seed
   are compared step by step with them.
-
-``--summarize <cell>`` needs no chip: it reads those files back and
-prints the window's seconds by kind of operation.
 """
 
-import collections
 import json
 import os
-import re
+import shutil
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 OUT = os.path.join(ROOT, "chiprun_out")
-
-#: kind of operation <- a pattern in the HLO line's ``op_name`` (the
-#: jaxpr path that made it) or in its name; first match wins
-KINDS = [
-    ("ragged products", r"ragged[-_]dot"),
-    ("flash attention kernels", r"flash_(fwd|bwd)"),
-    ("sort", r"\bsort\b|argsort"),
-    ("scatter-add", r"scatter"),
-    ("gather", r"gather|dynamic_slice|take"),
-    ("optimizer", r"/optimizer/"),
-    ("vocabulary head and loss",
-     r"vocab_head|slh,hv->slv|log_softmax|logsumexp"),
-    ("gradient accumulation", r"closed_call/add$|closed_call/add "),
-    ("copies", r" copy$|/squeeze|/remat2"),
-]
-
 
 #: what every ``Engine.train_batch`` call of the run returned, in order
 OPT_STEPS = []
@@ -63,9 +43,26 @@ def workload_of(argv):
     return argv[argv.index("--workload") + 1]
 
 
+def keep_profile(cell):
+    """Copy the profiled capture's trace file and ``programs.json``
+    (the run deletes its work directory when it ends)."""
+    from realhf_tpu.obs import parts, tracing
+    for capture in reversed(tracing.captures()):
+        if capture.profile_dir is None:
+            continue
+        kept = os.path.join(OUT, f"profile_{cell}")
+        shutil.rmtree(kept, ignore_errors=True)
+        os.makedirs(kept)
+        for path in (parts.newest_profile(capture.profile_dir),
+                     parts.programs_path(capture.profile_dir)):
+            if path and os.path.exists(path):
+                shutil.copy(path, kept)
+        return
+
+
 def install(cell):
-    """Patch the reducer and ``Engine.compiled_text`` to keep what
-    they see under ``chiprun_out/``."""
+    """Patch the reducer and ``Engine.train_batch`` to keep what they
+    see under ``chiprun_out/``."""
     from benchmark import trace_reduce
     from realhf_tpu.engine.engine import Engine
 
@@ -79,24 +76,8 @@ def install(cell):
                 json.dump(dict(busy_s=full["busy_s"],
                                window_s=full["window_s"],
                                ops=full["breakdown"]["device_ops"]), f)
+        keep_profile(cell)
         return reduce(trace, categories, chips=chips, **kw)
-
-    compiled_text = Engine.compiled_text
-
-    def text_and_keep(self, name):
-        fn, args, static = self._last_call[name]
-        compiled = fn.lower(*args, **static).compile()
-        m = compiled.memory_analysis()
-        with open(os.path.join(OUT, f"memory_{cell}_{name}.json"),
-                  "w") as f:
-            json.dump({k: getattr(m, k) for k in (
-                "argument_size_in_bytes", "output_size_in_bytes",
-                "alias_size_in_bytes", "temp_size_in_bytes",
-                "generated_code_size_in_bytes")}, f)
-        text = compiled_text(self, name)
-        with open(os.path.join(OUT, f"hlo_{cell}_{name}.txt"), "w") as f:
-            f.write(text)
-        return text
 
     train_batch = Engine.train_batch
 
@@ -106,7 +87,6 @@ def install(cell):
         return out
 
     trace_reduce.reduce = reduce_and_keep
-    Engine.compiled_text = text_and_keep
     Engine.train_batch = train_and_keep
 
 
@@ -121,12 +101,13 @@ def run(argv):
 
 
 def keep_spans(cell):
-    """``spans_<cell>.json``: every capture's counters and its
-    ``engine:*`` spans with their attributes (``flash_block_share``,
-    ``moe_dispatch``, ``compiled``), which no reader takes."""
+    """``spans_<cell>.json``: every capture's counters, gauges and its
+    ``engine:*`` spans with their attributes (``program``,
+    ``program_fingerprint``, ``flash_block_share``, ``moe_dispatch``,
+    ``compiled``), which no reader takes."""
     from realhf_tpu.obs import tracing
     kept = [dict(profiled=c.profile_dir is not None, sync=c.sync,
-                 counters=c.counters,
+                 counters=c.counters, gauges=c.gauges,
                  spans=[dict(name=s["name"], secs=s["end"] - s["start"],
                              **s["attributes"])
                         for s in c.spans if s["name"].startswith("engine:")])
@@ -137,40 +118,5 @@ def keep_spans(cell):
         json.dump(OPT_STEPS, f)
 
 
-def summarize(cell, program="train"):
-    with open(os.path.join(OUT, f"ops_{cell}.json")) as f:
-        kept = json.load(f)
-    with open(os.path.join(OUT, f"hlo_{cell}_{program}.txt")) as f:
-        hlo = f.read()
-    # instruction name -> its line (fusions: the calling line)
-    line_of = {}
-    for line in hlo.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
-        if m:
-            line_of.setdefault(m.group(1), line)
-    by_kind = collections.defaultdict(float)
-    rows = []
-    for name, secs in kept["ops"]:
-        op = name.split("/", 1)[-1].split(" ")[0]
-        line = line_of.get(op, "")
-        meta = re.search(r'op_name="([^"]*)"', line)
-        what = (meta.group(1) if meta else "") + " " + name
-        kind = next((k for k, pat in KINDS if re.search(pat, what)),
-                    "other")
-        by_kind[kind] += secs
-        rows.append((secs, name, kind, meta.group(1) if meta else ""))
-    total = sum(by_kind.values())
-    print(json.dumps(dict(cell=cell, busy_s=kept["busy_s"],
-                          window_s=kept["window_s"], own_s=total)))
-    for kind, secs in sorted(by_kind.items(), key=lambda x: -x[1]):
-        print(f"{secs:9.4f} s  {100 * secs / total:5.1f}%  {kind}")
-    print()
-    for secs, name, kind, meta in rows[:40]:
-        print(f"{secs:9.4f} s  {name}  [{kind}]  {meta[-110:]}")
-
-
 if __name__ == "__main__":
-    if sys.argv[1] == "--summarize":
-        summarize(*sys.argv[2:])
-    else:
-        sys.exit(run(sys.argv[1:]))
+    sys.exit(run(sys.argv[1:]))

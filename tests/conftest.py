@@ -88,6 +88,21 @@ _compiler.compile_or_get_cached = _compile_each_program_once
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="module")
+def programs_compiled_by_this_tree():
+    """JAX keys its persistent compile cache WITHOUT metadata: a
+    program loaded from a cache that an older tree wrote carries that
+    tree's ``jax.named_scope``s in its ``op_name``s. Modules whose
+    tests READ scopes (``pytestmark = pytest.mark.usefixtures(...)``)
+    compile their programs themselves."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

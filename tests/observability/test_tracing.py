@@ -530,7 +530,8 @@ class _FakeEngine:
     params = ()
 
     def __init__(self):
-        self._last_call = {}
+        self._last_call, self._last_key = {}, {}
+        self._facts, self._unread = {}, {}
 
     def ensure_on_device(self):
         pass
@@ -543,7 +544,7 @@ class _FakeInterface:
 
     def inference(self, model, inp, n_mbs=None):
         import numpy as np
-        return dict(out=model.engine._run("logprobs", self.fn, {},
+        return dict(out=model.engine._run("logprobs", (4,), self.fn, {},
                                           np.ones(4, np.float32)))
 
 
