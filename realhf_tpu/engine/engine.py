@@ -484,7 +484,11 @@ class Engine:
         the program's spans: ``flash_fwd_per_bwd`` of a train program
         (``ops.flash_attention.flash_fwd_per_bwd``: 1.0 where the
         rematerialised blocks keep the flash kernel's residuals, 2.0
-        where they run it again; None off the kernels) and of a
+        where they run it again; None off the kernels) and its
+        ``attn_proj_remat_products`` (``obs.parts.count_products``:
+        the attention projections' products the backward runs a second
+        time; k's and v's where the blocks keep what the two wide ones
+        made, 4 a layer where they keep nothing), and of a
         generate program :meth:`_decode_facts`. Sets gauge
         ``engine_program_bytes{role,program,kind}``."""
         if (name, key) not in self._facts:
@@ -492,7 +496,10 @@ class Engine:
                 derive = self._decode_facts(key)
             elif name.startswith("train"):
                 def derive(text):
-                    return dict(flash_fwd_per_bwd=flash_fwd_per_bwd(text))
+                    return dict(
+                        flash_fwd_per_bwd=flash_fwd_per_bwd(text),
+                        attn_proj_remat_products=parts.count_products(
+                            text, parts.ATTN_PROJ, parts.REMAT))
             else:
                 derive = None
             facts = parts.read_program(self._compiled(name, call), derive)
@@ -529,7 +536,8 @@ class Engine:
         inside set-up), and set on the ``engine:*`` span that has just
         ended its fingerprint and what else came of the text
         (``decode_kernel``, ``decode_layer_copies``,
-        ``flash_fwd_per_bwd``), as on every later span of it."""
+        ``flash_fwd_per_bwd``, ``attn_proj_remat_products``), as on
+        every later span of it."""
         facts = self._program_facts(name, key)
         self._unread.pop(getattr(self._last_span, "span_id", None), None)
         self._last_span.set_attribute("program_fingerprint",

@@ -42,6 +42,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from realhf_tpu.base.backend import pallas_enabled
 from realhf_tpu.models.config import TransformerConfig
@@ -52,6 +53,20 @@ from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
+#: What only an attention layer's two WIDE projection products can
+#: make, by the names ``_attention_op`` gives them
+#: (``checkpoint_name``): q as the attention function takes it (after
+#: bias, query/key norm and rotary) and the projected output after
+#: ``wo`` and its bias. A rematerialised block keeps them
+#: (``_remat``), so its backward runs neither ``attn @ wo`` nor,
+#: where no query norm's backward needs q before the norm, ``x @ wq``
+#: a second time: ``tokens x (q width + hidden) x 2`` bytes a layer a
+#: microbatch in bf16. k and v stay recomputed: kept too they took
+#: Laguna-XS.2's five-layer step from 13.87 to 14.00 GB of a chip's 16
+#: for 0.6% of its tokens a second (PERF.md, PR 36).
+PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
+#: every name the policy of a rematerialised block keeps
+KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS
 
 
 # ----------------------------------------------------------------------
@@ -398,6 +413,7 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
         if cfg.apply_rotary:
             q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
             k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+        q = checkpoint_name(q, PROJECTION_RESIDUALS[0])
     attn_impl = attention_fn or packed_attention
     with jax.named_scope(P.ATTN):
         attn = attn_impl(q, k, v, seg_ids, causal=True,
@@ -409,6 +425,7 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
         proj = attn @ lp["attn"]["wo"].astype(ln1.dtype)
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
+        proj = checkpoint_name(proj, PROJECTION_RESIDUALS[1])
     return proj, (k, v)
 
 
@@ -452,13 +469,17 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
 def _remat(cfg: TransformerConfig, block_fn):
     """``block_fn`` rematerialised in the backward, where
     ``cfg.gradient_checkpointing`` asks for it: it keeps what
-    ``cfg.remat_policy`` names and, whatever that is, the flash
-    kernel's output and log-sum-exp (``ops/flash_attention.py:
-    RESIDUAL_NAMES``). With both outputs of the kernel kept the
-    recomputed block's ``flash_fwd`` has no consumer and is not
-    emitted: the backward recomputes q, k and v and runs the kernel's
-    two backward passes, not its forward a second time. The XLA
-    attention path names nothing, so nothing more is kept there."""
+    ``cfg.remat_policy`` names and, whatever that is,
+    ``KEPT_RESIDUALS``: the flash kernel's output and log-sum-exp
+    (``ops/flash_attention.py:RESIDUAL_NAMES``) and what the two wide
+    attention projections made (``PROJECTION_RESIDUALS``). With both
+    outputs of the kernel kept the recomputed block's ``flash_fwd``
+    has no consumer and is not emitted, and with q and the projected
+    output kept neither are ``x @ wq`` and ``attn @ wo``: the backward
+    recomputes the norm before attention, k, v, the gate and the
+    feed-forward, and runs the kernel's two backward passes. The XLA
+    attention path keeps the same two (q is its einsum's operand) and
+    no kernel's outputs."""
     if not cfg.gradient_checkpointing:
         return block_fn
     return jax.checkpoint(block_fn, policy=_remat_policy(cfg.remat_policy))
@@ -474,7 +495,7 @@ def _remat_policy(name: str):
     policies = jax.checkpoint_policies
     return policies.save_from_both_policies(
         getattr(policies, name),
-        policies.save_only_these_names(*RESIDUAL_NAMES))
+        policies.save_only_these_names(*KEPT_RESIDUALS))
 
 
 def rotary_table(cfg: TransformerConfig, positions: jnp.ndarray,

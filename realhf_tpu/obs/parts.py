@@ -70,6 +70,9 @@ COLLECTIVES = tuple(
                            "all-to-all", "collective-permute",
                            "collective-broadcast")
     for suffix in ("", "-start", "-done"))
+#: opcodes of a matrix product in a compiled program's text (XLA:TPU
+#: writes most of a model's dots as convolutions)
+PRODUCTS_OPCODES = ("dot", "convolution")
 #: instructions that are never an operation of the device's line
 _NO_OPERATION = ("parameter", "constant", "get-tuple-element", "tuple")
 #: fields of ``CompiledMemoryStats`` a program's facts keep
@@ -265,6 +268,26 @@ def parse_program(text: str) -> Dict[str, Tuple]:
     return out
 
 
+def count_products(text: str, part: str, pass_: str) -> int:
+    """The matrix products (:data:`PRODUCTS_OPCODES`) of a compiled
+    program's text whose ``op_name`` :func:`classify` puts under
+    ``part`` in ``pass_``, those inside fusion bodies included (a
+    product is nearly always fused with what surrounds it); a loop's
+    body counts once. ``count_products(text, ATTN_PROJ, REMAT)`` is
+    the engine's ``attn_proj_remat_products``: the attention
+    projections a rematerialised block runs a second time."""
+    called = tuple(f" {opcode}(" for opcode in PRODUCTS_OPCODES)
+    n = 0
+    for line in text.splitlines():
+        if not any(c in line for c in called) \
+                or opcode_of(line) not in PRODUCTS_OPCODES:
+            continue  # (the substrings first: a text has 1e5 lines)
+        meta = _OP_NAME.search(line)
+        if meta and classify(meta.group(1))[:2] == (part, pass_):
+            n += 1
+    return n
+
+
 @dataclasses.dataclass
 class ProgramFacts:
     """What one compiled program says of itself, read once.
@@ -276,7 +299,7 @@ class ProgramFacts:
     compiler's own count, bytes on one device (:data:`MEMORY_FIELDS`);
     ``attributes``: what else the owner read from the same text (the
     engine's ``decode_kernel``, ``decode_layer_copies``,
-    ``flash_fwd_per_bwd``)."""
+    ``flash_fwd_per_bwd``, ``attn_proj_remat_products``)."""
     module: str
     fingerprint: str
     ops: Dict[str, Tuple]

@@ -28,6 +28,7 @@ the forward alone (``.fwd.jaxpr.txt``: what logprobs, values and
 prefill programs run of them).
 """
 import os
+import re
 import sys
 
 root, out = os.path.abspath(sys.argv[1]), sys.argv[2]
@@ -43,8 +44,19 @@ from realhf_tpu.ops import moe as moe_ops
 from realhf_tpu.engine import generation as gen_mod
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 
+def renumbered(txt):
+    """``txt`` with its private functions numbered by first appearance
+    (``@closed_call_116`` -> ``@closed_call#7``): jax numbers them with
+    one counter a PROCESS, so a change to a program lowered earlier
+    (one more ``closed_call`` in a gradient) moves the names in every
+    text after it."""
+    seen = {}
+    return re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", lambda m: seen.setdefault(
+        m.group(0), f"@{m.group(1)}#{len(seen)}"), txt)
+
+
 def dump(name, fn, *args, **kw):
-    txt = jax.jit(fn, **kw).lower(*args).as_text()
+    txt = renumbered(jax.jit(fn, **kw).lower(*args).as_text())
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
@@ -74,7 +86,6 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
              jax.eval_shape(lambda: jax.random.PRNGKey(0)))
 
 # the flash kernels by themselves: (query heads, key/value heads, head size, row) of cells 1-2, 3, 4, 5, 6's full layers
-import re  # noqa: E402
 from realhf_tpu.ops.flash_attention import flash_attention  # noqa: E402
 for nq, nkv, hd, L in ((14, 2, 64, 4096), (32, 8, 128, 2048), (16, 16, 128, 2048), (32, 8, 64, 4096), (48, 8, 128, 4096)):
     sds = jax.ShapeDtypeStruct
@@ -107,5 +118,5 @@ for name, fam, path in (("tiny-qwen2", "qwen2", "tests/benchmark/configs/tiny-qw
     eng.forward_logprobs(ids, seg)
     for prog in ("train", "logprobs"):
         fn, args, static = eng._last_call[prog]
-        open(os.path.join(out, f"{name}.engine_{prog}.txt"), "w").write(fn.lower(*args, **static).as_text())
+        open(os.path.join(out, f"{name}.engine_{prog}.txt"), "w").write(renumbered(fn.lower(*args, **static).as_text()))
         print(name, prog)

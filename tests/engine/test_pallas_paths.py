@@ -262,16 +262,14 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
                                kind=kind) == blocks * cfg.n_layers
 
 
-@pytest.mark.parametrize("program,ratio", [("train", 1.0), ("train", 2.0),
-                                           ("train_seq", 1.0)])
-def test_train_span_says_how_often_the_forward_kernel_runs(
-        program, ratio, monkeypatch):
-    """Every ``engine:train`` / ``engine:train_seq`` span of a program
-    whose rows go to the flash kernels carries ``flash_fwd_per_bwd``,
-    read ONCE from the program's compiled text after its first call
-    (here a text of the chip's: the interpreter's CPU program holds no
-    custom call; ``tests/ops/test_chip_compile.py`` reads the real
-    ones). An engine whose rows take the XLA path reads no text."""
+def _train_spans(program, text, monkeypatch):
+    """The attributes of the two ``engine:<program>`` spans of an
+    engine that takes two steps of ``program`` under a capture, with
+    its rows on the XLA path (no text is read) and on the flash
+    kernels (ONE read of the program's compiled text, which is
+    ``text``: a text of the chip's, since the interpreter's CPU
+    program holds no custom call and no product under a scope's name;
+    ``tests/ops/test_chip_compile.py`` reads the real ones)."""
     from realhf_tpu.api.config import ModelName
     from realhf_tpu.engine.engine import Engine
     from realhf_tpu.engine.optim import OptimizerConfig
@@ -286,11 +284,6 @@ def test_train_span_says_how_often_the_forward_kernel_runs(
     ids = np.random.default_rng(0).integers(
         1, 120, size=seg.shape).astype(np.int32)
     mbs = [dict(input_ids=ids[i], seg_ids=seg[i]) for i in range(2)]
-    call = "custom-call(%q), custom_call_target=\"tpu_custom_call\"\n"
-    text = "%body (q: f32[8]) -> f32[8] {\n" \
-        + "".join(f"  %flash_fwd.{i} = f32[8]{{0}} {call}"
-                  for i in range(int(ratio))) \
-        + f"  ROOT %flash_bwd_dq.1 = f32[8]{{0}} {call}}}\n"
     read = []
 
     class Compiled:  # what Engine._compiled returns, as far as read
@@ -321,10 +314,59 @@ def test_train_span_says_how_often_the_forward_kernel_runs(
         return [s["attributes"]
                 for s in tracing.stop().named(f"engine:{program}")]
 
-    assert all("flash_fwd_per_bwd" not in attrs for attrs in run())
+    off = run()
     assert read == []
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with pltpu.force_tpu_interpret_mode():
-        spans = run()
-    assert [attrs["flash_fwd_per_bwd"] for attrs in spans] == [ratio] * 2
+        on = run()
     assert read == [program]
+    return off, on
+
+
+@pytest.mark.parametrize("program,ratio", [("train", 1.0), ("train", 2.0),
+                                           ("train_seq", 1.0)])
+def test_train_span_says_how_often_the_forward_kernel_runs(
+        program, ratio, monkeypatch):
+    """Every ``engine:train`` / ``engine:train_seq`` span of a program
+    whose rows go to the flash kernels carries ``flash_fwd_per_bwd``,
+    read ONCE from the program's compiled text after its first call.
+    An engine whose rows take the XLA path reads no text."""
+    call = "custom-call(%q), custom_call_target=\"tpu_custom_call\"\n"
+    text = "%body (q: f32[8]) -> f32[8] {\n" \
+        + "".join(f"  %flash_fwd.{i} = f32[8]{{0}} {call}"
+                  for i in range(int(ratio))) \
+        + f"  ROOT %flash_bwd_dq.1 = f32[8]{{0}} {call}}}\n"
+    off, on = _train_spans(program, text, monkeypatch)
+    assert all("flash_fwd_per_bwd" not in attrs for attrs in off)
+    assert [attrs["flash_fwd_per_bwd"] for attrs in on] == [ratio] * 2
+
+
+@pytest.mark.parametrize("program,products", [("train", 0), ("train", 4),
+                                              ("train_seq", 0),
+                                              ("train_seq", 4)])
+def test_train_span_counts_the_projections_run_a_second_time(
+        program, products, monkeypatch):
+    """The sibling of ``flash_fwd_per_bwd`` from the same ONE read of
+    the text: ``attn_proj_remat_products``, the products under part
+    ``attn_proj`` in the rematerialised forward
+    (``obs.parts.count_products``), on every ``engine:train`` /
+    ``engine:train_seq`` span of a program whose rows go to the
+    kernels; 0 is said too (the blocks keep what the projections
+    made), and a text without a backward kernel has the count and no
+    ratio."""
+    scope = "jit(train_step)/jit(main)/forward_backward/transpose(jvp())" \
+        "/checkpoint/rematted_computation"
+
+    def product(i, part):
+        return (f"  %convolution.{i} = bf16[8,8]{{1,0}} convolution(%a, %b),"
+                f" dim_labels=bf_io->bf, metadata={{op_name=\"{scope}/"
+                f"{part}/dot_general\"}}\n")
+
+    text = "%body (q: bf16[8,8]) -> bf16[8,8] {\n" \
+        + "".join(product(i, "attn_proj") for i in range(products)) \
+        + product(9, "mlp") + "}\n"
+    off, on = _train_spans(program, text, monkeypatch)
+    assert all("attn_proj_remat_products" not in attrs for attrs in off)
+    assert [attrs["attn_proj_remat_products"] for attrs in on] \
+        == [products] * 2
+    assert all("flash_fwd_per_bwd" not in attrs for attrs in on)

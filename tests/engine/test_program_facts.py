@@ -89,9 +89,14 @@ def generate_(engine, new_tokens=4):
 
 @pytest.fixture(autouse=True)
 def fresh_tracer():
+    """A tracer and a metrics registry of the test's own: the gauges
+    are process-wide, and an engine that a file earlier on the same
+    xdist worker built left its programs' series in them."""
     tracing.reset_default()
+    metrics.reset_default()
     yield
     tracing.reset_default()
+    metrics.reset_default()
 
 
 @pytest.fixture

@@ -198,6 +198,56 @@ class _Compiled:
         return self.stats
 
 
+REMAT = (f"{FB}/transpose(jvp())/while/body/closed_call/checkpoint/"
+         "rematted_computation")
+
+
+def _layer(remat_parts):
+    """One layer's products as a compiled text holds them: four of the
+    attention projections forward and eight backward, and one in the
+    rematerialised forward for each of ``remat_parts``, the first of
+    them inside a fusion body."""
+    fwd = [line(f"convolution.{i}", "convolution",
+                f"{FB}/jvp()/while/body/closed_call/attn_proj/dot_general")
+           for i in range(4)]
+    bwd = [line(f"dot.{i}", "dot",
+                f"{FB}/transpose(jvp())/while/body/closed_call/checkpoint/"
+                "attn_proj/dot_general") for i in range(8)]
+    again = [line(f"convolution.{9 + i}", "convolution",
+                  f"{REMAT}/{part}/dot_general")
+             for i, part in enumerate(remat_parts)]
+    # what is no product does not count, whatever its scope
+    other = [line("fusion.77", "fusion", f"{REMAT}/attn_proj/mul",
+                  extra=", kind=kLoop, calls=%fused_computation.1"),
+             line("flash_fwd.3", "custom-call", f"{REMAT}/attn/custom_call")]
+    body = "%fused_computation.1 (p: bf16[8]) -> bf16[8] {\n" \
+        + "\n".join(again[:1]) + "\n}\n\n"
+    return body + "%body (x: bf16[8]) -> bf16[8] {\n" + "\n".join(
+        fwd + bwd + again[1:] + other) + "\n}\n"
+
+
+@pytest.mark.parametrize("remat_parts,want", [
+    # every projection runs a second time: 4 a layer (one of them
+    # inside a fusion body: counted)
+    (["attn_proj"] * 4, 4),
+    # the block keeps what the projections made
+    ([], 0),
+    # a product under ANOTHER part in the rematerialised forward (the
+    # feed-forward's, the router's, the head's) is not counted
+    (["mlp", "mlp", "experts/route", "vocab_head"], 0),
+], ids=["recomputed", "kept", "another_part"])
+def test_count_products_by_part_and_pass(remat_parts, want):
+    text = "HloModule jit_train_step, is_scheduled=true\n\n" \
+        + _layer(remat_parts)
+    assert parts.count_products(text, "attn_proj", "remat") == want
+    assert parts.count_products(text, "attn_proj", "fwd") == 4
+    assert parts.count_products(text, "attn_proj", "bwd") == 8
+    assert parts.count_products(text, "mlp", "remat") \
+        == remat_parts.count("mlp")
+    # an unrolled stack of three layers holds them three times
+    assert parts.count_products(text * 3, "attn_proj", "remat") == 3 * want
+
+
 def test_read_program_facts():
     class Stats:
         argument_size_in_bytes, temp_size_in_bytes = 7, 5

@@ -204,16 +204,29 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
     for 16.17 MB of it, because what XLA schedules around a kernel
     takes from the same 16 MiB, and PR 33's first chip call died of
     that. ``flash_attention._vmem_limit`` gives such a call its
-    ``vmem_limit_bytes``; ``without_vmem_limit`` takes it away and the
-    same program must be refused for VMEM: the day that case compiles,
-    ``_vmem_limit`` holds nothing up and can go."""
+    ``vmem_limit_bytes``. ``without_vmem_limit`` takes it away from
+    the program as it was then, whose backward recomputes q, k and v
+    (the policy keeps the kernel's residuals alone): the producers'
+    fusions feed the kernel, and that program must be refused for
+    VMEM. Since the blocks keep q (``PROJECTION_RESIDUALS``) the
+    kernel reads a kept array and the program as it IS compiles
+    without the limit too, by 1% of the 16 MiB: what XLA schedules
+    around the kernel decides, so the limit stays. The day the
+    recomputing program compiles without it, ``_vmem_limit`` holds
+    nothing up and can go."""
+    from realhf_tpu.models import transformer as T
     from realhf_tpu.ops import flash_attention as fa
 
     if not limit:
         monkeypatch.setattr(fa, "_vmem_limit", lambda *a: None)
-        with pytest.raises(Exception, match="(?i)vmem"):
-            _compile(*_sft_microbatch(one_chip, "laguna-xs.2-l5-ep16",
-                                      "laguna"))
+        monkeypatch.setattr(T, "KEPT_RESIDUALS", fa.RESIDUAL_NAMES)
+        T._remat_policy.cache_clear()
+        try:
+            with pytest.raises(Exception, match="(?i)vmem"):
+                _compile(*_sft_microbatch(
+                    one_chip, "laguna-xs.2-l5-ep16", "laguna"))
+        finally:
+            T._remat_policy.cache_clear()
         return
     text = _compiled_microbatch(one_chip, "laguna-xs.2-l5-ep16",
                                 "laguna").as_text()
@@ -221,20 +234,38 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
         assert kernel in text
 
 
-@pytest.mark.parametrize("config,family,calls,gigabytes", [
-    ("laguna-xs.2-l5-ep16", "laguna", 5, 13.5),
-    ("qwen2.5-0.5b", "qwen2", 1, None),
+@pytest.mark.parametrize("config,family,calls,gate,q_products,gigabytes", [
+    ("laguna-xs.2-l5-ep16", "laguna", 5, 5, 10, 3.15),
+    ("qwen2.5-0.5b", "qwen2", 1, 0, None, None),
 ], ids=["laguna_unrolled_5", "qwen_scanned_24"])
 def test_rematerialised_stack_runs_the_forward_kernel_once_a_layer(
-        one_chip, config, family, calls, gigabytes):
+        one_chip, config, family, calls, gate, q_products, gigabytes):
     """The blocks keep the flash kernel's output and log-sum-exp
     (``models/transformer.py:_remat``), so the compiled backward holds
     as many ``flash_fwd`` custom calls as ``flash_bwd_dq`` ones: five
     and five in Laguna's unrolled stack, one and one in the loop
     bodies of Qwen2.5-0.5B's scanned 24 layers. Before the residuals
-    were kept each held twice as many ``flash_fwd``. What Laguna's
-    program keeps for it (0.31 GB a microbatch) still leaves the
-    compiler's count of arguments and temporaries under the chip."""
+    were kept each held twice as many ``flash_fwd``.
+
+    They keep what the two wide attention projections made too
+    (``PROJECTION_RESIDUALS``), so the rematerialised forward holds
+    neither ``x @ wq`` nor ``attn @ wo`` (``obs.parts.count_products``,
+    the engine's ``attn_proj_remat_products``): of a layer's 4
+    products 2 are left, k's and v's, in Qwen's loop body (where the
+    forward holds 4 and the backward 8), and in Laguna's five layers
+    those 10 and the head gates' small ``[4096,2048] x [2048,64 or
+    48]`` (15 of 25 before; 25 forward, 50 backward); Laguna's program
+    holds 10 products that make a ``[4096,64 or 48,128]``, the
+    forward's q and the backward's gradient of the attention output
+    (15 before: 175 -> 165 products in the text, 11.148 -> 9.911
+    TFLOP as XLA counts them). What Laguna's program keeps for both
+    (the kernel's 0.31 GB a microbatch and the projections' 0.39:
+    arguments + temporaries 2.960 -> 3.047 GB as the compiler counts
+    them; Qwen's scan stacks 24 layers' residuals, 3.277 -> 3.860 GB)
+    stays within 0.1 GB of that."""
+    import re
+
+    from realhf_tpu.obs import parts
     from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
     from realhf_tpu.ops.hlo_text import device_instructions
 
@@ -246,6 +277,16 @@ def test_rematerialised_stack_runs_the_forward_kernel_once_a_layer(
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert sum(kernel in name for name in names) == calls, kernel
     assert flash_fwd_per_bwd(text) == 1.0
+    layers = calls  # a scanned stack holds its layers' one body
+    products = {p: parts.count_products(text, parts.ATTN_PROJ, p)
+                for p in (parts.FWD, parts.REMAT, parts.BWD)}
+    assert products == {parts.FWD: 4 * layers + gate,
+                        parts.REMAT: 2 * layers + gate,
+                        parts.BWD: 2 * (4 * layers + gate)}
+    if q_products is not None:
+        assert len(re.findall(
+            r"= bf16\[4096,(?:64|48),128\]\S* (?:convolution|dot)\(",
+            text)) == q_products
     if gigabytes is not None:
         memory = compiled.memory_analysis()
         assert (memory.argument_size_in_bytes
