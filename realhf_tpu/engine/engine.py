@@ -252,6 +252,11 @@ class Engine:
                 self._model_attrs.update(
                     window=cfg.sliding_window,
                     window_layers=len(cfg.window_layers))
+            if cfg.latent is not None:
+                self._model_attrs.update(
+                    latent_layers=len(cfg.latent_layers),
+                    kv_lora_rank=cfg.latent.kv_rank,
+                    qk_dim=cfg.head_dim, v_dim=cfg.latent.v_dim)
             if cfg.layer_q_heads is not None:
                 self._model_attrs.update(q_heads=" ".join(
                     str(cfg.q_heads(i)) for i in cfg.attention_layers))

@@ -11,9 +11,11 @@ from typing import Dict, Optional, Tuple
 
 #: what a layer of a patterned model is made of: "attention" sees
 #: every earlier token of its document, "window" the last
-#: ``sliding_window`` of them
-OPERATORS = ("conv", "attention", "window")
-ATTENTION_OPERATORS = ("attention", "window")
+#: ``sliding_window`` of them, "latent" every earlier token through
+#: keys and values expanded from ONE compressed row a token
+#: (``LatentConfig``)
+OPERATORS = ("conv", "attention", "window", "latent")
+ATTENTION_OPERATORS = ("attention", "window", "latent")
 FEED_FORWARDS = ("dense", "moe")
 
 
@@ -41,6 +43,8 @@ class RotaryConfig:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    # pairs (2j, 2j+1) rotate together, not (j, j + r/2)
+    interleaved: bool = False
 
     def __post_init__(self):
         if self.scaling_type not in (None, "linear", "dynamic", "yarn"):
@@ -61,10 +65,43 @@ class RotaryConfig:
         return int(head_dim * self.partial_factor)
 
     def describe(self) -> str:
-        """``yarn64@500000/0.5``: for a span's attribute."""
+        """``yarn64@500000/0.5``, ``plain@50000/0.333333/interleaved``:
+        for a span's attribute."""
         kind = "plain" if self.scaling_type is None \
             else f"{self.scaling_type}{self.factor:g}"
-        return f"{kind}@{self.base:g}/{self.partial_factor:g}"
+        return f"{kind}@{self.base:g}/{self.partial_factor:g}" \
+            + ("/interleaved" if self.interleaved else "")
+
+
+#: what a latent layer's norm of the compressed row norms at: the
+#: published module builds it without an epsilon of its own, so this,
+#: its class's default, and not the model's ``rms_norm_eps``
+LATENT_NORM_EPS = 1e-6
+
+
+@dataclasses.dataclass
+class LatentConfig:
+    """Latent attention (operator "latent" of a layer pattern): keys
+    and values come from ONE compressed row a token. With u the
+    layer's normed input, ``hd = TransformerConfig.head_dim`` the
+    query/key's width and ``nope = hd - rope_dim``::
+
+        q = u wq                      [T, heads, hd]
+        a = u w_kv_a                  [T, kv_rank + rope_dim]
+        c = RMSNorm(a[:, :kv_rank]; kv_a_norm) at LATENT_NORM_EPS
+        c w_kv_b                      [T, heads, nope + v_dim]
+            -> k_nope [.., :nope], v [.., nope:]
+        k = [k_nope, rotary(a[:, kv_rank:]) to EVERY head]
+        q = [q[.., :nope], rotary(q[.., nope:])]
+
+    scores over ``hd`` at ``hd ** -0.5``, values and the heads'
+    outputs ``v_dim`` wide, ``wo`` [heads x v_dim, H]. The rotary
+    embedding (``rotary_of("latent")``) is over the LAST ``rope_dim``
+    values of a query head and over one key part all heads share.
+    Every head has keys of its own (``n_kv_heads == n_q_heads``)."""
+    kv_rank: int
+    rope_dim: int
+    v_dim: int
 
 
 @dataclasses.dataclass
@@ -213,6 +250,9 @@ class TransformerConfig:
     # the layer's normed input, multiplied into each head's attention
     # output before ``wo`` (leaf ``attn["w_gate"]`` [H, heads]).
     attn_output_gate: bool = False
+    # What the "latent" layers of the pattern are made of; ``head_dim``
+    # is then their query/key's width and ``v_head_dim`` their value's.
+    latent: Optional[LatentConfig] = None
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -284,10 +324,31 @@ class TransformerConfig:
         if self.layer_pattern is None and (
                 self.layer_q_heads is not None
                 or self.rotary_by_operator is not None
-                or self.attn_output_gate):
+                or self.attn_output_gate or self.latent is not None):
             raise NotImplementedError(
-                "layer_q_heads, rotary_by_operator and attn_output_gate "
-                "belong to a model with a layer_pattern")
+                "layer_q_heads, rotary_by_operator, attn_output_gate "
+                "and latent belong to a model with a layer_pattern")
+        if (self.latent is not None) != bool(self.latent_layers):
+            raise ValueError(
+                f"layer_pattern has {len(self.latent_layers)} latent "
+                f"layers, latent is {self.latent}")
+        if self.latent is not None:
+            if len(self.latent_layers) != len(self.attention_layers):
+                raise NotImplementedError(
+                    "latent layers beside attention or window layers: "
+                    "the K/V stack keeps ONE shape (layer_pattern "
+                    f"'{self.pattern_string}')")
+            if (self.n_kv_heads != self.n_q_heads
+                    or self.rotary_by_operator is None
+                    or self.layer_q_heads is not None
+                    or self.qk_norm is not None or self.attn_output_gate
+                    or not 0 < self.latent.rope_dim < self.head_dim
+                    or self.latent.rope_dim % 2):
+                raise NotImplementedError(
+                    "a latent layer has a key a query head, its rotary "
+                    "embedding under rotary_by_operator['latent'], no "
+                    "query/key norm or output gate, and an even "
+                    "rope_dim under head_dim")
         if self.layer_q_heads is not None:
             self.layer_q_heads = tuple(int(n) for n in self.layer_q_heads)
             if len(self.layer_q_heads) != self.n_layers or any(
@@ -302,8 +363,9 @@ class TransformerConfig:
                 - set(self.rotary_by_operator)
             if missing or self.rotary_interleaved:
                 raise ValueError(
-                    f"rotary_by_operator lacks {sorted(missing)} "
-                    "(halves convention only)")
+                    f"rotary_by_operator lacks {sorted(missing)} (and "
+                    "says the convention a kind of layer, RotaryConfig."
+                    "interleaved, not model-wide rotary_interleaved)")
         else:
             self.rotary_of("attention")  # a RotaryConfig checks itself
 
@@ -335,6 +397,18 @@ class TransformerConfig:
                      if op in ATTENTION_OPERATORS)
 
     @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """The layers whose keys and values come from a latent."""
+        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
+                     if op == "latent")
+
+    @property
+    def v_head_dim(self) -> int:
+        """A value head's width, which is also an attention output
+        head's: ``head_dim`` unless the layers are latent."""
+        return self.head_dim if self.latent is None else self.latent.v_dim
+
+    @property
     def window_layers(self) -> Tuple[int, ...]:
         """The layers whose attention sees ``sliding_window`` tokens."""
         return tuple(i for i in self.attention_layers
@@ -351,15 +425,25 @@ class TransformerConfig:
             if self.layer_pattern[i][0] == "window" else None
 
     def rotary_of(self, op: str) -> RotaryConfig:
-        """The rotary embedding of an ``op`` layer ("attention" or
-        "window"): its kind's where the model declares one a kind,
-        else the model-wide ``rotary_*`` fields as a RotaryConfig."""
+        """The rotary embedding of an ``op`` layer ("attention",
+        "window" or "latent"): its kind's where the model declares one
+        a kind, else the model-wide ``rotary_*`` fields as a
+        RotaryConfig."""
         if self.rotary_by_operator is not None:
             return self.rotary_by_operator[op]
         return RotaryConfig(
             base=self.rotary_base, scaling_type=self.rotary_scaling_type,
             factor=self.rotary_scaling,
-            original_max_positions=self.n_positions)
+            original_max_positions=self.n_positions,
+            interleaved=self.rotary_interleaved)
+
+    def rotated_dim(self, op: str) -> int:
+        """How many values of a head an ``op`` layer rotates: a latent
+        layer its ``rope_dim`` (the LAST of a query head), another the
+        first ``partial_factor x head_dim``."""
+        if op == "latent":
+            return self.latent.rope_dim
+        return self.rotary_of(op).rotated(self.head_dim)
 
     def q_heads(self, i: int) -> int:
         """Query heads of layer ``i``."""
@@ -373,8 +457,9 @@ class TransformerConfig:
 
     @property
     def pattern_string(self) -> str:
-        """``c a c c c``, ``a w w w a``: every layer's operator by its
-        first letter (conv, attention, window)."""
+        """``c a c c c``, ``a w w w a``, ``l l l``: every layer's
+        operator by its first letter (conv, attention, window,
+        latent)."""
         return " ".join(op[0] for op, _ in self.layer_kinds)
 
     def require_one_block(self, what: str):
@@ -387,6 +472,7 @@ class TransformerConfig:
                 f"{len(self.conv_layers)} conv and "
                 f"{len(self.attention_layers)} attention layers, "
                 f"{len(self.window_layers)} of those with a window, "
+                f"{len(self.latent_layers)} latent, "
                 f"{self.n_moe_layers} layers sparse)")
 
     def n_params(self) -> int:
@@ -395,12 +481,19 @@ class TransformerConfig:
         taps, the router (and its selection bias) over all experts,
         the experts HELD, the shared expert, the query/key norms and
         the output gate, each attention layer at its own count of
-        query heads; biases and the layer norms' scales are left
-        out."""
+        query heads, a latent layer's five leaves; biases and the
+        layer norms' scales are left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
 
         def attn(i):
             nq = self.q_heads(i)
+            if self.latent is not None:
+                lat = self.latent
+                nope = self.head_dim - lat.rope_dim
+                return h * nq * self.head_dim \
+                    + h * (lat.kv_rank + lat.rope_dim) + lat.kv_rank \
+                    + lat.kv_rank * nq * (nope + lat.v_dim) \
+                    + nq * lat.v_dim * h
             n = h * (nq + 2 * self.n_kv_heads) * self.head_dim \
                 + nq * self.head_dim * h
             if self.qk_norm == "full":

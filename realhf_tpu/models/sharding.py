@@ -113,6 +113,12 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
                               "ln2": {"scale": P(None)}}
         if op == "conv":
             lp["conv"] = {"w_in": col, "w": col, "w_out": row}
+        elif op == "latent":
+            # by head, as wq and wo: the expansion's columns are a
+            # head's (nope + v) at a time; the compression, whose
+            # output is one row for all heads, is on every shard
+            lp["attn"] = {"wq": col, "w_kv_a": P(None, None),
+                          "kv_a_norm": P(None), "w_kv_b": col, "wo": row}
         else:
             lp["attn"] = {"wq": col, "wk": col, "wv": col, "wo": row}
             if cfg.attn_output_gate:  # a gate a head: by head, as wq

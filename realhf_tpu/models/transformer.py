@@ -30,6 +30,11 @@ Design (idiomatic JAX, not a torch translation):
   over a window of ``sliding_window`` tokens (operator "window"),
   their count of query heads (``layer_q_heads``; K and V keep one
   shape), the rotary table of their kind (``rotary_by_operator``).
+  Or they are LATENT (operator "latent", ``LatentConfig``): keys and
+  values expanded from one compressed row a token, the key
+  ``head_dim`` wide (its last ``rope_dim`` values one rotary part all
+  heads share), the value ``v_head_dim``; attention, the flash kernels
+  and the cache (K rows of one width, V rows of the other) take both.
   A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
@@ -45,7 +50,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from realhf_tpu.base.backend import pallas_enabled
-from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.models.config import LATENT_NORM_EPS, TransformerConfig
 from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
 from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
@@ -178,6 +183,15 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             lp["conv"] = {"w_in": norm((h, 3 * h)),
                           "w": norm((cfg.conv_kernel, h)),
                           "w_out": norm((h, h), proj_std)}
+        elif op == "latent":
+            lat = cfg.latent
+            lp["attn"] = {
+                "wq": norm((h, nq * hd)),
+                "w_kv_a": norm((h, lat.kv_rank + lat.rope_dim)),
+                "kv_a_norm": ones((lat.kv_rank,)),
+                "w_kv_b": norm((lat.kv_rank,
+                                nq * (hd - lat.rope_dim + lat.v_dim))),
+                "wo": norm((nq * lat.v_dim, h), proj_std)}
         else:
             nq = cfg.q_heads(i)
             lp["attn"] = {"wq": norm((h, nq * hd)),
@@ -220,23 +234,27 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 # Building blocks
 # ----------------------------------------------------------------------
 def _norm(cfg: TransformerConfig, x: jnp.ndarray, scale: jnp.ndarray,
-          bias: Optional[jnp.ndarray]) -> jnp.ndarray:
-    """LayerNorm / RMSNorm / gemma-RMSNorm with fp32 accumulation."""
+          bias: Optional[jnp.ndarray],
+          eps: Optional[float] = None) -> jnp.ndarray:
+    """LayerNorm / RMSNorm / gemma-RMSNorm with fp32 accumulation, at
+    ``cfg.layer_norm_epsilon`` unless the norm has an ``eps`` of its
+    own (a latent's)."""
+    eps = cfg.layer_norm_epsilon if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.layer_norm_type is None:
         mean = xf.mean(-1, keepdims=True)
         var = jnp.mean((xf - mean) ** 2, -1, keepdims=True)
-        out = (xf - mean) * jax.lax.rsqrt(var + cfg.layer_norm_epsilon)
+        out = (xf - mean) * jax.lax.rsqrt(var + eps)
         out = out * scale.astype(jnp.float32)
         if bias is not None:
             out = out + bias.astype(jnp.float32)
     elif cfg.layer_norm_type == "rms":
         var = jnp.mean(xf ** 2, -1, keepdims=True)
-        out = xf * jax.lax.rsqrt(var + cfg.layer_norm_epsilon)
+        out = xf * jax.lax.rsqrt(var + eps)
         out = out * scale.astype(jnp.float32)
     elif cfg.layer_norm_type == "gemma":
         var = jnp.mean(xf ** 2, -1, keepdims=True)
-        out = xf * jax.lax.rsqrt(var + cfg.layer_norm_epsilon)
+        out = xf * jax.lax.rsqrt(var + eps)
         out = out * (1.0 + scale.astype(jnp.float32))
     else:
         raise NotImplementedError(cfg.layer_norm_type)
@@ -340,6 +358,53 @@ def _qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray):
     return q, k, v
 
 
+def _latent_qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
+                cos: jnp.ndarray, sin: jnp.ndarray):
+    """q and k [..., heads, head_dim], ROTATED, and v [..., heads,
+    v_dim] of the latent layer ``lp`` (``LatentConfig`` has the
+    equations): the keys' first ``nope`` values and the values are
+    expanded, a head at a time, from the token's normed latent; the
+    keys' last ``rope_dim`` are ONE rotated part that every head gets,
+    as the queries' last ``rope_dim`` are rotated. What makes k and v
+    from the latent is sub-part ``attn_proj/latent`` (obs/parts.py)."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    a, lat = lp["attn"], cfg.latent
+    *lead, _ = x.shape
+    nope = cfg.head_dim - lat.rope_dim
+    interleaved = cfg.rotary_of("latent").interleaved
+    q = (x @ a["wq"].astype(cdt)).reshape(*lead, -1, cfg.head_dim)
+    q = jnp.concatenate(
+        [q[..., :nope],
+         apply_rotary(q[..., nope:], cos, sin, interleaved)], axis=-1)
+    with jax.named_scope(P.LATENT):
+        kv_a = x @ a["w_kv_a"].astype(cdt)
+        c = _norm(cfg, kv_a[..., :lat.kv_rank], a["kv_a_norm"], None,
+                  LATENT_NORM_EPS)
+        kv = (c @ a["w_kv_b"].astype(cdt)).reshape(
+            *lead, -1, nope + lat.v_dim)
+        k_rope = apply_rotary(kv_a[..., None, lat.kv_rank:], cos, sin,
+                              interleaved)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, (*kv.shape[:-1], lat.rope_dim))],
+            axis=-1)
+    return q, k, kv[..., nope:]
+
+
+def _rotated_qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
+                 cos: jnp.ndarray, sin: jnp.ndarray, op: str):
+    """q, k and v of an ``op`` layer as attention takes them:
+    projected, normed, and q and k rotated by the kind's table."""
+    if op == "latent":
+        return _latent_qkv(cfg, lp, x, cos, sin)
+    q, k, v = _qkv(cfg, lp, x)
+    if cfg.apply_rotary:
+        interleaved = cfg.rotary_of(op).interleaved
+        q = apply_rotary(q, cos, sin, interleaved)
+        k = apply_rotary(k, cos, sin, interleaved)
+    return q, k, v
+
+
 def _short_conv(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
                 seg_ids: jnp.ndarray):
     """The gated short convolution over packed rows: u [B, L, H] (the
@@ -404,15 +469,14 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
                   layer_idx: jnp.ndarray, ln1: jnp.ndarray,
                   seg_ids: jnp.ndarray, cos: jnp.ndarray,
                   sin: jnp.ndarray, attention_fn=None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, op: str = "attention"):
     """Attention over packed streams on the normed residual ``ln1``
     [B, L, H] -> (its projected output [B, L, H], (k, v)). ``window``:
-    the tokens THIS layer sees (``cfg.layer_window``), None for all."""
+    the tokens THIS layer sees (``cfg.layer_window``), None for all;
+    ``op``: the layer's operator (a latent layer's v, and the heads'
+    outputs, are ``v_head_dim`` wide)."""
     with jax.named_scope(P.ATTN_PROJ):
-        q, k, v = _qkv(cfg, lp, ln1)
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-            k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+        q, k, v = _rotated_qkv(cfg, lp, ln1, cos, sin, op)
         q = checkpoint_name(q, PROJECTION_RESIDUALS[0])
     attn_impl = attention_fn or packed_attention
     with jax.named_scope(P.ATTN):
@@ -453,7 +517,7 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
             proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
-                                    cos, sin, attention_fn, window)
+                                    cos, sin, attention_fn, window, op)
     with jax.named_scope(mixer):
         x = constrain(x + proj)
     ff = _ff_part(cfg, sparse)
@@ -505,7 +569,7 @@ def rotary_table(cfg: TransformerConfig, positions: jnp.ndarray,
     rotates."""
     rc = cfg.rotary_of(op)
     return rotary_freqs(
-        positions, rc.rotated(cfg.head_dim), rc.base, rc.factor,
+        positions, cfg.rotated_dim(op), rc.base, rc.factor,
         rc.scaling_type, rc.original_max_positions,
         beta_fast=rc.beta_fast, beta_slow=rc.beta_slow,
         attention_factor=rc.attention_factor)
@@ -701,7 +765,8 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
     aux). Each attention layer takes the window its operator says and
     the rotary table of its kind (``rotary``: ``_rotary_tables``).
     ``states`` (for prefill): K and V stacked over the ATTENTION
-    layers [n_attn, B, L, nkv, hd] and the convolutions' inputs
+    layers [n_attn, B, L, nkv, hd] (V ``v_head_dim`` wide where the
+    layers are latent) and the convolutions' inputs
     stacked over the CONV layers [n_conv, B, L, H]; None unless
     ``return_kv``. ``aux``: the sparse layers' entries reduced as
     ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
@@ -772,7 +837,9 @@ def critic_values(cfg: TransformerConfig, params: Params,
 # ----------------------------------------------------------------------
 # Cache layout is HEAD-MAJOR: k/v are [nl, B, nkv, S, hd] so the decode
 # attention kernel streams a layer's rows straight from HBM with no
-# transpose on the hot path. The slot axis is pre-padded to a multiple
+# transpose on the hot path (latent layers: the EXPANDED keys, hd wide,
+# and values, v_head_dim wide, a head; the 576-wide latent row itself
+# is not what is cached, ROADMAP R3c). The slot axis is pre-padded to a multiple
 # of the kernel's K block so per-token calls never concat-pad.
 _CACHE_LEN_MULTIPLE = 128
 # Below this depth the decode layer loop is unrolled (XLA schedules
@@ -796,11 +863,10 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     reference `prepare_generate_inputs` (real_llm_generate.py:179)."""
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     max_len = round_cache_len(max_len)
-    shape = (len(cfg.attention_layers), batch, cfg.n_kv_heads, max_len,
-             cfg.head_dim)
+    shape = (len(cfg.attention_layers), batch, cfg.n_kv_heads, max_len)
     cache = {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
+        "k": jnp.zeros(shape + (cfg.head_dim,), dtype),
+        "v": jnp.zeros(shape + (cfg.v_head_dim,), dtype),
         "valid": jnp.zeros((batch, max_len), bool),
         "length": jnp.zeros((batch,), jnp.int32),
     }
@@ -883,11 +949,11 @@ def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
 
     Prefer ``prefill(..., total_len=...)`` which allocates the final
     size up front; this concat path remains for incremental callers."""
-    nl, b, nkv, s, hd = cache["k"].shape
+    _, b, _, s, _ = cache["k"].shape
     new_s = round_cache_len(s + extra)
     extra = new_s - s
     pad = lambda a: jnp.concatenate(
-        [a, jnp.zeros((nl, b, nkv, extra, hd), a.dtype)], axis=3)
+        [a, jnp.zeros(a.shape[:3] + (extra, a.shape[4]), a.dtype)], axis=3)
     return {
         **cache,  # length, and a patterned model's conv state
         "k": pad(cache["k"]),
@@ -910,9 +976,10 @@ def _stacked_decode_attention(q, k_all, v_all, valid, layer_idx, *,
     very bottleneck this kernel removes. The XLA slice path remains
     where the kernel does not apply: CPU, heads under 64, a mesh on
     which neither heads nor cache slots divide (GSPMD partitions the
-    einsums itself)."""
+    einsums itself), values of another width than the keys (latent
+    layers)."""
     hd = q.shape[-1]
-    if pallas_enabled() and hd >= 64:
+    if pallas_enabled() and hd >= 64 and v_all.shape[-1] == hd:
         from realhf_tpu.ops.decode_attention import run_decode_kernels
         out = run_decode_kernels(
             mesh, q, (k_all, v_all), valid, slot, layer_idx,
@@ -994,10 +1061,7 @@ def decode_step(
         with jax.named_scope(P.ATTN_PROJ):
             ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
             # q: [B, nq, hd]; k/v: [B, nkv, hd]
-            q, k, v = _qkv(cfg, lp, ln1)
-            if cfg.apply_rotary:
-                q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-                k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+            q, k, v = _rotated_qkv(cfg, lp, ln1, cos, sin, op)
         with jax.named_scope(P.ATTN):  # the token's write and the kernel
             if uniform_slot:
                 # [1, B, nkv, 1, hd]

@@ -60,6 +60,13 @@ PHASES = (FORWARD_BACKWARD, PREFILL, DECODE, SAMPLE)
 ROUTE, GATHER, PRODUCTS, COMBINE = "route", "gather", "products", "combine"
 #: sub-scopes of ``experts``: the part then reads ``experts/route``
 EXPERT_STEPS = (ROUTE, GATHER, PRODUCTS, COMBINE)
+#: sub-scope of ``attn_proj`` in a latent layer: what makes keys and
+#: values from the compressed row (its projection, the norm, the
+#: expansion a head, the shared rotary key's rotation and broadcast);
+#: the part then reads ``attn_proj/latent``
+LATENT = "latent"
+#: part -> the sub-scopes that may stand inside it
+SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,)}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: the pass of an operation whose ``op_name`` the compiler wrote
@@ -141,9 +148,10 @@ def classify(op_name: str) -> Tuple[Optional[str], str, Optional[str]]:
     """``(part, pass, phase)`` of one ``op_name``.
 
     ``part``: the innermost component that is one of :data:`PARTS`
-    (``experts`` with the :data:`EXPERT_STEPS` scope inside it, where
-    there is one: ``experts/route``); None where no part claims the
-    operation. ``pass``: ``remat`` in a rematerialised forward, else
+    (with the :data:`SUB_STEPS` scope inside it, where the part has
+    them and there is one: ``experts/route``, ``attn_proj/latent``);
+    None where no part claims the operation. ``pass``: ``remat`` in a
+    rematerialised forward, else
     ``bwd`` where the path holds a ``transpose(``, else ``fwd``; ``?``
     for an ``op_name`` of :data:`COMPILER_MADE`.
     ``phase``: the outermost component of :data:`PHASES`, or None."""
@@ -154,11 +162,12 @@ def classify(op_name: str) -> Tuple[Optional[str], str, Optional[str]]:
     for i, comp in enumerate(comps):
         if comp in PARTS:
             part = comp
-            if comp == EXPERTS:
+            steps = SUB_STEPS.get(comp)
+            if steps:
                 step = next((c for c in comps[i + 1:]
-                             if c in EXPERT_STEPS + PARTS), None)
-                if step in EXPERT_STEPS:
-                    part = f"{EXPERTS}/{step}"
+                             if c in steps + PARTS), None)
+                if step in steps:
+                    part = f"{comp}/{step}"
         elif phase is None and comp in PHASES:
             phase = comp
     if _REMAT_MARK in comps:

@@ -50,7 +50,7 @@ def _segment_mask(seg_q: jnp.ndarray, seg_k: jnp.ndarray,
 def packed_attention_xla(
     q: jnp.ndarray,  # [B, L, nq, hd]
     k: jnp.ndarray,  # [B, L, nkv, hd]
-    v: jnp.ndarray,  # [B, L, nkv, hd]
+    v: jnp.ndarray,  # [B, L, nkv, hv]: hv the value's width, hd or not
     seg_ids: jnp.ndarray,  # [B, L] int32, 0 = padding
     *,
     causal: bool = True,
@@ -80,15 +80,16 @@ def packed_attention_xla(
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, l, nq, hd).astype(q.dtype)
+    return out.reshape(b, l, nq, v.shape[-1]).astype(q.dtype)
 
 
-def flash_takes(row_len: int, head_dim: int, *, scale=None,
+def flash_takes(row_len: int, key_dim: int, *, scale=None,
                 logits_soft_cap=None) -> bool:
-    """Whether a packed row meets the flash kernel's gate: its tiling,
-    a static python scale, no soft cap (a sliding window is the
-    kernel's own)."""
-    return (row_len % 128 == 0 and head_dim >= 64
+    """Whether a packed row meets the flash kernel's gate: its tiling
+    (``key_dim``: the query/key's width, which the scores contract
+    over; the value's may differ), a static python scale, no soft cap
+    (a sliding window is the kernel's own)."""
+    return (row_len % 128 == 0 and key_dim >= 64
             and logits_soft_cap is None
             and (scale is None or isinstance(scale, (int, float))))
 
@@ -183,7 +184,7 @@ def make_sharded_attention(mesh, inner=None):
 def decode_attention(
     q: jnp.ndarray,        # [B, nq, hd] -- one new token per stream
     k_cache: jnp.ndarray,  # [B, nkv, S, hd] (head-major)
-    v_cache: jnp.ndarray,  # [B, nkv, S, hd]
+    v_cache: jnp.ndarray,  # [B, nkv, S, hv]
     valid_mask: jnp.ndarray,  # [B, S] bool: which cache slots hold real
                               # tokens (left-padded prompts leave invalid
                               # low slots, so a prefix length is not enough)
@@ -226,4 +227,4 @@ def decode_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgk,bhkd->bhgd", probs.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, nq, hd).astype(q.dtype)
+    return out.reshape(b, nq, v_cache.shape[-1]).astype(q.dtype)

@@ -13,14 +13,24 @@ attention (flash-attention-2 schedule) with
 - a custom VJP with Pallas backward kernels (dq and dkv passes),
   recomputing probabilities from the saved log-sum-exp.
 
-Layout contract: q [B, L, nq, hd], k/v [B, L, nkv, hd], seg_ids [B, L]
-(0 = padding). One segment id is ONE contiguous run of a row, as
+Layout contract: q [B, L, nq, hd], k [B, L, nkv, hd], v [B, L, nkv, hv],
+seg_ids [B, L] (0 = padding). ``hd`` is the query/key's width, which
+the scores contract over, ``hv`` the value's, which the output and its
+cotangent share; most models have ``hv == hd``, latent attention has
+(192, 128), and the kernels read each from its own operand. One
+segment id is ONE contiguous run of a row, as
 ``engine/packing.py:segment_ids`` lays sequences out (ids in no
 order: the packer places the longest first) and as
 ``models/transformer.py:positions_from_segments`` and the window test
 of ``ops/attention.py:_segment_mask`` already assume. L must be a
-multiple of the Q block; hd should be a multiple of 128 for MXU
-tiling (128 for llama-family models).
+multiple of the Q block; hd and hv should be multiples of 128 for MXU
+tiling (128 for llama-family models). A key's width of 192 compiles
+and runs: its blocks take the whole last axis, VMEM holds them over
+256 lanes, and a score-shaped product contracts over two passes of the
+128-wide MXU, half of the second empty. On the chip that is not what
+limits the kernels: at (192, 128), 16 heads and rows of 4096 they run
+at 55.5% of the matrix peak by the mathematics' count, over the 48.5%
+of window and full layers at (128, 128) (PERF.md, PR 37).
 
 Which blocks are visited. A token attends inside its own segment
 only, so a query block needs the key blocks from the lowest start to
@@ -88,19 +98,21 @@ DEFAULT_BK = 512
 #: (32, 8, 128) and runs out of VMEM one kilotoken above either; the
 #: forward alone compiles to 8192 and is refused at 16384. A sliding
 #: window changes none of this (K and V stay whole a head in VMEM);
-#: the windowed backward at (64, 8, 128) x 4096 compiles too.
+#: the windowed backward at (64, 8, 128) x 4096 compiles too, as does
+#: the backward at a key's width of 192 and a value's of 128 (16, 16
+#: heads) x 4096.
 FLASH_MAX_LEN = 4096
 NEG_INF = -2.0 ** 30
 LANES = 128
 SUBLANES = 8
 #: The two residuals of the backward that only the forward kernel can
 #: make, by the names ``_flash_attention_fwd`` gives them
-#: (``checkpoint_name``): the output, head-major ``[B, nq, L, hd]`` as
-#: the kernel writes it, and the log-sum-exp, one float32 a (row,
-#: head), ``[B, nq, L]``. A ``jax.checkpoint`` whose policy keeps both
-#: (``models/transformer.py:_remat``) recomputes q, k and v in the
-#: backward but not the kernel: ``2 hd + 4`` bytes a (token, head)
-#: against a second run of ``flash_fwd``.
+#: (``checkpoint_name``): the output, head-major ``[B, nq, L, hv]`` as
+#: the kernel writes it (the VALUE's width), and the log-sum-exp, one
+#: float32 a (row, head), ``[B, nq, L]``. A ``jax.checkpoint`` whose
+#: policy keeps both (``models/transformer.py:_remat``) recomputes q, k
+#: and v in the backward but not the kernel: ``2 hv + 4`` bytes a
+#: (token, head) against a second run of ``flash_fwd``.
 RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
@@ -222,7 +234,7 @@ def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
                 *, scale: float, bk: int, causal: bool,
                 window: Optional[int] = None):
     qi = pl.program_id(2)
-    bq, hd = q_ref.shape[-2], q_ref.shape[-1]
+    bq, hv = q_ref.shape[-2], v_ref.shape[-1]
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, hd]
     seg_q = segq_ref[0, :, 0]  # [BQ]
@@ -230,12 +242,12 @@ def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
 
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, hd), jnp.float32)
+    acc0 = jnp.zeros((bq, hv), jnp.float32)
 
     def body(j, carry):
         m, l_sum, acc = carry
         k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # [BK, hd]
-        v = v_ref[0, 0, pl.ds(j * bk, bk), :]
+        v = v_ref[0, 0, pl.ds(j * bk, bk), :]  # [BK, hv]
         seg_k = segk_ref[0, 0, pl.ds(j * bk, bk)]  # [BK]
 
         s = jax.lax.dot_general(
@@ -339,10 +351,10 @@ def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
 
 def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
     """The forward kernel's two outputs as it writes them: the output
-    head-major ``[B, nq, L, hd]`` and the lane-broadcast log-sum-exp
-    ``[B, nq, L, LANES]``."""
+    head-major ``[B, nq, L, hv]`` (the value's width) and the
+    lane-broadcast log-sum-exp ``[B, nq, L, LANES]``."""
     b, l, nq, hd = q.shape
-    nkv = k.shape[2]
+    nkv, hv = k.shape[2], v.shape[3]
     group = nq // nkv
     bq, bk = _blocks(l, bq, bk)
 
@@ -362,13 +374,13 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
         [
             pl.BlockSpec((1, 1, bq, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
-            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, 1, l, hv), at["kv_whole"]),
             pl.BlockSpec((1, bq, LANES), at["seg_row"]),
             pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
         ],
-        (pl.BlockSpec((1, 1, bq, hd), at["row"]),
+        (pl.BlockSpec((1, 1, bq, hv), at["row"]),
          pl.BlockSpec((1, 1, bq, LANES), at["row"])),
-        (jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        (jax.ShapeDtypeStruct((b, nq, l, hv), q.dtype),
          jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
         qt, kt, vt, segq, segk)
     return out, lse
@@ -423,7 +435,7 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
                     *, scale: float, bq: int, causal: bool,
                     window: Optional[int] = None):
     ki = pl.program_id(2)
-    bk, hd = k_ref.shape[-2], k_ref.shape[-1]
+    bk, hd, hv = k_ref.shape[-2], k_ref.shape[-1], v_ref.shape[-1]
 
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
@@ -456,7 +468,7 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
         return dk, dv
 
     dk0 = jnp.zeros((bk, hd), jnp.float32)
-    dv0 = jnp.zeros((bk, hd), jnp.float32)
+    dv0 = jnp.zeros((bk, hv), jnp.float32)
     dk, dv = jax.lax.fori_loop(*_block_range(q_lo_ref, q_hi_ref), body,
                                (dk0, dv0))
     # Per-q-head partials; summed over each KV group outside (race-free).
@@ -468,6 +480,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
     q, k, v, seg_ids, ot, lse = res
     do = g
     b, l, nq, hd = q.shape
+    hv = v.shape[3]
     # the kept log-sum-exp is one number a (row, head); the kernels
     # read it over 128 lanes, as they do delta
     lse = jnp.broadcast_to(lse[..., None], (b, nq, l, LANES))
@@ -496,10 +509,10 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
         [
             pl.BlockSpec((1, 1, bq_, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
-            pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
+            pl.BlockSpec((1, 1, l, hv), at["kv_whole"]),
             pl.BlockSpec((1, bq_, LANES), at["seg_row"]),
             pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
-            pl.BlockSpec((1, 1, bq_, hd), at["row"]),
+            pl.BlockSpec((1, 1, bq_, hv), at["row"]),
             pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
             pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
         ],
@@ -514,22 +527,22 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
         [
             pl.BlockSpec((1, 1, l, hd), at["whole"]),
             pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),
-            pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),
+            pl.BlockSpec((1, 1, bk_, hv), at["kv_row"]),
             pl.BlockSpec((1, l, LANES), at["seg_whole"]),
             pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
-            pl.BlockSpec((1, 1, l, hd), at["whole"]),
+            pl.BlockSpec((1, 1, l, hv), at["whole"]),
             pl.BlockSpec((1, 1, l, LANES), at["whole"]),
             pl.BlockSpec((1, 1, l, LANES), at["whole"]),
         ],
         (pl.BlockSpec((1, 1, bk_, hd), at["row"]),
-         pl.BlockSpec((1, 1, bk_, hd), at["row"])),
+         pl.BlockSpec((1, 1, bk_, hv), at["row"])),
         (jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32),
-         jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32)),
+         jax.ShapeDtypeStruct((b, nq, l, hv), jnp.float32)),
         qt, kt, vt, segq, segk, dot, lse, delta)
 
     # Sum q-head partials within each KV group.
     dk = dk_partial.reshape(b, nkv, group, l, hd).sum(2).transpose(0, 2, 1, 3)
-    dv = dv_partial.reshape(b, nkv, group, l, hd).sum(2).transpose(0, 2, 1, 3)
+    dv = dv_partial.reshape(b, nkv, group, l, hv).sum(2).transpose(0, 2, 1, 3)
     dq_ = dq.transpose(0, 2, 1, 3).astype(q.dtype)
     return (dq_, dk.astype(k.dtype), dv.astype(v.dtype), None)
 
@@ -594,7 +607,8 @@ def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
         raise ValueError(
             f"flash_attention: packed row of {q.shape[1]} tokens exceeds "
             f"FLASH_MAX_LEN={FLASH_MAX_LEN}, the longest row whose "
-            "forward and backward kernels fit the chip's VMEM. Split "
+            "forward and backward kernels hold whole in the chip's "
+            f"VMEM at a key's width of {q.shape[-1]}. Split "
             "the batch into more microbatches (the MFC's n_mbs) so "
             "packed rows get shorter, or shard the sequence over a "
             "context-parallel mesh (ring attention).")

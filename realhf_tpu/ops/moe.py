@@ -41,8 +41,11 @@ pairs of absent experts are never multiplied: what those experts would
 have added is left out. ``rows`` is ``SHARE_ROWS_OVER_MEAN`` times the
 pairs even routing would bring the held experts; where a batch routes
 MORE to them, a ``lax.cond`` takes the same path over all ``T x k``
-sorted rows instead, so no pair of a held expert is ever dropped,
-whatever the imbalance. There is no exchange and nothing stands in
+sorted rows instead, ``rows`` of them at a time (a rematerialised scan:
+the branch a step rarely takes then holds one chunk's intermediates,
+not four or eight chunks' beside the other branch's; PERF.md, PR 37),
+so no pair of a held expert is ever dropped, whatever the imbalance.
+There is no exchange and nothing stands in
 for the other ranks. With every expert held the same code is the
 uncut layer. A SHARED expert (``MoEConfig.shared_intermediate_dim``,
 leaves ``m["shared"]``) is no part of a share: every token visits it,
@@ -279,8 +282,9 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
     are zeroed on the way in and counted into the last group, so that
     every row lies in a group (``lax.ragged_dot`` leaves a row that
     no group covers undefined on the chip: ``SHARE_ROWS_OVER_MEAN``)
-    and adds nothing, to the result or to a gradient. ``rows`` is static: the
-    fast path's where the held pairs fit it, else all ``T x k``."""
+    and adds nothing, to the result or to a gradient. ``rows`` is
+    static: the fast path takes the first ``rows`` sorted rows, the
+    slow path all ``T x k`` in chunks of ``rows`` (``every_row``)."""
     from realhf_tpu.models.transformer import _activation
     t, h = xt.shape
     k, e = cfg.moe.top_k, cfg.moe.num_experts
@@ -291,13 +295,26 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
         n_held = held_sizes.sum()
         gates_flat = top_probs.reshape(-1)
 
-    def part(rows):
+    def part(rows, at=None):
+        """The sorted rows ``[at, at + rows)`` (``at`` a traced start
+        in the slow path's scan; None: the first ``rows``, in the form
+        the fast path always had: it is the program every step runs,
+        and its lowering stays what it was)."""
         with jax.named_scope(P.GATHER):
-            sel = order[:rows]
+            if at is None:
+                sel = order[:rows]
+                mine = jnp.arange(rows) < n_held
+                sizes = held_sizes.at[-1].add(rows - n_held)
+            else:
+                sel = jax.lax.dynamic_slice_in_dim(padded, at, rows)
+                mine = at + jnp.arange(rows) < n_held
+                # each held expert's pairs inside the chunk; what is
+                # left of the chunk goes, zeroed, to the last group
+                ends = jnp.clip(jnp.cumsum(held_sizes), at, at + rows) - at
+                sizes = jnp.diff(ends, prepend=0)
+                sizes = sizes.at[-1].add(rows - ends[-1])
             tok_idx = sel // k
-            mine = jnp.arange(rows) < n_held
             xs = jnp.where(mine[:, None], xt[tok_idx], 0)
-            sizes = held_sizes.at[-1].add(rows - n_held)
         with jax.named_scope(P.PRODUCTS):
             gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), sizes)
             up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), sizes)
@@ -312,8 +329,25 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
     rows = share_rows(cfg, t)
     if rows == t * k:
         return part(rows)
-    return jax.lax.cond(n_held <= rows, lambda: part(rows),
-                        lambda: part(t * k))
+    chunks = -(-t * k // rows)
+    with jax.named_scope(P.GATHER):  # (no operation where rows divide)
+        padded = order if chunks * rows == t * k else jnp.pad(
+            order, (0, chunks * rows - t * k))
+
+    def every_row():
+        """All ``T x k`` sorted rows, ``rows`` at a time, each chunk
+        rematerialised in the backward: under a gradient the branch
+        keeps its inputs and the running sum, not every chunk's
+        gathered rows and products (the whole of them stood in the
+        program's peak beside the fast path's: 2.1 of 14.7 GB at 24,576
+        rows of 2048 and experts of 1408). Rows past ``T x k`` in the
+        last chunk are no pair's (``mine`` is false there)."""
+        out, _ = jax.lax.scan(
+            jax.checkpoint(lambda acc, at: (acc + part(rows, at), None)),
+            jnp.zeros((t, h), jnp.float32), jnp.arange(chunks) * rows)
+        return out
+
+    return jax.lax.cond(n_held <= rows, lambda: part(rows), every_row)
 
 
 def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,

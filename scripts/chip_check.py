@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -27,6 +27,19 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   lie inside a window's reach of the next one's first tokens; and what
   each wrong equation does to the longest of them (the fixed batch's
   256 tokens never reach a window of 512).
+- ``exact`` (``deepseek_v3``): the program with FLOAT32 weights and
+  products at the highest precision on ONE document of 4,096 tokens,
+  the cell's row, through the COMPILED flash kernels at a key of 192
+  and a value of 128, against the reference; and what each wrong
+  equation reads there, reference against reference.
+- ``slow_path`` (``deepseek_v3``): the share's fallback
+  (``ops/moe.py:_ragged_share``: all ``T x k`` sorted rows, a
+  rematerialised chunk at a time), which a benchmark run hardly ever
+  takes, FORCED by handing the layer a quarter of its fast path's rows:
+  the bf16 engine's log-probabilities against the reference, and loss
+  and gradient of one 4,096-token SFT microbatch against the fast
+  path's (on the chip a grouped product leaves a row no group covers
+  unwritten, in the backward too: PERF.md, PR 31).
 - ``decode``: a prefill, then ``decode_step``s through the caches,
   teacher-forced, bf16, against the reference's full forward
   (log-probabilities, not tokens). ``laguna``: 2 sequences of 768, a
@@ -78,6 +91,11 @@ FAMILIES = {
         cell="laguna-xs.2-l5-ep16.sft-4k",
         tiny=("laguna", "tiny-laguna.sft"), wrong_keys={},
         packed_docs=(1536, 1024, 1024, 512), decode=(2, 768, 640)),
+    "deepseek_v3": dict(
+        cell="moonlight-16b-a3b-l5-ep8.sft-4k",
+        tiny=("deepseek_v3", "tiny-deepseek-v3.sft"), wrong_keys={},
+        packed_docs=(1536, 1024, 1024, 512), decode=(2, 768, 640),
+        exact_doc=4096, slow_path=True),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -278,6 +296,82 @@ def packed(cell, engine, tensors, docs, ckpt=None):
         tolerance=family.TOLERANCE, **rows)
 
 
+def exact(cell, ckpt, tensors, doc):
+    """The float32 engine at the highest precision on ONE document of
+    the cell's row length, through the compiled kernels, against the
+    reference; and every wrong equation there."""
+    import jax
+    import numpy as np
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    doc = doc[None].astype(np.int32)
+    want = family.logprobs(hf, tensors, doc)
+    with jax.default_matmul_precision("highest"):
+        engine = one_chip_engine(ckpt, "float32")
+        got = np.asarray(engine.forward_logprobs(doc, np.ones_like(doc)),
+                         np.float32)[:, :-1]
+        kernel = "tpu_custom_call" in engine.compiled_text("logprobs")
+    del engine
+    say(phase="exact", document=doc.shape[1], flash_kernels=kernel,
+        engine_float32=share(got, want),
+        wrong={wrong: share(family.logprobs(hf, tensors, doc,
+                                            wrong=(wrong,)), want)
+               for wrong in family.WRONG},
+        secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
+
+
+def slow_path(cell, ckpt, ids, want, fast, seed, divide=4):
+    """The share's fallback forced (``share_rows`` cut to a ``divide``th
+    so the held pairs pass it): the engine's log-probabilities on the
+    fixed batch, and one SFT microbatch's loss and gradient against the
+    fast path's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.ops import moe as moe_ops
+
+    family, hf = cell["family"], cell["hf"]
+    real = moe_ops.share_rows
+    row = min(hf.get("max_position_embeddings", 4096), 4096)
+    rng = np.random.default_rng(seed + 5)
+    mb = dict(input_ids=jnp.asarray(rng.integers(
+        0, hf["vocab_size"], (1, row)), jnp.int32),
+        seg_ids=jnp.ones((1, row), jnp.int32),
+        prompt_mask=jnp.arange(row)[None] < row // 8)
+
+    def reading(engine):
+        got = np.asarray(engine.forward_logprobs(ids, np.ones_like(ids)),
+                         np.float32)[:, :-1]
+        engine.cfg.gradient_checkpointing = True
+        objective = engine._objective(sft._make_loss_fn(engine.cfg))
+        (loss, stats), grads = jax.jit(jax.value_and_grad(
+            objective, has_aux=True))(engine.params, mb)
+        flat = jnp.concatenate([g.astype(jnp.float32).reshape(-1)
+                                for g in jax.tree.leaves(grads)])
+        return got, float(loss), np.asarray(flat), \
+            float(stats[moe_ops.SHARE_OVERFLOW_STAT])
+
+    t = time.monotonic()
+    _, loss_f, grad_f, over_f = reading(fast)
+    moe_ops.share_rows = lambda cfg, t_: max(real(cfg, t_) // divide, 8)
+    try:
+        got, loss_s, grad_s, over_s = reading(one_chip_engine(ckpt))
+    finally:
+        moe_ops.share_rows = real
+    norm_f, norm_s = np.linalg.norm(grad_f), np.linalg.norm(grad_s)
+    say(phase="slow_path", rows_divided_by=divide,
+        layers_on_the_slow_path=dict(fast=over_f, forced=over_s),
+        engine_bf16_forced=share(got, want),
+        loss=dict(fast=loss_f, forced=loss_s),
+        grad_norm=dict(fast=float(norm_f), forced=float(norm_s)),
+        grad_cosine=float(grad_f @ grad_s / (norm_f * norm_s)),
+        grad_relative_l2=float(np.linalg.norm(grad_f - grad_s) / norm_f),
+        finite=bool(np.isfinite(grad_s).all()),
+        secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
+
+
 def decode(cell, engine, ids, want, n_pre=192):
     import jax
     import jax.numpy as jnp
@@ -406,6 +500,12 @@ def main():
                     long = generate.fixed_batch(cell["hf"], seed + 3, b, n)
                     decode(cell, engine, long, cell["family"].logprobs(
                         cell["hf"], tensors, long), n_pre=n_pre)
+                if spec.get("slow_path"):
+                    slow_path(cell, ckpt, ids, want, engine, seed)
+                if spec.get("exact_doc"):
+                    n = spec["exact_doc"] // (8 if args.rehearse else 1)
+                    engine = None  # the bf16 weights go before float32's come
+                    exact(cell, ckpt, tensors, rng.integers(0, vocab, n))
             del engine, tensors
         if args.gen:
             gen(cell, ckpt, work, args.seeds[-1])

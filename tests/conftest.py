@@ -122,12 +122,27 @@ def pytest_configure(config):
 #: ``tests/benchmark/test_benchmark_laguna.py::
 #: test_lfm2s_manifest_test_holds_as_far_as_its_cell`` runs the WHOLE
 #: body of the test below, every assertion of it, on the manifest as
-#: far as PR 31's cell, so nothing it held is left unheld.
+#: far as PR 31's cell, so nothing it held is left unheld. PR 37
+#: appended a cell and two per-layer metrics, which made that test's
+#: own pin of the last TWO cells stale, and the count of per-layer
+#: metrics in PR 35's manifest test; ``tests/benchmark/
+#: test_benchmark_deepseek_v3.py`` runs the WHOLE body of each on the
+#: manifest as far as the entries it was written for
+#: (``test_lagunas_manifest_test_holds_as_far_as_its_cell``,
+#: ``test_the_parts_manifest_test_holds_as_far_as_its_entries``).
 _STALE_BENCHMARK_TESTS = {
     "tests/benchmark/test_benchmark_lfm2.py::"
     "test_real_manifest_names_the_cell_as_the_issue_does":
         "line 57 asserts that PR 31's cell is the LAST of "
         "BENCHMARK.json's workloads; PR 33 appended the sixth",
+    "tests/benchmark/test_benchmark_laguna.py::"
+    "test_lfm2s_manifest_test_holds_as_far_as_its_cell":
+        "line 98 asserts that PR 31's and PR 33's cells are the LAST "
+        "TWO of BENCHMARK.json's workloads; PR 37 appended the seventh",
+    "tests/benchmark/test_benchmark_parts.py::"
+    "test_manifest_gains_the_fourteen_at_its_end_and_nothing_else":
+        "line 187 asserts that BENCHMARK.json has 34 per-layer metrics "
+        "and PR 35's fourteen are the LAST; PR 37 appended two",
 }
 
 

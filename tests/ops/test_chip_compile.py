@@ -293,6 +293,60 @@ def test_rematerialised_stack_runs_the_forward_kernel_once_a_layer(
                 + memory.temp_size_in_bytes) < gigabytes * 1e9
 
 
+def _qkv_two_widths(sharding, l, heads, hd, hv):
+    q, k, _, seg = _qkv(sharding, 1, l, heads, heads, hd)
+    return q, k, _qkv(sharding, 1, l, heads, heads, hv)[2], seg
+
+
+def test_flash_compiles_at_moonlights_two_widths(one_chip):
+    """The seventh cell's rows of 4096 at latent attention's widths: 16
+    heads, keys 192 wide (128 + 64 rotary: no multiple of the 128
+    lanes, so a block takes the whole last axis and VMEM holds it over
+    256), values and output 128 wide, forward and backward, at the
+    kernels' stated limit."""
+    args = _qkv_two_widths(one_chip, FLASH_MAX_LEN, 16, 192, 128)
+    _compile(flash_attention, *args)
+    _compile(_flash_grads, *args)
+
+
+def test_moonlights_whole_microbatch_compiles(one_chip):
+    """The seventh cell's train program as the chip compiles it: one
+    microbatch's SFT forward and backward of ALL FIVE of
+    ``moonlight-16b-a3b-l5-ep8``'s latent layers at published widths,
+    a row of 4096, bf16, rematerialised. The three kernels once a
+    layer (the blocks keep ``flash_out`` at the value's width and q at
+    the key's); no product of ``attn_proj`` itself is run again, and
+    what makes k and v from the latent (sub-part ``attn_proj/latent``:
+    the compression and the expansion) is, twice a layer; the
+    compiler's count of the microbatch's memory."""
+    from realhf_tpu.obs import parts
+    from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
+    from realhf_tpu.ops.hlo_text import device_instructions
+
+    compiled = _compiled_microbatch(one_chip, "moonlight-16b-a3b-l5-ep8",
+                                    "deepseek_v3")
+    text = compiled.as_text()
+    names = [name for name, _, opcode in device_instructions(text)
+             if opcode == "custom-call"]
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(kernel in name for name in names) == 5, kernel
+    assert flash_fwd_per_bwd(text) == 1.0
+    latent = f"{parts.ATTN_PROJ}/{parts.LATENT}"
+    assert {p: parts.count_products(text, parts.ATTN_PROJ, p)
+            for p in (parts.FWD, parts.REMAT)} == {
+        parts.FWD: 2 * 5, parts.REMAT: 0}
+    assert {p: parts.count_products(text, latent, p)
+            for p in (parts.FWD, parts.REMAT)} == {
+        parts.FWD: 2 * 5, parts.REMAT: 2 * 5}
+    # 1.14 GB of bf16 weights and 0.82 of temporaries: 3.42 GB while
+    # the share's slow branch took all 24,576 sorted rows at once
+    # (``ops/moe.py:_ragged_share``), 1.96 since it takes them a
+    # rematerialised chunk at a time (the whole step 14.74 -> 13.63 GB)
+    memory = compiled.memory_analysis()
+    assert 1.8e9 < (memory.argument_size_in_bytes
+                    + memory.temp_size_in_bytes) < 2.2e9
+
+
 def test_flash_compiles_under_shard_map(topo):
     """Cell 3's layout: rows over "data", heads over "model" on a 2x2
     mesh, each shard's kernels taking their ranges from the local

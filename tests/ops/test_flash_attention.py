@@ -473,3 +473,121 @@ def test_the_saved_log_sum_exp_broadcasts_back_to_the_kernels_own(
     np.testing.assert_array_equal(np.asarray(res[-2]), np.asarray(out))
     np.testing.assert_array_equal(
         np.asarray(kept_out), np.asarray(out).transpose(0, 2, 1, 3))
+
+
+# ----------------------------------------------------------------------
+# A key wider than its value (latent attention)
+# ----------------------------------------------------------------------
+def _two_widths(rng, b, l, nq, nkv, hd, hv, n_segs=3):
+    q, k, _, seg = make_inputs(rng, b=b, l=l, nq=nq, nkv=nkv, hd=hd,
+                               n_segs=n_segs)
+    v = jnp.asarray(rng.standard_normal((b, l, nkv, hv)), jnp.float32)
+    return q, k, v, seg
+
+
+@pytest.mark.parametrize("nq,nkv,hd,hv,window", [
+    (16, 16, 192, 128, None), (4, 2, 24, 12, None), (4, 4, 64, 128, None),
+    (2, 2, 192, 128, 40)],
+    ids=["moonlight_16x192x128", "gqa_24x12", "value_wider", "windowed"])
+def test_kernels_at_two_widths_match_the_xla_mask(nq, nkv, hd, hv, window):
+    """Scores over a key ``hd`` wide, values and output ``hv`` wide
+    (Moonlight's 192 and 128 among them, 192 no multiple of the 128
+    lanes): the forward and all three gradients of the kernels, in
+    interpret mode, against the XLA path's explicit mask on packed
+    rows with padding; the scale is the KEY's width's."""
+    rng = np.random.default_rng(11)
+    q, k, v, seg = _two_widths(rng, 1, 128, nq, nkv, hd, hv, n_segs=2)
+    valid = jnp.where(seg[..., None, None] != 0, 1.0, 0.0)
+    w = jnp.asarray(rng.standard_normal((1, 128, nq, hv)), jnp.float32)
+
+    def loss(attn):
+        def f(q, k, v):
+            o = attn(q, k, v, seg, sliding_window=window)
+            assert o.shape == (1, 128, nq, hv)
+            return (o * valid * w).sum(), o
+        return f
+
+    (_, want), gr = jax.value_and_grad(
+        loss(packed_attention_xla), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    with pltpu.force_tpu_interpret_mode():
+        (_, got), gf = jax.value_and_grad(loss(functools.partial(
+            fa.flash_attention, block_q=64, block_k=64)),
+            argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    keep = np.asarray(seg) != 0
+    np.testing.assert_allclose(np.asarray(got)[keep], np.asarray(want)[keep],
+                               rtol=2e-3, atol=2e-3)
+    for a, b, name in zip(gr, gf, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=5e-3,
+                                   atol=5e-3, err_msg=f"d{name} mismatch")
+    # the scale: 192 ** -0.5, not the value's 128 ** -0.5
+    if (hd, hv) == (192, 128) and window is None:
+        other = packed_attention_xla(q, k, v, seg, scale=hv ** -0.5)
+        assert np.abs(np.asarray(other - want))[keep].max() > 0.05
+
+
+def test_equal_widths_keep_the_kernels_jaxprs():
+    """With the value as wide as the key the kernels are what they
+    were: the jaxpr of forward and gradients names no shape or
+    constant that the second width could have moved (the six accepted
+    cells' lowered programs are byte-equal to the parent's,
+    ``scripts/lowered_programs.py``); here: the residuals and every
+    ``pallas_call``'s operands carry ONE width."""
+    q, k, v = (jax.ShapeDtypeStruct((1, 256, n, 128), jnp.bfloat16)
+               for n in (4, 2, 2))
+    seg = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    text = str(jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, s).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v))(q, k, v, seg))
+    assert text.count("pallas_call") == 3
+    assert ",192]" not in text
+    wide = jax.ShapeDtypeStruct((1, 256, 2, 192), jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, s).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v))(
+        jax.ShapeDtypeStruct((1, 256, 4, 192), jnp.bfloat16), wide, v, seg))
+    assert "f32[1,4,256,192]" in text and "f32[1,4,256,128]" in text
+
+
+def test_the_kept_residuals_have_the_values_width():
+    """``flash_out`` is the VALUE's width (128 of Moonlight's 192): the
+    kept output head-major ``[B, nq, L, hv]``, q kept at the key's."""
+    rng = np.random.default_rng(13)
+    q, k, v, seg = _two_widths(rng, 1, 128, 2, 2, 24, 12)
+    with pltpu.force_tpu_interpret_mode():
+        out, res = fa._flash_attention_fwd(q, k, v, seg, 24 ** -0.5, True,
+                                           64, 64, None)
+    assert out.shape == (1, 128, 2, 12)
+    assert res[0].shape == (1, 128, 2, 24)       # q, the key's width
+    assert res[4].shape == (1, 2, 128, 12)       # flash_out
+    assert res[5].shape == (1, 2, 128)           # flash_lse
+
+
+def test_flash_takes_and_the_limit_speak_of_the_keys_width():
+    from realhf_tpu.ops import attention
+    assert attention.flash_takes(4096, key_dim=192)
+    assert not attention.flash_takes(4096, key_dim=32)
+    q = jnp.zeros((1, fa.FLASH_MAX_LEN + 128, 1, 192), jnp.bfloat16)
+    v = jnp.zeros((1, fa.FLASH_MAX_LEN + 128, 1, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="key's width of 192"):
+        fa.flash_attention(q, q, v, jnp.ones(q.shape[:2], jnp.int32))
+
+
+def test_the_dkv_pass_at_moonlights_widths_asks_for_more_vmem(monkeypatch):
+    """Q whole a head is 192 wide and dO 128: with the lane-broadcast
+    log-sum-exp and delta the dkv pass at rows of 4096 holds 17.3 MB
+    twice-buffered and asks for its own limit, as Laguna's does; the
+    forward and dq passes fit the default."""
+    limits = []
+    real = fa._vmem_limit
+    monkeypatch.setattr(fa, "_vmem_limit", lambda *a: limits.append(
+        real(*a)) or limits[-1])
+    q, k = (jax.ShapeDtypeStruct((1, 4096, 16, 192), jnp.bfloat16),) * 2
+    v = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, s).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v))(q, k, v, seg)
+    fwd, dq, dkv = limits
+    assert fwd is None and dq is None
+    assert fa.DEFAULT_SCOPED_VMEM < dkv < 2 * fa.DEFAULT_SCOPED_VMEM

@@ -360,7 +360,7 @@ def held_leaves(m, first, count):
             for k, v in m.items()}
 
 
-def sigmoid_oracle(m, x, norm=True, scaling=1.0, bias=True):
+def sigmoid_oracle(m, x, norm=True, scaling=1.0, bias=True, eps=1e-6):
     """The layer by a loop over tokens and their chosen experts."""
     xt = np.asarray(x, np.float64)[0]
     s = 1 / (1 + np.exp(-(xt @ np.asarray(m["router"], np.float64))))
@@ -370,7 +370,7 @@ def sigmoid_oracle(m, x, norm=True, scaling=1.0, bias=True):
         idx = np.argsort(choice[t])[::-1][:4]
         g = s[t][idx]
         if norm:
-            g = g / (g.sum() + 1e-6)
+            g = g / (g.sum() + eps)
         for gate, e in zip(g * scaling, idx):
             a = xt[t] @ np.asarray(m["wg"], np.float64)[e]
             u = xt[t] @ np.asarray(m["wu"], np.float64)[e]
@@ -538,11 +538,16 @@ def test_every_row_of_a_shares_grouped_products_lies_in_a_group(
         return real(lhs, rhs, group_sizes, **kw)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", checked)
+    # (the fallback's chunks are rematerialised: not here, where the
+    # group sizes have to be numbers)
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
     with jax.disable_jit():
         moe_ops.moe_mlp_with_losses(share_cfg(held=(4, 2)),
                                     held_leaves(m, 4, 2), x)
-    rows = 64 * 4 if pull else 2 * 64 * 4 * 2 // 16
-    assert seen == [(rows, rows)] * 3
+    # the fast path's rows once; the fallback all 64 x 4 sorted rows,
+    # that many at a time (three products a chunk)
+    rows = 2 * 64 * 4 * 2 // 16
+    assert seen == [(rows, rows)] * 3 * (64 * 4 // rows if pull else 1)
 
 
 # ----------------------------------------------------------------------
@@ -616,3 +621,98 @@ def test_sixteen_shares_and_the_shared_expert_once_add_up_to_the_layer():
                                atol=2e-6)
     assert np.abs(naive - np.asarray(whole)[0]).max() > 1.0
     assert pairs == 40 * 4
+
+
+def test_eight_shares_and_the_shared_experts_once_add_up_to_the_layer():
+    """Moonlight's router and its deployment at a small size: sigmoid
+    scores, the k chosen by score + selection bias, gates over (their
+    sum + 1e-20) times 2.446, the two shared experts as ONE SwiGLU
+    every rank holds. Eight ranks of two experts each: their ROUTED
+    parts added up, plus the shared experts counted once, are what the
+    layer gives with all sixteen held, and what a loop over tokens and
+    their chosen experts gives."""
+    m, x = share_layer(seed=7)
+    shared = shared_leaves(width=2 * 24)  # n_shared_experts x the width
+    kw = dict(shared_intermediate_dim=48, routed_scaling_factor=2.446,
+              norm_topk_eps=1e-20)
+    whole, _ = moe_ops.moe_mlp_with_losses(
+        share_cfg(**kw), {**m, "shared": shared}, x)
+    once = swiglu_oracle(shared, x)
+    routed, naive, pairs = 0.0, 0.0, 0.0
+    for rank in range(8):
+        part, aux = moe_ops.moe_mlp_with_losses(
+            share_cfg(held=(2 * rank, 2), **kw),
+            {**held_leaves(m, 2 * rank, 2), "shared": shared}, x)
+        routed = routed + (np.asarray(part, np.float64)[0] - once)
+        naive = naive + np.asarray(part, np.float64)[0]
+        pairs += float(aux[moe_ops.HELD_PAIRS_STAT])
+    np.testing.assert_allclose(routed + once, np.asarray(whole)[0],
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(whole)[0],
+        sigmoid_oracle(m, x, scaling=2.446, eps=1e-20) + once,
+        rtol=2e-4, atol=2e-6)
+    # the shares as they are count the shared experts eight times
+    np.testing.assert_allclose(naive - np.asarray(whole)[0], 7 * once,
+                               rtol=2e-4, atol=2e-5)
+    assert pairs == 40 * 4
+    # the bias moves the choice and nothing else: without it other
+    # experts are chosen for some token
+    unbiased, _ = moe_ops.moe_mlp_with_losses(
+        share_cfg(**kw), {**m, "shared": shared,
+                          "expert_bias": jnp.zeros((16,))}, x)
+    assert np.abs(np.asarray(unbiased - whole)).max() > 1e-2
+
+
+@pytest.mark.parametrize("tokens,pulled,count", [
+    (64, 64, 2), (64, 40, 2), (50, 50, 3)],
+    ids=["every_chunk_full", "pairs_end_inside_a_chunk",
+         "rows_do_not_divide"])
+def test_the_slow_path_in_chunks_is_the_held_experts_part(tokens, pulled,
+                                                          count):
+    """The fallback takes all ``T x k`` sorted rows ``share_rows`` at a
+    time in a rematerialised scan: result AND gradients (of the input,
+    the router and each held expert's matrices) are those of the same
+    experts alone in the uncut layer, where an expert's pairs straddle
+    two chunks, where the held pairs end inside a chunk, and where the
+    last chunk runs past ``T x k`` (50 x 4 = 200 rows in three chunks
+    of 75)."""
+    m, x = share_layer(seed=4, tokens=tokens)
+    cfg = share_cfg(held=(4, count))
+    rows = moe_ops.share_rows(cfg, tokens)
+    assert rows < tokens * 4 and (tokens * 4 % rows == 0) == (count == 2)
+    sign = np.zeros(16, np.float32)
+    sign[[4, 5, 9, 13]], sign[[0, 1, 2, 3]] = 1.0, -1.0
+    m = dict(m, expert_bias=jnp.zeros(16),
+             router=m["router"] * 0.05 + jnp.zeros((32, 16)).at[-1].set(
+                 6.0 * sign))  # (no saturated sigmoid: the router learns)
+    x = x.at[0, :, -1].set(jnp.where(jnp.arange(tokens) < pulled, 1.0,
+                                     -1.0))
+    held = slice(4, 4 + count)
+
+    def share(m_, x_):
+        out, aux = moe_ops.moe_mlp_with_losses(
+            cfg, held_leaves(m_, 4, count), x_)
+        return (out * jnp.cos(jnp.arange(32.0))).sum(), aux
+
+    def alone(m_, x_):
+        only = dict(m_, wd=m_["wd"].at[:4].set(0).at[4 + count:].set(0))
+        return (moe_ops.moe_mlp_with_losses(share_cfg(), only, x_)[0]
+                * jnp.cos(jnp.arange(32.0))).sum()
+
+    (got, aux), grads = jax.value_and_grad(share, argnums=(0, 1),
+                                           has_aux=True)(m, x)
+    assert float(aux[moe_ops.SHARE_OVERFLOW_STAT]) == 1
+    assert float(aux[moe_ops.HELD_PAIRS_STAT]) == 2 * pulled > rows
+    want, want_grads = jax.value_and_grad(alone, argnums=(0, 1))(m, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(grads[1]),
+                               np.asarray(want_grads[1]), rtol=1e-4,
+                               atol=1e-6)
+    for leaf in ("router", "wg", "wu", "wd"):
+        g, w = np.asarray(grads[0][leaf]), np.asarray(want_grads[0][leaf])
+        if leaf != "router":  # the held experts' own matrices
+            g, w = g[held], w[held]
+        assert np.abs(w).max() > 0, leaf
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=1e-6,
+                                   err_msg=leaf)
