@@ -172,6 +172,12 @@ class Engine:
                     f"expert_parallel needs num_experts "
                     f"({cfg.moe.num_experts}) divisible by "
                     f"data_parallel_size ({ctx.dp_size}).")
+        elif moe_ops.dispatch_mode(cfg) == "ragged" and self.mesh.size > 1:
+            # the experts' grouped products are Pallas kernels where
+            # the stacks lie whole on the device; over a mesh a bare
+            # pallas_call would gather them, so they stay
+            # lax.ragged_dot, which GSPMD partitions (ops/moe.py)
+            self._moe_constraint = moe_ops.SHARDED_STACKS
 
         self._param_shardings = shard_rules.param_shardings(cfg, self.mesh)
         # Megatron-style vocab padding so wte/head shard over tp even
@@ -493,20 +499,32 @@ class Engine:
         ``attn_proj_remat_products`` (``obs.parts.count_products``:
         the attention projections' products the backward runs a second
         time; k's and v's where the blocks keep what the two wide ones
-        made, 4 a layer where they keep nothing), and of a
-        generate program :meth:`_decode_facts`. Sets gauge
+        made, 4 a layer where they keep nothing), of a
+        generate program :meth:`_decode_facts`, and of every program
+        of a model in the ragged dispatch mode ``moe_products``,
+        ``moe_gmm_calls``, ``moe_ragged_dot_calls``
+        (``ops.moe.grouped_product_calls``: which grouped matmul the
+        experts' products really are). Sets gauge
         ``engine_program_bytes{role,program,kind}``."""
         if (name, key) not in self._facts:
             if name == "generate":
-                derive = self._decode_facts(key)
+                mine = self._decode_facts(key)
             elif name.startswith("train"):
-                def derive(text):
+                def mine(text):
                     return dict(
                         flash_fwd_per_bwd=flash_fwd_per_bwd(text),
                         attn_proj_remat_products=parts.count_products(
                             text, parts.ATTN_PROJ, parts.REMAT))
             else:
-                derive = None
+                mine = None
+            ragged = moe_ops.dispatch_mode(self.cfg) == "ragged"
+
+            def derive(text):
+                out = mine(text) if mine is not None else {}
+                if ragged:
+                    out.update(moe_ops.grouped_product_calls(text))
+                return out
+
             facts = parts.read_program(self._compiled(name, call), derive)
             self._facts[(name, key)] = facts
             role = str(self.ctx.model_name.role)

@@ -21,6 +21,7 @@ from typing import Any, Dict
 KERNELS = (
     "flash_attention",               # ops/flash_attention.py (packed fwd/bwd)
     "flash_decode_attention_stacked",  # ops/decode_attention.py
+    "grouped_matmul",                # ops/grouped_matmul.py (the experts')
 )
 
 

@@ -9,12 +9,14 @@ reference's layout) and can shard the expert axis for true EP.
 
 Three dispatch modes (``dispatch_mode``):
 - ``ragged`` (``capacity_factor=None`` + ``use_grouped_gemm``, the
-  default): (token, k) pairs sorted by expert feed one
-  ``jax.lax.ragged_dot`` a projection, a grouped GEMM over the
-  stacked weights (the equivalent of reference experts.py:98
-  GroupedMLP; XLA:TPU lowers it to a grouped-matmul kernel of its
-  own, PERF.md), then a float32 scatter-add back to the tokens.
-  Exact (no token dropping), top-k cost only.
+  default): (token, k) pairs sorted by expert feed one grouped GEMM a
+  projection over the stacked weights (the equivalent of reference
+  experts.py:98 GroupedMLP), then a float32 scatter-add back to the
+  tokens. Exact (no token dropping), top-k cost only. The grouped
+  GEMM is ``ops/grouped_matmul.py``'s Pallas kernels where they
+  engage (``_grouped_products``: a TPU, the expert stacks whole on
+  the device), else ``jax.lax.ragged_dot`` (XLA:TPU lowers it to a
+  grouped-matmul kernel of its own, PERF.md).
 - ``dense`` (``capacity_factor=None`` + ``use_grouped_gemm=False``):
   every expert sees every token through one batched einsum a
   projection, weighted by its gate (exact; E/topk times the FLOPs;
@@ -38,7 +40,9 @@ give, in the ragged mode only (``_ragged_share``). The (token, k)
 pairs are sorted with the held experts' first; the first ``rows`` of
 them are gathered, multiplied in ``count`` groups and scattered back;
 pairs of absent experts are never multiplied: what those experts would
-have added is left out. ``rows`` is ``SHARE_ROWS_OVER_MEAN`` times the
+have added is left out (the kernels visit the held pairs' row tiles
+alone; ``lax.ragged_dot`` multiplies all ``rows``, the rest zeroed).
+``rows`` is ``SHARE_ROWS_OVER_MEAN`` times the
 pairs even routing would bring the held experts; where a batch routes
 MORE to them, a ``lax.cond`` takes the same path over all ``T x k``
 sorted rows instead, ``rows`` of them at a time (a rematerialised scan:
@@ -62,8 +66,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from realhf_tpu.base.backend import pallas_enabled
 from realhf_tpu.models.config import MoEConfig, TransformerConfig
 from realhf_tpu.obs import parts as P
+from realhf_tpu.ops.grouped_matmul import GMM as GMM_KERNEL, grouped_matmul
+from realhf_tpu.ops.hlo_text import device_instructions
 
 
 #: key, in the layer's auxiliary dict, of the one entry that is a
@@ -220,17 +227,69 @@ def dispatch_mode(cfg: TransformerConfig) -> Optional[str]:
     return "ragged" if cfg.moe.use_grouped_gemm else "dense"
 
 
+#: ``ep_constraint`` of a model whose expert stacks do NOT lie whole on
+#: each device (the engine's word for a tensor- or data-parallel mesh):
+#: a bare ``pallas_call`` has no partitioning rule, so the grouped
+#: products stay ``lax.ragged_dot``, which GSPMD partitions
+SHARDED_STACKS = "sharded_stacks"
+
+
+def _grouped_products(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray,
+                      sizes: jnp.ndarray, kernel: bool,
+                      every_row_covered: bool = False) -> jnp.ndarray:
+    """The three grouped products of the sorted rows ``xs [rows, H]``
+    through the stacks of ``m``: ``sizes`` are the rows each expert's
+    group covers, and may add up to less than ``rows``. What becomes
+    of the rows past them differs by path, so the group sizes each
+    path is handed are built HERE: ``ops/grouped_matmul.py``'s kernels
+    (``kernel``) return those rows as zero and do not multiply them;
+    ``lax.ragged_dot`` leaves them UNWRITTEN on the chip, in the
+    backward's products too (PERF.md, PR 31), so there they are
+    counted into the last group: every row lies in a group (the
+    caller zeroes them on the way in and gives them no gate).
+    ``every_row_covered``: the caller's sizes add up to the rows."""
+    from realhf_tpu.models.transformer import _activation
+    cdt = xs.dtype
+    if kernel:
+        dot = grouped_matmul
+    else:
+        dot = jax.lax.ragged_dot
+        if not every_row_covered:
+            sizes = sizes.at[-1].add(xs.shape[0] - sizes.sum())
+    gate = dot(xs, m["wg"].astype(cdt), sizes)
+    up = dot(xs, m["wu"].astype(cdt), sizes)
+    return dot(_activation(cfg, gate) * up, m["wd"].astype(cdt), sizes)
+
+
+def grouped_product_calls(hlo_text: str) -> Dict[str, object]:
+    """Which grouped matmul a compiled program's experts really run,
+    from its optimized text (``Engine.compiled_text``): the custom
+    calls that are ``ops/grouped_matmul.py``'s kernels
+    (``moe_gmm_calls``: twelve a sparse layer of a train program, 3
+    forward, 3 rematerialised, 3 + 3 backward), those that are the
+    compiler's own ``ragged-dot`` kernel (``moe_ragged_dot_calls``;
+    XLA:CPU has no such call: 0 there), and ``moe_products``: ``gmm``
+    where the program holds a kernel, else ``ragged_dot``. A loop's
+    body counts once."""
+    calls = [name for name, _, opcode in device_instructions(hlo_text)
+             if opcode == "custom-call"]
+    gmm = sum(GMM_KERNEL in name for name in calls)
+    return dict(moe_products="gmm" if gmm else "ragged_dot",
+                moe_gmm_calls=gmm,
+                moe_ragged_dot_calls=sum("ragged-dot" in name
+                                         for name in calls))
+
+
 def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
                 top_probs: jnp.ndarray, top_idx: jnp.ndarray,
-                group_sizes: jnp.ndarray) -> jnp.ndarray:
-    """Grouped-GEMM dispatch: sort (token, k) pairs by expert, run
-    ``jax.lax.ragged_dot`` per projection over the stacked [E, H, F]
-    weights, scatter-add gate-weighted outputs back. Exact top-k MoE
-    (reference GroupedMLP, experts.py:98) with static shapes."""
-    from realhf_tpu.models.transformer import _activation
+                group_sizes: jnp.ndarray, kernel: bool) -> jnp.ndarray:
+    """Grouped-GEMM dispatch: sort (token, k) pairs by expert, run one
+    grouped product per projection over the stacked [E, H, F] weights
+    (``_grouped_products``), scatter-add gate-weighted outputs back.
+    Exact top-k MoE (reference GroupedMLP, experts.py:98) with static
+    shapes."""
     t, h = xt.shape
     k = cfg.moe.top_k
-    cdt = xt.dtype
 
     with jax.named_scope(P.GATHER):
         order = jnp.argsort(top_idx.reshape(-1))      # sort by expert
@@ -238,10 +297,8 @@ def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
         xs = xt[tok_idx]                              # [T*k, H] sorted
 
     with jax.named_scope(P.PRODUCTS):
-        gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), group_sizes)
-        up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), group_sizes)
-        down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
-                                  m["wd"].astype(cdt), group_sizes)
+        down = _grouped_products(cfg, m, xs, group_sizes, kernel,
+                                 every_row_covered=True)
     with jax.named_scope(P.COMBINE):
         gates_sorted = top_probs.reshape(-1)[order]   # pads carry 0
         weighted = down.astype(jnp.float32) * gates_sorted[:, None]
@@ -249,16 +306,20 @@ def _ragged_moe(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
 
 
 #: rows the fast path of a share gathers, over the pairs that even
-#: routing would bring its experts (T x k x held / E). XLA:TPU's
+#: routing would bring its experts (T x k x held / E): the bound of
+#: the GATHER and of the scatter-add (a bounded number of rows spares
+#: three quarters of both), and of the grouped products' operands; no
+#: longer of what is multiplied: ``ops/grouped_matmul.py``'s kernels
+#: visit the row tiles the held pairs cover and return the rest as
+#: zero. Where ``lax.ragged_dot`` runs instead (``_grouped_products``)
+#: every gathered row still lies in a group and is multiplied: XLA:TPU's
 #: grouped matmul does skip the row tiles past its last group (16,384
 #: sorted rows of which 8 groups cover 2,048 cost what 2,048 rows
 #: alone do), but it leaves those rows UNWRITTEN, zero only by chance,
 #: in the forward and in the backward's products alike: with group
 #: sizes over the held pairs alone the cell's forward agreed with the
 #: reference and its gradient norm read 185,709 against 0.78, the loss
-#: standing still (PERF.md, PR 31). So every gathered row lies in a
-#: group, and gathering a bounded number of rows first also spares
-#: three quarters of the gather and of the scatter-add.
+#: standing still (PERF.md, PR 31).
 SHARE_ROWS_OVER_MEAN = 2
 
 
@@ -273,23 +334,22 @@ def share_rows(cfg: TransformerConfig, t: int) -> int:
 
 def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
                   top_probs: jnp.ndarray, top_idx: jnp.ndarray,
-                  held_sizes: jnp.ndarray) -> jnp.ndarray:
+                  held_sizes: jnp.ndarray, kernel: bool) -> jnp.ndarray:
     """``_ragged_moe`` for a rank that holds experts ``first .. first
     + count - 1`` (``m``'s stacks are theirs alone; ``held_sizes``
     [count] their loads): the pairs sorted with the held experts'
     first, in the stacks' order, and only the first ``rows`` of them
     gathered, multiplied and scattered back. Rows past the held pairs
-    are zeroed on the way in and counted into the last group, so that
-    every row lies in a group (``lax.ragged_dot`` leaves a row that
-    no group covers undefined on the chip: ``SHARE_ROWS_OVER_MEAN``)
-    and adds nothing, to the result or to a gradient. ``rows`` is
-    static: the fast path takes the first ``rows`` sorted rows, the
-    slow path all ``T x k`` in chunks of ``rows`` (``every_row``)."""
-    from realhf_tpu.models.transformer import _activation
+    are zeroed on the way in, get no gate and lie in no expert's group:
+    what the grouped products make of them is ``_grouped_products``'s
+    to say (the kernels: zero, unmultiplied; ``lax.ragged_dot``: the
+    last group's, multiplied) and adds nothing, to the result or to a
+    gradient. ``rows`` is static: the fast path takes the first
+    ``rows`` sorted rows, the slow path all ``T x k`` in chunks of
+    ``rows`` (``every_row``)."""
     t, h = xt.shape
     k, e = cfg.moe.top_k, cfg.moe.num_experts
     first, _ = cfg.moe.experts_held
-    cdt = xt.dtype
     with jax.named_scope(P.GATHER):
         order = jnp.argsort(((top_idx - first) % e).reshape(-1))
         n_held = held_sizes.sum()
@@ -304,22 +364,17 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
             if at is None:
                 sel = order[:rows]
                 mine = jnp.arange(rows) < n_held
-                sizes = held_sizes.at[-1].add(rows - n_held)
+                sizes = held_sizes
             else:
                 sel = jax.lax.dynamic_slice_in_dim(padded, at, rows)
                 mine = at + jnp.arange(rows) < n_held
-                # each held expert's pairs inside the chunk; what is
-                # left of the chunk goes, zeroed, to the last group
+                # each held expert's pairs inside the chunk
                 ends = jnp.clip(jnp.cumsum(held_sizes), at, at + rows) - at
                 sizes = jnp.diff(ends, prepend=0)
-                sizes = sizes.at[-1].add(rows - ends[-1])
             tok_idx = sel // k
             xs = jnp.where(mine[:, None], xt[tok_idx], 0)
         with jax.named_scope(P.PRODUCTS):
-            gate = jax.lax.ragged_dot(xs, m["wg"].astype(cdt), sizes)
-            up = jax.lax.ragged_dot(xs, m["wu"].astype(cdt), sizes)
-            down = jax.lax.ragged_dot(_activation(cfg, gate) * up,
-                                      m["wd"].astype(cdt), sizes)
+            down = _grouped_products(cfg, m, xs, sizes, kernel)
         with jax.named_scope(P.COMBINE):
             gates = jnp.where(mine, gates_flat[sel], 0.0)
             weighted = down.astype(jnp.float32) * gates[:, None]
@@ -362,7 +417,10 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     ``ep_constraint`` (models/sharding.py moe_ep_constraint) pins the
     expert-major intermediates to the expert-parallel axis so GSPMD
     lowers dispatch/combine to all-to-alls; requires the capacity or
-    dense dispatch mode."""
+    dense dispatch mode. In the ragged mode it is None, or
+    ``SHARDED_STACKS`` where the expert stacks are sharded over a
+    mesh: the grouped products then stay ``lax.ragged_dot``; without
+    it they are the Pallas kernels wherever ``pallas_enabled()``."""
     moe = cfg.moe
     if moe.input_jitter_eps and rng is None:
         raise NotImplementedError(
@@ -396,7 +454,8 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
             f"experts_held={held} of {e} needs the ragged dispatch "
             f"mode, not {mode!r}")
     if mode == "ragged":
-        if ep_constraint is not None:
+        kernel = ep_constraint != SHARDED_STACKS and pallas_enabled()
+        if callable(ep_constraint):
             raise ValueError(
                 "expert_parallel requires the capacity or dense "
                 "dispatch mode; ragged grouped GEMMs cannot shard the "
@@ -404,10 +463,11 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                 "use_grouped_gemm=False).")
         if held is None:
             out = _ragged_moe(cfg, m, xt.astype(x.dtype), top_probs,
-                              top_idx, load)
+                              top_idx, load, kernel)
         else:
             out = _ragged_share(cfg, m, xt.astype(x.dtype), top_probs,
-                                top_idx, load[held[0]:held[0] + held[1]])
+                                top_idx, load[held[0]:held[0] + held[1]],
+                                kernel)
     elif mode == "dense":
         # Dense mode: every expert over all tokens, gate-weighted.
         xs = ep(jnp.broadcast_to(xt[None], (e, t, h)).astype(x.dtype))

@@ -40,19 +40,26 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   and gradient of one 4,096-token SFT microbatch against the fast
   path's (on the chip a grouped product leaves a row no group covers
   unwritten, in the backward too: PERF.md, PR 31).
+- ``uncovered_rows`` (``deepseek_v3``): the same microbatch with the
+  rows of every grouped product that lie past its groups POISONED
+  (NaN in the operands and in the cotangent) against the step as it
+  is: ``ops/grouped_matmul.py``'s kernels return those rows as zero
+  whatever lies there, so loss and gradient are equal; PR 31's
+  failure (the rows left unwritten), which the CPU's zero hides.
 - ``decode``: a prefill, then ``decode_step``s through the caches,
   teacher-forced, bf16, against the reference's full forward
   (log-probabilities, not tokens). ``laguna``: 2 sequences of 768, a
   prefill of 640: every decoded token's window (512) ends inside the
   cache, which keeps every row in window layers too.
-- ``ragged`` (``lfm2_moe`` only): ``lax.ragged_dot`` of 16,384 sorted
-  rows of 2048 against 8 experts of 2048 x 1536 with group sizes that
-  cover 2,048 rows (an eighth: the cell), 8,192 and all 16,384, of
-  2,048 and 4,096 rows alone, and of 4,096 rows whose last group takes
-  in 2,048 rows that belong to no expert: milliseconds a call, every
-  case compiled and warmed before any is timed. The first costs what
-  2,048 rows alone do: row tiles past the last group are skipped, and
-  left UNWRITTEN (PERF.md, PR 31).
+- ``ragged`` (``lfm2_moe``, or any family with ``--only-ragged``):
+  ``ops/grouped_matmul.py``'s kernels beside ``lax.ragged_dot``,
+  forward and both gradients, at the four sparse cells' shapes with
+  the groups covering all rows (``even``, ``uneven``) and half of
+  them: milliseconds a call and TFLOP/s of the covered rows, every
+  case compiled and warmed before any is timed. XLA's kernel skips the
+  row tiles past its last group and leaves them UNWRITTEN (PERF.md, PR
+  31), so the layer hands it every row; the repo's kernels visit the
+  covered tiles alone and return the rest as zero.
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
   their attributes.
@@ -95,7 +102,7 @@ FAMILIES = {
         cell="moonlight-16b-a3b-l5-ep8.sft-4k",
         tiny=("deepseek_v3", "tiny-deepseek-v3.sft"), wrong_keys={},
         packed_docs=(1536, 1024, 1024, 512), decode=(2, 768, 640),
-        exact_doc=4096, slow_path=True),
+        exact_doc=4096, slow_path=True, uncovered_rows=True),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -126,60 +133,113 @@ def one_chip_engine(ckpt, dtype="bfloat16"):
     return Engine(cfg, ctx, params)
 
 
-def ragged(hf, calls=20, rounds=3):
-    """Does the grouped matmul pay for rows no group covers? Every
-    case is compiled and warmed first, then timed ``rounds`` times in
-    turn (the least is kept): rows, the rows its 8 groups cover, and
-    how the covered rows are split (``even``; ``uneven``: seeded
-    random sizes; ``last``: 256 rows a group and the rest in the last
-    one, as ``ops/moe.py:_ragged_share`` counts the rows that belong to
-    no held expert)."""
+#: the grouped products' shapes in the four sparse cells: the sorted
+#: rows a product takes, hidden, expert width, groups (cell 4 holds all
+#: 64 experts: every row covered; cells 5 to 7 gather twice the rows
+#: even routing brings their held experts)
+RAGGED_SHAPES = {
+    "olmoe": (16384, 2048, 1024, 64),
+    "lfm2": (4096, 2048, 1536, 8),
+    "laguna": (4096, 2048, 512, 16),
+    "moonlight": (6144, 2048, 1408, 8),
+}
+
+
+def ragged(calls=4, inner=10, rounds=3, row_tiles=(None,)):
+    """``ops/grouped_matmul.py``'s kernels beside ``lax.ragged_dot``:
+    forward, gradient of the rows and gradient of the weights, each a
+    program of its own, at the four cells' shapes (up / gate: rows x
+    hidden x width; down: rows x width x hidden), bf16, with the
+    groups covering ALL rows (``even``; ``uneven``: seeded random
+    sizes) and HALF of them (what a share's fast path hands them:
+    ``lax.ragged_dot`` is then timed as the layer calls it, the other
+    half counted into the last group). A program runs the product
+    ``inner`` times in a loop on the device, each turn's group sizes
+    waiting on the turn before (one dispatch of a jitted call costs
+    the host 0.2 ms here, more than most of these products: PR 38's
+    first table read that floor). Every case is compiled and warmed
+    first, then timed ``rounds`` times in turn (the least is kept):
+    milliseconds a product and TFLOP/s of the COVERED rows.
+    ``row_tiles``: the kernels' row tile to time them at (None: the
+    file's own; a builder's sweep)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    h, f, e = hf["hidden_size"], hf["moe_intermediate_size"], 8
-    key = jax.random.PRNGKey(0)
-    w = jax.random.normal(key, (e, h, f), jnp.bfloat16)
-    dot = jax.jit(jax.lax.ragged_dot)
-    rng = np.random.default_rng(0)
+    from realhf_tpu.ops import grouped_matmul as gm
 
-    def sizes_of(covered, split):
+    rng = np.random.default_rng(0)
+    key = jax.random.PRNGKey(0)
+
+    def sizes_of(covered, e, split):
         if split == "even":
             return np.full(e, covered // e)
-        if split == "last":
-            return np.array([256] * (e - 1) + [covered - 256 * (e - 1)])
         cuts = np.sort(rng.integers(0, covered + 1, e - 1))
         return np.diff(np.concatenate([[0], cuts, [covered]]))
 
+    def looped(product):
+        def turn(_, carry):
+            x, w, ct, s = carry
+            y = product(x, w, ct, s)
+            # never true, and not known before the product has run
+            return x, w, ct, s + (y.reshape(-1)[0] > 3e38).astype(s.dtype)
+        return jax.jit(lambda *a: jax.lax.fori_loop(0, inner, turn, a)[3])
+
+    def xla(which):
+        dot = jax.lax.ragged_dot
+        return looped({
+            "fwd": lambda x, w, ct, s: dot(x, w, s),
+            "d_rows": lambda x, w, ct, s: jax.vjp(
+                lambda x: dot(x, w, s), x)[1](ct)[0],
+            "d_weights": lambda x, w, ct, s: jax.vjp(
+                lambda w: dot(x, w, s), w)[1](ct)[0]}[which])
+
+    def kernels(which):
+        return looped({
+            "fwd": lambda x, w, ct, s: gm._gmm(x, w, s, False),
+            "d_rows": lambda x, w, ct, s: gm._gmm(ct, w, s, True),
+            "d_weights": lambda x, w, ct, s: gm._tgmm(x, ct, s)}[which])
+
     cases = {}
-    for rows, covered, split in (
-            (16384, 2048, "even"), (16384, 2048, "uneven"),
-            (16384, 8192, "uneven"), (16384, 16384, "even"),
-            (16384, 16384, "uneven"), (2048, 2048, "even"),
-            (2048, 2048, "uneven"), (4096, 4096, "even"),
-            (4096, 4096, "uneven"), (4096, 4096, "last"),
-            (4096, 2048, "uneven")):
-        x = jax.random.normal(key, (rows, h), jnp.bfloat16)
-        sizes = jnp.asarray(sizes_of(covered, split), jnp.int32)
-        y = jax.block_until_ready(dot(x, w, sizes))
-        cases[f"rows{rows}_covered{covered}_{split}"] = dict(
-            args=(x, w, sizes), ms=[], covered=covered,
-            rows_past_last_group_are_zero=bool(
-                (np.asarray(y[covered:].astype(jnp.float32)) == 0).all()))
+    own_tile = gm.ROW_TILE
+    for cell, (rows, h, f, e) in RAGGED_SHAPES.items():
+        for proj, (k, n) in (("up", (h, f)), ("down", (f, h))):
+            x = jax.random.normal(key, (rows, k), jnp.bfloat16)
+            w = jax.random.normal(key, (e, k, n), jnp.bfloat16)
+            ct = jax.random.normal(key, (rows, n), jnp.bfloat16)
+            for which in ("fwd", "d_rows", "d_weights"):
+                fns = {"ragged_dot": (None, xla(which))}
+                for tile in row_tiles:
+                    fns["kernel" if tile is None
+                        else f"kernel_tile{tile}"] = (tile, kernels(which))
+                for split, covered in (("even", rows), ("uneven", rows),
+                                       ("even", rows // 2),
+                                       ("uneven", rows // 2)):
+                    held = sizes_of(covered, e, split)
+                    every = held.copy()
+                    every[-1] += rows - covered
+                    for name, (tile, fn) in fns.items():
+                        gm.ROW_TILE = tile or own_tile
+                        sizes = jnp.asarray(
+                            every if name == "ragged_dot" else held,
+                            jnp.int32)
+                        jax.block_until_ready(fn(x, w, ct, sizes))
+                        cases[f"{cell}_{proj}_{which}_covered{covered}"
+                              f"_{split}_{name}"] = dict(
+                            fn=fn, args=(x, w, ct, sizes), ms=[],
+                            flops=2 * covered * k * n)
+    gm.ROW_TILE = own_tile
     for _ in range(rounds):
         for case in cases.values():
             t = time.monotonic()
             for _ in range(calls):
-                y = dot(*case["args"])
+                y = case["fn"](*case["args"])
             jax.block_until_ready(y)
-            case["ms"].append((time.monotonic() - t) / calls * 1e3)
-    say(phase="ragged", **{
-        name: dict(ms=min(c["ms"]), ms_rounds=c["ms"],
-                   tflops_of_covered_rows=2 * c["covered"] * h * f
-                   / min(c["ms"]) / 1e9,
-                   rows_past_last_group_are_zero=c[
-                       "rows_past_last_group_are_zero"])
+            case["ms"].append(
+                (time.monotonic() - t) / (calls * inner) * 1e3)
+    say(phase="ragged", row_tile=own_tile, products_a_program=inner, **{
+        name: dict(ms=min(c["ms"]),
+                   tflops_of_covered_rows=c["flops"] / min(c["ms"]) / 1e9)
         for name, c in cases.items()})
 
 
@@ -320,38 +380,56 @@ def exact(cell, ckpt, tensors, doc):
         secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
 
 
-def slow_path(cell, ckpt, ids, want, fast, seed, divide=4):
-    """The share's fallback forced (``share_rows`` cut to a ``divide``th
-    so the held pairs pass it): the engine's log-probabilities on the
-    fixed batch, and one SFT microbatch's loss and gradient against the
-    fast path's."""
+def sft_microbatch(hf, seed):
+    """One SFT microbatch at the cell's row length, from the seed."""
+    import jax.numpy as jnp
+    import numpy as np
+    row = min(hf.get("max_position_embeddings", 4096), 4096)
+    rng = np.random.default_rng(seed + 5)
+    return dict(input_ids=jnp.asarray(rng.integers(
+        0, hf["vocab_size"], (1, row)), jnp.int32),
+        seg_ids=jnp.ones((1, row), jnp.int32),
+        prompt_mask=jnp.arange(row)[None] < row // 8)
+
+
+def loss_and_gradient(engine, mb):
+    """``(loss, the gradient as one float32 vector, statistics)`` of
+    one rematerialised SFT microbatch through the engine's own
+    objective, compiled anew (what ``ops/moe.py`` holds NOW is what
+    runs)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from realhf_tpu.interfaces import sft
+
+    engine.cfg.gradient_checkpointing = True
+    objective = engine._objective(sft._make_loss_fn(engine.cfg))
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        objective, has_aux=True))(engine.params, mb)
+    flat = jnp.concatenate([g.astype(jnp.float32).reshape(-1)
+                            for g in jax.tree.leaves(grads)])
+    return float(loss), np.asarray(flat), stats
+
+
+def slow_path(cell, ckpt, ids, want, fast, seed, divide=4):
+    """The share's fallback forced (``share_rows`` cut to a ``divide``th
+    so the held pairs pass it): the engine's log-probabilities on the
+    fixed batch, and one SFT microbatch's loss and gradient against the
+    fast path's."""
+    import numpy as np
+
     from realhf_tpu.ops import moe as moe_ops
 
     family, hf = cell["family"], cell["hf"]
     real = moe_ops.share_rows
-    row = min(hf.get("max_position_embeddings", 4096), 4096)
-    rng = np.random.default_rng(seed + 5)
-    mb = dict(input_ids=jnp.asarray(rng.integers(
-        0, hf["vocab_size"], (1, row)), jnp.int32),
-        seg_ids=jnp.ones((1, row), jnp.int32),
-        prompt_mask=jnp.arange(row)[None] < row // 8)
+    mb = sft_microbatch(hf, seed)
 
     def reading(engine):
         got = np.asarray(engine.forward_logprobs(ids, np.ones_like(ids)),
                          np.float32)[:, :-1]
-        engine.cfg.gradient_checkpointing = True
-        objective = engine._objective(sft._make_loss_fn(engine.cfg))
-        (loss, stats), grads = jax.jit(jax.value_and_grad(
-            objective, has_aux=True))(engine.params, mb)
-        flat = jnp.concatenate([g.astype(jnp.float32).reshape(-1)
-                                for g in jax.tree.leaves(grads)])
-        return got, float(loss), np.asarray(flat), \
-            float(stats[moe_ops.SHARE_OVERFLOW_STAT])
+        loss, flat, stats = loss_and_gradient(engine, mb)
+        return got, loss, flat, float(stats[moe_ops.SHARE_OVERFLOW_STAT])
 
     t = time.monotonic()
     _, loss_f, grad_f, over_f = reading(fast)
@@ -370,6 +448,61 @@ def slow_path(cell, ckpt, ids, want, fast, seed, divide=4):
         grad_relative_l2=float(np.linalg.norm(grad_f - grad_s) / norm_f),
         finite=bool(np.isfinite(grad_s).all()),
         secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
+
+
+def uncovered_rows(cell, engine, seed):
+    """PR 31's failure, held on the chip (the CPU's zero hides it): one
+    SFT microbatch's loss and gradient with the rows of every grouped
+    product that lie past its groups POISONED, NaN in the rows the
+    layer gathered past the held pairs, in the activations handed to
+    the down projection and in the cotangent that comes back, against
+    the same step unpoisoned. ``ops/grouped_matmul.py``'s contract
+    makes them equal; under ``lax.ragged_dot`` (``kernels`` false: the
+    products off the kernels) every number is NaN."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from realhf_tpu.ops import moe as moe_ops
+
+    real = moe_ops.grouped_matmul
+
+    @jax.custom_vjp
+    def poisoned_back(y, total):
+        return y
+
+    poisoned_back.defvjp(
+        lambda y, total: (y, total),
+        lambda total, ct: (poison(ct, total), None))
+
+    def poison(a, total):
+        return jnp.where(jnp.arange(a.shape[0])[:, None] < total, a,
+                         jnp.nan)
+
+    def poisoned(lhs, rhs, sizes):
+        total = sizes.sum()
+        return poisoned_back(real(poison(lhs, total), rhs, sizes), total)
+
+    t = time.monotonic()
+    mb = sft_microbatch(cell["hf"], seed)
+    loss, grad, stats = loss_and_gradient(engine, mb)
+    moe_ops.grouped_matmul = poisoned
+    try:
+        loss_p, grad_p, stats_p = loss_and_gradient(engine, mb)
+    finally:
+        moe_ops.grouped_matmul = real
+    norm, norm_p = np.linalg.norm(grad), np.linalg.norm(grad_p)
+    held = float(stats[moe_ops.HELD_PAIRS_STAT])
+    say(phase="uncovered_rows", kernels=moe_ops.pallas_enabled(),
+        held_pairs=held, rows_gathered=float(
+            moe_ops.share_rows(engine.cfg, mb["input_ids"].size)
+            * engine.cfg.n_moe_layers),
+        loss=dict(clean=loss, poisoned=loss_p),
+        grad_norm=dict(clean=float(norm), poisoned=float(norm_p)),
+        grad_relative_l2=float(np.linalg.norm(grad - grad_p) / norm),
+        equal=bool(loss == loss_p and (grad == grad_p).all()),
+        finite=bool(np.isfinite(grad_p).all()),
+        secs=round(time.monotonic() - t, 1))
 
 
 def decode(cell, engine, ids, want, n_pre=192):
@@ -442,6 +575,10 @@ def main():
     p.add_argument("--seeds", type=int, nargs="+", required=True)
     p.add_argument("--gen", action="store_true")
     p.add_argument("--only-ragged", action="store_true")
+    p.add_argument("--row-tiles", type=int, nargs="+",
+                   help="with --only-ragged: time the kernels at these "
+                        "row tiles (a builder's sweep; the file's own "
+                        "otherwise)")
     p.add_argument("--no-table", action="store_true",
                    help="the engine's reading alone, no lower precision "
                         "and no wrong equation")
@@ -471,8 +608,8 @@ def main():
     else:
         cell = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"),
                              spec["cell"])
-        if FAMILY == "lfm2_moe":
-            ragged(cell["hf"])
+        if FAMILY == "lfm2_moe" or args.only_ragged:
+            ragged(row_tiles=args.row_tiles or (None,))
         if args.only_ragged:
             return
     work = os.path.join(ROOT, "benchmark", ".cache", f"chip_check_{FAMILY}")
@@ -502,6 +639,8 @@ def main():
                         cell["hf"], tensors, long), n_pre=n_pre)
                 if spec.get("slow_path"):
                     slow_path(cell, ckpt, ids, want, engine, seed)
+                if spec.get("uncovered_rows"):
+                    uncovered_rows(cell, engine, seed)
                 if spec.get("exact_doc"):
                     n = spec["exact_doc"] // (8 if args.rehearse else 1)
                     engine = None  # the bf16 weights go before float32's come

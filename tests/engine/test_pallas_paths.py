@@ -262,7 +262,7 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
                                kind=kind) == blocks * cfg.n_layers
 
 
-def _train_spans(program, text, monkeypatch):
+def _train_spans(program, text, monkeypatch, cfg=None):
     """The attributes of the two ``engine:<program>`` spans of an
     engine that takes two steps of ``program`` under a capture, with
     its rows on the XLA path (no text is read) and on the flash
@@ -277,7 +277,7 @@ def _train_spans(program, text, monkeypatch):
     from realhf_tpu.ops import functional as F
     from realhf_tpu.parallel.mesh import MeshContext
 
-    cfg = _cfg()
+    cfg = cfg or _cfg()
     ctx = MeshContext(ModelName("default", 0), _mesh(1, 1),
                       ParallelismConfig())
     seg = np.ones((2, 1, 512), np.int32)
@@ -370,3 +370,55 @@ def test_train_span_counts_the_projections_run_a_second_time(
     assert [attrs["attn_proj_remat_products"] for attrs in on] \
         == [products] * 2
     assert all("flash_fwd_per_bwd" not in attrs for attrs in on)
+
+
+def test_train_span_says_which_grouped_matmul_the_experts_run(monkeypatch):
+    """Every ``engine:train`` span of a sparse model in the ragged mode
+    whose program's text has been read carries ``moe_products`` (``gmm``
+    where the program holds ``ops/grouped_matmul.py``'s kernels, else
+    ``ragged_dot``), ``moe_gmm_calls`` and ``moe_ragged_dot_calls``
+    (``ops.moe.grouped_product_calls``), from the ONE read that gives
+    ``flash_fwd_per_bwd``; the step itself runs the kernels in
+    interpret mode through the engine (the layer's own tests hold
+    their values)."""
+    from realhf_tpu.models.config import MoEConfig
+
+    call = "custom-call(%q), custom_call_target=\"tpu_custom_call\"\n"
+    names = ["jvp_gmm_", "gmm", "transpose_jvp_gmm_t__",
+             "transpose_jvp_tgmm__", "flash_fwd", "flash_bwd_dq"]
+    text = "%body (q: f32[8]) -> f32[8] {\n" + "".join(
+        f"  %{name}.{i} = f32[8]{{0}} {call}"
+        for i, name in enumerate(names)) + "}\n"
+    cfg = _cfg(mlp_type="moe", moe=MoEConfig(num_experts=4, top_k=2,
+                                             routing_type="none"))
+    off, on = _train_spans("train", text, monkeypatch, cfg)
+    assert all("moe_products" not in attrs for attrs in off)
+    for attrs in on:
+        assert attrs["moe_dispatch"] == "ragged"
+        assert (attrs["moe_products"], attrs["moe_gmm_calls"],
+                attrs["moe_ragged_dot_calls"]) == ("gmm", 4, 0)
+        assert attrs["flash_fwd_per_bwd"] == 1.0
+
+
+@pytest.mark.parametrize("dp,tp,sharded", [(1, 1, False), (2, 1, True),
+                                           (1, 2, True)])
+def test_over_a_mesh_the_grouped_products_stay_ragged_dot(dp, tp, sharded):
+    """The engine's word to the experts' layer (``moe_constraint``):
+    nothing where the stacks lie whole on one device (the kernels run
+    wherever Pallas is enabled), ``SHARDED_STACKS`` over a data- or
+    tensor-parallel mesh (a bare ``pallas_call`` has no partitioning
+    rule; ``lax.ragged_dot`` has GSPMD's)."""
+    from realhf_tpu.api.config import ModelName
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.models.config import MoEConfig
+    from realhf_tpu.ops import moe as moe_ops
+    from realhf_tpu.parallel.mesh import MeshContext
+
+    cfg = _cfg(mlp_type="moe", moe=MoEConfig(num_experts=4, top_k=2,
+                                             routing_type="none"))
+    par = ParallelismConfig(data_parallel_size=dp, tensor_parallel_size=tp)
+    engine = Engine(cfg, MeshContext(ModelName("default", 0),
+                                     _mesh(dp, tp), par),
+                    T.init_params(cfg, jax.random.PRNGKey(0)))
+    assert engine._moe_constraint == (
+        moe_ops.SHARDED_STACKS if sharded else None)

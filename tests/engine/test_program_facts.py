@@ -135,6 +135,13 @@ def test_train_program_names_every_part_the_family_has(family):
     if "experts" in have:
         assert {f"experts/{s}" for s in ("route", "products", "combine")} \
             <= set(passes)
+        # which grouped matmul the experts' products are, from the same
+        # read (here lax.ragged_dot, and XLA:CPU makes no custom call
+        # of it; on the chip the repo's kernels: test_chip_compile.py)
+        assert facts.attributes["moe_products"] == "ragged_dot"
+        assert facts.attributes["moe_gmm_calls"] == 0
+    else:
+        assert "moe_products" not in facts.attributes
     # what does the work is put down to a part: no product is left
     # out, and the fusions that carry an op_name and no part are the
     # loops' own plumbing (counters, a layer's slice out of the stack,
@@ -157,8 +164,10 @@ def test_generate_program_nests_the_parts_in_its_phases(family):
     generate_(engine)
     facts = engine.program_facts("generate")
     assert facts.module == "jit_generate"
+    sparse = {"moe_products", "moe_gmm_calls", "moe_ragged_dot_calls"} \
+        if "experts" in FAMILIES[family][1] else set()
     assert set(facts.attributes) == {"decode_kernel",
-                                     "decode_layer_copies"}
+                                     "decode_layer_copies"} | sparse
     seen = {(op[3], (op[0] or "").split("/")[0])
             for op in facts.ops.values()}
     assert {phase for phase, _ in seen} >= {"prefill", "decode", "sample"}

@@ -50,6 +50,25 @@ def line(name, opcode="fusion", op_name=None, shape="bf16[4096,896]{1,0}",
     (f"{FB}/transpose(jvp())/experts/combine/scatter-add",
      ("experts/combine", "bwd", "forward_backward")),
     (f"{FB}/jvp()/experts/mul", ("experts", "fwd", "forward_backward")),
+    # the grouped products as the repo's own kernels: a Pallas call
+    # keeps its op_name, so the pass is known (the compiler's
+    # ragged-dot kernel loses it: COMPILER_MADE, below)
+    (f"{FB}/jvp(layers)/experts/cond/branch_1_fun/products/gmm/pallas_call",
+     ("experts/products", "fwd", "forward_backward")),
+    (f"{FB}/transpose(jvp(layers))/jvp(layers)/checkpoint/"
+     "rematted_computation/experts/cond/branch_1_fun/products/gmm/"
+     "pallas_call", ("experts/products", "remat", "forward_backward")),
+    (f"{FB}/transpose(jvp(layers))/jvp(layers)/checkpoint/experts/cond/"
+     "branch_1_fun/products/gmm_t/pallas_call",
+     ("experts/products", "bwd", "forward_backward")),
+    (f"{FB}/transpose(jvp(layers))/jvp(layers)/checkpoint/experts/cond/"
+     "branch_0_fun/while/body/closed_call/checkpoint/products/tgmm/"
+     "pallas_call", ("experts/products", "bwd", "forward_backward")),
+    # (the slow branch's chunk, rematerialised inside the backward)
+    (f"{FB}/transpose(jvp(layers))/jvp(layers)/checkpoint/experts/cond/"
+     "branch_0_fun/while/body/closed_call/checkpoint/"
+     "rematted_computation/products/gmm/pallas_call",
+     ("experts/products", "remat", "forward_backward")),
     # no part: what only the phase holds, and what nothing holds
     (f"{FB}/transpose(jvp())/while/body/closed_call/remat2",
      (None, "bwd", "forward_backward")),
@@ -185,6 +204,53 @@ def test_parse_program_keeps_what_the_device_runs():
     assert ops["cond.6"][:3] == ("experts", "fwd", "conditional")
     assert ops["sort.1"][:4] == (None, "fwd", "sort", "sample")
     assert ops["gather.1"][:4] == ("embed", "fwd", "gather", "decode")
+
+
+def test_grouped_products_by_kernel_and_by_the_compilers_own():
+    """The repo's kernels land in ``experts/products`` WITH their pass;
+    the engine's count of them (``ops.moe.grouped_product_calls``: the
+    attributes ``moe_products``, ``moe_gmm_calls``,
+    ``moe_ragged_dot_calls`` of a sparse model's ``engine:*`` spans)
+    reads the custom calls outside fusion bodies by name."""
+    from realhf_tpu.ops import moe as moe_ops
+
+    products = f"{FB}/jvp(layers)/experts/products"
+    back = (f"{FB}/transpose(jvp(layers))/jvp(layers)/checkpoint/experts/"
+            "products")
+    kernels = "\n".join([
+        "HloModule jit_train_step, is_scheduled=true",
+        "",
+        "ENTRY %main.9 (a: bf16[8]) -> bf16[8] {",
+        line("a", "parameter"),
+        line("jvp_gmm_.1", "custom-call", f"{products}/gmm/pallas_call"),
+        line("gmm.7", "custom-call", f"{back.replace('/experts', '/rematted_computation/experts')}/gmm/pallas_call"),
+        line("transpose_jvp_gmm_t__.1", "custom-call",
+             f"{back}/gmm_t/pallas_call"),
+        line("transpose_jvp_tgmm__.1", "custom-call",
+             f"{back}/tgmm/pallas_call"),
+        line("flash_fwd.3", "custom-call", f"{FB}/jvp()/attn/pallas_call"),
+        line("fusion.1", "fusion", f"{products}/jit(_where)/select_n"),
+        "  ROOT " + line("tuple.2", "tuple").strip(),
+        "}",
+    ])
+    ops = parts.parse_program(kernels)
+    assert {name: ops[name][:3] for name in ops if "gmm" in name} == {
+        "jvp_gmm_.1": ("experts/products", "fwd", "custom-call"),
+        "gmm.7": ("experts/products", "remat", "custom-call"),
+        "transpose_jvp_gmm_t__.1": ("experts/products", "bwd",
+                                    "custom-call"),
+        "transpose_jvp_tgmm__.1": ("experts/products", "bwd",
+                                   "custom-call")}
+    assert ops["fusion.1"][:2] == ("experts/products", "fwd")
+    assert moe_ops.grouped_product_calls(kernels) == dict(
+        moe_products="gmm", moe_gmm_calls=4, moe_ragged_dot_calls=0)
+    # the path that keeps lax.ragged_dot: the compiler's kernel, its
+    # pass unknown; and a program with neither (XLA:CPU)
+    assert moe_ops.grouped_product_calls(PROGRAM) == dict(
+        moe_products="ragged_dot", moe_gmm_calls=0,
+        moe_ragged_dot_calls=1)
+    assert moe_ops.grouped_product_calls(
+        kernels.replace("gmm", "dot"))["moe_products"] == "ragged_dot"
 
 
 class _Compiled:

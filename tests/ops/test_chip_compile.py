@@ -134,19 +134,17 @@ def test_flash_compiles_at_lagunas_two_kinds_of_layer(one_chip, nq,
         assert kernel in text
 
 
-def _sft_microbatch(one_chip, config_name, family):
-    """``(step, params, mb)``: one microbatch's SFT forward and
-    backward of a benchmark configuration's WHOLE model at published
-    widths, a row of 4096, bf16, rematerialised as every experiment
-    runs it, with abstract arguments on the described chip."""
+def _model(one_chip, config_name, family):
+    """``(cfg, params, sds, attn)``: a benchmark configuration's WHOLE
+    model at published widths, bf16, rematerialised as every
+    experiment runs it, its parameters abstract on the described chip,
+    and the flash kernels as its attention."""
     import json
     import os
 
     from benchmark import generate, run
-    from realhf_tpu.interfaces import sft
     from realhf_tpu.models import hf as hf_models
     from realhf_tpu.models import transformer as T
-    from realhf_tpu.ops import moe as moe_ops
 
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         config = next(c for c in json.load(f)["configs"]
@@ -162,15 +160,45 @@ def _sft_microbatch(one_chip, config_name, family):
     params = jax.tree.map(
         lambda a: sds(a.shape, jnp.bfloat16),
         jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+
+    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
+        return flash_attention(q, k, v, seg, causal=causal, scale=scale,
+                               sliding_window=sliding_window)
+
+    return cfg, params, sds, attn
+
+
+def _as_on_a_tpu(fn):
+    """``fn`` traced with ``pallas_enabled()`` true where the experts'
+    layer asks: the backend here is the CPU, and the chip's program
+    holds ``ops/grouped_matmul.py``'s kernels."""
+    from unittest import mock
+
+    from realhf_tpu.ops import moe as moe_ops
+
+    def traced(*args):
+        with mock.patch.object(moe_ops, "pallas_enabled", lambda: True):
+            return fn(*args)
+    return traced
+
+
+def _sft_microbatch(one_chip, config_name, family, kernels=True):
+    """``(step, params, mb)``: one microbatch's SFT forward and
+    backward of a benchmark configuration's WHOLE model at published
+    widths, a row of 4096, bf16, rematerialised as every experiment
+    runs it, with abstract arguments on the described chip.
+    ``kernels``: the experts' grouped products as the chip runs them
+    (``ops/grouped_matmul.py``), else as ``lax.ragged_dot``."""
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import moe as moe_ops
+
+    cfg, params, sds, attn = _model(one_chip, config_name, family)
     mb = dict(input_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
               seg_ids=sds((1, FLASH_MAX_LEN), jnp.int32),
               prompt_mask=sds((1, FLASH_MAX_LEN), jnp.bool_))
     loss_fn = sft._make_loss_fn(cfg)
     sparse = cfg.n_moe_layers > 0
-
-    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
-        return flash_attention(q, k, v, seg, causal=causal, scale=scale,
-                               sliding_window=sliding_window)
 
     def objective(p, mb):
         h, _, aux = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
@@ -182,7 +210,35 @@ def _sft_microbatch(one_chip, config_name, family):
     def step(p, mb):
         return jax.value_and_grad(objective, has_aux=True)(p, mb)
 
-    return step, params, mb
+    return (_as_on_a_tpu(step) if kernels else step), params, mb
+
+
+def _sft_train_step(one_chip, config_name, family, microbatches):
+    """``(step, params, optimizer state, microbatches, weights)``: the
+    engine's WHOLE train step (``Engine._train_step_body``: the scan
+    over ``microbatches`` rows of 4096, the float32 accumulation, Adam
+    on float32 master weights) of a benchmark configuration, abstract
+    on the described chip. The engine is bare: it holds what the step
+    body reads and no array."""
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.engine.optim import OptimizerConfig, make_optimizer
+    from realhf_tpu.interfaces import sft
+
+    cfg, params, sds, attn = _model(one_chip, config_name, family)
+    engine = object.__new__(Engine)
+    engine.cfg, engine._attention_fn = cfg, attn
+    engine._pipeline_ctx = engine._constrain = None
+    engine._moe_constraint = None
+    engine._grad_shardings = engine._opt_shardings = None
+    engine._tx = make_optimizer(OptimizerConfig(), 100, master_weights=True)
+    opt_state = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                             jax.eval_shape(engine._tx.init, params))
+    rows = (microbatches, 1, FLASH_MAX_LEN)
+    mbs = dict(input_ids=sds(rows, jnp.int32), seg_ids=sds(rows, jnp.int32),
+               prompt_mask=sds(rows, jnp.bool_))
+    body = engine._train_step_body(sft._make_loss_fn(cfg))
+    return (_as_on_a_tpu(body), params, opt_state, mbs,
+            sds((microbatches,), jnp.float32))
 
 
 @functools.lru_cache(maxsize=None)
@@ -208,7 +264,10 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
     the program as it was then, whose backward recomputes q, k and v
     (the policy keeps the kernel's residuals alone): the producers'
     fusions feed the kernel, and that program must be refused for
-    VMEM. Since the blocks keep q (``PROJECTION_RESIDUALS``) the
+    VMEM (with the experts' products as they were then too,
+    ``lax.ragged_dot``: beside ``ops/grouped_matmul.py``'s kernels
+    even that program compiles, so near the line does it stand).
+    Since the blocks keep q (``PROJECTION_RESIDUALS``) the
     kernel reads a kept array and the program as it IS compiles
     without the limit too, by 1% of the 16 MiB: what XLA schedules
     around the kernel decides, so the limit stays. The day the
@@ -224,7 +283,8 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
         try:
             with pytest.raises(Exception, match="(?i)vmem"):
                 _compile(*_sft_microbatch(
-                    one_chip, "laguna-xs.2-l5-ep16", "laguna"))
+                    one_chip, "laguna-xs.2-l5-ep16", "laguna",
+                    kernels=False))
         finally:
             T._remat_policy.cache_clear()
         return
@@ -317,9 +377,11 @@ def test_moonlights_whole_microbatch_compiles(one_chip):
     layer (the blocks keep ``flash_out`` at the value's width and q at
     the key's); no product of ``attn_proj`` itself is run again, and
     what makes k and v from the latent (sub-part ``attn_proj/latent``:
-    the compression and the expansion) is, twice a layer; the
+    the compression and the expansion) is, twice a layer; the experts'
+    grouped products as ``ops/grouped_matmul.py``'s kernels; the
     compiler's count of the microbatch's memory."""
     from realhf_tpu.obs import parts
+    from realhf_tpu.ops import moe as moe_ops
     from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
     from realhf_tpu.ops.hlo_text import device_instructions
 
@@ -338,6 +400,12 @@ def test_moonlights_whole_microbatch_compiles(one_chip):
     assert {p: parts.count_products(text, latent, p)
             for p in (parts.FWD, parts.REMAT)} == {
         parts.FWD: 2 * 5, parts.REMAT: 2 * 5}
+    # the experts' grouped products are the repo's kernels: twelve a
+    # sparse layer (3 forward, 3 rematerialised, 3 + 3 backward) in
+    # each of the share's two branches, none of the compiler's own
+    assert moe_ops.grouped_product_calls(text) == dict(
+        moe_products="gmm", moe_gmm_calls=4 * 2 * 12,
+        moe_ragged_dot_calls=0)
     # 1.14 GB of bf16 weights and 0.82 of temporaries: 3.42 GB while
     # the share's slow branch took all 24,576 sorted rows at once
     # (``ops/moe.py:_ragged_share``), 1.96 since it takes them a
@@ -345,6 +413,84 @@ def test_moonlights_whole_microbatch_compiles(one_chip):
     memory = compiled.memory_analysis()
     assert 1.8e9 < (memory.argument_size_in_bytes
                     + memory.temp_size_in_bytes) < 2.2e9
+
+
+@pytest.mark.parametrize("rows,hidden,width,groups", [
+    (16384, 2048, 1024, 64), (4096, 2048, 1536, 8), (4096, 2048, 512, 16),
+    (6144, 2048, 1408, 8)], ids=["olmoe", "lfm2", "laguna", "moonlight"])
+@pytest.mark.parametrize("down", [False, True], ids=["up", "down"])
+def test_grouped_matmul_compiles_at_the_cells_shapes(
+        one_chip, rows, hidden, width, groups, down):
+    """``ops/grouped_matmul.py``'s three kernels (the product, the
+    rows' gradient with the weights read transposed, the weights'
+    gradient) at the sorted rows, widths and groups of the four sparse
+    cells' grouped products, the up / gate projection and the down
+    projection, bf16: a group's weights whole in VMEM (2048 x 1536 in
+    bf16 twice: 12.6 MB) and ``tgmm``'s float32 accumulator beside
+    them ask for more than the default scoped limit, which
+    ``vmem_limit_bytes`` gives."""
+    from realhf_tpu.ops.grouped_matmul import GMM, GMM_T, TGMM
+    from realhf_tpu.ops.grouped_matmul import grouped_matmul
+    from realhf_tpu.ops.hlo_text import device_instructions
+
+    k, n = (width, hidden) if down else (hidden, width)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(x, w, sizes):
+        return jax.value_and_grad(
+            lambda x, w: grouped_matmul(x, w, sizes).astype(
+                jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+    text = _compile(grads, sds((rows, k), jnp.bfloat16),
+                    sds((groups, k, n), jnp.bfloat16),
+                    sds((groups,), jnp.int32)).as_text()
+    # (under a gradient: jvp_gmm_.N, transpose_jvp_gmm_t_.N, ..._tgmm.N)
+    names = [name for name, _, opcode in device_instructions(text)
+             if opcode == "custom-call" and GMM in name]
+    assert len(names) == 3
+    assert sum(GMM_T in name for name in names) == 1
+    assert sum(TGMM in name for name in names) == 1
+
+
+def test_grouped_matmul_compiles_in_float32_at_moonlights_shape(one_chip):
+    """``scripts/chip_check.py deepseek_v3``'s row ``exact`` runs the
+    float32 engine through the compiled kernels: the forward product
+    with float32 operands (a group's weights 11.5 MB, twice)."""
+    from realhf_tpu.ops.grouped_matmul import grouped_matmul
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _compile(grouped_matmul, sds((6144, 2048), jnp.float32),
+             sds((8, 2048, 1408), jnp.float32), sds((8,), jnp.int32))
+
+
+def test_moonlights_whole_train_step_compiles(one_chip):
+    """The seventh cell's WHOLE train step for the described chip: 32
+    microbatches of one row of 4096 scanned, accumulated in float32,
+    Adam on float32 masters, the parameters and optimizer state
+    donated. One microbatch's program (the test above) is not enough,
+    a third time: with ``gmm_t``'s blocks at 15.1 MB the microbatch
+    compiled and THIS program was refused (16.09 MB of scoped VMEM
+    asked of the default 16 MiB: what XLA schedules around a kernel
+    differs by program), on the chip and here alike
+    (``ops/grouped_matmul.py:_params``; PERF.md, PR 38). And the
+    compiler's count of the step's memory, which the chip's own count
+    (``engine.program_gb``) follows to four digits: 13.35 GB, of the
+    13.9 a program is held to (13.64 with ``lax.ragged_dot``)."""
+    from realhf_tpu.ops import moe as moe_ops
+
+    step, *args = _sft_train_step(one_chip, "moonlight-16b-a3b-l5-ep8",
+                                  "deepseek_v3", 32)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    assert moe_ops.grouped_product_calls(compiled.as_text()) == dict(
+        moe_products="gmm", moe_gmm_calls=4 * 2 * 12,
+        moe_ragged_dot_calls=0)
+    memory = compiled.memory_analysis()
+    assert 13.0e9 < (memory.argument_size_in_bytes
+                     + memory.temp_size_in_bytes) < 13.6e9
 
 
 def test_flash_compiles_under_shard_map(topo):

@@ -55,6 +55,13 @@ def test_compiled_kernel_matches_reference(kernel):
         # flash_attention has no interpret switch: off-TPU it cannot
         # run at all, which is exactly what the skip above encodes
         got = flash_attention(q, k, v, seg, causal=True)
+    elif kernel == "grouped_matmul":
+        from realhf_tpu.ops.grouped_matmul import grouped_matmul
+        sizes = jnp.asarray([200, 0, 312], jnp.int32)
+        q = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((3, 256, 384)), jnp.float32)
+        ref = jax.lax.ragged_dot(q, w, sizes)
+        got = grouped_matmul(q, w, sizes)
     else:  # flash_decode_attention_stacked
         from realhf_tpu.ops.attention import decode_attention
         from realhf_tpu.ops.decode_attention import (

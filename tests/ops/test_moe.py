@@ -355,6 +355,24 @@ def share_layer(seed=0, tokens=40):
     return m, x
 
 
+@pytest.fixture(params=["ragged_dot", "kernels"])
+def products(request, monkeypatch):
+    """Both ways a share's grouped products run
+    (``ops/moe.py:_grouped_products``): ``lax.ragged_dot`` with every
+    row in a group, and ``ops/grouped_matmul.py``'s kernels, here in
+    interpret mode, with the group sizes as they are."""
+    if request.param == "ragged_dot":
+        yield request.param
+        return
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(moe_ops, "pallas_enabled", lambda: True)
+    # (the interpreter's callbacks are effects that the fallback's
+    # ``checkpoint`` cannot split under a gradient)
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
+    with pltpu.force_tpu_interpret_mode():
+        yield request.param
+
+
 def held_leaves(m, first, count):
     return {k: (v[first:first + count] if k in ("wg", "wu", "wd") else v)
             for k, v in m.items()}
@@ -413,7 +431,7 @@ def test_no_gradient_reaches_the_expert_bias():
     assert np.asarray(grads["router"]).any()
 
 
-def test_eight_shares_add_up_to_the_whole_layer():
+def test_eight_shares_add_up_to_the_whole_layer(products):
     """The tie of the share to the model: eight ranks of 2 experts,
     each routing over all 16, add up to what the layer gives with every
     expert held (the same code) and to the loop over tokens; the pairs
@@ -448,7 +466,7 @@ def test_eight_shares_add_up_to_the_whole_layer():
 
 
 @pytest.mark.parametrize("pulled, slow", [(32, 0), (33, 1), (64, 1)])
-def test_overflow_statistic_is_the_branch_taken(pulled, slow):
+def test_overflow_statistic_is_the_branch_taken(products, pulled, slow):
     """``SHARE_OVERFLOW_STAT`` reads 1 exactly where the held pairs
     pass ``share_rows`` (64 of 64 x 4 here). The router reads one
     input feature alone: it sends the first ``pulled`` tokens to the
@@ -473,7 +491,8 @@ def test_overflow_statistic_is_the_branch_taken(pulled, slow):
 
 
 @pytest.mark.parametrize("first", [0, 6, 14])
-def test_no_pair_of_a_held_expert_is_dropped_under_imbalance(first):
+def test_no_pair_of_a_held_expert_is_dropped_under_imbalance(products,
+                                                              first):
     """A bias that sends EVERY token to the held experts (and to two
     more): the share multiplies 2 x T pairs, far above T x k / E x 2,
     and still equals those experts' part of the whole layer; a bias
@@ -548,6 +567,43 @@ def test_every_row_of_a_shares_grouped_products_lies_in_a_group(
     # that many at a time (three products a chunk)
     rows = 2 * 64 * 4 * 2 // 16
     assert seen == [(rows, rows)] * 3 * (64 * 4 // rows if pull else 1)
+
+
+@pytest.mark.parametrize("pull", [0.0, 10.0], ids=["fast_path", "fallback"])
+def test_the_kernels_group_sizes_are_the_held_pairs_and_no_more(
+        monkeypatch, pull):
+    """The twin of the test above on the kernels' path
+    (``ops/grouped_matmul.py`` returns a row no group covers as zero
+    and does not multiply it): the group sizes of every grouped
+    product of a share add up to the HELD pairs among its rows, never
+    to more than the rows, and over the fallback's chunks to all of
+    them; nothing is counted into the last group."""
+    m, x = share_layer(seed=3, tokens=64)
+    bias = np.asarray(m["expert_bias"]).copy()
+    bias[[4, 5]] += pull
+    m["expert_bias"] = jnp.asarray(bias)
+    seen = []
+
+    def checked(lhs, rhs, group_sizes):
+        seen.append((lhs.shape[0], int(group_sizes.sum())))
+        covered = jnp.arange(lhs.shape[0])[:, None] < group_sizes.sum()
+        sizes = group_sizes.at[-1].add(lhs.shape[0] - group_sizes.sum())
+        return jnp.where(covered, jax.lax.ragged_dot(lhs, rhs, sizes), 0)
+
+    monkeypatch.setattr(moe_ops, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(moe_ops, "grouped_matmul", checked)
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
+    with jax.disable_jit():
+        _, aux = moe_ops.moe_mlp_with_losses(share_cfg(held=(4, 2)),
+                                             held_leaves(m, 4, 2), x)
+    held = int(aux[moe_ops.HELD_PAIRS_STAT])
+    rows = 2 * 64 * 4 * 2 // 16
+    assert all(n == rows and covered <= rows for n, covered in seen)
+    if pull:  # 128 held pairs over four chunks of 64 sorted rows
+        assert held == 2 * 64 > rows
+        assert seen == [(rows, rows)] * 6 + [(rows, 0)] * 6
+    else:
+        assert 0 < held < rows and seen == [(rows, held)] * 3
 
 
 # ----------------------------------------------------------------------
