@@ -263,12 +263,20 @@ class Engine:
                     latent_layers=len(cfg.latent_layers),
                     kv_lora_rank=cfg.latent.kv_rank,
                     qk_dim=cfg.head_dim, v_dim=cfg.latent.v_dim)
+            if cfg.delta is not None:
+                from realhf_tpu.ops.delta_rule import CHUNK
+                self._model_attrs.update(
+                    delta_layers=len(cfg.delta_layers),
+                    delta_heads=cfg.delta.n_heads,
+                    delta_head_dim=cfg.delta.head_dim,
+                    delta_chunk=CHUNK)
             if cfg.layer_q_heads is not None:
                 self._model_attrs.update(q_heads=" ".join(
                     str(cfg.q_heads(i)) for i in cfg.attention_layers))
             if cfg.rotary_by_operator is not None:
                 self._model_attrs.update(rotary=" ".join(
-                    f"{op[0]}:{rc.describe()}" for op, rc in sorted(
+                    f"{op[0]}:{'none' if rc is None else rc.describe()}"
+                    for op, rc in sorted(
                         cfg.rotary_by_operator.items())))
         if mode == "dense" and cfg.moe.num_experts > 4:
             logger.warning(
@@ -434,13 +442,18 @@ class Engine:
         router's experts whatever share of them is held. A device
         array is not read: all its positions count (pads are routed
         like tokens). ``conv_tokens_total{role}`` likewise: tokens x
-        conv layers of a patterned model."""
+        conv layers of a patterned model, ``delta_tokens_total{role}``
+        tokens x delta layers."""
         tokens = decode_tokens + (
             int(np.count_nonzero(seg_ids))
             if isinstance(seg_ids, np.ndarray) else int(seg_ids.size))
         if self.cfg.conv_layers:
             metrics.inc("conv_tokens_total",
                         tokens * len(self.cfg.conv_layers),
+                        role=str(self.ctx.model_name.role))
+        if self.cfg.delta_layers:
+            metrics.inc("delta_tokens_total",
+                        tokens * len(self.cfg.delta_layers),
                         role=str(self.ctx.model_name.role))
         if "moe_dispatch" not in self._model_attrs:
             return
@@ -1167,14 +1180,20 @@ class Engine:
                         seg, pos, key)
         self._read_facts_now("generate", program)
         if self.cfg.layer_pattern is not None:
-            # the two kinds of state the decode loop carried
+            # the kinds of state the decode loop carried
             self._last_span.set_attribute(
                 "kv_layers", len(self.cfg.attention_layers))
+            item = jnp.dtype(self.cfg.compute_dtype).itemsize
             self._last_span.set_attribute(
                 "conv_state_bytes",
                 int(np.prod(T.conv_state_shape(
-                    self.cfg, prompt_seg.shape[0])))
-                * jnp.dtype(self.cfg.compute_dtype).itemsize)
+                    self.cfg, prompt_seg.shape[0]))) * item)
+            if self.cfg.delta_layers:
+                tail, state = T.delta_state_shapes(
+                    self.cfg, prompt_seg.shape[0])
+                self._last_span.set_attribute(
+                    "delta_state_bytes",
+                    int(np.prod(tail)) * item + int(np.prod(state)) * 4)
         return out
 
     def inflight_generator(self, gconfig: GenerationHyperparameters,

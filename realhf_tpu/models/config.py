@@ -13,8 +13,10 @@ from typing import Dict, Optional, Tuple
 #: every earlier token of its document, "window" the last
 #: ``sliding_window`` of them, "latent" every earlier token through
 #: keys and values expanded from ONE compressed row a token
-#: (``LatentConfig``)
-OPERATORS = ("conv", "attention", "window", "latent")
+#: (``LatentConfig``); "delta" is no attention: it has no K/V rows, a
+#: head keeps ONE state that every earlier token of the document went
+#: into (``DeltaConfig``)
+OPERATORS = ("conv", "attention", "window", "latent", "delta")
 ATTENTION_OPERATORS = ("attention", "window", "latent")
 FEED_FORWARDS = ("dense", "moe")
 
@@ -77,6 +79,9 @@ class RotaryConfig:
 #: published module builds it without an epsilon of its own, so this,
 #: its class's default, and not the model's ``rms_norm_eps``
 LATENT_NORM_EPS = 1e-6
+#: what a delta layer's l2 norm of q and k adds to the sum of squares:
+#: the published config has no key for it either
+DELTA_L2_EPS = 1e-6
 
 
 @dataclasses.dataclass
@@ -102,6 +107,42 @@ class LatentConfig:
     kv_rank: int
     rope_dim: int
     v_dim: int
+
+
+@dataclasses.dataclass
+class DeltaConfig:
+    """The gated delta rule with a decay a key channel (operator
+    "delta" of a layer pattern; Kimi Delta Attention). With u the
+    layer's normed input, ``n`` heads of ``head_dim`` (key and value
+    alike), ``conv`` a depthwise causal convolution of ``conv_kernel``
+    taps that stops at a document's first token::
+
+        q~, k~, v = SiLU(conv(u wq)), SiLU(conv(u wk)), SiLU(conv(u wv))
+        q = q~ / |q~|_2 * head_dim^-0.5,  k = k~ / |k~|_2      a head
+                       (DELTA_L2_EPS beside the sum of squares)
+        g = -exp(a_log[head]) * softplus((u w_fa) w_fb + dt_bias)
+        beta = sigmoid(u w_b)                              [n] a token
+        S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T
+        o = S_t^T q             (``ops/delta_rule.py``, chunked)
+        y = (RMSNorm(o; o_norm) * sigmoid((u w_ga) w_gb)) wo
+
+    The state S [head_dim, head_dim] a head is 0 before a document's
+    first token and is what decoding keeps, beside the last
+    ``conv_kernel - 1`` rows of the three convolutions' inputs."""
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+
+    @property
+    def width(self) -> int:
+        """All heads' keys (or values) side by side."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def gate_rank(self) -> int:
+        """The width (u w_fa) and (u w_ga) pass through: a head's, as
+        published (the config has no key for it)."""
+        return self.head_dim
 
 
 @dataclasses.dataclass
@@ -244,8 +285,10 @@ class TransformerConfig:
     # one shape). None: ``n_q_heads`` everywhere.
     layer_q_heads: Optional[Tuple[int, ...]] = None
     # The rotary embedding by kind of layer: {"attention": ...,
-    # "window": ...}. None: the model-wide ``rotary_*`` fields.
-    rotary_by_operator: Optional[Dict[str, RotaryConfig]] = None
+    # "window": ...}; None for a kind says that its layers have NONE
+    # (latent layers only: queries and the shared key part go to the
+    # scores as they are). None: the model-wide ``rotary_*`` fields.
+    rotary_by_operator: Optional[Dict[str, Optional[RotaryConfig]]] = None
     # One output gate a head: ``g = sigmoid(u W_g)`` [.., n heads] from
     # the layer's normed input, multiplied into each head's attention
     # output before ``wo`` (leaf ``attn["w_gate"]`` [H, heads]).
@@ -253,6 +296,8 @@ class TransformerConfig:
     # What the "latent" layers of the pattern are made of; ``head_dim``
     # is then their query/key's width and ``v_head_dim`` their value's.
     latent: Optional[LatentConfig] = None
+    # What the "delta" layers of the pattern are made of.
+    delta: Optional[DeltaConfig] = None
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -324,10 +369,16 @@ class TransformerConfig:
         if self.layer_pattern is None and (
                 self.layer_q_heads is not None
                 or self.rotary_by_operator is not None
-                or self.attn_output_gate or self.latent is not None):
+                or self.attn_output_gate or self.latent is not None
+                or self.delta is not None):
             raise NotImplementedError(
-                "layer_q_heads, rotary_by_operator, attn_output_gate "
-                "and latent belong to a model with a layer_pattern")
+                "layer_q_heads, rotary_by_operator, attn_output_gate, "
+                "latent and delta belong to a model with a "
+                "layer_pattern")
+        if (self.delta is not None) != bool(self.delta_layers):
+            raise ValueError(
+                f"layer_pattern has {len(self.delta_layers)} delta "
+                f"layers, delta is {self.delta}")
         if (self.latent is not None) != bool(self.latent_layers):
             raise ValueError(
                 f"layer_pattern has {len(self.latent_layers)} latent "
@@ -343,7 +394,8 @@ class TransformerConfig:
                     or self.layer_q_heads is not None
                     or self.qk_norm is not None or self.attn_output_gate
                     or not 0 < self.latent.rope_dim < self.head_dim
-                    or self.latent.rope_dim % 2):
+                    or self.latent.rope_dim % 2
+                    or "latent" not in self.rotary_by_operator):
                 raise NotImplementedError(
                     "a latent layer has a key a query head, its rotary "
                     "embedding under rotary_by_operator['latent'], no "
@@ -361,6 +413,8 @@ class TransformerConfig:
             missing = {op for op, _ in self.layer_pattern
                        if op in ATTENTION_OPERATORS} \
                 - set(self.rotary_by_operator)
+            missing |= {op for op, rc in self.rotary_by_operator.items()
+                        if rc is None and op != "latent"}
             if missing or self.rotary_interleaved:
                 raise ValueError(
                     f"rotary_by_operator lacks {sorted(missing)} (and "
@@ -403,6 +457,12 @@ class TransformerConfig:
                      if op == "latent")
 
     @property
+    def delta_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a delta-rule state a head."""
+        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
+                     if op == "delta")
+
+    @property
     def v_head_dim(self) -> int:
         """A value head's width, which is also an attention output
         head's: ``head_dim`` unless the layers are latent."""
@@ -424,11 +484,11 @@ class TransformerConfig:
         return self.sliding_window \
             if self.layer_pattern[i][0] == "window" else None
 
-    def rotary_of(self, op: str) -> RotaryConfig:
+    def rotary_of(self, op: str) -> Optional[RotaryConfig]:
         """The rotary embedding of an ``op`` layer ("attention",
         "window" or "latent"): its kind's where the model declares one
-        a kind, else the model-wide ``rotary_*`` fields as a
-        RotaryConfig."""
+        a kind (None: the kind's layers have none), else the
+        model-wide ``rotary_*`` fields as a RotaryConfig."""
         if self.rotary_by_operator is not None:
             return self.rotary_by_operator[op]
         return RotaryConfig(
@@ -457,9 +517,9 @@ class TransformerConfig:
 
     @property
     def pattern_string(self) -> str:
-        """``c a c c c``, ``a w w w a``, ``l l l``: every layer's
-        operator by its first letter (conv, attention, window,
-        latent)."""
+        """``c a c c c``, ``a w w w a``, ``l l l``, ``d d d l d``:
+        every layer's operator by its first letter (conv, attention,
+        window, latent, delta)."""
         return " ".join(op[0] for op, _ in self.layer_kinds)
 
     def require_one_block(self, what: str):
@@ -473,7 +533,8 @@ class TransformerConfig:
                 f"{len(self.attention_layers)} attention layers, "
                 f"{len(self.window_layers)} of those with a window, "
                 f"{len(self.latent_layers)} latent, "
-                f"{self.n_moe_layers} layers sparse)")
+                f"{len(self.delta_layers)} delta layers that keep a "
+                f"state a head, {self.n_moe_layers} layers sparse)")
 
     def n_params(self) -> int:
         """Approximate parameter count (for FLOPs/memory estimates),
@@ -481,8 +542,8 @@ class TransformerConfig:
         taps, the router (and its selection bias) over all experts,
         the experts HELD, the shared expert, the query/key norms and
         the output gate, each attention layer at its own count of
-        query heads, a latent layer's five leaves; biases and the
-        layer norms' scales are left out."""
+        query heads, a latent layer's five leaves, a delta layer's
+        fifteen; biases and the layer norms' scales are left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
 
         def attn(i):
@@ -503,6 +564,12 @@ class TransformerConfig:
             return n + (h * nq if self.attn_output_gate else 0)
 
         conv = 4 * h * h + self.conv_kernel * h
+        delta = 0
+        if self.delta is not None:
+            dl = self.delta
+            delta = 4 * h * dl.width + 3 * dl.conv_kernel * dl.width \
+                + 2 * (h + dl.width) * dl.gate_rank + dl.width \
+                + h * dl.n_heads + dl.n_heads + dl.head_dim
         dense = (3 if self.gated_mlp else 2) * h * f
         moe = 0
         if self.moe is not None:
@@ -516,6 +583,7 @@ class TransformerConfig:
         if self.is_critic:
             embed = v * h + h
         return embed + sum(
-            (conv if op == "conv" else attn(i))
+            (conv if op == "conv" else delta if op == "delta"
+             else attn(i))
             + (moe if ff == "moe" else dense)
             for i, (op, ff) in enumerate(self.layer_kinds))

@@ -46,6 +46,8 @@ from realhf_tpu.models.config import (
 from realhf_tpu.models.hf.registry import (
     HFFamily,
     StateDict,
+    held_expert_ids,
+    layered_converters,
     register_hf_family,
 )
 
@@ -168,11 +170,6 @@ def _config_to_hf(cfg: TransformerConfig) -> Dict[str, Any]:
     return d
 
 
-def _expert_ids(cfg: TransformerConfig):
-    first = cfg.moe.experts_held[0] if cfg.moe.experts_held else 0
-    return range(first, first + cfg.moe.n_held)
-
-
 def layer_from_hf(state: StateDict, cfg: TransformerConfig,
                   i: int) -> Dict[str, Any]:
     """The tree of layer ``i``: the leaves its feed-forward has, HF
@@ -195,7 +192,7 @@ def layer_from_hf(state: StateDict, cfg: TransformerConfig,
     for leaf, hf in _FFN:
         lp["mlp"][leaf] = np.stack(
             [state[f"{mlp}experts.{e}.{hf}.weight"].T
-             for e in _expert_ids(cfg)], axis=0)
+             for e in held_expert_ids(cfg)], axis=0)
     if cfg.moe.shared_intermediate_dim is not None:
         lp["mlp"]["shared"] = {
             leaf: state[f"{mlp}shared_experts.{hf}.weight"].T
@@ -222,37 +219,15 @@ def layer_to_hf(lp: Dict[str, Any], cfg: TransformerConfig, i: int,
     out[mlp + "gate.weight"] = c(lp["mlp"]["router"].T)
     out[mlp + "gate.e_score_correction_bias"] = c(lp["mlp"]["expert_bias"])
     for leaf, hf in _FFN:
-        for j, e in enumerate(_expert_ids(cfg)):
+        for j, e in enumerate(held_expert_ids(cfg)):
             out[f"{mlp}experts.{e}.{hf}.weight"] = c(lp["mlp"][leaf][j].T)
         if "shared" in lp["mlp"]:
             out[f"{mlp}shared_experts.{hf}.weight"] = c(
                 lp["mlp"]["shared"][leaf].T)
 
 
-def _params_from_hf(state: StateDict, cfg: TransformerConfig) -> Dict[str, Any]:
-    params: Dict[str, Any] = {
-        "embed": {"wte": state["model.embed_tokens.weight"]},
-        "layers": {str(i): layer_from_hf(state, cfg, i)
-                   for i in range(cfg.n_layers)},
-        "ln_f": {"scale": state["model.norm.weight"]},
-    }
-    if not cfg.is_critic and not cfg.tied_embedding:
-        params["head"] = {"w": state["lm_head.weight"].T.copy()}
-    return params
-
-
-def _params_to_hf(params: Dict[str, Any], cfg: TransformerConfig) -> StateDict:
-    out: StateDict = {
-        "model.embed_tokens.weight": np.ascontiguousarray(
-            params["embed"]["wte"]),
-        "model.norm.weight": np.ascontiguousarray(
-            params["ln_f"]["scale"])}
-    for i in range(cfg.n_layers):
-        layer_to_hf(params["layers"][str(i)], cfg, i, out)
-    if not cfg.is_critic and not cfg.tied_embedding:
-        out["lm_head.weight"] = np.ascontiguousarray(params["head"]["w"].T)
-    return out
-
+_params_from_hf, _params_to_hf = layered_converters(
+    layer_from_hf, layer_to_hf)
 
 register_hf_family(HFFamily(
     name="deepseek_v3", hf_model_type="deepseek_v3",

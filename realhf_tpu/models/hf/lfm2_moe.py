@@ -41,6 +41,8 @@ from realhf_tpu.models.config import MoEConfig, TransformerConfig
 from realhf_tpu.models.hf.registry import (
     HFFamily,
     StateDict,
+    held_expert_ids,
+    layered_converters,
     register_hf_family,
 )
 
@@ -146,11 +148,6 @@ def _config_to_hf(cfg: TransformerConfig) -> Dict[str, Any]:
     return d
 
 
-def _expert_ids(cfg: TransformerConfig):
-    first = cfg.moe.experts_held[0] if cfg.moe.experts_held else 0
-    return range(first, first + cfg.moe.n_held)
-
-
 def layer_from_hf(state: StateDict, cfg: TransformerConfig,
                   i: int) -> Dict[str, Any]:
     """The tree of layer ``i``: the leaves its (operator,
@@ -182,7 +179,7 @@ def layer_from_hf(state: StateDict, cfg: TransformerConfig,
     for leaf, hf in _FFN:
         lp["mlp"][leaf] = np.stack(
             [state[f"{ffn}experts.{e}.{hf}.weight"].T
-             for e in _expert_ids(cfg)], axis=0)
+             for e in held_expert_ids(cfg)], axis=0)
     return lp
 
 
@@ -212,34 +209,13 @@ def layer_to_hf(lp: Dict[str, Any], cfg: TransformerConfig, i: int,
     if cfg.moe.use_expert_bias:
         out[ffn + "expert_bias"] = c(lp["mlp"]["expert_bias"])
     for leaf, hf in _FFN:
-        for j, e in enumerate(_expert_ids(cfg)):
+        for j, e in enumerate(held_expert_ids(cfg)):
             out[f"{ffn}experts.{e}.{hf}.weight"] = c(lp["mlp"][leaf][j].T)
 
 
-def _params_from_hf(state: StateDict, cfg: TransformerConfig) -> Dict[str, Any]:
-    params: Dict[str, Any] = {
-        "embed": {"wte": state["model.embed_tokens.weight"]},
-        "layers": {str(i): layer_from_hf(state, cfg, i)
-                   for i in range(cfg.n_layers)},
-        "ln_f": {"scale": state["model.embedding_norm.weight"]},
-    }
-    if not cfg.is_critic and not cfg.tied_embedding:
-        params["head"] = {"w": state["lm_head.weight"].T.copy()}
-    return params
-
-
-def _params_to_hf(params: Dict[str, Any], cfg: TransformerConfig) -> StateDict:
-    out: StateDict = {
-        "model.embed_tokens.weight": np.ascontiguousarray(
-            params["embed"]["wte"]),
-        "model.embedding_norm.weight": np.ascontiguousarray(
-            params["ln_f"]["scale"])}
-    for i in range(cfg.n_layers):
-        layer_to_hf(params["layers"][str(i)], cfg, i, out)
-    if not cfg.is_critic and not cfg.tied_embedding:
-        out["lm_head.weight"] = np.ascontiguousarray(params["head"]["w"].T)
-    return out
-
+_params_from_hf, _params_to_hf = layered_converters(
+    layer_from_hf, layer_to_hf,
+    final_norm="model.embedding_norm.weight")
 
 register_hf_family(HFFamily(
     name="lfm2_moe", hf_model_type="lfm2_moe",

@@ -56,6 +56,49 @@ def register_hf_family(family: HFFamily):
     HF_FAMILIES[family.name] = family
 
 
+def held_expert_ids(cfg: TransformerConfig) -> range:
+    """The GLOBAL ids of the experts whose weights a checkpoint holds
+    (``MoEConfig.experts_held``: one expert-parallel rank's share;
+    all of them without it)."""
+    first = cfg.moe.experts_held[0] if cfg.moe.experts_held else 0
+    return range(first, first + cfg.moe.n_held)
+
+
+def layered_converters(layer_from_hf, layer_to_hf,
+                       final_norm: str = "model.norm.weight"):
+    """``(params_from_hf, params_to_hf)`` of a family that converts a
+    LAYER at a time (``TransformerConfig.layer_pattern``): the
+    embedding, the final norm under ``final_norm`` and the head around
+    the family's own layers."""
+
+    def params_from_hf(state: StateDict,
+                       cfg: TransformerConfig) -> Dict[str, Any]:
+        params: Dict[str, Any] = {
+            "embed": {"wte": state["model.embed_tokens.weight"]},
+            "layers": {str(i): layer_from_hf(state, cfg, i)
+                       for i in range(cfg.n_layers)},
+            "ln_f": {"scale": state[final_norm]},
+        }
+        if not cfg.is_critic and not cfg.tied_embedding:
+            params["head"] = {"w": state["lm_head.weight"].T.copy()}
+        return params
+
+    def params_to_hf(params: Dict[str, Any],
+                     cfg: TransformerConfig) -> StateDict:
+        out: StateDict = {
+            "model.embed_tokens.weight": np.ascontiguousarray(
+                params["embed"]["wte"]),
+            final_norm: np.ascontiguousarray(params["ln_f"]["scale"])}
+        for i in range(cfg.n_layers):
+            layer_to_hf(params["layers"][str(i)], cfg, i, out)
+        if not cfg.is_critic and not cfg.tied_embedding:
+            out["lm_head.weight"] = np.ascontiguousarray(
+                params["head"]["w"].T)
+        return out
+
+    return params_from_hf, params_to_hf
+
+
 def config_from_hf(family: str, hf_config: Any,
                    is_critic: bool = False) -> TransformerConfig:
     d = hf_config if isinstance(hf_config, dict) else hf_config.to_dict()

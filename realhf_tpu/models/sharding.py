@@ -102,7 +102,8 @@ def param_pspecs(cfg: TransformerConfig,
 
 def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
     """``param_pspecs`` of a patterned model: the same rules with no
-    layer axis, a tree a layer. The convolution is tensor parallel
+    layer axis, a tree a layer. A delta layer is tensor parallel by
+    head. The convolution is tensor parallel
     like a feed-forward: ``w_in`` by column (GSPMD moves its three
     parts to a sharding by channel after the split), the taps by
     channel, ``w_out`` by row."""
@@ -113,6 +114,18 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
                               "ln2": {"scale": P(None)}}
         if op == "conv":
             lp["conv"] = {"w_in": col, "w": col, "w_out": row}
+        elif op == "delta":
+            # by head: the projections' columns, the convolutions'
+            # channels, the decay's leaves, the step and the expansions
+            # of the two gates; what is one rank or one head wide (the
+            # gates' compressions, the output's norm) on every shard
+            lp["delta"] = {
+                "wq": col, "wk": col, "wv": col, "conv_q": col,
+                "conv_k": col, "conv_v": col, "a_log": P(MODEL_AXIS),
+                "w_fa": P(None, None), "w_fb": col,
+                "dt_bias": P(MODEL_AXIS), "w_b": col,
+                "w_ga": P(None, None), "w_gb": col, "o_norm": P(None),
+                "wo": row}
         elif op == "latent":
             # by head, as wq and wo: the expansion's columns are a
             # head's (nope + v) at a time; the compression, whose

@@ -35,6 +35,13 @@ Design (idiomatic JAX, not a torch translation):
   ``head_dim`` wide (its last ``rope_dim`` values one rotary part all
   heads share), the value ``v_head_dim``; attention, the flash kernels
   and the cache (K rows of one width, V rows of the other) take both.
+  Or a layer keeps no keys and values at all (operator "delta",
+  ``DeltaConfig``): a head's gated delta-rule state, chunked over the
+  row by ``ops/delta_rule.py`` and reset at a document's first token;
+  decoding carries that state [heads, hd, hd] in float32 and the last
+  rows of its three short convolutions' inputs, a THIRD kind of decode
+  state beside K/V and ``cache["conv"]``. A latent layer may have no
+  rotary embedding (``rotary_by_operator["latent"] = None``).
   A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
@@ -50,9 +57,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from realhf_tpu.base.backend import pallas_enabled
-from realhf_tpu.models.config import LATENT_NORM_EPS, TransformerConfig
+from realhf_tpu.models.config import (DELTA_L2_EPS, LATENT_NORM_EPS,
+                                      TransformerConfig)
 from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
+from realhf_tpu.ops.delta_rule import chunked_delta_rule, delta_rule_step
 from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 
@@ -70,8 +79,14 @@ KVCache = Dict[str, jnp.ndarray]
 #: Laguna-XS.2's five-layer step from 13.87 to 14.00 GB of a chip's 16
 #: for 0.6% of its tokens a second (PERF.md, PR 36).
 PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
+#: What a delta layer's chunked recurrence made (``_delta_op``): its
+#: heads' outputs, ``tokens x width`` values a layer a microbatch in
+#: the compute dtype. Kept, the rematerialised block does not run the
+#: recurrence a second time for its OUTPUT; its own backward runs it
+#: again a segment at a time (``ops/delta_rule.py``).
+DELTA_RESIDUALS = ("delta_out",)
 #: every name the policy of a rematerialised block keeps
-KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS
+KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS
 
 
 # ----------------------------------------------------------------------
@@ -168,7 +183,10 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     nq, nkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
     std = 0.02
     proj_std = std / (2 * cfg.n_layers) ** 0.5
-    keys = iter(jax.random.split(key, 16 * cfg.n_layers + 4))
+    # a delta layer has more leaves than 16; the others keep the keys
+    # they have always drawn
+    per_layer = 16 if cfg.delta is None else 24
+    keys = iter(jax.random.split(key, per_layer * cfg.n_layers + 4))
 
     def norm(shape, s=std):
         return (s * jax.random.normal(next(keys), shape)).astype(pdt)
@@ -183,6 +201,9 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             lp["conv"] = {"w_in": norm((h, 3 * h)),
                           "w": norm((cfg.conv_kernel, h)),
                           "w_out": norm((h, h), proj_std)}
+        elif op == "delta":
+            lp["delta"] = _init_delta(cfg, norm, ones, next(keys), pdt,
+                                      proj_std)
         elif op == "latent":
             lat = cfg.latent
             lp["attn"] = {
@@ -228,6 +249,32 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     elif not cfg.tied_embedding:
         params["head"] = {"w": norm((h, v))}
     return params
+
+
+def _init_delta(cfg, norm, ones, key, pdt, proj_std) -> Params:
+    """A delta layer's leaves (``DeltaConfig`` has the equations). The
+    decay's two leaves start as published: ``a_log = log U(1, 16)`` a
+    head, ``dt_bias`` the inverse softplus of a step drawn
+    log-uniformly from [1e-3, 1e-1], so a channel forgets between
+    0.001 and 1.6 a token and a state lives hundreds of tokens."""
+    h, dl = cfg.hidden_dim, cfg.delta
+    w, r = dl.width, dl.gate_rank
+    ka, kd = jax.random.split(key)
+    dt = jnp.exp(jax.random.uniform(
+        kd, (w,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    return {
+        "wq": norm((h, w)), "wk": norm((h, w)), "wv": norm((h, w)),
+        "conv_q": norm((dl.conv_kernel, w)),
+        "conv_k": norm((dl.conv_kernel, w)),
+        "conv_v": norm((dl.conv_kernel, w)),
+        "a_log": jnp.log(jax.random.uniform(
+            ka, (dl.n_heads,), minval=1.0, maxval=16.0)).astype(pdt),
+        "w_fa": norm((h, r)), "w_fb": norm((r, w)),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+        "w_b": norm((h, dl.n_heads)),
+        "w_ga": norm((h, r)), "w_gb": norm((r, w)),
+        "o_norm": ones((dl.head_dim,)),
+        "wo": norm((w, h), proj_std)}
 
 
 # ----------------------------------------------------------------------
@@ -371,19 +418,26 @@ def _latent_qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
     a, lat = lp["attn"], cfg.latent
     *lead, _ = x.shape
     nope = cfg.head_dim - lat.rope_dim
-    interleaved = cfg.rotary_of("latent").interleaved
+    rc = cfg.rotary_of("latent")
+
+    def rotated(t):
+        # no rotary embedding (``rotary_by_operator["latent"] = None``):
+        # the queries' last values and the shared key part go to the
+        # scores as they are
+        return t if rc is None else apply_rotary(t, cos, sin,
+                                                 rc.interleaved)
+
     q = (x @ a["wq"].astype(cdt)).reshape(*lead, -1, cfg.head_dim)
-    q = jnp.concatenate(
-        [q[..., :nope],
-         apply_rotary(q[..., nope:], cos, sin, interleaved)], axis=-1)
+    if rc is not None:
+        q = jnp.concatenate([q[..., :nope], rotated(q[..., nope:])],
+                            axis=-1)
     with jax.named_scope(P.LATENT):
         kv_a = x @ a["w_kv_a"].astype(cdt)
         c = _norm(cfg, kv_a[..., :lat.kv_rank], a["kv_a_norm"], None,
                   LATENT_NORM_EPS)
         kv = (c @ a["w_kv_b"].astype(cdt)).reshape(
             *lead, -1, nope + lat.v_dim)
-        k_rope = apply_rotary(kv_a[..., None, lat.kv_rank:], cos, sin,
-                              interleaved)
+        k_rope = rotated(kv_a[..., None, lat.kv_rank:])
         k = jnp.concatenate(
             [kv[..., :nope],
              jnp.broadcast_to(k_rope, (*kv.shape[:-1], lat.rope_dim))],
@@ -417,11 +471,21 @@ def _short_conv(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
     token: s of another segment of the packed row, or of padding,
     counts as 0 (``seg_ids``; each id one contiguous run)."""
     cdt = u.dtype
-    k = cfg.conv_kernel
-    n = u.shape[1]
     b_, g, z = jnp.split(u @ c["w_in"].astype(cdt), 3, axis=-1)
     s = b_ * z
-    w = c["w"].astype(jnp.float32)
+    acc = _causal_conv(s, c["w"], seg_ids)
+    return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), s
+
+
+def _causal_conv(s: jnp.ndarray, w: jnp.ndarray,
+                 seg_ids: jnp.ndarray) -> jnp.ndarray:
+    """A depthwise causal convolution over packed rows: s [B, L, C]
+    and taps w [K, C] -> [B, L, C] in float32, tap ``w[K-1]`` on the
+    token itself, ``w[K-1-d]`` on the one d before it. A token's
+    window stops at its DOCUMENT's first token: s of another segment
+    of the packed row, or of padding, counts as 0."""
+    k, n = w.shape[0], s.shape[1]
+    w = w.astype(jnp.float32)
     acc = s.astype(jnp.float32) * w[k - 1]
     for d in range(1, k):
         before = jnp.pad(s, ((0, 0), (d, 0), (0, 0)))[:, :n]
@@ -429,7 +493,7 @@ def _short_conv(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
             seg_ids == jnp.pad(seg_ids, ((0, 0), (d, 0)))[:, :n])
         acc = acc + jnp.where(same[..., None],
                               before.astype(jnp.float32), 0.0) * w[k - 1 - d]
-    return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), s
+    return acc
 
 
 def _short_conv_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
@@ -445,6 +509,98 @@ def _short_conv_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
     acc = (window.astype(jnp.float32)
            * c["w"].astype(jnp.float32)[None]).sum(axis=1)
     return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), window[:, 1:]
+
+
+_DELTA_CONVS = (("wq", "conv_q"), ("wk", "conv_k"), ("wv", "conv_v"))
+
+
+def _delta_inputs(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+                  conv):
+    """What the recurrence takes of a delta layer's normed input u
+    [..., H] (``DeltaConfig`` has the equations): (the three
+    convolutions' inputs side by side [..., 3 x width]; q~, k~ and v
+    after convolution and SiLU and the decay's pre-activation
+    ``(u w_fa) w_fb`` [..., n, hd], in the compute dtype; beta
+    [..., n] in float32; ``prepare``). ``prepare(q~, k~, f)`` makes, in
+    float32, q and k l2-normed a head (q scaled) and the log-decay g:
+    the recurrence applies it where it computes (a segment of the row
+    at a time, ``ops/delta_rule.py``). ``conv``: (which of the three,
+    input [..., width], taps [K, width]) -> the convolution's output
+    in float32."""
+    cdt, dl = u.dtype, cfg.delta
+    f32 = jnp.float32
+    heads = (*u.shape[:-1], dl.n_heads, dl.head_dim)
+    raw = [u @ c[w].astype(cdt) for w, _ in _DELTA_CONVS]
+    q, k, v = (jax.nn.silu(conv(i, x, c[taps])).astype(cdt).reshape(heads)
+               for i, (x, (_, taps)) in enumerate(zip(raw, _DELTA_CONVS)))
+    f = ((u @ c["w_fa"].astype(cdt)) @ c["w_fb"].astype(cdt)).reshape(heads)
+    beta = jax.nn.sigmoid((u @ c["w_b"].astype(cdt)).astype(f32))
+    rate = -jnp.exp(c["a_log"].astype(f32))[:, None]
+    dt_bias = c["dt_bias"].astype(f32).reshape(heads[-2:])
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.square(x).sum(-1, keepdims=True) + DELTA_L2_EPS)
+
+    def prepare(q, k, f):
+        return (unit(q) * dl.head_dim ** -0.5, unit(k),
+                rate * jax.nn.softplus(f + dt_bias))
+
+    return jnp.concatenate(raw, axis=-1), q, k, v, f, beta, prepare
+
+
+def _delta_output(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+                  o: jnp.ndarray) -> jnp.ndarray:
+    """The heads' outputs o [..., n, hd] normed a head, gated from the
+    normed input u and projected: [..., H]."""
+    cdt = u.dtype
+    gate = jax.nn.sigmoid(
+        ((u @ c["w_ga"].astype(cdt)) @ c["w_gb"].astype(cdt)).astype(
+            jnp.float32)).reshape(o.shape)
+    y = _norm(cfg, o, c["o_norm"], None) * gate
+    return y.astype(cdt).reshape(*u.shape[:-1], -1) @ c["wo"].astype(cdt)
+
+
+def _delta_op(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+              seg_ids: jnp.ndarray):
+    """The delta operator over packed rows on the normed residual u
+    [B, L, H] -> (its projected output [B, L, H], (the convolutions'
+    inputs [B, L, 3 x width], each row's state after its last token
+    [B, n, hd, hd] float32)): what prefill's caches are made of. The
+    recurrence alone is sub-part ``delta/scan`` (obs/parts.py)."""
+    raw, q, k, v, f, beta, prepare = _delta_inputs(
+        cfg, c, u, lambda i, x, taps: _causal_conv(x, taps, seg_ids))
+    with jax.named_scope(P.SCAN):
+        o, last = chunked_delta_rule(q, k, v, f, beta, seg_ids,
+                                     prepare=prepare)
+        o = checkpoint_name(o, DELTA_RESIDUALS[0])
+    proj = checkpoint_name(_delta_output(cfg, c, u, o),
+                           PROJECTION_RESIDUALS[1])
+    return proj, (raw, last)
+
+
+def _delta_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+                tail: jnp.ndarray, state: jnp.ndarray):
+    """One token of :func:`_delta_op`: u [B, H], the stream's last
+    ``conv_kernel - 1`` rows of the convolutions' inputs, oldest first
+    [B, K-1, 3 x width], and its state [B, n, hd, hd] -> (output
+    [B, H], the tail and the state moved on by the token)."""
+    width = cfg.delta.width
+
+    def conv(i, x, taps):
+        window = jnp.concatenate(
+            [tail[..., i * width:(i + 1) * width],
+             x[:, None].astype(tail.dtype)], axis=1)
+        return (window.astype(jnp.float32)
+                * taps.astype(jnp.float32)[None]).sum(axis=1)
+
+    raw, q, k, v, f, beta, prepare = _delta_inputs(cfg, c, u, conv)
+    with jax.named_scope(P.SCAN):
+        q, k, g = prepare(*(x.astype(jnp.float32) for x in (q, k, f)))
+        o, state = delta_rule_step(q, k, v, g, beta, state)
+    tail = jnp.concatenate([tail[:, 1:], raw[:, None].astype(tail.dtype)],
+                           axis=1)
+    return _delta_output(cfg, c, u, o.astype(u.dtype)), tail, state
 
 
 def _attn_scale(cfg: TransformerConfig, layer_idx: jnp.ndarray) -> jnp.ndarray:
@@ -503,18 +659,22 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     ``mlp_type``; ``window``: its attention's window, None for the
     whole document. The state feeds prefill's caches: (k, v) of an
     attention layer, the convolution's input s [B, L, H] of a conv
-    layer; aux is non-empty for MoE."""
+    layer, (the convolutions' inputs, the rows' last states) of a
+    delta layer; aux is non-empty for MoE."""
     op, sparse = ("attention", None) if kind is None \
         else (kind[0], kind[1] == "moe")
     # the norm before an operator and the residual's add after it go
     # with the operator's projections, those around the feed-forward
     # with the feed-forward (obs/parts.py)
-    mixer = P.CONV if op == "conv" else P.ATTN_PROJ
+    mixer = {"conv": P.CONV, "delta": P.DELTA}.get(op, P.ATTN_PROJ)
     with jax.named_scope(mixer):
         ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
     if op == "conv":
         with jax.named_scope(P.CONV):
             proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
+    elif op == "delta":
+        with jax.named_scope(P.DELTA):
+            proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
                                     cos, sin, attention_fn, window, op)
@@ -581,7 +741,8 @@ def _rotary_tables(cfg: TransformerConfig, positions: jnp.ndarray):
     kind of layer (``rotary_by_operator``)."""
     if cfg.rotary_by_operator is not None:
         return {op: rotary_table(cfg, positions, op)
-                for op in cfg.rotary_by_operator}
+                for op, rc in cfg.rotary_by_operator.items()
+                if rc is not None}
     if cfg.apply_rotary:
         table = rotary_table(cfg, positions)
     else:
@@ -766,11 +927,13 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
     the rotary table of its kind (``rotary``: ``_rotary_tables``).
     ``states`` (for prefill): K and V stacked over the ATTENTION
     layers [n_attn, B, L, nkv, hd] (V ``v_head_dim`` wide where the
-    layers are latent) and the convolutions' inputs
-    stacked over the CONV layers [n_conv, B, L, H]; None unless
+    layers are latent), the convolutions' inputs
+    stacked over the CONV layers [n_conv, B, L, H], and of the DELTA
+    layers their convolutions' inputs [n_delta, B, L, 3 x width] and
+    the rows' last states [n_delta, B, n, hd, hd]; None unless
     ``return_kv``. ``aux``: the sparse layers' entries reduced as
     ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
-    ks, vs, convs, auxs = [], [], [], []
+    ks, vs, convs, tails, deltas, auxs = [], [], [], [], [], []
     for i, kind in enumerate(cfg.layer_pattern):
         cos, sin = rotary.get(kind[0], (None, None))
 
@@ -780,18 +943,22 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                           kind, cfg.layer_window(i))
 
         x, state, aux = _remat(cfg, block_fn)(layers[str(i)], x)
-        if return_kv and kind[0] != "conv":
-            ks.append(state[0])
-            vs.append(state[1])
-        elif return_kv:
+        if return_kv and kind[0] == "conv":
             convs.append(state)
+        elif return_kv:
+            first, second = (tails, deltas) if kind[0] == "delta" \
+                else (ks, vs)
+            first.append(state[0])
+            second.append(state[1])
         if aux:
             auxs.append(aux)
     states = None
     if return_kv:
         states = {name: jnp.stack(rows) if rows else None
                   for name, rows in (("k", ks), ("v", vs),
-                                     ("conv", convs))}
+                                     ("conv", convs),
+                                     ("delta_conv", tails),
+                                     ("delta", deltas))}
     aux = {}
     if return_aux and auxs:
         from realhf_tpu.ops.moe import reduce_layers
@@ -872,6 +1039,10 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     }
     if cfg.conv_layers:
         cache["conv"] = jnp.zeros(conv_state_shape(cfg, batch), dtype)
+    if cfg.delta_layers:
+        tail, state = delta_state_shapes(cfg, batch)
+        cache["delta_conv"] = jnp.zeros(tail, dtype)
+        cache["delta"] = jnp.zeros(state, jnp.float32)
     return cache
 
 
@@ -880,6 +1051,17 @@ def conv_state_shape(cfg: TransformerConfig, batch: int):
     the last ``conv_kernel - 1`` rows of the convolution's input."""
     return (len(cfg.conv_layers), batch, cfg.conv_kernel - 1,
             cfg.hidden_dim)
+
+
+def delta_state_shapes(cfg: TransformerConfig, batch: int):
+    """The delta layers' decode state: for each delta layer and stream
+    (the last ``conv_kernel - 1`` rows of its three convolutions'
+    inputs side by side, in the cache's dtype; a head's state
+    [hd, hd], in float32)."""
+    dl = cfg.delta
+    n = len(cfg.delta_layers)
+    return ((n, batch, dl.conv_kernel - 1, 3 * dl.width),
+            (n, batch, dl.n_heads, dl.head_dim, dl.head_dim))
 
 
 def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
@@ -907,22 +1089,32 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
 
 def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
     """``prefill``'s cache from the states ``forward`` returned."""
-    conv = None
+    more = {}
     if cfg.layer_pattern is None:
         k, v = kvs  # [nl, B, L, nkv, hd]
     else:
         # K and V of the attention layers alone; of each conv layer
-        # the last rows of its input, 0 where the row is padding
-        k, v, conv = kvs["k"], kvs["v"], kvs["conv"]
+        # the last rows of its input, 0 where the row is padding; of
+        # each delta layer the same of its three convolutions and the
+        # state after the row's last token
+        k, v = kvs["k"], kvs["v"]
         if k is None:
             k = v = jnp.zeros((0, b, lp, cfg.n_kv_heads, cfg.head_dim),
                               dtype)
-        if conv is not None:
-            t = min(cfg.conv_kernel - 1, lp)
-            conv = jnp.where((seg_ids[:, lp - t:] != 0)[None, :, :, None],
-                             conv[:, :, lp - t:], 0)
-            conv = jnp.pad(conv, [(0, 0), (0, 0),
-                                  (cfg.conv_kernel - 1 - t, 0), (0, 0)])
+
+        def tails(rows, kernel):
+            t = min(kernel - 1, lp)
+            rows = jnp.where((seg_ids[:, lp - t:] != 0)[None, :, :, None],
+                             rows[:, :, lp - t:], 0)
+            return jnp.pad(rows, [(0, 0), (0, 0), (kernel - 1 - t, 0),
+                                  (0, 0)])
+
+        if kvs["conv"] is not None:
+            more["conv"] = tails(kvs["conv"], cfg.conv_kernel)
+        if kvs["delta"] is not None:
+            more["delta_conv"] = tails(
+                kvs["delta_conv"], cfg.delta.conv_kernel).astype(dtype)
+            more["delta"] = kvs["delta"]
     k = k.transpose(0, 1, 3, 2, 4)  # -> [nl, B, nkv, L, hd] head-major
     v = v.transpose(0, 1, 3, 2, 4)
     valid = seg_ids != 0
@@ -939,9 +1131,7 @@ def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
         "valid": valid,
         "length": jnp.full((b,), lp, jnp.int32),
     }
-    if conv is not None:
-        cache["conv"] = conv
-    return cache
+    return {**cache, **more}
 
 
 def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
@@ -955,7 +1145,7 @@ def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
     pad = lambda a: jnp.concatenate(
         [a, jnp.zeros(a.shape[:3] + (extra, a.shape[4]), a.dtype)], axis=3)
     return {
-        **cache,  # length, and a patterned model's conv state
+        **cache,  # length, and a patterned model's other states
         "k": pad(cache["k"]),
         "v": pad(cache["v"]),
         "valid": jnp.concatenate(
@@ -1057,7 +1247,7 @@ def decode_step(
         # l: the layer's place in the K/V stack, a Python int
         # (unrolled) or a traced scalar; op, window: its kind's rotary
         # table and what it sees (a patterned model says them a layer)
-        cos, sin = rotary[op]
+        cos, sin = rotary.get(op, (None, None))  # a latent without one
         with jax.named_scope(P.ATTN_PROJ):
             ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
             # q: [B, nq, hd]; k/v: [B, nkv, hd]
@@ -1101,15 +1291,28 @@ def decode_step(
             return x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
 
     k_all, v_all = cache["k"], cache["v"]
-    new_conv = []
+    new_conv, new_tails, new_deltas = [], [], []
     if cfg.layer_pattern is not None:
         # a layer of the pattern at a time: an attention layer reads
         # and writes ITS slice of the K/V stack (the stack holds the
         # attention layers alone, window layers with EVERY row: the
         # kernel masks what is past the window), a conv layer its two
-        # rows of state
+        # rows of state, a delta layer its heads' states and the
+        # tails of its three convolutions
         for i, (op, ff) in enumerate(cfg.layer_pattern):
             lp = params["layers"][str(i)]
+            if op == "delta":
+                with jax.named_scope(P.DELTA):
+                    ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
+                    at = len(new_deltas)
+                    proj, tail, state = _delta_step(
+                        cfg, lp["delta"], ln1, cache["delta_conv"][at],
+                        cache["delta"][at])
+                    new_tails.append(tail)
+                    new_deltas.append(state)
+                    x = x + proj
+                x = _ff_step(x, lp, ff == "moe")
+                continue
             if op != "conv":
                 x, k_all, v_all = layer_body(
                     x, k_all, v_all, lp, cfg.attention_layers.index(i),
@@ -1141,4 +1344,8 @@ def decode_step(
     if new_conv:
         with jax.named_scope(P.CONV):
             new_cache["conv"] = jnp.stack(new_conv)
+    if new_deltas:
+        with jax.named_scope(P.DELTA):
+            new_cache["delta_conv"] = jnp.stack(new_tails)
+            new_cache["delta"] = jnp.stack(new_deltas)
     return x, new_cache

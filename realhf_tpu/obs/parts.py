@@ -35,6 +35,10 @@ EMBED = "embed"
 ATTN_PROJ = "attn_proj"
 ATTN = "attn"
 CONV = "conv"
+#: a delta layer's mixer (models/config.py:DeltaConfig): the norm
+#: before it, its projections, short convolutions and gates, the
+#: output's norm and gate, ``wo``; the recurrence is its sub-scope
+DELTA = "delta"
 MLP = "mlp"
 SHARED_EXPERT = "shared_expert"
 EXPERTS = "experts"
@@ -47,7 +51,7 @@ OPTIMIZER = "optimizer"
 LAYERS = "layers"
 #: what a device operation can be put down to: the INNERMOST of these
 #: in its ``op_name`` is its part
-PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, MLP, SHARED_EXPERT,
+PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, MLP, SHARED_EXPERT,
          EXPERTS, VOCAB_HEAD, LOSS, GRAD_ACCUM, OPTIMIZER)
 
 FORWARD_BACKWARD = "forward_backward"
@@ -65,8 +69,11 @@ EXPERT_STEPS = (ROUTE, GATHER, PRODUCTS, COMBINE)
 #: expansion a head, the shared rotary key's rotation and broadcast);
 #: the part then reads ``attn_proj/latent``
 LATENT = "latent"
+#: sub-scope of ``delta``: the chunked recurrence alone
+#: (``ops/delta_rule.py``); the part then reads ``delta/scan``
+SCAN = "scan"
 #: part -> the sub-scopes that may stand inside it
-SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,)}
+SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,)}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: the pass of an operation whose ``op_name`` the compiler wrote

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``, ``deepseek_v3``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -60,6 +60,23 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   row tiles past its last group and leaves them UNWRITTEN (PERF.md, PR
   31), so the layer hands it every row; the repo's kernels visit the
   covered tiles alone and return the rest as zero.
+- ``published``, ``exact_packed``, ``gradient``, ``scan``
+  (``kimi_linear``): the harness's weights put the delta layers' decay
+  near a half a token, so the same readings are taken again with the
+  decay's two tensors as PUBLISHED (``family.published_decay``: a state
+  lives hundreds of tokens): the bf16 engine and every wrong equation
+  on the fixed batch; the FLOAT32 engine through the compiled program
+  on ONE document of 2,048 tokens (``exact``) and on a packed row of
+  four documents (each against the reference of it alone: the state's
+  reset, the convolutions' stop), under both initialisations; loss and
+  gradient of one SFT microbatch of 256 tokens through the engine
+  against ``family.sft_loss_and_grad`` (the reference's gradient keeps
+  a state a token: a row of 2,048 does not fit); and milliseconds of
+  the chunked scan alone, forward and gradient, at the cell's shape by
+  ``ops/delta_rule.py:SEGMENT_CHUNKS``, with ``scan_accuracy``: the
+  chunked scan and the recurrence token by token in float32 on the
+  device, each against the recurrence in float64 on the host.
+  ``--only <phases>`` runs these rows alone.
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
   their attributes.
@@ -103,6 +120,11 @@ FAMILIES = {
         tiny=("deepseek_v3", "tiny-deepseek-v3.sft"), wrong_keys={},
         packed_docs=(1536, 1024, 1024, 512), decode=(2, 768, 640),
         exact_doc=4096, slow_path=True, uncovered_rows=True),
+    "kimi_linear": dict(
+        cell="kimi-linear-48b-a3b-l5-ep32.sft-2k",
+        tiny=("kimi_linear", "tiny-kimi-linear.sft"), wrong_keys={},
+        packed_docs=(700, 600, 500, 248), decode=(2, 768, 640),
+        exact_doc=2048, published_decay=True),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -380,6 +402,226 @@ def exact(cell, ckpt, tensors, doc):
         secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
 
 
+def with_published_decay(cell, ckpt, seed):
+    """The checkpoint at ``ckpt`` with its delta layers' decay as
+    published (``family.published_decay``), in a directory beside it:
+    (that directory, its tensors)."""
+    import safetensors.numpy
+
+    from benchmark import reference
+    family, hf = cell["family"], cell["hf"]
+    out = ckpt + "-published"
+    shutil.copytree(ckpt, out)
+    tensors = family.published_decay(hf, reference.load_tensors(ckpt), seed)
+    safetensors.numpy.save_file(
+        tensors, os.path.join(out, "model.safetensors"))
+    return out, tensors
+
+
+def published(cell, ckpt, tensors, ids):
+    """The bf16 engine and every wrong equation on the fixed batch
+    under the published decay."""
+    import numpy as np
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    want = family.logprobs(hf, tensors, ids)
+    got = np.asarray(one_chip_engine(ckpt).forward_logprobs(
+        ids, np.ones_like(ids)), np.float32)[:, :-1]
+    say(phase="published", engine_bf16=share(got, want),
+        **{f"wrong_{w}": share(family.logprobs(hf, tensors, ids,
+                                               wrong=(w,)), want)
+           for w in family.WRONG},
+        secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
+
+
+def exact_packed(cell, ckpt, tensors, docs, decay):
+    """The float32 engine at the highest precision on ``docs`` as ONE
+    packed row, each document against the reference of it alone."""
+    import jax
+    import numpy as np
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    row = np.concatenate(docs)[None].astype(np.int32)
+    seg = np.concatenate([np.full(len(d), j + 1, np.int32)
+                          for j, d in enumerate(docs)])[None]
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(one_chip_engine(ckpt, "float32").forward_logprobs(
+            row, seg), np.float32)[0]
+    rows, at = {}, 0
+    for j, doc in enumerate(docs):
+        want = family.logprobs(hf, tensors, doc[None].astype(np.int32))[0]
+        rows[f"document_{j}_of_{len(doc)}"] = share(
+            got[at:at + len(doc) - 1], want)
+        at += len(doc)
+    # the wrong equations that are about documents show on a packed
+    # row only: reference against reference, the row's real pairs
+    import jax.numpy as jnp
+    real = np.asarray(seg[0, 1:] == seg[0, :-1])
+
+    def of_the_row(wrong):
+        out = family._token_logprobs(jnp.asarray(family.logits(
+            hf, tensors, row, seg, wrong=wrong)), jnp.asarray(row))
+        return np.asarray(out)[:, real]
+
+    want = of_the_row(())
+    for wrong in ("state_over_documents", "conv_over_documents"):
+        rows[f"wrong_{wrong}"] = share(of_the_row((wrong,)), want)
+    say(phase="exact_packed", decay=decay, row=row.shape[1],
+        secs=round(time.monotonic() - t, 1), **rows)
+
+
+def gradient(cell, ckpt, tensors, seed, length=256):
+    """Loss and gradient of one SFT microbatch of ``length`` tokens:
+    the float32 engine's own objective against the reference's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from realhf_tpu.models import hf as hf_models
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    ids = np.random.default_rng(seed + 5).integers(
+        0, hf["vocab_size"], (1, length)).astype(np.int32)
+    mb = dict(input_ids=jnp.asarray(ids),
+              seg_ids=jnp.ones((1, length), jnp.int32),
+              prompt_mask=jnp.arange(length)[None] < length // 8)
+    with jax.default_matmul_precision("highest"):
+        engine = one_chip_engine(ckpt, "float32")
+        from realhf_tpu.interfaces import sft
+        engine.cfg.gradient_checkpointing = True
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            engine._objective(sft._make_loss_fn(engine.cfg)),
+            has_aux=True))(engine.params, mb)
+        got = hf_models.params_to_hf(
+            FAMILY, jax.tree.map(np.asarray, grads), engine.cfg)
+        del engine, grads
+        ref_loss, _, want = family.sft_loss_and_grad(
+            hf, tensors, ids, length // 8)
+    norm = lambda g: float(np.sqrt(sum(
+        np.square(v.astype(np.float64)).sum() for v in g.values())))
+    gap = float(np.sqrt(sum(np.square(
+        got[k].reshape(want[k].shape).astype(np.float64) - want[k]).sum()
+        for k in want)))
+    say(phase="gradient", tokens=length, loss=float(loss),
+        reference_loss=ref_loss, grad_norm=norm(got),
+        reference_grad_norm=norm(want), relative_l2=gap / norm(want),
+        secs=round(time.monotonic() - t, 1))
+
+
+def scan_alone(cell, rounds=5):
+    """Milliseconds of the chunked recurrence alone at the cell's shape
+    (one row of 2,048, 32 heads of 128), forward and gradient, by
+    ``SEGMENT_CHUNKS``."""
+    import jax
+    import jax.numpy as jnp
+    from realhf_tpu.ops import delta_rule as D
+    lin = cell["hf"]["linear_attn_config"]
+    shape = (1, cell["traffic"]["doc_len"], lin["num_heads"],
+             lin["head_dim"])
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q, k = (unit(jax.random.normal(key, shape)) for key in keys[:2])
+    v = jax.random.normal(keys[2], shape)
+    g = -jax.nn.softplus(jax.random.normal(keys[3], shape))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
+    seg = jnp.ones(shape[:2], jnp.int32)
+    rows = {}
+    was = D.SEGMENT_CHUNKS
+    for per in (2, 4, 8):
+        D.SEGMENT_CHUNKS = per
+        fwd = jax.jit(lambda *a: D.chunked_delta_rule(*a, seg)[0])
+        bwd = jax.jit(jax.grad(lambda *a: D.chunked_delta_rule(
+            *a, seg)[0].astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+        for name, fn in (("forward", fwd), ("gradient", bwd)):
+            jax.block_until_ready(fn(q, k, v, g, beta))
+            t = time.monotonic()
+            for _ in range(rounds):
+                out = fn(q, k, v, g, beta)
+            jax.block_until_ready(out)
+            rows[f"{name}_ms_at_{per}_chunks"] = round(
+                (time.monotonic() - t) / rounds * 1e3, 3)
+    D.SEGMENT_CHUNKS = was
+    say(phase="scan", shape=list(shape), **rows)
+    scan_accuracy(shape[1], lin["head_dim"])
+
+
+def scan_accuracy(length, hd, heads=4):
+    """Whose float32 is it: the chunked scan and the recurrence token by
+    token (``delta_rule_step``), both on this device in float32 at the
+    highest precision, each against the recurrence in FLOAT64 on the
+    host, under a decay of a half a token and under the published one
+    (a state that lives hundreds of tokens): shares of the truth's
+    spread."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from realhf_tpu.ops import delta_rule as D
+    rng = np.random.default_rng(0)
+    shape = (1, length, heads, hd)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(rng.normal(size=shape)) * hd ** -0.5
+    k, v = unit(rng.normal(size=shape)), rng.normal(size=shape)
+    beta = 1 / (1 + np.exp(-rng.normal(size=shape[:3])))
+    rate = rng.uniform(1, 16, (heads, 1)) * np.exp(rng.uniform(
+        np.log(1e-3), np.log(1e-1), (heads, hd)))
+    f32 = lambda *xs: [jnp.asarray(x, jnp.float32) for x in xs]
+
+    def token_by_token(q, k, v, g, beta):
+        def step(s, x):
+            o, s = D.delta_rule_step(*x, s)
+            return s, o
+        xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+        return jnp.moveaxis(jax.lax.scan(
+            step, jnp.zeros((1, heads, hd, hd), jnp.float32), xs)[1], 0, 1)
+
+    for decay, g in (("half_a_token", np.full(shape, -np.log(2.0))),
+                     ("published", np.broadcast_to(-rate, shape))):
+        s, truth = np.zeros((heads, hd, hd)), np.zeros(shape[1:])
+        for t in range(length):
+            s = s * np.exp(g[0, t])[..., None]
+            u = beta[0, t, :, None] * (v[0, t] - np.einsum(
+                "nkv,nk->nv", s, k[0, t]))
+            s = s + k[0, t][..., None] * u[:, None, :]
+            truth[t] = np.einsum("nkv,nk->nv", s, q[0, t])
+        with jax.default_matmul_precision("highest"):
+            args = f32(q, k, v, g, beta)
+            chunked = jax.jit(lambda *a: D.chunked_delta_rule(
+                *a, jnp.ones(shape[:2], jnp.int32))[0])(*args)
+            stepped = jax.jit(token_by_token)(*args)
+        say(phase="scan_accuracy", decay=decay, shape=list(shape),
+            chunked=share(np.asarray(chunked)[0], truth),
+            token_by_token=share(np.asarray(stepped)[0], truth))
+
+
+#: what ``under_published_decay`` runs, in this order (``--only``)
+PUBLISHED_PHASES = ("exact_packed", "published", "exact", "scan",
+                    "gradient")
+
+
+def under_published_decay(cell, ckpt, tensors, ids, seed, docs, long,
+                          phases, rehearse):
+    """``kimi_linear``'s own rows: ``phases`` of ``PUBLISHED_PHASES``
+    on the checkpoint at ``ckpt`` and on a copy of it with the decay as
+    published; ``docs``: the packed row's documents, ``long``: ONE
+    document of the cell's row length."""
+    ckpt_p, tensors_p = with_published_decay(cell, ckpt, seed)
+    for phase in phases:
+        if phase == "exact_packed":
+            exact_packed(cell, ckpt, tensors, docs, "harness")
+            exact_packed(cell, ckpt_p, tensors_p, docs, "published")
+        elif phase == "published":
+            published(cell, ckpt_p, tensors_p, ids)
+        elif phase == "exact":
+            exact(cell, ckpt_p, tensors_p, long)
+        elif phase == "scan":
+            scan_alone(cell)
+        elif phase == "gradient":
+            try:  # the reference's gradient is the largest program
+                gradient(cell, ckpt_p, tensors_p, seed,
+                         64 if rehearse else 256)
+            except Exception as e:  # noqa: BLE001 - out of memory
+                say(phase="gradient", failed=repr(e)[:400])
+
+
 def sft_microbatch(hf, seed):
     """One SFT microbatch at the cell's row length, from the seed."""
     import jax.numpy as jnp
@@ -552,7 +794,8 @@ def gen(cell, ckpt, work, seed):
     tracing.start(sync=True)
     t = time.monotonic()
     quickstart.main([
-        "gen", f"experiment_name=chip-check-{FAMILY}", f"trial_name=s{seed}",
+        "gen", f"experiment_name=chip-check-{FAMILY.replace('_', '-')}",
+        f"trial_name=s{seed}",
         f"seed={seed}", "total_train_epochs=1",
         f"dataset.path={prompts}", "dataset.train_bs_n_seqs=128",
         "dataset.max_seqlen=256", f"model.type={FAMILY}",
@@ -582,6 +825,9 @@ def main():
     p.add_argument("--no-table", action="store_true",
                    help="the engine's reading alone, no lower precision "
                         "and no wrong equation")
+    p.add_argument("--only", nargs="+", default=None,
+                   help="kimi_linear: after the first seed's engine "
+                        "reading, these of PUBLISHED_PHASES alone")
     p.add_argument("--rehearse", action="store_true",
                    help="the tests' tiny cell of the family, on any "
                         "device: finds faults, measures nothing")
@@ -625,6 +871,7 @@ def main():
                 lens = spec["packed_docs"]
                 if args.rehearse and lens:  # toy rows: an eighth
                     lens = tuple(n // 8 for n in lens)
+            if i == 0 and not args.only:
                 packed(cell, engine, tensors, list(ids) if lens is None
                        else [rng.integers(0, vocab, n) for n in lens], ckpt)
                 if spec["decode"] is None:
@@ -645,6 +892,14 @@ def main():
                     n = spec["exact_doc"] // (8 if args.rehearse else 1)
                     engine = None  # the bf16 weights go before float32's come
                     exact(cell, ckpt, tensors, rng.integers(0, vocab, n))
+            if i == 0 and spec.get("published_decay"):
+                engine = None
+                under_published_decay(
+                    cell, ckpt, tensors, ids, seed,
+                    [rng.integers(0, vocab, n) for n in lens],
+                    rng.integers(0, vocab, spec["exact_doc"] // (
+                        8 if args.rehearse else 1)),
+                    args.only or PUBLISHED_PHASES, args.rehearse)
             del engine, tensors
         if args.gen:
             gen(cell, ckpt, work, args.seeds[-1])

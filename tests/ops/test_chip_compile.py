@@ -213,13 +213,14 @@ def _sft_microbatch(one_chip, config_name, family, kernels=True):
     return (_as_on_a_tpu(step) if kernels else step), params, mb
 
 
-def _sft_train_step(one_chip, config_name, family, microbatches):
+def _sft_train_step(one_chip, config_name, family, microbatches,
+                    row_len=FLASH_MAX_LEN):
     """``(step, params, optimizer state, microbatches, weights)``: the
     engine's WHOLE train step (``Engine._train_step_body``: the scan
-    over ``microbatches`` rows of 4096, the float32 accumulation, Adam
-    on float32 master weights) of a benchmark configuration, abstract
-    on the described chip. The engine is bare: it holds what the step
-    body reads and no array."""
+    over ``microbatches`` rows of ``row_len`` (4096), the float32
+    accumulation, Adam on float32 master weights) of a benchmark
+    configuration, abstract on the described chip. The engine is bare:
+    it holds what the step body reads and no array."""
     from realhf_tpu.engine.engine import Engine
     from realhf_tpu.engine.optim import OptimizerConfig, make_optimizer
     from realhf_tpu.interfaces import sft
@@ -233,7 +234,7 @@ def _sft_train_step(one_chip, config_name, family, microbatches):
     engine._tx = make_optimizer(OptimizerConfig(), 100, master_weights=True)
     opt_state = jax.tree.map(lambda a: sds(a.shape, a.dtype),
                              jax.eval_shape(engine._tx.init, params))
-    rows = (microbatches, 1, FLASH_MAX_LEN)
+    rows = (microbatches, 1, row_len)
     mbs = dict(input_ids=sds(rows, jnp.int32), seg_ids=sds(rows, jnp.int32),
                prompt_mask=sds(rows, jnp.bool_))
     body = engine._train_step_body(sft._make_loss_fn(cfg))
@@ -491,6 +492,33 @@ def test_moonlights_whole_train_step_compiles(one_chip):
     memory = compiled.memory_analysis()
     assert 13.0e9 < (memory.argument_size_in_bytes
                      + memory.temp_size_in_bytes) < 13.6e9
+
+
+def test_kimis_whole_train_step_compiles(one_chip):
+    """The eighth cell's WHOLE train step for the described chip: 32
+    microbatches of one row of 2048 through four delta layers (the
+    chunked scan of ``ops/delta_rule.py``, a rematerialised segment of
+    ``SEGMENT_CHUNKS`` chunks at a time) and one latent layer without a
+    rotary, accumulated in float32, Adam on float32 masters, parameters
+    and optimizer state donated. The compiler's count of its memory is
+    what the cell's size hangs on: 602 M parameters are 12.05 GB at 20
+    bytes before a row's activations, and a program is held to 13.9
+    GB. With the whole row's coefficients kept for the backward at once
+    the step read 15.6 to 17.4 GB (the pairwise [16, 16, 128] decays
+    materialised: 17.4); a segment of 8 chunks 13.56, of 4 13.19
+    (PERF.md, PR 39)."""
+    from realhf_tpu.ops import delta_rule
+
+    assert delta_rule.SEGMENT_CHUNKS == 4
+    step, *args = _sft_train_step(
+        one_chip, "kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 32,
+        row_len=2048)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "gmm" in text  # both kinds of kernel
+    memory = compiled.memory_analysis()
+    assert 12.8e9 < (memory.argument_size_in_bytes
+                     + memory.temp_size_in_bytes) < 13.5e9
 
 
 def test_flash_compiles_under_shard_map(topo):
