@@ -8,7 +8,10 @@ compile and run in CI.
 """
 
 import collections
+import contextlib
+import faulthandler
 import os
+import sys
 
 # The tests share many tiny programs: one persistent compile cache with
 # no floor on compile time or entry size. It is the tests' own
@@ -53,6 +56,16 @@ from jax._src import cache_key as _cache_key  # noqa: E402
 from jax._src import compiler as _compiler  # noqa: E402
 
 jax.config.update("jax_enable_compilation_cache", True)
+# The TPU interpreter (``interpreted_kernels`` below) reads and writes a
+# kernel's memory in io_callbacks that run jitted ops of their own on
+# the ``jax.Array``s they are handed (``device_id + 1``, ``tuple(idx)``)
+# while the CPU program that called them is in flight. Under
+# asynchronous dispatch the test's thread is issuing the next eager op
+# at the same time, and now and then, under load, neither dispatch
+# returns (CHANGES.md, PR 40, has both stacks). The CPU client reads
+# this flag when it is made, so it is the process's and no block's;
+# programs with collectives are launched asynchronously all the same.
+jax.config.update("jax_cpu_enable_async_dispatch", False)
 _compile_or_get_cached = _compiler.compile_or_get_cached
 _compiled_here = collections.OrderedDict()
 _KEEP_COMPILED = 256
@@ -86,6 +99,26 @@ def _compile_each_program_once(backend, computation, devices,
 _compiler.compile_or_get_cached = _compile_each_program_once
 
 import pytest  # noqa: E402
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
+
+
+@contextlib.contextmanager
+def _interpreted_kernels():
+    assert not jax.config.read("jax_cpu_enable_async_dispatch"), (
+        "the TPU interpreter's callbacks deadlock with a caller that "
+        "dispatches asynchronously: see the top of tests/conftest.py")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture
+def interpreted_kernels():
+    """``with interpreted_kernels():`` runs the Pallas TPU kernels
+    traced inside it through jax's interpreter on the CPU. The ONE way
+    into ``pltpu.force_tpu_interpret_mode()`` (``test_tier1_budget.py``
+    holds that): it is safe only in a process that dispatches
+    synchronously, which this file sets and this checks."""
+    return _interpreted_kernels
 
 
 @pytest.fixture(scope="module")
@@ -101,6 +134,34 @@ def programs_compiled_by_this_tree():
     yield
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+#: Seconds ONE test may take, with the set-up and tear-down it pays.
+#: Past it the worker prints every thread's stack and exits: xdist
+#: reports that test failed by name, starts a new worker on the rest
+#: of the file, and the run goes on instead of sitting on a deadlock
+#: (a native rendezvous, a callback waiting for its caller) until the
+#: driver's clock cuts it with whatever stood behind it uncounted.
+#: About three times the dearest tier-1 test (CHANGES.md, PR 40). A
+#: test that needs more is marked ``slow``, which takes it out of
+#: tier-1 and from under the limit (a whole train step compiled for
+#: the described chip takes two to three minutes); there is
+#: no other way to raise it.
+TEST_LIMIT_S = 300
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item):
+    if item.get_closest_marker("slow") is not None:
+        return (yield)
+    from _pytest.faulthandler import fault_handler_stderr_fd_key
+    # (pytest's own copy of stderr: fd 2 is captured while a test runs)
+    log = item.config.stash.get(fault_handler_stderr_fd_key, sys.__stderr__)
+    faulthandler.dump_traceback_later(TEST_LIMIT_S, exit=True, file=log)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_configure(config):

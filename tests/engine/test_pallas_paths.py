@@ -2,7 +2,7 @@
 
 The kernel gates key on ``pallas_enabled()`` (real TPU, or the
 ``REALHF_TPU_FORCE_PALLAS=1`` test hook). With the hook set and
-``pltpu.force_tpu_interpret_mode()`` active, ``T.prefill`` +
+``interpreted_kernels()`` active, ``T.prefill`` +
 ``T.decode_step`` run the SAME plumbing a TPU runs -- the decode
 partitioning chooser and the heads-sharded / KV-sequence-split
 shard_map kernel wrappers -- with interpret-mode kernels on the
@@ -80,7 +80,8 @@ def _one_decode_step(cfg, params, mesh, uniform_slot=True):
 
 
 @pytest.mark.parametrize("dp,tp,path", [(4, 2, "heads"), (2, 4, "seq")])
-def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch):
+def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch,
+                                        interpreted_kernels):
     cfg = _cfg()
     params = T.init_params(cfg, jax.random.PRNGKey(0))
 
@@ -97,7 +98,7 @@ def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch):
         mesh, 4, cfg.n_q_heads, cfg.n_kv_heads, 16) == path
 
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh=mesh)
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
 
@@ -110,7 +111,8 @@ def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch):
     ids=["hd64_group7", "hd128_group4", "hd64_group7_window_d2t2",
          "hd128_group4_window_d1t1"])
 def test_decode_step_unrolled_reads_the_stack_in_place(
-        hd, nq, window, mesh, uniform_slot, monkeypatch):
+        hd, nq, window, mesh, uniform_slot, monkeypatch,
+        interpreted_kernels):
     """The unrolled layer loop (every model of 48 layers or fewer)
     hands the stacked kernel the whole cache and a static layer
     index: equal to the XLA path on a layer sliced out, at the
@@ -129,7 +131,7 @@ def test_decode_step_unrolled_reads_the_stack_in_place(
         D, "flash_decode_attention_stacked",
         lambda *a, **kw: calls.append(a[4]) or kernel(*a, **kw))
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh and _mesh(*mesh),
                                uniform_slot)
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
@@ -137,7 +139,8 @@ def test_decode_step_unrolled_reads_the_stack_in_place(
 
 
 @pytest.mark.parametrize("dp,tp", [(4, 2), (2, 4)])
-def test_decode_step_stacked_scan_path(dp, tp, monkeypatch):
+def test_decode_step_stacked_scan_path(dp, tp, monkeypatch,
+                                       interpreted_kernels):
     """Deep-model wiring: dropping the unroll threshold forces the
     layer lax.scan with a TRACED layer index, so decode_step routes
     through the scalar-prefetch stacked kernel
@@ -149,14 +152,14 @@ def test_decode_step_stacked_scan_path(dp, tp, monkeypatch):
 
     monkeypatch.setattr(T, "_DECODE_UNROLL_MAX_LAYERS", 0)
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh=_mesh(dp, tp))
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
 
 
 @pytest.mark.parametrize("dp,tp", [(1, 1), (2, 2)])
 def test_generate_span_says_how_the_decode_loop_reads_the_cache(
-        dp, tp, monkeypatch):
+        dp, tp, monkeypatch, interpreted_kernels):
     """Every ``engine:generate`` span carries ``decode_kernel`` and
     ``decode_layer_copies``, read once from the program's compiled
     text: ``stacked`` and 0 where the kernel reads the stack in
@@ -192,7 +195,7 @@ def test_generate_span_says_how_the_decode_loop_reads_the_cache(
     assert [a["decode_kernel"] for a in xla] == ["xla", "xla"]
     assert [a["compiled"] for a in xla] == [True, False]
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got, stacked = run()
     np.testing.assert_array_equal(got, ref)
     for attrs in stacked:
@@ -200,7 +203,8 @@ def test_generate_span_says_how_the_decode_loop_reads_the_cache(
         assert attrs["decode_layer_copies"] == 0
 
 
-def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
+def test_engine_counts_the_key_blocks_its_flash_kernels_visit(
+        monkeypatch, interpreted_kernels):
     """A packed batch through ``train_batch`` and ``forward_logprobs``
     with the flash kernels engaged: each ``engine:*`` span carries
     ``flash_block_share`` and ``flash_kv_blocks_total{kind}`` grows by
@@ -251,7 +255,7 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
         "engine:train")[0]["attributes"]
 
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         capture = run(engine())
     [train] = capture.named("engine:train")
     [logprobs] = capture.named("engine:logprobs")
@@ -262,11 +266,19 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(monkeypatch):
                                kind=kind) == blocks * cfg.n_layers
 
 
-def _train_spans(program, text, monkeypatch, cfg=None):
+@pytest.fixture(scope="module")
+def xla_path_spans():
+    """(program, dense) -> the spans of the XLA-path engine."""
+    return {}
+
+
+def _train_spans(program, text, monkeypatch, interpreted_kernels,
+                 xla_path_spans, cfg=None):
     """The attributes of the two ``engine:<program>`` spans of an
     engine that takes two steps of ``program`` under a capture, with
-    its rows on the XLA path (no text is read) and on the flash
-    kernels (ONE read of the program's compiled text, which is
+    its rows on the XLA path (no text is read; ``text`` has no part
+    in that run, so it is made once a program and model) and on the
+    flash kernels (ONE read of the program's compiled text, which is
     ``text``: a text of the chip's, since the interpreter's CPU
     program holds no custom call and no product under a scope's name;
     ``tests/ops/test_chip_compile.py`` reads the real ones)."""
@@ -277,6 +289,7 @@ def _train_spans(program, text, monkeypatch, cfg=None):
     from realhf_tpu.ops import functional as F
     from realhf_tpu.parallel.mesh import MeshContext
 
+    dense = cfg is None
     cfg = cfg or _cfg()
     ctx = MeshContext(ModelName("default", 0), _mesh(1, 1),
                       ParallelismConfig())
@@ -314,10 +327,12 @@ def _train_spans(program, text, monkeypatch, cfg=None):
         return [s["attributes"]
                 for s in tracing.stop().named(f"engine:{program}")]
 
-    off = run()
-    assert read == []
+    if (program, dense) not in xla_path_spans:
+        xla_path_spans[program, dense] = run()
+        assert read == []
+    off = xla_path_spans[program, dense]
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         on = run()
     assert read == [program]
     return off, on
@@ -326,7 +341,7 @@ def _train_spans(program, text, monkeypatch, cfg=None):
 @pytest.mark.parametrize("program,ratio", [("train", 1.0), ("train", 2.0),
                                            ("train_seq", 1.0)])
 def test_train_span_says_how_often_the_forward_kernel_runs(
-        program, ratio, monkeypatch):
+        program, ratio, monkeypatch, interpreted_kernels, xla_path_spans):
     """Every ``engine:train`` / ``engine:train_seq`` span of a program
     whose rows go to the flash kernels carries ``flash_fwd_per_bwd``,
     read ONCE from the program's compiled text after its first call.
@@ -336,7 +351,8 @@ def test_train_span_says_how_often_the_forward_kernel_runs(
         + "".join(f"  %flash_fwd.{i} = f32[8]{{0}} {call}"
                   for i in range(int(ratio))) \
         + f"  ROOT %flash_bwd_dq.1 = f32[8]{{0}} {call}}}\n"
-    off, on = _train_spans(program, text, monkeypatch)
+    off, on = _train_spans(program, text, monkeypatch,
+                           interpreted_kernels, xla_path_spans)
     assert all("flash_fwd_per_bwd" not in attrs for attrs in off)
     assert [attrs["flash_fwd_per_bwd"] for attrs in on] == [ratio] * 2
 
@@ -345,7 +361,8 @@ def test_train_span_says_how_often_the_forward_kernel_runs(
                                               ("train_seq", 0),
                                               ("train_seq", 4)])
 def test_train_span_counts_the_projections_run_a_second_time(
-        program, products, monkeypatch):
+        program, products, monkeypatch, interpreted_kernels,
+        xla_path_spans):
     """The sibling of ``flash_fwd_per_bwd`` from the same ONE read of
     the text: ``attn_proj_remat_products``, the products under part
     ``attn_proj`` in the rematerialised forward
@@ -365,14 +382,16 @@ def test_train_span_counts_the_projections_run_a_second_time(
     text = "%body (q: bf16[8,8]) -> bf16[8,8] {\n" \
         + "".join(product(i, "attn_proj") for i in range(products)) \
         + product(9, "mlp") + "}\n"
-    off, on = _train_spans(program, text, monkeypatch)
+    off, on = _train_spans(program, text, monkeypatch,
+                           interpreted_kernels, xla_path_spans)
     assert all("attn_proj_remat_products" not in attrs for attrs in off)
     assert [attrs["attn_proj_remat_products"] for attrs in on] \
         == [products] * 2
     assert all("flash_fwd_per_bwd" not in attrs for attrs in on)
 
 
-def test_train_span_says_which_grouped_matmul_the_experts_run(monkeypatch):
+def test_train_span_says_which_grouped_matmul_the_experts_run(
+        monkeypatch, interpreted_kernels, xla_path_spans):
     """Every ``engine:train`` span of a sparse model in the ragged mode
     whose program's text has been read carries ``moe_products`` (``gmm``
     where the program holds ``ops/grouped_matmul.py``'s kernels, else
@@ -391,7 +410,8 @@ def test_train_span_says_which_grouped_matmul_the_experts_run(monkeypatch):
         for i, name in enumerate(names)) + "}\n"
     cfg = _cfg(mlp_type="moe", moe=MoEConfig(num_experts=4, top_k=2,
                                              routing_type="none"))
-    off, on = _train_spans("train", text, monkeypatch, cfg)
+    off, on = _train_spans("train", text, monkeypatch,
+                           interpreted_kernels, xla_path_spans, cfg)
     assert all("moe_products" not in attrs for attrs in off)
     for attrs in on:
         assert attrs["moe_dispatch"] == "ragged"

@@ -498,14 +498,13 @@ def test_one_block_models_refuse_the_per_layer_fields():
 
 
 def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
-        monkeypatch):
+        monkeypatch, interpreted_kernels):
     """Heads of 64 and rows of 1024, so that the packed rows meet the
     flash kernels' gate: with the kernels engaged (interpret mode) the
     stack of window and full layers gives the XLA path's hidden
     states, and ``flash_kv_blocks_total`` adds up each layer by its own
     rule: a full layer visits the 6 block pairs (256 x 512) under a
     row's diagonal, a layer with a window of 128 visits 5 of them."""
-    from jax.experimental.pallas import tpu as pltpu
 
     from realhf_tpu.obs import tracing
     from realhf_tpu.ops.flash_attention import block_counts
@@ -532,7 +531,7 @@ def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
     assert not any(k.startswith("flash_kv_blocks_total")
                    for k in xla.counters)
     monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got, capture = run()
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
     [span] = capture.named("engine:hidden")

@@ -244,11 +244,12 @@ def _sft_train_step(one_chip, config_name, family, microbatches,
 
 @functools.lru_cache(maxsize=None)
 def _compiled_microbatch(one_chip, config_name, family):
-    """Compiled once a file: Laguna's takes half a minute here, and
-    two tests read it."""
+    """Compiled once a file: Laguna's takes two minutes here, and two
+    tests read it."""
     return _compile(*_sft_microbatch(one_chip, config_name, family))
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("limit", [True, False],
                          ids=["as_it_is", "without_vmem_limit"])
 def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
@@ -296,7 +297,10 @@ def test_lagunas_whole_microbatch_compiles(one_chip, monkeypatch, limit):
 
 
 @pytest.mark.parametrize("config,family,calls,gate,q_products,gigabytes", [
-    ("laguna-xs.2-l5-ep16", "laguna", 5, 5, 10, 3.15),
+    # (Laguna's whole microbatch, two minutes to compile: with the
+    # test above that shares it)
+    pytest.param("laguna-xs.2-l5-ep16", "laguna", 5, 5, 10, 3.15,
+                 marks=pytest.mark.slow),
     ("qwen2.5-0.5b", "qwen2", 1, 0, None, None),
 ], ids=["laguna_unrolled_5", "qwen_scanned_24"])
 def test_rematerialised_stack_runs_the_forward_kernel_once_a_layer(
@@ -370,6 +374,7 @@ def test_flash_compiles_at_moonlights_two_widths(one_chip):
     _compile(_flash_grads, *args)
 
 
+@pytest.mark.slow
 def test_moonlights_whole_microbatch_compiles(one_chip):
     """The seventh cell's train program as the chip compiles it: one
     microbatch's SFT forward and backward of ALL FIVE of
@@ -468,6 +473,7 @@ def test_grouped_matmul_compiles_in_float32_at_moonlights_shape(one_chip):
              sds((8, 2048, 1408), jnp.float32), sds((8,), jnp.int32))
 
 
+@pytest.mark.slow
 def test_moonlights_whole_train_step_compiles(one_chip):
     """The seventh cell's WHOLE train step for the described chip: 32
     microbatches of one row of 4096 scanned, accumulated in float32,
@@ -494,6 +500,7 @@ def test_moonlights_whole_train_step_compiles(one_chip):
                      + memory.temp_size_in_bytes) < 13.6e9
 
 
+@pytest.mark.slow
 def test_kimis_whole_train_step_compiles(one_chip):
     """The eighth cell's WHOLE train step for the described chip: 32
     microbatches of one row of 2048 through four delta layers (the
@@ -506,10 +513,7 @@ def test_kimis_whole_train_step_compiles(one_chip):
     GB. With the whole row's coefficients kept for the backward at once
     the step read 15.6 to 17.4 GB (the pairwise [16, 16, 128] decays
     materialised: 17.4); a segment of 8 chunks 13.56, of 4 13.19
-    (PERF.md, PR 39)."""
-    from realhf_tpu.ops import delta_rule
-
-    assert delta_rule.SEGMENT_CHUNKS == 4
+    (PERF.md, PR 39; ``tests/ops/test_delta_rule.py`` holds the 4)."""
     step, *args = _sft_train_step(
         one_chip, "kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 32,
         row_len=2048)

@@ -10,7 +10,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from realhf_tpu.ops.attention import packed_attention, packed_attention_xla
 from realhf_tpu.ops import flash_attention as fa
@@ -34,24 +33,27 @@ def make_inputs(rng, b=2, l=256, nq=4, nkv=2, hd=32, n_segs=3,
     return q, k, v, jnp.asarray(seg)
 
 
-def _interp_flash(q, k, v, seg, **kw):
-    with pltpu.force_tpu_interpret_mode():
-        return fa.flash_attention(q, k, v, seg, **kw)
+@pytest.fixture
+def interp_flash(interpreted_kernels):
+    def run(q, k, v, seg, **kw):
+        with interpreted_kernels():
+            return fa.flash_attention(q, k, v, seg, **kw)
+    return run
 
 
 @pytest.mark.parametrize("blocks", [(64, 64), (128, 64), (64, 128)])
-def test_forward_matches_xla(blocks):
+def test_forward_matches_xla(blocks, interp_flash):
     rng = np.random.default_rng(0)
     q, k, v, seg = make_inputs(rng)
     ref = packed_attention_xla(q, k, v, seg)
-    got = _interp_flash(q, k, v, seg, block_q=blocks[0], block_k=blocks[1])
+    got = interp_flash(q, k, v, seg, block_q=blocks[0], block_k=blocks[1])
     # rows that are entirely padding are unspecified in the XLA path
     valid = np.asarray(seg) != 0
     np.testing.assert_allclose(np.asarray(got)[valid],
                                np.asarray(ref)[valid], rtol=2e-3, atol=2e-3)
 
 
-def test_gradients_match_xla():
+def test_gradients_match_xla(interpreted_kernels):
     rng = np.random.default_rng(1)
     q, k, v, seg = make_inputs(rng, l=128, n_segs=2)
 
@@ -64,7 +66,7 @@ def test_gradients_match_xla():
         return (o * jnp.where(seg[..., None, None] != 0, 1.0, 0.0)).sum()
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gr, gf, "qkv"):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
@@ -72,37 +74,37 @@ def test_gradients_match_xla():
                                    err_msg=f"d{name} mismatch")
 
 
-def test_segment_isolation():
+def test_segment_isolation(interp_flash):
     """Perturbing segment 2's K/V must not change segment 1's output."""
     rng = np.random.default_rng(2)
     q, k, v, seg = make_inputs(rng, b=1, l=128, n_segs=2, with_pad=False)
-    out1 = _interp_flash(q, k, v, seg, block_q=64, block_k=64)
+    out1 = interp_flash(q, k, v, seg, block_q=64, block_k=64)
     seg_np = np.asarray(seg)[0]
     second = np.where(seg_np == 2)[0]
     k2 = k.at[0, second].add(1.0)
     v2 = v.at[0, second].add(1.0)
-    out2 = _interp_flash(q, k2, v2, seg, block_q=64, block_k=64)
+    out2 = interp_flash(q, k2, v2, seg, block_q=64, block_k=64)
     first = np.where(seg_np == 1)[0]
     np.testing.assert_allclose(np.asarray(out1)[0, first],
                                np.asarray(out2)[0, first], rtol=1e-5,
                                atol=1e-6)
 
 
-def test_non_causal():
+def test_non_causal(interp_flash):
     rng = np.random.default_rng(3)
     q, k, v, seg = make_inputs(rng, l=128, n_segs=2, with_pad=False)
     ref = packed_attention_xla(q, k, v, seg, causal=False)
-    got = _interp_flash(q, k, v, seg, causal=False, block_q=64, block_k=64)
+    got = interp_flash(q, k, v, seg, causal=False, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
 
 
-def test_padding_rows_emit_zeros():
+def test_padding_rows_emit_zeros(interp_flash):
     """All-padding rows must output exactly zero (contract for the
     residual stream at pad slots)."""
     rng = np.random.default_rng(4)
     q, k, v, seg = make_inputs(rng, b=1, l=128, n_segs=2, with_pad=True)
-    out = _interp_flash(q, k, v, seg, block_q=64, block_k=64)
+    out = interp_flash(q, k, v, seg, block_q=64, block_k=64)
     pad = np.asarray(seg)[0] == 0
     assert pad.any()
     assert np.abs(np.asarray(out)[0, pad]).max() == 0.0
@@ -235,7 +237,8 @@ def _dense_ranges(seg, bq, bk, causal=True, sliding_window=None,
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("kind,align", [("both", 1), ("tail", 64),
                                         ("between", 8)])
-def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch):
+def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch,
+                                      interpreted_kernels):
     """Forward and all three gradients with the loops bounded by the
     segments' ranges are bitwise what the same kernels give when they
     visit every block, and within the XLA path's tolerances."""
@@ -257,7 +260,7 @@ def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch):
         return [np.asarray(x) for x in (out,) + grads]
 
     flash = functools.partial(fa.flash_attention, block_q=32, block_k=64)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         by_segment = run(flash)
         with monkeypatch.context() as patch:
             patch.setattr(fa, "block_ranges", _dense_ranges)
@@ -352,7 +355,7 @@ def test_block_counts_under_a_window(row, window, want):
 
 
 @pytest.mark.parametrize("window", [5, 32, 64, 70, 200])
-def test_windowed_kernels_match_the_xla_mask(window):
+def test_windowed_kernels_match_the_xla_mask(window, interpreted_kernels):
     """Forward and all three gradients of the windowed kernels (in
     interpret mode, blocks of 32 x 64: windows below, at and above
     both) against the XLA path's explicit mask, on packed rows whose
@@ -373,7 +376,7 @@ def test_windowed_kernels_match_the_xla_mask(window):
             loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return [np.asarray(x) for x in (out,) + grads]
 
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         got = run(functools.partial(fa.flash_attention, block_q=32,
                                     block_k=64))
     want = run(packed_attention_xla)
@@ -449,7 +452,7 @@ def test_only_a_row_of_4096_at_heads_of_128_asks_for_more_vmem(
                                               (4, 1, 128, 40)],
                          ids=["gqa_hd32", "mha_hd64", "window_hd128"])
 def test_the_saved_log_sum_exp_broadcasts_back_to_the_kernels_own(
-        nq, nkv, hd, window):
+        nq, nkv, hd, window, interpreted_kernels):
     """The forward rule keeps ONE float32 a (row, head) of the
     kernel's lane-broadcast ``[B, nq, L, 128]`` log-sum-exp
     (``RESIDUAL_NAMES``) and the backward broadcasts it again: bit for
@@ -457,7 +460,7 @@ def test_the_saved_log_sum_exp_broadcasts_back_to_the_kernels_own(
     included, so the backward kernels read what they always read."""
     rng = np.random.default_rng(7)
     q, k, v, seg = make_inputs(rng, b=2, l=128, nq=nq, nkv=nkv, hd=hd)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         out, lanes = fa._flash_fwd(q, k, v, seg, hd ** -0.5, True, 64, 64,
                                    window)
         kept_out, res = fa._flash_attention_fwd(
@@ -489,7 +492,8 @@ def _two_widths(rng, b, l, nq, nkv, hd, hv, n_segs=3):
     (16, 16, 192, 128, None), (4, 2, 24, 12, None), (4, 4, 64, 128, None),
     (2, 2, 192, 128, 40)],
     ids=["moonlight_16x192x128", "gqa_24x12", "value_wider", "windowed"])
-def test_kernels_at_two_widths_match_the_xla_mask(nq, nkv, hd, hv, window):
+def test_kernels_at_two_widths_match_the_xla_mask(nq, nkv, hd, hv, window,
+                                                  interpreted_kernels):
     """Scores over a key ``hd`` wide, values and output ``hv`` wide
     (Moonlight's 192 and 128 among them, 192 no multiple of the 128
     lanes): the forward and all three gradients of the kernels, in
@@ -509,7 +513,7 @@ def test_kernels_at_two_widths_match_the_xla_mask(nq, nkv, hd, hv, window):
 
     (_, want), gr = jax.value_and_grad(
         loss(packed_attention_xla), argnums=(0, 1, 2), has_aux=True)(q, k, v)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         (_, got), gf = jax.value_and_grad(loss(functools.partial(
             fa.flash_attention, block_q=64, block_k=64)),
             argnums=(0, 1, 2), has_aux=True)(q, k, v)
@@ -549,12 +553,12 @@ def test_equal_widths_keep_the_kernels_jaxprs():
     assert "f32[1,4,256,192]" in text and "f32[1,4,256,128]" in text
 
 
-def test_the_kept_residuals_have_the_values_width():
+def test_the_kept_residuals_have_the_values_width(interpreted_kernels):
     """``flash_out`` is the VALUE's width (128 of Moonlight's 192): the
     kept output head-major ``[B, nq, L, hv]``, q kept at the key's."""
     rng = np.random.default_rng(13)
     q, k, v, seg = _two_widths(rng, 1, 128, 2, 2, 24, 12)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         out, res = fa._flash_attention_fwd(q, k, v, seg, 24 ** -0.5, True,
                                            64, 64, None)
     assert out.shape == (1, 128, 2, 12)

@@ -10,7 +10,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from realhf_tpu.ops import grouped_matmul as gm
 from realhf_tpu.ops import moe as moe_ops
@@ -21,8 +20,8 @@ K, N = 64, 96
 
 
 @pytest.fixture(autouse=True)
-def _interpreted():
-    with pltpu.force_tpu_interpret_mode():
+def _interpreted(interpreted_kernels):
+    with interpreted_kernels():
         yield
 
 

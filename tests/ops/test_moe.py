@@ -356,7 +356,7 @@ def share_layer(seed=0, tokens=40):
 
 
 @pytest.fixture(params=["ragged_dot", "kernels"])
-def products(request, monkeypatch):
+def products(request, monkeypatch, interpreted_kernels):
     """Both ways a share's grouped products run
     (``ops/moe.py:_grouped_products``): ``lax.ragged_dot`` with every
     row in a group, and ``ops/grouped_matmul.py``'s kernels, here in
@@ -364,12 +364,11 @@ def products(request, monkeypatch):
     if request.param == "ragged_dot":
         yield request.param
         return
-    from jax.experimental.pallas import tpu as pltpu
     monkeypatch.setattr(moe_ops, "pallas_enabled", lambda: True)
     # (the interpreter's callbacks are effects that the fallback's
     # ``checkpoint`` cannot split under a gradient)
     monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         yield request.param
 
 

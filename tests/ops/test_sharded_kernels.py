@@ -35,7 +35,7 @@ def _mesh(dp=2, tp=2):
     return make_mesh(par, devices=jax.devices("cpu")[:par.world_size])
 
 
-def test_sharded_packed_attention_matches_xla():
+def test_sharded_packed_attention_matches_xla(interpreted_kernels):
     rng = np.random.default_rng(0)
     b, l, nq, nkv, hd = 4, 128, 8, 4, 128
     q = jnp.asarray(rng.standard_normal((b, l, nq, hd)), jnp.float32)
@@ -47,7 +47,7 @@ def test_sharded_packed_attention_matches_xla():
     seg = jnp.asarray(seg)
 
     ref = packed_attention_xla(q, k, v, seg, causal=True)
-    inner = functools.partial(_interp_packed)
+    inner = functools.partial(_interp_packed, interpreted_kernels)
     attn = make_sharded_attention(_mesh(), inner=inner)
     got = jax.jit(lambda *a: attn(*a))(q, k, v, seg)
     valid = np.asarray(seg) != 0  # pad-row outputs are don't-care
@@ -56,16 +56,15 @@ def test_sharded_packed_attention_matches_xla():
                                atol=2e-5, rtol=2e-5)
 
 
-def _interp_packed(q, k, v, seg, causal=True, scale=None,
-                   sliding_window=None, **blocks):
-    from jax.experimental.pallas import tpu as pltpu
+def _interp_packed(interpreted_kernels, q, k, v, seg, causal=True,
+                   scale=None, sliding_window=None, **blocks):
     assert sliding_window is None
-    with pltpu.force_tpu_interpret_mode():
+    with interpreted_kernels():
         return flash_attention(q, k, v, seg, causal=causal, scale=scale,
                                **blocks)
 
 
-def test_sharded_packed_attention_ranges_per_shard():
+def test_sharded_packed_attention_ranges_per_shard(interpreted_kernels):
     """Each shard bounds its kernels' loops from its LOCAL segment
     ids: rows packed differently on the two data shards, several
     blocks a row, forward and the three gradients against XLA."""
@@ -84,12 +83,12 @@ def test_sharded_packed_attention_ranges_per_shard():
             return (jnp.where(valid, attn(q, k, v, seg), 0.0) * w).sum()
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    from jax.experimental.pallas import tpu as pltpu
     ref = grads_of(packed_attention_xla)
-    with pltpu.force_tpu_interpret_mode():  # the backward is traced late
+    with interpreted_kernels():  # the backward is traced late
         got = grads_of(make_sharded_attention(
-            _mesh(), inner=functools.partial(_interp_packed, block_q=64,
-                                             block_k=64)))
+            _mesh(), inner=functools.partial(
+                _interp_packed, interpreted_kernels, block_q=64,
+                block_k=64)))
     for name, a, b_ in zip("qkv", got, ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=2e-4, rtol=2e-4,
