@@ -45,6 +45,7 @@ from realhf_tpu.ops.attention import flash_takes
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
+from realhf_tpu.ops.delta_rule import scan_kernel_calls
 from realhf_tpu.ops.flash_attention import block_counts, flash_fwd_per_bwd
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
@@ -517,7 +518,12 @@ class Engine:
         of a model in the ragged dispatch mode ``moe_products``,
         ``moe_gmm_calls``, ``moe_ragged_dot_calls``
         (``ops.moe.grouped_product_calls``: which grouped matmul the
-        experts' products really are). Sets gauge
+        experts' products really are), and of every program of a model
+        with delta layers ``delta_scan_kernel_calls``
+        (``ops.delta_rule.scan_kernel_calls``: the chunked scan's
+        kernels in the text: a forward and a backward one a delta
+        layer of a train program, 0 where the XLA products run). Sets
+        gauge
         ``engine_program_bytes{role,program,kind}``."""
         if (name, key) not in self._facts:
             if name == "generate":
@@ -531,11 +537,15 @@ class Engine:
             else:
                 mine = None
             ragged = moe_ops.dispatch_mode(self.cfg) == "ragged"
+            delta = self.cfg.delta is not None
 
             def derive(text):
                 out = mine(text) if mine is not None else {}
                 if ragged:
                     out.update(moe_ops.grouped_product_calls(text))
+                if delta:
+                    out.update(delta_scan_kernel_calls=scan_kernel_calls(
+                        text))
                 return out
 
             facts = parts.read_program(self._compiled(name, call), derive)
@@ -713,7 +723,7 @@ class Engine:
                         activation_constraint=constrain,
                         attention_fn=self._attention_fn,
                         moe_constraint=self._moe_constraint,
-                        pipeline=pipeline)
+                        pipeline=pipeline, mesh=self.mesh)
         return out[0], (out[2] if with_aux else {})
 
     # ------------------------------------------------------------------

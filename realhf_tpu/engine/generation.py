@@ -63,7 +63,7 @@ def generate(
     pad_token_id: int,
     activation_constraint=None,
     moe_constraint=None,
-    mesh=None,  # partitions the pallas decode kernels on dp x tp meshes
+    mesh=None,  # partitions the decode and delta kernels on dp x tp meshes
     attention_fn=None,  # sharded prefill attention on dp x tp meshes
 ) -> GenerationOutput:
     """Functional generation; wrap in jax.jit with gconfig/eos/pad
@@ -76,7 +76,8 @@ def generate(
             cfg, params, prompt_ids, prompt_seg, prompt_pos,
             total_len=lp + gconfig.max_new_tokens,
             activation_constraint=activation_constraint,
-            attention_fn=attention_fn, moe_constraint=moe_constraint)
+            attention_fn=attention_fn, moe_constraint=moe_constraint,
+            mesh=mesh)
         # left padding => last column is last token
         last_hidden = hidden[:, -1]
 

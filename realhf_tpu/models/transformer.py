@@ -61,7 +61,9 @@ from realhf_tpu.models.config import (DELTA_L2_EPS, LATENT_NORM_EPS,
                                       TransformerConfig)
 from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
-from realhf_tpu.ops.delta_rule import chunked_delta_rule, delta_rule_step
+from realhf_tpu.ops.delta_rule import RESIDUAL_NAMES as _SCAN_RESIDUALS
+from realhf_tpu.ops.delta_rule import (Prepare, chunked_delta_rule,
+                                       delta_rule_step)
 from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 
@@ -81,10 +83,14 @@ KVCache = Dict[str, jnp.ndarray]
 PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
 #: What a delta layer's chunked recurrence made (``_delta_op``): its
 #: heads' outputs, ``tokens x width`` values a layer a microbatch in
-#: the compute dtype. Kept, the rematerialised block does not run the
-#: recurrence a second time for its OUTPUT; its own backward runs it
-#: again a segment at a time (``ops/delta_rule.py``).
-DELTA_RESIDUALS = ("delta_out",)
+#: the compute dtype, and, where the recurrence is the kernels', what
+#: its forward hands its backward (``ops/delta_rule.py:
+#: RESIDUAL_NAMES``: every chunk's start state in float32). Kept, the
+#: rematerialised block does not run the recurrence a second time: not
+#: for its OUTPUT, and not for the backward kernel's sake. (The XLA
+#: path names the output alone: its own backward runs it again a
+#: segment at a time.)
+DELTA_RESIDUALS = ("delta_out",) + _SCAN_RESIDUALS
 #: every name the policy of a rematerialised block keeps
 KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS
 
@@ -535,17 +541,9 @@ def _delta_inputs(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
                for i, (x, (_, taps)) in enumerate(zip(raw, _DELTA_CONVS)))
     f = ((u @ c["w_fa"].astype(cdt)) @ c["w_fb"].astype(cdt)).reshape(heads)
     beta = jax.nn.sigmoid((u @ c["w_b"].astype(cdt)).astype(f32))
-    rate = -jnp.exp(c["a_log"].astype(f32))[:, None]
-    dt_bias = c["dt_bias"].astype(f32).reshape(heads[-2:])
-
-    def unit(x):
-        return x * jax.lax.rsqrt(
-            jnp.square(x).sum(-1, keepdims=True) + DELTA_L2_EPS)
-
-    def prepare(q, k, f):
-        return (unit(q) * dl.head_dim ** -0.5, unit(k),
-                rate * jax.nn.softplus(f + dt_bias))
-
+    prepare = Prepare(rate=-jnp.exp(c["a_log"].astype(f32)),
+                      dt_bias=c["dt_bias"].astype(f32).reshape(heads[-2:]),
+                      scale=dl.head_dim ** -0.5, eps=DELTA_L2_EPS)
     return jnp.concatenate(raw, axis=-1), q, k, v, f, beta, prepare
 
 
@@ -562,17 +560,19 @@ def _delta_output(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
 
 
 def _delta_op(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
-              seg_ids: jnp.ndarray):
+              seg_ids: jnp.ndarray, mesh=None):
     """The delta operator over packed rows on the normed residual u
     [B, L, H] -> (its projected output [B, L, H], (the convolutions'
     inputs [B, L, 3 x width], each row's state after its last token
     [B, n, hd, hd] float32)): what prefill's caches are made of. The
-    recurrence alone is sub-part ``delta/scan`` (obs/parts.py)."""
+    recurrence alone is sub-part ``delta/scan`` (obs/parts.py);
+    ``mesh``: what the arrays are sharded over, by which the
+    recurrence's kernels are partitioned (``ops/delta_rule.py``)."""
     raw, q, k, v, f, beta, prepare = _delta_inputs(
         cfg, c, u, lambda i, x, taps: _causal_conv(x, taps, seg_ids))
     with jax.named_scope(P.SCAN):
         o, last = chunked_delta_rule(q, k, v, f, beta, seg_ids,
-                                     prepare=prepare)
+                                     prepare=prepare, mesh=mesh)
         o = checkpoint_name(o, DELTA_RESIDUALS[0])
     proj = checkpoint_name(_delta_output(cfg, c, u, o),
                            PROJECTION_RESIDUALS[1])
@@ -652,7 +652,7 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
 def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
            x: jnp.ndarray, seg_ids: jnp.ndarray, cos: jnp.ndarray,
            sin: jnp.ndarray, constrain, attention_fn=None,
-           moe_constraint=None, kind=None, window=None):
+           moe_constraint=None, kind=None, window=None, mesh=None):
     """One block over packed streams [B, L, H]; returns (residual
     output, state, aux-losses). ``kind``: the layer's (operator,
     feed-forward) in a patterned model, None for the one block of
@@ -660,7 +660,7 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     whole document. The state feeds prefill's caches: (k, v) of an
     attention layer, the convolution's input s [B, L, H] of a conv
     layer, (the convolutions' inputs, the rows' last states) of a
-    delta layer; aux is non-empty for MoE."""
+    delta layer; aux is non-empty for MoE. ``mesh``: ``forward``'s."""
     op, sparse = ("attention", None) if kind is None \
         else (kind[0], kind[1] == "moe")
     # the norm before an operator and the residual's add after it go
@@ -674,7 +674,7 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
             proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
     elif op == "delta":
         with jax.named_scope(P.DELTA):
-            proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids)
+            proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids, mesh)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
                                     cos, sin, attention_fn, window, op)
@@ -781,6 +781,7 @@ def forward(
     attention_fn=None,
     moe_constraint=None,  # models/sharding.py moe_ep_constraint (EP)
     pipeline=None,  # parallel.pipeline.PipelineContext when pp > 1
+    mesh=None,  # what the arrays are sharded over (delta layers)
 ):
     """Packed forward pass -> final hidden states [B, L, H] (after the
     final norm). Heads are applied separately (`lm_logits`,
@@ -788,6 +789,10 @@ def forward(
 
     ``activation_constraint`` is an optional fn applied to the residual
     stream each block (sharding constraints; see models/sharding.py).
+    ``mesh``: the mesh the parameters and the batch are sharded over,
+    None for one device. A delta layer's kernels are partitioned by it
+    (``ops/delta_rule.py``: a Mosaic call has no rule by which GSPMD
+    could); attention brings its own in ``attention_fn``.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     constrain = activation_constraint or (lambda t: t)
@@ -876,7 +881,7 @@ def forward(
         with jax.named_scope(P.LAYERS):
             x, states, aux = _pattern_layers(
                 cfg, params["layers"], x, seg_ids, rotary, constrain,
-                attention_fn, moe_constraint, return_kv, return_aux)
+                attention_fn, moe_constraint, return_kv, return_aux, mesh)
         x = _final_norm(cfg, params, x)
         return (x, states, aux) if return_aux else (x, states)
 
@@ -921,7 +926,8 @@ def _final_norm(cfg: TransformerConfig, params: Params,
 
 
 def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
-                    attention_fn, moe_constraint, return_kv, return_aux):
+                    attention_fn, moe_constraint, return_kv, return_aux,
+                    mesh=None):
     """The layers of a patterned model, unrolled: x -> (x, states,
     aux). Each attention layer takes the window its operator says and
     the rotary table of its kind (``rotary``: ``_rotary_tables``).
@@ -940,7 +946,7 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
         def block_fn(lp, carry, i=i, kind=kind, cos=cos, sin=sin):
             return _block(cfg, lp, jnp.int32(i), carry, seg_ids, cos,
                           sin, constrain, attention_fn, moe_constraint,
-                          kind, cfg.layer_window(i))
+                          kind, cfg.layer_window(i), mesh)
 
         x, state, aux = _remat(cfg, block_fn)(layers[str(i)], x)
         if return_kv and kind[0] == "conv":
@@ -1067,8 +1073,8 @@ def delta_state_shapes(cfg: TransformerConfig, batch: int):
 def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
             seg_ids: jnp.ndarray, positions: Optional[jnp.ndarray] = None,
             *, total_len: Optional[int] = None, activation_constraint=None,
-            attention_fn=None,
-            moe_constraint=None) -> Tuple[jnp.ndarray, KVCache]:
+            attention_fn=None, moe_constraint=None,
+            mesh=None) -> Tuple[jnp.ndarray, KVCache]:
     """Run the packed forward and materialize a KV cache whose first
     L slots hold the prompt keys/values.
 
@@ -1079,7 +1085,7 @@ def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
                           return_kv=True,
                           activation_constraint=activation_constraint,
                           attention_fn=attention_fn,
-                          moe_constraint=moe_constraint)
+                          moe_constraint=moe_constraint, mesh=mesh)
     b, lp = input_ids.shape
     with jax.named_scope(P.ATTN):  # the caches' layout is the kernels'
         cache = _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len,

@@ -22,6 +22,7 @@ KERNELS = (
     "flash_attention",               # ops/flash_attention.py (packed fwd/bwd)
     "flash_decode_attention_stacked",  # ops/decode_attention.py
     "grouped_matmul",                # ops/grouped_matmul.py (the experts')
+    "delta_rule_scan",               # ops/delta_rule.py (chunked scan fwd/bwd)
 )
 
 

@@ -135,7 +135,7 @@ def _over_columns(tn: int, chunk_fn):
     jax.lax.fori_loop(0, tn // cn, turn, 0)
 
 
-def _params(held_bytes: int, semantics: Tuple[str, ...]):
+def _params(held_bytes: int, semantics: Tuple[str, ...], itemsize: int):
     """Compiler parameters of a call whose blocks and values take
     ``held_bytes`` of VMEM (``_tiles``): where those and a quarter more
     pass the default scoped limit the call asks for them, as
@@ -143,8 +143,14 @@ def _params(held_bytes: int, semantics: Tuple[str, ...]):
     luxury: inside Moonlight's whole train step ``gmm_t`` at blocks of
     15.1 MB and a product of 1.4 was refused for 96 KB over the
     default 16 MiB, where the microbatch's program alone compiled:
-    PERF.md, PR 38.)"""
-    limit = held_bytes * 5 // 4
+    PERF.md, PR 38.) Float32 operands (no benchmark cell has them: the
+    tests and ``chip_check.py``'s float32 rows, whose products run at
+    the highest precision) get half again: with the delta scan's
+    kernels in the same program Kimi-Linear's float32 forward was
+    refused for 1.83 MiB over the quarter at a held expert's
+    ``[2304, 1024]`` (what XLA stacks around a call differs by
+    program: PERF.md, PR 42)."""
+    limit = held_bytes * 3 // 2 if itemsize == 4 else held_bytes * 5 // 4
     return pltpu.CompilerParams(
         dimension_semantics=semantics,
         vmem_limit_bytes=limit if limit > DEFAULT_SCOPED_VMEM else None)
@@ -240,7 +246,7 @@ def _gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
                              else (None, k, tn), weights)],
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda j, v, g, t, o: (t[v], j))),
-        compiler_params=_params(held, ("parallel", "arbitrary")),
+        compiler_params=_params(held, ("parallel", "arbitrary"), size),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=size * (m * k + rhs.size + m * n)),
@@ -308,7 +314,7 @@ def _tgmm(lhs: jnp.ndarray, dout: jnp.ndarray, group_sizes: jnp.ndarray
                 (None, tk, tn), lambda j, i, v, g, t, o: (g[v], i, j)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
         compiler_params=_params(
-            held, ("parallel", "arbitrary", "arbitrary")),
+            held, ("parallel", "arbitrary", "arbitrary"), size),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=size * (m * k + m * n + groups * k * n)),

@@ -73,9 +73,10 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   against ``family.sft_loss_and_grad`` (the reference's gradient keeps
   a state a token: a row of 2,048 does not fit); and milliseconds of
   the chunked scan alone, forward and gradient, at the cell's shape by
-  ``ops/delta_rule.py:SEGMENT_CHUNKS``, with ``scan_accuracy``: the
-  chunked scan and the recurrence token by token in float32 on the
-  device, each against the recurrence in float64 on the host.
+  ``ops/delta_rule.py``'s kernels and by its XLA products side by side,
+  with ``scan_accuracy``: the chunked scan (through the kernels, and
+  by the XLA products) and the recurrence token by token in float32 on
+  the device, each against the recurrence in float64 on the host.
   ``--only <phases>`` runs these rows alone.
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
@@ -507,10 +508,13 @@ def gradient(cell, ckpt, tensors, seed, length=256):
         secs=round(time.monotonic() - t, 1))
 
 
-def scan_alone(cell, rounds=5):
+def scan_alone(cell, rounds=20):
     """Milliseconds of the chunked recurrence alone at the cell's shape
-    (one row of 2,048, 32 heads of 128), forward and gradient, by
-    ``SEGMENT_CHUNKS``."""
+    (one row of 2,048, 32 heads of 128, bf16 operands, a layer's
+    ``Prepare`` applied where each path applies it), forward and
+    gradient, by the kernels and by the XLA products, every case
+    compiled and warmed before any is timed; and how far the two lie
+    apart."""
     import jax
     import jax.numpy as jnp
     from realhf_tpu.ops import delta_rule as D
@@ -518,30 +522,104 @@ def scan_alone(cell, rounds=5):
     shape = (1, cell["traffic"]["doc_len"], lin["num_heads"],
              lin["head_dim"])
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q, k = (unit(jax.random.normal(key, shape)) for key in keys[:2])
-    v = jax.random.normal(keys[2], shape)
-    g = -jax.nn.softplus(jax.random.normal(keys[3], shape))
+    bf16 = jnp.bfloat16
+    q, k, v, g = (jax.random.normal(key, shape).astype(bf16)
+                  for key in keys[:4])
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
     seg = jnp.ones(shape[:2], jnp.int32)
-    rows = {}
-    was = D.SEGMENT_CHUNKS
-    for per in (2, 4, 8):
-        D.SEGMENT_CHUNKS = per
-        fwd = jax.jit(lambda *a: D.chunked_delta_rule(*a, seg)[0])
-        bwd = jax.jit(jax.grad(lambda *a: D.chunked_delta_rule(
-            *a, seg)[0].astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+    prepare = D.Prepare(rate=-jnp.ones(shape[2]),
+                        dt_bias=jnp.zeros(shape[2:]),
+                        scale=shape[3] ** -0.5, eps=1e-6)
+    rows, outs = {}, {}
+    for path, scan in (("kernel", D._by_kernels), ("xla", D._by_xla)):
+        fwd = jax.jit(lambda *a, scan=scan: scan(*a, seg, prepare)[0])
+        bwd = jax.jit(jax.grad(lambda *a, scan=scan: scan(
+            *a, seg, prepare)[0].astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4)))
         for name, fn in (("forward", fwd), ("gradient", bwd)):
-            jax.block_until_ready(fn(q, k, v, g, beta))
+            outs[path, name] = jax.block_until_ready(fn(q, k, v, g, beta))
+        for name, fn in (("forward", fwd), ("gradient", bwd)):
             t = time.monotonic()
             for _ in range(rounds):
                 out = fn(q, k, v, g, beta)
             jax.block_until_ready(out)
-            rows[f"{name}_ms_at_{per}_chunks"] = round(
+            rows[f"{name}_ms_{path}"] = round(
                 (time.monotonic() - t) / rounds * 1e3, 3)
-    D.SEGMENT_CHUNKS = was
+    f32 = jnp.float32
+    rows["forward_max_gap"] = float(jnp.abs(
+        outs["kernel", "forward"].astype(f32)
+        - outs["xla", "forward"].astype(f32)).max())
+    for name, a, b in zip("q k v g beta".split(), outs["kernel", "gradient"],
+                          outs["xla", "gradient"]):
+        rows[f"d{name}_relative_gap"] = float(
+            jnp.linalg.norm((a.astype(f32) - b.astype(f32)).ravel())
+            / jnp.linalg.norm(b.astype(f32).ravel()))
     say(phase="scan", shape=list(shape), **rows)
+    if len(jax.devices()) >= 4:
+        scan_on_mesh(shape, rounds)
     scan_accuracy(shape[1], lin["head_dim"])
+
+
+def scan_on_mesh(shape, rounds):
+    """Given four chips (``chiprun --chips 4``): the kernels on the
+    engine's dp2 x tp2 mesh, two rows of the cell's shape with each
+    device taking one row's half of the heads under ``shard_map``
+    (``ops/delta_rule.py:_scan_over``: a bare Mosaic call does not
+    lower on a mesh), against the kernels on ONE chip on the same
+    values: outputs, last states and every gradient, those of the
+    decay's two tensors (summed over "data") among them, and the
+    milliseconds of both."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from realhf_tpu.ops import delta_rule as D
+    from realhf_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS,
+                                          ParallelismConfig, make_mesh)
+    mesh = make_mesh(ParallelismConfig(data_parallel_size=2,
+                                       tensor_parallel_size=2),
+                     jax.devices()[:4])
+    _, l, h, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(1), 7)
+    wide = (2, l, h, d)
+    x = [jax.random.normal(key, wide).astype(jnp.bfloat16)
+         for key in keys[:4]]
+    x.append(jax.nn.sigmoid(jax.random.normal(keys[4], wide[:3])))
+    x.append(-jnp.exp(jax.random.uniform(keys[5], (h,))))
+    x.append(jax.random.normal(keys[6], (h, d)) - 3)
+    seg = jnp.ones(wide[:2], jnp.int32)
+    specs = [P(DATA_AXIS, None, MODEL_AXIS)] * 5 + [P(MODEL_AXIS)] * 2
+
+    def grads(mesh):
+        def loss(q, k, v, f, beta, rate, dt_bias):
+            o, last = D.chunked_delta_rule(
+                q, k, v, f, beta, seg, D.Prepare(
+                    rate=rate, dt_bias=dt_bias, scale=d ** -0.5, eps=1e-6),
+                mesh=mesh)
+            return o.astype(jnp.float32).sum() + last.sum(), (o, last)
+        return jax.jit(jax.grad(loss, argnums=tuple(range(7)),
+                                has_aux=True))
+
+    rows, outs = {}, {}
+    for name, fn, args in (
+            ("one_chip", grads(None), x),
+            ("mesh", grads(mesh), [jax.device_put(a, NamedSharding(mesh, s))
+                                   for a, s in zip(x, specs)])):
+        outs[name] = jax.block_until_ready(fn(*args))
+        t = time.monotonic()
+        for _ in range(rounds):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        rows[f"gradient_ms_{name}"] = round(
+            (time.monotonic() - t) / rounds * 1e3, 3)
+    f32 = jnp.float32
+    flat = lambda out: (*out[0], *out[1])
+    for name, a, b in zip("dq dk dv df dbeta drate ddt_bias o last".split(),
+                          flat(outs["mesh"]), flat(outs["one_chip"])):
+        a, b = np.asarray(a.astype(f32)), np.asarray(b.astype(f32))
+        rows[f"{name}_relative_gap"] = float(
+            np.linalg.norm((a - b).ravel()) / np.linalg.norm(b.ravel()))
+    say(phase="scan_on_mesh", shape=list(wide), mesh="d2t2", **rows)
 
 
 def scan_accuracy(length, hd, heads=4):
@@ -582,13 +660,17 @@ def scan_accuracy(length, hd, heads=4):
                 "nkv,nk->nv", s, k[0, t]))
             s = s + k[0, t][..., None] * u[:, None, :]
             truth[t] = np.einsum("nkv,nk->nv", s, q[0, t])
+        seg = jnp.ones(shape[:2], jnp.int32)
         with jax.default_matmul_precision("highest"):
             args = f32(q, k, v, g, beta)
+            # (``chunked_delta_rule`` takes the kernels on the chip)
             chunked = jax.jit(lambda *a: D.chunked_delta_rule(
-                *a, jnp.ones(shape[:2], jnp.int32))[0])(*args)
+                *a, seg)[0])(*args)
+            by_xla = jax.jit(lambda *a: D._by_xla(*a, seg, None)[0])(*args)
             stepped = jax.jit(token_by_token)(*args)
         say(phase="scan_accuracy", decay=decay, shape=list(shape),
             chunked=share(np.asarray(chunked)[0], truth),
+            chunked_xla=share(np.asarray(by_xla)[0], truth),
             token_by_token=share(np.asarray(stepped)[0], truth))
 
 

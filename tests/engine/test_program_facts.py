@@ -37,6 +37,9 @@ FAMILIES = {
                  {"conv", "mlp", "experts"}),
     "laguna": ("tests/benchmark/laguna/configs/tiny-laguna.json",
                {"mlp", "experts", "shared_expert"}),
+    "kimi_linear": (
+        "tests/benchmark/kimi_linear/configs/tiny-kimi-linear.json",
+        {"delta", "mlp", "experts", "shared_expert"}),
 }
 #: how the op_name of a loop's own operations ends
 PLUMBING = {"add", "lt", "closed_call", "dynamic_slice",
@@ -142,6 +145,15 @@ def test_train_program_names_every_part_the_family_has(family):
         assert facts.attributes["moe_gmm_calls"] == 0
     else:
         assert "moe_products" not in facts.attributes
+    # whether the delta layers' scan is the kernels', from the same
+    # read (here the XLA products: the backend is the CPU, and the
+    # tiny heads are no whole lane; on the chip one forward and one
+    # backward kernel a layer: test_chip_compile.py)
+    if "delta" in have:
+        assert "delta/scan" in passes
+        assert facts.attributes["delta_scan_kernel_calls"] == 0
+    else:
+        assert "delta_scan_kernel_calls" not in facts.attributes
     # what does the work is put down to a part: no product is left
     # out, and the fusions that carry an op_name and no part are the
     # loops' own plumbing (counters, a layer's slice out of the stack,
@@ -166,8 +178,10 @@ def test_generate_program_nests_the_parts_in_its_phases(family):
     assert facts.module == "jit_generate"
     sparse = {"moe_products", "moe_gmm_calls", "moe_ragged_dot_calls"} \
         if "experts" in FAMILIES[family][1] else set()
+    delta = {"delta_scan_kernel_calls"} \
+        if "delta" in FAMILIES[family][1] else set()
     assert set(facts.attributes) == {"decode_kernel",
-                                     "decode_layer_copies"} | sparse
+                                     "decode_layer_copies"} | sparse | delta
     seen = {(op[3], (op[0] or "").split("/")[0])
             for op in facts.ops.values()}
     assert {phase for phase, _ in seen} >= {"prefill", "decode", "sample"}

@@ -170,14 +170,18 @@ def _model(one_chip, config_name, family):
 
 def _as_on_a_tpu(fn):
     """``fn`` traced with ``pallas_enabled()`` true where the experts'
-    layer asks: the backend here is the CPU, and the chip's program
-    holds ``ops/grouped_matmul.py``'s kernels."""
+    layer and the delta layers' scan ask: the backend here is the CPU,
+    and the chip's program holds ``ops/grouped_matmul.py``'s and
+    ``ops/delta_rule.py``'s kernels."""
     from unittest import mock
 
+    from realhf_tpu.ops import delta_rule
     from realhf_tpu.ops import moe as moe_ops
 
     def traced(*args):
-        with mock.patch.object(moe_ops, "pallas_enabled", lambda: True):
+        with mock.patch.object(moe_ops, "pallas_enabled", lambda: True), \
+                mock.patch.object(delta_rule, "pallas_enabled",
+                                  lambda: True):
             return fn(*args)
     return traced
 
@@ -227,7 +231,7 @@ def _sft_train_step(one_chip, config_name, family, microbatches,
 
     cfg, params, sds, attn = _model(one_chip, config_name, family)
     engine = object.__new__(Engine)
-    engine.cfg, engine._attention_fn = cfg, attn
+    engine.cfg, engine._attention_fn, engine.mesh = cfg, attn, None
     engine._pipeline_ctx = engine._constrain = None
     engine._moe_constraint = None
     engine._grad_shardings = engine._opt_shardings = None
@@ -500,29 +504,106 @@ def test_moonlights_whole_train_step_compiles(one_chip):
                      + memory.temp_size_in_bytes) < 13.6e9
 
 
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.bfloat16, "default"), (jnp.float32, "highest")],
+    ids=["bf16_one_pass", "float32_highest"])
+@pytest.mark.parametrize("through", ["prepare_inside", "prepare_before"])
+def test_delta_scan_kernels_compile_at_the_cells_shape(one_chip, dtype,
+                                                       precision, through):
+    """``ops/delta_rule.py``'s two kernels at the eighth cell's shape
+    (one row of 2048, 32 heads of 128), forward and gradient: as the
+    bf16 engine runs them (operands bf16, products in one pass) and as
+    ``chip_check.py kimi_linear``'s float32 rows do (operands float32,
+    every product at the highest precision: the caller's precision
+    must not reach the kernels' own products of bf16 pieces, which
+    Mosaic refuses: "Bad lhs type", PERF.md, PR 42), with a layer's
+    ``Prepare`` applied inside the kernels and with any other callable
+    applied before them."""
+    from realhf_tpu.ops import delta_rule as D
+    b, l, h, d = 1, 2048, 32, 128
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    prepare = D.Prepare(rate=-jnp.ones((h,)), dt_bias=jnp.zeros((h, d)),
+                        scale=d ** -0.5, eps=1e-6)
+    if through == "prepare_before":
+        prepare = lambda q, k, f, made=prepare: made(q, k, f)
+
+    def loss(q, k, v, f, beta, seg):
+        o, last = D._by_kernels(q, k, v, f, beta, seg, prepare)
+        return o.astype(jnp.float32).sum() + last.sum()
+
+    args = (*(sds((b, l, h, d), dtype) for _ in range(4)),
+            sds((b, l, h), jnp.float32), sds((b, l), jnp.int32))
+    with jax.default_matmul_precision(precision):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        *args).as_text()
+    assert D.scan_kernel_calls(text) == 2
+
+
 @pytest.mark.slow
 def test_kimis_whole_train_step_compiles(one_chip):
     """The eighth cell's WHOLE train step for the described chip: 32
     microbatches of one row of 2048 through four delta layers (the
-    chunked scan of ``ops/delta_rule.py``, a rematerialised segment of
-    ``SEGMENT_CHUNKS`` chunks at a time) and one latent layer without a
-    rotary, accumulated in float32, Adam on float32 masters, parameters
-    and optimizer state donated. The compiler's count of its memory is
-    what the cell's size hangs on: 602 M parameters are 12.05 GB at 20
-    bytes before a row's activations, and a program is held to 13.9
-    GB. With the whole row's coefficients kept for the backward at once
-    the step read 15.6 to 17.4 GB (the pairwise [16, 16, 128] decays
-    materialised: 17.4); a segment of 8 chunks 13.56, of 4 13.19
-    (PERF.md, PR 39; ``tests/ops/test_delta_rule.py`` holds the 4)."""
+    chunked scan by ``ops/delta_rule.py``'s two kernels, a chunk's
+    coefficients in VMEM) and one latent layer without a rotary,
+    accumulated in float32, Adam on float32 masters, parameters and
+    optimizer state donated. The kernels' scoped VMEM is counted inside
+    the whole program, and a rematerialised block keeps the scan's
+    output and the chunks' start states: ONE forward and ONE backward
+    kernel a delta layer, none in the rematerialised pass. The
+    compiler's count of the step's memory is what the cell's size hangs
+    on: 602 M parameters are 12.05 GB at 20 bytes before a row's
+    activations, and a program is held to 13.9 GB. By the XLA products
+    (PR 39) the step read 13.19 GB with a rematerialised segment of 4
+    chunks (15.6 to 17.4 with the row's coefficients kept at once); by
+    the kernels 13.19 with ``Prepare`` applied inside them (13.58 with
+    q, k and the decay written in float32 by an XLA pass before them:
+    PERF.md, PR 42)."""
+    from realhf_tpu.ops import delta_rule
+
     step, *args = _sft_train_step(
         one_chip, "kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 32,
         row_len=2048)
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
     text = compiled.as_text()
-    assert "flash_fwd" in text and "gmm" in text  # both kinds of kernel
+    # all three kinds of kernel
+    assert "flash_fwd" in text and "gmm" in text
+    assert delta_rule.DELTA_FWD in text and delta_rule.DELTA_BWD in text
+    assert delta_rule.scan_kernel_calls(text) == 4 * 2
     memory = compiled.memory_analysis()
     assert 12.8e9 < (memory.argument_size_in_bytes
-                     + memory.temp_size_in_bytes) < 13.5e9
+                     + memory.temp_size_in_bytes) < 13.3e9
+
+
+@pytest.mark.slow
+def test_kimis_float32_forward_compiles(one_chip):
+    """What ``scripts/chip_check.py kimi_linear``'s FLOAT32 rows run (the
+    gate for ``ops/delta_rule.py``): the eighth cell's model in float32
+    with every product at the highest precision, forward on one row of
+    2048, all three kinds of kernel in one program. With the delta
+    scan's kernels in it the experts' ``gmm`` at a held expert's
+    float32 ``[2304, 1024]`` was refused on the chip for 1.83 MiB of
+    scoped VMEM over its own limit, where the program without them
+    compiled (``ops/grouped_matmul.py:_params``; PERF.md, PR 42)."""
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import delta_rule
+
+    cfg, params, sds, attn = _model(one_chip, "kimi-linear-48b-a3b-l5-ep32",
+                                    "kimi_linear")
+    cfg.param_dtype = cfg.compute_dtype = "float32"
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.float32), params)
+
+    def forward(p, ids, seg):
+        return T.forward(cfg, p, ids, seg, attention_fn=attn)
+
+    with jax.default_matmul_precision("highest"):
+        text = _compile(_as_on_a_tpu(forward), params,
+                        sds((1, 2048), jnp.int32),
+                        sds((1, 2048), jnp.int32)).as_text()
+    assert "gmm" in text and "flash_fwd" in text
+    assert delta_rule.scan_kernel_calls(text) == 4
 
 
 def test_flash_compiles_under_shard_map(topo):
@@ -553,6 +634,99 @@ def test_flash_compiles_under_shard_map(topo):
     text = _compile(grads, q, k, v, seg).as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert kernel in text
+
+
+def _mesh_of_four(topo):
+    """The described chips as the engine's dp2 x tp2 mesh."""
+    import numpy as np
+
+    from realhf_tpu.parallel.mesh import MESH_AXES
+    return Mesh(np.array(topo.devices[:4]).reshape(1, 2, 1, 2), MESH_AXES)
+
+
+def test_delta_scan_compiles_under_shard_map(topo):
+    """``ops/delta_rule.py``'s two kernels on a dp2 x tp2 mesh, two
+    rows of the eighth cell's shape: a bare Mosaic call is refused at
+    lowering there ("Mosaic kernels cannot be automatically
+    partitioned"), so ``chunked_delta_rule`` hands each device its own
+    row and its own 16 of the 32 heads under ``shard_map``; d of the
+    decay's two tensors is summed over "data", and nothing is gathered."""
+    from realhf_tpu.ops import delta_rule as D
+    from realhf_tpu.ops.hlo_text import device_instructions
+    from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    mesh = _mesh_of_four(topo)
+    b, l, h, d = 2, 2048, 32, 128
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    def loss(q, k, v, f, beta, seg, rate, dt_bias):
+        prepare = D.Prepare(rate=rate, dt_bias=dt_bias, scale=d ** -0.5,
+                            eps=1e-6)
+        o, last = D.chunked_delta_rule(q, k, v, f, beta, seg, prepare,
+                                       mesh=mesh)
+        return o.astype(jnp.float32).sum() + last.sum()
+
+    args = (*(sds((b, l, h, d), jnp.bfloat16, DATA_AXIS, None, MODEL_AXIS)
+              for _ in range(4)),
+            sds((b, l, h), jnp.float32, DATA_AXIS, None, MODEL_AXIS),
+            sds((b, l), jnp.int32, DATA_AXIS),
+            sds((h,), jnp.float32, MODEL_AXIS),
+            sds((h, d), jnp.float32, MODEL_AXIS))
+    text = _compile(_as_on_a_tpu(jax.grad(
+        loss, argnums=(0, 1, 2, 3, 4, 6, 7))), *args).as_text()
+    assert D.scan_kernel_calls(text) == 2
+    opcodes = {opcode for _, _, opcode in device_instructions(text)}
+    assert "all-reduce" in opcodes  # d of the decay, over "data"
+    assert not opcodes & {"all-gather", "all-to-all", "collective-permute"}
+
+
+@pytest.mark.slow
+def test_kimis_whole_microbatch_compiles_on_a_mesh(topo):
+    """Kimi-Linear's WHOLE model (the eighth cell's configuration) as
+    the engine lays it over a dp2 x tp2 mesh, two rows of 2048, one
+    microbatch's forward and backward: the delta layers' heads, their
+    convolutions' channels and the decay's leaves by "model", the rows
+    by "data" (``models/sharding.py``), the flash kernels and the
+    delta scan's each under its ``shard_map``, the experts' products
+    ``lax.ragged_dot`` (``SHARDED_STACKS``). A rematerialised block
+    keeps the scan's output and the chunks' start states through the
+    ``shard_map``: one forward and one backward kernel a delta
+    layer."""
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.models import sharding as shard_rules
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import delta_rule
+    from realhf_tpu.ops import moe as moe_ops
+    from realhf_tpu.parallel.mesh import DATA_AXIS
+
+    mesh = _mesh_of_four(topo)
+    cfg, params, _, flash = _model(None, "kimi-linear-48b-a3b-l5-ep32",
+                                   "kimi_linear")
+    params = jax.tree.map(
+        lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=sharding),
+        params, shard_rules.param_shardings(cfg, mesh))
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+    mb = {name: jax.ShapeDtypeStruct((2, 2048), dtype, sharding=rows)
+          for name, dtype in (("input_ids", jnp.int32),
+                              ("seg_ids", jnp.int32),
+                              ("prompt_mask", jnp.bool_))}
+    attn = make_sharded_attention(mesh, inner=flash)
+    loss_fn = sft._make_loss_fn(cfg)
+
+    def objective(p, mb):
+        h, _, aux = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"],
+                              return_aux=True, attention_fn=attn,
+                              moe_constraint=moe_ops.SHARDED_STACKS,
+                              mesh=mesh)
+        loss, _ = loss_fn(p, h, mb)
+        return loss + moe_ops.aux_loss(aux)
+
+    text = _compile(_as_on_a_tpu(jax.grad(objective)), params, mb).as_text()
+    assert "flash_fwd" in text and "gmm" not in text
+    assert delta_rule.scan_kernel_calls(text) == 4 * 2
 
 
 def test_row_above_the_limit_raises_not_xla():

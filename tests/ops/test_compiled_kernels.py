@@ -62,6 +62,22 @@ def test_compiled_kernel_matches_reference(kernel):
         w = jnp.asarray(rng.standard_normal((3, 256, 384)), jnp.float32)
         ref = jax.lax.ragged_dot(q, w, sizes)
         got = grouped_matmul(q, w, sizes)
+    elif kernel == "delta_rule_scan":
+        from realhf_tpu.ops import delta_rule as D
+        b, l, h, d = 2, 200, 2, 128
+        unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+        q, k = (jnp.asarray(unit(rng.standard_normal((b, l, h, d))),
+                            jnp.float32) for _ in range(2))
+        v = jnp.asarray(rng.standard_normal((b, l, h, d)), jnp.float32)
+        g = -jnp.asarray(np.log1p(np.exp(rng.standard_normal((b, l, h, d)))),
+                         jnp.float32)
+        beta = jnp.asarray(rng.uniform(size=(b, l, h)), jnp.float32)
+        seg = np.ones((b, l), np.int32)
+        seg[:, 90:] = 2
+        seg[-1, -30:] = 0
+        seg = jnp.asarray(seg)
+        ref = D._by_xla(q, k, v, g, beta, seg, None)[0]
+        got = D.chunked_delta_rule(q, k, v, g, beta, seg)[0]
     else:  # flash_decode_attention_stacked
         from realhf_tpu.ops.attention import decode_attention
         from realhf_tpu.ops.decode_attention import (

@@ -5,7 +5,11 @@ token) and the published initialisation's (a state lives hundreds of
 tokens), at lengths that are and are not multiples of the chunk, on
 packed rows whose documents end inside a chunk and inside a 16-token
 sub-block; and, with the decay made equal over a head's channels,
-against ``transformers``' own recurrent gated delta rule."""
+against ``transformers``' own recurrent gated delta rule. Every test of
+the chunked form runs by both of its paths (``path``): the XLA
+products at tiny heads, and the two Pallas kernels (heads of 128, a
+whole lane, under the TPU interpreter: ``interpreted_kernels``)."""
+
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +19,32 @@ import pytest
 from realhf_tpu.ops import delta_rule as D
 
 H, DK, DV = 2, 16, 8
+PATHS = ["xla", "kernel"]
+
+
+@pytest.fixture(params=PATHS)
+def path(request, interpreted_kernels, monkeypatch):
+    """``(chunked, widths)``: ``chunked_delta_rule`` as this path runs
+    it and the heads' ``dict(dk=, dv=, h=)`` it is run at. The kernels
+    take heads of whole lanes only (a narrower head goes down the XLA
+    path by ``kernel_takes``), so their cases run at 128."""
+    if request.param == "xla":
+        assert not D.pallas_enabled()
+        yield D.chunked_delta_rule, dict(dk=DK, dv=DV, h=H)
+        return
+    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
+    # (a gradient's backward kernel is traced after the forward call
+    # has returned: the whole test runs under the interpreter)
+    with interpreted_kernels():
+        yield D.chunked_delta_rule, dict(dk=128, dv=128, h=1)
+
+
+def two_heads(widths):
+    """The gradients' cases run at two heads on both paths, each with
+    a decay of its own: a head's columns of ``[L, H x d]``, its rows of
+    beta and of the decay's two tensors, and the sum of d of those
+    over a row's chunks are addressed by the kernels' block specs."""
+    return dict(widths, h=H)
 
 
 def inputs(seed, b, l, regime, dk=DK, dv=DV, h=H):
@@ -86,11 +116,12 @@ def segments(l, *ends):
     (700, (300, 520, 690)),   # two SEGMENTS of six chunks, a document
                               # over the segments' boundary at 384
 ])
-def test_chunked_equals_token_by_token(regime, l, ends):
+def test_chunked_equals_token_by_token(path, regime, l, ends):
+    chunked, widths = path
     seg = segments(l, *ends)
-    x = inputs(7, 1, l, regime)
+    x = inputs(7, 1, l, regime, **widths)
     with jax.default_matmul_precision("highest"):
-        o, last = D.chunked_delta_rule(*x, jnp.asarray(seg))
+        o, last = chunked(*x, jnp.asarray(seg))
     want_o, want_last = token_by_token(*x, seg)
     valid = (seg != 0)[..., None, None]
     scale = float(jnp.abs(want_o).max())
@@ -100,33 +131,41 @@ def test_chunked_equals_token_by_token(regime, l, ends):
         < 2e-5 * max(1.0, float(jnp.abs(want_last).max()))
 
 
-def test_left_padding_leaves_the_state_at_zero_until_the_document():
+def test_left_padding_leaves_the_state_at_zero_until_the_document(path):
+    chunked, widths = path
     seg = np.zeros((1, 96), np.int32)
     seg[0, 37:] = 1
-    x = inputs(3, 1, 96, "published")
+    x = inputs(3, 1, 96, "published", **widths)
     with jax.default_matmul_precision("highest"):
-        o, last = D.chunked_delta_rule(*x, jnp.asarray(seg))
-        o1, last1 = D.chunked_delta_rule(
+        o, last = chunked(*x, jnp.asarray(seg))
+        o1, last1 = chunked(
             *(a[:, 37:] for a in x), jnp.asarray(seg[:, 37:]))
     np.testing.assert_allclose(o[:, 37:], o1, atol=2e-6)
     np.testing.assert_allclose(last, last1, atol=2e-6)
 
 
 @pytest.mark.parametrize("regime", ["harness", "published"])
-def test_gradients_equal_the_recurrences(regime):
+def test_gradients_equal_the_recurrences(path, regime):
+    """d of q, k, v, g and beta, of the outputs AND of the last state
+    (the kernels' backward starts from its cotangent)."""
+    chunked, widths = path
+    widths = two_heads(widths)
     l = 600  # two rematerialised segments of five chunks
     seg = segments(l, 23, 301, 560)
-    x = inputs(11, 2, l, regime)
+    x = inputs(11, 2, l, regime, **widths)
     seg = np.concatenate([seg, segments(l, l)])
-    w = jnp.asarray(np.random.default_rng(5).normal(size=(2, l, H, DV)),
+    rng = np.random.default_rng(5)
+    w = jnp.asarray(rng.normal(size=x[2].shape),
                     jnp.float32) * jnp.asarray(seg != 0)[..., None, None]
+    w_last = jnp.asarray(rng.normal(size=(
+        2, widths["h"], widths["dk"], widths["dv"])), jnp.float32)
 
     def loss(fn, *a):
-        return (fn(*a, jnp.asarray(seg) if fn is D.chunked_delta_rule
-                   else seg)[0] * w).sum()
+        o, last = fn(*a, jnp.asarray(seg) if fn is chunked else seg)
+        return (o * w).sum() + (last * w_last).sum()
 
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda *a: loss(D.chunked_delta_rule, *a),
+        got = jax.grad(lambda *a: loss(chunked, *a),
                        argnums=(0, 1, 2, 3, 4))(*x)
     want = jax.grad(lambda *a: loss(token_by_token, *a),
                     argnums=(0, 1, 2, 3, 4))(*x)
@@ -137,29 +176,72 @@ def test_gradients_equal_the_recurrences(regime):
         assert bool(jnp.isfinite(a).all()), name
 
 
-def test_no_exponent_overflows_where_the_factored_form_would():
+def test_gradients_reach_what_prepare_reads(path):
+    """Through a layer's ``Prepare`` (its l2 norm and its decay from
+    a pre-activation) to the two tensors the decay is made of, on a
+    row with padding, against the same ``Prepare`` before the
+    recurrence token by token."""
+    chunked, widths = path
+    widths = two_heads(widths)
+    l = 150
+    seg = segments(l, 70, 140)
+    q, k, v, f, beta = inputs(23, 1, l, "published", **widths)
+    h, dk = widths["h"], widths["dk"]
+    rng = np.random.default_rng(29)
+    a_log = jnp.asarray(rng.uniform(0, 2, size=(h,)), jnp.float32)
+    dt_bias = jnp.asarray(rng.normal(size=(h, dk)) - 3, jnp.float32)
+    w = jnp.asarray(rng.normal(size=v.shape), jnp.float32) \
+        * jnp.asarray(seg != 0)[..., None, None]
+
+    def prepare_of(a_log, dt_bias):
+        # (the kernels apply THIS one themselves, a chunk at a time)
+        return D.Prepare(rate=-jnp.exp(a_log), dt_bias=dt_bias,
+                         scale=dk ** -0.5, eps=1e-6)
+
+    def inside(a_log, dt_bias, q, k, f):
+        return (chunked(q, k, v, f, beta, jnp.asarray(seg),
+                        prepare=prepare_of(a_log, dt_bias))[0] * w).sum()
+
+    def before(a_log, dt_bias, q, k, f):
+        return (token_by_token(*prepare_of(a_log, dt_bias)(q, k, f)[:2], v,
+                               prepare_of(a_log, dt_bias)(q, k, f)[2], beta,
+                               seg)[0] * w).sum()
+
+    args = (a_log, dt_bias, q * 3.0, k * 0.5, f)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(inside, argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(before, argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b_ in zip("a_log dt_bias q k f".split(), got, want):
+        err = float(jnp.abs(a - b_).max())
+        assert err < 1e-4 * float(jnp.abs(b_).max()), (name, err)
+
+
+def test_no_exponent_overflows_where_the_factored_form_would(path):
     """1.6 a token over a chunk is exp(102) in the factored form:
     float32 ends at exp(88.7). Here every exponent is <= 0."""
+    chunked, widths = path
     l = 128
-    q, k, v, g, beta = inputs(2, 1, l, "published")
+    q, k, v, g, beta = inputs(2, 1, l, "published", **widths)
     g = jnp.full_like(g, -1.7)
     seg = jnp.ones((1, l), jnp.int32)
-    o, last = D.chunked_delta_rule(q, k, v, g, beta, seg)
+    with jax.default_matmul_precision("highest"):
+        o, last = chunked(q, k, v, g, beta, seg)
+        grads = jax.grad(lambda g_: chunked(
+            q, k, v, g_, beta, seg)[0].sum())(g)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(last).all())
     want, _ = token_by_token(q, k, v, g, beta, np.ones((1, l), np.int32))
     np.testing.assert_allclose(o, want, atol=1e-5)
-    grads = jax.grad(lambda g_: D.chunked_delta_rule(
-        q, k, v, g_, beta, seg)[0].sum())(g)
     assert bool(jnp.isfinite(grads).all())
 
 
-def test_a_step_at_a_time_continues_the_chunked_state():
+def test_a_step_at_a_time_continues_the_chunked_state(path):
+    chunked, widths = path
     l = 90
-    x = inputs(13, 2, l + 5, "published")
+    x = inputs(13, 2, l + 5, "published", **widths)
     seg = jnp.ones((2, l), jnp.int32)
     with jax.default_matmul_precision("highest"):
-        _, state = D.chunked_delta_rule(*(a[:, :l] for a in x), seg)
-        whole, _ = D.chunked_delta_rule(*x, jnp.ones((2, l + 5), jnp.int32))
+        _, state = chunked(*(a[:, :l] for a in x), seg)
+        whole, _ = chunked(*x, jnp.ones((2, l + 5), jnp.int32))
         for t in range(l, l + 5):
             o, state = D.delta_rule_step(*(a[:, t] for a in x), state)
             np.testing.assert_allclose(o, whole[:, t], atol=2e-6)
@@ -187,26 +269,35 @@ def test_equal_decay_is_transformers_recurrent_gated_delta_rule():
     np.testing.assert_allclose(last, want_last.numpy(), atol=3e-6)
 
 
-def test_prepare_runs_where_the_segment_is_computed():
+def test_prepare_runs_where_the_path_computes(path):
     """``prepare`` (a layer's l2 norm and decay) applied inside equals
     applying it before; the output takes the values' dtype, the state
-    stays float32."""
+    stays float32; operands in bf16 (as the engine hands them over)
+    are taken to float32 inside."""
+    chunked, widths = path
     l = 600
-    q, k, v, g, beta = inputs(19, 1, l, "published")
+    q, k, v, g, beta = inputs(19, 1, l, "published", **widths)
     seg = jnp.asarray(segments(l, 250, 590))
     raw = (q * 3.0, k * 0.5, g * 2.0)
     prepare = lambda q_, k_, g_: (q_ / 3.0, k_ / 0.5, g_ / 2.0)
     with jax.default_matmul_precision("highest"):
-        want, want_last = D.chunked_delta_rule(q, k, v, g, beta, seg)
-        got, last = D.chunked_delta_rule(*raw[:2], v, raw[2], beta, seg,
-                                         prepare=prepare)
-        half, last16 = D.chunked_delta_rule(
+        want, want_last = chunked(q, k, v, g, beta, seg)
+        got, last = chunked(*raw[:2], v, raw[2], beta, seg,
+                            prepare=prepare)
+        half, last16 = chunked(
             *raw[:2], v.astype(jnp.bfloat16), raw[2], beta, seg,
             prepare=prepare)
+        bf16 = lambda x: x.astype(jnp.bfloat16)
+        all_half, _ = chunked(bf16(q), bf16(k), bf16(v), bf16(g), beta, seg)
+        as_rounded, _ = chunked(*(bf16(x).astype(jnp.float32)
+                                  for x in (q, k, v, g)), beta, seg)
     np.testing.assert_allclose(got, want, atol=2e-6)
     np.testing.assert_allclose(last, want_last, atol=2e-6)
     assert half.dtype == jnp.bfloat16 and last16.dtype == jnp.float32
     np.testing.assert_allclose(half.astype(jnp.float32), want, atol=2e-2)
+    assert all_half.dtype == jnp.bfloat16
+    np.testing.assert_allclose(all_half.astype(jnp.float32), as_rounded,
+                               atol=1e-2)
 
 
 def test_a_segment_is_four_chunks_of_64():
@@ -217,6 +308,24 @@ def test_a_segment_is_four_chunks_of_64():
     test_kimis_whole_train_step_compiles``); and the lengths above
     cross the boundaries they say only while these hold."""
     assert (D.CHUNK, D.SUB, D.SEGMENT_CHUNKS) == (64, 16, 4)
+
+
+def test_the_kernels_take_whole_lanes_and_the_rest_goes_by_xla(
+        interpreted_kernels, monkeypatch):
+    """A head that is no multiple of 128 wide goes down the XLA path
+    where the kernels are enabled: by what the call can see of its
+    operands, not by a setting."""
+    assert D.kernel_takes(128, 128) and D.kernel_takes(128, 256)
+    assert not D.kernel_takes(64, 128) and not D.kernel_takes(128, 192)
+    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
+    x = inputs(31, 1, 70, "published")
+    seg = jnp.ones((1, 70), jnp.int32)
+    text = jax.jit(D.chunked_delta_rule).lower(*x, seg).as_text()
+    assert "delta_fwd" not in text and "tpu_custom_call" not in text
+    with interpreted_kernels():
+        wide = inputs(31, 1, 70, "published", dk=128, dv=128, h=1)
+        lowered = jax.jit(D.chunked_delta_rule).lower(*wide, seg)
+    assert D.DELTA_FWD in lowered.as_text(debug_info=True)
 
 
 def test_doc_index_counts_padding_with_the_document_before():

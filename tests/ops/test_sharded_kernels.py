@@ -299,3 +299,86 @@ def test_flash_decode_return_stats_consistency():
     np.testing.assert_allclose(np.asarray(out), np.asarray(plain),
                                atol=1e-6)
     assert np.asarray(l).min() > 0 and np.isfinite(np.asarray(m)).all()
+
+
+def _delta_case(b=2, l=100, h=2, d=128):
+    """A packed batch of a delta layer at heads a whole lane wide (the
+    rows packed differently), a ``Prepare`` whose decay differs head by
+    head, and weights for a loss over the outputs and the last
+    states."""
+    rng = np.random.default_rng(41)
+    q, k, v, f = (jnp.asarray(rng.normal(size=(b, l, h, d)), jnp.float32)
+                  for _ in range(4))
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(b, l, h)))),
+                       jnp.float32)
+    seg = np.ones((b, l), np.int32)
+    seg[0, 37:90], seg[0, 90:] = 2, 0  # a pad tail
+    seg[1::2, 70:] = 2
+    a_log = jnp.asarray(rng.uniform(0, 2, size=(h,)), jnp.float32)
+    dt_bias = jnp.asarray(rng.normal(size=(h, d)) - 3, jnp.float32)
+    w = jnp.asarray(rng.normal(size=v.shape) * (seg != 0)[..., None, None],
+                    jnp.float32)
+    w_last = jnp.asarray(rng.normal(size=(b, h, d, d)), jnp.float32)
+    return (a_log, dt_bias, q, k, v, f, beta), jnp.asarray(seg), w, w_last
+
+
+def test_sharded_delta_scan_matches_one_device(interpreted_kernels,
+                                               monkeypatch):
+    """``ops/delta_rule.py``'s two kernels on a dp2 x tp2 mesh: a bare
+    Mosaic call cannot be partitioned (on the chip jax refuses to lower
+    one on a mesh), so each device runs them on its own rows and heads
+    under ``shard_map``. Forward and every gradient against the XLA
+    products on one device; d of the decay's two tensors, which every
+    data shard holds, is summed over "data"."""
+    from realhf_tpu.ops import delta_rule as D
+    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
+    args, seg, w, w_last = _delta_case()
+    d = args[2].shape[-1]
+
+    def grads_of(scan):
+        def loss(a_log, dt_bias, q, k, v, f, beta):
+            prepare = D.Prepare(rate=-jnp.exp(a_log), dt_bias=dt_bias,
+                                scale=d ** -0.5, eps=1e-6)
+            o, last = scan(q, k, v, f, beta, seg, prepare)
+            return (o * w).sum() + (last * w_last).sum()
+        return jax.jit(jax.grad(loss, argnums=tuple(range(7))))
+
+    def sharded(*a):
+        return D.chunked_delta_rule(*a, mesh=_mesh())
+
+    with jax.default_matmul_precision("highest"):
+        want = grads_of(D._by_xla)(*args)
+        with interpreted_kernels():  # the backward is traced late
+            text = grads_of(sharded).lower(*args).as_text(debug_info=True)
+            got = grads_of(sharded)(*args)
+    assert "shard_map" in text
+    assert D.DELTA_FWD in text and D.DELTA_BWD in text
+    for name, a, b_ in zip("a_log dt_bias q k v f beta".split(), got, want):
+        err = float(jnp.abs(a - b_).max())
+        assert err < 1e-4 * float(jnp.abs(b_).max()), (name, err)
+
+
+def test_delta_scan_goes_by_xla_where_a_mesh_cannot_take_the_kernels(
+        monkeypatch):
+    """One device (or no mesh): the bare kernels. Rows or heads that do
+    not divide the mesh, and a mesh that cuts a row along its length
+    (context parallelism): the XLA products, which GSPMD partitions,
+    with no Mosaic call in the program."""
+    from realhf_tpu.ops import delta_rule as D
+    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
+    assert D._scan_over(None, 3, 5) is D._scan
+    assert D._scan_over(_mesh(1, 1), 3, 5) is D._scan
+    mesh = _mesh()
+    assert D._scan_over(mesh, 4, 4) not in (None, D._scan)
+    assert D._scan_over(mesh, 3, 4) is None
+    assert D._scan_over(mesh, 4, 3) is None
+    par = ParallelismConfig(data_parallel_size=2, context_parallel_size=2)
+    cut = make_mesh(par, devices=jax.devices("cpu")[:par.world_size])
+    assert D._scan_over(cut, 4, 4) is None
+    args, seg, _, _ = _delta_case(b=3)
+    prepare = D.Prepare(rate=-jnp.exp(args[0]), dt_bias=args[1],
+                        scale=1.0, eps=1e-6)
+    text = jax.jit(lambda *a: D.chunked_delta_rule(
+        *a, seg, prepare, mesh=mesh)).lower(*args[2:]).as_text(
+            debug_info=True)
+    assert D.DELTA_FWD not in text and "tpu_custom_call" not in text
