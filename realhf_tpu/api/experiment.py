@@ -206,7 +206,6 @@ class ServingSpec:
     #: prompt-lookup speculative decoding: draft k tokens per round
     #: from the request's own history and verify them in one forward
     #: (greedy-exact; ignored unless gconfig is greedy). 0 disables.
-    #: The REALHF_TPU_SPEC_K env var overrides at worker start.
     spec_decode_k: int = 0
     #: seconds drain() waits for in-flight sequences at shutdown
     drain_timeout_secs: float = 30.0

@@ -37,7 +37,7 @@ def force_cpu_backend(n_devices: Optional[int] = None) -> None:
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process and
     return its directory. Called wherever the program starts
-    (quickstart, the runners, ``apps/remote.py`` workers, bench).
+    (quickstart, the runners, ``apps/remote.py`` workers).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it
     and no other directory is set here; otherwise the cache lives in
@@ -57,34 +57,18 @@ def enable_compile_cache() -> str:
 
 def pallas_enabled() -> bool:
     """Whether the Pallas kernel paths (flash attention, flash decode,
-    their shard_map wrappers) should engage: a real TPU backend, or
-    ``REALHF_TPU_FORCE_PALLAS=1`` -- the test hook that runs the SAME
-    wiring with interpret-mode kernels on CPU (callers then execute
-    under ``pltpu.force_tpu_interpret_mode()``), so the kernel
-    plumbing is exercised in CI instead of only on hardware.
+    the grouped products, the delta scan, their shard_map wrappers)
+    engage: a TPU backend, or a trace made under
+    ``pltpu.force_tpu_interpret_mode()``, where the SAME wiring runs
+    the kernels through jax's interpreter on the CPU (the tests'
+    fixture ``interpreted_kernels`` is the one way in).
 
-    The flag is read at TRACE time: set it before building engines /
-    tracing jits, and do not expect a mid-process flip to invalidate
-    already-compiled programs (the env var is not part of any jit
-    cache key). Forcing the flag on a non-TPU backend OUTSIDE the
-    interpret-mode context raises here -- the bare kernels would
-    otherwise die deep in Mosaic lowering with an opaque error."""
+    Read at TRACE time: a program traced outside the interpreter keeps
+    its XLA paths when it is called inside it (the context is no part
+    of a jit's cache key)."""
     import jax
-
-    # Escape hatch / A-B rig: force the GSPMD/XLA fallback paths even
-    # on a real TPU (profile_decode --no-pallas sets this to compare
-    # the handwritten kernels against XLA on silicon).
-    if os.environ.get("REALHF_TPU_DISABLE_PALLAS") == "1":
-        return False
-    if jax.default_backend() == "tpu":
-        return True
-    if os.environ.get("REALHF_TPU_FORCE_PALLAS") != "1":
-        return False
     from jax._src import config as _jcfg
-    if _jcfg.pallas_tpu_interpret_mode_context_manager.value is None:
-        raise RuntimeError(
-            "REALHF_TPU_FORCE_PALLAS=1 on a non-TPU backend requires "
-            "running under pltpu.force_tpu_interpret_mode() (the bare "
-            "Pallas kernels cannot lower for CPU); wrap the "
-            "computation in that context or unset the flag.")
-    return True
+
+    return (jax.default_backend() == "tpu"
+            or _jcfg.pallas_tpu_interpret_mode_context_manager.value
+            is not None)

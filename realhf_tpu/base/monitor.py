@@ -53,38 +53,6 @@ def transformer_train_flops(**kw) -> int:
     return 3 * transformer_forward_flops(**kw)
 
 
-def generation_flops(
-    n_layers: int,
-    hidden_dim: int,
-    n_q_heads: int,
-    n_kv_heads: int,
-    head_dim: int,
-    intermediate_dim: int,
-    vocab_size: int,
-    prompt_lens: List[int],
-    gen_len: int,
-    gated_mlp: bool = True,
-) -> int:
-    """Prefill + decode FLOPs for a generation MFC."""
-    prefill = transformer_forward_flops(
-        n_layers=n_layers, hidden_dim=hidden_dim, n_q_heads=n_q_heads,
-        n_kv_heads=n_kv_heads, head_dim=head_dim,
-        intermediate_dim=intermediate_dim, vocab_size=vocab_size,
-        seqlens=prompt_lens, gated_mlp=gated_mlp)
-    decode = 0
-    for pl in prompt_lens:
-        # Each decoded token attends to the whole prefix.
-        dense = transformer_forward_flops(
-            n_layers=n_layers, hidden_dim=hidden_dim, n_q_heads=n_q_heads,
-            n_kv_heads=n_kv_heads, head_dim=head_dim,
-            intermediate_dim=intermediate_dim, vocab_size=vocab_size,
-            seqlens=[1] * gen_len, gated_mlp=gated_mlp)
-        kv_attn = sum(2 * 2 * (pl + t) * n_q_heads * head_dim
-                      for t in range(gen_len))
-        decode += dense + kv_attn
-    return prefill + decode
-
-
 def device_memory_stats(device=None) -> Dict[str, int]:
     """Per-chip HBM stats (replaces nvml polling, reference :255)."""
     import jax

@@ -7,7 +7,6 @@ arrays for the data plane.
 """
 
 import dataclasses
-import os
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -99,17 +98,14 @@ def run_train_minibatches(engine, minibatch_samples, build_sb, loss_fn,
     """The PPO-style minibatch loop: one optimizer step per minibatch
     sample, each accumulating over ``n_mbs`` memory microbatches.
 
-    By default the WHOLE loop runs inside one jitted dispatch
-    (``Engine.train_minibatches``: lax.scan threads params/opt state
-    through the per-minibatch step): one dispatch and one host sync
-    instead of one per minibatch -- identical
-    update order and numerics to sequential ``train_batch`` calls.
-    ``REALHF_TPU_FUSE_MINIBATCHES=0`` restores the sequential calls
-    (e.g. when length-skewed minibatches would over-pad the common
-    bucket the fused path stacks into)."""
-    fused = os.environ.get("REALHF_TPU_FUSE_MINIBATCHES", "1") != "0"
+    Wherever the minibatches stack, the WHOLE loop runs inside one
+    jitted dispatch (``Engine.train_minibatches``: lax.scan threads
+    params/opt state through the per-minibatch step): one dispatch
+    and one host sync instead of one per minibatch -- identical
+    update order and numerics to sequential ``train_batch`` calls,
+    which a single minibatch and uneven microbatch counts still take."""
     splits = [split_minibatches(s, n_mbs or 1) for s in minibatch_samples]
-    if (not fused or len(minibatch_samples) == 1
+    if (len(minibatch_samples) == 1
             or len({len(g) for g in splits}) != 1):
         # uneven microbatch counts cannot stack into one [N, M, ...];
         # counts are checked BEFORE any packing so the fallback does

@@ -27,8 +27,7 @@ Entry points: :func:`analyze_path` (merged JSON, a ``.jsonl`` shard,
 or a trace directory), :func:`analyze_events`,
 :func:`format_report` (human table) and :func:`one_line_summary`
 (the teardown log line next to the Perfetto pointer).
-``scripts/analyze_trace.py`` is the CLI; ``bench.py`` embeds the same
-report as its ``trace_report`` phase.
+``scripts/analyze_trace.py`` is the CLI.
 """
 
 import json

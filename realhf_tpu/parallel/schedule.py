@@ -30,11 +30,9 @@ autodiff:
 3. **Masked bubble ticks.** Warm-up/cool-down ticks on inactive stages
    run a ``lax.cond`` no-op branch instead of computing garbage the
    way the GPipe scan does. Per pass, each stage computes exactly M
-   stage-steps instead of M + S - 1 (a (S-1)/(M+S-1) FLOP saving,
-   measured directly by ``scripts/bench_pipeline.py``; on lockstep
-   silicon it returns energy/HBM slack rather than wall-clock).
-   ``REALHF_TPU_PIPE_MASK=0`` disables the cond (escape hatch for
-   backends whose partitioner rejects stage-varying predicates).
+   stage-steps instead of M + S - 1 (a (S-1)/(M+S-1) FLOP saving;
+   on lockstep silicon it returns energy/HBM slack rather than
+   wall-clock).
 
 The schedule needs the same mesh contract as GPipe: blocks sharded
 P("pipe") on the leading layer axis, activations pipe-replicated,
@@ -45,7 +43,6 @@ through them.
 """
 
 import dataclasses
-import os
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -138,7 +135,7 @@ def train_schedule(n_stages: int, n_microbatches: int) -> List[List[Tick]]:
 
 
 # ----------------------------------------------------------------------
-# Analytics (consumed by search/engine.py cost model and bench.py)
+# Analytics (consumed by search/engine.py's cost model)
 # ----------------------------------------------------------------------
 def default_microbatches(pp: int, schedule: str = ONE_F_ONE_B) -> int:
     """Engine default microbatch count. 1F1B holds one full-batch
@@ -151,31 +148,6 @@ def default_microbatches(pp: int, schedule: str = ONE_F_ONE_B) -> int:
 
 def ticks_per_pass(n_stages: int, n_microbatches: int) -> int:
     return n_microbatches + n_stages - 1
-
-
-def train_ticks(n_stages: int, n_microbatches: int) -> int:
-    """Lockstep ticks of one train step (forward + backward pass)."""
-    return 2 * ticks_per_pass(n_stages, n_microbatches)
-
-
-def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
-    """Fraction of a pass's ticks that are bubble: (S-1)/(M+S-1).
-    Identical for forward and backward passes, hence also the
-    train-step fraction. Equivalently an (S-1)/M overhead over the
-    M-tick ideal."""
-    return (n_stages - 1) / (n_microbatches + n_stages - 1)
-
-
-def computed_stage_steps(n_stages: int, n_microbatches: int,
-                         schedule: str) -> int:
-    """Stage-step computations actually executed per train step:
-    GPipe's lockstep scan computes every stage every tick (garbage on
-    bubble ticks, forward AND autodiff backward); 1F1B's cond masks
-    them, leaving exactly the 2*M*S useful steps."""
-    t = ticks_per_pass(n_stages, n_microbatches)
-    if schedule == ONE_F_ONE_B:
-        return 2 * n_microbatches * n_stages
-    return 2 * t * n_stages
 
 
 def train_bubble_factor(pp: int, n_mb: Optional[int] = None,
@@ -194,13 +166,6 @@ def train_bubble_factor(pp: int, n_mb: Optional[int] = None,
 # ----------------------------------------------------------------------
 # The pipelined forward with an explicit 1F1B backward
 # ----------------------------------------------------------------------
-def _mask_bubbles() -> bool:
-    """Trace-time knob: lax.cond-mask bubble ticks (default) or
-    compute-and-discard like GPipe (REALHF_TPU_PIPE_MASK=0 -- escape
-    hatch for partitioners that reject stage-varying predicates)."""
-    return os.environ.get("REALHF_TPU_PIPE_MASK", "1") != "0"
-
-
 def pipeline_blocks_1f1b(
     pipe,                           # parallel.pipeline.PipelineContext
     blocks: Any,                    # stacked pytree, leading dim n_layers
@@ -237,13 +202,12 @@ def pipeline_blocks_1f1b(
     S, M = pipe.n_stages, pipe.n_microbatches
     assert n_layers % S == 0, (n_layers, S)
     per_stage = n_layers // S
-    mask = _mask_bubbles()
 
     (x, seg_ids, cos, sin), b_orig = pad_streams(
         [x, seg_ids, cos, sin], M)
     B, L, H = x.shape
     Bm = B // M
-    T = M + S - 1
+    T = ticks_per_pass(S, M)
     mb_w = jnp.asarray(microbatch_weights(b_orig, Bm, M))  # [M] f32
 
     # Aux output structure of one stage-step, needed to build the
@@ -300,14 +264,9 @@ def pipeline_blocks_1f1b(
             xin = jnp.where(idx == 0, inj, state)
             xsave = jax.lax.dynamic_update_index_in_dim(
                 xsave, jnp.where(valid, xin, _pick(xsave, m)), m, 0)
-            if mask:
-                y, aux = jax.lax.cond(
-                    valid, lambda xc: compute(m, xc),
-                    lambda xc: (jnp.zeros_like(xc), aux0), xin)
-            else:
-                y, aux = compute(m, xin)
-                vf = valid.astype(jnp.float32)
-                aux = {k: aux[k] * vf for k in aux_keys}
+            y, aux = jax.lax.cond(
+                valid, lambda xc: compute(m, xc),
+                lambda xc: (jnp.zeros_like(xc), aux0), xin)
             # real-stream aux weight of this tick's microbatch (zero
             # contribution on bubble ticks: aux is already zeroed)
             wt = _pick(wv, m)
@@ -372,14 +331,8 @@ def pipeline_blocks_1f1b(
                 return (jax.tree.map(jnp.zeros_like, blocks_l),
                         jnp.zeros_like(op[0]))
 
-            if mask:
-                dblk_t, dx_t = jax.lax.cond(valid, live, dead,
-                                            (xin, gy, g_aux_t))
-            else:
-                dblk_t, dx_t = live((xin, gy, g_aux_t))
-                vf = valid.astype(dx_t.dtype)
-                dblk_t = jax.tree.map(lambda a: a * vf, dblk_t)
-                dx_t = dx_t * vf
+            dblk_t, dx_t = jax.lax.cond(valid, live, dead,
+                                        (xin, gy, g_aux_t))
             dblk = jax.tree.map(jnp.add, dblk, dblk_t)
             dxbuf = jax.lax.dynamic_update_index_in_dim(
                 dxbuf,

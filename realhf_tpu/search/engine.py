@@ -579,8 +579,7 @@ def calibrate_cost_model(
         # generation share the prefill + sampling + dispatch overheads,
         # so the difference isolates pure per-token decode time (the
         # single-call version divided decode bytes by a wall that
-        # included prefill, deflating the bandwidth estimate -- same
-        # conflation the r3 advisor flagged in bench.py).
+        # included prefill, deflating the bandwidth estimate).
         gn_lo = max(2, probe_gen_tokens // 4)
         t_lo = timed_gen(gn_lo)
         t_hi = timed_gen(probe_gen_tokens)

@@ -17,7 +17,6 @@ Extra worker commands beyond the base set:
 - ``drain``: early graceful drain without exiting.
 """
 
-import os
 import pickle
 from typing import Any, Dict
 
@@ -58,10 +57,6 @@ class GenServerWorker(worker_base.Worker):
                                  total_steps=1, init_seed=spec.seed)
         gconfig = GenerationHyperparameters(
             **dict(sv.gconfig, force_no_logits_mask=True))
-        # hot-path knobs (docs/serving.md "Prefix cache & speculative
-        # decoding"): REALHF_TPU_SPEC_K overrides the spec for drills
-        spec_k = int(os.environ.get("REALHF_TPU_SPEC_K",
-                                    sv.spec_decode_k))
         # paged KV pool (docs/perf.md "Paged KV & quantization"):
         # int8 implies the pool -- dequant-on-read lives in its
         # gather path
@@ -86,7 +81,7 @@ class GenServerWorker(worker_base.Worker):
             self.model.config, self.model.engine.params, gconfig,
             n_slots=sv.n_slots, max_prompt_len=sv.max_prompt_len,
             eos_token_id=sv.eos_token_id, pad_token_id=sv.pad_token_id,
-            chunk_size=sv.chunk_size, spec_decode_k=spec_k,
+            chunk_size=sv.chunk_size, spec_decode_k=sv.spec_decode_k,
             kv_pool=kv_pool,
             kv_cache_dtype=None if paged else sv.kv_cache_dtype)
         if sv.prefix_cache_bytes <= 0:
@@ -136,7 +131,7 @@ class GenServerWorker(worker_base.Worker):
                     "staleness=%s fleet=%s prefix_cache=%dB "
                     "spec_k=%d.", self.worker_name, sv.model_role,
                     sv.n_slots, sv.max_staleness, sv.fleet_router,
-                    sv.prefix_cache_bytes, spec_k)
+                    sv.prefix_cache_bytes, sv.spec_decode_k)
         return dict(address=self.rollout_server.address)
 
     # ------------------------------------------------------------------

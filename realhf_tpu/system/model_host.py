@@ -642,7 +642,7 @@ class ModelHost:
                             node.role, node_name)
         return out
 
-    def execute_level(self, named_inputs, parallel: Optional[bool] = None):
+    def execute_level(self, named_inputs):
         """Run a list of ``(node_name, inp)`` MFCs -- one topological
         level, mutually independent by construction -- CONCURRENTLY in
         threads, returning outputs in input order. On a single device
@@ -653,21 +653,13 @@ class ModelHost:
         is thread-safe, and two same-role nodes (which share one
         Engine) serialize on the role's lock inside execute(), so only
         genuinely independent cross-role work overlaps.
-        ``parallel=False`` (or ``REALHF_TPU_PARALLEL_MFC=0``)
-        serializes; ``REALHF_TPU_PARALLEL_MFC=1`` forces overlap.
-        With neither set, overlap additionally requires >1 online
-        CPU: concurrent XLA CPU executables carrying cross-module
+        A host with ONE online CPU runs the level in order instead:
+        concurrent XLA CPU executables carrying cross-module
         collectives rendezvous by spin-waiting across threads, and on
         a single-core host those spinners starve each other into a
         deadlock (observed as 'waiting for all participants to
         arrive at rendezvous' forever)."""
-        if parallel is None:
-            env = os.environ.get("REALHF_TPU_PARALLEL_MFC")
-            if env is not None:
-                parallel = env != "0"
-            else:
-                parallel = (os.cpu_count() or 1) > 1
-        if len(named_inputs) == 1 or not parallel:
+        if len(named_inputs) == 1 or (os.cpu_count() or 1) == 1:
             return [self.execute(n, i) for n, i in named_inputs]
         from concurrent.futures import ThreadPoolExecutor
 

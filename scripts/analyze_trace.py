@@ -18,8 +18,7 @@ Usage::
     python scripts/analyze_trace.py --demo [--steps N]
         # self-contained proof: run a tiny traced inline PPO trial
         # (CPU, random-init models) and analyze its own merged trace;
-        # prints the report JSON as the last stdout line. This is the
-        # bench.py `trace_report` phase.
+        # prints the report JSON as the last stdout line.
 """
 
 import argparse
@@ -73,8 +72,7 @@ def main(argv=None) -> int:
                     help="print only the one-line summary")
     ap.add_argument("--demo", action="store_true",
                     help="run a tiny traced inline PPO trial and "
-                         "analyze it (the bench.py trace_report "
-                         "phase); prints the report JSON")
+                         "analyze it; prints the report JSON")
     ap.add_argument("--steps", type=int, default=2,
                     help="steps for --demo")
     args = ap.parse_args(argv)

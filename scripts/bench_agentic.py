@@ -14,9 +14,7 @@ same tiny model and the same tool-game episodes:
 
 Reports episodes/s, **turns/s**, and the env-step vs generation
 overlap fraction (wall-clock inside ``env.step`` while other requests
-were in flight / total env-step wall). ``bench.py`` runs this in a
-CPU-forced subprocess and merges the JSON line into the BENCH payload
-as ``agentic_bench``.
+were in flight / total env-step wall) as one JSON line.
 
 Usage::
 

@@ -22,9 +22,7 @@ modes:
 Reports steps/s for both modes, the rollout-idle fraction, the
 staleness histogram, how many train steps overlapped with in-flight
 generation, and the per-step reward/importance-weight curves (the
-slow e2e asserts reward parity on these). ``bench.py`` runs this in a
-CPU-forced subprocess and merges the JSON line into the BENCH payload
-as ``async_bench``.
+slow e2e asserts reward parity on these) as one JSON line.
 
 Usage::
 

@@ -12,9 +12,8 @@ concurrent clients over two traffic shapes:
   cache's worst case: every request is a miss).
 
 Per scenario it reports tokens/sec, prefill tokens saved by the radix
-cache, and the speculative-decoding accept rate. ``bench.py`` runs
-this in a CPU-forced subprocess and merges the JSON line into the
-BENCH payload as ``serving_bench``. On this box (CPU, tiny model) the
+cache, and the speculative-decoding accept rate, as one JSON line.
+On this box (CPU, tiny model) the
 *tokens/sec deltas* are indicative only -- the load-bearing numbers
 are prefill_tokens_saved > 0 on shared traffic and the accept rate,
 which are backend-independent.

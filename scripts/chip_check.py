@@ -93,13 +93,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from chip_check_olmoe import (  # noqa: E402  (scripts/ is sys.path[0])
-    experts_rounded,
-    round_int8_by_row,
-    round_to,
-    share,
-)
-
 #: what differs by family: its cell, the tests' tiny cell
 #: (``--rehearse``), the wrong equations that are said by a key of the
 #: config (``family.WRONG`` names the others), the packed row's
@@ -137,6 +130,32 @@ def say(**fields):
     os.makedirs(os.path.dirname(out), exist_ok=True)
     with open(out, "a") as f:
         f.write(line + "\n")
+
+
+def round_int8_by_row(x):
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    scale = jnp.abs(x).max(axis=-1, keepdims=True) / 127.0
+    return jnp.round(x / scale) * scale
+
+
+def round_to(dtype):
+    import jax.numpy as jnp
+    return lambda x: x.astype(dtype).astype(jnp.float32)
+
+
+def experts_rounded(tensors, cast):
+    """The checkpoint with only the experts' matrices rounded."""
+    import numpy as np
+    return {k: (np.asarray(cast(v), np.float32) if ".experts." in k else v)
+            for k, v in tensors.items()}
+
+
+def share(got, want):
+    from benchmark import reference
+    gap, spread = reference.gap(got, want)
+    return dict(mean_abs_delta=gap, reference_std=spread,
+                share_of_std=gap / spread)
 
 
 def one_chip_engine(ckpt, dtype="bfloat16"):

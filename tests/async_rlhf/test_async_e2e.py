@@ -78,7 +78,6 @@ def test_async_overlap_matches_sync_reward_curve():
     assert abs(r_sync.mean() - r_async.mean()) < 0.15, (
         r_sync, r_async)
 
-    # overlap must not cost throughput (generous CPU-walls bound;
-    # bench.py records the real number as async_bench)
+    # overlap must not cost throughput (generous CPU-walls bound)
     assert async_["steps_per_sec"] >= 0.6 * sync["steps_per_sec"], (
         sync["steps_per_sec"], async_["steps_per_sec"])

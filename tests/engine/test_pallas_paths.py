@@ -1,7 +1,7 @@
 """Coverage of the PALLAS decode wiring at the transformer level.
 
-The kernel gates key on ``pallas_enabled()`` (real TPU, or the
-``REALHF_TPU_FORCE_PALLAS=1`` test hook). With the hook set and
+The kernel gates key on ``pallas_enabled()`` (a TPU backend, or a
+trace made under the TPU interpreter). With
 ``interpreted_kernels()`` active, ``T.prefill`` +
 ``T.decode_step`` run the SAME plumbing a TPU runs -- the decode
 partitioning chooser and the heads-sharded / KV-sequence-split
@@ -80,8 +80,7 @@ def _one_decode_step(cfg, params, mesh, uniform_slot=True):
 
 
 @pytest.mark.parametrize("dp,tp,path", [(4, 2, "heads"), (2, 4, "seq")])
-def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch,
-                                        interpreted_kernels):
+def test_decode_step_via_pallas_kernels(dp, tp, path, interpreted_kernels):
     cfg = _cfg()
     params = T.init_params(cfg, jax.random.PRNGKey(0))
 
@@ -97,7 +96,6 @@ def test_decode_step_via_pallas_kernels(dp, tp, path, monkeypatch,
     assert choose_decode_partitioning(
         mesh, 4, cfg.n_q_heads, cfg.n_kv_heads, 16) == path
 
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh=mesh)
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
@@ -130,7 +128,6 @@ def test_decode_step_unrolled_reads_the_stack_in_place(
     monkeypatch.setattr(
         D, "flash_decode_attention_stacked",
         lambda *a, **kw: calls.append(a[4]) or kernel(*a, **kw))
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh and _mesh(*mesh),
                                uniform_slot)
@@ -151,7 +148,6 @@ def test_decode_step_stacked_scan_path(dp, tp, monkeypatch,
     ref = _one_decode_step(cfg, params, mesh=None)  # unrolled XLA path
 
     monkeypatch.setattr(T, "_DECODE_UNROLL_MAX_LAYERS", 0)
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got = _one_decode_step(cfg, params, mesh=_mesh(dp, tp))
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
@@ -159,7 +155,7 @@ def test_decode_step_stacked_scan_path(dp, tp, monkeypatch,
 
 @pytest.mark.parametrize("dp,tp", [(1, 1), (2, 2)])
 def test_generate_span_says_how_the_decode_loop_reads_the_cache(
-        dp, tp, monkeypatch, interpreted_kernels):
+        dp, tp, interpreted_kernels):
     """Every ``engine:generate`` span carries ``decode_kernel`` and
     ``decode_layer_copies``, read once from the program's compiled
     text: ``stacked`` and 0 where the kernel reads the stack in
@@ -194,7 +190,6 @@ def test_generate_span_says_how_the_decode_loop_reads_the_cache(
     ref, xla = run()
     assert [a["decode_kernel"] for a in xla] == ["xla", "xla"]
     assert [a["compiled"] for a in xla] == [True, False]
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got, stacked = run()
     np.testing.assert_array_equal(got, ref)
@@ -204,7 +199,7 @@ def test_generate_span_says_how_the_decode_loop_reads_the_cache(
 
 
 def test_engine_counts_the_key_blocks_its_flash_kernels_visit(
-        monkeypatch, interpreted_kernels):
+        interpreted_kernels):
     """A packed batch through ``train_batch`` and ``forward_logprobs``
     with the flash kernels engaged: each ``engine:*`` span carries
     ``flash_block_share`` and ``flash_kv_blocks_total{kind}`` grows by
@@ -254,7 +249,6 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(
     assert "flash_block_share" not in xla.named(
         "engine:train")[0]["attributes"]
 
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         capture = run(engine())
     [train] = capture.named("engine:train")
@@ -331,7 +325,6 @@ def _train_spans(program, text, monkeypatch, interpreted_kernels,
         xla_path_spans[program, dense] = run()
         assert read == []
     off = xla_path_spans[program, dense]
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         on = run()
     assert read == [program]

@@ -640,8 +640,7 @@ def test_the_config_says_what_a_latent_layer_may_be():
     assert one.rotary_of("attention").interleaved
 
 
-def test_latent_stack_through_the_flash_kernels(monkeypatch,
-                                                interpreted_kernels):
+def test_latent_stack_through_the_flash_kernels(interpreted_kernels):
     """Keys of 64 + 64 = 128 and values of 64, rows of 1024, so that
     the packed rows meet the flash kernels' gate: with the kernels
     engaged (interpret mode) at TWO widths the stack gives the XLA
@@ -669,7 +668,6 @@ def test_latent_stack_through_the_flash_kernels(monkeypatch,
     want, xla = run()
     assert not any(k.startswith("flash_kv_blocks_total")
                    for k in xla.counters)
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got, capture = run()
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)

@@ -498,7 +498,7 @@ def test_one_block_models_refuse_the_per_layer_fields():
 
 
 def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
-        monkeypatch, interpreted_kernels):
+        interpreted_kernels):
     """Heads of 64 and rows of 1024, so that the packed rows meet the
     flash kernels' gate: with the kernels engaged (interpret mode) the
     stack of window and full layers gives the XLA path's hidden
@@ -530,7 +530,6 @@ def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
     want, xla = run()
     assert not any(k.startswith("flash_kv_blocks_total")
                    for k in xla.counters)
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
     with interpreted_kernels():
         got, capture = run()
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)

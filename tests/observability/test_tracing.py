@@ -586,7 +586,10 @@ class _FakeHost:
 
 
 @pytest.mark.parametrize("parallel", [True, False])
-def test_parentage_step_mfc_compute_engine(parallel):
+def test_parentage_step_mfc_compute_engine(parallel, monkeypatch):
+    # a level overlaps where the host has more than one CPU
+    import realhf_tpu.system.model_host as mh
+    monkeypatch.setattr(mh.os, "cpu_count", lambda: 2 if parallel else 1)
     host = _FakeHost()
     seen = set()
     tracing.start()
@@ -598,8 +601,7 @@ def test_parentage_step_mfc_compute_engine(parallel):
             return orig(node_name, inp)
         host.execute = execute
         outs = host.execute_level(
-            [("ref_inf", _Batch()), ("rew_inf", _Batch())],
-            parallel=parallel)
+            [("ref_inf", _Batch()), ("rew_inf", _Batch())])
     capture = tracing.stop()
     assert len(outs) == 2 and len(seen) == (2 if parallel else 1)
     [step] = capture.named("step")
@@ -639,8 +641,7 @@ def test_parentage_step_mfc_compute_engine(parallel):
 
 def test_off_the_runner_layers_record_nothing():
     host = _FakeHost()
-    host.execute_level([("ref_inf", _Batch()), ("rew_inf", _Batch())],
-                       parallel=True)
+    host.execute_level([("ref_inf", _Batch()), ("rew_inf", _Batch())])
     assert set(host.exec_infos) == {"ref_inf", "rew_inf"}
     assert tracing.default_tracer().drain() == []
 

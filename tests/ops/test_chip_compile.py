@@ -4,9 +4,9 @@ a chip (section 2 of /opt/skills/guides/on-chip-measurement/SKILL.md).
 Interpret mode cannot show a slice that misses the tiling or a kernel
 that wants more VMEM than it may use; the chip's compiler can, and it is
 installed here. Shapes are Qwen2.5-0.5B's (14 query and 2 key/value
-heads of 64, bf16) at the lengths chip_smoke.py runs: packed train and
-inference rows of 4096 tokens, a generation prefill of 128 prompts of
-256, and a decode cache of 512 slots.
+heads of 64, bf16) at the lengths the benchmark's first cell runs:
+packed train and inference rows of 4096 tokens, a generation prefill of
+128 prompts of 256, and a decode cache of 512 slots.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU's library, and

@@ -1,14 +1,11 @@
-"""Compiled-mode (non-interpret) Pallas kernel tier + the disposition
-table (ROADMAP weak #2).
+"""Compiled-mode (non-interpret) Pallas kernel tier.
 
 The interpret-mode tests elsewhere in tests/ops prove kernel MATH; an
 interpret-only kernel is still a first-contact risk because nothing
 exercises the Mosaic lowering until a chip window. This tier runs each
 kernel with ``interpret=False`` wherever the backend can lower it and
 skips WITH AN EXPLICIT REASON STRING everywhere else, so a TPU CI run
-flips these from skipped to executed with no code change. The
-disposition table (ops/dispositions.kernel_dispositions) reports the
-same gates into every BENCH payload.
+flips these from skipped to executed with no code change.
 """
 
 import numpy as np
@@ -17,17 +14,23 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from realhf_tpu.ops.dispositions import KERNELS, kernel_dispositions
+from realhf_tpu.base.backend import pallas_enabled
+
+KERNELS = (
+    "flash_attention",               # ops/flash_attention.py (packed fwd/bwd)
+    "flash_decode_attention_stacked",  # ops/decode_attention.py
+    "grouped_matmul",                # ops/grouped_matmul.py (the experts')
+    "delta_rule_scan",               # ops/delta_rule.py (chunked scan fwd/bwd)
+)
 
 
 def _compiled_unavailable_reason(kernel: str):
-    """None when `kernel` can run compiled here, else the skip
-    reason -- the SAME verdict the disposition table publishes."""
-    disp = kernel_dispositions()[kernel]
-    if disp["mode"] == "compiled":
+    """None when `kernel` can run compiled here, else the skip reason:
+    the program's own gate, outside the interpreter."""
+    if pallas_enabled():
         return None
-    return (f"compiled-mode {kernel} unavailable: {disp['reason']} "
-            f"(disposition mode={disp['mode']})")
+    return (f"compiled-mode {kernel} unavailable: backend "
+            f"{jax.default_backend()!r} cannot lower Mosaic kernels")
 
 
 @pytest.mark.parametrize("kernel", list(KERNELS))
@@ -99,47 +102,3 @@ def test_compiled_kernel_matches_reference(kernel):
             q, ks, vs, valid, jnp.int32(li), interpret=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-2, rtol=2e-2)
-
-
-# ----------------------------------------------------------------------
-# Disposition table contract (runs everywhere)
-# ----------------------------------------------------------------------
-def test_disposition_table_covers_all_kernels_with_reasons():
-    disp = kernel_dispositions()
-    assert sorted(disp) == sorted(KERNELS)
-    for k, d in disp.items():
-        assert d["mode"] in ("compiled", "interpret", "xla"), (k, d)
-        assert isinstance(d["engaged"], bool)
-        assert d["reason"] and isinstance(d["reason"], str), (
-            f"{k}: disposition must carry an explicit reason")
-        assert d["engaged"] == (d["mode"] != "xla")
-
-
-def test_disposition_reflects_backend_and_overrides(monkeypatch):
-    monkeypatch.delenv("REALHF_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.setenv("REALHF_TPU_DISABLE_PALLAS", "1")
-    disp = kernel_dispositions()
-    assert all(not d["engaged"] for d in disp.values())
-    assert "REALHF_TPU_DISABLE_PALLAS" in \
-        disp["flash_decode_attention_stacked"]["reason"]
-
-    monkeypatch.delenv("REALHF_TPU_DISABLE_PALLAS", raising=False)
-    if jax.default_backend() != "tpu":
-        # off-TPU the default is the XLA path with the backend named
-        disp = kernel_dispositions()
-        assert disp["flash_decode_attention_stacked"]["mode"] == "xla"
-        assert jax.default_backend() in \
-            disp["flash_decode_attention_stacked"]["reason"]
-
-    monkeypatch.setenv("REALHF_TPU_FORCE_PALLAS", "1")
-    disp = kernel_dispositions()
-    assert all(d["engaged"] for d in disp.values())
-
-
-def test_disposition_lands_in_bench_payload_shape():
-    """bench.py embeds this exact table; pin the serializable shape so
-    the payload contract cannot drift silently."""
-    import json
-    disp = kernel_dispositions()
-    rt = json.loads(json.dumps(disp))
-    assert rt == disp

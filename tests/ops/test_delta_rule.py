@@ -23,7 +23,7 @@ PATHS = ["xla", "kernel"]
 
 
 @pytest.fixture(params=PATHS)
-def path(request, interpreted_kernels, monkeypatch):
+def path(request, interpreted_kernels):
     """``(chunked, widths)``: ``chunked_delta_rule`` as this path runs
     it and the heads' ``dict(dk=, dv=, h=)`` it is run at. The kernels
     take heads of whole lanes only (a narrower head goes down the XLA
@@ -32,7 +32,6 @@ def path(request, interpreted_kernels, monkeypatch):
         assert not D.pallas_enabled()
         yield D.chunked_delta_rule, dict(dk=DK, dv=DV, h=H)
         return
-    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
     # (a gradient's backward kernel is traced after the forward call
     # has returned: the whole test runs under the interpreter)
     with interpreted_kernels():

@@ -364,7 +364,6 @@ def products(request, monkeypatch, interpreted_kernels):
     if request.param == "ragged_dot":
         yield request.param
         return
-    monkeypatch.setattr(moe_ops, "pallas_enabled", lambda: True)
     # (the interpreter's callbacks are effects that the fallback's
     # ``checkpoint`` cannot split under a gradient)
     monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)

@@ -322,8 +322,7 @@ def _delta_case(b=2, l=100, h=2, d=128):
     return (a_log, dt_bias, q, k, v, f, beta), jnp.asarray(seg), w, w_last
 
 
-def test_sharded_delta_scan_matches_one_device(interpreted_kernels,
-                                               monkeypatch):
+def test_sharded_delta_scan_matches_one_device(interpreted_kernels):
     """``ops/delta_rule.py``'s two kernels on a dp2 x tp2 mesh: a bare
     Mosaic call cannot be partitioned (on the chip jax refuses to lower
     one on a mesh), so each device runs them on its own rows and heads
@@ -331,7 +330,6 @@ def test_sharded_delta_scan_matches_one_device(interpreted_kernels,
     products on one device; d of the decay's two tensors, which every
     data shard holds, is summed over "data"."""
     from realhf_tpu.ops import delta_rule as D
-    monkeypatch.setattr(D, "pallas_enabled", lambda: True)
     args, seg, w, w_last = _delta_case()
     d = args[2].shape[-1]
 

@@ -96,12 +96,6 @@ def test_stream_properties(n_stages, mult):
 
 def test_analytics():
     assert S.ticks_per_pass(4, 4) == 7
-    assert S.train_ticks(4, 4) == 14
-    # the acceptance numbers: (S-1)/(M+S-1) = 3/7 at S=4, M=4
-    assert S.bubble_fraction(4, 4) == pytest.approx(3 / 7)
-    # 1F1B computes only useful stage-steps; GPipe burns every tick
-    assert S.computed_stage_steps(4, 4, "1f1b") == 2 * 4 * 4
-    assert S.computed_stage_steps(4, 4, "gpipe") == 2 * 7 * 4
     # defaults: 1F1B affords twice the microbatches -> smaller factor
     assert S.default_microbatches(4, "1f1b") == 16
     assert S.default_microbatches(4, "gpipe") == 8
@@ -260,31 +254,6 @@ def test_1f1b_pads_stream_remainder_and_weights_aux():
                     > gap / 2, f"{sched}/{k}: aux still equal-weighted"
         assert any(abs(float(aux_ref[k]) - float(aux_old[k])) > 1e-5
                    for k in aux_ref), "test data cannot discriminate"
-
-
-def test_1f1b_mask_escape_hatch_matches(monkeypatch):
-    """REALHF_TPU_PIPE_MASK=0 (compute-and-discard bubble ticks) is
-    numerically identical to the masked default."""
-    cfg = _cfg()
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    ids, seg = _batch(cfg)
-    mesh = _pp_mesh(2)
-    pipe = PipelineContext(mesh=mesh, n_stages=2, n_microbatches=2,
-                           schedule="1f1b")
-    p_sharded = jax.device_put(params,
-                               shard_rules.param_shardings(cfg, mesh))
-
-    def loss(p):
-        h, _ = T.forward(cfg, p, ids, seg, pipeline=pipe)
-        return (h ** 2).mean()
-
-    g_masked = jax.jit(jax.grad(loss))(p_sharded)
-    monkeypatch.setenv("REALHF_TPU_PIPE_MASK", "0")
-    g_unmasked = jax.jit(jax.grad(loss))(p_sharded)
-    for a, b in zip(jax.tree.leaves(jax.tree.map(np.asarray, g_masked)),
-                    jax.tree.leaves(jax.tree.map(np.asarray,
-                                                 g_unmasked))):
-        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
 # ----------------------------------------------------------------------

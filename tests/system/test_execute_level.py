@@ -1,6 +1,6 @@
 """ModelHost.execute_level contract: outputs in input order, per-node
-exec_infos populated, concurrent threads actually used for >1 node,
-and the serialized escape hatch honored."""
+exec_infos populated, concurrent threads actually used for >1 node
+where the host has more than one CPU, and in order where it has one."""
 
 import threading
 import time
@@ -34,12 +34,14 @@ class TestExecuteLevel:
         assert outs == [f"out:n{i}:{i}" for i in range(5)]
         assert set(host.exec_infos) == {f"n{i}" for i in range(5)}
 
-    def test_concurrent_threads_for_multi_node_level(self):
+    def test_concurrent_threads_for_multi_node_level(self, monkeypatch):
         # deterministic overlap proof: every execute() waits at a
         # shared barrier, which only releases when all three calls are
         # in flight SIMULTANEOUSLY -- no wall-clock bound to flake on
-        # a loaded box. parallel=True bypasses the single-CPU default
-        # (the mechanism is what's under test, not the gate).
+        # a loaded box. The host is given two CPUs here (the mechanism
+        # is what's under test, not the gate).
+        import realhf_tpu.system.model_host as mh
+        monkeypatch.setattr(mh.os, "cpu_count", lambda: 2)
         host = _FakeHost()
         barrier = threading.Barrier(3)
         orig = host.execute
@@ -49,50 +51,20 @@ class TestExecuteLevel:
             return orig(node_name, inp)
 
         host.execute = execute
-        outs = host.execute_level([("a", 1), ("b", 2), ("c", 3)],
-                                  parallel=True)
+        outs = host.execute_level([("a", 1), ("b", 2), ("c", 3)])
         assert outs == ["out:a:1", "out:b:2", "out:c:3"]
         assert len(host.threads_seen) == 3
 
     def test_single_cpu_defaults_to_serial(self, monkeypatch):
         # concurrent XLA CPU collectives spin-wait their rendezvous;
-        # one core starves them into deadlock -- the default must
-        # serialize there (REALHF_TPU_PARALLEL_MFC=1 still forces)
+        # one core starves them into deadlock -- the level must run in
+        # order there
         import realhf_tpu.system.model_host as mh
-        monkeypatch.delenv("REALHF_TPU_PARALLEL_MFC", raising=False)
         monkeypatch.setattr(mh.os, "cpu_count", lambda: 1)
         host = _FakeHost()
-        host.execute_level([("a", 1), ("b", 2)])
-        assert len(host.threads_seen) == 1
-        monkeypatch.setenv("REALHF_TPU_PARALLEL_MFC", "1")
-        host2 = _FakeHost()
-        barrier = threading.Barrier(2)
-        orig = host2.execute
-
-        def execute(node_name, inp):
-            barrier.wait(timeout=30)  # needs both in flight at once
-            return orig(node_name, inp)
-
-        host2.execute = execute
-        host2.execute_level([("a", 1), ("b", 2)])
-        assert len(host2.threads_seen) == 2
-
-    def test_parallel_false_serializes(self):
-        host = _FakeHost(sleep_s=0.1)
-        t0 = time.monotonic()
-        outs = host.execute_level([("a", 1), ("b", 2)], parallel=False)
-        wall = time.monotonic() - t0
+        outs = host.execute_level([("a", 1), ("b", 2)])
         assert outs == ["out:a:1", "out:b:2"]
-        assert wall >= 0.2
-        assert len(host.threads_seen) == 1
-
-    def test_env_kill_switch(self, monkeypatch):
-        monkeypatch.setenv("REALHF_TPU_PARALLEL_MFC", "0")
-        host = _FakeHost(sleep_s=0.1)
-        t0 = time.monotonic()
-        host.execute_level([("a", 1), ("b", 2)])
-        assert time.monotonic() - t0 >= 0.2
-        assert len(host.threads_seen) == 1
+        assert host.threads_seen == {threading.get_ident()}
 
     def test_single_node_stays_on_caller_thread(self):
         host = _FakeHost()
