@@ -46,7 +46,9 @@ from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
 from realhf_tpu.ops.delta_rule import scan_kernel_calls
-from realhf_tpu.ops.flash_attention import block_counts, flash_fwd_per_bwd
+from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
+                                            flash_mask_calls)
+from realhf_tpu.ops.sparse_index import pair_counts
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host
@@ -197,6 +199,12 @@ class Engine:
         # counts. The ring and the pipeline's XLA path do not.
         self._flash_rows = False
         if ctx.parallel.context_parallel_size > 1:
+            if cfg.sparse_layers:
+                raise NotImplementedError(
+                    "context parallelism (ops/ring_attention.py) is not "
+                    "implemented for a model with sparse layers "
+                    f"(layer_pattern '{cfg.pattern_string}'): the ring "
+                    "takes no selection of keys")
             from realhf_tpu.ops.ring_attention import ring_attention
             mesh = self.mesh
 
@@ -271,6 +279,12 @@ class Engine:
                     delta_heads=cfg.delta.n_heads,
                     delta_head_dim=cfg.delta.head_dim,
                     delta_chunk=CHUNK)
+            if cfg.indexer is not None:
+                self._model_attrs.update(
+                    sparse_layers=len(cfg.sparse_layers),
+                    index_heads=cfg.indexer.heads,
+                    index_dim=cfg.indexer.head_dim,
+                    index_topk=cfg.indexer.topk)
             if cfg.layer_q_heads is not None:
                 self._model_attrs.update(q_heads=" ".join(
                     str(cfg.q_heads(i)) for i in cfg.attention_layers))
@@ -404,7 +418,29 @@ class Engine:
         on the host from the segment ids: the counters, and what of
         them its ``engine:*`` span carries (:meth:`_run`)."""
         self._count_routed_pairs(seg_ids, decode_tokens)
+        self._count_sparse_pairs(seg_ids)
         return self._count_flash_blocks(seg_ids)
+
+    def _count_sparse_pairs(self, seg_ids):
+        """``sparse_pairs_total{role,kind}``: the (query, key) pairs
+        the sparse layers of the program about to run attend over
+        these packed rows (``selected``: ``min(position + 1, topk)`` a
+        token) and the pairs under their documents' causal masks
+        (``causal``), one head's, times the sparse layers
+        (``ops.sparse_index.pair_counts``); ``index_tokens_total
+        {role}``: valid tokens x sparse layers. Nothing for a model
+        without such layers or a batch on the device already."""
+        cfg = self.cfg
+        if not cfg.sparse_layers or not isinstance(seg_ids, np.ndarray):
+            return
+        role, n = str(self.ctx.model_name.role), len(cfg.sparse_layers)
+        selected, causal = pair_counts(seg_ids, cfg.indexer.topk)
+        metrics.inc("sparse_pairs_total", n * selected, role=role,
+                    kind="selected")
+        metrics.inc("sparse_pairs_total", n * causal, role=role,
+                    kind="causal")
+        metrics.inc("index_tokens_total",
+                    n * int(np.count_nonzero(seg_ids)), role=role)
 
     def _count_flash_blocks(self, seg_ids) -> Dict[str, float]:
         """``flash_kv_blocks_total{role,kind}``: the (query block, key
@@ -522,7 +558,11 @@ class Engine:
         with delta layers ``delta_scan_kernel_calls``
         (``ops.delta_rule.scan_kernel_calls``: the chunked scan's
         kernels in the text: a forward and a backward one a delta
-        layer of a train program, 0 where the XLA products run). Sets
+        layer of a train program, 0 where the XLA products run), and
+        of every program of a model with sparse layers
+        ``flash_mask_calls`` (``ops.flash_attention.flash_mask_calls``:
+        the flash kernels in the text that take the selection, three a
+        sparse layer of a train program, 0 on the XLA path). Sets
         gauge
         ``engine_program_bytes{role,program,kind}``."""
         if (name, key) not in self._facts:
@@ -538,6 +578,7 @@ class Engine:
                 mine = None
             ragged = moe_ops.dispatch_mode(self.cfg) == "ragged"
             delta = self.cfg.delta is not None
+            sparse = self.cfg.indexer is not None
 
             def derive(text):
                 out = mine(text) if mine is not None else {}
@@ -546,6 +587,8 @@ class Engine:
                 if delta:
                     out.update(delta_scan_kernel_calls=scan_kernel_calls(
                         text))
+                if sparse:
+                    out.update(flash_mask_calls=flash_mask_calls(text))
                 return out
 
             facts = parts.read_program(self._compiled(name, call), derive)
@@ -1204,6 +1247,12 @@ class Engine:
                 self._last_span.set_attribute(
                     "delta_state_bytes",
                     int(np.prod(tail)) * item + int(np.prod(state)) * 4)
+            if self.cfg.sparse_layers:
+                b, lp = prompt_seg.shape
+                self._last_span.set_attribute(
+                    "index_cache_bytes", int(np.prod(T.index_cache_shape(
+                        self.cfg, b, T.round_cache_len(
+                            lp + gconfig.max_new_tokens)))) * item)
         return out
 
     def inflight_generator(self, gconfig: GenerationHyperparameters,

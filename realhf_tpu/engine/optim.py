@@ -16,6 +16,11 @@ import jax.numpy as jnp
 import optax
 
 
+#: the key of a layer's subtree that takes no update (see
+#: ``make_optimizer``): a sparse layer's indexer
+UNTRAINED_SUBTREE = "index"
+
+
 @dataclasses.dataclass
 class OptimizerConfig:
     """Mirrors reference OptimizerConfig field-by-field (type "empty"
@@ -114,9 +119,16 @@ def make_optimizer(cfg: OptimizerConfig,
     if cfg.gradient_clipping and cfg.gradient_clipping > 0:
         chain.append(optax.clip_by_global_norm(cfg.gradient_clipping))
     # Decay only matrix-shaped params (norm scales/biases excluded),
-    # matching Megatron's no-weight-decay param groups.
+    # matching Megatron's no-weight-decay param groups; and nothing
+    # under a sparse layer's "index": no gradient of the language-model
+    # loss reaches an indexer (models/config.py:IndexerConfig), and a
+    # decay alone would shrink what nothing trains. With neither, its
+    # leaves stay bit-equal through every step.
     def decay_mask(params):
-        return jax.tree.map(lambda p: p.ndim >= 2, params)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, p: p.ndim >= 2 and not any(
+                getattr(k, "key", None) == UNTRAINED_SUBTREE
+                for k in path), params)
 
     chain.append(optax.adamw(
         learning_rate=sched, b1=cfg.beta1, b2=cfg.beta2, eps=cfg.eps,

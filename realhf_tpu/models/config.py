@@ -15,9 +15,11 @@ from typing import Dict, Optional, Tuple
 #: keys and values expanded from ONE compressed row a token
 #: (``LatentConfig``); "delta" is no attention: it has no K/V rows, a
 #: head keeps ONE state that every earlier token of the document went
-#: into (``DeltaConfig``)
-OPERATORS = ("conv", "attention", "window", "latent", "delta")
-ATTENTION_OPERATORS = ("attention", "window", "latent")
+#: into (``DeltaConfig``); "sparse" is attention over the keys a learned
+#: indexer picks for the token among the earlier ones of its document,
+#: ``topk`` of them (``IndexerConfig``)
+OPERATORS = ("conv", "attention", "window", "latent", "delta", "sparse")
+ATTENTION_OPERATORS = ("attention", "window", "latent", "sparse")
 FEED_FORWARDS = ("dense", "moe")
 
 
@@ -143,6 +145,37 @@ class DeltaConfig:
         """The width (u w_fa) and (u w_ga) pass through: a head's, as
         published (the config has no key for it)."""
         return self.head_dim
+
+
+#: what the indexer's LayerNorm of its key norms at (its class's
+#: default: the published config has no key for it)
+INDEX_NORM_EPS = 1e-6
+
+
+@dataclasses.dataclass
+class IndexerConfig:
+    """The learned indexer of a "sparse" layer (DeepSeek sparse
+    attention): which keys a token's attention runs over. With u the
+    layer's normed input, ``heads`` index heads of ``head_dim`` over ONE
+    index key a token, ``rot`` the layer's rotary embedding over the
+    whole ``head_dim``-wide head::
+
+        qI = rot(u wq)                        [T, heads, head_dim]
+        kI = rot(LayerNorm(u wk; k_norm, k_norm_bias))   [T, head_dim]
+        w  = (u w_weights) * heads^-0.5 * head_dim^-0.5  [T, heads]
+        I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])
+        S_t = the ``topk`` visible s (same document, s <= t) of largest
+              I[t, s], ties to the lower s; every visible s where there
+              are no more than ``topk``
+
+    Attention (grouped-query, every head of a token over the same
+    ``S_t``) then runs over ``S_t`` alone. The selection is discrete: no
+    gradient of the language-model loss reaches the indexer's leaves
+    (``models/transformer.py:_index_select`` stops it by name), and
+    decoding keeps ``kI`` a token a layer (``cache["index_k"]``)."""
+    heads: int
+    head_dim: int
+    topk: int
 
 
 @dataclasses.dataclass
@@ -298,6 +331,8 @@ class TransformerConfig:
     latent: Optional[LatentConfig] = None
     # What the "delta" layers of the pattern are made of.
     delta: Optional[DeltaConfig] = None
+    # The indexer of the pattern's "sparse" layers.
+    indexer: Optional[IndexerConfig] = None
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -370,11 +405,22 @@ class TransformerConfig:
                 self.layer_q_heads is not None
                 or self.rotary_by_operator is not None
                 or self.attn_output_gate or self.latent is not None
-                or self.delta is not None):
+                or self.delta is not None or self.indexer is not None):
             raise NotImplementedError(
                 "layer_q_heads, rotary_by_operator, attn_output_gate, "
-                "latent and delta belong to a model with a "
+                "latent, delta and indexer belong to a model with a "
                 "layer_pattern")
+        if (self.indexer is not None) != bool(self.sparse_layers):
+            raise ValueError(
+                f"layer_pattern has {len(self.sparse_layers)} sparse "
+                f"layers, indexer is {self.indexer}")
+        if self.indexer is not None and (
+                self.indexer.head_dim % 2 or self.indexer.topk < 1
+                or self.rotary_by_operator is not None
+                or self.scale_attn_by_inverse_layer_idx):
+            raise NotImplementedError(
+                "a sparse layer's indexer has an even head_dim, a topk "
+                "of at least 1 and the model-wide rotary embedding")
         if (self.delta is not None) != bool(self.delta_layers):
             raise ValueError(
                 f"layer_pattern has {len(self.delta_layers)} delta "
@@ -463,6 +509,12 @@ class TransformerConfig:
                      if op == "delta")
 
     @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        """The layers whose attention runs over an indexer's choice."""
+        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
+                     if op == "sparse")
+
+    @property
     def v_head_dim(self) -> int:
         """A value head's width, which is also an attention output
         head's: ``head_dim`` unless the layers are latent."""
@@ -517,9 +569,9 @@ class TransformerConfig:
 
     @property
     def pattern_string(self) -> str:
-        """``c a c c c``, ``a w w w a``, ``l l l``, ``d d d l d``:
-        every layer's operator by its first letter (conv, attention,
-        window, latent, delta)."""
+        """``c a c c c``, ``a w w w a``, ``l l l``, ``d d d l d``,
+        ``s s s``: every layer's operator by its first letter (conv,
+        attention, window, latent, delta, sparse)."""
         return " ".join(op[0] for op, _ in self.layer_kinds)
 
     def require_one_block(self, what: str):
@@ -534,7 +586,9 @@ class TransformerConfig:
                 f"{len(self.window_layers)} of those with a window, "
                 f"{len(self.latent_layers)} latent, "
                 f"{len(self.delta_layers)} delta layers that keep a "
-                f"state a head, {self.n_moe_layers} layers sparse)")
+                f"state a head, {len(self.sparse_layers)} whose keys an "
+                f"indexer picks, {self.n_moe_layers} layers with "
+                "experts)")
 
     def n_params(self) -> int:
         """Approximate parameter count (for FLOPs/memory estimates),
@@ -543,7 +597,8 @@ class TransformerConfig:
         the experts HELD, the shared expert, the query/key norms and
         the output gate, each attention layer at its own count of
         query heads, a latent layer's five leaves, a delta layer's
-        fifteen; biases and the layer norms' scales are left out."""
+        fifteen, a sparse layer's indexer; biases and the layer norms'
+        scales are left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
 
         def attn(i):
@@ -561,6 +616,10 @@ class TransformerConfig:
                 n += (nq + self.n_kv_heads) * self.head_dim
             elif self.qk_norm == "head":
                 n += 2 * self.head_dim
+            if self.layer_kinds[i][0] == "sparse":
+                ix = self.indexer
+                n += h * (ix.heads + 1) * ix.head_dim + h * ix.heads \
+                    + ix.head_dim
             return n + (h * nq if self.attn_output_gate else 0)
 
         conv = 4 * h * h + self.conv_kernel * h

@@ -103,7 +103,7 @@ def param_pspecs(cfg: TransformerConfig,
 def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
     """``param_pspecs`` of a patterned model: the same rules with no
     layer axis, a tree a layer. A delta layer is tensor parallel by
-    head. The convolution is tensor parallel
+    head, a sparse layer's indexer on every shard. The convolution is tensor parallel
     like a feed-forward: ``w_in`` by column (GSPMD moves its three
     parts to a sharding by channel after the split), the taps by
     channel, ``w_out`` by row."""
@@ -141,6 +141,12 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
             elif cfg.qk_norm is not None:
                 lp["attn"].update(q_norm=P(MODEL_AXIS),
                                   k_norm=P(MODEL_AXIS))
+            if op == "sparse":
+                # on every shard: the selection is one for all the
+                # heads of a token, so every shard needs it whole
+                lp["index"] = {"wq": P(None, None), "wk": P(None, None),
+                               "k_norm": P(None), "k_norm_bias": P(None),
+                               "w_weights": P(None, None)}
         if ff == "moe":
             lp["mlp"] = {"router": P(None, None),
                          "wg": P(None, None, MODEL_AXIS),

@@ -42,6 +42,12 @@ Design (idiomatic JAX, not a torch translation):
   rows of its three short convolutions' inputs, a THIRD kind of decode
   state beside K/V and ``cache["conv"]``. A latent layer may have no
   rotary embedding (``rotary_by_operator["latent"] = None``).
+  Or a layer's attention runs over the keys a learned indexer picks
+  (operator "sparse", ``IndexerConfig``): the selection [B, L, L] is
+  one more operand of the attention function beside ``seg_ids``, and
+  the indexer's keys are a THIRD kind of attention cache
+  (``cache["index_k"]``, one ``head_dim``-wide row a token a layer)
+  beside K and V, which decoding scores, selects from and attends over.
   A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
@@ -57,15 +63,17 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from realhf_tpu.base.backend import pallas_enabled
-from realhf_tpu.models.config import (DELTA_L2_EPS, LATENT_NORM_EPS,
-                                      TransformerConfig)
+from realhf_tpu.models.config import (DELTA_L2_EPS, INDEX_NORM_EPS,
+                                      LATENT_NORM_EPS, TransformerConfig)
 from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
 from realhf_tpu.ops.delta_rule import RESIDUAL_NAMES as _SCAN_RESIDUALS
 from realhf_tpu.ops.delta_rule import (Prepare, chunked_delta_rule,
                                        delta_rule_step)
-from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES
+from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES, SELECT_RESIDUAL
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
+from realhf_tpu.ops.sparse_index import (index_scores, select_topk,
+                                         selection_mask)
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
@@ -91,8 +99,12 @@ PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
 #: path names the output alone: its own backward runs it again a
 #: segment at a time.)
 DELTA_RESIDUALS = ("delta_out",) + _SCAN_RESIDUALS
-#: every name the policy of a rematerialised block keeps
-KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS
+#: every name the policy of a rematerialised block keeps; the last is
+#: a sparse layer's selection (int8, ``L x L`` bytes a row a layer):
+#: kept, the backward's kernels mask by it and the indexer, which no
+#: gradient reaches, does not run a second time
+KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS \
+    + (SELECT_RESIDUAL,)
 
 
 # ----------------------------------------------------------------------
@@ -191,7 +203,8 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     proj_std = std / (2 * cfg.n_layers) ** 0.5
     # a delta layer has more leaves than 16; the others keep the keys
     # they have always drawn
-    per_layer = 16 if cfg.delta is None else 24
+    per_layer = 24 if cfg.delta is not None or cfg.indexer is not None \
+        else 16
     keys = iter(jax.random.split(key, per_layer * cfg.n_layers + 4))
 
     def norm(shape, s=std):
@@ -231,6 +244,14 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                 lp["attn"]["k_norm"] = ones((heads[1] * hd,))
             if cfg.attn_output_gate:
                 lp["attn"]["w_gate"] = norm((h, nq))
+            if op == "sparse":
+                ix = cfg.indexer
+                lp["index"] = {
+                    "wq": norm((h, ix.heads * ix.head_dim)),
+                    "wk": norm((h, ix.head_dim)),
+                    "k_norm": ones((ix.head_dim,)),
+                    "k_norm_bias": jnp.zeros((ix.head_dim,), pdt),
+                    "w_weights": norm((h, ix.heads))}
         if ff == "moe":
             ne, nh = cfg.moe.num_experts, cfg.moe.n_held
             fe = cfg.moe.intermediate_dim or f
@@ -621,16 +642,71 @@ def _head_gate(lp: Params, ln1: jnp.ndarray, attn: jnp.ndarray):
     return attn * gate[..., None].astype(attn.dtype)
 
 
+def _index_inputs(cfg: TransformerConfig, ix: Params, u: jnp.ndarray,
+                  cos: jnp.ndarray, sin: jnp.ndarray):
+    """What a sparse layer's indexer makes of the normed input u
+    [..., H] (``IndexerConfig`` has the equations; sub-part
+    ``index/project``): its queries [..., heads, d] and its ONE key
+    [..., d], both rotated by the indexer's table (the layer's rotary
+    embedding over the whole d-wide head), in the compute dtype, and
+    the heads' weights [..., heads], scaled, in float32."""
+    cdt, ic = u.dtype, cfg.indexer
+    with jax.named_scope(P.PROJECT):
+        q = (u @ ix["wq"].astype(cdt)).reshape(
+            *u.shape[:-1], ic.heads, ic.head_dim)
+        k = (u @ ix["wk"].astype(cdt)).astype(jnp.float32)
+        # a LayerNorm WITH a bias, whatever the model's norms are
+        k = k - k.mean(-1, keepdims=True)
+        k = k * jax.lax.rsqrt(
+            jnp.mean(k * k, -1, keepdims=True) + INDEX_NORM_EPS) \
+            * ix["k_norm"].astype(jnp.float32) \
+            + ix["k_norm_bias"].astype(jnp.float32)
+        interleaved = cfg.rotary_of("sparse").interleaved
+        q = apply_rotary(q, cos, sin, interleaved)
+        k = apply_rotary(k.astype(cdt)[..., None, :], cos, sin,
+                         interleaved)[..., 0, :]
+        w = (u @ ix["w_weights"].astype(cdt)).astype(jnp.float32) \
+            * (ic.heads ** -0.5 * ic.head_dim ** -0.5)
+    return q, k, w
+
+
+def _index_select(cfg: TransformerConfig, ix: Params, u: jnp.ndarray,
+                  seg_ids: jnp.ndarray, cos: jnp.ndarray,
+                  sin: jnp.ndarray):
+    """A sparse layer's selection over packed rows on the normed
+    residual u [B, L, H] -> (the int8 mask [B, L, L] that the attention
+    function takes, the indexer's keys [B, L, d] for prefill's cache).
+    Part ``index`` (obs/parts.py). The selection is discrete and NO
+    gradient passes it: ``stop_gradient`` on what it is made from says
+    so by name and changes no number (the indexer's leaves get zeros
+    from the language-model loss either way; the alignment loss that
+    trains them is not part of this program, ROADMAP R4c)."""
+    with jax.named_scope(P.INDEX):
+        q, k, w = _index_inputs(cfg, jax.lax.stop_gradient(ix),
+                                jax.lax.stop_gradient(u), cos, sin)
+        select = selection_mask(q, k, w, seg_ids, cfg.indexer.topk)
+        return checkpoint_name(select, SELECT_RESIDUAL), k
+
+
 def _attention_op(cfg: TransformerConfig, lp: Params,
                   layer_idx: jnp.ndarray, ln1: jnp.ndarray,
                   seg_ids: jnp.ndarray, cos: jnp.ndarray,
                   sin: jnp.ndarray, attention_fn=None,
-                  window: Optional[int] = None, op: str = "attention"):
+                  window: Optional[int] = None, op: str = "attention",
+                  index_rotary=None):
     """Attention over packed streams on the normed residual ``ln1``
     [B, L, H] -> (its projected output [B, L, H], (k, v)). ``window``:
     the tokens THIS layer sees (``cfg.layer_window``), None for all;
     ``op``: the layer's operator (a latent layer's v, and the heads'
-    outputs, are ``v_head_dim`` wide)."""
+    outputs, are ``v_head_dim`` wide). A "sparse" layer runs its
+    indexer first (``index_rotary``: the indexer's table), hands the
+    selection to the attention function as ``select=`` and returns
+    (k, v, the indexer's keys)."""
+    more, states = {}, ()
+    if op == "sparse":
+        select, index_k = _index_select(cfg, lp["index"], ln1, seg_ids,
+                                        *index_rotary)
+        more, states = dict(select=select), (index_k,)
     with jax.named_scope(P.ATTN_PROJ):
         q, k, v = _rotated_qkv(cfg, lp, ln1, cos, sin, op)
         q = checkpoint_name(q, PROJECTION_RESIDUALS[0])
@@ -638,7 +714,7 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
     with jax.named_scope(P.ATTN):
         attn = attn_impl(q, k, v, seg_ids, causal=True,
                          scale=_attn_scale(cfg, layer_idx),
-                         sliding_window=window)
+                         sliding_window=window, **more)
     with jax.named_scope(P.ATTN_PROJ):
         attn = _head_gate(lp, ln1, attn)
         attn = attn.reshape(*ln1.shape[:-1], -1)
@@ -646,19 +722,21 @@ def _attention_op(cfg: TransformerConfig, lp: Params,
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(ln1.dtype)
         proj = checkpoint_name(proj, PROJECTION_RESIDUALS[1])
-    return proj, (k, v)
+    return proj, (k, v) + states
 
 
 def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
            x: jnp.ndarray, seg_ids: jnp.ndarray, cos: jnp.ndarray,
            sin: jnp.ndarray, constrain, attention_fn=None,
-           moe_constraint=None, kind=None, window=None, mesh=None):
+           moe_constraint=None, kind=None, window=None, mesh=None,
+           index_rotary=None):
     """One block over packed streams [B, L, H]; returns (residual
     output, state, aux-losses). ``kind``: the layer's (operator,
     feed-forward) in a patterned model, None for the one block of
     ``mlp_type``; ``window``: its attention's window, None for the
     whole document. The state feeds prefill's caches: (k, v) of an
-    attention layer, the convolution's input s [B, L, H] of a conv
+    attention layer (and the indexer's keys of a sparse one, whose
+    table is ``index_rotary``), the convolution's input s [B, L, H] of a conv
     layer, (the convolutions' inputs, the rows' last states) of a
     delta layer; aux is non-empty for MoE. ``mesh``: ``forward``'s."""
     op, sparse = ("attention", None) if kind is None \
@@ -677,7 +755,8 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
             proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids, mesh)
     else:
         proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
-                                    cos, sin, attention_fn, window, op)
+                                    cos, sin, attention_fn, window, op,
+                                    index_rotary)
     with jax.named_scope(mixer):
         x = constrain(x + proj)
     ff = _ff_part(cfg, sparse)
@@ -749,7 +828,14 @@ def _rotary_tables(cfg: TransformerConfig, positions: jnp.ndarray):
         half = cfg.head_dim // 2
         table = (jnp.ones((*positions.shape, half), jnp.float32),
                  jnp.zeros((*positions.shape, half), jnp.float32))
-    return {"attention": table, "window": table}
+    tables = {"attention": table, "window": table}
+    if cfg.indexer is not None:
+        # the layer's embedding again over the indexer's narrower head
+        rc = cfg.rotary_of("sparse")
+        tables.update(sparse=table, index=rotary_freqs(
+            positions, cfg.indexer.head_dim, rc.base, rc.factor,
+            rc.scaling_type, rc.original_max_positions))
+    return tables
 
 
 def positions_from_segments(seg_ids: jnp.ndarray) -> jnp.ndarray:
@@ -936,17 +1022,22 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
     layers are latent), the convolutions' inputs
     stacked over the CONV layers [n_conv, B, L, H], and of the DELTA
     layers their convolutions' inputs [n_delta, B, L, 3 x width] and
-    the rows' last states [n_delta, B, n, hd, hd]; None unless
+    the rows' last states [n_delta, B, n, hd, hd], of the SPARSE
+    layers their indexer's keys [n_sparse, B, L, d]; None unless
     ``return_kv``. ``aux``: the sparse layers' entries reduced as
     ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
-    ks, vs, convs, tails, deltas, auxs = [], [], [], [], [], []
+    ks, vs, convs, tails, deltas, index_ks, auxs = [], [], [], [], [], [], []
+    # (the indexer's table only where there is one: every other model's
+    # blocks are called as they were)
+    more = {} if cfg.indexer is None else dict(
+        index_rotary=rotary["index"])
     for i, kind in enumerate(cfg.layer_pattern):
         cos, sin = rotary.get(kind[0], (None, None))
 
         def block_fn(lp, carry, i=i, kind=kind, cos=cos, sin=sin):
             return _block(cfg, lp, jnp.int32(i), carry, seg_ids, cos,
                           sin, constrain, attention_fn, moe_constraint,
-                          kind, cfg.layer_window(i), mesh)
+                          kind, cfg.layer_window(i), mesh, **more)
 
         x, state, aux = _remat(cfg, block_fn)(layers[str(i)], x)
         if return_kv and kind[0] == "conv":
@@ -956,6 +1047,8 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                 else (ks, vs)
             first.append(state[0])
             second.append(state[1])
+            if kind[0] == "sparse":
+                index_ks.append(state[2])
         if aux:
             auxs.append(aux)
     states = None
@@ -964,7 +1057,8 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                   for name, rows in (("k", ks), ("v", vs),
                                      ("conv", convs),
                                      ("delta_conv", tails),
-                                     ("delta", deltas))}
+                                     ("delta", deltas),
+                                     ("index_k", index_ks))}
     aux = {}
     if return_aux and auxs:
         from realhf_tpu.ops.moe import reduce_layers
@@ -1049,7 +1143,17 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
         tail, state = delta_state_shapes(cfg, batch)
         cache["delta_conv"] = jnp.zeros(tail, dtype)
         cache["delta"] = jnp.zeros(state, jnp.float32)
+    if cfg.sparse_layers:
+        cache["index_k"] = jnp.zeros(
+            index_cache_shape(cfg, batch, max_len), dtype)
     return cache
+
+
+def index_cache_shape(cfg: TransformerConfig, batch: int, slots: int):
+    """The sparse layers' third attention cache: for each sparse layer,
+    stream and cache slot the indexer's ONE key (``indexer.head_dim``
+    values beside the ``2 x n_kv_heads x head_dim`` of K and V)."""
+    return (len(cfg.sparse_layers), batch, slots, cfg.indexer.head_dim)
 
 
 def conv_state_shape(cfg: TransformerConfig, batch: int):
@@ -1121,6 +1225,11 @@ def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
             more["delta_conv"] = tails(
                 kvs["delta_conv"], cfg.delta.conv_kernel).astype(dtype)
             more["delta"] = kvs["delta"]
+        if kvs["index_k"] is not None:  # [n_sparse, B, L, d], by slot
+            pad = round_cache_len(
+                total_len if total_len is not None else lp) - lp
+            more["index_k"] = jnp.pad(
+                kvs["index_k"], [(0, 0), (0, 0), (0, pad), (0, 0)])
     k = k.transpose(0, 1, 3, 2, 4)  # -> [nl, B, nkv, L, hd] head-major
     v = v.transpose(0, 1, 3, 2, 4)
     valid = seg_ids != 0
@@ -1150,8 +1259,13 @@ def extend_kv_cache(cache: KVCache, extra: int) -> KVCache:
     extra = new_s - s
     pad = lambda a: jnp.concatenate(
         [a, jnp.zeros(a.shape[:3] + (extra, a.shape[4]), a.dtype)], axis=3)
+    more = {}
+    if "index_k" in cache:
+        more["index_k"] = jnp.pad(
+            cache["index_k"], [(0, 0), (0, 0), (0, extra), (0, 0)])
     return {
         **cache,  # length, and a patterned model's other states
+        **more,
         "k": pad(cache["k"]),
         "v": pad(cache["v"]),
         "valid": jnp.concatenate(
@@ -1248,14 +1362,38 @@ def decode_step(
             valid = cache["valid"].at[jnp.arange(b), slot].set(True)
         new_len = slot + 1
 
+    def pick(lp, ln1, index_all, at):
+        # a sparse layer's indexer on the token: its key into slot
+        # `slot` of the layer's rows of the third cache, the token's
+        # scores of every row, and the `topk` best of the valid ones
+        with jax.named_scope(P.INDEX):
+            qi, ki, w = _index_inputs(cfg, lp["index"], ln1,
+                                      *rotary["index"])
+            if uniform_slot:
+                index_all = jax.lax.dynamic_update_slice(
+                    index_all, ki[None, :, None].astype(index_all.dtype),
+                    (at, 0, s0, 0))
+            else:
+                index_all = index_all.at[at, jnp.arange(b), slot].set(
+                    ki.astype(index_all.dtype))
+            with jax.named_scope(P.SCORES):
+                scores = index_scores(qi[:, None], index_all[at],
+                                      w[:, None])[:, 0]
+            with jax.named_scope(P.SELECT):
+                return select_topk(scores, valid,
+                                   cfg.indexer.topk), index_all
+
     def layer_body(x, k_all, v_all, lp, l, sparse=None, op="attention",
-                   window=cfg.sliding_window):
+                   window=cfg.sliding_window, picked=None):
         # l: the layer's place in the K/V stack, a Python int
         # (unrolled) or a traced scalar; op, window: its kind's rotary
-        # table and what it sees (a patterned model says them a layer)
+        # table and what it sees (a patterned model says them a layer);
+        # picked: ln1 -> the cache slots a sparse layer's token attends
         cos, sin = rotary.get(op, (None, None))  # a latent without one
         with jax.named_scope(P.ATTN_PROJ):
             ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+        keep = None if picked is None else picked(ln1)
+        with jax.named_scope(P.ATTN_PROJ):
             # q: [B, nq, hd]; k/v: [B, nkv, hd]
             q, k, v = _rotated_qkv(cfg, lp, ln1, cos, sin, op)
         with jax.named_scope(P.ATTN):  # the token's write and the kernel
@@ -1279,9 +1417,16 @@ def decode_step(
                 scale = base / (l + 1)
             else:
                 scale = _attn_scale(cfg, l)  # traced scalar
-            attn = _stacked_decode_attention(
-                q, k_all, v_all, valid, l, scale=scale,
-                sliding_window=window, slot=slot, mesh=mesh)
+            if keep is not None:
+                # over the selection, by the XLA path (as a latent
+                # layer decodes): the stacked kernel masks by validity
+                # and window alone
+                attn = decode_attention(q, k_all[l], v_all[l], keep,
+                                        scale=scale, slot=slot)
+            else:
+                attn = _stacked_decode_attention(
+                    q, k_all, v_all, valid, l, scale=scale,
+                    sliding_window=window, slot=slot, mesh=mesh)
         with jax.named_scope(P.ATTN_PROJ):
             attn = _head_gate(lp, ln1, attn)
             proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
@@ -1297,6 +1442,7 @@ def decode_step(
             return x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
 
     k_all, v_all = cache["k"], cache["v"]
+    index_all = cache.get("index_k")
     new_conv, new_tails, new_deltas = [], [], []
     if cfg.layer_pattern is not None:
         # a layer of the pattern at a time: an attention layer reads
@@ -1318,6 +1464,16 @@ def decode_step(
                     new_deltas.append(state)
                     x = x + proj
                 x = _ff_step(x, lp, ff == "moe")
+                continue
+            if op == "sparse":
+                def picked(ln1, lp=lp, at=cfg.sparse_layers.index(i)):
+                    nonlocal index_all
+                    keep, index_all = pick(lp, ln1, index_all, at)
+                    return keep
+
+                x, k_all, v_all = layer_body(
+                    x, k_all, v_all, lp, cfg.attention_layers.index(i),
+                    ff == "moe", op, None, picked)
                 continue
             if op != "conv":
                 x, k_all, v_all = layer_body(
@@ -1354,4 +1510,6 @@ def decode_step(
         with jax.named_scope(P.DELTA):
             new_cache["delta_conv"] = jnp.stack(new_tails)
             new_cache["delta"] = jnp.stack(new_deltas)
+    if index_all is not None:
+        new_cache["index_k"] = index_all
     return x, new_cache

@@ -39,6 +39,9 @@ CONV = "conv"
 #: before it, its projections, short convolutions and gates, the
 #: output's norm and gate, ``wo``; the recurrence is its sub-scope
 DELTA = "delta"
+#: a sparse layer's indexer (models/config.py:IndexerConfig): what
+#: picks the keys its attention runs over; three sub-scopes
+INDEX = "index"
 MLP = "mlp"
 SHARED_EXPERT = "shared_expert"
 EXPERTS = "experts"
@@ -51,8 +54,8 @@ OPTIMIZER = "optimizer"
 LAYERS = "layers"
 #: what a device operation can be put down to: the INNERMOST of these
 #: in its ``op_name`` is its part
-PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, MLP, SHARED_EXPERT,
-         EXPERTS, VOCAB_HEAD, LOSS, GRAD_ACCUM, OPTIMIZER)
+PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, INDEX, MLP,
+         SHARED_EXPERT, EXPERTS, VOCAB_HEAD, LOSS, GRAD_ACCUM, OPTIMIZER)
 
 FORWARD_BACKWARD = "forward_backward"
 PREFILL, DECODE, SAMPLE = "prefill", "decode", "sample"
@@ -72,8 +75,15 @@ LATENT = "latent"
 #: sub-scope of ``delta``: the chunked recurrence alone
 #: (``ops/delta_rule.py``); the part then reads ``delta/scan``
 SCAN = "scan"
+PROJECT, SCORES, SELECT = "project", "scores", "select"
+#: sub-scopes of ``index``: the indexer's projections, norm and rotary
+#: (``index/project``), its scores of every (query, key) pair
+#: (``index/scores``) and the choice of the ``topk`` best a query
+#: (``index/select``; ``ops/sparse_index.py``)
+INDEX_STEPS = (PROJECT, SCORES, SELECT)
 #: part -> the sub-scopes that may stand inside it
-SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,)}
+SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,),
+             INDEX: INDEX_STEPS}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: the pass of an operation whose ``op_name`` the compiler wrote

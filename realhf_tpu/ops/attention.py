@@ -57,11 +57,14 @@ def packed_attention_xla(
     scale: Optional[float] = None,
     logits_soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
+    select: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Reference XLA implementation; O(L^2) scores in fp32.
 
     GQA is expressed by grouping query heads over each KV head so the
-    einsum keeps a single contraction (MXU-friendly).
+    einsum keeps a single contraction (MXU-friendly). ``select``
+    [B, L, L] (0 = not attended): a learned selection of keys a query
+    that every head shares, one more term of the mask.
     """
     b, l, nq, hd = q.shape
     nkv = k.shape[2]
@@ -76,6 +79,8 @@ def packed_attention_xla(
         scores = logits_soft_cap * jnp.tanh(scores / logits_soft_cap)
     mask = _segment_mask(seg_ids, seg_ids, causal,
                          sliding_window)[:, None, None]
+    if select is not None:
+        mask = mask & (select != 0)[:, None, None]
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
@@ -96,11 +101,12 @@ def flash_takes(row_len: int, key_dim: int, *, scale=None,
 
 def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
                      logits_soft_cap=None, sliding_window=None,
-                     use_flash: Optional[bool] = None):
+                     use_flash: Optional[bool] = None, select=None):
     """Dispatch between the Pallas flash kernel (TPU) and the XLA path.
 
     ``use_flash=None`` auto-selects: flash on TPU backends when shapes
     meet the kernel's tiling constraints, XLA otherwise (CPU tests).
+    ``select``: a sparse layer's selection [B, L, L], to either path.
     """
     if use_flash is None:
         use_flash = pallas_enabled() and flash_takes(
@@ -114,10 +120,12 @@ def packed_attention(q, k, v, seg_ids, *, causal=True, scale=None,
         return flash_attention(q, k, v, seg_ids, causal=causal,
                                scale=scale,
                                logits_soft_cap=logits_soft_cap,
-                               sliding_window=sliding_window)
+                               sliding_window=sliding_window,
+                               select=select)
     return packed_attention_xla(q, k, v, seg_ids, causal=causal, scale=scale,
                                 logits_soft_cap=logits_soft_cap,
-                                sliding_window=sliding_window)
+                                sliding_window=sliding_window,
+                                select=select)
 
 
 def make_sharded_attention(mesh, inner=None):
@@ -132,7 +140,9 @@ def make_sharded_attention(mesh, inner=None):
     Falls back to the XLA path (which GSPMD partitions natively) when
     shapes do not divide the mesh or the scale is traced. ``inner``
     overrides the per-shard implementation (tests inject the
-    interpret-mode kernel)."""
+    interpret-mode kernel). A sparse layer's selection [B, L, L] goes
+    with the rows over "data" and whole to every shard of "model":
+    the heads of a token share it."""
     from functools import partial as _partial
 
     from jax.sharding import PartitionSpec as P
@@ -144,18 +154,19 @@ def make_sharded_attention(mesh, inner=None):
     local = inner or packed_attention
 
     def attn(q, k, v, seg_ids, causal=True, scale=None,
-             sliding_window=None):
+             sliding_window=None, select=None):
         b, _, nq, _ = q.shape
         nkv = k.shape[2]
+        more = {} if select is None else dict(select=select)
         if dp * tp == 1:
             return local(q, k, v, seg_ids, causal=causal, scale=scale,
-                         sliding_window=sliding_window)
+                         sliding_window=sliding_window, **more)
         if (b % dp or nq % tp or nkv % tp
                 or not (scale is None
                         or isinstance(scale, (int, float)))):
             return packed_attention_xla(
                 q, k, v, seg_ids, causal=causal, scale=scale,
-                sliding_window=sliding_window)
+                sliding_window=sliding_window, **more)
 
         extra = [a for a in mesh.axis_names
                  if a not in (DATA_AXIS, MODEL_AXIS)]
@@ -168,15 +179,17 @@ def make_sharded_attention(mesh, inner=None):
                   in_specs=(P(DATA_AXIS, None, MODEL_AXIS, None),
                             P(DATA_AXIS, None, MODEL_AXIS, None),
                             P(DATA_AXIS, None, MODEL_AXIS, None),
-                            P(DATA_AXIS, None)),
+                            P(DATA_AXIS, None))
+                  + (P(DATA_AXIS, None, None),) * len(more),
                   out_specs=P(DATA_AXIS, None, MODEL_AXIS, None),
                   # pallas_call outputs carry no varying-axes metadata
                   check_vma=False)
-        def run(q_l, k_l, v_l, seg_l):
+        def run(q_l, k_l, v_l, seg_l, *sel_l):
             return local(q_l, k_l, v_l, seg_l, causal=causal,
-                         scale=scale, sliding_window=sliding_window)
+                         scale=scale, sliding_window=sliding_window,
+                         **dict(zip(more, sel_l)))
 
-        return run(q, k, v, seg_ids)
+        return run(q, k, v, seg_ids, *more.values())
 
     return attn
 

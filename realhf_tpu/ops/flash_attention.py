@@ -62,6 +62,21 @@ VMEM: K and V are still whole a head, so ``FLASH_MAX_LEN`` stands; it
 takes blocks off the loops (a row of 4096 at W = 512 visits 30 of its
 72 causal block pairs).
 
+A learned selection (``select=`` int8 ``[B, L, L]``, 0 = not attended:
+a sparse layer's, ``ops/sparse_index.py``) is ONE MORE blocked operand
+of the three kernels beside the segment views: the rows of the step's
+query block and every column in the forward and the dq pass (256 x L
+bytes), every row and the columns of the step's key block in the dkv
+pass (L x 512), widened to int32 in the kernel and ``and``ed into the
+mask a block pair at a time. Every head of a query shares it. No pair
+it leaves out can be computed inside the kernel from row metadata, as
+segments, causality and the window are; the block ranges stay theirs
+(a visited block with no selected pair is multiplied and masked away:
+skipping it is ROADMAP R4c (b)). A kernel that takes one is named
+``flash_fwd_sel`` / ``flash_bwd_dq_sel`` / ``flash_bwd_dkv_sel`` and
+has a ``custom_vjp`` of its own; a call without one is, to the byte of
+its jaxpr, the call it was.
+
 What is still whole in VMEM. K and V (forward, dq) and Q, dO, lse,
 delta (dkv) are kept whole per (batch, head) whatever the ranges say,
 which bounds L: ``FLASH_MAX_LEN`` below is what the v5e compiler
@@ -230,9 +245,10 @@ def _block_range(lo_ref, hi_ref):
 
 def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
                 q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
-                o_ref, lse_ref,  # outputs
-                *, scale: float, bk: int, causal: bool,
+                *rest,  # [the selection,] then the outputs: o, lse
+                scale: float, bk: int, causal: bool,
                 window: Optional[int] = None):
+    *sel_ref, o_ref, lse_ref = rest
     qi = pl.program_id(2)
     bq, hv = q_ref.shape[-2], v_ref.shape[-1]
 
@@ -259,6 +275,9 @@ def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
             mask &= q_idx >= k_idx
         if window is not None:
             mask &= q_idx - k_idx < window
+        if sel_ref:  # [BQ, BK] of the selection's rows of this block
+            mask &= sel_ref[0][0, :, pl.ds(j * bk, bk)].astype(
+                jnp.int32) != 0
         s = jnp.where(mask, s, NEG_INF)
 
         m_new = jnp.maximum(m, s.max(axis=1))
@@ -349,10 +368,13 @@ def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
     )(*(x.reshape(-1) for x in bounds), *args)
 
 
-def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
+def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None,
+               select=None):
     """The forward kernel's two outputs as it writes them: the output
     head-major ``[B, nq, L, hv]`` (the value's width) and the
-    lane-broadcast log-sum-exp ``[B, nq, L, LANES]``."""
+    lane-broadcast log-sum-exp ``[B, nq, L, LANES]``. ``select``: the
+    selection ``[B, L, L]`` int8, one more blocked operand (the rows of
+    the step's query block, every column)."""
     b, l, nq, hd = q.shape
     nkv, hv = k.shape[2], v.shape[3]
     group = nq // nkv
@@ -367,23 +389,41 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
 
     at = _index_maps(group)
 
+    specs, operands, suffix = _selected(
+        select, pl.BlockSpec((1, bq, l), at["seg_row"]))
     out, lse = _ranged_call(
         functools.partial(_fwd_kernel, scale=scale, bk=bk, causal=causal,
                           window=window),
-        "flash_fwd", (b, nq, l // bq), kv_range,
+        "flash_fwd" + suffix, (b, nq, l // bq), kv_range,
         [
             pl.BlockSpec((1, 1, bq, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
             pl.BlockSpec((1, 1, l, hv), at["kv_whole"]),
             pl.BlockSpec((1, bq, LANES), at["seg_row"]),
             pl.BlockSpec((1, SUBLANES, l), at["seg_whole"]),
-        ],
+        ] + specs,
         (pl.BlockSpec((1, 1, bq, hv), at["row"]),
          pl.BlockSpec((1, 1, bq, LANES), at["row"])),
         (jax.ShapeDtypeStruct((b, nq, l, hv), q.dtype),
          jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
-        qt, kt, vt, segq, segk)
+        qt, kt, vt, segq, segk, *operands)
     return out, lse
+
+
+#: what the name of a kernel that takes a selection ends in
+#: (``flash_fwd_sel``, ``flash_bwd_dq_sel``, ``flash_bwd_dkv_sel``):
+#: :func:`flash_mask_calls` counts them in a compiled program's text
+SELECTED_SUFFIX = "_sel"
+
+
+def _selected(select, spec):
+    """``(specs, args, suffix)``: what a call adds for a selection: its
+    block's spec and the operand after the kernel's other inputs, the
+    suffix of the kernel's name; nothing where there is none, so that
+    such a call is the call it was."""
+    if select is None:
+        return [], [], ""
+    return [spec], [select], SELECTED_SUFFIX
 
 
 # ----------------------------------------------------------------------
@@ -391,9 +431,10 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None):
 # ----------------------------------------------------------------------
 def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
                    q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref,
-                   *, scale: float, bk: int, causal: bool,
+                   lse_ref, delta_ref, *rest,  # [the selection,] dq
+                   scale: float, bk: int, causal: bool,
                    window: Optional[int] = None):
+    *sel_ref, dq_ref = rest
     qi = pl.program_id(2)
     bq, hd = q_ref.shape[-2], q_ref.shape[-1]
 
@@ -416,6 +457,9 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
             mask &= q_idx >= k_idx
         if window is not None:
             mask &= q_idx - k_idx < window
+        if sel_ref:
+            mask &= sel_ref[0][0, :, pl.ds(j * bk, bk)].astype(
+                jnp.int32) != 0
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -431,9 +475,10 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
 
 def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
                     q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref,
-                    *, scale: float, bq: int, causal: bool,
+                    lse_ref, delta_ref, *rest,  # [the selection,] dk, dv
+                    scale: float, bq: int, causal: bool,
                     window: Optional[int] = None):
+    *sel_ref, dk_ref, dv_ref = rest
     ki = pl.program_id(2)
     bk, hd, hv = k_ref.shape[-2], k_ref.shape[-1], v_ref.shape[-1]
 
@@ -457,6 +502,9 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
             mask &= q_idx >= k_idx
         if window is not None:
             mask &= q_idx - k_idx < window
+        if sel_ref:  # the selection's columns of this block, all rows
+            mask &= sel_ref[0][0, pl.ds(j * bq, bq), :].astype(
+                jnp.int32) != 0
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
         dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
@@ -476,7 +524,7 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
+def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
     q, k, v, seg_ids, ot, lse = res
     do = g
     b, l, nq, hd = q.shape
@@ -502,10 +550,14 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
 
     at = _index_maps(group)
 
+    row_specs, row_operands, suffix = _selected(
+        select, pl.BlockSpec((1, bq_, l), at["seg_row"]))
+    col_specs, col_operands, _ = _selected(select, pl.BlockSpec(
+        (1, l, bk_), lambda bi, h, i, *_: (bi, 0, i)))
     dq = _ranged_call(
         functools.partial(_bwd_dq_kernel, scale=scale, bk=bk_,
                           causal=causal, window=window),
-        "flash_bwd_dq", (b, nq, l // bq_), kv_range,
+        "flash_bwd_dq" + suffix, (b, nq, l // bq_), kv_range,
         [
             pl.BlockSpec((1, 1, bq_, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
@@ -515,15 +567,15 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
             pl.BlockSpec((1, 1, bq_, hv), at["row"]),
             pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
             pl.BlockSpec((1, 1, bq_, LANES), at["row"]),
-        ],
+        ] + row_specs,
         pl.BlockSpec((1, 1, bq_, hd), at["row"]),
         jax.ShapeDtypeStruct(qt.shape, jnp.float32),
-        qt, kt, vt, segq, segk, dot, lse, delta)
+        qt, kt, vt, segq, segk, dot, lse, delta, *row_operands)
 
     dk_partial, dv_partial = _ranged_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq_,
                           causal=causal, window=window),
-        "flash_bwd_dkv", (b, nq, l // bk_), q_range,
+        "flash_bwd_dkv" + suffix, (b, nq, l // bk_), q_range,
         [
             pl.BlockSpec((1, 1, l, hd), at["whole"]),
             pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),
@@ -533,12 +585,12 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None):
             pl.BlockSpec((1, 1, l, hv), at["whole"]),
             pl.BlockSpec((1, 1, l, LANES), at["whole"]),
             pl.BlockSpec((1, 1, l, LANES), at["whole"]),
-        ],
+        ] + col_specs,
         (pl.BlockSpec((1, 1, bk_, hd), at["row"]),
          pl.BlockSpec((1, 1, bk_, hv), at["row"])),
         (jax.ShapeDtypeStruct((b, nq, l, hd), jnp.float32),
          jax.ShapeDtypeStruct((b, nq, l, hv), jnp.float32)),
-        qt, kt, vt, segq, segk, dot, lse, delta)
+        qt, kt, vt, segq, segk, dot, lse, delta, *col_operands)
 
     # Sum q-head partials within each KV group.
     dk = dk_partial.reshape(b, nkv, group, l, hd).sum(2).transpose(0, 2, 1, 3)
@@ -563,6 +615,19 @@ def flash_fwd_per_bwd(hlo_text: str) -> Optional[float]:
     if not bwd:
         return None
     return sum("flash_fwd" in name for name in calls) / bwd
+
+
+def flash_mask_calls(hlo_text: str) -> int:
+    """The flash custom calls of a compiled program that take a
+    SELECTION (a sparse layer's: ``SELECTED_SUFFIX`` in the kernel's
+    name): three a sparse layer of a train program whose rematerialised
+    blocks keep the forward's residuals, one of a forward-only program,
+    0 on the XLA path. The same pure function of the text as
+    :func:`flash_fwd_per_bwd`."""
+    return sum(
+        "flash_" in name and SELECTED_SUFFIX in name
+        for name, _, opcode in device_instructions(hlo_text)
+        if opcode == "custom-call")
 
 
 # ----------------------------------------------------------------------
@@ -590,15 +655,53 @@ _flash_attention.defvjp(
         res, g, scale, causal, bq, bk, window))
 
 
+#: the selection a sparse layer's forward hands its backward, by the
+#: name ``models/transformer.py`` gives it: int8 ``[B, L, L]``. Kept by
+#: a rematerialised block (``_remat``), the indexer does not run again.
+SELECT_RESIDUAL = "flash_select"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_attention_selected(q, k, v, seg_ids, select, scale, causal, bq,
+                              bk, window):
+    out, _ = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window,
+                        select)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _flash_attention_selected_fwd(q, k, v, seg_ids, select, scale, causal,
+                                  bq, bk, window):
+    out, lse = _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window,
+                          select)
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return out.transpose(0, 2, 1, 3), (q, k, v, seg_ids, select, out, lse)
+
+
+def _flash_attention_selected_bwd(scale, causal, bq, bk, window, res, g):
+    q, k, v, seg_ids, select, out, lse = res
+    return _flash_bwd((q, k, v, seg_ids, out, lse), g, scale, causal, bq,
+                      bk, window, select) + (None,)
+
+
+_flash_attention_selected.defvjp(_flash_attention_selected_fwd,
+                                 _flash_attention_selected_bwd)
+
+
 def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
                     scale: Optional[float] = None,
                     logits_soft_cap: Optional[float] = None,
                     sliding_window: Optional[int] = None,
+                    select: Optional[jnp.ndarray] = None,
                     block_q: int = DEFAULT_BQ,
                     block_k: int = DEFAULT_BK) -> jnp.ndarray:
     """Packed-segment flash attention; drop-in for
     `ops.attention.packed_attention_xla` on TPU. ``sliding_window=W``
-    (causal only): a query sees the last W tokens of its document."""
+    (causal only): a query sees the last W tokens of its document.
+    ``select`` [B, L, L] (int8, 0 = not attended): a learned selection
+    of keys a query, one more operand of the three kernels' masks;
+    every head of a query shares it. The block ranges stay those of
+    segments, causality and window."""
     if logits_soft_cap is not None:
         raise NotImplementedError(
             "soft cap not yet supported by the flash kernel; use the XLA "
@@ -617,6 +720,10 @@ def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
             f"sliding_window={sliding_window} needs causal attention "
             "and at least one token")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
+    if select is not None:
+        return _flash_attention_selected(
+            q, k, v, seg_ids.astype(jnp.int32), select.astype(jnp.int8),
+            float(scale), causal, block_q, block_k, sliding_window)
     return _flash_attention(q, k, v, seg_ids.astype(jnp.int32),
                             float(scale), causal, block_q, block_k,
                             sliding_window)

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -78,6 +78,19 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   by the XLA products) and the recurrence token by token in float32 on
   the device, each against the recurrence in float64 on the host.
   ``--only <phases>`` runs these rows alone.
+- ``selection`` (``keye_vl2``): the fixed batch's 256 tokens never
+  reach the indexer's ``topk`` of 2,048, so everything about the
+  selection is read on ONE document of 4,096 tokens: the bf16 engine
+  through the compiled kernels against the reference; the reference
+  with the INDEXER's terms rounded to bfloat16 (what the selection's
+  precision alone costs: keys near the 2,048th score fall in or out)
+  and how many selected pairs that moves in the first layer; the
+  (query block, key block) pairs the forward kernel visits that hold
+  no selected pair, counted on the host by the kernels' own rule; then
+  ``exact`` (the float32 engine through the compiled kernels, every
+  wrong equation there), a packed row whose first document passes
+  ``topk``, and a prefill of 2,176 then 127 decode steps through the
+  three attention caches.
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
   their attributes.
@@ -119,6 +132,11 @@ FAMILIES = {
         tiny=("kimi_linear", "tiny-kimi-linear.sft"), wrong_keys={},
         packed_docs=(700, 600, 500, 248), decode=(2, 768, 640),
         exact_doc=2048, published_decay=True),
+    "keye_vl2": dict(
+        cell="keye-vl-2.0-30b-a3b-l5-ep8.sft-4k",
+        tiny=("keye_vl2", "tiny-keye-vl2.sft"), wrong_keys={},
+        packed_docs=(2560, 1024, 512), decode=(2, 2304, 2176),
+        exact_doc=4096, selection=True),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -419,6 +437,43 @@ def exact(cell, ckpt, tensors, doc):
         wrong={wrong: share(family.logprobs(hf, tensors, doc,
                                             wrong=(wrong,)), want)
                for wrong in family.WRONG},
+        secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
+
+
+def selection_live(cell, engine, tensors, doc):
+    """``keye_vl2``: what the fixed batch cannot see, on ONE document
+    long enough to pass the indexer's ``topk`` (see the module's
+    docstring, ``selection``)."""
+    import numpy as np
+
+    from realhf_tpu.ops import sparse_index
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    doc = doc[None].astype(np.int32)
+    want = family.logprobs(hf, tensors, doc)
+    got = np.asarray(engine.forward_logprobs(doc, np.ones_like(doc)),
+                     np.float32)[:, :-1]
+    rounded = family.logprobs(hf, tensors, doc,
+                              wrong=(family.INDEX_ROUNDED,))
+    picked = family.selection(hf, tensors, doc, 0)
+    moved = family.selection(hf, tensors, doc, 0,
+                             wrong=(family.INDEX_ROUNDED,))
+    empty, visited = sparse_index.unselected_blocks(
+        picked, np.ones_like(doc))
+    topk = family.dims(hf)["topk"]
+    say(phase="selection", document=doc.shape[1], topk=topk,
+        engine_bf16=share(got, want),
+        past_topk=dict(engine_bf16=share(got[:, topk:], want[:, topk:]),
+                       index_rounded=share(rounded[:, topk:],
+                                           want[:, topk:])),
+        index_rounded_to_bf16=share(rounded, want),
+        first_layer=dict(selected_pairs=int(picked.sum()),
+                         pairs_moved_by_rounding=int(
+                             (picked != moved).sum() // 2),
+                         blocks_visited=visited,
+                         blocks_without_a_selected_pair=empty),
+        flash_mask_calls=engine.program_facts("logprobs").attributes.get(
+            "flash_mask_calls"),
         secs=round(time.monotonic() - t, 1), tolerance=family.TOLERANCE)
 
 
@@ -926,6 +981,10 @@ def main():
     p.add_argument("--no-table", action="store_true",
                    help="the engine's reading alone, no lower precision "
                         "and no wrong equation")
+    p.add_argument("--table-only", action="store_true",
+                   help="the fixed batch's table for every seed and "
+                        "nothing else: no packed row, no decode, no "
+                        "long document")
     p.add_argument("--only", nargs="+", default=None,
                    help="kimi_linear: after the first seed's engine "
                         "reading, these of PUBLISHED_PHASES alone")
@@ -972,7 +1031,7 @@ def main():
                 lens = spec["packed_docs"]
                 if args.rehearse and lens:  # toy rows: an eighth
                     lens = tuple(n // 8 for n in lens)
-            if i == 0 and not args.only:
+            if i == 0 and not args.only and not args.table_only:
                 packed(cell, engine, tensors, list(ids) if lens is None
                        else [rng.integers(0, vocab, n) for n in lens], ckpt)
                 if spec["decode"] is None:
@@ -991,8 +1050,11 @@ def main():
                     uncovered_rows(cell, engine, seed)
                 if spec.get("exact_doc"):
                     n = spec["exact_doc"] // (8 if args.rehearse else 1)
+                    long = rng.integers(0, vocab, n)
+                    if spec.get("selection"):
+                        selection_live(cell, engine, tensors, long)
                     engine = None  # the bf16 weights go before float32's come
-                    exact(cell, ckpt, tensors, rng.integers(0, vocab, n))
+                    exact(cell, ckpt, tensors, long)
             if i == 0 and spec.get("published_decay"):
                 engine = None
                 under_published_decay(

@@ -40,6 +40,9 @@ FAMILIES = {
     "kimi_linear": (
         "tests/benchmark/kimi_linear/configs/tiny-kimi-linear.json",
         {"delta", "mlp", "experts", "shared_expert"}),
+    "keye_vl2": (
+        "tests/benchmark/keye_vl2/configs/tiny-keye-vl2.json",
+        {"index", "experts"}),
 }
 #: how the op_name of a loop's own operations ends
 PLUMBING = {"add", "lt", "closed_call", "dynamic_slice",
@@ -154,6 +157,15 @@ def test_train_program_names_every_part_the_family_has(family):
         assert facts.attributes["delta_scan_kernel_calls"] == 0
     else:
         assert "delta_scan_kernel_calls" not in facts.attributes
+    # whether the flash kernels take a sparse layer's selection (here
+    # the XLA path takes it: none does), and the indexer forward only
+    if "index" in have:
+        assert {"index/project", "index/scores", "index/select"} \
+            <= set(passes)
+        assert passes["index/scores"] == passes["index/select"] == {"fwd"}
+        assert facts.attributes["flash_mask_calls"] == 0
+    else:
+        assert "flash_mask_calls" not in facts.attributes
     # what does the work is put down to a part: no product is left
     # out, and the fusions that carry an op_name and no part are the
     # loops' own plumbing (counters, a layer's slice out of the stack,
@@ -180,8 +192,10 @@ def test_generate_program_nests_the_parts_in_its_phases(family):
         if "experts" in FAMILIES[family][1] else set()
     delta = {"delta_scan_kernel_calls"} \
         if "delta" in FAMILIES[family][1] else set()
-    assert set(facts.attributes) == {"decode_kernel",
-                                     "decode_layer_copies"} | sparse | delta
+    masked = {"flash_mask_calls"} \
+        if "index" in FAMILIES[family][1] else set()
+    assert set(facts.attributes) == {
+        "decode_kernel", "decode_layer_copies"} | sparse | delta | masked
     seen = {(op[3], (op[0] or "").split("/")[0])
             for op in facts.ops.values()}
     assert {phase for phase, _ in seen} >= {"prefill", "decode", "sample"}

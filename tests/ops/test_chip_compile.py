@@ -161,9 +161,10 @@ def _model(one_chip, config_name, family):
         lambda a: sds(a.shape, jnp.bfloat16),
         jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
 
-    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None):
+    def attn(q, k, v, seg, causal=True, scale=None, sliding_window=None,
+             **select):
         return flash_attention(q, k, v, seg, causal=causal, scale=scale,
-                               sliding_window=sliding_window)
+                               sliding_window=sliding_window, **select)
 
     return cfg, params, sds, attn
 
@@ -893,3 +894,71 @@ def test_decode_loop_touches_the_stacked_cache_in_place(
     stack = ",".join(map(str, (cfg.n_layers,) + local))
     layouts = set(re.findall(rf"bf16\[{stack}\]{{([\d,]+)", text))
     assert layouts == {"4,3,2,1,0"}
+
+
+def test_flash_compiles_with_a_selection(one_chip):
+    """The three kernels with a sparse layer's selection as one more
+    blocked operand, at Keye-VL-2.0's heads (32 query and 4 key/value
+    heads of 128) and the cell's row of 4096: the int8 ``[1, L, L]``
+    mask goes in by the rows of a query block (forward, dq: 256 x 4096)
+    and by the columns of a key block (dkv: 4096 x 512), is widened to
+    int32 inside and joins the mask of segments and causality. Their
+    names end in ``_sel``: what ``flash_mask_calls`` counts."""
+    from realhf_tpu.ops.flash_attention import flash_mask_calls
+    q, k, v, seg = _qkv(one_chip, 1, FLASH_MAX_LEN, 32, 4, 128)
+    select = jax.ShapeDtypeStruct((1, FLASH_MAX_LEN, FLASH_MAX_LEN),
+                                  jnp.int8, sharding=one_chip)
+
+    def grads(q, k, v, seg, select):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, seg, select=select).astype(
+                jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(grads, q, k, v, seg, select).as_text()
+    assert flash_mask_calls(text) == 3
+    for name in ("flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel"):
+        assert name in text
+    # and without one the kernels are the ones they were
+    assert flash_mask_calls(_compile(_flash_grads, q, k, v,
+                                     seg).as_text()) == 0
+
+
+@pytest.mark.slow
+def test_keyes_whole_train_step_compiles(one_chip):
+    """The ninth cell's WHOLE train step for the described chip: 32
+    microbatches of one row of 4096 through five sparse layers (the
+    indexer's scores a block of 512 queries at a time, the exact top
+    2048 by bisection, the int8 selection handed to the three flash
+    kernels), accumulated in float32, Adam on float32 masters,
+    parameters and optimizer state donated. A rematerialised block
+    keeps the selection beside the forward kernel's residuals: THREE
+    kernels that take it a layer (15), none a second time, and nothing
+    of part ``index`` in the rematerialised pass. The compiler's count
+    of the step's memory is what the cell's size hangs on: 562 M
+    parameters are 11.25 GB at 20 bytes before a row's activations and
+    its five kept selections (16.8 MB each), and a program is held to
+    13.9 GB: 13.79 as committed."""
+    from realhf_tpu.obs import parts
+    from realhf_tpu.ops.flash_attention import (flash_fwd_per_bwd,
+                                                flash_mask_calls)
+
+    step, *args = _sft_train_step(
+        one_chip, "keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 32)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "gmm" in text
+    assert flash_mask_calls(text) == 5 * 3
+    assert flash_fwd_per_bwd(text) == 1.0
+    ops = parts.parse_program(text)
+    index = {(part, pass_) for part, pass_, *_ in ops.values()
+             if (part or "").startswith("index")}
+    # (the loop over blocks of queries and the mask's layout are the
+    # part's own, under no sub-step)
+    assert {part for part, _ in index} - {"index"} == {
+        "index/project", "index/scores", "index/select"}
+    assert {pass_ for _, pass_ in index} == {"fwd"}
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("keye whole step GB", total / 1e9)
+    assert 13.3e9 < total < 13.9e9

@@ -56,6 +56,39 @@ def test_sharded_packed_attention_matches_xla(interpreted_kernels):
                                atol=2e-5, rtol=2e-5)
 
 
+def test_sharded_packed_attention_takes_a_selection(interpreted_kernels):
+    """A sparse layer's selection [B, L, L] goes to each shard with its
+    rows over "data" and WHOLE over "model" (the heads of a token share
+    it), through the kernels that take it: forward and the three
+    gradients as the XLA path's on one device."""
+    rng = np.random.default_rng(1)
+    b, l, nq, nkv, hd = 4, 128, 8, 4, 128
+    q, k, v = (jnp.asarray(rng.standard_normal((b, l, n, hd)), jnp.float32)
+               for n in (nq, nkv, nkv))
+    seg = np.ones((b, l), np.int32)
+    seg[:, l // 2:] = 2
+    seg = jnp.asarray(seg)
+    select = jnp.asarray(rng.random((b, l, l)) < 0.5, jnp.int8) \
+        | jnp.eye(l, dtype=jnp.int8)  # every query keeps itself
+
+    def grads_of(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (fn(q, k, v, seg, select=select) ** 2).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    want = grads_of(functools.partial(packed_attention_xla, causal=True))
+    inner = functools.partial(_interp_packed, interpreted_kernels)
+    with interpreted_kernels():  # the backward is traced late
+        got = grads_of(make_sharded_attention(_mesh(), inner=inner))
+    for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-4, rtol=2e-4)
+    # and it changes the result: the mask is not dropped on the way
+    plain = grads_of(lambda q, k, v, seg, select: packed_attention_xla(
+        q, k, v, seg, causal=True))
+    assert abs(float(plain[0]) - float(want[0])) > 1e-3 * float(want[0])
+
+
 def _interp_packed(interpreted_kernels, q, k, v, seg, causal=True,
                    scale=None, sliding_window=None, **blocks):
     assert sliding_window is None
