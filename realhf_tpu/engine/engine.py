@@ -48,7 +48,7 @@ from realhf_tpu.ops.decode_attention import (
 from realhf_tpu.ops.delta_rule import scan_kernel_calls
 from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
                                             flash_mask_calls)
-from realhf_tpu.ops.sparse_index import pair_counts
+from realhf_tpu.ops.sparse_index import pair_counts, scoring_blocks
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host
@@ -418,22 +418,34 @@ class Engine:
         on the host from the segment ids: the counters, and what of
         them its ``engine:*`` span carries (:meth:`_run`)."""
         self._count_routed_pairs(seg_ids, decode_tokens)
-        self._count_sparse_pairs(seg_ids)
-        return self._count_flash_blocks(seg_ids)
+        return {**self._count_sparse_pairs(seg_ids),
+                **self._count_flash_blocks(seg_ids)}
 
-    def _count_sparse_pairs(self, seg_ids):
+    def _count_sparse_pairs(self, seg_ids) -> Dict[str, float]:
         """``sparse_pairs_total{role,kind}``: the (query, key) pairs
         the sparse layers of the program about to run attend over
         these packed rows (``selected``: ``min(position + 1, topk)`` a
         token) and the pairs under their documents' causal masks
         (``causal``), one head's, times the sparse layers
         (``ops.sparse_index.pair_counts``); ``index_tokens_total
-        {role}``: valid tokens x sparse layers. Nothing for a model
-        without such layers or a batch on the device already."""
+        {role}``: valid tokens x sparse layers;
+        ``index_blocks_total{role,kind}``: the blocks of queries the
+        layers' indexers go over (``all``) and those of them that
+        score and select, the rest being their visibility masks
+        (``scored``), by the rule the program branches on
+        (``ops.sparse_index.scoring_blocks``; the last two axes are
+        what one call of the program sees). Their ratio is the span's
+        ``index_scored_share``. Nothing for a model without such
+        layers or a batch on the device already."""
         cfg = self.cfg
         if not cfg.sparse_layers or not isinstance(seg_ids, np.ndarray):
-            return
+            return {}
         role, n = str(self.ctx.model_name.role), len(cfg.sparse_layers)
+        scoring = scoring_blocks(seg_ids, cfg.indexer.topk, xp=np)
+        metrics.inc("index_blocks_total", n * int(scoring.sum()),
+                    role=role, kind="scored")
+        metrics.inc("index_blocks_total", n * scoring.size, role=role,
+                    kind="all")
         selected, causal = pair_counts(seg_ids, cfg.indexer.topk)
         metrics.inc("sparse_pairs_total", n * selected, role=role,
                     kind="selected")
@@ -441,6 +453,7 @@ class Engine:
                     kind="causal")
         metrics.inc("index_tokens_total",
                     n * int(np.count_nonzero(seg_ids)), role=role)
+        return dict(index_scored_share=float(scoring.mean()))
 
     def _count_flash_blocks(self, seg_ids) -> Dict[str, float]:
         """``flash_kv_blocks_total{role,kind}``: the (query block, key

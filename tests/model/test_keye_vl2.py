@@ -671,6 +671,125 @@ def test_select_topk_is_exact_and_breaks_ties_low():
         assert (got.sum(-1) == np.minimum(visible.sum(-1), topk)).all()
 
 
+def _rows(*rows, length=64):
+    """Packed rows of ``length``: each a tuple of document lengths, a
+    negative one a run of padding; what is left is padding."""
+    seg = np.zeros((len(rows), length), np.int32)
+    for r, docs in enumerate(rows):
+        at = 0
+        for j, n in enumerate(docs):
+            seg[r, at:at + abs(n)] = (j + 1) * (n > 0)
+            at += abs(n)
+        assert at <= length
+    return seg
+
+
+#: name -> (packed rows, the blocks of 16 queries that have to score at
+#: a ``topk`` of 24), each on an edge of :func:`scoring_blocks`' rule
+_EDGE_ROWS = {
+    "a_document_of_exactly_topk": (_rows((24, 24, 16)), []),
+    "a_document_of_topk_plus_one": (_rows((25, 24, 15)), [1]),
+    "a_blocks_last_row_alone_exceeds": (_rows((-7, 25, 24)), [1]),
+    "three_documents_one_over_topk": (_rows((10, 30, 20)), [2]),
+    "one_document_a_row": (_rows((64,)), [1, 2, 3]),
+    "a_row_of_padding": (_rows(()), []),
+    "padding_beside_a_long_row": (_rows((), (64,)), [1, 2, 3]),
+    "two_rows_a_block_scores_for_one": (
+        _rows((30, 34), (20, 20, 24)), [1, 3]),
+    "two_rows_scoring_different_blocks": (
+        _rows((26, 24), (24, 40)), [1, 3]),
+    "length_not_a_multiple_of_the_block": (_rows((40,), length=40), [0]),
+    "length_not_a_multiple_all_short": (_rows((20, 20), length=40), []),
+    "a_row_no_longer_than_a_block": (_rows((-1, 15), length=16), []),
+}
+
+
+def _indexed(seg, scores):
+    """An indexer's queries, keys and weights over rows ``seg``:
+    ``random`` draws, or ``tied``: the keys one of THREE vectors and
+    the weights constant, so that a query's scores take three values
+    and the ``topk``-th falls among equals."""
+    b, l = seg.shape
+    rng = np.random.default_rng(l + b)
+    q = rng.normal(size=(b, l, 2, 4)).astype(np.float32)
+    k = rng.normal(size=(b, l, 4)).astype(np.float32)
+    w = rng.normal(size=(b, l, 2)).astype(np.float32)
+    if scores == "tied":
+        k = k[:, :3][:, rng.integers(0, 3, size=l)]
+        w = np.full_like(w, 0.25)
+    return jnp.asarray(q), jnp.asarray(k), jnp.asarray(w)
+
+
+@pytest.mark.parametrize("scores", ["random", "tied"])
+@pytest.mark.parametrize("case", sorted(_EDGE_ROWS))
+def test_selection_mask_equals_scoring_every_block(case, scores):
+    """``selection_mask`` scores only the blocks of queries in which
+    some row sees more than ``topk`` keys and hands the others their
+    visibility mask: EQUAL, entry for entry, to the unconditional rule
+    (``select_topk`` of the whole row's scores under the whole
+    visibility mask, written out here), on rows that sit on the edges
+    of that predicate, with random scores and with tied ones."""
+    seg, scoring = _EDGE_ROWS[case]
+    topk, block = 24, 16
+    q, k, w = _indexed(seg, scores)
+    at = np.arange(seg.shape[1])
+    visible = (seg[:, :, None] == seg[:, None, :]) \
+        & (seg[:, :, None] != 0) & (at[None, :, None] >= at[None, None, :])
+    want = np.asarray(sparse_index.select_topk(
+        sparse_index.index_scores(q, k, w), jnp.asarray(visible), topk))
+    got = np.asarray(jax.jit(
+        lambda q, k, w, seg: sparse_index.selection_mask(
+            q, k, w, seg, topk, block=block))(q, k, w, jnp.asarray(seg)))
+    assert got.dtype == np.int8 and got.shape == visible.shape
+    assert np.array_equal(got, want.astype(np.int8))
+    assert got.sum() == sparse_index.pair_counts(seg, topk)[0]
+    assert np.flatnonzero(sparse_index.scoring_blocks(
+        seg, topk, block, xp=np)).tolist() == scoring
+    # where no block scores the selection is the visibility mask
+    # itself, and where one does a tie or a score decides some entry
+    assert np.array_equal(got, visible) == (not scoring)
+
+
+def test_blocks_that_score_by_host_and_program_and_the_counter(built):
+    """One rule says which blocks of queries score:
+    ``scoring_blocks`` gives the program (``xp=jnp``) and the host
+    (``xp=np``) the same blocks on the same rows, a call of the program
+    a leading axis, and the engine's ``index_blocks_total`` and its
+    spans' ``index_scored_share`` grow by the host's count."""
+    from realhf_tpu.obs import tracing
+    stacked = np.stack([seg for seg, _ in _EDGE_ROWS.values()
+                        if seg.shape == (1, 64)])
+    for topk, block in ((24, 16), (6, 16), (40, 32), (24, 48), (63, 16)):
+        host = sparse_index.scoring_blocks(stacked, topk, block, xp=np)
+        assert host.shape == (len(stacked), 64 // block
+                              if 64 % block == 0 else 1)
+        program = jax.jit(lambda s: sparse_index.scoring_blocks(
+            s, topk, block))(jnp.asarray(stacked))
+        assert np.array_equal(host, np.asarray(program)), (topk, block)
+        for seg, row in zip(stacked, host):  # a call at a time
+            assert np.array_equal(row, sparse_index.scoring_blocks(
+                seg, topk, block, xp=np))
+    model = built("share")
+    engine = _engine(model["cfg"], model["params"])
+    ids, seg = _packed(model["docs"])
+    # (rows of 64 are one block of queries here: 64 < QUERY_BLOCK)
+    short = np.where(np.arange(64) % DOC < TOPK, seg, 0)
+    assert sparse_index.scoring_blocks(seg, TOPK, xp=np).tolist() == [True]
+    assert sparse_index.scoring_blocks(short, TOPK, xp=np).tolist() \
+        == [False]
+    tracing.start()
+    engine.forward_hidden(ids, seg)
+    engine.forward_hidden(ids, short)
+    engine.forward_hidden(ids, short)
+    capture = tracing.stop()
+    assert capture.counter("index_blocks_total", role=ROLE,
+                           kind="scored") == 3 * 1
+    assert capture.counter("index_blocks_total", role=ROLE,
+                           kind="all") == 3 * 3
+    assert [s["attributes"]["index_scored_share"]
+            for s in capture.named("engine:hidden")] == [1.0, 0.0, 0.0]
+
+
 def test_sparse_stack_through_the_flash_kernels(interpreted_kernels):
     """Heads of 128 and rows of 1024, so that the packed rows meet the
     flash kernels' gate: with the kernels engaged (interpret mode) and
