@@ -458,14 +458,17 @@ class Engine:
     def _count_flash_blocks(self, seg_ids) -> Dict[str, float]:
         """``flash_kv_blocks_total{role,kind}``: the (query block, key
         block) pairs the flash forward kernel visits over these packed
-        rows (``visited``) and the pairs under the rows' causal
-        diagonals (``causal``), one head's, added up over the
-        attention layers, EACH by its own rule
-        (``ops.flash_attention.block_counts``): ``visited`` under the
-        layer's window where it has one, ``causal`` without, so that a
-        stack of window and full layers adds up right. Their ratio is
-        the span's ``flash_block_share``. Nothing where the rows do
-        not go to the kernel, or are on the device already."""
+        rows (``visited``), the pairs under the rows' causal diagonals
+        (``causal``) and those of the visited that no edge crosses, for
+        which the kernels build no mask (``unmasked``), one head's,
+        added up over the attention layers, EACH by its own rule
+        (``ops.flash_attention.block_counts``): ``visited`` and
+        ``unmasked`` under the layer's window where it has one,
+        ``causal`` without, so that a stack of window and full layers
+        adds up right. The span's ``flash_block_share`` is visited
+        over causal, its ``flash_unmasked_share`` unmasked over
+        visited. Nothing where the rows do not go to the kernel, or
+        are on the device already."""
         cfg = self.cfg
         if not (self._flash_rows and isinstance(seg_ids, np.ndarray)
                 and flash_takes(seg_ids.shape[-1], cfg.head_dim)
@@ -473,16 +476,18 @@ class Engine:
             return {}
         layers_of = collections.Counter(
             cfg.layer_window(i) for i in cfg.attention_layers)
-        counts = dict(visited=0, causal=0)
+        counts = dict(visited=0, causal=0, unmasked=0)
         for window, n in layers_of.items():
-            visited, causal = block_counts(seg_ids, sliding_window=window)
-            counts["visited"] += n * visited
-            counts["causal"] += n * causal
+            for kind, pairs in zip(counts, block_counts(
+                    seg_ids, sliding_window=window)):
+                counts[kind] += n * pairs
         for kind, n in counts.items():
             metrics.inc("flash_kv_blocks_total", n,
                         role=str(self.ctx.model_name.role), kind=kind)
         return dict(
-            flash_block_share=counts["visited"] / counts["causal"])
+            flash_block_share=counts["visited"] / counts["causal"],
+            flash_unmasked_share=counts["unmasked"] / max(
+                counts["visited"], 1))
 
     def _count_routed_pairs(self, seg_ids, decode_tokens: int = 0):
         """``moe_routed_pairs_total{role,dispatch}``: the (token,

@@ -40,15 +40,36 @@ at its causal diagonal, and a key block the query blocks likewise
 the jitted program, handed to the kernels by scalar prefetch and made
 the bounds of their loops: forward and dq over ``[kv_lo, kv_hi)`` of
 their query block, dkv over ``[q_lo, q_hi)`` of its key block. A
-block of another segment is never computed; a block that is visited
-is masked exactly as before, so the numbers are those of visiting
-every block up to the diagonal: a block left out contributed
-``p = 0``, or garbage that the next rescaling by ``alpha = 0`` wiped.
-A row of one segment visits the whole causal triangle; a block of
-padding alone visits nothing. The range runs from the first to the
-last block that holds an unmasked pair; only padding that fills whole
-blocks between two segments of one block leaves a masked block inside
-it.
+block of another segment is never computed, so the numbers are those
+of visiting every block up to the diagonal: a block left out
+contributed ``p = 0``, or garbage that the next rescaling by ``alpha
+= 0`` wiped. A row of one segment visits the whole causal triangle; a
+block of padding alone visits nothing. The range runs from the first
+to the last block that holds an unmasked pair; only padding that fills
+whole blocks between two segments of one block leaves a masked block
+inside it.
+
+Which visited pairs build a mask. Only a pair that an edge crosses: a
+document's, padding, the causal diagonal, a window's. ``block_ranges``
+gives, inside each range, the sub-range ``[full_lo, full_hi)`` of
+pairs that attend EVERY (row, column) (the query block in one
+document, the key block whole inside it, before the diagonal and
+inside the window), prefetched as two more scalars, and each kernel
+runs three loops: ``[lo, full_lo)`` and ``[full_hi, hi)`` with the
+mask of segments, causality and window built as ever, ``[full_lo,
+full_hi)`` without iotas, compares, ``and``s or ``where`` (``s`` goes
+to the running maximum as it is; ``p = exp(s - lse)``). A ``where``
+under an all-true mask is the identity, so the results are, bit for
+bit, those of masking every pair. Under a selection an interior pair's
+mask IS the selection's block. With one document of 4096 a row 56 of a
+full layer's 72 pairs are interior; with documents of 512 (two query
+blocks on one key block, both on the diagonal) none, and under a window
+narrower than a pair's two blocks (512 < 256 + 512) none whatever the
+segments: such a layer keeps one loop. So that three loops cost no
+more than one at their boundaries, the forward's accumulator is a VMEM
+scratch and the dkv pass accumulates in its float32 output blocks (a
+carried ``[BQ, hv]`` is copied between one loop's spill slots and the
+next's); PERF.md, PR 47, has the static schedule's counts.
 
 A window bounds both ranges a second time, from the row indices alone
 (inside a document the distance in the row IS the distance in the
@@ -68,7 +89,8 @@ of the three kernels beside the segment views: the rows of the step's
 query block and every column in the forward and the dq pass (256 x L
 bytes), every row and the columns of the step's key block in the dkv
 pass (L x 512), widened to int32 in the kernel and ``and``ed into the
-mask a block pair at a time. Every head of a query shares it. No pair
+mask a block pair at a time (the whole mask of a pair that no edge
+crosses). Every head of a query shares it. No pair
 it leaves out can be computed inside the kernel from row metadata, as
 segments, causality and the window are; the block ranges stay theirs
 (a visited block with no selected pair is multiplied and masked away:
@@ -149,7 +171,9 @@ def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp,
     """The key blocks each query block has to visit and the query
     blocks each key block has to visit, from the segment ids alone:
     ``(kv_lo, kv_hi) [B, L // bq]`` and ``(q_lo, q_hi) [B, L // bk]``,
-    int32, ``hi`` one past the last block.
+    int32, ``hi`` one past the last block; and third, inside each, the
+    sub-range of pairs that no edge crosses, ``((kv_full_lo,
+    kv_full_hi), (q_full_lo, q_full_hi))``.
 
     A token attends only inside its own segment, and a segment is ONE
     contiguous run of its id, so the tokens of a block reach no
@@ -161,8 +185,18 @@ def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp,
     segment gets the whole causal triangle. A ``sliding_window`` of W
     (with ``causal``) cuts the span again: no key before the query
     block's first row less W - 1, no query after the key block's last
-    column plus W - 1. ``xp`` is ``jnp`` inside a
-    program and ``np`` for :func:`block_counts`: one rule for both."""
+    column plus W - 1.
+
+    A pair of the sub-range attends EVERY (row, column): the query
+    block lies in one non-padding segment, the key block whole inside
+    that segment, its last column at or before the query block's first
+    row (``causal``), and the query block's last row less the key
+    block's first column is under W. Segments are contiguous runs, so
+    these pairs are contiguous: ``lo <= full_lo <= full_hi <= hi``, and
+    ``full_lo == full_hi`` where a block holds two documents or
+    padding. The kernels build no mask there. ``xp`` is ``jnp`` inside
+    a program and ``np`` for :func:`block_counts`: one rule for
+    both."""
     b, l = seg_ids.shape
     idx = xp.arange(l, dtype=xp.int32)[None, :]
     edge = seg_ids[:, 1:] != seg_ids[:, :-1]
@@ -186,69 +220,130 @@ def block_ranges(seg_ids, bq: int, bk: int, causal: bool = True, xp=jnp,
     end = xp.where(valid, end, 0)
 
     def span(block):
-        return (start.reshape(b, l // block, block).min(-1),
-                end.reshape(b, l // block, block).max(-1))
+        """Lowest start and highest end of a block's runs, its first
+        index, and whether it lies in ONE run and holds no padding:
+        every token's run starts at or before the block (padding's
+        "starts" at ``l``)."""
+        starts = start.reshape(b, l // block, block)
+        first = xp.arange(l // block, dtype=xp.int32) * block
+        return (starts.min(-1), end.reshape(b, l // block, block).max(-1),
+                first, starts.max(-1) <= first)
 
-    q_start, q_end = span(bq)
-    k_start, k_end = span(bk)
+    q_start, q_end, q_first, q_one = span(bq)
+    k_start, k_end, k_first, k_one = span(bk)
+    # the pairs no edge crosses, by the blocks' own run [start, end):
+    # the other block whole inside it
+    kv_full = (-(-q_start // bk), q_end // bk)
+    q_full = (-(-k_start // bq), k_end // bq)
     if causal:
         # keys at or before the query block's last row; queries at or
         # after the key block's first column
-        q_end = xp.minimum(
-            q_end, (xp.arange(l // bq, dtype=xp.int32) + 1) * bq)
-        k_start = xp.maximum(
-            k_start, xp.arange(l // bk, dtype=xp.int32) * bk)
+        q_end = xp.minimum(q_end, q_first + bq)
+        k_start = xp.maximum(k_start, k_first)
+        # no edge: the key block's last column at or before the query
+        # block's first row
+        kv_full = (kv_full[0], (q_first + 1) // bk)
+        q_full = (-(-(k_first + bk - 1) // bq), q_full[1])
     if sliding_window is not None:
         assert causal, "a sliding window is a causal window"
         # keys no older than W - 1 before the query block's first row;
         # queries no later than W - 1 after the key block's last column
-        q_start = xp.maximum(
-            q_start, xp.arange(l // bq, dtype=xp.int32) * bq
-            - (sliding_window - 1))
-        k_end = xp.minimum(
-            k_end, (xp.arange(l // bk, dtype=xp.int32) + 1) * bk
-            + (sliding_window - 1))
-    return ((q_start // bk, -(-q_end // bk)),
-            (k_start // bq, -(-k_end // bq)))
+        q_start = xp.maximum(q_start, q_first - (sliding_window - 1))
+        k_end = xp.minimum(k_end, k_first + bk + (sliding_window - 1))
+        # no edge: the query block's LAST row sees the key block's
+        # FIRST column
+        kv_full = (xp.maximum(
+            kv_full[0], -(-(q_first + bq - sliding_window) // bk)),
+            kv_full[1])
+        q_full = (q_full[0], xp.minimum(
+            q_full[1], (k_first + sliding_window) // bq))
+    kv_range = (q_start // bk, -(-q_end // bk))
+    q_range = (k_start // bq, -(-k_end // bq))
+
+    def inside(full, one, visited):
+        """``full`` cut to ``lo <= full_lo <= full_hi <= hi``; empty
+        (at ``hi``) where the block is not of one run."""
+        lo, hi = visited
+        hi = xp.maximum(hi, lo)
+        full_lo = xp.where(one, xp.clip(full[0], lo, hi), hi)
+        return full_lo, xp.where(one, xp.clip(full[1], full_lo, hi), hi)
+
+    return kv_range, q_range, (inside(kv_full, q_one, kv_range),
+                               inside(q_full, k_one, q_range))
 
 
 def block_counts(seg_ids: np.ndarray, bq: int = DEFAULT_BQ,
                  bk: int = DEFAULT_BK,
                  sliding_window: Optional[int] = None):
-    """``(visited, causal)``: the (query block, key block) pairs the
-    causal forward kernel visits over packed rows ``seg_ids [..., L]``
-    (one head, one layer; under the layer's ``sliding_window``), and
-    the pairs under the row's causal diagonal that it would visit if
-    each row were one segment and there were no window. On the
-    host, in numpy, by the kernels' own rule (:func:`block_ranges`);
-    the engine's counter ``flash_kv_blocks_total`` adds these up."""
+    """``(visited, causal, unmasked)``: the (query block, key block)
+    pairs the causal forward kernel visits over packed rows ``seg_ids
+    [..., L]`` (one head, one layer; under the layer's
+    ``sliding_window``), the pairs under the row's causal diagonal
+    that it would visit if each row were one segment and there were no
+    window, and those of the visited that no edge crosses, for which
+    the kernels build no mask. On the host, in numpy, by the kernels'
+    own rule (:func:`block_ranges`); the engine's counter
+    ``flash_kv_blocks_total`` adds these up."""
     seg_ids = np.asarray(seg_ids)
     seg_ids = seg_ids.reshape(-1, seg_ids.shape[-1])
     bq, bk = _blocks(seg_ids.shape[1], bq, bk)
-    (lo, hi), _ = block_ranges(seg_ids, bq, bk, xp=np,
-                               sliding_window=sliding_window)
-    (_, diag), _ = block_ranges(np.ones_like(seg_ids[:1]), bq, bk, xp=np)
+    (lo, hi), _, ((full_lo, full_hi), _) = block_ranges(
+        seg_ids, bq, bk, xp=np, sliding_window=sliding_window)
+    (_, diag), _, _ = block_ranges(np.ones_like(seg_ids[:1]), bq, bk, xp=np)
     return (int(np.maximum(hi - lo, 0).sum()),
-            int(diag.sum()) * seg_ids.shape[0])
+            int(diag.sum()) * seg_ids.shape[0],
+            int((full_hi - full_lo).sum()))
 
 
 # ----------------------------------------------------------------------
 # Forward
 # ----------------------------------------------------------------------
-def _block_range(lo_ref, hi_ref):
-    """This grid step's loop bounds out of the prefetched scalars
-    (``[B * blocks]``, flat: a 2-D array in SMEM pads its last axis to
-    128 words)."""
+def _loop_pairs(bounds, body, carry, bq, bk, window):
+    """``body(j, carry, edges)`` over this grid step's visited pairs
+    ``[lo, hi)``, ``bounds`` the four prefetched scalar arrays (``lo,
+    hi, full_lo, full_hi``, each ``[B * blocks]``, flat: a 2-D array in
+    SMEM pads its last axis to 128 words): ``edges`` False over
+    ``[full_lo, full_hi)``, the pairs that attend every (row, column)
+    and need no mask (``block_ranges``), True before and after them.
+    Three loops, not a branch a pair: by the compiler's static schedule
+    a ``cond`` merges its carries over 170 bundles a pair. A window
+    narrower than a pair's two blocks leaves no such pair whatever the
+    segments, and the loop is the one it was."""
     i = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
-    return lo_ref[i], hi_ref[i]
+    lo, hi, full_lo, full_hi = (ref[i] for ref in bounds)
+    masked = functools.partial(body, edges=True)
+    if window is not None and window < bq + bk:
+        return jax.lax.fori_loop(lo, hi, masked, carry)
+    carry = jax.lax.fori_loop(lo, full_lo, masked, carry)
+    carry = jax.lax.fori_loop(full_lo, full_hi,
+                              functools.partial(body, edges=False), carry)
+    return jax.lax.fori_loop(full_hi, hi, masked, carry)
 
 
-def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
+def _edge_mask(seg_q, seg_k, q_idx, k_idx, causal, window):
+    """The ``[BQ, BK]`` mask of a block pair that an edge may cross:
+    segments, padding, causality, the window."""
+    mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
+    if causal:
+        mask &= q_idx >= k_idx
+    if window is not None:
+        mask &= q_idx - k_idx < window
+    return mask
+
+
+def _and_selected(mask, block):
+    """``mask`` (None: every pair attended) and the selection's int8
+    ``[BQ, BK]`` block of the pair, widened."""
+    selected = block.astype(jnp.int32) != 0
+    return selected if mask is None else mask & selected
+
+
+def _fwd_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,  # prefetch
                 q_ref, k_ref, v_ref, segq_ref, segk_ref,  # inputs
-                *rest,  # [the selection,] then the outputs: o, lse
+                *rest,  # [the selection,] the outputs o, lse, a scratch
                 scale: float, bk: int, causal: bool,
                 window: Optional[int] = None):
-    *sel_ref, o_ref, lse_ref = rest
+    *sel_ref, o_ref, lse_ref, acc_ref = rest
     qi = pl.program_id(2)
     bq, hv = q_ref.shape[-2], v_ref.shape[-1]
 
@@ -258,41 +353,44 @@ def _fwd_kernel(kv_lo_ref, kv_hi_ref,  # scalar prefetch
 
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, hv), jnp.float32)
+    # (the accumulator in VMEM, not a third carry: between two loops a
+    # carry of [BQ, hv] is copied from one loop's registers and spill
+    # slots to the next's)
+    acc_ref[...] = jnp.zeros((bq, hv), jnp.float32)
 
-    def body(j, carry):
-        m, l_sum, acc = carry
+    def body(j, carry, edges):
+        m, l_sum = carry
         k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # [BK, hd]
         v = v_ref[0, 0, pl.ds(j * bk, bk), :]  # [BK, hv]
-        seg_k = segk_ref[0, 0, pl.ds(j * bk, bk)]  # [BK]
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)  # [BQ, BK]
-        k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
-        if causal:
-            mask &= q_idx >= k_idx
-        if window is not None:
-            mask &= q_idx - k_idx < window
+        mask = None
+        if edges:
+            seg_k = segk_ref[0, 0, pl.ds(j * bk, bk)]  # [BK]
+            k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = _edge_mask(seg_q, seg_k, q_idx, k_idx, causal, window)
         if sel_ref:  # [BQ, BK] of the selection's rows of this block
-            mask &= sel_ref[0][0, :, pl.ds(j * bk, bk)].astype(
-                jnp.int32) != 0
-        s = jnp.where(mask, s, NEG_INF)
+            mask = _and_selected(mask, sel_ref[0][0, :, pl.ds(j * bk, bk)])
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
 
         m_new = jnp.maximum(m, s.max(axis=1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
         l_new = l_sum * alpha + p.sum(axis=1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        return m_new, l_new
 
     # only the key blocks this query block's segments reach
     # (block_ranges): a block left out held no unmasked pair
-    m, l_sum, acc = jax.lax.fori_loop(
-        *_block_range(kv_lo_ref, kv_hi_ref), body, (m0, l0, acc0))
+    m, l_sum = _loop_pairs(
+        (kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref), body, (m0, l0),
+        bq, bk, window)
+    acc = acc_ref[...]
     # Rows that never saw a valid key (all-padding rows) keep
     # m == NEG_INF: their p = exp(NEG_INF - NEG_INF) = 1 garbage must be
     # zeroed here. (Fully-masked *blocks* of otherwise-valid rows
@@ -352,18 +450,19 @@ def _vmem_limit(in_specs, out_specs, out_shape, args):
 
 
 def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
-                 out_shape, *args):
-    """``pallas_call`` with a grid step's loop bounds ``(lo, hi)
-    [B, grid[2]]`` prefetched as scalars; index maps get both after
-    the grid indices."""
+                 out_shape, *args, scratch=()):
+    """``pallas_call`` with a grid step's loop bounds ``(lo, hi,
+    full_lo, full_hi)``, each ``[B, grid[2]]``, prefetched as scalars;
+    index maps get them after the grid indices. ``scratch``: the
+    kernel's VMEM scratch shapes, its last arguments."""
     limit = _vmem_limit(in_specs, out_specs, out_shape, args)
     params = {} if limit is None else dict(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit))
     return pl.pallas_call(
         kernel, out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
-            out_specs=out_specs),
+            num_scalar_prefetch=len(bounds), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=list(scratch)),
         name=name, **params,
     )(*(x.reshape(-1) for x in bounds), *args)
 
@@ -384,8 +483,8 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None,
     kt = k.transpose(0, 2, 1, 3)  # [B, nkv, L, hd]
     vt = v.transpose(0, 2, 1, 3)
     segq, segk = _expand_segments(seg_ids)
-    kv_range, _ = block_ranges(seg_ids, bq, bk, causal,
-                               sliding_window=window)
+    kv_range, _, (kv_full, _) = block_ranges(seg_ids, bq, bk, causal,
+                                             sliding_window=window)
 
     at = _index_maps(group)
 
@@ -394,7 +493,7 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None,
     out, lse = _ranged_call(
         functools.partial(_fwd_kernel, scale=scale, bk=bk, causal=causal,
                           window=window),
-        "flash_fwd" + suffix, (b, nq, l // bq), kv_range,
+        "flash_fwd" + suffix, (b, nq, l // bq), kv_range + kv_full,
         [
             pl.BlockSpec((1, 1, bq, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
@@ -406,7 +505,8 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None,
          pl.BlockSpec((1, 1, bq, LANES), at["row"])),
         (jax.ShapeDtypeStruct((b, nq, l, hv), q.dtype),
          jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
-        qt, kt, vt, segq, segk, *operands)
+        qt, kt, vt, segq, segk, *operands,
+        scratch=[pltpu.VMEM((bq, hv), jnp.float32)])
     return out, lse
 
 
@@ -429,7 +529,7 @@ def _selected(select, spec):
 # ----------------------------------------------------------------------
 # Backward
 # ----------------------------------------------------------------------
-def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
+def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
                    q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                    lse_ref, delta_ref, *rest,  # [the selection,] dq
                    scale: float, bk: int, causal: bool,
@@ -445,22 +545,21 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
     seg_q = segq_ref[0, :, 0]
     q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
 
-    def body(j, dq):
+    def body(j, dq, edges):
         k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        seg_k = segk_ref[0, 0, pl.ds(j * bk, bk)]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
-        if causal:
-            mask &= q_idx >= k_idx
-        if window is not None:
-            mask &= q_idx - k_idx < window
+        mask = None
+        if edges:
+            seg_k = segk_ref[0, 0, pl.ds(j * bk, bk)]
+            k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = _edge_mask(seg_q, seg_k, q_idx, k_idx, causal, window)
         if sel_ref:
-            mask &= sel_ref[0][0, :, pl.ds(j * bk, bk)].astype(
-                jnp.int32) != 0
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+            mask = _and_selected(mask, sel_ref[0][0, :, pl.ds(j * bk, bk)])
+        p = jnp.exp(s - lse[:, None])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
@@ -468,12 +567,13 @@ def _bwd_dq_kernel(kv_lo_ref, kv_hi_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(*_block_range(kv_lo_ref, kv_hi_ref), body,
-                           jnp.zeros((bq, hd), jnp.float32))
+    dq = _loop_pairs(
+        (kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref), body,
+        jnp.zeros((bq, hd), jnp.float32), bq, bk, window)
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
+def _bwd_dkv_kernel(q_lo_ref, q_hi_ref, full_lo_ref, full_hi_ref,
                     q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
                     lse_ref, delta_ref, *rest,  # [the selection,] dk, dv
                     scale: float, bq: int, causal: bool,
@@ -487,41 +587,41 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref,
     seg_k = segk_ref[0, 0, pl.ds(ki * bk, bk)]
     k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
 
-    def body(j, carry):
-        dk, dv = carry
+    def body(j, carry, edges):
         q = q_ref[0, 0, pl.ds(j * bq, bq), :].astype(jnp.float32) * scale
         do = do_ref[0, 0, pl.ds(j * bq, bq), :].astype(jnp.float32)
         lse = lse_ref[0, 0, pl.ds(j * bq, bq), 0]
         delta = delta_ref[0, 0, pl.ds(j * bq, bq), 0]
-        seg_q = segq_ref[0, pl.ds(j * bq, bq), 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        q_idx = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
-        if causal:
-            mask &= q_idx >= k_idx
-        if window is not None:
-            mask &= q_idx - k_idx < window
+        mask = None
+        if edges:
+            seg_q = segq_ref[0, pl.ds(j * bq, bq), 0]
+            q_idx = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            mask = _edge_mask(seg_q, seg_k, q_idx, k_idx, causal, window)
         if sel_ref:  # the selection's columns of this block, all rows
-            mask &= sel_ref[0][0, pl.ds(j * bq, bq), :].astype(
-                jnp.int32) != 0
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+            mask = _and_selected(mask, sel_ref[0][0, pl.ds(j * bq, bq), :])
+        p = jnp.exp(s - lse[:, None])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dv_ref[0, 0] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
+        dk_ref[0, 0] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return carry
 
-    dk0 = jnp.zeros((bk, hd), jnp.float32)
-    dv0 = jnp.zeros((bk, hv), jnp.float32)
-    dk, dv = jax.lax.fori_loop(*_block_range(q_lo_ref, q_hi_ref), body,
-                               (dk0, dv0))
-    # Per-q-head partials; summed over each KV group outside (race-free).
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    # Per-q-head partials; summed over each KV group outside
+    # (race-free). Accumulated in the float32 output blocks, not in
+    # carries: two of [BK, hd] are copied between one loop and the next
+    dk_ref[0, 0] = jnp.zeros((bk, hd), jnp.float32)
+    dv_ref[0, 0] = jnp.zeros((bk, hv), jnp.float32)
+    _loop_pairs((q_lo_ref, q_hi_ref, full_lo_ref, full_hi_ref), body, None,
+                bq, bk, window)
 
 
 def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
@@ -545,8 +645,8 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
     delta = (ot.astype(jnp.float32) * dot.astype(jnp.float32)).sum(-1)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, l, LANES))
 
-    kv_range, q_range = block_ranges(seg_ids, bq_, bk_, causal,
-                                     sliding_window=window)
+    kv_range, q_range, (kv_full, q_full) = block_ranges(
+        seg_ids, bq_, bk_, causal, sliding_window=window)
 
     at = _index_maps(group)
 
@@ -557,7 +657,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
     dq = _ranged_call(
         functools.partial(_bwd_dq_kernel, scale=scale, bk=bk_,
                           causal=causal, window=window),
-        "flash_bwd_dq" + suffix, (b, nq, l // bq_), kv_range,
+        "flash_bwd_dq" + suffix, (b, nq, l // bq_), kv_range + kv_full,
         [
             pl.BlockSpec((1, 1, bq_, hd), at["row"]),
             pl.BlockSpec((1, 1, l, hd), at["kv_whole"]),
@@ -575,7 +675,7 @@ def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
     dk_partial, dv_partial = _ranged_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq_,
                           causal=causal, window=window),
-        "flash_bwd_dkv" + suffix, (b, nq, l // bk_), q_range,
+        "flash_bwd_dkv" + suffix, (b, nq, l // bk_), q_range + q_full,
         [
             pl.BlockSpec((1, 1, l, hd), at["whole"]),
             pl.BlockSpec((1, 1, bk_, hd), at["kv_row"]),

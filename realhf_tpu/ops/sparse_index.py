@@ -197,7 +197,7 @@ def unselected_blocks(select: np.ndarray, seg_ids: np.ndarray,
     select, seg = np.asarray(select), np.asarray(seg_ids)
     b, l = seg.shape
     bq, bk = F._blocks(l, bq or F.DEFAULT_BQ, bk or F.DEFAULT_BK)
-    (lo, hi), _ = F.block_ranges(seg, bq, bk, xp=np)
+    (lo, hi), *_ = F.block_ranges(seg, bq, bk, xp=np)
     any_pair = select.reshape(b, l // bq, bq, l // bk, bk).any((2, 4))
     j = np.arange(l // bk)
     visit = (j >= lo[..., None]) & (j < hi[..., None])

@@ -101,11 +101,12 @@ def main():
         assert seg.shape[-1] == ROW, seg.shape
         bq, bk = fa._blocks(ROW, fa.DEFAULT_BQ, fa.DEFAULT_BK)
         # (a tree from before the ranges visits every block)
-        visited, causal = getattr(
-            fa, "block_counts", lambda seg: (None, None))(seg)
+        visited, causal, unmasked = getattr(
+            fa, "block_counts", lambda seg: (None, None, None))(seg)
         calls.append(dict(
             visited=visited, needed=needed_blocks(seg, bq, bk),
-            causal=causal, tokens=int(np.count_nonzero(seg)),
+            causal=causal, unmasked=unmasked,
+            tokens=int(np.count_nonzero(seg)),
             documents=int(sum(len(np.unique(r[r != 0]))
                               for r in seg.reshape(-1, ROW)))))
         return train_batch(self, microbatches, *a, **kw)

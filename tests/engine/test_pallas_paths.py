@@ -228,7 +228,7 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(
     seg[0, 0] = np.repeat([2, 4, 1, 3], 256)
     seg[1, 0, :512] = 1
     ids = rng.integers(1, 120, size=seg.shape).astype(np.int32)
-    assert block_counts(seg) == (4 + 2, 6 + 6)
+    assert block_counts(seg) == (4 + 2, 6 + 6, 0)
 
     def loss_fn(params, h, mb):
         lp = F.shifted_logprobs_from_hidden(
@@ -255,9 +255,45 @@ def test_engine_counts_the_key_blocks_its_flash_kernels_visit(
     [logprobs] = capture.named("engine:logprobs")
     assert train["attributes"]["flash_block_share"] == 6 / 12
     assert logprobs["attributes"]["flash_block_share"] == 4 / 6
-    for kind, blocks in (("visited", 6 + 4), ("causal", 12 + 6)):
+    # sequences of 256 and 512 at blocks of 256 x 512: an edge crosses
+    # every visited pair
+    assert train["attributes"]["flash_unmasked_share"] == 0
+    for kind, blocks in (("visited", 6 + 4), ("causal", 12 + 6),
+                         ("unmasked", 0)):
         assert capture.counter("flash_kv_blocks_total", role="default",
                                kind=kind) == blocks * cfg.n_layers
+
+
+@pytest.mark.parametrize("window,visited,unmasked", [(None, 72, 56),
+                                                     (512, 30, 0)])
+def test_engine_counts_the_pairs_its_flash_kernels_build_no_mask_for(
+        window, visited, unmasked, interpreted_kernels):
+    """One document of 4,096 tokens a row, as the benchmark's cells 6,
+    7 and 9 pack them: a full layer visits 72 block pairs (256 x 512)
+    and builds no mask for the 56 off the diagonal; under a window of
+    512 every one of a layer's 30 lies on the diagonal or on the
+    window's edge. What the engine counts before a program runs
+    (``_count_batch``: no row of 4,096 goes through the interpreter
+    here) and what it hands the ``engine:*`` span."""
+    from realhf_tpu.api.config import ModelName
+    from realhf_tpu.engine.engine import Engine
+    from realhf_tpu.obs import tracing
+    from realhf_tpu.parallel.mesh import MeshContext
+
+    cfg = _cfg(sliding_window=window)
+    ctx = MeshContext(ModelName("default", 0), _mesh(1, 1),
+                      ParallelismConfig())
+    with interpreted_kernels():  # the rows go to the kernels
+        engine = Engine(cfg, ctx, T.init_params(cfg, jax.random.PRNGKey(0)))
+    tracing.start()
+    attrs = engine._count_batch(np.ones((2, 1, 4096), np.int32))
+    capture = tracing.stop()
+    assert attrs["flash_block_share"] == visited / 72
+    assert attrs["flash_unmasked_share"] == unmasked / visited
+    for kind, blocks in (("visited", visited), ("causal", 72),
+                         ("unmasked", unmasked)):
+        assert capture.counter("flash_kv_blocks_total", role="default",
+                               kind=kind) == 2 * blocks * cfg.n_layers
 
 
 @pytest.fixture(scope="module")

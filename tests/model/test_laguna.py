@@ -518,8 +518,8 @@ def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
     ids = np.random.default_rng(0).integers(
         2, 128, size=(1, 1024)).astype(np.int32)
     seg = np.ones((1, 1024), np.int32)
-    assert block_counts(seg) == (6, 6)
-    assert block_counts(seg, sliding_window=128) == (5, 6)
+    assert block_counts(seg) == (6, 6, 2)
+    assert block_counts(seg, sliding_window=128) == (5, 6, 0)
 
     def run():
         engine = _engine(cfg, params)
@@ -541,3 +541,8 @@ def test_mixed_stack_through_the_flash_kernels_counts_each_layers_blocks(
                            kind="visited") == 3 * 5 + 2 * 6
     assert capture.counter("flash_kv_blocks_total", role=role,
                            kind="causal") == 5 * 6
+    # no mask is built for a full layer's two pairs off the diagonal
+    assert span["attributes"]["flash_unmasked_share"] == \
+        2 * 2 / (3 * 5 + 2 * 6)
+    assert capture.counter("flash_kv_blocks_total", role=role,
+                           kind="unmasked") == 2 * 2

@@ -148,20 +148,62 @@ def packed_rows(rng, l, align, kind):
     return seg
 
 
-def needed_blocks(seg, bq, bk, causal):
-    """[B, L // bq, L // bk] bool: the block holds an unmasked pair."""
-    b, l = seg.shape
+def pair_mask(seg, causal=True, window=None):
+    """[B, L, L] bool, built the long way: the pairs attended."""
+    idx = np.arange(seg.shape[1])
     mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0)
     if causal:
-        mask &= np.tril(np.ones((l, l), bool))[None]
-    return mask.reshape(b, l // bq, bq, l // bk, bk).any(axis=(2, 4))
+        mask &= (idx[:, None] >= idx[None, :])[None]
+    if window is not None:
+        mask &= (idx[:, None] - idx[None, :] < window)[None]
+    return mask
+
+
+def blocks_of(mask, bq, bk, reduce):
+    """[B, L // bq, L // bk]: ``reduce`` (``any`` / ``all``) over each
+    block pair of a [B, L, L] mask."""
+    b, l, _ = mask.shape
+    return getattr(mask.reshape(b, l // bq, bq, l // bk, bk), reduce)(
+        axis=(2, 4))
+
+
+def long_rows(l):
+    """[4, l] rows whose documents span several blocks: one document;
+    two that meet off every block's edge; padding inside and at the
+    end; ids in no order; a document that ends at a block's edge."""
+    seg = np.ones((4, l), np.int32)
+    seg[1, :l * 5 // 8 + 3] = 7
+    seg[2, :l * 3 // 8 + 5] = 3
+    seg[2, l * 3 // 8 + 5:l // 2 - 3] = 0
+    seg[2, -l // 16 - 1:] = 0
+    seg[3, l // 2:] = 2
+    return seg
+
+
+def assert_no_edge_pairs_are_exactly_the_all_true(ranges, mask, bq, bk):
+    """``block_ranges``' third answer against the mask built the long
+    way: every pair of ``[full_lo, full_hi)`` has an all-true ``[bq,
+    bk]`` mask, every all-true pair lies in it, from the query blocks'
+    side and from the key blocks', inside what is visited."""
+    n_q, n_k = mask.shape[1] // bq, mask.shape[2] // bk
+    all_true = blocks_of(mask, bq, bk, "all")
+    by_q, by_k = visited_blocks(ranges[2], n_q, n_k)
+    np.testing.assert_array_equal(by_q, all_true)
+    np.testing.assert_array_equal(by_k, all_true)
+    for (lo, hi), (full_lo, full_hi) in zip(ranges[:2], ranges[2]):
+        lo, hi, full_lo, full_hi = (
+            np.asarray(x) for x in (lo, hi, full_lo, full_hi))
+        hi = np.maximum(hi, lo)
+        assert (lo <= full_lo).all() and (full_lo <= full_hi).all() \
+            and (full_hi <= hi).all()
+    return int(all_true.sum())
 
 
 def visited_blocks(ranges, n_q, n_k):
     """The same shape from ``block_ranges``' two answers: what the
     forward and dq loops visit, and what the dkv loop visits."""
     (kv_lo, kv_hi), (q_lo, q_hi) = [
-        [np.asarray(x) for x in r] for r in ranges]
+        [np.asarray(x) for x in r] for r in ranges[:2]]
     kj, qi = np.arange(n_k), np.arange(n_q)
     by_q = (kv_lo[:, :, None] <= kj) & (kj < kv_hi[:, :, None])
     by_k = (q_lo[:, None, :] <= qi[:, None]) & (qi[:, None] < q_hi[:, None, :])
@@ -179,19 +221,24 @@ def _hull(needed, axis):
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("kind", ["none", "tail", "between", "both"])
 @pytest.mark.parametrize("bq,bk,align", [(32, 64, 64), (64, 32, 64),
-                                         (32, 64, 1), (64, 64, 8)])
+                                         (32, 64, 1), (64, 64, 8),
+                                         (256, 512, 128), (256, 512, 1)])
 def test_block_ranges_against_brute_force(bq, bk, align, kind, causal):
     """No block that holds an unmasked pair is left out, and where the
-    segments' boundaries fall on the blocks nothing else is visited."""
-    l = 512
+    segments' boundaries fall on the blocks nothing else is visited;
+    the pairs no edge crosses are exactly those whose mask, built the
+    long way, is all true."""
+    l = 8 * max(bq, bk)
     rng = np.random.default_rng(abs(hash((bq, bk, align, kind))) % 2**31)
-    seg = packed_rows(rng, l, align, kind)
-    needed = needed_blocks(seg, bq, bk, causal)
+    seg = np.concatenate([packed_rows(rng, l, align, kind), long_rows(l)])
+    mask = pair_mask(seg, causal)
+    needed = blocks_of(mask, bq, bk, "any")
     assert not needed[3].any() and needed[:3].any()
 
     on_host = fa.block_ranges(seg, bq, bk, causal, xp=np)
     in_program = jax.jit(fa.block_ranges, static_argnums=(1, 2, 3))(
         jnp.asarray(seg), bq, bk, causal)
+    assert len(jax.tree.leaves(on_host)) == 8
     for a, b in zip(jax.tree.leaves(on_host), jax.tree.leaves(in_program)):
         assert a.dtype == b.dtype == np.int32
         np.testing.assert_array_equal(a, np.asarray(b))
@@ -200,19 +247,23 @@ def test_block_ranges_against_brute_force(bq, bk, align, kind, causal):
         assert not (needed & ~visited).any()
         assert not visited[3].any()  # padding alone: an empty range
         if align % max(bq, bk) == 0:
-            np.testing.assert_array_equal(visited, needed)
+            np.testing.assert_array_equal(visited[:4], needed[:4])
     # wherever the boundaries fall, a range runs from the first to the
     # last block that is needed, no further
     by_q, by_k = visited_blocks(on_host, l // bq, l // bk)
     np.testing.assert_array_equal(by_q, _hull(needed, axis=2))
     np.testing.assert_array_equal(by_k, _hull(needed, axis=1))
+    # a row of one document: every pair off the diagonal
+    assert assert_no_edge_pairs_are_exactly_the_all_true(
+        on_host, mask, bq, bk) >= (l // bq) * (l // bk) // 4
 
 
 BENCHMARK_ROWS = {  # cell: (sequences a row, their length, row, counts)
-    "qwen2.5-0.5b.sft": (4, 1024, 4096, (24, 72)),
-    "qwen2.5-0.5b.grpo": (8, 512, 4096, (16, 72)),
-    "mistral-7b-v0.3-l4.grpo-realloc": (4, 512, 2048, (8, 20)),
-    "olmoe-1b-7b-0125-l1.sft-2k": (1, 2048, 2048, (20, 20)),
+    "qwen2.5-0.5b.sft": (4, 1024, 4096, (24, 72, 8)),
+    "qwen2.5-0.5b.grpo": (8, 512, 4096, (16, 72, 0)),
+    "mistral-7b-v0.3-l4.grpo-realloc": (4, 512, 2048, (8, 20, 0)),
+    "olmoe-1b-7b-0125-l1.sft-2k": (1, 2048, 2048, (20, 20, 12)),
+    "keye-vl-2.0-30b-a3b-l5-ep8.sft-4k": (1, 4096, 4096, (72, 72, 56)),
 }
 
 
@@ -223,15 +274,22 @@ def test_block_counts_of_the_benchmark_rows(cell):
     assert seg.shape == (row,)
     assert fa.block_counts(seg[None]) == want
     # a stack of microbatches of rows counts each row
-    assert fa.block_counts(np.tile(seg, (3, 2, 1))) == (
-        6 * want[0], 6 * want[1])
+    assert fa.block_counts(np.tile(seg, (3, 2, 1))) == tuple(
+        6 * n for n in want)
 
 
-def _dense_ranges(seg, bq, bk, causal=True, sliding_window=None,
-                  ranges=fa.block_ranges):
+def _every_pair_masked(*args, ranges=fa.block_ranges, **kw):
+    """The ranges as they are, the pairs that no edge crosses forced
+    empty: every visited pair builds its mask, as before there was
+    such a sub-range."""
+    kv_range, q_range, _ = ranges(*args, **kw)
+    return kv_range, q_range, ((kv_range[1],) * 2, (q_range[1],) * 2)
+
+
+def _dense_ranges(seg, bq, bk, causal=True, sliding_window=None):
     """The ranges of rows of ONE segment: every key block up to the
     causal diagonal (or the row's end), as before the ranges existed."""
-    return ranges(jnp.ones_like(seg), bq, bk, causal)
+    return _every_pair_masked(jnp.ones_like(seg), bq, bk, causal)
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
@@ -267,7 +325,7 @@ def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch,
             dense = run(flash)
     reference = run(packed_attention_xla)
     # fewer blocks were visited than the dense loops visit
-    visited, diagonal = fa.block_counts(seg_np, 32, 64)
+    visited, diagonal, _ = fa.block_counts(seg_np, 32, 64)
     assert visited < diagonal
     for name, got, same, ref in zip(("out", "dq", "dk", "dv"), by_segment,
                                     dense, reference):
@@ -277,31 +335,94 @@ def test_segment_ranges_change_no_bit(kind, align, causal, monkeypatch,
                                    atol=5e-3, err_msg=name)
 
 
+def pairs_without_a_mask_change_no_bit(
+        interpreted_kernels, monkeypatch, heads=(2, 1, 32, 32), rows=(0, 2),
+        causal=True, window=None, select=False):
+    """The three kernels over rows whose documents span several blocks
+    (``long_rows``; blocks of 32 x 64), with no mask built over the
+    pairs that no edge crosses and with that sub-range forced empty:
+    output, log-sum-exp, dq, dk and dv equal to the bit, and within
+    the XLA path's tolerances. ``heads``: query and key/value heads,
+    the key's width and the value's."""
+    rng = np.random.default_rng(7)
+    l, (nq, nkv, hd, hv) = 256, heads  # a small grid: interpreted
+    seg_np = long_rows(l)[list(rows)]
+    b, seg = len(seg_np), jnp.asarray(seg_np)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((b, l, n, d)), jnp.float32)
+                  for n, d in ((nq, hd), (nkv, hd), (nkv, hv), (nq, hv)))
+    g = jnp.where(jnp.asarray(seg_np != 0)[..., None, None], w, 0.0)
+    kw = dict(causal=causal, sliding_window=window)
+    if select:
+        kw["select"] = jnp.asarray(rng.random((b, l, l)) < 0.5, jnp.int8) \
+            | jnp.eye(l, dtype=jnp.int8)
+    static = (hd ** -0.5, causal, 32, 64, window)
+
+    @jax.jit  # one program: interpreted grid steps run op by op otherwise
+    def kernels(q, k, v):
+        if select:
+            out, res = fa._flash_attention_selected_fwd(
+                q, k, v, seg, kw["select"], *static)
+            grads = fa._flash_attention_selected_bwd(*static, res, g)
+        else:
+            out, res = fa._flash_attention_fwd(q, k, v, seg, *static)
+            grads = fa._flash_bwd(res, g, *static)
+        return (out, res[-1]) + tuple(grads[:3])
+
+    with interpreted_kernels():
+        got = [np.asarray(x) for x in kernels(q, k, v)]
+        with monkeypatch.context() as patch:
+            patch.setattr(fa, "block_ranges", _every_pair_masked)
+            kernels.clear_cache()
+            masked = [np.asarray(x) for x in kernels(q, k, v)]
+    out, vjp = jax.vjp(lambda q, k, v: packed_attention_xla(
+        q, k, v, seg, **kw), q, k, v)
+    # the sub-range is not empty, nor is it everything
+    visited, _, unmasked = fa.block_counts(seg_np, 32, 64,
+                                           sliding_window=window)
+    assert 0 < unmasked < visited
+    for name, a, same in zip(("out", "lse", "dq", "dk", "dv"), got, masked):
+        assert np.array_equal(a, same), name
+    keep = seg_np != 0
+    np.testing.assert_allclose(got[0][keep], np.asarray(out)[keep],
+                               rtol=5e-3, atol=5e-3)
+    for name, a, ref in zip(("dq", "dk", "dv"), got[2:], vjp(g)):
+        np.testing.assert_allclose(a, np.asarray(ref), rtol=5e-3, atol=5e-3,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(rows=(1, 3), causal=False), dict(select=True), dict(heads=(2, 2, 192, 128)),
+    dict(heads=(2, 2, 64, 64)), dict(heads=(4, 1, 32, 32)),
+    dict(heads=(8, 1, 32, 32), rows=(2,))],
+    ids=["causal", "full", "selection", "key192_value128",
+         "heads64_group1", "group4", "group8"])
+def test_pairs_without_a_mask_change_no_bit(kw, monkeypatch,
+                                            interpreted_kernels):
+    pairs_without_a_mask_change_no_bit(interpreted_kernels, monkeypatch,
+                                       **kw)
+
+
 # ----------------------------------------------------------------------
 # A sliding window inside the kernels
 # ----------------------------------------------------------------------
-def needed_blocks_windowed(seg, bq, bk, window):
-    b, l = seg.shape
-    idx = np.arange(l)
-    mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0) \
-        & (idx[:, None] >= idx[None, :])[None] \
-        & (idx[:, None] - idx[None, :] < window)[None]
-    return mask.reshape(b, l // bq, bq, l // bk, bk).any(axis=(2, 4))
-
-
 @pytest.mark.parametrize("kind", ["none", "both"])
-@pytest.mark.parametrize("window", [1, 31, 32, 33, 64, 100, 511, 512, 4096])
-@pytest.mark.parametrize("bq,bk,align", [(32, 64, 64), (64, 32, 1)])
+@pytest.mark.parametrize("window", [1, 31, 32, 33, 64, 100, 128, 511, 512,
+                                    768, 1500, 4096])
+@pytest.mark.parametrize("bq,bk,align", [(32, 64, 64), (64, 32, 1),
+                                         (256, 512, 128)])
 def test_block_ranges_with_a_window_leave_out_no_needed_block(
         bq, bk, align, window, kind):
     """Whatever the window against the blocks (below, at, above them,
     the whole row): no block that holds an unmasked pair is left out,
     the ranges on the host are those inside a program, and they run
-    from the first needed block to the last, no further."""
-    l = 512
+    from the first needed block to the last, no further; the pairs no
+    edge crosses (a window's edge among them) are exactly those whose
+    mask, built the long way, is all true."""
+    l = 8 * max(bq, bk)
     rng = np.random.default_rng(abs(hash((bq, bk, align, window))) % 2**31)
-    seg = packed_rows(rng, l, align, kind)
-    needed = needed_blocks_windowed(seg, bq, bk, window)
+    seg = np.concatenate([packed_rows(rng, l, align, kind), long_rows(l)])
+    mask = pair_mask(seg, True, window)
+    needed = blocks_of(mask, bq, bk, "any")
     assert not needed[3].any() and needed[:3].any()
     on_host = fa.block_ranges(seg, bq, bk, True, xp=np,
                               sliding_window=window)
@@ -317,6 +438,9 @@ def test_block_ranges_with_a_window_leave_out_no_needed_block(
         assert not visited[3].any()
     np.testing.assert_array_equal(by_q, _hull(needed, axis=2))
     np.testing.assert_array_equal(by_k, _hull(needed, axis=1))
+    # a pair is whole inside no window narrower than its two blocks
+    assert (assert_no_edge_pairs_are_exactly_the_all_true(
+        on_host, mask, bq, bk) > 0) == (window >= bq + bk)
     # and a window changes nothing of the ranges without one
     causal = fa.block_ranges(seg, bq, bk, True, xp=np)
     if window >= l:
@@ -344,12 +468,16 @@ def test_no_window_is_the_ranges_of_before():
 
 
 @pytest.mark.parametrize("row,window,want", [
-    (4096, 512, (30, 72)), (4096, None, (72, 72)), (4096, 4096, (72, 72)),
-    (2048, 512, (14, 20)), (4096, 1, (16, 72))])
+    (4096, 512, (30, 72, 0)), (4096, None, (72, 72, 56)),
+    (4096, 4096, (72, 72, 56)), (2048, 512, (14, 20, 0)),
+    (4096, 1, (16, 72, 0)), (4096, 768, (36, 72, 7)),
+    (4096, 1024, (42, 72, 14))])
 def test_block_counts_under_a_window(row, window, want):
     """One document a row at the kernels' blocks (256 x 512): the
     benchmark's sixth cell visits 30 of its 72 causal block pairs in a
-    window layer."""
+    window layer, and an edge (the diagonal or the window's) crosses
+    every one of them; in a full layer 56 of the 72 lie off the
+    diagonal and are not masked."""
     seg = np.ones((1, row), np.int32)
     assert fa.block_counts(seg, sliding_window=window) == want
 
@@ -380,8 +508,8 @@ def test_windowed_kernels_match_the_xla_mask(window, interpreted_kernels):
         got = run(functools.partial(fa.flash_attention, block_q=32,
                                     block_k=64))
     want = run(packed_attention_xla)
-    visited, diagonal = fa.block_counts(seg_np, 32, 64,
-                                        sliding_window=window)
+    visited, diagonal, _ = fa.block_counts(seg_np, 32, 64,
+                                           sliding_window=window)
     assert visited < diagonal
     for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
         keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
@@ -551,6 +679,27 @@ def test_equal_widths_keep_the_kernels_jaxprs():
             jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v))(
         jax.ShapeDtypeStruct((1, 256, 4, 192), jnp.bfloat16), wide, v, seg))
     assert "f32[1,4,256,192]" in text and "f32[1,4,256,128]" in text
+
+
+@pytest.mark.parametrize("window,loops", [(None, 3), (768, 3), (767, 1),
+                                          (512, 1)])
+def test_a_window_narrower_than_a_pair_keeps_one_loop(window, loops):
+    """A pair that no edge crosses spans ``bq + bk - 1`` positions: a
+    window under 256 + 512 holds none whatever the segments, and each
+    of that layer's three kernels keeps the ONE loop it had (the
+    benchmark's sixth cell's window of 512); every other call runs the
+    pairs before, inside and after the sub-range as three."""
+    q, k, v = (jax.ShapeDtypeStruct((1, 4096, n, 128), jnp.bfloat16)
+               for n in (4, 2, 2))
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    text = str(jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, s, sliding_window=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v))(q, k, v, seg))
+    assert text.count("pallas_call") == 3
+    assert text.count(" while[") == 3 * loops
+    assert (fa.block_counts(np.ones((1, 4096), np.int32),
+                            sliding_window=window)[2] > 0) == (loops == 3)
 
 
 def test_the_kept_residuals_have_the_values_width(interpreted_kernels):

@@ -14,6 +14,7 @@ from realhf_tpu.ops.attention import (
     packed_attention,
     packed_attention_xla,
 )
+from test_flash_attention import pairs_without_a_mask_change_no_bit
 
 
 def _naive(q, k, v, seg, window, causal=True):
@@ -70,6 +71,20 @@ def test_packed_xla_matches_naive(window):
     want = _naive(np.asarray(q), np.asarray(k), np.asarray(v), seg, window)
     valid = seg != 0  # pad-row outputs are don't-care
     np.testing.assert_allclose(got[valid], want[valid], atol=1e-5)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(window=100, rows=(0, 1)), dict(window=130, select=True),
+    dict(window=97, heads=(8, 1, 32, 32), rows=(3,))],
+    ids=["window", "selection", "group8"])
+def test_flash_builds_no_mask_between_the_windows_edge_and_the_diagonal(
+        kw, monkeypatch, interpreted_kernels):
+    """The flash kernels under a window (blocks of 32 x 64: a pair
+    whole inside a window of 96 or more, off the diagonal, builds no
+    mask), held to the same kernels with every pair masked, bit for
+    bit, and to the XLA mask above."""
+    pairs_without_a_mask_change_no_bit(interpreted_kernels, monkeypatch,
+                                       **kw)
 
 
 def test_window_larger_than_seq_is_full_attention():
