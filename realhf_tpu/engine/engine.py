@@ -205,6 +205,13 @@ class Engine:
                     "implemented for a model with sparse layers "
                     f"(layer_pattern '{cfg.pattern_string}'): the ring "
                     "takes no selection of keys")
+            if cfg.ssm_layers:
+                raise NotImplementedError(
+                    "context parallelism is not implemented for a model "
+                    "with ssm layers (layer_pattern "
+                    f"'{cfg.pattern_string}'): a row cut along its "
+                    "length hands no state-space state from one part to "
+                    "the next")
             from realhf_tpu.ops.ring_attention import ring_attention
             mesh = self.mesh
 
@@ -279,6 +286,17 @@ class Engine:
                     delta_heads=cfg.delta.n_heads,
                     delta_head_dim=cfg.delta.head_dim,
                     delta_chunk=CHUNK)
+            if cfg.ssm is not None:
+                from realhf_tpu.ops.ssm_scan import CHUNK
+                self._model_attrs.update(
+                    ssm_layers=len(cfg.ssm_layers),
+                    ssm_heads=cfg.ssm.n_heads,
+                    ssm_head_dim=cfg.ssm.head_dim,
+                    ssm_state=cfg.ssm.state, ssm_groups=cfg.ssm.n_groups,
+                    ssm_chunk=CHUNK)
+            if mode is not None and not cfg.gated_mlp:
+                self._model_attrs.update(
+                    expert_ff=f"{cfg.activation_function}/ungated")
             if cfg.indexer is not None:
                 self._model_attrs.update(
                     sparse_layers=len(cfg.sparse_layers),
@@ -498,7 +516,8 @@ class Engine:
         array is not read: all its positions count (pads are routed
         like tokens). ``conv_tokens_total{role}`` likewise: tokens x
         conv layers of a patterned model, ``delta_tokens_total{role}``
-        tokens x delta layers."""
+        tokens x delta layers, ``ssm_tokens_total{role}`` tokens x ssm
+        layers."""
         tokens = decode_tokens + (
             int(np.count_nonzero(seg_ids))
             if isinstance(seg_ids, np.ndarray) else int(seg_ids.size))
@@ -509,6 +528,10 @@ class Engine:
         if self.cfg.delta_layers:
             metrics.inc("delta_tokens_total",
                         tokens * len(self.cfg.delta_layers),
+                        role=str(self.ctx.model_name.role))
+        if self.cfg.ssm_layers:
+            metrics.inc("ssm_tokens_total",
+                        tokens * len(self.cfg.ssm_layers),
                         role=str(self.ctx.model_name.role))
         if "moe_dispatch" not in self._model_attrs:
             return
@@ -1264,6 +1287,12 @@ class Engine:
                     self.cfg, prompt_seg.shape[0])
                 self._last_span.set_attribute(
                     "delta_state_bytes",
+                    int(np.prod(tail)) * item + int(np.prod(state)) * 4)
+            if self.cfg.ssm_layers:
+                tail, state = T.ssm_state_shapes(
+                    self.cfg, prompt_seg.shape[0])
+                self._last_span.set_attribute(
+                    "ssm_state_bytes",
                     int(np.prod(tail)) * item + int(np.prod(state)) * 4)
             if self.cfg.sparse_layers:
                 b, lp = prompt_seg.shape

@@ -17,10 +17,21 @@ from typing import Dict, Optional, Tuple
 #: head keeps ONE state that every earlier token of the document went
 #: into (``DeltaConfig``); "sparse" is attention over the keys a learned
 #: indexer picks for the token among the earlier ones of its document,
-#: ``topk`` of them (``IndexerConfig``)
-OPERATORS = ("conv", "attention", "window", "latent", "delta", "sparse")
+#: ``topk`` of them (``IndexerConfig``); "ssm" is no attention either:
+#: the Mamba-2 state-space scan, a head's state [head_dim, state] under
+#: ONE decay a head (``SsmConfig``). A layer is ``x + mixer(norm(x))``
+#: then ``x + ff(norm(x))``; where a model's layers are a mixer OR a
+#: feed-forward alone, the part a layer lacks is ``ABSENT`` (the layer
+#: then has one norm, one part and one residual add)
+ABSENT = "none"
+OPERATORS = ("conv", "attention", "window", "latent", "delta", "sparse",
+             "ssm", ABSENT)
 ATTENTION_OPERATORS = ("attention", "window", "latent", "sparse")
-FEED_FORWARDS = ("dense", "moe")
+FEED_FORWARDS = ("dense", "moe", ABSENT)
+#: an operator's letter in ``TransformerConfig.pattern_string``
+OPERATOR_LETTERS = {"conv": "c", "attention": "a", "window": "w",
+                    "latent": "l", "delta": "d", "sparse": "s",
+                    "ssm": "m", ABSENT: "-"}
 
 
 @dataclasses.dataclass
@@ -179,6 +190,56 @@ class IndexerConfig:
 
 
 @dataclasses.dataclass
+class SsmConfig:
+    """The Mamba-2 state-space mixer (operator "ssm" of a layer
+    pattern). With u the layer's normed input, ``n`` heads of
+    ``head_dim`` (``width = n x head_dim``), ``g`` groups that share B
+    and C (head h reads group ``h // (n / g)``), ``conv`` a depthwise
+    causal convolution of ``conv_kernel`` taps WITH a bias that stops at
+    a document's first token::
+
+        [z | xBC | dt] = u w_in       widths width | width + 2 g state | n
+        xBC = SiLU(conv(xBC) + conv_bias)
+        x [n, head_dim], B [g, state], C [g, state] = split(xBC)
+        Delta = softplus(dt + dt_bias)                     [n] a token
+        S_t = exp(Delta_t A) S_{t-1} + Delta_t x_t B_t^T   A = -exp(a_log)
+        y_t = S_t C_t + D x_t           (``ops/ssm_scan.py``, chunked)
+        out = (GroupRMSNorm(y * SiLU(z); norm)) w_out
+
+    the gate FIRST, then each of the g groups of ``width / g`` values by
+    its own root mean square at the model's epsilon. The state S
+    [head_dim, state] a head is 0 before a document's first token and
+    is what decoding keeps (float32), beside the last ``conv_kernel -
+    1`` rows of the convolution's input."""
+    n_heads: int
+    head_dim: int
+    state: int
+    n_groups: int
+    conv_kernel: int = 4
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups or self.width % self.n_groups:
+            raise ValueError(
+                f"ssm: {self.n_heads} heads do not divide into "
+                f"{self.n_groups} groups")
+
+    @property
+    def width(self) -> int:
+        """All heads' values side by side (``d_inner``)."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C side by side."""
+        return self.width + 2 * self.n_groups * self.state
+
+    @property
+    def in_dim(self) -> int:
+        """``w_in``'s columns: z, xBC and dt."""
+        return self.width + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass
 class MoEConfig:
     """Mixture-of-experts settings (reference ``ReaLMoEConfig``)."""
     num_experts: int = 8
@@ -273,7 +334,7 @@ class TransformerConfig:
     resid_pdrop: float = 0.0
     attn_pdrop: float = 0.0
     layer_norm_epsilon: float = 1e-5
-    activation_function: str = "gelu"  # gelu | gelu_new | silu
+    activation_function: str = "gelu"  # gelu | gelu_new | silu | relu2
     scale_attn_by_inverse_layer_idx: bool = False
     scale_attn_weights: bool = True
     use_attention_bias: bool = True
@@ -319,7 +380,7 @@ class TransformerConfig:
     layer_q_heads: Optional[Tuple[int, ...]] = None
     # The rotary embedding by kind of layer: {"attention": ...,
     # "window": ...}; None for a kind says that its layers have NONE
-    # (latent layers only: queries and the shared key part go to the
+    # ("latent" and "attention" layers: queries and keys go to the
     # scores as they are). None: the model-wide ``rotary_*`` fields.
     rotary_by_operator: Optional[Dict[str, Optional[RotaryConfig]]] = None
     # One output gate a head: ``g = sigmoid(u W_g)`` [.., n heads] from
@@ -333,6 +394,8 @@ class TransformerConfig:
     delta: Optional[DeltaConfig] = None
     # The indexer of the pattern's "sparse" layers.
     indexer: Optional[IndexerConfig] = None
+    # What the "ssm" layers of the pattern are made of.
+    ssm: Optional[SsmConfig] = None
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -377,7 +440,8 @@ class TransformerConfig:
                     f"layer_pattern names {len(self.layer_pattern)} "
                     f"layers, n_layers is {self.n_layers}")
             for op, ff in self.layer_pattern:
-                if op not in OPERATORS or ff not in FEED_FORWARDS:
+                if op not in OPERATORS or ff not in FEED_FORWARDS \
+                        or op == ff == ABSENT:
                     raise NotImplementedError(
                         f"layer ({op!r}, {ff!r}) of layer_pattern")
             if self.mlp_type == "moe":
@@ -392,24 +456,34 @@ class TransformerConfig:
                     op == "window" for op, _ in self.layer_pattern):
                 raise ValueError("layer_pattern has window layers, "
                                  "sliding_window is None")
-            if not (self.layer_norm_type == "rms" and self.gated_mlp
+            # (an ungated feed-forward, ``mlp_type`` None, has no bias)
+            if not (self.layer_norm_type == "rms"
+                    and (self.gated_mlp or not self.use_mlp_bias)
                     and self.apply_rotary
                     and not self.use_attention_bias
                     and not self.use_attn_proj_bias
                     and not self.scale_attn_by_inverse_layer_idx):
                 raise NotImplementedError(
-                    "a layer_pattern model is RMSNorm, rotary, gated "
-                    "feed-forward, without biases or per-layer "
-                    "attention scale")
+                    "a layer_pattern model is RMSNorm, rotary, without "
+                    "biases or per-layer attention scale")
         if self.layer_pattern is None and (
                 self.layer_q_heads is not None
                 or self.rotary_by_operator is not None
                 or self.attn_output_gate or self.latent is not None
-                or self.delta is not None or self.indexer is not None):
+                or self.delta is not None or self.indexer is not None
+                or self.ssm is not None):
             raise NotImplementedError(
                 "layer_q_heads, rotary_by_operator, attn_output_gate, "
-                "latent, delta and indexer belong to a model with a "
-                "layer_pattern")
+                "latent, delta, indexer and ssm belong to a model with "
+                "a layer_pattern")
+        if self.activation_function == "relu2" and self.gated_mlp:
+            raise NotImplementedError(
+                "relu2 is an UNGATED feed-forward's activation "
+                "(mlp_type None)")
+        if (self.ssm is not None) != bool(self.ssm_layers):
+            raise ValueError(
+                f"layer_pattern has {len(self.ssm_layers)} ssm layers, "
+                f"ssm is {self.ssm}")
         if (self.indexer is not None) != bool(self.sparse_layers):
             raise ValueError(
                 f"layer_pattern has {len(self.sparse_layers)} sparse "
@@ -460,7 +534,8 @@ class TransformerConfig:
                        if op in ATTENTION_OPERATORS} \
                 - set(self.rotary_by_operator)
             missing |= {op for op, rc in self.rotary_by_operator.items()
-                        if rc is None and op != "latent"}
+                        if rc is None and op not in ("latent",
+                                                     "attention")}
             if missing or self.rotary_interleaved:
                 raise ValueError(
                     f"rotary_by_operator lacks {sorted(missing)} (and "
@@ -507,6 +582,12 @@ class TransformerConfig:
         """The layers that keep a delta-rule state a head."""
         return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
                      if op == "delta")
+
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a state-space state a head."""
+        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
+                     if op == "ssm")
 
     @property
     def sparse_layers(self) -> Tuple[int, ...]:
@@ -570,9 +651,11 @@ class TransformerConfig:
     @property
     def pattern_string(self) -> str:
         """``c a c c c``, ``a w w w a``, ``l l l``, ``d d d l d``,
-        ``s s s``: every layer's operator by its first letter (conv,
-        attention, window, latent, delta, sparse)."""
-        return " ".join(op[0] for op, _ in self.layer_kinds)
+        ``s s s``, ``- m - m - m a``: every layer's operator by its
+        letter (``OPERATOR_LETTERS``: conv, attention, window, latent,
+        delta, sparse, ssm as ``m``; ``-`` a layer that is a
+        feed-forward alone)."""
+        return " ".join(OPERATOR_LETTERS[op] for op, _ in self.layer_kinds)
 
     def require_one_block(self, what: str):
         """Refuse, by name, what only runs a model of one kind of
@@ -588,7 +671,8 @@ class TransformerConfig:
                 f"{len(self.delta_layers)} delta layers that keep a "
                 f"state a head, {len(self.sparse_layers)} whose keys an "
                 f"indexer picks, {self.n_moe_layers} layers with "
-                "experts)")
+                f"experts, {len(self.ssm_layers)} ssm layers that keep a "
+                "state-space state a head)")
 
     def n_params(self) -> int:
         """Approximate parameter count (for FLOPs/memory estimates),
@@ -597,8 +681,11 @@ class TransformerConfig:
         the experts HELD, the shared expert, the query/key norms and
         the output gate, each attention layer at its own count of
         query heads, a latent layer's five leaves, a delta layer's
-        fifteen, a sparse layer's indexer; biases and the layer norms'
-        scales are left out."""
+        fifteen, a sparse layer's indexer, an ssm layer's seven (its
+        convolution's bias among them); an ungated feed-forward's two
+        matrices where ``mlp_type`` is None; a part a layer lacks
+        counts nothing; other biases and the layer norms' scales are
+        left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
 
         def attn(i):
@@ -629,20 +716,27 @@ class TransformerConfig:
             delta = 4 * h * dl.width + 3 * dl.conv_kernel * dl.width \
                 + 2 * (h + dl.width) * dl.gate_rank + dl.width \
                 + h * dl.n_heads + dl.n_heads + dl.head_dim
-        dense = (3 if self.gated_mlp else 2) * h * f
+        ssm = 0
+        if self.ssm is not None:
+            sm = self.ssm
+            ssm = h * sm.in_dim + (sm.conv_kernel + 1) * sm.conv_dim \
+                + 3 * sm.n_heads + sm.width + sm.width * h
+        mats = 3 if self.gated_mlp else 2
+        dense = mats * h * f
         moe = 0
         if self.moe is not None:
             # the experts HELD, the router (and bias) over all of them
-            moe = 3 * h * (self.moe.intermediate_dim or f) \
+            moe = mats * h * (self.moe.intermediate_dim or f) \
                 * self.moe.n_held + h * self.moe.num_experts
             if self.moe.use_expert_bias:
                 moe += self.moe.num_experts
-            moe += 3 * h * (self.moe.shared_intermediate_dim or 0)
+            moe += mats * h * (self.moe.shared_intermediate_dim or 0)
         embed = v * h if self.tied_embedding else 2 * v * h
         if self.is_critic:
             embed = v * h + h
         return embed + sum(
             (conv if op == "conv" else delta if op == "delta"
+             else ssm if op == "ssm" else 0 if op == ABSENT
              else attn(i))
-            + (moe if ff == "moe" else dense)
+            + (moe if ff == "moe" else 0 if ff == ABSENT else dense)
             for i, (op, ff) in enumerate(self.layer_kinds))

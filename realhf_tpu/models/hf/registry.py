@@ -65,16 +65,17 @@ def held_expert_ids(cfg: TransformerConfig) -> range:
 
 
 def layered_converters(layer_from_hf, layer_to_hf,
-                       final_norm: str = "model.norm.weight"):
+                       final_norm: str = "model.norm.weight",
+                       embed: str = "model.embed_tokens.weight"):
     """``(params_from_hf, params_to_hf)`` of a family that converts a
     LAYER at a time (``TransformerConfig.layer_pattern``): the
-    embedding, the final norm under ``final_norm`` and the head around
-    the family's own layers."""
+    embedding under ``embed``, the final norm under ``final_norm`` and
+    the head around the family's own layers."""
 
     def params_from_hf(state: StateDict,
                        cfg: TransformerConfig) -> Dict[str, Any]:
         params: Dict[str, Any] = {
-            "embed": {"wte": state["model.embed_tokens.weight"]},
+            "embed": {"wte": state[embed]},
             "layers": {str(i): layer_from_hf(state, cfg, i)
                        for i in range(cfg.n_layers)},
             "ln_f": {"scale": state[final_norm]},
@@ -86,8 +87,7 @@ def layered_converters(layer_from_hf, layer_to_hf,
     def params_to_hf(params: Dict[str, Any],
                      cfg: TransformerConfig) -> StateDict:
         out: StateDict = {
-            "model.embed_tokens.weight": np.ascontiguousarray(
-                params["embed"]["wte"]),
+            embed: np.ascontiguousarray(params["embed"]["wte"]),
             final_norm: np.ascontiguousarray(params["ln_f"]["scale"])}
         for i in range(cfg.n_layers):
             layer_to_hf(params["layers"][str(i)], cfg, i, out)

@@ -22,7 +22,7 @@ from typing import Any, Dict
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.models.config import ABSENT, TransformerConfig
 from realhf_tpu.parallel.mesh import CTX_AXIS, DATA_AXIS, MODEL_AXIS, PIPE_AXIS
 
 
@@ -109,10 +109,26 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
     channel, ``w_out`` by row."""
     col, row = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
     layers = {}
+    # an ungated feed-forward (``mlp_type`` None) has no ``wg``
+    gate = {"wg": col} if cfg.gated_mlp else {}
     for i, (op, ff) in enumerate(cfg.layer_pattern):
-        lp: Dict[str, Any] = {"ln1": {"scale": P(None)},
-                              "ln2": {"scale": P(None)}}
-        if op == "conv":
+        # (a part a layer lacks has no norm either)
+        lp: Dict[str, Any] = {name: {"scale": P(None)}
+                              for name, part in (("ln1", op), ("ln2", ff))
+                              if part != ABSENT}
+        if op == ABSENT:
+            pass
+        elif op == "ssm":
+            # by head, and a group's B and C with its heads: ``w_in``
+            # by column (GSPMD moves z, x, B, C and dt to a sharding by
+            # head and group after the split), the taps and the bias by
+            # channel, the three leaves a head, the grouped norm's
+            # scale by its width, ``w_out`` by row
+            lp["ssm"] = {"w_in": col, "conv": col,
+                         "conv_bias": P(MODEL_AXIS), "a_log": P(MODEL_AXIS),
+                         "dt_bias": P(MODEL_AXIS), "d": P(MODEL_AXIS),
+                         "norm": P(MODEL_AXIS), "w_out": row}
+        elif op == "conv":
             lp["conv"] = {"w_in": col, "w": col, "w_out": row}
         elif op == "delta":
             # by head: the projections' columns, the convolutions'
@@ -149,15 +165,16 @@ def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
                                "w_weights": P(None, None)}
         if ff == "moe":
             lp["mlp"] = {"router": P(None, None),
-                         "wg": P(None, None, MODEL_AXIS),
                          "wu": P(None, None, MODEL_AXIS),
                          "wd": P(None, MODEL_AXIS, None)}
+            if cfg.gated_mlp:
+                lp["mlp"]["wg"] = P(None, None, MODEL_AXIS)
             if cfg.moe.use_expert_bias:
                 lp["mlp"]["expert_bias"] = P(None)
             if cfg.moe.shared_intermediate_dim is not None:
-                lp["mlp"]["shared"] = {"wg": col, "wu": col, "wd": row}
-        else:
-            lp["mlp"] = {"wg": col, "wu": col, "wd": row}
+                lp["mlp"]["shared"] = {**gate, "wu": col, "wd": row}
+        elif ff != ABSENT:
+            lp["mlp"] = {**gate, "wu": col, "wd": row}
         layers[str(i)] = lp
     specs: Dict[str, Any] = {"embed": {"wte": P(MODEL_AXIS, None)},
                              "layers": layers,

@@ -48,6 +48,17 @@ Design (idiomatic JAX, not a torch translation):
   the indexer's keys are a THIRD kind of attention cache
   (``cache["index_k"]``, one ``head_dim``-wide row a token a layer)
   beside K and V, which decoding scores, selects from and attends over.
+  Or a layer is the Mamba-2 state-space mixer (operator "ssm",
+  ``SsmConfig``): a head's state [head_dim, state] under one decay a
+  head, chunked over the row by ``ops/ssm_scan.py``; decoding carries
+  it in float32 (``cache["ssm"]``) beside the last rows of its ONE
+  convolution's input (``cache["ssm_conv"]``), a FOURTH kind of decode
+  state. And a layer may be a mixer OR a feed-forward ALONE (the other
+  part ``ABSENT`` in its pattern entry): it then holds one norm
+  (``ln1`` a mixer's, ``ln2`` a feed-forward's) and the leaves of its
+  one part, and ``_block`` runs that part and its residual add only.
+  A feed-forward without a gate (``mlp_type`` None: ``wu`` and ``wd``
+  alone, dense, shared or an expert's) is two products.
   A model of one block takes none of these paths.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
@@ -63,7 +74,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from realhf_tpu.base.backend import pallas_enabled
-from realhf_tpu.models.config import (DELTA_L2_EPS, INDEX_NORM_EPS,
+from realhf_tpu.models.config import (ABSENT, DELTA_L2_EPS, INDEX_NORM_EPS,
                                       LATENT_NORM_EPS, TransformerConfig)
 from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.attention import decode_attention, packed_attention
@@ -74,6 +85,7 @@ from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES, SELECT_RESIDUAL
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 from realhf_tpu.ops.sparse_index import (index_scores, select_topk,
                                          selection_mask)
+from realhf_tpu.ops.ssm_scan import chunked_ssm_scan, ssm_step
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
@@ -99,12 +111,18 @@ PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
 #: path names the output alone: its own backward runs it again a
 #: segment at a time.)
 DELTA_RESIDUALS = ("delta_out",) + _SCAN_RESIDUALS
+#: What an ssm layer's chunked scan made (``_ssm_op``): its heads'
+#: outputs before the gate, ``tokens x width`` values a layer a
+#: microbatch in the compute dtype; with the projected output
+#: (``PROJECTION_RESIDUALS[1]``) ``tokens x (width + hidden) x 2``
+#: bytes in bf16.
+SSM_RESIDUALS = ("ssm_out",)
 #: every name the policy of a rematerialised block keeps; the last is
 #: a sparse layer's selection (int8, ``L x L`` bytes a row a layer):
 #: kept, the backward's kernels mask by it and the indexer, which no
 #: gradient reaches, does not run a second time
 KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS \
-    + (SELECT_RESIDUAL,)
+    + (SELECT_RESIDUAL,) + SSM_RESIDUALS
 
 
 # ----------------------------------------------------------------------
@@ -203,8 +221,8 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     proj_std = std / (2 * cfg.n_layers) ** 0.5
     # a delta layer has more leaves than 16; the others keep the keys
     # they have always drawn
-    per_layer = 24 if cfg.delta is not None or cfg.indexer is not None \
-        else 16
+    per_layer = 16 if cfg.delta is None and cfg.indexer is None \
+        and cfg.ssm is None else 24
     keys = iter(jax.random.split(key, per_layer * cfg.n_layers + 4))
 
     def norm(shape, s=std):
@@ -213,10 +231,24 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     def ones(shape):
         return jnp.ones(shape, dtype=pdt)
 
+    def ffn(f, lead=()):
+        # a feed-forward's matrices, gated or not, [*lead, ...]
+        gate = {"wg": norm((*lead, h, f))} if cfg.gated_mlp else {}
+        return {**gate, "wu": norm((*lead, h, f)),
+                "wd": norm((*lead, f, h), proj_std)}
+
     layers = {}
     for i, (op, ff) in enumerate(cfg.layer_pattern):
-        lp = {"ln1": {"scale": ones((h,))}, "ln2": {"scale": ones((h,))}}
-        if op == "conv":
+        # a part a layer lacks has no norm either
+        lp = {name: {"scale": ones((h,))}
+              for name, part in (("ln1", op), ("ln2", ff))
+              if part != ABSENT}
+        if op == ABSENT:
+            pass
+        elif op == "ssm":
+            lp["ssm"] = _init_ssm(cfg, norm, ones, next(keys), pdt,
+                                  proj_std)
+        elif op == "conv":
             lp["conv"] = {"w_in": norm((h, 3 * h)),
                           "w": norm((cfg.conv_kernel, h)),
                           "w_out": norm((h, h), proj_std)}
@@ -255,19 +287,14 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         if ff == "moe":
             ne, nh = cfg.moe.num_experts, cfg.moe.n_held
             fe = cfg.moe.intermediate_dim or f
-            lp["mlp"] = {"router": norm((h, ne)),
-                         "wg": norm((nh, h, fe)), "wu": norm((nh, h, fe)),
-                         "wd": norm((nh, fe, h), proj_std)}
+            lp["mlp"] = {"router": norm((h, ne)), **ffn(fe, (nh,))}
             if cfg.moe.use_expert_bias:
                 lp["mlp"]["expert_bias"] = jnp.zeros((ne,), pdt)
             fs = cfg.moe.shared_intermediate_dim
             if fs is not None:
-                lp["mlp"]["shared"] = {
-                    "wg": norm((h, fs)), "wu": norm((h, fs)),
-                    "wd": norm((fs, h), proj_std)}
-        else:
-            lp["mlp"] = {"wg": norm((h, f)), "wu": norm((h, f)),
-                         "wd": norm((f, h), proj_std)}
+                lp["mlp"]["shared"] = ffn(fs)
+        elif ff != ABSENT:
+            lp["mlp"] = ffn(f)
         layers[str(i)] = lp
     params: Params = {"embed": {"wte": norm((v, h))}, "layers": layers,
                       "ln_f": {"scale": ones((h,))}}
@@ -302,6 +329,27 @@ def _init_delta(cfg, norm, ones, key, pdt, proj_std) -> Params:
         "w_ga": norm((h, r)), "w_gb": norm((r, w)),
         "o_norm": ones((dl.head_dim,)),
         "wo": norm((w, h), proj_std)}
+
+
+def _init_ssm(cfg, norm, ones, key, pdt, proj_std) -> Params:
+    """An ssm layer's leaves (``SsmConfig`` has the equations), the
+    decay's as published: ``a_log = log U(1, 16)`` a head, ``dt_bias``
+    the inverse softplus of a step drawn log-uniformly from [1e-3,
+    1e-1], D = 1."""
+    h, sm = cfg.hidden_dim, cfg.ssm
+    ka, kd = jax.random.split(key)
+    dt = jnp.exp(jax.random.uniform(
+        kd, (sm.n_heads,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    return {
+        "w_in": norm((h, sm.in_dim)),
+        "conv": norm((sm.conv_kernel, sm.conv_dim)),
+        "conv_bias": jnp.zeros((sm.conv_dim,), pdt),
+        "a_log": jnp.log(jax.random.uniform(
+            ka, (sm.n_heads,), minval=1.0, maxval=16.0)).astype(pdt),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+        "d": ones((sm.n_heads,)),
+        "norm": ones((sm.width,)),
+        "w_out": norm((sm.width, h), proj_std)}
 
 
 # ----------------------------------------------------------------------
@@ -342,6 +390,8 @@ def _activation(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
         return jax.nn.gelu(x, approximate=False)
     if cfg.activation_function == "gelu_new":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.activation_function == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise NotImplementedError(cfg.activation_function)
 
 
@@ -479,7 +529,9 @@ def _rotated_qkv(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
     if op == "latent":
         return _latent_qkv(cfg, lp, x, cos, sin)
     q, k, v = _qkv(cfg, lp, x)
-    if cfg.apply_rotary:
+    # (a kind of layer WITHOUT a rotary embedding has no table: its
+    # queries and keys go to the scores as they are)
+    if cfg.apply_rotary and cos is not None:
         interleaved = cfg.rotary_of(op).interleaved
         q = apply_rotary(q, cos, sin, interleaved)
         k = apply_rotary(k, cos, sin, interleaved)
@@ -536,6 +588,16 @@ def _short_conv_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
     acc = (window.astype(jnp.float32)
            * c["w"].astype(jnp.float32)[None]).sum(axis=1)
     return (g * acc.astype(cdt)) @ c["w_out"].astype(cdt), window[:, 1:]
+
+
+def _conv_step(tail: jnp.ndarray, s: jnp.ndarray,
+               taps: jnp.ndarray) -> jnp.ndarray:
+    """One token of :func:`_causal_conv`: the stream's last ``K - 1``
+    rows of the convolution's input, oldest first [B, K-1, C], the
+    token's s [B, C] and taps [K, C] -> [B, C] in float32."""
+    window = jnp.concatenate([tail, s[:, None].astype(tail.dtype)], axis=1)
+    return (window.astype(jnp.float32)
+            * taps.astype(jnp.float32)[None]).sum(axis=1)
 
 
 _DELTA_CONVS = (("wq", "conv_q"), ("wk", "conv_k"), ("wv", "conv_v"))
@@ -609,11 +671,7 @@ def _delta_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
     width = cfg.delta.width
 
     def conv(i, x, taps):
-        window = jnp.concatenate(
-            [tail[..., i * width:(i + 1) * width],
-             x[:, None].astype(tail.dtype)], axis=1)
-        return (window.astype(jnp.float32)
-                * taps.astype(jnp.float32)[None]).sum(axis=1)
+        return _conv_step(tail[..., i * width:(i + 1) * width], x, taps)
 
     raw, q, k, v, f, beta, prepare = _delta_inputs(cfg, c, u, conv)
     with jax.named_scope(P.SCAN):
@@ -622,6 +680,82 @@ def _delta_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
     tail = jnp.concatenate([tail[:, 1:], raw[:, None].astype(tail.dtype)],
                            axis=1)
     return _delta_output(cfg, c, u, o.astype(u.dtype)), tail, state
+
+
+def _ssm_inputs(cfg: TransformerConfig, c: Params, u: jnp.ndarray, conv):
+    """What the scan takes of an ssm layer's normed input u [..., H]
+    (``SsmConfig`` has the equations): (the convolution's input
+    [..., conv_dim]; the gate z [..., width]; x [..., n, hd], B and C
+    [..., g, state] after convolution, bias and SiLU, and the step's
+    pre-activation dt [..., n], in the compute dtype). ``conv``: (input
+    [..., conv_dim], taps [K, conv_dim]) -> the convolution's output in
+    float32."""
+    cdt, sm = u.dtype, cfg.ssm
+    lead = u.shape[:-1]
+    z, raw, dt = jnp.split(u @ c["w_in"].astype(cdt),
+                           [sm.width, sm.width + sm.conv_dim], axis=-1)
+    xbc = jax.nn.silu(conv(raw, c["conv"])
+                      + c["conv_bias"].astype(jnp.float32)).astype(cdt)
+    x, b, cc = jnp.split(
+        xbc, [sm.width, sm.width + sm.n_groups * sm.state], axis=-1)
+    return (raw, z, x.reshape(*lead, sm.n_heads, sm.head_dim),
+            b.reshape(*lead, sm.n_groups, sm.state),
+            cc.reshape(*lead, sm.n_groups, sm.state), dt)
+
+
+def _ssm_leaves(c: Params):
+    """The scan's three leaves a head in float32: the decay's rate
+    ``-exp(a_log)``, the step's bias, D."""
+    f32 = jnp.float32
+    return dict(rate=-jnp.exp(c["a_log"].astype(f32)),
+                dt_bias=c["dt_bias"].astype(f32), skip=c["d"].astype(f32))
+
+
+def _ssm_output(cfg: TransformerConfig, c: Params, z: jnp.ndarray,
+                y: jnp.ndarray) -> jnp.ndarray:
+    """The heads' outputs y [..., n, hd] gated by SiLU(z) FIRST, then
+    each of the ``n_groups`` groups of the width normed by its own root
+    mean square (float32), scaled and projected: [..., H]."""
+    f32, sm = jnp.float32, cfg.ssm
+    y = y.reshape(z.shape).astype(f32) * jax.nn.silu(z.astype(f32))
+    grouped = y.reshape(*z.shape[:-1], sm.n_groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(jnp.square(grouped), -1, keepdims=True)
+        + cfg.layer_norm_epsilon)
+    y = grouped.reshape(z.shape) * c["norm"].astype(f32)
+    return y.astype(z.dtype) @ c["w_out"].astype(z.dtype)
+
+
+def _ssm_op(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+            seg_ids: jnp.ndarray):
+    """The ssm operator over packed rows on the normed residual u
+    [B, L, H] -> (its projected output [B, L, H], (the convolution's
+    input [B, L, conv_dim], each row's state after its last token
+    [B, n, hd, state] float32)): what prefill's caches are made of. The
+    recurrence alone is sub-part ``ssm/scan`` (obs/parts.py)."""
+    raw, z, x, b, cc, dt = _ssm_inputs(
+        cfg, c, u, lambda s, taps: _causal_conv(s, taps, seg_ids))
+    with jax.named_scope(P.SCAN):
+        y, last = chunked_ssm_scan(x, dt, b, cc, seg_ids, **_ssm_leaves(c))
+        y = checkpoint_name(y, SSM_RESIDUALS[0])
+    proj = checkpoint_name(_ssm_output(cfg, c, z, y),
+                           PROJECTION_RESIDUALS[1])
+    return proj, (raw, last)
+
+
+def _ssm_step(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
+              tail: jnp.ndarray, state: jnp.ndarray):
+    """One token of :func:`_ssm_op`: u [B, H], the stream's last
+    ``conv_kernel - 1`` rows of the convolution's input, oldest first
+    [B, K-1, conv_dim], and its state [B, n, hd, state] -> (output
+    [B, H], the tail and the state moved on by the token)."""
+    raw, z, x, b, cc, dt = _ssm_inputs(
+        cfg, c, u, lambda s, taps: _conv_step(tail, s, taps))
+    with jax.named_scope(P.SCAN):
+        y, state = ssm_step(x, dt, b, cc, state, **_ssm_leaves(c))
+    tail = jnp.concatenate([tail[:, 1:], raw[:, None].astype(tail.dtype)],
+                           axis=1)
+    return _ssm_output(cfg, c, z, y.astype(u.dtype)), tail, state
 
 
 def _attn_scale(cfg: TransformerConfig, layer_idx: jnp.ndarray) -> jnp.ndarray:
@@ -738,27 +872,40 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     attention layer (and the indexer's keys of a sparse one, whose
     table is ``index_rotary``), the convolution's input s [B, L, H] of a conv
     layer, (the convolutions' inputs, the rows' last states) of a
-    delta layer; aux is non-empty for MoE. ``mesh``: ``forward``'s."""
+    delta layer, (the convolution's input, the rows' last states) of an
+    ssm layer, None of a layer without an operator; aux is non-empty
+    for MoE. ``mesh``: ``forward``'s. A part the layer's kind says is
+    ``ABSENT`` is not run: the layer is its one part, that part's norm
+    and one residual add."""
     op, sparse = ("attention", None) if kind is None \
         else (kind[0], kind[1] == "moe")
-    # the norm before an operator and the residual's add after it go
-    # with the operator's projections, those around the feed-forward
-    # with the feed-forward (obs/parts.py)
-    mixer = {"conv": P.CONV, "delta": P.DELTA}.get(op, P.ATTN_PROJ)
-    with jax.named_scope(mixer):
-        ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
-    if op == "conv":
-        with jax.named_scope(P.CONV):
-            proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
-    elif op == "delta":
-        with jax.named_scope(P.DELTA):
-            proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids, mesh)
-    else:
-        proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
-                                    cos, sin, attention_fn, window, op,
-                                    index_rotary)
-    with jax.named_scope(mixer):
-        x = constrain(x + proj)
+    state = None
+    if op != ABSENT:
+        # the norm before an operator and the residual's add after it
+        # go with the operator's projections, those around the
+        # feed-forward with the feed-forward (obs/parts.py)
+        mixer = {"conv": P.CONV, "delta": P.DELTA, "ssm": P.SSM}.get(
+            op, P.ATTN_PROJ)
+        with jax.named_scope(mixer):
+            ln1 = _norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+        if op == "conv":
+            with jax.named_scope(P.CONV):
+                proj, state = _short_conv(cfg, lp["conv"], ln1, seg_ids)
+        elif op == "delta":
+            with jax.named_scope(P.DELTA):
+                proj, state = _delta_op(cfg, lp["delta"], ln1, seg_ids,
+                                        mesh)
+        elif op == "ssm":
+            with jax.named_scope(P.SSM):
+                proj, state = _ssm_op(cfg, lp["ssm"], ln1, seg_ids)
+        else:
+            proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
+                                        cos, sin, attention_fn, window,
+                                        op, index_rotary)
+        with jax.named_scope(mixer):
+            x = constrain(x + proj)
+    if kind is not None and kind[1] == ABSENT:
+        return x, state, {}
     ff = _ff_part(cfg, sparse)
     with jax.named_scope(ff):
         ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
@@ -1023,10 +1170,13 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
     stacked over the CONV layers [n_conv, B, L, H], and of the DELTA
     layers their convolutions' inputs [n_delta, B, L, 3 x width] and
     the rows' last states [n_delta, B, n, hd, hd], of the SPARSE
-    layers their indexer's keys [n_sparse, B, L, d]; None unless
+    layers their indexer's keys [n_sparse, B, L, d], of the SSM layers
+    their convolution's input [n_ssm, B, L, conv_dim] and the rows'
+    last states [n_ssm, B, n, hd, state]; None unless
     ``return_kv``. ``aux``: the sparse layers' entries reduced as
     ``ops.moe.reduce_layers`` does; ``{}`` unless ``return_aux``."""
     ks, vs, convs, tails, deltas, index_ks, auxs = [], [], [], [], [], [], []
+    ssm_tails, ssms = [], []
     # (the indexer's table only where there is one: every other model's
     # blocks are called as they were)
     more = {} if cfg.indexer is None else dict(
@@ -1042,9 +1192,10 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
         x, state, aux = _remat(cfg, block_fn)(layers[str(i)], x)
         if return_kv and kind[0] == "conv":
             convs.append(state)
-        elif return_kv:
-            first, second = (tails, deltas) if kind[0] == "delta" \
-                else (ks, vs)
+        elif return_kv and kind[0] != ABSENT:
+            first, second = {"delta": (tails, deltas),
+                             "ssm": (ssm_tails, ssms)}.get(
+                                 kind[0], (ks, vs))
             first.append(state[0])
             second.append(state[1])
             if kind[0] == "sparse":
@@ -1058,7 +1209,9 @@ def _pattern_layers(cfg, layers, x, seg_ids, rotary, constrain,
                                      ("conv", convs),
                                      ("delta_conv", tails),
                                      ("delta", deltas),
-                                     ("index_k", index_ks))}
+                                     ("index_k", index_ks),
+                                     ("ssm_conv", ssm_tails),
+                                     ("ssm", ssms))}
     aux = {}
     if return_aux and auxs:
         from realhf_tpu.ops.moe import reduce_layers
@@ -1146,6 +1299,10 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     if cfg.sparse_layers:
         cache["index_k"] = jnp.zeros(
             index_cache_shape(cfg, batch, max_len), dtype)
+    if cfg.ssm_layers:
+        tail, state = ssm_state_shapes(cfg, batch)
+        cache["ssm_conv"] = jnp.zeros(tail, dtype)
+        cache["ssm"] = jnp.zeros(state, jnp.float32)
     return cache
 
 
@@ -1172,6 +1329,17 @@ def delta_state_shapes(cfg: TransformerConfig, batch: int):
     n = len(cfg.delta_layers)
     return ((n, batch, dl.conv_kernel - 1, 3 * dl.width),
             (n, batch, dl.n_heads, dl.head_dim, dl.head_dim))
+
+
+def ssm_state_shapes(cfg: TransformerConfig, batch: int):
+    """The ssm layers' decode state: for each ssm layer and stream (the
+    last ``conv_kernel - 1`` rows of its convolution's input, x, B and
+    C side by side, in the cache's dtype; a head's state [hd, state],
+    in float32)."""
+    sm = cfg.ssm
+    n = len(cfg.ssm_layers)
+    return ((n, batch, sm.conv_kernel - 1, sm.conv_dim),
+            (n, batch, sm.n_heads, sm.head_dim, sm.state))
 
 
 def prefill(cfg: TransformerConfig, params: Params, input_ids: jnp.ndarray,
@@ -1225,6 +1393,10 @@ def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
             more["delta_conv"] = tails(
                 kvs["delta_conv"], cfg.delta.conv_kernel).astype(dtype)
             more["delta"] = kvs["delta"]
+        if kvs["ssm"] is not None:
+            more["ssm_conv"] = tails(
+                kvs["ssm_conv"], cfg.ssm.conv_kernel).astype(dtype)
+            more["ssm"] = kvs["ssm"]
         if kvs["index_k"] is not None:  # [n_sparse, B, L, d], by slot
             pad = round_cache_len(
                 total_len if total_len is not None else lp) - lp
@@ -1437,6 +1609,9 @@ def decode_step(
 
     def _ff_step(x, lp, sparse):
         # the norm, the feed-forward and the residual's add, one part
+        # (a layer that is a mixer alone holds no "mlp" and has none)
+        if "mlp" not in lp:
+            return x
         with jax.named_scope(_ff_part(cfg, sparse)):
             ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
             return x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
@@ -1444,15 +1619,33 @@ def decode_step(
     k_all, v_all = cache["k"], cache["v"]
     index_all = cache.get("index_k")
     new_conv, new_tails, new_deltas = [], [], []
+    new_ssm_tails, new_ssms = [], []
     if cfg.layer_pattern is not None:
         # a layer of the pattern at a time: an attention layer reads
         # and writes ITS slice of the K/V stack (the stack holds the
         # attention layers alone, window layers with EVERY row: the
         # kernel masks what is past the window), a conv layer its two
         # rows of state, a delta layer its heads' states and the
-        # tails of its three convolutions
+        # tails of its three convolutions, an ssm layer its heads'
+        # states and its convolution's tail; a layer without an
+        # operator is its feed-forward
         for i, (op, ff) in enumerate(cfg.layer_pattern):
             lp = params["layers"][str(i)]
+            if op == ABSENT:
+                x = _ff_step(x, lp, ff == "moe")
+                continue
+            if op == "ssm":
+                with jax.named_scope(P.SSM):
+                    ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
+                    at = len(new_ssms)
+                    proj, tail, state = _ssm_step(
+                        cfg, lp["ssm"], ln1, cache["ssm_conv"][at],
+                        cache["ssm"][at])
+                    new_ssm_tails.append(tail)
+                    new_ssms.append(state)
+                    x = x + proj
+                x = _ff_step(x, lp, ff == "moe")
+                continue
             if op == "delta":
                 with jax.named_scope(P.DELTA):
                     ln1 = _norm(cfg, x, lp["ln1"]["scale"], None)
@@ -1512,4 +1705,8 @@ def decode_step(
             new_cache["delta"] = jnp.stack(new_deltas)
     if index_all is not None:
         new_cache["index_k"] = index_all
+    if new_ssms:
+        with jax.named_scope(P.SSM):
+            new_cache["ssm_conv"] = jnp.stack(new_ssm_tails)
+            new_cache["ssm"] = jnp.stack(new_ssms)
     return x, new_cache

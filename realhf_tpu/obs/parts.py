@@ -39,6 +39,10 @@ CONV = "conv"
 #: before it, its projections, short convolutions and gates, the
 #: output's norm and gate, ``wo``; the recurrence is its sub-scope
 DELTA = "delta"
+#: an ssm layer's mixer (models/config.py:SsmConfig): the norm before
+#: it, ``w_in``, its short convolution, the gate and the grouped norm,
+#: ``w_out``; the chunked scan is its sub-scope
+SSM = "ssm"
 #: a sparse layer's indexer (models/config.py:IndexerConfig): what
 #: picks the keys its attention runs over; three sub-scopes
 INDEX = "index"
@@ -54,7 +58,7 @@ OPTIMIZER = "optimizer"
 LAYERS = "layers"
 #: what a device operation can be put down to: the INNERMOST of these
 #: in its ``op_name`` is its part
-PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, INDEX, MLP,
+PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, SSM, INDEX, MLP,
          SHARED_EXPERT, EXPERTS, VOCAB_HEAD, LOSS, GRAD_ACCUM, OPTIMIZER)
 
 FORWARD_BACKWARD = "forward_backward"
@@ -72,8 +76,9 @@ EXPERT_STEPS = (ROUTE, GATHER, PRODUCTS, COMBINE)
 #: expansion a head, the shared rotary key's rotation and broadcast);
 #: the part then reads ``attn_proj/latent``
 LATENT = "latent"
-#: sub-scope of ``delta``: the chunked recurrence alone
-#: (``ops/delta_rule.py``); the part then reads ``delta/scan``
+#: sub-scope of ``delta`` and of ``ssm``: the chunked recurrence alone
+#: (``ops/delta_rule.py``, ``ops/ssm_scan.py``); the part then reads
+#: ``delta/scan``, ``ssm/scan``
 SCAN = "scan"
 PROJECT, SCORES, SELECT = "project", "scores", "select"
 #: sub-scopes of ``index``: the indexer's projections, norm and rotary
@@ -83,7 +88,7 @@ PROJECT, SCORES, SELECT = "project", "scores", "select"
 INDEX_STEPS = (PROJECT, SCORES, SELECT)
 #: part -> the sub-scopes that may stand inside it
 SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,),
-             INDEX: INDEX_STEPS}
+             SSM: (SCAN,), INDEX: INDEX_STEPS}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: the pass of an operation whose ``op_name`` the compiler wrote

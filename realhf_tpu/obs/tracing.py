@@ -93,7 +93,7 @@ CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "moe_held_pairs_total", "conv_tokens_total",
                     "moe_share_overflow_total", "delta_tokens_total",
                     "sparse_pairs_total", "index_tokens_total",
-                    "index_blocks_total")
+                    "index_blocks_total", "ssm_tokens_total")
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",

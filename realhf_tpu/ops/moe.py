@@ -208,13 +208,18 @@ def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
                 ) -> jnp.ndarray:
     """Batched expert MLP of the dense and capacity modes: xs
     [E, C, H] -> [E, C, H] through stacked [E, H, F] weights, one
-    batched einsum per projection (every expert over C rows)."""
+    batched einsum per projection (every expert over C rows): three
+    where the feed-forward is gated, ``act(gate) * up``, two where it
+    is not, ``act(up)``."""
     from realhf_tpu.models.transformer import _activation
     cdt = xs.dtype
-    gate = jnp.einsum("ech,ehf->ecf", xs, m["wg"].astype(cdt))
     up = jnp.einsum("ech,ehf->ecf", xs, m["wu"].astype(cdt))
-    return jnp.einsum("ecf,efh->ech", _activation(cfg, gate) * up,
-                      m["wd"].astype(cdt))
+    if cfg.gated_mlp:
+        gate = jnp.einsum("ech,ehf->ecf", xs, m["wg"].astype(cdt))
+        mid = _activation(cfg, gate) * up
+    else:
+        mid = _activation(cfg, up)
+    return jnp.einsum("ecf,efh->ech", mid, m["wd"].astype(cdt))
 
 
 def dispatch_mode(cfg: TransformerConfig) -> Optional[str]:
@@ -237,8 +242,9 @@ SHARDED_STACKS = "sharded_stacks"
 def _grouped_products(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray,
                       sizes: jnp.ndarray, kernel: bool,
                       every_row_covered: bool = False) -> jnp.ndarray:
-    """The three grouped products of the sorted rows ``xs [rows, H]``
-    through the stacks of ``m``: ``sizes`` are the rows each expert's
+    """The grouped products of the sorted rows ``xs [rows, H]``
+    through the stacks of ``m`` (three of a gated feed-forward, two of
+    an ungated one): ``sizes`` are the rows each expert's
     group covers, and may add up to less than ``rows``. What becomes
     of the rows past them differs by path, so the group sizes each
     path is handed are built HERE: ``ops/grouped_matmul.py``'s kernels
@@ -256,6 +262,9 @@ def _grouped_products(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray,
         dot = jax.lax.ragged_dot
         if not every_row_covered:
             sizes = sizes.at[-1].add(xs.shape[0] - sizes.sum())
+    if not cfg.gated_mlp:
+        up = dot(xs, m["wu"].astype(cdt), sizes)
+        return dot(_activation(cfg, up), m["wd"].astype(cdt), sizes)
     gate = dot(xs, m["wg"].astype(cdt), sizes)
     up = dot(xs, m["wu"].astype(cdt), sizes)
     return dot(_activation(cfg, gate) * up, m["wd"].astype(cdt), sizes)
@@ -266,7 +275,8 @@ def grouped_product_calls(hlo_text: str) -> Dict[str, object]:
     from its optimized text (``Engine.compiled_text``): the custom
     calls that are ``ops/grouped_matmul.py``'s kernels
     (``moe_gmm_calls``: twelve a sparse layer of a train program, 3
-    forward, 3 rematerialised, 3 + 3 backward), those that are the
+    forward, 3 rematerialised, 3 + 3 backward; eight where the experts
+    are ungated, 2, 2 and 2 + 2), those that are the
     compiler's own ``ragged-dot`` kernel (``moe_ragged_dot_calls``;
     XLA:CPU has no such call: 0 there), and ``moe_products``: ``gmm``
     where the program holds a kernel, else ``ragged_dot``. A loop's
@@ -501,8 +511,8 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                          combine)
 
     if "shared" in m:
-        # the expert every token visits: a dense gated feed-forward
-        # beside the routed ones, weight 1, outside the sort. Every
+        # the expert every token visits: a dense feed-forward (gated
+        # as the routed ones are) beside them, weight 1, outside the sort. Every
         # rank of an expert-parallel deployment holds it whole, so the
         # shares' routed parts and THIS, once, add up to the layer.
         from realhf_tpu.models.transformer import _dense_mlp

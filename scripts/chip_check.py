@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``, ``nemotron_h``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -137,6 +137,15 @@ FAMILIES = {
         tiny=("keye_vl2", "tiny-keye-vl2.sft"), wrong_keys={},
         packed_docs=(2560, 1024, 512), decode=(2, 2304, 2176),
         exact_doc=4096, selection=True),
+    # published_decay: the family's ``published_init`` (the decay's two
+    # tensors and D a Mamba layer); no kernels of the scan to time
+    "nemotron_h": dict(
+        cell="nemotron-3-nano-30b-a3b-l7-ep16.sft-1k",
+        tiny=("nemotron_h", "tiny-nemotron-h.sft"), wrong_keys={},
+        packed_docs=(1500, 1100, 1000, 496), decode=(2, 768, 640),
+        exact_doc=4096, published_decay=True,
+        published_phases=("exact_packed", "published", "exact",
+                          "gradient")),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -479,15 +488,18 @@ def selection_live(cell, engine, tensors, doc):
 
 def with_published_decay(cell, ckpt, seed):
     """The checkpoint at ``ckpt`` with its delta layers' decay as
-    published (``family.published_decay``), in a directory beside it:
-    (that directory, its tensors)."""
+    published (``family.published_decay``; a family of ssm layers calls
+    it ``published_init``), in a directory beside it: (that directory,
+    its tensors)."""
     import safetensors.numpy
 
     from benchmark import reference
     family, hf = cell["family"], cell["hf"]
     out = ckpt + "-published"
     shutil.copytree(ckpt, out)
-    tensors = family.published_decay(hf, reference.load_tensors(ckpt), seed)
+    redraw = getattr(family, "published_init", None) \
+        or family.published_decay
+    tensors = redraw(hf, reference.load_tensors(ckpt), seed)
     safetensors.numpy.save_file(
         tensors, os.path.join(out, "model.safetensors"))
     return out, tensors
@@ -539,8 +551,18 @@ def exact_packed(cell, ckpt, tensors, docs, decay):
         return np.asarray(out)[:, real]
 
     want = of_the_row(())
+    # (a boundary touches the tokens just after it: the whole row's
+    # mean dilutes them, so also the 8 that follow each boundary alone)
+    starts = np.cumsum([len(d) for d in docs])[:-1]
+    after = np.zeros(row.shape[1], bool)
+    for at in starts:
+        after[at:at + 8] = True
+    after = after[1:][real]
     for wrong in ("state_over_documents", "conv_over_documents"):
-        rows[f"wrong_{wrong}"] = share(of_the_row((wrong,)), want)
+        got = of_the_row((wrong,))
+        rows[f"wrong_{wrong}"] = share(got, want)
+        rows[f"wrong_{wrong}_8_tokens_after_a_boundary"] = share(
+            got[:, after], want[:, after])
     say(phase="exact_packed", decay=decay, row=row.shape[1],
         secs=round(time.monotonic() - t, 1), **rows)
 
@@ -1062,7 +1084,9 @@ def main():
                     [rng.integers(0, vocab, n) for n in lens],
                     rng.integers(0, vocab, spec["exact_doc"] // (
                         8 if args.rehearse else 1)),
-                    args.only or PUBLISHED_PHASES, args.rehearse)
+                    args.only or spec.get("published_phases",
+                                          PUBLISHED_PHASES),
+                    args.rehearse)
             del engine, tensors
         if args.gen:
             gen(cell, ckpt, work, args.seeds[-1])

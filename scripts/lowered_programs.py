@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's tool, no chip: lower the programs of the benchmark's
-accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2) under a checkout and write
+accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2, nemotron_h) under a checkout and write
 their StableHLO texts, to show that a change to shared model code left
 a model of one block the programs it had.
 
@@ -62,7 +62,7 @@ def dump(name, fn, *args, **kw):
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
-for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096)):
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096), ("nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 4096)):
     if fam not in hf_models.HF_FAMILIES:
         print(cfgname, "left out: this tree has no family", fam)
         continue

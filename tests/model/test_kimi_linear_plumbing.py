@@ -273,8 +273,9 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
 
 def test_the_config_says_what_a_delta_layer_may_be():
     """``TransformerConfig``: delta layers need their ``DeltaConfig``
-    and a pattern; only latent layers may say that they have no rotary
-    embedding."""
+    and a pattern; only latent and (since ``nemotron_h``) full attention
+    layers may say that they have no rotary embedding: a window layer
+    may not."""
     from realhf_tpu.models.config import (
         DeltaConfig,
         RotaryConfig,
@@ -300,8 +301,13 @@ def test_the_config_says_what_a_delta_layer_may_be():
             ("attention", "dense"),) * 2)
     with pytest.raises(NotImplementedError, match="layer_pattern"):
         TransformerConfig(**base, delta=delta)
-    with pytest.raises(ValueError, match=r"lacks \['attention'\]"):
+    with pytest.raises(ValueError, match=r"lacks \['window'\]"):
         TransformerConfig(
-            **base, delta=delta, rotary_by_operator={"attention": None},
-            layer_pattern=(("delta", "dense"), ("attention", "dense")))
+            **base, delta=delta, sliding_window=8,
+            rotary_by_operator={"window": None},
+            layer_pattern=(("delta", "dense"), ("window", "dense")))
+    bare = TransformerConfig(
+        **base, delta=delta, rotary_by_operator={"attention": None},
+        layer_pattern=(("delta", "dense"), ("attention", "dense")))
+    assert bare.rotary_of("attention") is None
     assert RotaryConfig().describe() == "plain@10000/1"

@@ -962,3 +962,41 @@ def test_keyes_whole_train_step_compiles(one_chip):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print("keye whole step GB", total / 1e9)
     assert 13.3e9 < total < 13.9e9
+
+
+@pytest.mark.slow
+def test_nemotrons_whole_train_step_compiles(one_chip):
+    """The tenth cell's WHOLE train step for the described chip: 16
+    microbatches of one row of 4096 (four documents of 1024) through
+    ``E M E M E M *``: three Mamba-2 layers (the chunked scan of
+    ``ops/ssm_scan.py`` in XLA products, rematerialised segments of 8
+    chunks of 128), three layers of ungated experts (TWO grouped
+    products forward: eight ``gmm`` kernels a layer in each branch of
+    the share's ``cond``, not twelve) and one GQA layer at 16 query heads a key head, accumulated
+    in float32, Adam on float32 masters, parameters and optimizer state
+    donated. The compiler's count of the step's memory is what the
+    cell's size hangs on: 528 M parameters are 10.56 GB at 20 bytes
+    before a row's activations, and a program is held to 13.9 GB."""
+    from realhf_tpu.obs import parts
+    from realhf_tpu.ops import moe as moe_ops
+    from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
+
+    step, *args = _sft_train_step(
+        one_chip, "nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 16)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and flash_fwd_per_bwd(text) == 1.0
+    # 2 forward, 2 rematerialised, 2 + 2 backward, in the share's fast
+    # path and in its slow one
+    assert moe_ops.grouped_product_calls(text)["moe_gmm_calls"] \
+        == 3 * 2 * 8
+    ops = parts.parse_program(text)
+    by_part = {part for part, *_ in ops.values()}
+    assert {"ssm", "ssm/scan", "attn", "attn_proj", "experts/products",
+            "shared_expert"} <= by_part
+    # a layer is ONE part: no dense feed-forward anywhere
+    assert "mlp" not in by_part and "conv" not in by_part
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("nemotron whole step GB", total / 1e9)
+    assert 11.0e9 < total < 13.9e9
