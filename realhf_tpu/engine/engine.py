@@ -46,6 +46,7 @@ from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
 from realhf_tpu.ops.delta_rule import scan_kernel_calls
+from realhf_tpu.ops.ssm_scan import scan_kernel_calls as ssm_kernel_calls
 from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
                                             flash_mask_calls)
 from realhf_tpu.ops.sparse_index import pair_counts, scoring_blocks
@@ -599,7 +600,10 @@ class Engine:
         with delta layers ``delta_scan_kernel_calls``
         (``ops.delta_rule.scan_kernel_calls``: the chunked scan's
         kernels in the text: a forward and a backward one a delta
-        layer of a train program, 0 where the XLA products run), and
+        layer of a train program, 0 where the XLA products run), of
+        every program of a model with ssm layers
+        ``ssm_scan_kernel_calls`` (``ops.ssm_scan.scan_kernel_calls``:
+        the same of the Mamba-2 scan's two kernels), and
         of every program of a model with sparse layers
         ``flash_mask_calls`` (``ops.flash_attention.flash_mask_calls``:
         the flash kernels in the text that take the selection, three a
@@ -619,6 +623,7 @@ class Engine:
                 mine = None
             ragged = moe_ops.dispatch_mode(self.cfg) == "ragged"
             delta = self.cfg.delta is not None
+            ssm = self.cfg.ssm is not None
             sparse = self.cfg.indexer is not None
 
             def derive(text):
@@ -627,6 +632,9 @@ class Engine:
                     out.update(moe_ops.grouped_product_calls(text))
                 if delta:
                     out.update(delta_scan_kernel_calls=scan_kernel_calls(
+                        text))
+                if ssm:
+                    out.update(ssm_scan_kernel_calls=ssm_kernel_calls(
                         text))
                 if sparse:
                     out.update(flash_mask_calls=flash_mask_calls(text))

@@ -85,6 +85,7 @@ from realhf_tpu.ops.flash_attention import RESIDUAL_NAMES, SELECT_RESIDUAL
 from realhf_tpu.ops.rotary import apply_rotary, rotary_freqs
 from realhf_tpu.ops.sparse_index import (index_scores, select_topk,
                                          selection_mask)
+from realhf_tpu.ops.ssm_scan import RESIDUAL_NAMES as _SSM_SCAN_RESIDUALS
 from realhf_tpu.ops.ssm_scan import chunked_ssm_scan, ssm_step
 
 Params = Dict[str, Any]
@@ -113,10 +114,17 @@ PROJECTION_RESIDUALS = ("attn_q", "attn_proj_out")
 DELTA_RESIDUALS = ("delta_out",) + _SCAN_RESIDUALS
 #: What an ssm layer's chunked scan made (``_ssm_op``): its heads'
 #: outputs before the gate, ``tokens x width`` values a layer a
-#: microbatch in the compute dtype; with the projected output
-#: (``PROJECTION_RESIDUALS[1]``) ``tokens x (width + hidden) x 2``
-#: bytes in bf16.
-SSM_RESIDUALS = ("ssm_out",)
+#: microbatch in the compute dtype (with the projected output,
+#: ``PROJECTION_RESIDUALS[1]``, ``tokens x (width + hidden) x 2``
+#: bytes in bf16), and, where the scan is the kernels', what its
+#: forward hands its backward (``ops/ssm_scan.py:RESIDUAL_NAMES``:
+#: every chunk's start states in float32, ``heads x head_dim x state x
+#: 4`` bytes a chunk of 128 tokens: 67 MB a layer a row of 4096 at 64
+#: heads of 64 and a state of 128). Kept, the rematerialised block
+#: does not run the scan a second time: not for its OUTPUT, and not
+#: for the backward kernel's sake. (The XLA path names the output
+#: alone: its own backward runs it again a segment at a time.)
+SSM_RESIDUALS = ("ssm_out",) + _SSM_SCAN_RESIDUALS
 #: every name the policy of a rematerialised block keeps; the last is
 #: a sparse layer's selection (int8, ``L x L`` bytes a row a layer):
 #: kept, the backward's kernels mask by it and the indexer, which no
@@ -727,16 +735,19 @@ def _ssm_output(cfg: TransformerConfig, c: Params, z: jnp.ndarray,
 
 
 def _ssm_op(cfg: TransformerConfig, c: Params, u: jnp.ndarray,
-            seg_ids: jnp.ndarray):
+            seg_ids: jnp.ndarray, mesh=None):
     """The ssm operator over packed rows on the normed residual u
     [B, L, H] -> (its projected output [B, L, H], (the convolution's
     input [B, L, conv_dim], each row's state after its last token
     [B, n, hd, state] float32)): what prefill's caches are made of. The
-    recurrence alone is sub-part ``ssm/scan`` (obs/parts.py)."""
+    recurrence alone is sub-part ``ssm/scan`` (obs/parts.py); ``mesh``:
+    what the arrays are sharded over, by which the scan's kernels are
+    partitioned (``ops/ssm_scan.py``)."""
     raw, z, x, b, cc, dt = _ssm_inputs(
         cfg, c, u, lambda s, taps: _causal_conv(s, taps, seg_ids))
     with jax.named_scope(P.SCAN):
-        y, last = chunked_ssm_scan(x, dt, b, cc, seg_ids, **_ssm_leaves(c))
+        y, last = chunked_ssm_scan(x, dt, b, cc, seg_ids, mesh=mesh,
+                                   **_ssm_leaves(c))
         y = checkpoint_name(y, SSM_RESIDUALS[0])
     proj = checkpoint_name(_ssm_output(cfg, c, z, y),
                            PROJECTION_RESIDUALS[1])
@@ -897,7 +908,7 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
                                         mesh)
         elif op == "ssm":
             with jax.named_scope(P.SSM):
-                proj, state = _ssm_op(cfg, lp["ssm"], ln1, seg_ids)
+                proj, state = _ssm_op(cfg, lp["ssm"], ln1, seg_ids, mesh)
         else:
             proj, state = _attention_op(cfg, lp, layer_idx, ln1, seg_ids,
                                         cos, sin, attention_fn, window,

@@ -138,14 +138,12 @@ FAMILIES = {
         packed_docs=(2560, 1024, 512), decode=(2, 2304, 2176),
         exact_doc=4096, selection=True),
     # published_decay: the family's ``published_init`` (the decay's two
-    # tensors and D a Mamba layer); no kernels of the scan to time
+    # tensors and D a Mamba layer)
     "nemotron_h": dict(
         cell="nemotron-3-nano-30b-a3b-l7-ep16.sft-1k",
         tiny=("nemotron_h", "tiny-nemotron-h.sft"), wrong_keys={},
         packed_docs=(1500, 1100, 1000, 496), decode=(2, 768, 640),
-        exact_doc=4096, published_decay=True,
-        published_phases=("exact_packed", "published", "exact",
-                          "gradient")),
+        exact_doc=4096, published_decay=True),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -604,6 +602,30 @@ def gradient(cell, ckpt, tensors, seed, length=256):
         secs=round(time.monotonic() - t, 1))
 
 
+def _timed(fn, args, rounds):
+    """(``fn(*args)`` once, compiled and warm; milliseconds a call over
+    ``rounds`` more)."""
+    import jax
+    first = jax.block_until_ready(fn(*args))
+    t = time.monotonic()
+    for _ in range(rounds):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return first, round((time.monotonic() - t) / rounds * 1e3, 3)
+
+
+def _relative_gaps(names, got, want):
+    import jax.numpy as jnp
+    import numpy as np
+    f32 = jnp.float32
+    rows = {}
+    for name, a, b in zip(names, got, want):
+        a, b = np.asarray(a.astype(f32)), np.asarray(b.astype(f32))
+        rows[f"{name}_relative_gap"] = float(
+            np.linalg.norm((a - b).ravel()) / np.linalg.norm(b.ravel()))
+    return rows
+
+
 def scan_alone(cell, rounds=20):
     """Milliseconds of the chunked recurrence alone at the cell's shape
     (one row of 2,048, 32 heads of 128, bf16 operands, a layer's
@@ -645,11 +667,9 @@ def scan_alone(cell, rounds=20):
     rows["forward_max_gap"] = float(jnp.abs(
         outs["kernel", "forward"].astype(f32)
         - outs["xla", "forward"].astype(f32)).max())
-    for name, a, b in zip("q k v g beta".split(), outs["kernel", "gradient"],
-                          outs["xla", "gradient"]):
-        rows[f"d{name}_relative_gap"] = float(
-            jnp.linalg.norm((a.astype(f32) - b.astype(f32)).ravel())
-            / jnp.linalg.norm(b.astype(f32).ravel()))
+    rows.update(_relative_gaps(
+        "dq dk dv dg dbeta".split(), outs["kernel", "gradient"],
+        outs["xla", "gradient"]))
     say(phase="scan", shape=list(shape), **rows)
     if len(jax.devices()) >= 4:
         scan_on_mesh(shape, rounds)
@@ -667,7 +687,6 @@ def scan_on_mesh(shape, rounds):
     milliseconds of both."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from realhf_tpu.ops import delta_rule as D
     from realhf_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS,
@@ -701,21 +720,131 @@ def scan_on_mesh(shape, rounds):
             ("one_chip", grads(None), x),
             ("mesh", grads(mesh), [jax.device_put(a, NamedSharding(mesh, s))
                                    for a, s in zip(x, specs)])):
-        outs[name] = jax.block_until_ready(fn(*args))
-        t = time.monotonic()
-        for _ in range(rounds):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        rows[f"gradient_ms_{name}"] = round(
-            (time.monotonic() - t) / rounds * 1e3, 3)
-    f32 = jnp.float32
+        outs[name], rows[f"gradient_ms_{name}"] = _timed(fn, args, rounds)
     flat = lambda out: (*out[0], *out[1])
-    for name, a, b in zip("dq dk dv df dbeta drate ddt_bias o last".split(),
-                          flat(outs["mesh"]), flat(outs["one_chip"])):
-        a, b = np.asarray(a.astype(f32)), np.asarray(b.astype(f32))
-        rows[f"{name}_relative_gap"] = float(
-            np.linalg.norm((a - b).ravel()) / np.linalg.norm(b.ravel()))
+    rows.update(_relative_gaps(
+        "dq dk dv df dbeta drate ddt_bias o last".split(),
+        flat(outs["mesh"]), flat(outs["one_chip"])))
     say(phase="scan_on_mesh", shape=list(wide), mesh="d2t2", **rows)
+
+
+SSM_NAMES = "x dt b c rate dt_bias skip".split()
+
+
+def _ssm_operands(cell, rows, key):
+    """The Mamba-2 scan's operands at the cell's shape (``rows`` rows
+    of the traffic's row length, 64 heads of 64 in 8 groups, a state of
+    128, bf16), the rate and the step as the harness draws them (a
+    state halves every token), and the cell's rows of equal documents
+    (four of 1024)."""
+    import jax
+    import jax.numpy as jnp
+    hf, traffic = cell["hf"], cell["traffic"]
+    l = traffic["doc_len"] * traffic["docs_per_row"]
+    h, p = hf["mamba_num_heads"], hf["mamba_head_dim"]
+    g, n = hf["n_groups"], hf["ssm_state_size"]
+    keys = jax.random.split(jax.random.PRNGKey(key), 7)
+    bf16 = jnp.bfloat16
+    normal = jax.random.normal
+    ops = [normal(keys[0], (rows, l, h, p)).astype(bf16),
+           (0.5 * normal(keys[1], (rows, l, h))).astype(bf16),
+           normal(keys[2], (rows, l, g, n)).astype(bf16),
+           normal(keys[3], (rows, l, g, n)).astype(bf16),
+           -jnp.exp(0.02 * normal(keys[4], (h,))),
+           0.02 * normal(keys[5], (h,)), 1 + 0.02 * normal(keys[6], (h,))]
+    seg = jnp.broadcast_to(
+        1 + jnp.arange(l, dtype=jnp.int32) // traffic["doc_len"], (rows, l))
+    return ops, seg
+
+
+def _ssm_gradient(scan, seg):
+    """The jitted gradient of a loss over the outputs and the last
+    states by every operand and leaf, (y, last) beside it; ``scan``:
+    ``ops/ssm_scan.py:_by_kernels`` or ``_by_xla``, or anything of
+    their signature."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(x, dt, b, c, rate, dt_bias, skip):
+        y, last = scan(x, dt, b, c, seg, rate, dt_bias, skip)
+        return y.astype(jnp.float32).sum() + last.sum(), (y, last)
+    return jax.jit(jax.grad(loss, argnums=tuple(range(7)), has_aux=True))
+
+
+def ssm_scan_alone(cell, rounds=20):
+    """Milliseconds of the Mamba-2 scan alone at the cell's shape (one
+    row of 4,096 of four documents, 64 heads of 64 in 8 groups, state
+    128, bf16 operands), forward and gradient, by the kernels and by
+    the XLA products, every case compiled and warmed before it is
+    timed; and how far the two lie apart."""
+    import jax
+    import jax.numpy as jnp
+    from realhf_tpu.ops import ssm_scan as S
+    ops, seg = _ssm_operands(cell, 1, 0)
+    rows, outs = {}, {}
+    for path, scan in (("kernel", S._by_kernels), ("xla", S._by_xla)):
+        fwd = jax.jit(lambda *a, scan=scan: scan(*a[:4], seg, *a[4:])[0])
+        for name, fn in (("forward", fwd),
+                         ("gradient", _ssm_gradient(scan, seg))):
+            outs[path, name], rows[f"{name}_ms_{path}"] = _timed(
+                fn, ops, rounds)
+    f32 = jnp.float32
+    rows["forward_max_gap"] = float(jnp.abs(
+        outs["kernel", "forward"].astype(f32)
+        - outs["xla", "forward"].astype(f32)).max())
+    names = [f"d{name}" for name in SSM_NAMES] + ["y", "last"]
+    flat = lambda out: (*out[0], *out[1])
+    rows.update(_relative_gaps(names, flat(outs["kernel", "gradient"]),
+                               flat(outs["xla", "gradient"])))
+    say(phase="scan", shape=list(ops[0].shape), **rows)
+    # whose bf16 is it: both paths against the XLA products on float32
+    # operands at the highest precision
+    with jax.default_matmul_precision("highest"):
+        want = _ssm_gradient(S._by_xla, seg)(*(t.astype(f32) for t in ops))
+    for path in ("kernel", "xla"):
+        say(phase="scan_against_float32", path=path, **_relative_gaps(
+            names, flat(outs[path, "gradient"]), flat(want)))
+    if len(jax.devices()) >= 4:
+        ssm_scan_on_mesh(cell, rounds)
+
+
+def ssm_scan_on_mesh(cell, rounds):
+    """Given four chips (``chiprun --chips 4``): the kernels on the
+    engine's dp2 x tp2 mesh, two rows of the cell's shape with each
+    device taking one row's half of the GROUPS under ``shard_map``
+    (``ops/ssm_scan.py:_scan_over``), against the kernels on ONE chip
+    on the same values: outputs, last states and every gradient, those
+    of the three leaves a head (summed over "data") among them, and
+    the milliseconds of both."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from realhf_tpu.ops import ssm_scan as S
+    from realhf_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS,
+                                          ParallelismConfig, make_mesh)
+    mesh = make_mesh(ParallelismConfig(data_parallel_size=2,
+                                       tensor_parallel_size=2),
+                     jax.devices()[:4])
+    ops, seg = _ssm_operands(cell, 2, 1)
+    specs = [P(DATA_AXIS, None, MODEL_AXIS)] * 4 + [P(MODEL_AXIS)] * 3
+    rows, outs = {}, {}
+    for name, args, over in (
+            ("one_chip", ops, None),
+            ("mesh", [jax.device_put(a, NamedSharding(mesh, s))
+                      for a, s in zip(ops, specs)], mesh)):
+        def scan(x, dt, b, c, seg, rate, dt_bias, skip, over=over):
+            return S.chunked_ssm_scan(x, dt, b, c, seg, rate=rate,
+                                      dt_bias=dt_bias, skip=skip, mesh=over)
+        outs[name], rows[f"gradient_ms_{name}"] = _timed(
+            _ssm_gradient(scan, seg), args, rounds)
+    flat = lambda out: (*out[0], *out[1])
+    rows.update(_relative_gaps(
+        [f"d{name}" for name in SSM_NAMES] + ["y", "last"],
+        flat(outs["mesh"]), flat(outs["one_chip"])))
+    say(phase="scan_on_mesh", shape=list(ops[0].shape), mesh="d2t2", **rows)
+
+
+#: row ``scan`` by family: the operator whose chunked scan has kernels
+SCAN_ROWS = {"kimi_linear": scan_alone, "nemotron_h": ssm_scan_alone}
 
 
 def scan_accuracy(length, hd, heads=4):
@@ -791,7 +920,7 @@ def under_published_decay(cell, ckpt, tensors, ids, seed, docs, long,
         elif phase == "exact":
             exact(cell, ckpt_p, tensors_p, long)
         elif phase == "scan":
-            scan_alone(cell)
+            SCAN_ROWS[FAMILY](cell)
         elif phase == "gradient":
             try:  # the reference's gradient is the largest program
                 gradient(cell, ckpt_p, tensors_p, seed,
@@ -1040,6 +1169,9 @@ def main():
             ragged(row_tiles=args.row_tiles or (None,))
         if args.only_ragged:
             return
+    if args.only == ["scan"]:  # the scan's operands alone: no checkpoint
+        SCAN_ROWS[FAMILY](cell)
+        return
     work = os.path.join(ROOT, "benchmark", ".cache", f"chip_check_{FAMILY}")
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)

@@ -43,6 +43,9 @@ FAMILIES = {
     "keye_vl2": (
         "tests/benchmark/keye_vl2/configs/tiny-keye-vl2.json",
         {"index", "experts"}),
+    "nemotron_h": (
+        "tests/benchmark/nemotron_h/configs/tiny-nemotron-h.json",
+        {"ssm", "experts", "shared_expert"}),
 }
 #: how the op_name of a loop's own operations ends
 PLUMBING = {"add", "lt", "closed_call", "dynamic_slice",
@@ -157,6 +160,13 @@ def test_train_program_names_every_part_the_family_has(family):
         assert facts.attributes["delta_scan_kernel_calls"] == 0
     else:
         assert "delta_scan_kernel_calls" not in facts.attributes
+    # and the ssm layers' (likewise: ``kernel_takes`` refuses the tiny
+    # cell's group of 2 heads of 8; on the chip 6 in the tenth cell)
+    if "ssm" in have:
+        assert "ssm/scan" in passes
+        assert facts.attributes["ssm_scan_kernel_calls"] == 0
+    else:
+        assert "ssm_scan_kernel_calls" not in facts.attributes
     # whether the flash kernels take a sparse layer's selection (here
     # the XLA path takes it: none does), and the indexer forward only
     if "index" in have:
@@ -194,8 +204,11 @@ def test_generate_program_nests_the_parts_in_its_phases(family):
         if "delta" in FAMILIES[family][1] else set()
     masked = {"flash_mask_calls"} \
         if "index" in FAMILIES[family][1] else set()
+    ssm = {"ssm_scan_kernel_calls"} \
+        if "ssm" in FAMILIES[family][1] else set()
     assert set(facts.attributes) == {
-        "decode_kernel", "decode_layer_copies"} | sparse | delta | masked
+        "decode_kernel", "decode_layer_copies"} | sparse | delta | masked \
+        | ssm
     seen = {(op[3], (op[0] or "").split("/")[0])
             for op in facts.ops.values()}
     assert {phase for phase, _ in seen} >= {"prefill", "decode", "sample"}

@@ -171,18 +171,19 @@ def _model(one_chip, config_name, family):
 
 def _as_on_a_tpu(fn):
     """``fn`` traced with ``pallas_enabled()`` true where the experts'
-    layer and the delta layers' scan ask: the backend here is the CPU,
-    and the chip's program holds ``ops/grouped_matmul.py``'s and
-    ``ops/delta_rule.py``'s kernels."""
+    layer and the delta and ssm layers' scans ask: the backend here is
+    the CPU, and the chip's program holds ``ops/grouped_matmul.py``'s,
+    ``ops/delta_rule.py``'s and ``ops/ssm_scan.py``'s kernels."""
     from unittest import mock
 
-    from realhf_tpu.ops import delta_rule
+    from realhf_tpu.ops import delta_rule, ssm_scan
     from realhf_tpu.ops import moe as moe_ops
 
     def traced(*args):
         with mock.patch.object(moe_ops, "pallas_enabled", lambda: True), \
                 mock.patch.object(delta_rule, "pallas_enabled",
-                                  lambda: True):
+                                  lambda: True), \
+                mock.patch.object(ssm_scan, "pallas_enabled", lambda: True):
             return fn(*args)
     return traced
 
@@ -543,6 +544,43 @@ def test_delta_scan_kernels_compile_at_the_cells_shape(one_chip, dtype,
     assert D.scan_kernel_calls(text) == 2
 
 
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.bfloat16, "default"), (jnp.float32, "highest")],
+    ids=["bf16_one_pass", "float32_highest"])
+@pytest.mark.parametrize("passes", ["forward", "gradient"])
+def test_ssm_scan_kernels_compile_at_the_cells_shape(one_chip, dtype,
+                                                     precision, passes):
+    """``ops/ssm_scan.py``'s two kernels at the tenth cell's shape (one
+    row of 4096, 64 heads of 64 in 8 groups, a state of 128): the
+    forward alone as prefill runs it (no start state kept) and forward
+    and gradient, as the bf16 engine runs them (operands bf16, products
+    in one pass, blocks of 4 chunks) and as ``chip_check.py
+    nemotron_h``'s float32 rows do (operands float32, every product at
+    the highest precision, blocks of 2 chunks: the backward's blocks
+    and scratch at 4 are refused for VMEM)."""
+    from realhf_tpu.ops import ssm_scan as S
+    b, l, h, p, g, n = 1, 4096, 64, 64, 8, 128
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def scan(x, dt, bb, cc, rate, dt_bias, skip, seg):
+        return S._by_kernels(x, dt, bb, cc, seg, rate, dt_bias, skip)
+
+    def loss(*a):
+        y, last = scan(*a)
+        return y.astype(jnp.float32).sum() + last.sum()
+
+    args = (sds((b, l, h, p), dtype), sds((b, l, h), dtype),
+            sds((b, l, g, n), dtype), sds((b, l, g, n), dtype),
+            *(sds((h,), jnp.float32) for _ in range(3)),
+            sds((b, l), jnp.int32))
+    with jax.default_matmul_precision(precision):
+        text = _compile(scan if passes == "forward" else jax.grad(
+            loss, argnums=tuple(range(7))), *args).as_text()
+    assert S.scan_kernel_calls(text) == (1 if passes == "forward" else 2)
+
+
 @pytest.mark.slow
 def test_kimis_whole_train_step_compiles(one_chip):
     """The eighth cell's WHOLE train step for the described chip: 32
@@ -680,6 +718,42 @@ def test_delta_scan_compiles_under_shard_map(topo):
     assert D.scan_kernel_calls(text) == 2
     opcodes = {opcode for _, _, opcode in device_instructions(text)}
     assert "all-reduce" in opcodes  # d of the decay, over "data"
+    assert not opcodes & {"all-gather", "all-to-all", "collective-permute"}
+
+
+def test_ssm_scan_compiles_under_shard_map(topo):
+    """``ops/ssm_scan.py``'s two kernels on a dp2 x tp2 mesh, two rows
+    of the tenth cell's shape: ``chunked_ssm_scan`` hands each device
+    its own row and its own 4 of the 8 GROUPS of heads (their columns
+    of x, B and C) under ``shard_map``; d of the three leaves a head is
+    summed over "data", and nothing is gathered."""
+    from realhf_tpu.ops import ssm_scan as S
+    from realhf_tpu.ops.hlo_text import device_instructions
+    from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    mesh = _mesh_of_four(topo)
+    b, l, h, p, g, n = 2, 4096, 64, 64, 8, 128
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    def loss(x, dt, bb, cc, rate, dt_bias, skip, seg):
+        y, last = S.chunked_ssm_scan(x, dt, bb, cc, seg, rate=rate,
+                                     dt_bias=dt_bias, skip=skip, mesh=mesh)
+        return y.astype(jnp.float32).sum() + last.sum()
+
+    heads = (DATA_AXIS, None, MODEL_AXIS)
+    args = (sds((b, l, h, p), jnp.bfloat16, *heads),
+            sds((b, l, h), jnp.bfloat16, *heads),
+            sds((b, l, g, n), jnp.bfloat16, *heads),
+            sds((b, l, g, n), jnp.bfloat16, *heads),
+            *(sds((h,), jnp.float32, MODEL_AXIS) for _ in range(3)),
+            sds((b, l), jnp.int32, DATA_AXIS))
+    text = _compile(_as_on_a_tpu(jax.grad(loss, argnums=tuple(range(7)))),
+                    *args).as_text()
+    assert S.scan_kernel_calls(text) == 2
+    opcodes = {opcode for _, _, opcode in device_instructions(text)}
+    assert "all-reduce" in opcodes  # d of the leaves, over "data"
     assert not opcodes & {"all-gather", "all-to-all", "collective-permute"}
 
 
@@ -968,9 +1042,13 @@ def test_keyes_whole_train_step_compiles(one_chip):
 def test_nemotrons_whole_train_step_compiles(one_chip):
     """The tenth cell's WHOLE train step for the described chip: 16
     microbatches of one row of 4096 (four documents of 1024) through
-    ``E M E M E M *``: three Mamba-2 layers (the chunked scan of
-    ``ops/ssm_scan.py`` in XLA products, rematerialised segments of 8
-    chunks of 128), three layers of ungated experts (TWO grouped
+    ``E M E M E M *``: three Mamba-2 layers (the chunked scan by
+    ``ops/ssm_scan.py``'s two kernels, a chunk's decays and ``C B^T``
+    in VMEM; a rematerialised block keeps the scan's output and the
+    chunks' start states, 67 MB a layer a row: ONE forward and ONE
+    backward kernel a layer, none in the rematerialised pass; by the
+    XLA products, PR 48, the step read 11.99 GB), three layers of
+    ungated experts (TWO grouped
     products forward: eight ``gmm`` kernels a layer in each branch of
     the share's ``cond``, not twelve) and one GQA layer at 16 query heads a key head, accumulated
     in float32, Adam on float32 masters, parameters and optimizer state
@@ -979,6 +1057,7 @@ def test_nemotrons_whole_train_step_compiles(one_chip):
     before a row's activations, and a program is held to 13.9 GB."""
     from realhf_tpu.obs import parts
     from realhf_tpu.ops import moe as moe_ops
+    from realhf_tpu.ops import ssm_scan
     from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
 
     step, *args = _sft_train_step(
@@ -986,6 +1065,7 @@ def test_nemotrons_whole_train_step_compiles(one_chip):
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
     text = compiled.as_text()
     assert "flash_fwd" in text and flash_fwd_per_bwd(text) == 1.0
+    assert ssm_scan.scan_kernel_calls(text) == 3 * 2
     # 2 forward, 2 rematerialised, 2 + 2 backward, in the share's fast
     # path and in its slow one
     assert moe_ops.grouped_product_calls(text)["moe_gmm_calls"] \

@@ -413,3 +413,73 @@ def test_delta_scan_goes_by_xla_where_a_mesh_cannot_take_the_kernels(
         *a, seg, prepare, mesh=mesh)).lower(*args[2:]).as_text(
             debug_info=True)
     assert D.DELTA_FWD not in text and "tpu_custom_call" not in text
+
+
+def _ssm_case(b=2, l=200, h=4, g=2, p=64, n=128):
+    """A packed batch of an ssm layer at groups a whole lane wide (the
+    rows packed differently), three leaves that differ head by head,
+    and weights for a loss over the outputs and the last states."""
+    rng = np.random.default_rng(43)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    seg = np.ones((b, l), np.int32)
+    seg[0, 37:150], seg[0, 150:] = 2, 0  # a pad tail
+    seg[1::2, 130:] = 2
+    args = (f(b, l, h, p), f(b, l, h), f(b, l, g, n), f(b, l, g, n),
+            -jnp.exp(f(h)), f(h), 1 + f(h))
+    w = f(b, l, h, p) * (seg != 0)[..., None, None]
+    return args, jnp.asarray(seg), w, f(b, h, p, n)
+
+
+def test_sharded_ssm_scan_matches_one_device(interpreted_kernels):
+    """``ops/ssm_scan.py``'s two kernels on a dp2 x tp2 mesh: each
+    device runs them on its own rows and GROUPS of heads under
+    ``shard_map``. Forward and every gradient against the XLA products
+    on one device; d of the three leaves a head, which every data shard
+    holds, is summed over "data"."""
+    from realhf_tpu.ops import ssm_scan as S
+    args, seg, w, w_last = _ssm_case()
+
+    def grads_of(**kw):
+        def loss(x, dt, b, c, rate, dt_bias, skip):
+            y, last = S.chunked_ssm_scan(x, dt, b, c, seg, rate=rate,
+                                         dt_bias=dt_bias, skip=skip, **kw)
+            return (y * w).sum() + (last * w_last).sum()
+        return jax.jit(jax.grad(loss, argnums=tuple(range(7))))
+
+    with jax.default_matmul_precision("highest"):
+        want = grads_of()(*args)  # outside the interpreter: XLA
+        with interpreted_kernels():  # the backward is traced late
+            sharded = grads_of(mesh=_mesh())
+            text = sharded.lower(*args).as_text(debug_info=True)
+            got = sharded(*args)
+    assert "shard_map" in text
+    assert S.SSM_FWD in text and S.SSM_BWD in text
+    for name, a, b_ in zip("x dt b c rate dt_bias skip".split(), got, want):
+        err = float(jnp.abs(a - b_).max())
+        # (a leaf's is a sum over every token of terms of both signs)
+        tol = 1e-4 if a.ndim > 1 else 1e-3
+        assert err < tol * float(jnp.abs(b_).max()), (name, err)
+
+
+def test_ssm_scan_goes_by_xla_where_a_mesh_cannot_take_the_kernels(
+        monkeypatch):
+    """One device (or no mesh): the bare kernels. Rows or GROUPS that
+    do not divide the mesh, and a mesh that cuts a row along its length
+    (context parallelism): the XLA products, which GSPMD partitions,
+    with no Mosaic call in the program."""
+    from realhf_tpu.ops import ssm_scan as S
+    monkeypatch.setattr(S, "pallas_enabled", lambda: True)
+    assert S._scan_over(None, 3, 5) is S._scan
+    assert S._scan_over(_mesh(1, 1), 3, 5) is S._scan
+    mesh = _mesh()
+    assert S._scan_over(mesh, 4, 4) not in (None, S._scan)
+    assert S._scan_over(mesh, 3, 4) is None
+    assert S._scan_over(mesh, 4, 3) is None
+    par = ParallelismConfig(data_parallel_size=2, context_parallel_size=2)
+    cut = make_mesh(par, devices=jax.devices("cpu")[:par.world_size])
+    assert S._scan_over(cut, 4, 4) is None
+    (x, dt, b, c, rate, dt_bias, skip), seg, _, _ = _ssm_case(b=3)
+    text = jax.jit(lambda *a: S.chunked_ssm_scan(
+        *a, seg, rate=rate, dt_bias=dt_bias, skip=skip,
+        mesh=mesh)).lower(x, dt, b, c).as_text(debug_info=True)
+    assert S.SSM_FWD not in text and "tpu_custom_call" not in text
