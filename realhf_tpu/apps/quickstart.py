@@ -30,22 +30,9 @@ def parse_overrides(tokens):
     return out
 
 
-def main(argv=None):
-    import realhf_tpu.experiments as experiments
-    from realhf_tpu.base.backend import enable_compile_cache
-    from realhf_tpu.base.importing import import_usercode
-
-    enable_compile_cache()  # a config update: touches no device
-    import_usercode()  # REALHF_TPU_PACKAGE_PATH custom registrations
-
-    argv = argv if argv is not None else sys.argv[1:]
-    parser = argparse.ArgumentParser("realhf_tpu quickstart")
-    parser.add_argument(
-        "experiment", choices=sorted(experiments.ALL_EXPERIMENT_CLASSES))
-    parser.add_argument("overrides", nargs="*",
-                        help="dotted key=value config overrides")
-    args = parser.parse_args(argv)
-
+def _build_spec(experiments, args):
+    """The experiment's configuration with its overrides, its spec and
+    the allocation (heuristic or search)."""
     from realhf_tpu.experiments.common import apply_overrides
     cfg = experiments.ALL_EXPERIMENT_CLASSES[args.experiment]()
     apply_overrides(cfg, parse_overrides(args.overrides))
@@ -108,18 +95,61 @@ def main(argv=None):
         logger.info("%s allocations on %d devices: %s",
                     cfg.allocation_mode, n,
                     {k: str(v) for k, v in spec.allocations.items()})
+    return cfg, spec
+
+
+def main(argv=None):
+    from realhf_tpu.obs import tracing
+
+    # the program records its own set-up, from here to the end of its
+    # first step (docs/observability.md, "The spans of set-up"); a
+    # capture that a caller has running takes the spans instead
+    tracing.start_setup()
+    try:
+        return _main(argv)
+    finally:
+        tracing.end_setup()  # raised before the first step had ended
+
+
+def _main(argv):
+    from realhf_tpu.obs import metrics, tracing
+
+    with tracing.span("setup:imports"):
+        import realhf_tpu.experiments as experiments
+        from realhf_tpu.base.backend import enable_compile_cache
+        from realhf_tpu.base.importing import import_usercode
+
+        enable_compile_cache()  # a config update: touches no device
+        import_usercode()  # REALHF_TPU_PACKAGE_PATH custom registrations
+        # the stages of every lowering from here on, the checkpoint's
+        # and the optimizer's before the first engine is whole too
+        metrics.watch_compiles()
+
+    argv = argv if argv is not None else sys.argv[1:]
+    parser = argparse.ArgumentParser("realhf_tpu quickstart")
+    parser.add_argument(
+        "experiment", choices=sorted(experiments.ALL_EXPERIMENT_CLASSES))
+    parser.add_argument("overrides", nargs="*",
+                        help="dotted key=value config overrides")
+    args = parser.parse_args(argv)
+
+    with tracing.span("setup:spec", experiment=args.experiment) as sp:
+        cfg, spec = _build_spec(experiments, args)
+        sp.set_attribute("allocation_mode", cfg.allocation_mode)
 
     if getattr(spec, "serving", None) is not None:
         # rollout/serving deployment: no master/dataflow, just
         # GenServerWorker processes answering RolloutClient traffic
         # (docs/serving.md)
         from realhf_tpu.apps.main import run_serve
+        tracing.end_setup()  # a launcher's set-up ends here
         stats = run_serve(
             spec, duration=getattr(cfg, "serve_duration_secs", None))
     elif cfg.mode == "distributed":
         # master + model-worker processes, concurrent MFCs on disjoint
         # meshes (reference multi-worker runtime)
         from realhf_tpu.apps.main import main_start
+        tracing.end_setup()  # a launcher's set-up ends here
         stats = main_start(spec, recover_mode=cfg.recover_mode,
                            recover_retries=cfg.recover_retries)
     else:

@@ -22,6 +22,7 @@ the algorithm interfaces do SequenceSample <-> stream packing.
 
 import collections
 import dataclasses
+import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -52,7 +53,7 @@ from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
 from realhf_tpu.ops.sparse_index import pair_counts, scoring_blocks
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
-from realhf_tpu.parallel.realloc import offload_to_host
+from realhf_tpu.parallel.realloc import offload_to_host, tree_bytes
 
 logger = logging.getLogger("engine")
 
@@ -190,7 +191,12 @@ class Engine:
         params = shard_rules.normalize_vocab_padding(cfg, params,
                                                      ctx.tp_size)
         params = self._cast_param_dtype(params)
-        self.params = jax.device_put(params, self._param_shardings)
+        with tracing.span("setup:model:shard",
+                          layout=str(ctx.parallel)) as sp:
+            self.params = sp.result(
+                jax.device_put(params, self._param_shardings))
+            if sp is not tracing.NOOP_SPAN:
+                sp.set_attribute("bytes", tree_bytes(self.params))
         self._constrain = shard_rules.activation_constraint(
             self.mesh, ctx.parallel.sequence_parallel)
         # Context parallelism: attention becomes a ring over the "ctx"
@@ -342,12 +348,13 @@ class Engine:
             # deepspeed.py:445). GSPMD inserts the reduce-scatter /
             # all-gather pair around the update.
             zero1 = getattr(optimizer, "zero1", True)
-            state_shape = jax.eval_shape(self._tx.init, self.params)
-            self._opt_shardings = shard_rules.opt_state_shardings(
-                state_shape, cfg, self.mesh, zero1=zero1)
-            self.opt_state = jax.jit(
-                self._tx.init,
-                out_shardings=self._opt_shardings)(self.params)
+            with tracing.span("setup:model:optimizer", zero1=zero1) as sp:
+                state_shape = jax.eval_shape(self._tx.init, self.params)
+                self._opt_shardings = shard_rules.opt_state_shardings(
+                    state_shape, cfg, self.mesh, zero1=zero1)
+                self.opt_state = sp.result(jax.jit(
+                    self._tx.init,
+                    out_shardings=self._opt_shardings)(self.params))
             # ZeRO-2-flavored grad accumulation: the fp32 grad
             # accumulator shards over DP too, turning the DP grad
             # all-reduce into a reduce-scatter (Megatron
@@ -640,7 +647,16 @@ class Engine:
                     out.update(flash_mask_calls=flash_mask_calls(text))
                 return out
 
-            facts = parts.read_program(self._compiled(name, call), derive)
+            # the program lowered again where jax has not kept it (the
+            # stages go to this span), and the parse of its text
+            t0 = time.monotonic()
+            with tracing.span("engine:facts") as sp:
+                facts = parts.read_program(self._compiled(name, call),
+                                           derive)
+                sp.set_attribute("program", facts.module)
+                sp.set_attribute("bytes", facts.text_bytes)
+            metrics.inc("engine_stage_secs_total", time.monotonic() - t0,
+                        stage="facts")
             self._facts[(name, key)] = facts
             role = str(self.ctx.model_name.role)
             for kind, field in _MEMORY_KINDS.items():

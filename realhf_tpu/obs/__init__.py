@@ -65,7 +65,11 @@ def configure_from_env(process_name: str,
             tracing.configure(
                 path=tracing.trace_file_path(process_name, experiment,
                                              trial))
-            tracing.start()  # unsynced: the run keeps its overlap
+            # unsynced: the run keeps its overlap. The set-up capture
+            # that quickstart began, where there is one, becomes the
+            # run's: its spans so far go to the file with the rest
+            if not tracing.release_setup():
+                tracing.start()
         if metrics_env not in ("", "0") and metrics_env != "1":
             metrics.default_registry().attach_jsonl(metrics_env)
         elif trace_on or metrics_env == "1":

@@ -543,24 +543,93 @@ def flush_final():
 
 _watching_compiles = False
 
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+#: jax.monitoring's other duration events of a lowering -> stage of
+#: ``engine_stage_secs_total`` (and ``<stage>_s`` on the span)
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+#: jax.monitoring's events of the persistent cache -> result of
+#: ``engine_cache_total`` (a miss is a program compiled AND written:
+#: none with the cache off)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class _Traces(threading.local):
+    """(start, seconds) of the calling thread's trace events that no
+    later one has been found to hold, in order."""
+
+    def __init__(self):
+        self.seen: List[Tuple[float, float]] = []
+
+
+_traces = _Traces()
+
+
+def _outermost_trace_secs(secs: float) -> float:
+    """jax fires the trace event of a jit traced inside another's
+    trace too (hundreds in one train step's), the inner seconds lying
+    inside the outer's, which ends later: of an event's seconds only
+    those that no earlier event of this thread has counted."""
+    start = time.monotonic() - secs
+    seen, inside = _traces.seen, 0.0
+    while seen and seen[-1][0] >= start:
+        inside += seen.pop()[1]
+    seen.append((start, secs))
+    if len(seen) > 1 << 16:  # a process's eager ops, one after another
+        del seen[:1 << 15]
+    return max(0.0, secs - inside)
+
 
 def watch_compiles():
     """Count, from now to the end of the process, every program the
     backend compiles or loads from the persistent cache
     (``engine_compiles_total``) and the seconds that took
-    (``engine_compile_secs_total``). JAX cannot drop a listener, so
-    there is one a process, registered by the first engine."""
+    (``engine_compile_secs_total``), the seconds of the other stages
+    of a lowering (``engine_stage_secs_total{stage}``: ``trace``,
+    ``lower``, ``cache_load``; ``facts`` is the engine's) and the
+    persistent cache's answers (``engine_cache_total{result}``). While
+    spans are on, each event is also added to the innermost open span
+    of the thread that compiles (``trace_s``, ``lower_s``,
+    ``backend_s``, ``cache_load_s``, ``programs``, ``cache_hits``,
+    ``cache_misses``; a load's seconds lie inside ``backend_s``). JAX
+    cannot drop a listener, so there is one a process, registered by
+    ``quickstart.main`` or the first engine."""
     global _watching_compiles
     if _watching_compiles:
         return
     from jax import monitoring
 
+    from realhf_tpu.obs import tracing
+
     def on_secs(event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
+        if event == _BACKEND_EVENT:
             inc("engine_compiles_total")
             inc("engine_compile_secs_total", secs)
+            tracing.add_to_current_span(backend_s=secs, programs=1)
+            return
+        stage = _STAGE_EVENTS.get(event)
+        if stage is None:
+            return
+        if stage == "trace":
+            secs = _outermost_trace_secs(secs)
+        inc("engine_stage_secs_total", secs, stage=stage)
+        tracing.add_to_current_span(**{f"{stage}_s": secs})
+
+    def on_event(event, **_):
+        result = _CACHE_EVENTS.get(event)
+        if result is not None:
+            inc("engine_cache_total", result=result)
+            # cache_hits, cache_misses: the event's own last name
+            tracing.add_to_current_span(**{event.rsplit("/", 1)[-1]: 1})
 
     monitoring.register_event_duration_secs_listener(on_secs)
+    monitoring.register_event_listener(on_event)
     _watching_compiles = True
 
 

@@ -330,12 +330,15 @@ class ProgramFacts:
     compiler's own count, bytes on one device (:data:`MEMORY_FIELDS`);
     ``attributes``: what else the owner read from the same text (the
     engine's ``decode_kernel``, ``decode_layer_copies``,
-    ``flash_fwd_per_bwd``, ``attn_proj_remat_products``)."""
+    ``flash_fwd_per_bwd``, ``attn_proj_remat_products``);
+    ``text_bytes``: the length of the text that was read (span
+    ``engine:facts``; no part of ``as_dict``)."""
     module: str
     fingerprint: str
     ops: Dict[str, Tuple]
     memory: Dict[str, int]
     attributes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    text_bytes: int = 0
 
     def as_dict(self) -> Dict[str, Any]:
         return dict(module=self.module, fingerprint=self.fingerprint,
@@ -357,7 +360,8 @@ def read_program(compiled, derive: Optional[Callable[[str], Dict]] = None
                                     digest_size=8).hexdigest(),
         ops=parse_program(text),
         memory={f: int(getattr(stats, f, 0) or 0) for f in MEMORY_FIELDS},
-        attributes=derive(text) if derive is not None else {})
+        attributes=derive(text) if derive is not None else {},
+        text_bytes=len(text))
 
 
 # ----------------------------------------------------------------------

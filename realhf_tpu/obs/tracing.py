@@ -29,6 +29,14 @@ event per line); :func:`merge_traces` folds every per-process file of
 a run into one ``merged_trace.json``. Without a path they stay in
 memory until ``stop``.
 
+Set-up: ``quickstart.main`` is a caller of the control too. Its first
+statement, :func:`start_setup`, starts a capture iff none is running;
+:func:`end_setup` stops it when the runner's first step has ended (or
+the program hands over to a launcher, or raised) and tells the operator
+what it held (``obs/setup.py``). A capture that a caller or
+``REALHF_TPU_TRACE=1`` has running takes the ``setup:*`` spans instead
+and is left alone.
+
 Clocks: a span takes ``time.monotonic()`` (steady, and the clock the
 benchmark's harness reads); :data:`EPOCH_OFFSET`, taken once a
 process, turns it into wall-clock time at Chrome export only, so the
@@ -93,7 +101,8 @@ CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "moe_held_pairs_total", "conv_tokens_total",
                     "moe_share_overflow_total", "delta_tokens_total",
                     "sparse_pairs_total", "index_tokens_total",
-                    "index_blocks_total", "ssm_tokens_total")
+                    "index_blocks_total", "ssm_tokens_total",
+                    "engine_stage_secs_total", "engine_cache_total")
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",
@@ -333,6 +342,8 @@ class Tracer:
         self._annotation = None
         self._profile_dir: Optional[str] = None
         self._started: Optional[float] = None
+        #: ``_started`` of the capture that start_setup() began
+        self._setup_started: Optional[float] = None
         self._counters_at_start: Dict[str, float] = {}
         self._gauge_writes_at_start: Dict[str, int] = {}
         #: what the last few start .. stop pairs recorded, newest last
@@ -425,6 +436,42 @@ class Tracer:
         self._started, self.sync = None, False
         self._captures.append(capture)
         return capture
+
+    def start_setup(self):
+        """The program records its own set-up (``quickstart.main``'s
+        first statement): an unsynced capture with no profile, begun
+        iff none is running, so a caller's ``start`` before it keeps
+        the whole run in the caller's capture."""
+        if self._started is None:
+            self.start()
+            self._setup_started = self._started
+
+    def _owns_setup(self) -> bool:
+        return self._setup_started is not None \
+            and self._setup_started == self._started
+
+    def release_setup(self) -> bool:
+        """The set-up capture is no longer ``start_setup``'s to stop;
+        True where it is the one still running (under
+        ``REALHF_TPU_TRACE=1`` it then becomes the run's)."""
+        mine, self._setup_started = self._owns_setup(), None
+        return mine
+
+    def setup_spans(self, on: bool):
+        """Spans of the program's OWN set-up capture off or on again,
+        the capture running on: the runner turns them off between the
+        call of ``run_step`` and that method's body, so that what a
+        caller has wrapped around it (a harness's reference comparison
+        before the first step) is no part of the program's record of
+        itself. Nothing where the running capture is a caller's."""
+        if self._owns_setup():
+            self.enabled = on
+
+    def end_setup(self) -> Optional[Capture]:
+        """Stop the capture ``start_setup`` began, iff it is the one
+        still running (the first step has ended, or the program hands
+        over or raised before it); None otherwise."""
+        return self.stop() if self.release_setup() else None
 
     @staticmethod
     def _programs(spans: List[Span], profile_dir: Optional[str]
@@ -636,6 +683,29 @@ def stop() -> Optional[Capture]:
     return _default.stop()
 
 
+def start_setup():
+    _default.start_setup()
+
+
+def end_setup() -> Optional[Capture]:
+    """Ends the program's own set-up capture and, where there was one,
+    says what it held (``obs/setup.py``: gauge ``setup_seconds`` and
+    one INFO line)."""
+    capture = _default.end_setup()
+    if capture is not None:
+        from realhf_tpu.obs import setup
+        setup.report(capture)
+    return capture
+
+
+def release_setup() -> bool:
+    return _default.release_setup()
+
+
+def setup_spans(on: bool):
+    _default.setup_spans(on)
+
+
 def last_capture() -> Optional[Capture]:
     return _default.last_capture()
 
@@ -663,8 +733,22 @@ def current_context() -> Optional[SpanContext]:
 
 def current_span():
     """The calling thread's innermost open span; the no-op span where
-    there is none, so a callee can annotate its caller's span."""
-    return _default.current_span() or NOOP_SPAN
+    there is none or spans are off (a span left open over a stretch
+    with spans off is not that stretch's caller), so a callee can
+    annotate its caller's span."""
+    return (_default.current_span() if _default.enabled else None) \
+        or NOOP_SPAN
+
+
+def add_to_current_span(**amounts: float):
+    """Add numbers to attributes of the calling thread's innermost
+    open span (``metrics.watch_compiles``: the stages of a lowering go
+    to the span that caused it); nothing where none is open or spans
+    are off."""
+    sp = current_span()
+    if sp is not NOOP_SPAN:
+        for key, amount in amounts.items():
+            sp.attributes[key] = sp.attributes.get(key, 0) + amount
 
 
 def attach(parent: Optional[SpanContext]):

@@ -83,27 +83,32 @@ class InlineRunner:
                     mspec.restore_optimizer_state = True
                     logger.info("Recovered %s from %s", role, ckpt)
 
-        import realhf_tpu.datasets  # noqa: F401 - register datasets
-        import realhf_tpu.interfaces  # noqa: F401 - register interfaces
+        with tracing.span("setup:imports"):
+            import realhf_tpu.datasets  # noqa: F401 - register datasets
+            import realhf_tpu.interfaces  # noqa: F401 - register interfaces
 
         self.dfg = DFG(spec.mfcs)
-        self.tokenizer = spec.tokenizer or (
-            data_api.load_hf_tokenizer(spec.tokenizer_path)
-            if spec.tokenizer_path else None)
+        with tracing.span("setup:data") as sp:
+            t0 = time.monotonic()
+            self.tokenizer = spec.tokenizer or (
+                data_api.load_hf_tokenizer(spec.tokenizer_path)
+                if spec.tokenizer_path else None)
+            sp.set_attribute("tokenizer_s", time.monotonic() - t0)
 
-        src = self.dfg.sources[0]
-        self.dataset = data_api.make_dataset(
-            spec.dataset, seed=spec.seed, dp_rank=0, world_size=1,
-            tokenizer_or_path=self.tokenizer)
-        self.dataloader = data_api.PackedDataLoader(
-            self.dataset, batch_size=src.n_seqs, seed=spec.seed)
-        self.eval_dataloader = None
-        if spec.eval_dataset is not None:
-            eval_ds = data_api.make_dataset(
-                spec.eval_dataset, seed=spec.seed, dp_rank=0, world_size=1,
+            src = self.dfg.sources[0]
+            self.dataset = data_api.make_dataset(
+                spec.dataset, seed=spec.seed, dp_rank=0, world_size=1,
                 tokenizer_or_path=self.tokenizer)
-            self.eval_dataloader = data_api.PackedDataLoader(
-                eval_ds, batch_size=src.n_seqs, shuffle=False)
+            self.dataloader = data_api.PackedDataLoader(
+                self.dataset, batch_size=src.n_seqs, seed=spec.seed)
+            sp.set_attribute("sequences", len(self.dataset))
+            self.eval_dataloader = None
+            if spec.eval_dataset is not None:
+                eval_ds = data_api.make_dataset(
+                    spec.eval_dataset, seed=spec.seed, dp_rank=0,
+                    world_size=1, tokenizer_or_path=self.tokenizer)
+                self.eval_dataloader = data_api.PackedDataLoader(
+                    eval_ds, batch_size=src.n_seqs, shuffle=False)
 
         steps_per_epoch = len(self.dataloader)
         total_steps = steps_per_epoch * spec.total_train_epochs
@@ -157,6 +162,7 @@ class InlineRunner:
     def run_step(self, batch: data_api.SequenceSample) -> Dict[str, Dict]:
         """Execute the full DFG once over one batch; returns per-MFC
         stats (mirrors one master-worker _poll iteration)."""
+        tracing.setup_spans(True)
         stats: Dict[str, Dict] = {}
         data = batch
         # Execute level by level; independent MFCs within a level run
@@ -240,8 +246,16 @@ class InlineRunner:
                 t0 = time.monotonic()
                 with tracing.span("step", epoch=epoch, epoch_step=step,
                                   global_step=self.global_step + 1):
+                    # what a caller wraps around run_step is not the
+                    # program's set-up: its own capture takes no span
+                    # from here to that method's body
+                    tracing.setup_spans(False)
                     last_stats = self.run_step(batch)
                 dt = time.monotonic() - t0
+                # the program's own record of its set-up ends with its
+                # first step (a no-op from the second on, and where
+                # quickstart began none): the run goes on untraced
+                tracing.end_setup()
                 self.global_step += 1
                 metrics.inc("master_steps_total")
                 metrics.observe("master_step_secs", dt)

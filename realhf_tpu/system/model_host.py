@@ -14,6 +14,8 @@ import os
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from realhf_tpu.api import data as data_api
 from realhf_tpu.api import model as model_api
 from realhf_tpu.api.config import ModelInterfaceType, ModelName
@@ -25,7 +27,7 @@ from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.models.hf import load_hf_checkpoint
 from realhf_tpu.obs import tracing
 from realhf_tpu.parallel.mesh import MeshContext, make_mesh
-from realhf_tpu.parallel.realloc import ReplicaManager
+from realhf_tpu.parallel.realloc import ReplicaManager, tree_bytes
 
 logger = logging.getLogger("model_host", "benchmark")
 
@@ -116,7 +118,25 @@ def build_model(role: str, spec, tokenizer, total_steps: int,
     ``seed_role``: role name to derive the random-init key from when
     it differs from ``role`` -- a CROSS-GROUP replica must initialize
     bit-identically to its role's primary living in another process,
-    even though its display name carries the MFC suffix."""
+    even though its display name carries the MFC suffix.
+
+    Span ``setup:model`` (one a role and one a replica), with the load
+    or the init, the sharding and the optimizer's state beneath it."""
+    with tracing.span("setup:model", role=role,
+                      replica=params_override is not None
+                      or seed_role is not None) as sp:
+        model = _build_model(role, spec, tokenizer, total_steps, devices,
+                             params_override, cfg_override, init_seed,
+                             seed_role)
+        if sp is not tracing.NOOP_SPAN:
+            sp.set_attribute("params", sum(
+                x.size for x in jax.tree.leaves(model.engine.params)))
+            sp.set_attribute("bytes", tree_bytes(model.engine.params))
+    return model
+
+
+def _build_model(role, spec, tokenizer, total_steps, devices,
+                 params_override, cfg_override, init_seed, seed_role):
     from realhf_tpu.parallel.mesh import default_devices
 
     # One mesh for both the (possibly streamed) load and the Engine:
@@ -131,19 +151,24 @@ def build_model(role: str, spec, tokenizer, total_steps: int,
         # Engine.__init__ reshards them) instead of re-reading the
         # checkpoint.
         cfg, params = cfg_override, params_override
-    elif spec.path and _agreed_streamed_load(spec, mesh, role):
-        # Host-RAM-bounded: stream layer-by-layer straight onto the
-        # mesh (needed for >host-RAM models; hf/registry.py).
-        from realhf_tpu.models.hf import load_hf_checkpoint_streamed
-
-        cfg, params = load_hf_checkpoint_streamed(
-            spec.path, mesh, spec.hf_family,
-            is_critic=spec.is_critic or spec.init_critic_from_actor,
-            param_dtype="bfloat16" if spec.bf16 else None)
     elif spec.path:
-        cfg, params = load_hf_checkpoint(
-            spec.path, spec.hf_family,
-            is_critic=spec.is_critic or spec.init_critic_from_actor)
+        # Host-RAM-bounded where agreed: stream layer-by-layer
+        # straight onto the mesh (needed for >host-RAM models;
+        # hf/registry.py).
+        streamed = _agreed_streamed_load(spec, mesh, role)
+        critic = spec.is_critic or spec.init_critic_from_actor
+        with tracing.span("setup:model:load", path=spec.path,
+                          streamed=streamed) as sp:
+            if streamed:
+                from realhf_tpu.models.hf import load_hf_checkpoint_streamed
+
+                cfg, params = load_hf_checkpoint_streamed(
+                    spec.path, mesh, spec.hf_family, is_critic=critic,
+                    param_dtype="bfloat16" if spec.bf16 else None)
+            else:
+                cfg, params = load_hf_checkpoint(
+                    spec.path, spec.hf_family, is_critic=critic)
+            sp.set_attribute("bytes", tree_bytes(sp.result(params)))
     else:
         if spec.random_init_config is None:
             raise ValueError(
@@ -172,7 +197,9 @@ def build_model(role: str, spec, tokenizer, total_steps: int,
         key = (seeding.derive_key_from(init_seed, "model_init", skey)
                if init_seed is not None
                else seeding.derive_key("model_init", skey))
-        params = T.init_params(cfg, key)
+        with tracing.span("setup:model:init") as sp:
+            params = sp.result(T.init_params(cfg, key))
+            sp.set_attribute("bytes", tree_bytes(params))
 
     ctx = MeshContext(ModelName(role, 0), mesh, spec.parallel)
     engine = Engine(cfg, ctx, params, optimizer=spec.optimizer,

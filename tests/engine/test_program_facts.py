@@ -232,6 +232,12 @@ def test_profiled_capture_carries_its_programs(tmp_path, reads):
     # the train and logprobs texts were read at the stop, once each
     assert sorted(reads) == ["generate", "logprobs", "train"]
     spans = first.named("engine:")
+    # the generate program's text was read while spans were on: that
+    # read has a span of its own (the two read at the stop have none)
+    facts_read = spans.pop()
+    assert facts_read["name"] == "engine:facts"
+    assert facts_read["attributes"]["program"] == "jit_generate"
+    assert facts_read["attributes"]["bytes"] > 0
     assert [s["name"] for s in spans] == [
         "engine:train", "engine:logprobs", "engine:generate"]
     for span in spans:
