@@ -46,7 +46,7 @@ from realhf_tpu.ops.attention import flash_takes
 from realhf_tpu.ops.decode_attention import (
     mesh_nontrivial as _mesh_nontrivial,
 )
-from realhf_tpu.ops.delta_rule import scan_kernel_calls
+from realhf_tpu.ops.delta_rule import scan_handed, scan_kernel_calls
 from realhf_tpu.ops.ssm_scan import scan_kernel_calls as ssm_kernel_calls
 from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
                                             flash_mask_calls)
@@ -607,7 +607,11 @@ class Engine:
         with delta layers ``delta_scan_kernel_calls``
         (``ops.delta_rule.scan_kernel_calls``: the chunked scan's
         kernels in the text: a forward and a backward one a delta
-        layer of a train program, 0 where the XLA products run), of
+        layer of a train program, 0 where the XLA products run) and,
+        of a train program, ``delta_scan_handed``
+        (``ops.delta_rule.scan_handed``: the arrays a backward kernel
+        takes from the forward one, 2 = every chunk's start state and
+        its pairs and inverse, 0 where the XLA products run), of
         every program of a model with ssm layers
         ``ssm_scan_kernel_calls`` (``ops.ssm_scan.scan_kernel_calls``:
         the same of the Mamba-2 scan's two kernels), and
@@ -618,9 +622,10 @@ class Engine:
         gauge
         ``engine_program_bytes{role,program,kind}``."""
         if (name, key) not in self._facts:
+            train = name.startswith("train")
             if name == "generate":
                 mine = self._decode_facts(key)
-            elif name.startswith("train"):
+            elif train:
                 def mine(text):
                     return dict(
                         flash_fwd_per_bwd=flash_fwd_per_bwd(text),
@@ -640,6 +645,8 @@ class Engine:
                 if delta:
                     out.update(delta_scan_kernel_calls=scan_kernel_calls(
                         text))
+                    if train:
+                        out.update(delta_scan_handed=scan_handed(text))
                 if ssm:
                     out.update(ssm_scan_kernel_calls=ssm_kernel_calls(
                         text))

@@ -56,11 +56,15 @@ half of this file): a grid over (row, head, block of chunks) with the
 blocks in order and the state in a VMEM scratch; a chunk's running decays,
 pairs, inverse, ``U``, ``O`` and next state are made in VMEM from the
 row's operands as they lie in HBM (a head's 128 columns of ``[L, H x
-d]``, in the caller's dtype), and only ``o``, the last state and every
-chunk's START state (what the backward is handed: ``RESIDUAL_NAMES``)
-are written. The backward walks the chunks in reverse with the end
-state's cotangent in VMEM, makes a chunk's coefficients AGAIN from its
-operands and its start state, and writes d of q, k, v, g and beta. A
+d]``, in the caller's dtype), and only ``o`` and the last state are
+written, and under a gradient what the backward is handed
+(``RESIDUAL_NAMES``): every chunk's START state and its masked pairs
+and triangular inverse (``A``, ``B`` and ``(I + Diag(beta) A)^-1``,
+the two parts of a chunk that the matrix unit helps least with). The
+backward walks the chunks in reverse with the end state's cotangent in
+VMEM, reads those, makes the chunk's other coefficients (the decays,
+``Prepare``, ``U``) AGAIN from its operands and its start state, and
+writes d of q, k, v, g and beta. A
 layer's ``prepare`` handed over as a ``Prepare`` (data: the l2 norm's
 epsilon and scale, the decay's rate and bias) is applied and
 differentiated inside the kernels, so nothing of the row is ever
@@ -100,7 +104,8 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from realhf_tpu.base.backend import pallas_enabled
-from realhf_tpu.ops.hlo_text import device_instructions
+from realhf_tpu.ops.hlo_text import (custom_call_operands,
+                                     device_instructions)
 
 #: tokens a chunk, and tokens a sub-block inside it
 CHUNK, SUB = 64, 16
@@ -368,13 +373,18 @@ def delta_rule_step(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 #: device trace (the engine's ``delta_scan_kernel_calls``)
 DELTA_FWD, DELTA_BWD = "delta_fwd", "delta_bwd"
 #: what the forward kernel hands the backward one besides the operands
-#: (``checkpoint_name``): every chunk's START state, transposed [dv,
-#: dk], float32: ``B x H x L / 64`` of them a layer. A rematerialised
-#: block that keeps it and the scan's output runs no forward kernel in
-#: its backward (``models/transformer.py:DELTA_RESIDUALS``).
-RESIDUAL_NAMES = ("delta_starts",)
+#: (``checkpoint_name``), float32, ``B x H x L / 64`` of each a layer:
+#: every chunk's START state, transposed [dv, dk] (64 KB at heads of
+#: 128), and its masked pairs and triangular inverse, three [64, 64]
+#: laid out as one [96, 128] (``_hand_over``, 48 KB). A rematerialised
+#: block that keeps them and the scan's output runs no forward kernel
+#: in its backward (``models/transformer.py:DELTA_RESIDUALS``), and the
+#: backward kernel makes neither the pairs nor the inverse again.
+RESIDUAL_NAMES = ("delta_starts", "delta_pairs")
 _LANES = 128
 _M = CHUNK // SUB
+#: operands of both kernels: meta, beta, q, k, v, g, decay
+_OPERANDS = 7
 
 
 def kernel_takes(dk: int, dv: int) -> bool:
@@ -392,6 +402,19 @@ def scan_kernel_calls(hlo_text: str) -> int:
     return sum(opcode == "custom-call"
                and (DELTA_FWD in name or DELTA_BWD in name)
                for name, _, opcode in device_instructions(hlo_text))
+
+
+def scan_handed(hlo_text: str) -> int:
+    """The arrays a backward kernel of a compiled program
+    (``Engine.compiled_text``) takes from the forward one, besides the
+    scan's own operands and the two cotangents: ``len(RESIDUAL_NAMES)``
+    (every chunk's start state; its pairs and inverse), 1 where only
+    the start states are handed over and the backward makes the rest
+    again, the least over the program's backward calls; 0 where it
+    holds none (the XLA path, a program without a gradient)."""
+    return min((operands - _OPERANDS - 2
+                for name, operands in custom_call_operands(hlo_text)
+                if DELTA_BWD in name), default=0)
 
 
 def _one_pass() -> bool:
@@ -542,14 +565,23 @@ def _lane_sum(x3):
         m, r, 1)
 
 
-def _pairs(q, k, big_g, to_first, one_pass, scratch):
-    """``_pair_products`` of one chunk into ``scratch["kk"]``,
-    ``scratch["qk"]`` [M, SUB, C] (every s <= t; the caller masks)."""
-    c, dk = k.shape
+def _pair_operands(q, k, to_first, scratch):
+    """What the pairs AND their gradient read of a chunk, into
+    ``scratch``: q and k by sub-block (``k3``, ``q3``) and decayed from
+    their sub-block's first row (``lk``, ``lq``)."""
+    scratch["k3"][...], scratch["q3"][...] = _rows3(k), _rows3(q)
+    scratch["lk"][...] = k * to_first
+    scratch["lq"][...] = q * to_first
+
+
+def _pairs(k, big_g, one_pass, scratch):
+    """``_pair_products`` of one chunk (its ``_pair_operands`` in
+    ``scratch``) into ``scratch["kk"]``, ``scratch["qk"]`` [M, SUB, C]
+    (every s <= t; the caller masks)."""
+    c = k.shape[0]
     g3_ref, k3_ref, q3_ref = (scratch[x] for x in ("g3", "k3", "q3"))
     kk_ref, qk_ref, lk_ref, lq_ref = (
         scratch[x] for x in ("kk", "qk", "lk", "lq"))
-    k3_ref[...], q3_ref[...] = _rows3(k), _rows3(q)
     kk_ref[...] = jnp.zeros_like(kk_ref)
     qk_ref[...] = jnp.zeros_like(qk_ref)
 
@@ -562,8 +594,6 @@ def _pairs(q, k, big_g, to_first, one_pass, scratch):
                 out_ref[:, rows, :])
 
     _over_own_rows(inside)
-    lk_ref[...] = k * to_first
-    lq_ref[...] = q * to_first
 
     for i in range(1, _M):  # across sub-blocks: the later one's rows
         right = k * _later_first(g3_ref, big_g, i)
@@ -607,12 +637,38 @@ def _chunk_rows(r):
     return pl.ds(pl.multiple_of(r * CHUNK, CHUNK), CHUNK)
 
 
-def _chunk_forward(refs, n, r, static, scratch):
+#: a chunk's block of what ``_hand_over`` lays out
+_HANDED = (CHUNK + CHUNK // 2, 2 * CHUNK)
+
+
+def _hand_over(ref, r, a, bm, x):
+    """A chunk's masked pairs and its inverse, each [C, C], into block
+    r of ``ref`` [per, *_HANDED]: x beside bm over a's upper half
+    of rows beside its lower half, so that every lane of the 128 that
+    float32 is tiled by holds a value (three arrays with a minor
+    dimension of 64 would be padded to twice their bytes)."""
+    c, half = CHUNK, CHUNK // 2
+    ref[r, 0:c, 0:c] = x
+    ref[r, 0:c, c:] = bm
+    ref[r, c:, 0:c] = a[:half]
+    ref[r, c:, c:] = a[half:]
+
+
+def _handed(ref, r):
+    """``(a, bm, x)`` as ``_hand_over`` laid them out."""
+    c = CHUNK
+    a = jnp.concatenate([ref[r, c:, 0:c], ref[r, c:, c:]], axis=0)
+    return a, ref[r, 0:c, c:], ref[r, 0:c, 0:c]
+
+
+def _chunk_forward(refs, n, r, static, scratch, handed=None):
     """What both kernels make of chunk n of the row, chunk r of the
     block in VMEM, from its operands and its start state
     (``scratch.state`` [dv, dk], TRANSPOSED: the decay then runs along
     lanes): a dict of the chunk's coefficients, all float32 values in
-    VMEM."""
+    VMEM. ``handed``: the chunk's ``(a, bm, x)`` where the forward
+    kernel kept them (the backward's: neither the pairs' products nor
+    the inverse run again), None where they are to be made."""
     f32 = jnp.float32
     one_pass, fused, scale, eps = static
     meta_ref, beta_ref, q_ref, k_ref, v_ref, g_ref, decay_ref = refs
@@ -626,12 +682,16 @@ def _chunk_forward(refs, n, r, static, scratch):
     beta_row = beta_ref[pl.ds(n, 1), :]
     beta = _column(beta_row)
     big_g, to_first = _decays(g, scratch["g3"])
-    _pairs(q, k, big_g, to_first, one_pass, scratch)
+    _pair_operands(q, k, to_first, scratch)
     strict = _iota((c, c), 0) > _iota((c, c), 1)
     lower = _iota((c, c), 0) >= _iota((c, c), 1)
-    a = jnp.where(same & strict, scratch["kk"][...].reshape(c, c), 0.0)
-    bm = jnp.where(same & lower, scratch["qk"][...].reshape(c, c), 0.0)
-    x = _inverse(beta * a, scratch["x3"])
+    if handed is None:
+        _pairs(k, big_g, one_pass, scratch)
+        a = jnp.where(same & strict, scratch["kk"][...].reshape(c, c), 0.0)
+        bm = jnp.where(same & lower, scratch["qk"][...].reshape(c, c), 0.0)
+        x = _inverse(beta * a, scratch["x3"])
+    else:
+        a, bm, x = handed
     state = scratch["state"][...]
     decay = jnp.exp(big_g)
     k_in = jnp.where(began, k * decay, 0.0)
@@ -653,11 +713,12 @@ def _scratch_shapes(dk, dv, backward):
     """name -> float32 VMEM scratch of a kernel."""
     by_rows, square = (_M, SUB, dk), (_M, SUB, CHUNK)
     shapes = dict(state=(dv, dk), g3=by_rows, k3=by_rows, q3=by_rows,
-                  lk=(CHUNK, dk), lq=(CHUNK, dk), kk=square, qk=square,
-                  x3=square)
+                  lk=(CHUNK, dk), lq=(CHUNK, dk), kk=square, qk=square)
     if backward:
         shapes.update(dstate=(dv, dk), dkl=by_rows, dqp=by_rows,
                       dkr=by_rows, tf=by_rows)
+    else:
+        shapes.update(x3=square)
     return {name: pltpu.VMEM(shape, jnp.float32)
             for name, shape in shapes.items()}
 
@@ -665,10 +726,11 @@ def _scratch_shapes(dk, dv, backward):
 def _forward_kernel(static, keep_starts, names, *refs):
     """Grid (row, head, block of ``per`` chunks), the blocks in order
     and a loop over a block's chunks inside, the state in VMEM: o, the
-    state after the row (transposed) and, for the backward, every
-    chunk's start state."""
+    state after the row (transposed) and, for the backward
+    (``keep_starts``), every chunk's start state, masked pairs and
+    inverse (``_hand_over``)."""
     ins, refs = refs[:_OPERANDS], refs[_OPERANDS:]
-    n_out = 3 if keep_starts else 2
+    n_out = 2 + (len(RESIDUAL_NAMES) if keep_starts else 0)
     outs, scratch = refs[:n_out], dict(zip(names, refs[n_out:]))
     o_ref, last_ref = outs[:2]
     one_pass = static[0]
@@ -683,6 +745,8 @@ def _forward_kernel(static, keep_starts, names, *refs):
         if keep_starts:
             outs[2][r] = scratch["state"][...]
         m = _chunk_forward(ins, step * per + r, r, static, scratch)
+        if keep_starts:
+            _hand_over(outs[3], r, m["a"], m["bm"], m["x"])
         o = _dot(m["q_in"], m["state"], (1, 1), one_pass) \
             + _dot(m["bm"], m["u"], (1, 0), one_pass)
         o_ref[_chunk_rows(r), :] = o.astype(o_ref.dtype)
@@ -699,13 +763,14 @@ def _forward_kernel(static, keep_starts, names, *refs):
 
 def _backward_kernel(static, names, *refs):
     """The blocks, and the chunks inside a block, in REVERSE with the
-    end state's cotangent in VMEM: a chunk's coefficients are made
-    again from its operands and its start state, then d of q, k, v, g
-    and beta (through ``Prepare`` where the kernel applied it: then
-    also d of the decay's rate and ``dt_bias``, added up over the
+    end state's cotangent in VMEM: a chunk's masked pairs and inverse
+    are read as the forward kernel kept them, its other coefficients
+    made again from its operands and its start state, then d of q, k,
+    v, g and beta (through ``Prepare`` where the kernel applied it:
+    then also d of the decay's rate and ``dt_bias``, added up over the
     row's chunks)."""
     ins, refs = refs[:_OPERANDS], refs[_OPERANDS:]
-    (starts_ref, do_ref, dlast_ref), refs = refs[:3], refs[3:]
+    (starts_ref, pairs_ref, do_ref, dlast_ref), refs = refs[:4], refs[4:]
     outs, scratch = refs[:6], dict(zip(names, refs[6:]))
     step, steps = pl.program_id(2), pl.num_programs(2)
     per = do_ref.shape[0] // CHUNK
@@ -719,20 +784,22 @@ def _backward_kernel(static, names, *refs):
         r = per - 1 - i
         scratch["state"][...] = starts_ref[r]
         _chunk_backward(ins, (steps - 1 - step) * per + r, r, static,
-                        scratch, do_ref, outs)
+                        scratch, _handed(pairs_ref, r), do_ref, outs)
         return carry
 
     jax.lax.fori_loop(0, per, chunk, 0)
 
 
-def _chunk_backward(ins, n, r, static, scratch, do_ref, outs):
-    """Chunk n of the row, chunk r of the block: its coefficients again,
-    the end state's cotangent in ``scratch.dstate`` taken to the start
-    state's, and the chunk's rows of the gradients."""
+def _chunk_backward(ins, n, r, static, scratch, handed, do_ref, outs):
+    """Chunk n of the row, chunk r of the block: its coefficients
+    again but for ``handed`` (its masked pairs and inverse, the
+    forward kernel's), the end state's cotangent in ``scratch.dstate``
+    taken to the start state's, and the chunk's rows of the
+    gradients."""
     (dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ddecay_ref) = outs
     one_pass = static[0]
     rows = _chunk_rows(r)
-    m = _chunk_forward(ins, n, r, static, scratch)
+    m = _chunk_forward(ins, n, r, static, scratch, handed)
     q, k, u, x, state = m["q"], m["k"], m["u"], m["x"], m["state"]
     c, dk = k.shape
     do = do_ref[rows, :].astype(jnp.float32)
@@ -828,10 +895,6 @@ def _chunk_backward(ins, n, r, static, scratch, do_ref, outs):
                                         axis=0, keepdims=True)
 
 
-#: operands of both kernels: meta, beta, q, k, v, g, decay
-_OPERANDS = 7
-
-
 #: chunks a block (a grid step) at most. Worth little: at the cell's
 #: shape 8 read 2.5% (forward) and 3.3% (gradient) under 1, and 16 and
 #: 32 under 0.5% more (a step's copies run beside the block before's
@@ -862,13 +925,22 @@ def _operand_specs(n, per, dk, dv, block_of):
             pl.BlockSpec((None, 2, dk), lambda i, j, s: (j, 0, 0))]
 
 
-def _state_spec(dk, dv, per=None, block_of=None):
-    """A [dv, dk] block of [B, H, dv, dk] (a row's last state or its
-    cotangent), or ``per`` chunks' of [B, H, N, dv, dk]."""
-    if per is None:
-        return pl.BlockSpec((None, None, dv, dk),
-                            lambda i, j, s: (i, j, 0, 0))
-    return pl.BlockSpec((None, None, per, dv, dk),
+def _state_spec(dk, dv):
+    """A [dv, dk] block of [B, H, dv, dk]: a row's last state or its
+    cotangent."""
+    return pl.BlockSpec((None, None, dv, dk), lambda i, j, s: (i, j, 0, 0))
+
+
+def _kept_shapes(dk, dv):
+    """What the forward keeps of a chunk for the backward, in the
+    order of ``RESIDUAL_NAMES``: its start state [dv, dk] and what
+    ``_hand_over`` lays out."""
+    return (dv, dk), _HANDED
+
+
+def _kept_spec(shape, per, block_of):
+    """``per`` chunks' blocks of a kept [B, H, N, *shape]."""
+    return pl.BlockSpec((None, None, per) + shape,
                         lambda i, j, s: (i, j, block_of(s), 0, 0))
 
 
@@ -889,8 +961,9 @@ def _forward_call(static, keep_starts, *operands):
                               lambda i, j, s: (i, s, j)),
                  _state_spec(dk, dv)]
     if keep_starts:
-        out_shape.append(jax.ShapeDtypeStruct((b, h, n, dv, dk), f32))
-        out_specs.append(_state_spec(dk, dv, per, lambda s: s))
+        for kept in _kept_shapes(dk, dv):
+            out_shape.append(jax.ShapeDtypeStruct((b, h, n) + kept, f32))
+            out_specs.append(_kept_spec(kept, per, lambda s: s))
     return pl.pallas_call(
         functools.partial(_forward_kernel, static, keep_starts,
                           tuple(scratch)),
@@ -905,7 +978,7 @@ def _forward_call(static, keep_starts, *operands):
     )(*operands)
 
 
-def _backward_call(static, operands, starts, do, dlast):
+def _backward_call(static, operands, starts, pairs, do, dlast):
     _, beta, q, k, v, g, decay = operands
     b, h, n, _ = beta.shape
     dk, dv = q.shape[-1] // h, v.shape[-1] // h
@@ -931,15 +1004,16 @@ def _backward_call(static, operands, starts, do, dlast):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=(b, h, blocks),
             in_specs=_operand_specs(n, per, dk, dv, back) + [
-                _state_spec(dk, dv, per, back), rows(dv),
-                _state_spec(dk, dv)],
+                _kept_spec(kept, per, back)
+                for kept in _kept_shapes(dk, dv)] + [
+                rows(dv), _state_spec(dk, dv)],
             out_specs=[rows(dk), rows(dk), rows(dv), rows(dk),
                        whole(n, CHUNK), whole(2, dk)],
             scratch_shapes=list(scratch.values())),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS),
         name=DELTA_BWD,
-    )(*operands, starts, do, dlast)
+    )(*operands, starts, pairs, do, dlast)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -954,15 +1028,15 @@ def _scan(static, meta, beta, q, k, v, g, decay):
 
 
 def _scan_fwd(static, *operands):
-    o, last, starts = _forward_call(static, True, *operands)
-    starts = checkpoint_name(starts, RESIDUAL_NAMES[0])
-    return (o, last), (operands, starts)
+    o, last, *kept = _forward_call(static, True, *operands)
+    kept = tuple(map(checkpoint_name, kept, RESIDUAL_NAMES))
+    return (o, last), (operands, kept)
 
 
 def _scan_bwd(static, residuals, cotangents):
-    operands, starts = residuals
+    operands, kept = residuals
     dq, dk, dv, dg, dbeta, ddecay = _backward_call(
-        static, operands, starts, *cotangents)
+        static, operands, *kept, *cotangents)
     return (np.zeros(operands[0].shape, jax.dtypes.float0), dbeta, dq, dk,
             dv, dg, ddecay.sum(0))
 

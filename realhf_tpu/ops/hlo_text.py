@@ -11,6 +11,9 @@ _CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*->.*\{\s*$")
 _INSTRUCTION = re.compile(
     r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*?)\s([\w\-]+)\(")
+_CUSTOM_CALL = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = .*?\scustom-call\((.*?)\), "
+    r"custom_call_target")
 
 
 def device_instructions(hlo_text: str) -> Iterator[Tuple[str, str, str]]:
@@ -29,3 +32,17 @@ def device_instructions(hlo_text: str) -> Iterator[Tuple[str, str, str]]:
         m = None if in_fusion else _INSTRUCTION.match(line)
         if m:
             yield m.groups()
+
+
+def custom_call_operands(hlo_text: str) -> Iterator[Tuple[str, int]]:
+    """``(name, number of operands)`` of every custom call of the
+    program (a kernel is one; none lies inside a fusion)."""
+    for line in hlo_text.splitlines():
+        m = _CUSTOM_CALL.match(line) if "custom-call(" in line else None
+        if m:
+            # the commas outside any bracket (a shape has its own)
+            depth = commas = 0
+            for ch in m.group(2):
+                depth += (ch in "([{") - (ch in ")]}")
+                commas += ch == "," and not depth
+            yield m.group(1), commas + 1

@@ -74,6 +74,9 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   a state a token: a row of 2,048 does not fit); and milliseconds of
   the chunked scan alone, forward and gradient, at the cell's shape by
   ``ops/delta_rule.py``'s kernels and by its XLA products side by side,
+  the kernels' forward as a gradient runs it and their backward ALONE
+  (``--against <tree>``: beside another checkout's kernels on the same
+  operands, all nine outputs and gradients compared to the last bit),
   with ``scan_accuracy``: the chunked scan (through the kernels, and
   by the XLA products) and the recurrence token by token in float32 on
   the device, each against the recurrence in float64 on the host.
@@ -670,10 +673,62 @@ def scan_alone(cell, rounds=20):
     rows.update(_relative_gaps(
         "dq dk dv dg dbeta".split(), outs["kernel", "gradient"],
         outs["xla", "gradient"]))
+    rows.update(_scan_by_pass(D, (q, k, v, g, beta), seg, rounds))
     say(phase="scan", shape=list(shape), **rows)
     if len(jax.devices()) >= 4:
         scan_on_mesh(shape, rounds)
     scan_accuracy(shape[1], lin["head_dim"])
+
+
+#: ``--against``: another checkout of this repo (the parent's, say)
+AGAINST = None
+
+
+def _scan_by_pass(D, x, seg, rounds):
+    """The kernels' forward as a gradient runs it (keeping what the
+    backward is handed) and their backward ALONE, milliseconds of
+    each; given ``--against``, the same by that checkout's
+    ``ops/delta_rule.py`` on the same operands, and which of the nine
+    outputs and gradients (o, the last state, d of q, k, v, the decay's
+    pre-activation, beta, and of the decay's rate and ``dt_bias``)
+    differ from it in any bit."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    h, d = x[0].shape[2:]
+    x = (*x, -jnp.ones((h,)), jnp.zeros((h, d)))
+
+    def passes(D):
+        def scan(q, k, v, f, beta, rate, dt_bias):
+            return D._by_kernels(q, k, v, f, beta, seg, D.Prepare(
+                rate=rate, dt_bias=dt_bias, scale=d ** -0.5, eps=1e-6))
+        (outs, pull), kept_ms = _timed(
+            jax.jit(lambda *a: jax.vjp(scan, *a)), x, rounds)
+        grads, back_ms = _timed(
+            jax.jit(lambda pull, ct: pull(ct)),
+            (pull, jax.tree.map(jnp.ones_like, outs)), rounds)
+        return (*outs, *grads), kept_ms, back_ms
+
+    mine, kept_ms, back_ms = passes(D)
+    rows = dict(kept_forward_ms_kernel=kept_ms, backward_ms_kernel=back_ms)
+    if AGAINST is not None:
+        spec = importlib.util.spec_from_file_location(
+            "delta_rule_against", os.path.join(
+                AGAINST, "realhf_tpu", "ops", "delta_rule.py"))
+        other = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(other)
+        theirs, kept_ms, back_ms = passes(other)
+        rows.update(
+            kept_forward_ms_against=kept_ms, backward_ms_against=back_ms,
+            differ_from_against=[
+                name for name, a, b in zip(
+                    "o last dq dk dv df dbeta drate ddt_bias".split(),
+                    mine, theirs)
+                if not np.array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))])
+    return rows
 
 
 def scan_on_mesh(shape, rounds):
@@ -1119,7 +1174,7 @@ def gen(cell, ckpt, work, seed):
 
 
 def main():
-    global FAMILY
+    global FAMILY, AGAINST
     p = argparse.ArgumentParser()
     p.add_argument("family", choices=sorted(FAMILIES))
     p.add_argument("--seeds", type=int, nargs="+", required=True)
@@ -1139,11 +1194,18 @@ def main():
     p.add_argument("--only", nargs="+", default=None,
                    help="kimi_linear: after the first seed's engine "
                         "reading, these of PUBLISHED_PHASES alone")
+    p.add_argument("--against", default=None,
+                   help="kimi_linear's row scan: another checkout of "
+                        "this repo (the parent's, unpacked under a "
+                        "directory .gitignore lists); its delta "
+                        "kernels run on the same operands, the "
+                        "backward's ms of both, and every output and "
+                        "gradient compared to the last bit")
     p.add_argument("--rehearse", action="store_true",
                    help="the tests' tiny cell of the family, on any "
                         "device: finds faults, measures nothing")
     args = p.parse_args()
-    FAMILY = args.family
+    FAMILY, AGAINST = args.family, args.against
     spec = FAMILIES[FAMILY]
 
     import jax

@@ -158,8 +158,13 @@ def test_train_program_names_every_part_the_family_has(family):
     if "delta" in have:
         assert "delta/scan" in passes
         assert facts.attributes["delta_scan_kernel_calls"] == 0
+        # and what a backward kernel takes from the forward one (the
+        # chunks' start states, their pairs and inverses: 2 on the
+        # chip), a train program's alone
+        assert facts.attributes["delta_scan_handed"] == 0
     else:
         assert "delta_scan_kernel_calls" not in facts.attributes
+        assert "delta_scan_handed" not in facts.attributes
     # and the ssm layers' (likewise: ``kernel_takes`` refuses the tiny
     # cell's group of 2 heads of 8; on the chip 6 in the tenth cell)
     if "ssm" in have:

@@ -542,6 +542,52 @@ def test_delta_scan_kernels_compile_at_the_cells_shape(one_chip, dtype,
         text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                         *args).as_text()
     assert D.scan_kernel_calls(text) == 2
+    assert D.scan_handed(text) == len(D.RESIDUAL_NAMES) == 2
+
+
+def test_a_rematerialised_delta_block_runs_each_kernel_once(one_chip):
+    """Two blocks rematerialised under the policy every experiment's
+    blocks have (``models/transformer.py:_remat_policy``), each the
+    delta scan between two products: the policy keeps the scan's
+    output and BOTH arrays its forward kernel hands its backward one
+    (every chunk's start state; its masked pairs and inverse), so the
+    program holds one forward and one backward kernel a block, none
+    for the rematerialised pass, and every backward kernel takes both
+    (``scan_handed``: with the pairs named and not kept the block
+    would run a third kernel to remake them)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from realhf_tpu.models import transformer as T
+    from realhf_tpu.ops import delta_rule as D
+    l, h, d, blocks = 512, 2, 128, 2
+    prepare = D.Prepare(rate=-jnp.ones((h,)), dt_bias=jnp.zeros((h, d)),
+                        scale=d ** -0.5, eps=1e-6)
+
+    def block(x, w, seg):
+        q, k, v, f = (z.reshape(1, l, h, d) for z in jnp.split(
+            x @ w["in"], 4, axis=-1))
+        o, _ = D._by_kernels(q, k, v, f, jnp.full((1, l, h), 0.5), seg,
+                             prepare)
+        o = checkpoint_name(o, T.DELTA_RESIDUALS[0])
+        return x + o.reshape(1, l, h * d) @ w["out"]
+
+    def loss(ws, x, seg):
+        for w in ws:
+            x = jax.checkpoint(block, policy=T._remat_policy(
+                "nothing_saveable"))(x, w, seg)
+        return x.astype(jnp.float32).sum()
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    ws = [{"in": sds((h * d, 4 * h * d), bf16),
+           "out": sds((h * d, h * d), bf16)} for _ in range(blocks)]
+    text = _compile(jax.value_and_grad(loss), ws, sds((1, l, h * d), bf16),
+                    sds((1, l), jnp.int32)).as_text()
+    assert D.scan_kernel_calls(text) == 2 * blocks
+    assert D.scan_handed(text) == len(D.RESIDUAL_NAMES)
+    assert set(D.RESIDUAL_NAMES) < set(T.KEPT_RESIDUALS)
 
 
 @pytest.mark.parametrize("dtype,precision", [
@@ -590,8 +636,10 @@ def test_kimis_whole_train_step_compiles(one_chip):
     accumulated in float32, Adam on float32 masters, parameters and
     optimizer state donated. The kernels' scoped VMEM is counted inside
     the whole program, and a rematerialised block keeps the scan's
-    output and the chunks' start states: ONE forward and ONE backward
-    kernel a delta layer, none in the rematerialised pass. The
+    output, the chunks' start states and their masked pairs and
+    inverses: ONE forward and ONE backward kernel a delta layer, none
+    in the rematerialised pass, and the backward kernel takes both kept
+    arrays (``scan_handed``). The
     compiler's count of the step's memory is what the cell's size hangs
     on: 602 M parameters are 12.05 GB at 20 bytes before a row's
     activations, and a program is held to 13.9 GB. By the XLA products
@@ -599,7 +647,10 @@ def test_kimis_whole_train_step_compiles(one_chip):
     chunks (15.6 to 17.4 with the row's coefficients kept at once); by
     the kernels 13.19 with ``Prepare`` applied inside them (13.58 with
     q, k and the decay written in float32 by an XLA pass before them:
-    PERF.md, PR 42)."""
+    PERF.md, PR 42); 13.48 (arguments 8.435 + temporaries 5.046) since
+    a chunk's pairs and inverse are handed to the backward kernel, 201
+    MB a microbatch beside the 268 MB of start states (PERF.md, PR
+    52)."""
     from realhf_tpu.ops import delta_rule
 
     step, *args = _sft_train_step(
@@ -611,9 +662,10 @@ def test_kimis_whole_train_step_compiles(one_chip):
     assert "flash_fwd" in text and "gmm" in text
     assert delta_rule.DELTA_FWD in text and delta_rule.DELTA_BWD in text
     assert delta_rule.scan_kernel_calls(text) == 4 * 2
+    assert delta_rule.scan_handed(text) == len(delta_rule.RESIDUAL_NAMES)
     memory = compiled.memory_analysis()
     assert 12.8e9 < (memory.argument_size_in_bytes
-                     + memory.temp_size_in_bytes) < 13.3e9
+                     + memory.temp_size_in_bytes) < 13.58e9
 
 
 @pytest.mark.slow
@@ -716,6 +768,7 @@ def test_delta_scan_compiles_under_shard_map(topo):
     text = _compile(_as_on_a_tpu(jax.grad(
         loss, argnums=(0, 1, 2, 3, 4, 6, 7))), *args).as_text()
     assert D.scan_kernel_calls(text) == 2
+    assert D.scan_handed(text) == len(D.RESIDUAL_NAMES)
     opcodes = {opcode for _, _, opcode in device_instructions(text)}
     assert "all-reduce" in opcodes  # d of the decay, over "data"
     assert not opcodes & {"all-gather", "all-to-all", "collective-permute"}
@@ -766,9 +819,9 @@ def test_kimis_whole_microbatch_compiles_on_a_mesh(topo):
     by "data" (``models/sharding.py``), the flash kernels and the
     delta scan's each under its ``shard_map``, the experts' products
     ``lax.ragged_dot`` (``SHARDED_STACKS``). A rematerialised block
-    keeps the scan's output and the chunks' start states through the
-    ``shard_map``: one forward and one backward kernel a delta
-    layer."""
+    keeps the scan's output, the chunks' start states and their
+    pairs and inverses through the ``shard_map``: one forward and one
+    backward kernel a delta layer, the backward handed both."""
     from realhf_tpu.interfaces import sft
     from realhf_tpu.models import sharding as shard_rules
     from realhf_tpu.models import transformer as T
@@ -802,6 +855,7 @@ def test_kimis_whole_microbatch_compiles_on_a_mesh(topo):
     text = _compile(_as_on_a_tpu(jax.grad(objective)), params, mb).as_text()
     assert "flash_fwd" in text and "gmm" not in text
     assert delta_rule.scan_kernel_calls(text) == 4 * 2
+    assert delta_rule.scan_handed(text) == len(delta_rule.RESIDUAL_NAMES)
 
 
 def test_row_above_the_limit_raises_not_xla():

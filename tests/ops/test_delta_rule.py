@@ -215,6 +215,142 @@ def test_gradients_reach_what_prepare_reads(path):
         assert err < 1e-4 * float(jnp.abs(b_).max()), (name, err)
 
 
+def _pairs_and_inverse_by_xla(q, k, g, beta, seg):
+    """The XLA form's ``(a, bm, x)`` of every chunk, [B, H, N, C, C]
+    each: ``_pair_products`` with a document's masks applied and
+    ``_unit_lower_inverse`` of ``beta a``, as ``_segment`` makes them
+    (a padding token: no decay, no step, the document before it)."""
+    b, l, h, _ = k.shape
+    n, c = l // D.CHUNK, D.CHUNK
+    valid = jnp.asarray(seg != 0)
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+
+    def chunks(x):  # [B, L, H, d] -> [B, H, N, C, d]
+        return x.reshape(b, n, c, h, -1).transpose(0, 3, 1, 2, 4)
+
+    doc = D.doc_index(jnp.asarray(seg)).reshape(b, 1, n, c)
+    same = doc[..., :, None] == doc[..., None, :]
+    kk, qk = D._pair_products(chunks(q), chunks(k),
+                              jnp.cumsum(chunks(g), axis=-2))
+    a = jnp.where(same & jnp.tril(jnp.ones((c, c), bool), -1), kk, 0.0)
+    bm = jnp.where(same & jnp.tril(jnp.ones((c, c), bool)), qk, 0.0)
+    beta = chunks(beta[..., None])[..., 0]
+    return a, bm, D._unit_lower_inverse(beta[..., :, None] * a)
+
+
+@pytest.mark.parametrize("regime", ["harness", "published"])
+@pytest.mark.parametrize("ends,pad_left", [
+    ((23, 301, 560), 0),   # documents end inside chunks, padding after
+    ((640,), 37),          # padding before the one document
+], ids=["packed", "left_padding"])
+def test_the_backward_is_handed_what_the_forward_made(
+        interpreted_kernels, monkeypatch, regime, ends, pad_left):
+    """Under a gradient the forward kernel keeps every chunk's masked
+    pairs and triangular inverse (``RESIDUAL_NAMES[1]``, laid out by
+    ``_hand_over``) and the backward kernel makes neither again: what
+    it trusts is what the XLA form makes of the same row."""
+    l, h = 640, 2  # two blocks of five chunks
+    seg = segments(l, *ends)
+    seg[0, :pad_left] = 0
+    q, k, v, g, beta = inputs(37, 1, l, regime, dk=128, dv=128, h=h)
+    made = []
+    forward_call = D._forward_call
+
+    def recorded(static, keep_starts, *operands):
+        made.append(forward_call(static, keep_starts, *operands))
+        return made[-1]
+
+    monkeypatch.setattr(D, "_forward_call", recorded)
+    with interpreted_kernels(), jax.default_matmul_precision("highest"):
+        jax.vjp(lambda *a: D.chunked_delta_rule(*a, jnp.asarray(seg)),
+                q, k, v, g, beta)
+    (_, _, starts, handed), = made
+    assert handed.shape == (1, h, l // D.CHUNK, 96, 128)
+    assert starts.shape == (1, h, l // D.CHUNK, 128, 128)
+    got = jax.vmap(jax.vmap(jax.vmap(
+        lambda block: D._handed(block[None], 0))))(handed)
+    with jax.default_matmul_precision("highest"):
+        want = _pairs_and_inverse_by_xla(q, k, g, beta, seg)
+    for name, a, b_ in zip(("a", "bm", "x"), got, want):
+        assert a.shape == b_.shape == (1, h, l // D.CHUNK, 64, 64), name
+        err = float(jnp.abs(a - b_).max())
+        assert err < 1e-5 * float(jnp.abs(b_).max()), (name, err)
+    # strictly lower, lower, unit lower: the masks are in what is kept
+    a, bm, x = (np.asarray(m) for m in got)
+    assert not np.triu(a).any() and not np.triu(bm, 1).any()
+    assert not np.triu(x, 1).any()
+    assert (np.diagonal(x, axis1=-2, axis2=-1) == 1).all()
+
+
+def _kernel_outputs(jaxpr):
+    """How many arrays each ``pallas_call`` of a jaxpr writes, in
+    order, those of its sub-jaxprs among them."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(len(eqn.outvars))
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_kernel_outputs(sub))
+    return found
+
+
+def test_only_a_gradients_forward_writes_what_the_backward_reads(
+        interpreted_kernels):
+    """The primal scan (inference, prefill) writes the outputs and the
+    last state and nothing else; under a gradient the forward kernel
+    also writes ``RESIDUAL_NAMES``, which the backward kernel takes
+    beside the operands and the two cotangents."""
+    l = 128
+    x = inputs(41, 1, l, "published", dk=128, dv=128, h=1)
+    seg = jnp.ones((1, l), jnp.int32)
+
+    def scan(*a):
+        return D.chunked_delta_rule(*a, seg)
+
+    with interpreted_kernels():
+        primal = jax.make_jaxpr(scan)(*x)
+        gradient = jax.make_jaxpr(jax.grad(
+            lambda *a: scan(*a)[0].sum(), argnums=(0, 1, 2, 3, 4)))(*x)
+    assert _kernel_outputs(primal.jaxpr) == [2]
+    assert _kernel_outputs(gradient.jaxpr) == [
+        2 + len(D.RESIDUAL_NAMES), 6]
+    assert D.RESIDUAL_NAMES == ("delta_starts", "delta_pairs")
+
+
+#: one backward kernel of a compiled program as ``scan_handed`` reads
+#: it: the seven operands, what the forward kept, the two cotangents
+_BWD_LINE = (
+    "  %transpose_jvp_delta_bwd__.{i} = (bf16[1,2048,4096]{{2,1,0:T(8,128)"
+    "(2,1)}}, f32[1,32,2,128]{{3,2,1,0:T(2,128)}}) custom-call({operands}),"
+    " custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+    "{{s32[1,4,32,64]{{3,2,1,0}}, f32[1,32,32,64]{{3,2,1,0}}}}\n")
+
+
+@pytest.mark.parametrize("kept,handed", [
+    ((), 0), (("%pallas_call.15",), 1),
+    (("%pallas_call.15", "f32[1,32,32,96,128]{4,3,2,1,0} %gte.16"), 2)],
+    ids=["none", "starts_alone", "starts_and_pairs"])
+def test_scan_handed_counts_what_a_backward_kernel_takes(kept, handed):
+    """``scan_handed`` on a compiled program's text: a backward call's
+    operands besides the scan's seven and the two cotangents, the
+    least over the calls; 0 without a backward kernel."""
+    operands = ["%copy-done.4", "%copy-done.3", "%reshape.18",
+                "%reshape.19", "%reshape.20", "/*index=5*/%reshape.21",
+                "%constant.13", *kept, "%broadcast.8", "%transpose.3"]
+    text = "ENTRY %main (p: f32[2]) -> f32[2] {\n" + "".join(
+        _BWD_LINE.format(i=i, operands=", ".join(operands))
+        for i in range(2)) + "}\n"
+    if not kept:  # a forward kernel alone
+        text = text.replace("transpose_jvp_delta_bwd", "jvp_delta_fwd")
+    assert D.scan_handed(text) == handed
+    assert D.scan_kernel_calls(text) == 2
+    more = text.replace(f"{operands[-3]}, %broadcast.8",
+                        f"{operands[-3]}, %more.1, %broadcast.8", 1)
+    assert D.scan_handed(more) == handed  # the LEAST over the calls
+
+
 def test_no_exponent_overflows_where_the_factored_form_would(path):
     """1.6 a token over a chunk is exp(102) in the factored form:
     float32 ends at exp(88.7). Here every exponent is <= 0."""
