@@ -318,6 +318,10 @@ class Engine:
                     f"{op[0]}:{'none' if rc is None else rc.describe()}"
                     for op, rc in sorted(
                         cfg.rotary_by_operator.items())))
+        if cfg.n_passes > 1 or cfg.exit_gate:
+            self._model_attrs.update(
+                passes=cfg.n_passes, kv_layers=cfg.kv_layers,
+                post_norm=cfg.post_norm, exit_gate=cfg.exit_gate)
         if mode == "dense" and cfg.moe.num_experts > 4:
             logger.warning(
                 "MoE model running in dense dispatch (capacity_factor "
@@ -541,6 +545,11 @@ class Engine:
             metrics.inc("ssm_tokens_total",
                         tokens * len(self.cfg.ssm_layers),
                         role=str(self.ctx.model_name.role))
+        if self.cfg.n_passes > 1:
+            # (token, pass) pairs: every token runs every pass
+            metrics.inc("loop_token_passes_total",
+                        tokens * self.cfg.n_passes,
+                        role=str(self.ctx.model_name.role))
         if "moe_dispatch" not in self._model_attrs:
             return
         metrics.inc("moe_routed_pairs_total",
@@ -581,6 +590,35 @@ class Engine:
             slow = float(np.sum(stats[moe_ops.SHARE_OVERFLOW_STAT]))
             metrics.inc("moe_share_overflow_total", slow, role=role)
             self._last_span.set_attribute(moe_ops.SHARE_OVERFLOW_STAT, slow)
+
+    def _report_exits(self, stats: Dict[str, Any]):
+        """What a looped model's objective said of its exit gate in the
+        train step that has just ended (``interfaces/sft.py``), as
+        attributes of its ``engine:train*`` span and as gauges:
+        ``loop_expected_exit_pass{role}`` (the mean over the answer
+        tokens of sum_t t p_t), ``loop_exit_entropy{role}``, and a pass
+        t from 1 ``loop_exit_mass{role,pass}`` (the mean p_t) and
+        ``loop_pass_nll{role,pass}`` (the mean next-token loss from
+        pass t's hidden state); a sequence of minibatches reports its
+        last."""
+        if "expected_exit_pass" not in stats:
+            return
+        role = str(self.ctx.model_name.role)
+
+        def last(name):
+            value = float(np.ravel(stats[name])[-1])
+            self._last_span.set_attribute(name, value)
+            return value
+
+        metrics.set_gauge("loop_expected_exit_pass",
+                          last("expected_exit_pass"), role=role)
+        metrics.set_gauge("loop_exit_entropy", last("exit_entropy"),
+                          role=role)
+        for t in range(1, self.cfg.n_passes + 1):
+            metrics.set_gauge("loop_exit_mass", last(f"exit_p{t}"),
+                              role=role, **{"pass": str(t)})
+            metrics.set_gauge("loop_pass_nll", last(f"nll_pass{t}"),
+                              role=role, **{"pass": str(t)})
 
     def _program_facts(self, name: str, key, call=None
                        ) -> parts.ProgramFacts:
@@ -809,7 +847,8 @@ class Engine:
     # ------------------------------------------------------------------
     # The model's forward
     # ------------------------------------------------------------------
-    def _forward(self, params, input_ids, seg_ids, *, train: bool):
+    def _forward(self, params, input_ids, seg_ids, *, train: bool,
+                 every_pass: bool = False):
         """Final hidden states [B, L, H] and the auxiliary dict of this
         engine's model: the one call of ``T.forward`` on an engine's
         behalf (generation's prefill and decode steps are
@@ -824,6 +863,10 @@ class Engine:
           backward, 1F1B's input saving and custom VJP are overhead;
         - inference constrains the residual stream's sharding,
           training does NOT. Nobody chose that: ROADMAP D14.
+
+        A looped model's hidden states are its LAST pass's; with
+        ``every_pass`` they are ``T.PassStates``, every pass's final
+        hidden state and exit-gate logit (:meth:`_objective`).
         """
         with_aux = train and self.cfg.n_moe_layers > 0
         pipeline = self._pipeline_ctx
@@ -838,7 +881,8 @@ class Engine:
                         activation_constraint=constrain,
                         attention_fn=self._attention_fn,
                         moe_constraint=self._moe_constraint,
-                        pipeline=pipeline, mesh=self.mesh)
+                        pipeline=pipeline, mesh=self.mesh,
+                        return_passes=every_pass)
         return out[0], (out[2] if with_aux else {})
 
     # ------------------------------------------------------------------
@@ -850,11 +894,16 @@ class Engine:
         ``input_ids`` and ``seg_ids``, ``loss_fn``'s head and objective
         on its hidden states, and a sparse model's auxiliary losses
         added to the loss, their entries and the load statistic to the
-        statistics (never by ``loss_fn``)."""
+        statistics (never by ``loss_fn``). A ``loss_fn`` that reads
+        EVERY pass of a looped model says so (attribute ``every_pass``,
+        ``interfaces/sft.py``) and is handed ``T.PassStates`` in the
+        hidden states' place; every other one gets the last pass's."""
+        every_pass = getattr(loss_fn, "every_pass", False)
 
         def objective(params, mb):
             h, aux = self._forward(params, mb["input_ids"],
-                                   mb["seg_ids"], train=True)
+                                   mb["seg_ids"], train=True,
+                                   every_pass=every_pass)
             # what the interface does after the picked
             # log-probabilities; its head enters a scope of its own
             with jax.named_scope(parts.LOSS):
@@ -1051,6 +1100,7 @@ class Engine:
         # scalar with float() would issue a separate blocking D2H sync.
         loss, stats, gnorm = jax.device_get((loss, stats, gnorm))
         self._report_moe_load(stats)
+        self._report_exits(stats)
         out = {k: float(v) for k, v in stats.items()}
         out["loss"] = float(loss)
         out["grad_norm"] = float(gnorm)
@@ -1112,6 +1162,7 @@ class Engine:
             self._opt_offloaded = True
         losses, stats, gnorms = jax.device_get((losses, stats, gnorms))
         self._report_moe_load(stats)
+        self._report_exits(stats)
         out = []
         for i in range(len(minibatches)):
             d = {k: float(v[i]) for k, v in stats.items()}

@@ -653,7 +653,7 @@ class InflightBatchingGenerator:
         else:
             s = n - c
             kdtype = self.state["cache"]["k"].dtype
-            dk = np.zeros((self.cfg.n_layers, self.cfg.n_kv_heads,
+            dk = np.zeros((self.cfg.kv_layers, self.cfg.n_kv_heads,
                            c_b, self.cfg.head_dim), kdtype)
             dv = np.zeros_like(dk)
             dk[:, :, :c] = np.asarray(prefix_kv[0])[:, :, :c]
@@ -953,27 +953,38 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
         proj = attn.reshape(b, m, -1) @ lp["attn"]["wo"].astype(x.dtype)
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(x.dtype)
-        x = x + proj
+        x = x + T._post_norm(cfg, lp, "ln1_post", proj)
         ln2 = T._norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
-        x = x + T._mlp(cfg, lp, ln2, moe_constraint)
+        x = x + T._post_norm(cfg, lp, "ln2_post",
+                             T._mlp(cfg, lp, ln2, moe_constraint))
         return x, k_all, v_all
 
-    if cfg.n_layers <= T._DECODE_UNROLL_MAX_LAYERS:
-        for li in range(cfg.n_layers):
-            lp = jax.tree_util.tree_map(lambda a: a[li],
-                                        params["blocks"])
-            x, k_all, v_all = layer_body(x, k_all, v_all, lp, li,
-                                         static_l=li)
-    else:
-        def body(carry, layer):
-            xc, kc, vc = carry
-            lp, layer_idx = layer
-            xc, kc, vc = layer_body(xc, kc, vc, lp, layer_idx)
-            return (xc, kc, vc), None
+    # a looped model walks the stack once a pass over the same
+    # weights, pass t layer l on row t x n_layers + l of the K/V stack,
+    # the final norm after every pass (models/transformer.py:_passes)
+    for first in range(0, cfg.kv_layers, cfg.n_layers):
+        if first:
+            x = T._norm(cfg, x, params["ln_f"]["scale"],
+                        params["ln_f"].get("bias"))
+        if cfg.kv_layers <= T._DECODE_UNROLL_MAX_LAYERS:
+            for li in range(cfg.n_layers):
+                lp = jax.tree_util.tree_map(lambda a: a[li],
+                                            params["blocks"])
+                x, k_all, v_all = layer_body(x, k_all, v_all, lp,
+                                             first + li,
+                                             static_l=first + li)
+        else:
+            def body(carry, layer):
+                xc, kc, vc = carry
+                lp, layer_idx = layer
+                xc, kc, vc = layer_body(xc, kc, vc, lp, layer_idx)
+                return (xc, kc, vc), None
 
-        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        (x, k_all, v_all), _ = jax.lax.scan(
-            body, (x, k_all, v_all), (params["blocks"], layer_ids))
+            layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            if first:
+                layer_ids = layer_ids + first
+            (x, k_all, v_all), _ = jax.lax.scan(
+                body, (x, k_all, v_all), (params["blocks"], layer_ids))
     x = T._norm(cfg, x, params["ln_f"]["scale"],
                 params["ln_f"].get("bias"))
     return x, k_all, v_all

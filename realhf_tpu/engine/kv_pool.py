@@ -116,7 +116,8 @@ class KVPool:
         if cfg is not None:
             import jax.numpy as jnp
             cfg.require_one_block("the paged KV pool (engine/kv_pool.py)")
-            nl, nkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+            # (a looped model: a layer's rows once a pass)
+            nl, nkv, hd = cfg.kv_layers, cfg.n_kv_heads, cfg.head_dim
             rows = (self.n_blocks + 1) * self.block_len
             sdt = jnp.dtype(self.meta.store_dtype)
             self._arrays = dict(

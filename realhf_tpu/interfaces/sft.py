@@ -8,6 +8,7 @@ non-prompt tokens of packed sequences.
 import dataclasses
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -15,31 +16,87 @@ from realhf_tpu.api import model as model_api
 from realhf_tpu.api.data import SequenceSample
 from realhf_tpu.base import logging
 from realhf_tpu.interfaces import common
+from realhf_tpu.obs import parts
 from realhf_tpu.ops import functional as F
 
 logger = logging.getLogger("SFTInterface")
 
 
+def _answer_mask(mb):
+    """[S, L] bool: position t predicts token t+1, an ANSWER token of
+    the same document (reference compute_packed_sft_loss:19 shifts the
+    prompt mask by one)."""
+    seg = mb["seg_ids"]
+    next_same = jnp.concatenate(
+        [(seg[:, 1:] == seg[:, :-1]) & (seg[:, 1:] != 0),
+         jnp.zeros_like(seg[:, :1], bool)], axis=1)
+    next_is_prompt = jnp.concatenate(
+        [mb["prompt_mask"][:, 1:], jnp.zeros_like(seg[:, :1], bool)],
+        axis=1)
+    return next_same & ~next_is_prompt
+
+
 def _make_loss_fn(cfg):
+    if cfg.exit_gate:
+        return _make_looped_loss_fn(cfg)
 
     def loss_fn(params, h, mb):
         lp = F.shifted_logprobs_from_hidden(
             cfg, params, h, mb["input_ids"], mb["seg_ids"])
-        # loss_mask[t] gates predicting token t+1: valid next-token
-        # positions that are not prompt tokens (reference
-        # compute_packed_sft_loss:19 shifts the prompt mask by one).
-        seg = mb["seg_ids"]
-        next_same = jnp.concatenate(
-            [(seg[:, 1:] == seg[:, :-1]) & (seg[:, 1:] != 0),
-             jnp.zeros_like(seg[:, :1], bool)], axis=1)
-        next_is_prompt = jnp.concatenate(
-            [mb["prompt_mask"][:, 1:], jnp.zeros_like(seg[:, :1], bool)],
-            axis=1)
-        mask = next_same & ~next_is_prompt
+        mask = _answer_mask(mb)
         denom = jnp.maximum(mask.sum(), 1)
         nll = -(lp * mask).sum() / denom
         return nll, {"nll": nll, "n_tokens": denom.astype(jnp.float32)}
 
+    return loss_fn
+
+
+def _make_looped_loss_fn(cfg):
+    """The objective of a looped model with an exit gate (``cfg.
+    exit_gate``; "Scaling Latent Reasoning via Looped Language Models",
+    first stage): an answer token i with next-token loss ``nll_{t,i}``
+    from pass t's hidden state and exit distribution ``p_{t,i}``
+    (``F.exit_log_distribution``) costs
+
+        sum_t p_{t,i} nll_{t,i} - beta H(p_{.,i}),  H(p) = -sum p log p
+
+    averaged over the answer tokens, ``beta = cfg.exit_entropy_coeff``.
+    Gradients reach the gate through p and the shared layers through
+    every pass. The function asks the engine for EVERY pass's state
+    (``every_pass``: ``Engine._objective`` then hands it
+    ``models/transformer.py:PassStates`` for ``h``). Statistics a
+    step: ``nll`` (pass T's, what an un-looped reader compares),
+    ``exit_p<t>`` and ``nll_pass<t>`` (t from 1: the mean exit mass and
+    loss a pass), ``expected_exit_pass`` (mean of sum_t t p_t) and
+    ``exit_entropy`` (mean H)."""
+    beta = cfg.exit_entropy_coeff
+
+    def loss_fn(params, states, mb):
+        nll = -F.passes_logprobs_from_hidden(
+            cfg, params, states.hidden, mb["input_ids"], mb["seg_ids"])
+        log_p = F.exit_log_distribution(states.gate)  # [T, S, L]
+        with jax.named_scope(parts.EXIT):
+            mask = _answer_mask(mb)
+            denom = jnp.maximum(mask.sum(), 1)
+
+            def mean(x):  # [..., S, L] -> [...] over the answer tokens
+                return (x * mask).sum((-2, -1)) / denom
+
+            p = jnp.exp(log_p)
+            entropy = mean(-(p * log_p).sum(0))
+            p_mean, nll_mean = mean(p), mean(nll)
+            loss = mean((p * nll).sum(0)) - beta * entropy
+            passes = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+            stats = {"nll": nll_mean[-1],
+                     "n_tokens": denom.astype(jnp.float32),
+                     "expected_exit_pass": (passes * p_mean).sum(),
+                     "exit_entropy": entropy}
+            for t in range(p.shape[0]):
+                stats[f"exit_p{t + 1}"] = p_mean[t]
+                stats[f"nll_pass{t + 1}"] = nll_mean[t]
+        return loss, stats
+
+    loss_fn.every_pass = True
     return loss_fn
 
 

@@ -396,6 +396,33 @@ class TransformerConfig:
     indexer: Optional[IndexerConfig] = None
     # What the "ssm" layers of the pattern are made of.
     ssm: Optional[SsmConfig] = None
+    # A LOOPED model: the whole stack of ``n_layers`` runs ``n_passes``
+    # times over ONE set of weights. With x^0 the embedding rows, pass
+    # t = 1..T runs every layer on x^(t-1) and then the final norm,
+    # ``x^t = norm(h; ln_f)``: the final norm is INSIDE the loop, and
+    # its output is what the next pass starts from and what the head
+    # (and the exit gate) of pass t read. Every pass has keys and
+    # values of its own (its input differs), so the caches hold
+    # ``kv_layers = n_passes x`` the attention layers, pass t (from 0)
+    # layer l at index ``t x n_layers + l``. Inference, generation and
+    # every loss but the looped objective below read pass T alone.
+    n_passes: int = 1
+    # A norm AFTER each operator as well, inside the residual's add:
+    # ``a = h + norm(attn(norm(h; ln1)); ln1_post)``, ``h = a +
+    # norm(mlp(norm(a; ln2)); ln2_post)`` (leaves ``ln1_post`` and
+    # ``ln2_post`` beside ``ln1`` and ``ln2``).
+    post_norm: bool = False
+    # The exit gate of a looped model: ``lambda_t = sigmoid(x^t . w +
+    # b)`` a token a pass (leaves ``params["exit_gate"]``: ``w``
+    # [H, 1], ``b`` [1]). A token leaves at pass t with probability
+    # ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``, the last pass
+    # taking what is left (``ops/functional.py:exit_log_distribution``),
+    # and supervised training weighs the passes' losses by it less
+    # ``exit_entropy_coeff`` times its entropy (``interfaces/sft.py``).
+    # Leaving the loop early (a threshold under 1 on the cumulative
+    # p) is NOT implemented: every token runs every pass (ROADMAP).
+    exit_gate: bool = False
+    exit_entropy_coeff: float = 0.05
     is_critic: bool = False
 
     # --- TPU-native additions -----------------------------------------
@@ -476,6 +503,17 @@ class TransformerConfig:
                 "layer_q_heads, rotary_by_operator, attn_output_gate, "
                 "latent, delta, indexer and ssm belong to a model with "
                 "a layer_pattern")
+        if self.n_passes < 1:
+            raise ValueError(f"n_passes={self.n_passes}")
+        if (self.n_passes > 1 or self.post_norm or self.exit_gate) and (
+                self.layer_pattern is not None
+                or self.layer_norm_type != "rms"
+                or self.scale_attn_by_inverse_layer_idx
+                or self.mlp_type == "moe"):
+            raise NotImplementedError(
+                "n_passes, post_norm and exit_gate belong to a model "
+                "of one dense block with RMSNorm (no layer_pattern, no "
+                "experts, no per-layer attention scale)")
         if self.activation_function == "relu2" and self.gated_mlp:
             raise NotImplementedError(
                 "relu2 is an UNGATED feed-forward's activation "
@@ -570,6 +608,13 @@ class TransformerConfig:
         window attention alike."""
         return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
                      if op in ATTENTION_OPERATORS)
+
+    @property
+    def kv_layers(self) -> int:
+        """How many layers' worth of keys and values a cache holds:
+        the attention layers, once a pass of a looped model (pass t
+        from 0, attention layer l of n at index ``t x n + l``)."""
+        return self.n_passes * len(self.attention_layers)
 
     @property
     def latent_layers(self) -> Tuple[int, ...]:
@@ -683,9 +728,10 @@ class TransformerConfig:
         query heads, a latent layer's five leaves, a delta layer's
         fifteen, a sparse layer's indexer, an ssm layer's seven (its
         convolution's bias among them); an ungated feed-forward's two
-        matrices where ``mlp_type`` is None; a part a layer lacks
-        counts nothing; other biases and the layer norms' scales are
-        left out."""
+        matrices where ``mlp_type`` is None; a looped model's exit
+        gate (its layers count ONCE however often they run); a part a
+        layer lacks counts nothing; other biases and the layer norms'
+        scales are left out."""
         h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
 
         def attn(i):
@@ -734,6 +780,8 @@ class TransformerConfig:
         embed = v * h if self.tied_embedding else 2 * v * h
         if self.is_critic:
             embed = v * h + h
+        if self.exit_gate:
+            embed += h + 1
         return embed + sum(
             (conv if op == "conv" else delta if op == "delta"
              else ssm if op == "ssm" else 0 if op == ABSENT

@@ -93,10 +93,16 @@ def param_pspecs(cfg: TransformerConfig,
         specs["blocks"]["ln1"]["bias"] = rep2
         specs["blocks"]["ln2"]["bias"] = rep2
         specs["ln_f"]["bias"] = P(None)
+    if cfg.post_norm:  # as the norms before the operators
+        specs["blocks"]["ln1_post"] = {"scale": rep2}
+        specs["blocks"]["ln2_post"] = {"scale": rep2}
     if cfg.is_critic:
         specs["head"] = {"w": P(None, None)}
     elif not cfg.tied_embedding:
         specs["head"] = {"w": P(None, MODEL_AXIS)}
+    if cfg.exit_gate:
+        # one output: on every shard (2,049 values), as a critic's head
+        specs["exit_gate"] = {"w": P(None, None), "b": P(None)}
     return specs
 
 

@@ -60,6 +60,14 @@ Design (idiomatic JAX, not a torch translation):
   A feed-forward without a gate (``mlp_type`` None: ``wu`` and ``wd``
   alone, dense, shared or an expert's) is two products.
   A model of one block takes none of these paths.
+- A LOOPED model (``TransformerConfig.n_passes`` > 1) runs its whole
+  stack several times over ONE set of weights: ``forward`` writes the
+  passes out around the scan of the layers (``_passes``), the final norm
+  INSIDE the loop (its output starts the next pass and feeds that
+  pass's head and exit gate), and every cache below holds
+  ``cfg.kv_layers`` layers' worth of keys and values, pass t layer l
+  at ``t x n_layers + l``. Its block may norm AFTER each operator too
+  (``post_norm``). A model without these keys lowers as it always did.
 
 Layer indexing convention matches the reference (real_llm_base.py:394):
 0 = embedding, 1..n_layers = blocks, n_layers+1 = head -- used by HF
@@ -67,7 +75,7 @@ conversion and (later) pipeline splitting.
 """
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +139,14 @@ SSM_RESIDUALS = ("ssm_out",) + _SSM_SCAN_RESIDUALS
 #: gradient reaches, does not run a second time
 KEPT_RESIDUALS = RESIDUAL_NAMES + PROJECTION_RESIDUALS + DELTA_RESIDUALS \
     + (SELECT_RESIDUAL,) + SSM_RESIDUALS
+#: what a block of a LOOPED model keeps (``_passes``): the flash
+#: kernel's two residuals alone. Its stack is walked ``n_passes`` times
+#: and the backward needs every application's, so each name costs T
+#: times its rows: with q and the projected output kept too,
+#: Ouro-2.6B's six layers at T = 4 and rows of 4096 compiled to 15.3 GB
+#: of a chip's 16, without them to 13.7 (PERF.md, PR 53). Running
+#: ``flash_fwd`` again would be the dearer saving (PR 36's table).
+KEPT_IN_A_LOOP = RESIDUAL_NAMES
 
 
 # ----------------------------------------------------------------------
@@ -211,11 +227,17 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         params["blocks"]["ln1"]["bias"] = zeros((nl, h))
         params["blocks"]["ln2"]["bias"] = zeros((nl, h))
         params["ln_f"]["bias"] = zeros((h,))
+    if cfg.post_norm:
+        params["blocks"]["ln1_post"] = {"scale": ones((nl, h))}
+        params["blocks"]["ln2_post"] = {"scale": ones((nl, h))}
 
     if cfg.is_critic:
         params["head"] = {"w": norm((h, 1), keys[10])}
     elif not cfg.tied_embedding:
         params["head"] = {"w": norm((h, v), keys[10])}
+    if cfg.exit_gate:
+        params["exit_gate"] = {"w": norm((h, 1), keys[11]),
+                               "b": zeros((1,))}
     return params
 
 
@@ -914,7 +936,7 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
                                         cos, sin, attention_fn, window,
                                         op, index_rotary)
         with jax.named_scope(mixer):
-            x = constrain(x + proj)
+            x = constrain(x + _post_norm(cfg, lp, "ln1_post", proj))
     if kind is not None and kind[1] == ABSENT:
         return x, state, {}
     ff = _ff_part(cfg, sparse)
@@ -923,11 +945,21 @@ def _block(cfg: TransformerConfig, lp: Params, layer_idx: jnp.ndarray,
     mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, seg_ids, moe_constraint,
                                  sparse)
     with jax.named_scope(ff):
-        x = constrain(x + mlp_out)
+        x = constrain(x + _post_norm(cfg, lp, "ln2_post", mlp_out))
     return x, state, aux
 
 
-def _remat(cfg: TransformerConfig, block_fn):
+def _post_norm(cfg: TransformerConfig, lp: Params, name: str,
+               out: jnp.ndarray) -> jnp.ndarray:
+    """An operator's output normed before the residual's add, where
+    the layer holds that norm (``post_norm``: ``ln1_post`` after the
+    mixer, ``ln2_post`` after the feed-forward); as it is elsewhere."""
+    if name not in lp:
+        return out
+    return _norm(cfg, out, lp[name]["scale"], None)
+
+
+def _remat(cfg: TransformerConfig, block_fn, kept=None):
     """``block_fn`` rematerialised in the backward, where
     ``cfg.gradient_checkpointing`` asks for it: it keeps what
     ``cfg.remat_policy`` names and, whatever that is,
@@ -940,15 +972,19 @@ def _remat(cfg: TransformerConfig, block_fn):
     recomputes the norm before attention, k, v, the gate and the
     feed-forward, and runs the kernel's two backward passes. The XLA
     attention path keeps the same two (q is its einsum's operand) and
-    no kernel's outputs."""
+    no kernel's outputs. ``kept``: the names to keep in
+    ``KEPT_RESIDUALS``' place (a looped model's blocks,
+    ``KEPT_IN_A_LOOP``)."""
     if not cfg.gradient_checkpointing:
         return block_fn
-    return jax.checkpoint(block_fn, policy=_remat_policy(cfg.remat_policy))
+    return jax.checkpoint(block_fn,
+                          policy=_remat_policy(cfg.remat_policy, kept))
 
 
 @functools.lru_cache(maxsize=None)
-def _remat_policy(name: str):
-    """ONE policy object a name: every layer of an unrolled stack then
+def _remat_policy(name: str, kept=None):
+    """ONE policy object a name (and kept set, ``KEPT_RESIDUALS`` by
+    default): every layer of an unrolled stack then
     carries the same one, and what jax caches by a checkpoint's
     parameters (a traced body's helper functions) is shared between
     the layers as it is under a policy of ``jax.checkpoint_policies``
@@ -956,7 +992,8 @@ def _remat_policy(name: str):
     policies = jax.checkpoint_policies
     return policies.save_from_both_policies(
         getattr(policies, name),
-        policies.save_only_these_names(*KEPT_RESIDUALS))
+        policies.save_only_these_names(
+            *(KEPT_RESIDUALS if kept is None else kept)))
 
 
 def rotary_table(cfg: TransformerConfig, positions: jnp.ndarray,
@@ -1026,10 +1063,17 @@ def forward(
     moe_constraint=None,  # models/sharding.py moe_ep_constraint (EP)
     pipeline=None,  # parallel.pipeline.PipelineContext when pp > 1
     mesh=None,  # what the arrays are sharded over (delta layers)
+    return_passes: bool = False,  # a looped model: EVERY pass's states
 ):
     """Packed forward pass -> final hidden states [B, L, H] (after the
     final norm). Heads are applied separately (`lm_logits`,
     `critic_values`, or fused ops in `realhf_tpu.ops.functional`).
+
+    A looped model (``cfg.n_passes``) returns its LAST pass's hidden
+    states, which is what inference, generation and every loss but the
+    looped objective read; with ``return_passes`` the first output is
+    ``PassStates``: every pass's final hidden state [T, B, L, H] and
+    its exit gate's logit [T, B, L] (float32).
 
     ``activation_constraint`` is an optional fn applied to the residual
     stream each block (sharding constraints; see models/sharding.py).
@@ -1057,6 +1101,11 @@ def forward(
     if pipeline is not None and pipeline.n_stages > 1:
         cfg.require_one_block(
             "pipeline parallelism (parallel/pipeline.py, schedule.py)")
+        if cfg.n_passes > 1 or cfg.exit_gate:
+            raise NotImplementedError(
+                "a looped model (n_passes, exit_gate) on a "
+                "pipeline-parallel mesh: a stage would be walked once a "
+                "pass")
         cos, sin = rotary["attention"]
         # Pipeline parallelism: blocks are stage-sharded over the
         # "pipe" mesh axis and run as a microbatch-rotation schedule
@@ -1130,6 +1179,8 @@ def forward(
         return (x, states, aux) if return_aux else (x, states)
 
     cos, sin = rotary["attention"]  # a model of one block has one table
+    if return_passes and not cfg.exit_gate:
+        raise ValueError("return_passes: the model has no exit gate")
 
     def block_fn(lp, layer_idx, carry):
         # cfg/constrain are non-array closures; seg_ids/cos/sin are
@@ -1138,6 +1189,10 @@ def forward(
         return _block(cfg, lp, layer_idx, carry, seg_ids, cos, sin,
                       constrain, attention_fn, moe_constraint,
                       window=cfg.sliding_window)
+
+    if cfg.n_passes > 1 or cfg.exit_gate:
+        assert not return_aux  # (a looped model has no experts)
+        return _passes(cfg, params, x, block_fn, return_kv, return_passes)
 
     block_fn = _remat(cfg, block_fn)
 
@@ -1159,6 +1214,153 @@ def forward(
         from realhf_tpu.ops.moe import reduce_layers
         return x, kvs, reduce_layers(auxs or {})
     return x, kvs
+
+
+class PassStates(NamedTuple):
+    """What a looped model's forward hands the looped objective."""
+    hidden: jnp.ndarray  # [T, B, L, H]: x^t, every pass's final norm's output
+    gate: jnp.ndarray    # [T, B, L] float32: the exit gate's logit on x^t
+
+
+def _passes(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
+            block_fn, return_kv: bool, return_passes: bool):
+    """A looped model's ``cfg.n_passes`` passes over the one stack
+    ``params["blocks"]``: x^0 [B, L, H] -> (x^T, or ``PassStates`` with
+    ``return_passes``; K and V of every pass [T x n_layers, B, L, nkv,
+    hd], pass t layer l at ``t x n_layers + l``, or None).
+    ``block_fn(lp, layer_idx, x)``: one block, NOT yet rematerialised.
+
+    A loop of passes around the scan of layers. Pass t runs every layer
+    on x^(t-1), then the final norm: ``x^t = norm(h; ln_f)`` is what
+    the next pass starts from, what the head of pass t reads and what
+    the exit gate scores. The passes are written out (T scans of the
+    layers in the program, T is small): a scan of passes makes the
+    compiler hold a pass's kept residuals twice, in the layer scan's
+    own stack and in the stack of passes it is copied to and sliced
+    from (0.4 GB at Ouro-2.6B's six layers and rows of 4096, which the
+    cell does not have; PERF.md, PR 53).
+
+    The backward keeps, a layer a pass, a rematerialised block's input
+    and ``KEPT_IN_A_LOOP`` (the flash kernel's output and log-sum-exp),
+    T x n_layers layer applications' worth, and a pass's un-normed h;
+    no more. q and the projected output, which a stack that runs once
+    keeps too (``PROJECTION_RESIDUALS``), are made again: T times their
+    rows did not fit beside 20 bytes a parameter.
+
+    A shared weight's gradient is the SUM of the passes' gradients.
+    Left to the layer scans' transposes it would be each pass's stacked
+    gradients, a stack of zeros to write them into and a running sum
+    beside them (the compiler counted 1.5 GB more for them at six
+    layers). Here the sum is ONE accumulator that every layer scan
+    carries (``_gradient_accumulator``): a layer's weights leave their
+    stack through ``_layer_of``, whose transpose adds the layer's
+    gradient into the accumulator's row IN PLACE. The additions are
+    taken in the accumulator's dtype, which is the parameters' (bf16 in
+    a bf16 engine, whose float32 accumulator then adds the
+    microbatches' sums): a row is rounded once a pass, T - 1 roundings
+    of 2^-9 beside what a bf16 backward loses anyway;
+    ``tests/model/test_ouro.py`` bounds it against the float32
+    reference. The loop's own operations lower under ``layers/loop``
+    (obs/parts.py:LOOP), a layer scan's under ``layers``, the gate's
+    under ``exit``."""
+    blocks, grads = _gradient_accumulator(params["blocks"])
+
+    def block_at(grads, layer_idx, carry):
+        lp, grads = _layer_of(blocks, grads, layer_idx)
+        y, kv, _ = block_fn(lp, layer_idx, carry)
+        return y, kv, grads
+
+    block_at = _remat(cfg, block_at, KEPT_IN_A_LOOP)
+
+    def one_layer(carry, layer_idx):
+        y, kv, grads = block_at(carry[1], layer_idx, carry[0])
+        return (y, grads), (kv if return_kv else None)
+
+    def tail(h):
+        xt = _final_norm(cfg, params, h)
+        return xt, (exit_logit(cfg, params, xt) if return_passes else None)
+
+    tail = _remat(cfg, tail, ())
+    states, kvs = [], []
+    with jax.named_scope(P.LAYERS), jax.named_scope(P.LOOP):
+        for _ in range(cfg.n_passes):
+            with jax.named_scope(P.LAYERS):
+                (h, grads), kv = jax.lax.scan(
+                    one_layer, (x, grads),
+                    jnp.arange(cfg.n_layers, dtype=jnp.int32))
+            x, gate = tail(h)
+            states.append((x, gate))
+            kvs.append(kv)
+        if return_passes:
+            x = PassStates(*(jnp.stack(s) for s in zip(*states)))
+        # [nl, ...] a pass -> [T x nl, ...]
+        kvs = jax.tree.map(lambda *a: jnp.concatenate(a), *kvs) \
+            if return_kv else None
+    return x, kvs
+
+
+@jax.custom_vjp
+def _gradient_accumulator(blocks: Params):
+    """(the stacked weights as constants of the loops they enter, an
+    accumulator of their shape and dtype, zero). The weights' gradient
+    is what has been added into the accumulator when the transposed
+    loops hand it back (``_layer_of``)."""
+    return blocks, jax.tree.map(jnp.zeros_like, blocks)
+
+
+def _accumulator_fwd(blocks):
+    return _gradient_accumulator(blocks), None
+
+
+def _accumulator_bwd(_, cotangents):
+    # no gradient comes back by the weights themselves: every use of
+    # them in the loops is ``_layer_of``, which sends it to the
+    # accumulator
+    return (cotangents[1],)
+
+
+_gradient_accumulator.defvjp(_accumulator_fwd, _accumulator_bwd)
+
+
+@jax.custom_vjp
+def _layer_of(blocks: Params, grads: Params, layer_idx: jnp.ndarray):
+    """(layer ``layer_idx``'s weights out of their stack, the
+    accumulator as it was). Transposed: the layer's gradient is added
+    into row ``layer_idx`` of the accumulator, in place and in the
+    accumulator's dtype, and nothing goes to ``blocks``."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer_idx, 0,
+                                               keepdims=False),
+        blocks), grads
+
+
+def _layer_of_fwd(blocks, grads, layer_idx):
+    return _layer_of(blocks, grads, layer_idx), layer_idx
+
+
+def _layer_of_bwd(layer_idx, cotangents):
+    d_layer, d_grads = cotangents
+
+    def add(acc, g):
+        row = jax.lax.dynamic_index_in_dim(acc, layer_idx, 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            acc, row + g.astype(acc.dtype), layer_idx, 0)
+
+    return None, jax.tree.map(add, d_grads, d_layer), None
+
+
+_layer_of.defvjp(_layer_of_fwd, _layer_of_bwd)
+
+
+def exit_logit(cfg: TransformerConfig, params: Params,
+               x: jnp.ndarray) -> jnp.ndarray:
+    """The exit gate's logit of a pass's final hidden state x [..., H]
+    -> [...] float32: ``x . w + b`` (``lambda = sigmoid`` of it)."""
+    with jax.named_scope(P.EXIT):
+        g = params["exit_gate"]
+        logit = jnp.einsum("...h,ho->...o", x, g["w"].astype(x.dtype),
+                           preferred_element_type=jnp.float32)[..., 0]
+        return logit + g["b"].astype(jnp.float32)[0]
 
 
 def _final_norm(cfg: TransformerConfig, params: Params,
@@ -1294,7 +1496,7 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     reference `prepare_generate_inputs` (real_llm_generate.py:179)."""
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     max_len = round_cache_len(max_len)
-    shape = (len(cfg.attention_layers), batch, cfg.n_kv_heads, max_len)
+    shape = (cfg.kv_layers, batch, cfg.n_kv_heads, max_len)
     cache = {
         "k": jnp.zeros(shape + (cfg.head_dim,), dtype),
         "v": jnp.zeros(shape + (cfg.v_head_dim,), dtype),
@@ -1380,7 +1582,7 @@ def _prefill_cache(cfg, kvs, seg_ids, b, lp, total_len, dtype) -> KVCache:
     """``prefill``'s cache from the states ``forward`` returned."""
     more = {}
     if cfg.layer_pattern is None:
-        k, v = kvs  # [nl, B, L, nkv, hd]
+        k, v = kvs  # [nl, B, L, nkv, hd] (a looped model: nl a pass)
     else:
         # K and V of the attention layers alone; of each conv layer
         # the last rows of its input, 0 where the row is padding; of
@@ -1482,6 +1684,34 @@ def _stacked_decode_attention(q, k_all, v_all, valid, layer_idx, *,
     return decode_attention(q, k_all[layer_idx], v_all[layer_idx], valid,
                             scale=scale, sliding_window=sliding_window,
                             slot=slot)
+
+
+def _decode_layers(cfg, params, layer_body, x, k_all, v_all, first=0,
+                   depth=None):
+    """One walk of a decode step over the stacked blocks: ``layer_body(
+    x, k_all, v_all, lp, l)`` a layer, layer i at row ``first + i`` of
+    the K/V stack (``first``: a looped model's pass times its layers).
+    Unrolled while the program's ``depth`` of layer bodies (the layers,
+    times a looped model's passes) is shallow, else a scan."""
+    depth = cfg.n_layers if depth is None else depth
+    if depth <= _DECODE_UNROLL_MAX_LAYERS:
+        for li in range(cfg.n_layers):
+            with jax.named_scope(P.LAYERS):
+                lp = jax.tree_util.tree_map(lambda a: a[li],
+                                            params["blocks"])
+            x, k_all, v_all = layer_body(x, k_all, v_all, lp, first + li)
+        return x, k_all, v_all
+
+    def body(carry, layer):
+        return layer_body(*carry, *layer), None
+
+    with jax.named_scope(P.LAYERS):
+        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        if first:
+            layer_ids = layer_ids + first
+        (x, k_all, v_all), _ = jax.lax.scan(
+            body, (x, k_all, v_all), (params["blocks"], layer_ids))
+    return x, k_all, v_all
 
 
 def decode_step(
@@ -1615,7 +1845,7 @@ def decode_step(
             proj = attn.reshape(b, -1) @ lp["attn"]["wo"].astype(x.dtype)
             if "bo" in lp["attn"]:
                 proj = proj + lp["attn"]["bo"].astype(x.dtype)
-            x = x + proj
+            x = x + _post_norm(cfg, lp, "ln1_post", proj)
         return _ff_step(x, lp, sparse), k_all, v_all
 
     def _ff_step(x, lp, sparse):
@@ -1625,7 +1855,9 @@ def decode_step(
             return x
         with jax.named_scope(_ff_part(cfg, sparse)):
             ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
-            return x + _mlp(cfg, lp, ln2, moe_constraint, sparse)
+            return x + _post_norm(cfg, lp, "ln2_post",
+                                  _mlp(cfg, lp, ln2, moe_constraint,
+                                       sparse))
 
     k_all, v_all = cache["k"], cache["v"]
     index_all = cache.get("index_k")
@@ -1691,20 +1923,21 @@ def decode_step(
                 new_conv.append(state)
                 x = x + proj
             x = _ff_step(x, lp, ff == "moe")
-    elif cfg.n_layers <= _DECODE_UNROLL_MAX_LAYERS:
-        for li in range(cfg.n_layers):
-            with jax.named_scope(P.LAYERS):
-                lp = jax.tree_util.tree_map(lambda a: a[li],
-                                            params["blocks"])
-            x, k_all, v_all = layer_body(x, k_all, v_all, lp, li)
+    elif cfg.n_passes > 1:
+        # a looped model: every pass over the same weights, each with
+        # ITS rows of the K/V stack (pass t layer l at t x n_layers +
+        # l), the final norm after every pass; at the published exit
+        # threshold of 1 no token leaves early, so all passes run and
+        # the gate is not asked
+        for t in range(cfg.n_passes):
+            x, k_all, v_all = _decode_layers(
+                cfg, params, layer_body, x, k_all, v_all,
+                t * cfg.n_layers, cfg.kv_layers)
+            if t < cfg.n_passes - 1:
+                x = _final_norm(cfg, params, x)
     else:
-        def body(carry, layer):
-            return layer_body(*carry, *layer), None
-
-        with jax.named_scope(P.LAYERS):
-            layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-            (x, k_all, v_all), _ = jax.lax.scan(
-                body, (x, k_all, v_all), (params["blocks"], layer_ids))
+        x, k_all, v_all = _decode_layers(cfg, params, layer_body, x,
+                                         k_all, v_all)
     x = _final_norm(cfg, params, x)
     new_cache = {"k": k_all, "v": v_all, "valid": valid, "length": new_len}
     if new_conv:

@@ -50,6 +50,12 @@ MLP = "mlp"
 SHARED_EXPERT = "shared_expert"
 EXPERTS = "experts"
 VOCAB_HEAD = "vocab_head"
+#: a looped model's exit gate (models/config.py:
+#: TransformerConfig.exit_gate): its projection of every pass's final
+#: hidden state, the exit distribution over the passes, the passes'
+#: losses weighed by it and its entropy
+#: (``ops/functional.py:exit_log_distribution``, ``interfaces/sft.py``)
+EXIT = "exit"
 LOSS = "loss"
 GRAD_ACCUM = "grad_accum"
 OPTIMIZER = "optimizer"
@@ -59,7 +65,8 @@ LAYERS = "layers"
 #: what a device operation can be put down to: the INNERMOST of these
 #: in its ``op_name`` is its part
 PARTS = (EMBED, LAYERS, ATTN_PROJ, ATTN, CONV, DELTA, SSM, INDEX, MLP,
-         SHARED_EXPERT, EXPERTS, VOCAB_HEAD, LOSS, GRAD_ACCUM, OPTIMIZER)
+         SHARED_EXPERT, EXPERTS, VOCAB_HEAD, EXIT, LOSS, GRAD_ACCUM,
+         OPTIMIZER)
 
 FORWARD_BACKWARD = "forward_backward"
 PREFILL, DECODE, SAMPLE = "prefill", "decode", "sample"
@@ -80,6 +87,14 @@ LATENT = "latent"
 #: (``ops/delta_rule.py``, ``ops/ssm_scan.py``); the part then reads
 #: ``delta/scan``, ``ssm/scan``
 SCAN = "scan"
+#: sub-scope of ``layers`` in a looped model (``TransformerConfig.
+#: n_passes``): the loop of passes' own work around the layer scans
+#: (every pass's final hidden state and gate logit stacked for the
+#: objective, a pass's carry); the part then reads ``layers/loop``, and
+#: a layer scan's own work inside a pass (weights out of their stack,
+#: kept residuals, the shared weights' gradients added into their
+#: accumulator's rows) stays ``layers``
+LOOP = "loop"
 PROJECT, SCORES, SELECT = "project", "scores", "select"
 #: sub-scopes of ``index``: the indexer's projections, norm and rotary
 #: (``index/project``), its scores of every (query, key) pair
@@ -88,7 +103,7 @@ PROJECT, SCORES, SELECT = "project", "scores", "select"
 INDEX_STEPS = (PROJECT, SCORES, SELECT)
 #: part -> the sub-scopes that may stand inside it
 SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,),
-             SSM: (SCAN,), INDEX: INDEX_STEPS}
+             SSM: (SCAN,), INDEX: INDEX_STEPS, LAYERS: (LOOP,)}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: the pass of an operation whose ``op_name`` the compiler wrote

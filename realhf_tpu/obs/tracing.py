@@ -102,12 +102,14 @@ CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "moe_share_overflow_total", "delta_tokens_total",
                     "sparse_pairs_total", "index_tokens_total",
                     "index_blocks_total", "ssm_tokens_total",
-                    "engine_stage_secs_total", "engine_cache_total")
+                    "engine_stage_secs_total", "engine_cache_total",
+                    "loop_token_passes_total")
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",
                   "moe_held_load_max_over_mean",
-                  "engine_program_bytes")
+                  "engine_program_bytes", "loop_expected_exit_pass",
+                  "loop_exit_entropy", "loop_exit_mass", "loop_pass_nll")
 
 #: ``(finished spans, profiled) -> {fingerprint: facts}``: who knows
 #: the compiled programs that ran under the spans (the engines:

@@ -126,6 +126,38 @@ def _shifted_logprobs(cfg, params, hidden, input_ids, seg_ids, chunk,
     return jnp.where(valid, lp, 0.0)
 
 
+def passes_logprobs_from_hidden(cfg: TransformerConfig, params,
+                                hidden: jnp.ndarray,  # [T, S, L, H]
+                                input_ids: jnp.ndarray,
+                                seg_ids: jnp.ndarray, *, chunk: int = 1024,
+                                temperature: float = 1.0) -> jnp.ndarray:
+    """:func:`shifted_logprobs_from_hidden` of every pass of a looped
+    model: [T, S, L] float32. The chunked head runs once a pass, one
+    pass after the other (a scan), so what is alive at a time is ONE
+    chunk's logits of ONE pass, never T logit arrays; the head's
+    gradient is the sum over T x chunks bodies, added in the weight's
+    dtype as a scan's transpose does."""
+    return jax.lax.map(
+        lambda h: shifted_logprobs_from_hidden(
+            cfg, params, h, input_ids, seg_ids, chunk=chunk,
+            temperature=temperature), hidden)
+
+
+def exit_log_distribution(gate_logits: jnp.ndarray) -> jnp.ndarray:
+    """log p_t [T, ...] of a looped model's exit distribution from its
+    gate's logits [T, ...] (float32; ``lambda_t = sigmoid``):
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < T and
+    ``p_T = prod_{j<T} (1 - lambda_j)``: the last pass takes what is
+    left, whatever its own gate says, so the p_t sum to 1. In logs:
+    ``log_sigmoid`` has no overflow at either end. Part ``exit``."""
+    with jax.named_scope(parts.EXIT):
+        g = gate_logits.astype(jnp.float32)
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)  # log prod_{j<=t}
+        before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+        leave = jax.nn.log_sigmoid(g).at[-1].set(0.0)
+        return before + leave
+
+
 def masked_normalization(
     x: jnp.ndarray,
     mask: Optional[jnp.ndarray] = None,

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``, ``nemotron_h``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``, ``nemotron_h``) or a looped one (``ouro``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -94,6 +94,15 @@ writes them to ``chiprun_out/chip_check_<family>.jsonl``:
   wrong equation there), a packed row whose first document passes
   ``topk``, and a prefill of 2,176 then 127 decode steps through the
   three attention caches.
+- ``objective`` (``ouro``): the looped objective (every pass's loss
+  weighed by the exit gate's distribution, less its entropy) and its
+  gradient on one microbatch of 512 tokens, rematerialised as the
+  experiments run it: the FLOAT32 engine against ``jax.grad`` of the
+  reference's written-out passes (loss, every statistic, the relative
+  gap of a shared matrix's gradient, of a norm's, of the gate's), the
+  BF16 engine's gradient against the same (the sum of four passes'
+  gradients is taken in the parameters' dtype: what that costs at
+  published widths), and what the WRONG objective reads.
 - ``gen`` (``--gen``): ``quickstart gen`` whole (128 prompts of 256,
   256 new tokens, two batches): the ``engine:generate`` spans with
   their attributes.
@@ -147,6 +156,11 @@ FAMILIES = {
         tiny=("nemotron_h", "tiny-nemotron-h.sft"), wrong_keys={},
         packed_docs=(1500, 1100, 1000, 496), decode=(2, 768, 640),
         exact_doc=4096, published_decay=True),
+    # objective: the family's ``objective_and_grad`` (a looped model's)
+    "ouro": dict(
+        cell="ouro-2.6b-l6.sft-4k-x4", tiny=("ouro", "tiny-ouro.sft"),
+        wrong_keys={}, packed_docs=(1536, 1024, 1024, 512),
+        decode=(2, 768, 640), exact_doc=4096, objective=512),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -362,8 +376,9 @@ def tolerance(cell, seed, work, table=True):
     casts = dict(int8_by_row=round_int8_by_row,
                  float8_e4m3=round_to(jnp.float8_e4m3fn),
                  float8_e5m2=round_to(jnp.float8_e5m2))
+    sparse = any(".experts." in k for k in tensors)
     for name, cast in casts.items():
-        if name != "float8_e5m2":
+        if name != "float8_e5m2" and sparse:
             rows[f"experts_{name}"] = share(family.logprobs(
                 hf, experts_rounded(tensors, cast), ids), want)
         rows[f"all_matrices_{name}"] = share(
@@ -984,6 +999,66 @@ def under_published_decay(cell, ckpt, tensors, ids, seed, docs, long,
                 say(phase="gradient", failed=repr(e)[:400])
 
 
+def objective(cell, ckpt, tensors, seed, length):
+    """A looped model's objective and its gradient on one microbatch of
+    ``length`` tokens (an eighth of it the prompt), rematerialised: the
+    float32 engine and the bf16 engine against ``jax.grad`` of the
+    reference, tensor by tensor."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from realhf_tpu.interfaces import sft
+    from realhf_tpu.models import hf as hf_models
+    family, hf = cell["family"], cell["hf"]
+    t = time.monotonic()
+    ids = np.random.default_rng(seed + 5).integers(
+        0, hf["vocab_size"], (1, length)).astype(np.int32)
+    mb = dict(input_ids=jnp.asarray(ids),
+              seg_ids=jnp.ones((1, length), jnp.int32),
+              prompt_mask=jnp.arange(length)[None] < length // 8)
+    ref_loss, parts, want = family.objective_and_grad(
+        hf, tensors, ids, length // 8)
+    wrong_loss = {w: float(family.objective(
+        hf, tensors, ids, length // 8, wrong=(w,))[0])
+        for w in family.WRONG}
+
+    def through_the_engine(dtype):
+        with jax.default_matmul_precision(
+                "highest" if dtype == "float32" else "default"):
+            engine = one_chip_engine(ckpt, dtype)
+            engine.cfg.gradient_checkpointing = True
+            (loss, stats), grads = jax.jit(jax.value_and_grad(
+                engine._objective(sft._make_loss_fn(engine.cfg)),
+                has_aux=True))(engine.params, mb)
+            got = hf_models.params_to_hf(FAMILY, jax.tree.map(
+                lambda g: np.asarray(g, np.float32), grads), engine.cfg)
+        gaps = {k: float(
+            np.linalg.norm(got[k].reshape(want[k].shape).astype(np.float64)
+                           - want[k]) / np.linalg.norm(want[k]))
+                for k in want}
+        layers = [v for k, v in gaps.items() if ".layers." in k
+                  and "norm" not in k]
+        norms = [v for k, v in gaps.items() if "layernorm" in k]
+        return dict(
+            loss=float(loss),
+            stats={k: float(v) for k, v in stats.items()},
+            shared_matrices=[min(layers), max(layers)],
+            shared_norms=[min(norms), max(norms)],
+            q_proj_0=gaps["model.layers.0.self_attn.q_proj.weight"],
+            gate_weight=gaps["model.early_exit_gate.weight"],
+            gate_bias=gaps["model.early_exit_gate.bias"],
+            final_norm=gaps["model.norm.weight"],
+            head=gaps["lm_head.weight"],
+            embedding=gaps["model.embed_tokens.weight"])
+
+    say(phase="objective", tokens=length, reference_loss=ref_loss,
+        reference={k: np.asarray(v).tolist() for k, v in parts.items()},
+        wrong_loss=wrong_loss,
+        engine_float32=through_the_engine("float32"),
+        engine_bf16=through_the_engine("bfloat16"),
+        secs=round(time.monotonic() - t, 1))
+
+
 def sft_microbatch(hf, seed):
     """One SFT microbatch at the cell's row length, from the seed."""
     import jax.numpy as jnp
@@ -1271,6 +1346,11 @@ def main():
                         selection_live(cell, engine, tensors, long)
                     engine = None  # the bf16 weights go before float32's come
                     exact(cell, ckpt, tensors, long)
+                if spec.get("objective"):
+                    engine = None
+                    objective(cell, ckpt, tensors, seed,
+                              spec["objective"] // (8 if args.rehearse
+                                                    else 1))
             if i == 0 and spec.get("published_decay"):
                 engine = None
                 under_published_decay(

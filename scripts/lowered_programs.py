@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's tool, no chip: lower the programs of the benchmark's
-accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2, nemotron_h) under a checkout and write
+accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2, nemotron_h, ouro) under a checkout and write
 their StableHLO texts, to show that a change to shared model code left
 a model of one block the programs it had.
 
@@ -62,7 +62,7 @@ def dump(name, fn, *args, **kw):
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
-for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096), ("nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 4096)):
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096), ("nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 4096), ("ouro-2.6b-l6", "ouro", 4096)):
     if fam not in hf_models.HF_FAMILIES:
         print(cfgname, "left out: this tree has no family", fam)
         continue
@@ -76,8 +76,10 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
     mb = dict(input_ids=sds((1, L), jnp.int32), seg_ids=sds((1, L), jnp.int32), prompt_mask=sds((1, L), jnp.bool_))
     loss_fn = sft._make_loss_fn(cfg)
     moe = bool(cfg.n_moe_layers)
+    # (a looped model's objective reads every pass's state)
+    passes = dict(return_passes=True) if getattr(loss_fn, "every_pass", False) else {}
     def objective(p, mb):
-        o = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"], return_aux=moe)
+        o = T.forward(cfg, p, mb["input_ids"], mb["seg_ids"], return_aux=moe, **passes)
         aux = o[2] if moe else {}
         loss, stats = loss_fn(p, o[0], mb)
         return loss + moe_ops.aux_loss(aux), {**stats, **aux}
