@@ -8,8 +8,10 @@ invisible to operators. This project checker diffs BOTH directions:
 
 - a **literal** metric name at an instrumentation call site
   (``inc`` / ``set_gauge`` / ``observe`` / ``observe_hist`` /
-  ``event`` or a registry constructor) that does not appear in the
-  catalog -> finding at the call site;
+  ``event`` or a registry constructor), or handed to a table as a
+  ``token_counter="..."`` keyword (a record that names the counter its
+  caller feeds, ``models/operators.py``), that does not appear in
+  the catalog -> finding at the call site;
 - a catalog row naming a metric that no call site emits -> finding
   at the doc line.
 
@@ -36,6 +38,8 @@ from realhf_tpu.analysis.finding import Finding
 #: instrumentation entry points taking a literal metric name first
 METRIC_CALLS = ("inc", "set_gauge", "observe", "observe_hist",
                 "counter", "gauge", "summary", "histogram", "event")
+#: keywords of ANY call whose literal value is a metric's name
+METRIC_KEYWORDS = ("token_counter",)
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 _HEADING_RE = re.compile(r"^#{2,}\s")
@@ -169,6 +173,14 @@ class ObsCatalogChecker(ProjectChecker):
             for node in ast.walk(tree):
                 if not isinstance(node, ast.Call):
                     continue
+                for kw in node.keywords:
+                    if kw.arg in METRIC_KEYWORDS and isinstance(
+                            kw.value, ast.Constant) and isinstance(
+                            kw.value.value, str):
+                        code_names.setdefault(
+                            kw.value.value,
+                            (rel, kw.value.lineno, kw.value.col_offset,
+                             symbols.get(node, "")))
                 func = node.func
                 attr = func.attr if isinstance(func, ast.Attribute) \
                     else (func.id if isinstance(func, ast.Name)

@@ -35,9 +35,10 @@ from realhf_tpu.base import logging
 from realhf_tpu.base.backend import pallas_enabled as _pallas_enabled
 from realhf_tpu.engine import generation as gen_mod
 from realhf_tpu.engine.optim import OptimizerConfig, make_optimizer
+from realhf_tpu.models import operators
 from realhf_tpu.models import sharding as shard_rules
 from realhf_tpu.models import transformer as T
-from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.models.config import ATTENTION_OPERATORS, TransformerConfig
 from realhf_tpu.obs import metrics, parts, tracing
 from realhf_tpu.ops import decode_attention as decode_ops
 from realhf_tpu.ops import functional as F
@@ -50,7 +51,6 @@ from realhf_tpu.ops.delta_rule import scan_handed, scan_kernel_calls
 from realhf_tpu.ops.ssm_scan import scan_kernel_calls as ssm_kernel_calls
 from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
                                             flash_mask_calls)
-from realhf_tpu.ops.sparse_index import pair_counts, scoring_blocks
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host, tree_bytes
@@ -107,6 +107,8 @@ class Engine:
         self.ctx = ctx
         self.mesh = ctx.mesh
         self.version = 0
+        # (an operator's record, how many of the layers are its)
+        self._operators = tuple(operators.used(cfg))
         # Multi-controller operation: when the mesh spans >1 OS process
         # (one jax.distributed world across hosts, reference NCCL world
         # global_comm.py:44), every member process runs the SAME engine
@@ -206,19 +208,10 @@ class Engine:
         # counts. The ring and the pipeline's XLA path do not.
         self._flash_rows = False
         if ctx.parallel.context_parallel_size > 1:
-            if cfg.sparse_layers:
-                raise NotImplementedError(
-                    "context parallelism (ops/ring_attention.py) is not "
-                    "implemented for a model with sparse layers "
-                    f"(layer_pattern '{cfg.pattern_string}'): the ring "
-                    "takes no selection of keys")
-            if cfg.ssm_layers:
-                raise NotImplementedError(
-                    "context parallelism is not implemented for a model "
-                    "with ssm layers (layer_pattern "
-                    f"'{cfg.pattern_string}'): a row cut along its "
-                    "length hands no state-space state from one part to "
-                    "the next")
+            for rec, n in self._operators:
+                if n and rec.no_context_parallel:
+                    raise NotImplementedError(rec.no_context_parallel.format(
+                        pattern=cfg.pattern_string))
             from realhf_tpu.ops.ring_attention import ring_attention
             mesh = self.mesh
 
@@ -267,8 +260,11 @@ class Engine:
         if cfg.layer_pattern is not None:
             self._model_attrs.update(
                 layer_pattern=cfg.pattern_string,
-                conv_layers=len(cfg.conv_layers),
                 dense_layers=cfg.n_layers - cfg.n_moe_layers)
+            # what each operator says of its layers (its record's)
+            for rec, n in self._operators:
+                if rec.attrs is not None and (n or rec.always):
+                    self._model_attrs.update(rec.attrs(cfg, n))
             if mode is not None:
                 self._model_attrs.update(
                     experts_held=cfg.moe.n_held,
@@ -277,42 +273,13 @@ class Engine:
                 if cfg.moe.shared_intermediate_dim is not None:
                     self._model_attrs.update(
                         shared_expert=cfg.moe.shared_intermediate_dim)
-            if cfg.window_layers:
-                self._model_attrs.update(
-                    window=cfg.sliding_window,
-                    window_layers=len(cfg.window_layers))
-            if cfg.latent is not None:
-                self._model_attrs.update(
-                    latent_layers=len(cfg.latent_layers),
-                    kv_lora_rank=cfg.latent.kv_rank,
-                    qk_dim=cfg.head_dim, v_dim=cfg.latent.v_dim)
-            if cfg.delta is not None:
-                from realhf_tpu.ops.delta_rule import CHUNK
-                self._model_attrs.update(
-                    delta_layers=len(cfg.delta_layers),
-                    delta_heads=cfg.delta.n_heads,
-                    delta_head_dim=cfg.delta.head_dim,
-                    delta_chunk=CHUNK)
-            if cfg.ssm is not None:
-                from realhf_tpu.ops.ssm_scan import CHUNK
-                self._model_attrs.update(
-                    ssm_layers=len(cfg.ssm_layers),
-                    ssm_heads=cfg.ssm.n_heads,
-                    ssm_head_dim=cfg.ssm.head_dim,
-                    ssm_state=cfg.ssm.state, ssm_groups=cfg.ssm.n_groups,
-                    ssm_chunk=CHUNK)
             if mode is not None and not cfg.gated_mlp:
                 self._model_attrs.update(
                     expert_ff=f"{cfg.activation_function}/ungated")
-            if cfg.indexer is not None:
-                self._model_attrs.update(
-                    sparse_layers=len(cfg.sparse_layers),
-                    index_heads=cfg.indexer.heads,
-                    index_dim=cfg.indexer.head_dim,
-                    index_topk=cfg.indexer.topk)
             if cfg.layer_q_heads is not None:
                 self._model_attrs.update(q_heads=" ".join(
-                    str(cfg.q_heads(i)) for i in cfg.attention_layers))
+                    str(cfg.q_heads(i))
+                    for i in cfg.layers_of(*ATTENTION_OPERATORS)))
             if cfg.rotary_by_operator is not None:
                 self._model_attrs.update(rotary=" ".join(
                     f"{op[0]}:{'none' if rc is None else rc.describe()}"
@@ -448,42 +415,22 @@ class Engine:
         on the host from the segment ids: the counters, and what of
         them its ``engine:*`` span carries (:meth:`_run`)."""
         self._count_routed_pairs(seg_ids, decode_tokens)
-        return {**self._count_sparse_pairs(seg_ids),
+        return {**self._count_rows(seg_ids),
                 **self._count_flash_blocks(seg_ids)}
 
-    def _count_sparse_pairs(self, seg_ids) -> Dict[str, float]:
-        """``sparse_pairs_total{role,kind}``: the (query, key) pairs
-        the sparse layers of the program about to run attend over
-        these packed rows (``selected``: ``min(position + 1, topk)`` a
-        token) and the pairs under their documents' causal masks
-        (``causal``), one head's, times the sparse layers
-        (``ops.sparse_index.pair_counts``); ``index_tokens_total
-        {role}``: valid tokens x sparse layers;
-        ``index_blocks_total{role,kind}``: the blocks of queries the
-        layers' indexers go over (``all``) and those of them that
-        score and select, the rest being their visibility masks
-        (``scored``), by the rule the program branches on
-        (``ops.sparse_index.scoring_blocks``; the last two axes are
-        what one call of the program sees). Their ratio is the span's
-        ``index_scored_share``. Nothing for a model without such
-        layers or a batch on the device already."""
-        cfg = self.cfg
-        if not cfg.sparse_layers or not isinstance(seg_ids, np.ndarray):
-            return {}
-        role, n = str(self.ctx.model_name.role), len(cfg.sparse_layers)
-        scoring = scoring_blocks(seg_ids, cfg.indexer.topk, xp=np)
-        metrics.inc("index_blocks_total", n * int(scoring.sum()),
-                    role=role, kind="scored")
-        metrics.inc("index_blocks_total", n * scoring.size, role=role,
-                    kind="all")
-        selected, causal = pair_counts(seg_ids, cfg.indexer.topk)
-        metrics.inc("sparse_pairs_total", n * selected, role=role,
-                    kind="selected")
-        metrics.inc("sparse_pairs_total", n * causal, role=role,
-                    kind="causal")
-        metrics.inc("index_tokens_total",
-                    n * int(np.count_nonzero(seg_ids)), role=role)
-        return dict(index_scored_share=float(scoring.mean()))
+    def _count_rows(self, seg_ids) -> Dict[str, float]:
+        """The counters an operator's record keeps of its own from the
+        packed rows of the program about to run (``Operator.count``: a
+        sparse layer's selected pairs and scoring blocks), and what of
+        them the span carries. Nothing for a model without such layers
+        or a batch on the device already."""
+        attrs = {}
+        if isinstance(seg_ids, np.ndarray):
+            for rec, n in self._operators:
+                if n and rec.count is not None:
+                    attrs.update(rec.count(self.cfg, n, seg_ids,
+                                           str(self.ctx.model_name.role)))
+        return attrs
 
     def _count_flash_blocks(self, seg_ids) -> Dict[str, float]:
         """``flash_kv_blocks_total{role,kind}``: the (query block, key
@@ -505,7 +452,8 @@ class Engine:
                 and not cfg.scale_attn_by_inverse_layer_idx):
             return {}
         layers_of = collections.Counter(
-            cfg.layer_window(i) for i in cfg.attention_layers)
+            cfg.layer_window(i)
+            for i in cfg.layers_of(*ATTENTION_OPERATORS))
         counts = dict(visited=0, causal=0, unmasked=0)
         for window, n in layers_of.items():
             for kind, pairs in zip(counts, block_counts(
@@ -526,25 +474,16 @@ class Engine:
         loop is asked for) x top_k x sparse layers, over ALL the
         router's experts whatever share of them is held. A device
         array is not read: all its positions count (pads are routed
-        like tokens). ``conv_tokens_total{role}`` likewise: tokens x
-        conv layers of a patterned model, ``delta_tokens_total{role}``
-        tokens x delta layers, ``ssm_tokens_total{role}`` tokens x ssm
-        layers."""
+        like tokens). An operator's own counter likewise
+        (``Operator.token_counter``, such as ``conv_tokens_total{role}``):
+        tokens x its layers of a patterned model."""
         tokens = decode_tokens + (
             int(np.count_nonzero(seg_ids))
             if isinstance(seg_ids, np.ndarray) else int(seg_ids.size))
-        if self.cfg.conv_layers:
-            metrics.inc("conv_tokens_total",
-                        tokens * len(self.cfg.conv_layers),
-                        role=str(self.ctx.model_name.role))
-        if self.cfg.delta_layers:
-            metrics.inc("delta_tokens_total",
-                        tokens * len(self.cfg.delta_layers),
-                        role=str(self.ctx.model_name.role))
-        if self.cfg.ssm_layers:
-            metrics.inc("ssm_tokens_total",
-                        tokens * len(self.cfg.ssm_layers),
-                        role=str(self.ctx.model_name.role))
+        for rec, n in self._operators:
+            if n and rec.token_counter is not None:
+                metrics.inc(rec.token_counter, tokens * n,
+                            role=str(self.ctx.model_name.role))
         if self.cfg.n_passes > 1:
             # (token, pass) pairs: every token runs every pass
             metrics.inc("loop_token_passes_total",
@@ -1357,31 +1296,15 @@ class Engine:
         self._read_facts_now("generate", program)
         if self.cfg.layer_pattern is not None:
             # the kinds of state the decode loop carried
-            self._last_span.set_attribute(
-                "kv_layers", len(self.cfg.attention_layers))
-            item = jnp.dtype(self.cfg.compute_dtype).itemsize
-            self._last_span.set_attribute(
-                "conv_state_bytes",
-                int(np.prod(T.conv_state_shape(
-                    self.cfg, prompt_seg.shape[0]))) * item)
-            if self.cfg.delta_layers:
-                tail, state = T.delta_state_shapes(
-                    self.cfg, prompt_seg.shape[0])
-                self._last_span.set_attribute(
-                    "delta_state_bytes",
-                    int(np.prod(tail)) * item + int(np.prod(state)) * 4)
-            if self.cfg.ssm_layers:
-                tail, state = T.ssm_state_shapes(
-                    self.cfg, prompt_seg.shape[0])
-                self._last_span.set_attribute(
-                    "ssm_state_bytes",
-                    int(np.prod(tail)) * item + int(np.prod(state)) * 4)
-            if self.cfg.sparse_layers:
-                b, lp = prompt_seg.shape
-                self._last_span.set_attribute(
-                    "index_cache_bytes", int(np.prod(T.index_cache_shape(
-                        self.cfg, b, T.round_cache_len(
-                            lp + gconfig.max_new_tokens)))) * item)
+            self._last_span.set_attribute("kv_layers", self.cfg.kv_layers)
+            b, lp = prompt_seg.shape
+            slots = T.round_cache_len(lp + gconfig.max_new_tokens)
+            for rec, n in self._operators:
+                if rec.state_bytes is not None and (n or rec.always):
+                    self._last_span.set_attribute(rec.state_bytes, n * sum(
+                        st.nbytes(self.cfg, b, slots,
+                                  self.cfg.compute_dtype)
+                        for st in rec.state))
         return out
 
     def inflight_generator(self, gconfig: GenerationHyperparameters,

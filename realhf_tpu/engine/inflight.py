@@ -31,6 +31,7 @@ import numpy as np
 
 from realhf_tpu.base import logging
 from realhf_tpu.engine import kv_pool as _kvp
+from realhf_tpu.models import operators as O
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.obs import tracing
@@ -917,11 +918,11 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
     group = cfg.n_q_heads // cfg.n_kv_heads
 
     def layer_body(x, k_all, v_all, lp, layer_idx, static_l=None):
-        ln1 = T._norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
-        q, k, v = T._qkv(cfg, lp, ln1)  # q [B,m,nq,hd]; k/v [B,m,nkv,hd]
+        ln1 = O._norm(cfg, x, lp["ln1"]["scale"], lp["ln1"].get("bias"))
+        q, k, v = O._qkv(cfg, lp, ln1)  # q [B,m,nq,hd]; k/v [B,m,nkv,hd]
         if cfg.apply_rotary:
-            q = T.apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-            k = T.apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+            q = O.apply_rotary(q, cos, sin, cfg.rotary_interleaved)
+            k = O.apply_rotary(k, cos, sin, cfg.rotary_interleaved)
         l = layer_idx if static_l is None else static_l
         k_l = k_all[l]  # [B, nkv, S, hd]
         v_l = v_all[l]
@@ -942,7 +943,7 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
         elif static_l is not None:
             scale = base / (static_l + 1)
         else:
-            scale = T._attn_scale(cfg, layer_idx)
+            scale = O._attn_scale(cfg, layer_idx)
         qg = q.reshape(b, m, cfg.n_kv_heads, group, cfg.head_dim)
         scores = jnp.einsum("bmhgd,bhsd->bmhgs", qg, k_l,
                             preferred_element_type=jnp.float32) * scale
@@ -954,7 +955,7 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
         if "bo" in lp["attn"]:
             proj = proj + lp["attn"]["bo"].astype(x.dtype)
         x = x + T._post_norm(cfg, lp, "ln1_post", proj)
-        ln2 = T._norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
+        ln2 = O._norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
         x = x + T._post_norm(cfg, lp, "ln2_post",
                              T._mlp(cfg, lp, ln2, moe_constraint))
         return x, k_all, v_all
@@ -964,7 +965,7 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
     # the final norm after every pass (models/transformer.py:_passes)
     for first in range(0, cfg.kv_layers, cfg.n_layers):
         if first:
-            x = T._norm(cfg, x, params["ln_f"]["scale"],
+            x = O._norm(cfg, x, params["ln_f"]["scale"],
                         params["ln_f"].get("bias"))
         if cfg.kv_layers <= T._DECODE_UNROLL_MAX_LAYERS:
             for li in range(cfg.n_layers):
@@ -985,7 +986,7 @@ def _extend_rows(cfg, moe_constraint, params, k_all, v_all, valid0,
                 layer_ids = layer_ids + first
             (x, k_all, v_all), _ = jax.lax.scan(
                 body, (x, k_all, v_all), (params["blocks"], layer_ids))
-    x = T._norm(cfg, x, params["ln_f"]["scale"],
+    x = O._norm(cfg, x, params["ln_f"]["scale"],
                 params["ln_f"].get("bias"))
     return x, k_all, v_all
 

@@ -19,7 +19,7 @@ TPU terms:
 - **inference MFCs** (reward/ref scoring) size TP to fit weights in
   bf16 (no optimizer), rest DP.
 
-All sizes are derived from ``TransformerConfig.n_params()``; the
+All sizes are derived from ``models/operators.py:n_params``; the
 layout is returned as {mfc_name: ParallelismConfig} plus the per-role
 primary, mirroring the (RPCAllocation, MFCConfig) output of the
 reference.
@@ -35,6 +35,7 @@ from realhf_tpu.base import logging as _logging
 logger = _logging.getLogger("heuristic")
 
 from realhf_tpu.api.config import ModelInterfaceType
+from realhf_tpu.models import operators
 from realhf_tpu.models.config import TransformerConfig
 from realhf_tpu.parallel.mesh import ParallelismConfig
 
@@ -115,7 +116,7 @@ def choose_layout(cfg: TransformerConfig, n_devices: int,
     (train batch seqs x seqlen, when known) lets the trainable fit
     check budget pipeline activations instead of weights-only (a pp
     allocation that ignores them can OOM on real shapes)."""
-    n_params = cfg.n_params()
+    n_params = operators.n_params(cfg)
 
     if trainable:
         # ZeRO-1 changes the trade-off: moments shrink with dp, so the

@@ -6,6 +6,7 @@ Field-level parity with reference ``realhf/api/core/model_api.py:144``
 variant replaces the LM head with a scalar value head (`is_critic`).
 """
 
+import collections
 import dataclasses
 from typing import Dict, Optional, Tuple
 
@@ -182,7 +183,7 @@ class IndexerConfig:
     Attention (grouped-query, every head of a token over the same
     ``S_t``) then runs over ``S_t`` alone. The selection is discrete: no
     gradient of the language-model loss reaches the indexer's leaves
-    (``models/transformer.py:_index_select`` stops it by name), and
+    (``models/operators.py:_index_select`` stops it by name), and
     decoding keeps ``kI`` a token a layer (``cache["index_k"]``)."""
     heads: int
     head_dim: int
@@ -518,14 +519,13 @@ class TransformerConfig:
             raise NotImplementedError(
                 "relu2 is an UNGATED feed-forward's activation "
                 "(mlp_type None)")
-        if (self.ssm is not None) != bool(self.ssm_layers):
-            raise ValueError(
-                f"layer_pattern has {len(self.ssm_layers)} ssm layers, "
-                f"ssm is {self.ssm}")
-        if (self.indexer is not None) != bool(self.sparse_layers):
-            raise ValueError(
-                f"layer_pattern has {len(self.sparse_layers)} sparse "
-                f"layers, indexer is {self.indexer}")
+        # an operator's own config and its layers come together
+        for op, name in (("ssm", "ssm"), ("sparse", "indexer"),
+                         ("delta", "delta"), ("latent", "latent")):
+            made_of, n = getattr(self, name), len(self.layers_of(op))
+            if (made_of is not None) != bool(n):
+                raise ValueError(f"layer_pattern has {n} {op} layers, "
+                                 f"{name} is {made_of}")
         if self.indexer is not None and (
                 self.indexer.head_dim % 2 or self.indexer.topk < 1
                 or self.rotary_by_operator is not None
@@ -533,16 +533,9 @@ class TransformerConfig:
             raise NotImplementedError(
                 "a sparse layer's indexer has an even head_dim, a topk "
                 "of at least 1 and the model-wide rotary embedding")
-        if (self.delta is not None) != bool(self.delta_layers):
-            raise ValueError(
-                f"layer_pattern has {len(self.delta_layers)} delta "
-                f"layers, delta is {self.delta}")
-        if (self.latent is not None) != bool(self.latent_layers):
-            raise ValueError(
-                f"layer_pattern has {len(self.latent_layers)} latent "
-                f"layers, latent is {self.latent}")
         if self.latent is not None:
-            if len(self.latent_layers) != len(self.attention_layers):
+            if self.layers_of("latent") \
+                    != self.layers_of(*ATTENTION_OPERATORS):
                 raise NotImplementedError(
                     "latent layers beside attention or window layers: "
                     "the K/V stack keeps ONE shape (layer_pattern "
@@ -602,55 +595,25 @@ class TransformerConfig:
     def n_moe_layers(self) -> int:
         return sum(ff == "moe" for _, ff in self.layer_kinds)
 
-    @property
-    def attention_layers(self) -> Tuple[int, ...]:
-        """The layers that have keys and values, in order: full and
-        window attention alike."""
+    def layers_of(self, *ops: str) -> Tuple[int, ...]:
+        """The layers whose operator is one of ``ops``, in order
+        (``layers_of(*ATTENTION_OPERATORS)``: those that have keys and
+        values, full, window, latent and sparse attention alike)."""
         return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op in ATTENTION_OPERATORS)
+                     if op in ops)
 
     @property
     def kv_layers(self) -> int:
         """How many layers' worth of keys and values a cache holds:
         the attention layers, once a pass of a looped model (pass t
         from 0, attention layer l of n at index ``t x n + l``)."""
-        return self.n_passes * len(self.attention_layers)
-
-    @property
-    def latent_layers(self) -> Tuple[int, ...]:
-        """The layers whose keys and values come from a latent."""
-        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "latent")
-
-    @property
-    def delta_layers(self) -> Tuple[int, ...]:
-        """The layers that keep a delta-rule state a head."""
-        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "delta")
-
-    @property
-    def ssm_layers(self) -> Tuple[int, ...]:
-        """The layers that keep a state-space state a head."""
-        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "ssm")
-
-    @property
-    def sparse_layers(self) -> Tuple[int, ...]:
-        """The layers whose attention runs over an indexer's choice."""
-        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "sparse")
+        return self.n_passes * len(self.layers_of(*ATTENTION_OPERATORS))
 
     @property
     def v_head_dim(self) -> int:
         """A value head's width, which is also an attention output
         head's: ``head_dim`` unless the layers are latent."""
         return self.head_dim if self.latent is None else self.latent.v_dim
-
-    @property
-    def window_layers(self) -> Tuple[int, ...]:
-        """The layers whose attention sees ``sliding_window`` tokens."""
-        return tuple(i for i in self.attention_layers
-                     if self.layer_window(i) is not None)
 
     def layer_window(self, i: int) -> Optional[int]:
         """The window of layer ``i``'s attention, None where it sees
@@ -689,11 +652,6 @@ class TransformerConfig:
             else self.layer_q_heads[i]
 
     @property
-    def conv_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i, (op, _) in enumerate(self.layer_kinds)
-                     if op == "conv")
-
-    @property
     def pattern_string(self) -> str:
         """``c a c c c``, ``a w w w a``, ``l l l``, ``d d d l d``,
         ``s s s``, ``- m - m - m a``: every layer's operator by its
@@ -706,85 +664,10 @@ class TransformerConfig:
         """Refuse, by name, what only runs a model of one kind of
         block (a stack under ``params["blocks"]``)."""
         if self.layer_pattern is not None:
+            kinds = collections.Counter(
+                op for op, _ in self.layer_pattern if op != ABSENT)
             raise NotImplementedError(
                 f"{what} is not implemented for a model with a layer "
                 f"pattern (layer_pattern '{self.pattern_string}': "
-                f"{len(self.conv_layers)} conv and "
-                f"{len(self.attention_layers)} attention layers, "
-                f"{len(self.window_layers)} of those with a window, "
-                f"{len(self.latent_layers)} latent, "
-                f"{len(self.delta_layers)} delta layers that keep a "
-                f"state a head, {len(self.sparse_layers)} whose keys an "
-                f"indexer picks, {self.n_moe_layers} layers with "
-                f"experts, {len(self.ssm_layers)} ssm layers that keep a "
-                "state-space state a head)")
-
-    def n_params(self) -> int:
-        """Approximate parameter count (for FLOPs/memory estimates),
-        layer by layer of the pattern: every matrix, the convolutions'
-        taps, the router (and its selection bias) over all experts,
-        the experts HELD, the shared expert, the query/key norms and
-        the output gate, each attention layer at its own count of
-        query heads, a latent layer's five leaves, a delta layer's
-        fifteen, a sparse layer's indexer, an ssm layer's seven (its
-        convolution's bias among them); an ungated feed-forward's two
-        matrices where ``mlp_type`` is None; a looped model's exit
-        gate (its layers count ONCE however often they run); a part a
-        layer lacks counts nothing; other biases and the layer norms'
-        scales are left out."""
-        h, f, v = self.hidden_dim, self.intermediate_dim, self.vocab_size
-
-        def attn(i):
-            nq = self.q_heads(i)
-            if self.latent is not None:
-                lat = self.latent
-                nope = self.head_dim - lat.rope_dim
-                return h * nq * self.head_dim \
-                    + h * (lat.kv_rank + lat.rope_dim) + lat.kv_rank \
-                    + lat.kv_rank * nq * (nope + lat.v_dim) \
-                    + nq * lat.v_dim * h
-            n = h * (nq + 2 * self.n_kv_heads) * self.head_dim \
-                + nq * self.head_dim * h
-            if self.qk_norm == "full":
-                n += (nq + self.n_kv_heads) * self.head_dim
-            elif self.qk_norm == "head":
-                n += 2 * self.head_dim
-            if self.layer_kinds[i][0] == "sparse":
-                ix = self.indexer
-                n += h * (ix.heads + 1) * ix.head_dim + h * ix.heads \
-                    + ix.head_dim
-            return n + (h * nq if self.attn_output_gate else 0)
-
-        conv = 4 * h * h + self.conv_kernel * h
-        delta = 0
-        if self.delta is not None:
-            dl = self.delta
-            delta = 4 * h * dl.width + 3 * dl.conv_kernel * dl.width \
-                + 2 * (h + dl.width) * dl.gate_rank + dl.width \
-                + h * dl.n_heads + dl.n_heads + dl.head_dim
-        ssm = 0
-        if self.ssm is not None:
-            sm = self.ssm
-            ssm = h * sm.in_dim + (sm.conv_kernel + 1) * sm.conv_dim \
-                + 3 * sm.n_heads + sm.width + sm.width * h
-        mats = 3 if self.gated_mlp else 2
-        dense = mats * h * f
-        moe = 0
-        if self.moe is not None:
-            # the experts HELD, the router (and bias) over all of them
-            moe = mats * h * (self.moe.intermediate_dim or f) \
-                * self.moe.n_held + h * self.moe.num_experts
-            if self.moe.use_expert_bias:
-                moe += self.moe.num_experts
-            moe += mats * h * (self.moe.shared_intermediate_dim or 0)
-        embed = v * h if self.tied_embedding else 2 * v * h
-        if self.is_critic:
-            embed = v * h + h
-        if self.exit_gate:
-            embed += h + 1
-        return embed + sum(
-            (conv if op == "conv" else delta if op == "delta"
-             else ssm if op == "ssm" else 0 if op == ABSENT
-             else attn(i))
-            + (moe if ff == "moe" else 0 if ff == ABSENT else dense)
-            for i, (op, ff) in enumerate(self.layer_kinds))
+                + "".join(f"{n} {op} layers, " for op, n in kinds.items())
+                + f"{self.n_moe_layers} layers with experts)")

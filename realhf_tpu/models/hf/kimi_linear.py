@@ -181,8 +181,8 @@ def _config_to_hf(cfg: TransformerConfig) -> Dict[str, Any]:
         "num_key_value_heads": cfg.n_kv_heads,
         "head_dim": cfg.hidden_dim // cfg.n_q_heads,
         "linear_attn_config": {
-            "kda_layers": [i + 1 for i in cfg.delta_layers],
-            "full_attn_layers": [i + 1 for i in cfg.latent_layers],
+            "kda_layers": [i + 1 for i in cfg.layers_of("delta")],
+            "full_attn_layers": [i + 1 for i in cfg.layers_of("latent")],
             "num_heads": dl.n_heads, "head_dim": dl.head_dim,
             "short_conv_kernel_size": dl.conv_kernel},
         "mla_use_nope": True,

@@ -22,6 +22,7 @@ from typing import Any, Dict
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from realhf_tpu.models import operators as O
 from realhf_tpu.models.config import ABSENT, TransformerConfig
 from realhf_tpu.parallel.mesh import CTX_AXIS, DATA_AXIS, MODEL_AXIS, PIPE_AXIS
 
@@ -108,80 +109,15 @@ def param_pspecs(cfg: TransformerConfig,
 
 def _pattern_pspecs(cfg: TransformerConfig) -> Dict[str, Any]:
     """``param_pspecs`` of a patterned model: the same rules with no
-    layer axis, a tree a layer. A delta layer is tensor parallel by
-    head, a sparse layer's indexer on every shard. The convolution is tensor parallel
-    like a feed-forward: ``w_in`` by column (GSPMD moves its three
-    parts to a sharding by channel after the split), the taps by
-    channel, ``w_out`` by row."""
-    col, row = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
-    layers = {}
-    # an ungated feed-forward (``mlp_type`` None) has no ``wg``
-    gate = {"wg": col} if cfg.gated_mlp else {}
-    for i, (op, ff) in enumerate(cfg.layer_pattern):
-        # (a part a layer lacks has no norm either)
-        lp: Dict[str, Any] = {name: {"scale": P(None)}
-                              for name, part in (("ln1", op), ("ln2", ff))
-                              if part != ABSENT}
-        if op == ABSENT:
-            pass
-        elif op == "ssm":
-            # by head, and a group's B and C with its heads: ``w_in``
-            # by column (GSPMD moves z, x, B, C and dt to a sharding by
-            # head and group after the split), the taps and the bias by
-            # channel, the three leaves a head, the grouped norm's
-            # scale by its width, ``w_out`` by row
-            lp["ssm"] = {"w_in": col, "conv": col,
-                         "conv_bias": P(MODEL_AXIS), "a_log": P(MODEL_AXIS),
-                         "dt_bias": P(MODEL_AXIS), "d": P(MODEL_AXIS),
-                         "norm": P(MODEL_AXIS), "w_out": row}
-        elif op == "conv":
-            lp["conv"] = {"w_in": col, "w": col, "w_out": row}
-        elif op == "delta":
-            # by head: the projections' columns, the convolutions'
-            # channels, the decay's leaves, the step and the expansions
-            # of the two gates; what is one rank or one head wide (the
-            # gates' compressions, the output's norm) on every shard
-            lp["delta"] = {
-                "wq": col, "wk": col, "wv": col, "conv_q": col,
-                "conv_k": col, "conv_v": col, "a_log": P(MODEL_AXIS),
-                "w_fa": P(None, None), "w_fb": col,
-                "dt_bias": P(MODEL_AXIS), "w_b": col,
-                "w_ga": P(None, None), "w_gb": col, "o_norm": P(None),
-                "wo": row}
-        elif op == "latent":
-            # by head, as wq and wo: the expansion's columns are a
-            # head's (nope + v) at a time; the compression, whose
-            # output is one row for all heads, is on every shard
-            lp["attn"] = {"wq": col, "w_kv_a": P(None, None),
-                          "kv_a_norm": P(None), "w_kv_b": col, "wo": row}
-        else:
-            lp["attn"] = {"wq": col, "wk": col, "wv": col, "wo": row}
-            if cfg.attn_output_gate:  # a gate a head: by head, as wq
-                lp["attn"]["w_gate"] = col
-            if cfg.qk_norm == "head":  # one head's width: on every shard
-                lp["attn"].update(q_norm=P(None), k_norm=P(None))
-            elif cfg.qk_norm is not None:
-                lp["attn"].update(q_norm=P(MODEL_AXIS),
-                                  k_norm=P(MODEL_AXIS))
-            if op == "sparse":
-                # on every shard: the selection is one for all the
-                # heads of a token, so every shard needs it whole
-                lp["index"] = {"wq": P(None, None), "wk": P(None, None),
-                               "k_norm": P(None), "k_norm_bias": P(None),
-                               "w_weights": P(None, None)}
-        if ff == "moe":
-            lp["mlp"] = {"router": P(None, None),
-                         "wu": P(None, None, MODEL_AXIS),
-                         "wd": P(None, MODEL_AXIS, None)}
-            if cfg.gated_mlp:
-                lp["mlp"]["wg"] = P(None, None, MODEL_AXIS)
-            if cfg.moe.use_expert_bias:
-                lp["mlp"]["expert_bias"] = P(None)
-            if cfg.moe.shared_intermediate_dim is not None:
-                lp["mlp"]["shared"] = {**gate, "wu": col, "wd": row}
-        elif ff != ABSENT:
-            lp["mlp"] = {**gate, "wu": col, "wd": row}
-        layers[str(i)] = lp
+    layer axis, a tree a layer: of each leaf its operator and its
+    feed-forward declare (``models/operators.py``) the spec declared
+    with it (a part a layer lacks has no norm either)."""
+    layers = {str(i): {
+        **{name: {"scale": P(None)}
+           for name, part in (("ln1", op), ("ln2", ff)) if part != ABSENT},
+        **O.walk({**O.OPERATORS[op].leaves(cfg, i),
+                  **O.FEED_FORWARDS[ff](cfg)}, lambda leaf: leaf.spec)}
+        for i, (op, ff) in enumerate(cfg.layer_pattern)}
     specs: Dict[str, Any] = {"embed": {"wte": P(MODEL_AXIS, None)},
                              "layers": layers,
                              "ln_f": {"scale": P(None)}}

@@ -61,6 +61,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -94,16 +95,16 @@ EPOCH_OFFSET = time.time() - time.monotonic()
 #: stretch may be several: profiled steps, then synced ones)
 KEPT_CAPTURES = 8
 
-#: counters whose deltas a capture reports (docs/observability.md)
+#: counters whose deltas a capture reports (docs/observability.md),
+#: beside the one a layer operator's record names for its tokens
+#: (``models/operators.py:token_counters``)
 CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "engine_compiles_total", "engine_compile_secs_total",
                     "moe_routed_pairs_total", "flash_kv_blocks_total",
-                    "moe_held_pairs_total", "conv_tokens_total",
-                    "moe_share_overflow_total", "delta_tokens_total",
+                    "moe_held_pairs_total", "moe_share_overflow_total",
                     "sparse_pairs_total", "index_tokens_total",
-                    "index_blocks_total", "ssm_tokens_total",
-                    "engine_stage_secs_total", "engine_cache_total",
-                    "loop_token_passes_total")
+                    "index_blocks_total", "engine_stage_secs_total",
+                    "engine_cache_total", "loop_token_passes_total")
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",
@@ -309,8 +310,14 @@ def _series(name: str, labels) -> str:
     return f"{name}{{{inner}}}" if inner else name
 
 
-def _metric_values(names=CAPTURE_COUNTERS) -> Dict[str, float]:
+def _metric_values(names=None) -> Dict[str, float]:
     from realhf_tpu.obs import metrics
+    if names is None:
+        # (a process that feeds an operator's counter holds a model,
+        # so it has imported the table; no other need import JAX)
+        table = sys.modules.get("realhf_tpu.models.operators")
+        names = CAPTURE_COUNTERS + (
+            table.token_counters() if table else ())
     out = {}
     for name, m in metrics.snapshot().items():
         if name in names:

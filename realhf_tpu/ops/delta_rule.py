@@ -1,6 +1,6 @@
 """The gated delta rule with one decay a KEY CHANNEL (Kimi Delta
 Attention), chunked over the row: operator "delta" of a layer pattern
-(``models/config.py:DeltaConfig``, ``models/transformer.py:_delta_op``).
+(``models/config.py:DeltaConfig``, ``models/operators.py:_delta_op``).
 
 A head keeps a state S [dk, dv], 0 before a document's first token.
 Token t brings q_t, k_t [dk] (l2-normed by the caller, q_t scaled), v_t

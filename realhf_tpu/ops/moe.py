@@ -204,6 +204,35 @@ def z_loss(logits: jnp.ndarray,
     return (z * valid).sum() / jnp.maximum(valid.sum(), 1.0)
 
 
+def _activation(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
+    if cfg.activation_function == "silu":
+        return jax.nn.silu(x)
+    if cfg.activation_function == "gelu":
+        return jax.nn.gelu(x, approximate=False)
+    if cfg.activation_function == "gelu_new":
+        return jax.nn.gelu(x, approximate=True)
+    if cfg.activation_function == "relu2":
+        return jnp.square(jax.nn.relu(x))
+    raise NotImplementedError(cfg.activation_function)
+
+
+def _dense_mlp(cfg, m, x, cdt):
+    """A dense feed-forward (``models/transformer.py:_mlp_with_aux``;
+    the shared expert below): gated, or two products with their
+    biases."""
+    if cfg.gated_mlp:
+        gate = x @ m["wg"].astype(cdt)
+        up = x @ m["wu"].astype(cdt)
+        return _activation(cfg, gate) * up @ m["wd"].astype(cdt)
+    up = x @ m["wu"].astype(cdt)
+    if "bu" in m:
+        up = up + m["bu"].astype(cdt)
+    out = _activation(cfg, up) @ m["wd"].astype(cdt)
+    if "bd" in m:
+        out = out + m["bd"].astype(cdt)
+    return out
+
+
 def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
                 ) -> jnp.ndarray:
     """Batched expert MLP of the dense and capacity modes: xs
@@ -211,7 +240,6 @@ def _expert_ffn(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray
     batched einsum per projection (every expert over C rows): three
     where the feed-forward is gated, ``act(gate) * up``, two where it
     is not, ``act(up)``."""
-    from realhf_tpu.models.transformer import _activation
     cdt = xs.dtype
     up = jnp.einsum("ech,ehf->ecf", xs, m["wu"].astype(cdt))
     if cfg.gated_mlp:
@@ -254,7 +282,6 @@ def _grouped_products(cfg: TransformerConfig, m: Dict, xs: jnp.ndarray,
     counted into the last group: every row lies in a group (the
     caller zeroes them on the way in and gives them no gate).
     ``every_row_covered``: the caller's sizes add up to the rows."""
-    from realhf_tpu.models.transformer import _activation
     cdt = xs.dtype
     if kernel:
         dot = grouped_matmul
@@ -515,7 +542,6 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
         # as the routed ones are) beside them, weight 1, outside the sort. Every
         # rank of an expert-parallel deployment holds it whole, so the
         # shares' routed parts and THIS, once, add up to the layer.
-        from realhf_tpu.models.transformer import _dense_mlp
         with jax.named_scope(P.SHARED_EXPERT):
             out = out + _dense_mlp(cfg, m["shared"], xt.astype(x.dtype),
                                    x.dtype).astype(jnp.float32)

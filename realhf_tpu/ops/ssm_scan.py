@@ -1,6 +1,6 @@
 """The Mamba-2 state-space scan with ONE decay a head, chunked over the
 row: operator "ssm" of a layer pattern (``models/config.py:SsmConfig``,
-``models/transformer.py:_ssm_op``).
+``models/operators.py:_ssm_op``).
 
 A head keeps a state S [P, N] (``head_dim`` x ``state``), 0 before a
 document's first token. Token t brings x_t [P], a step ``Delta_t =

@@ -27,6 +27,7 @@ import numpy as np
 
 from realhf_tpu.api.config import ModelInterfaceType
 from realhf_tpu.base import logging
+from realhf_tpu.models.operators import n_params
 from realhf_tpu.parallel.mesh import ParallelismConfig
 
 logger = logging.getLogger("search", "benchmark")
@@ -584,7 +585,7 @@ def calibrate_cost_model(
         t_lo = timed_gen(gn_lo)
         t_hi = timed_gen(probe_gen_tokens)
         decode_s = max(t_hi - t_lo, 1e-6)
-        pbytes = probe.n_params() * jnp_dtype_size(probe.param_dtype)
+        pbytes = n_params(probe) * jnp_dtype_size(probe.param_dtype)
         decode_bytes = (probe_gen_tokens - gn_lo) * pbytes
         bw_fracs.append(decode_bytes / decode_s / cm.hbm_bandwidth)
 
@@ -627,12 +628,12 @@ def workloads_from_spec(spec, gen_tokens: int = 256,
             head_dim=cfg.head_dim,
             intermediate_dim=cfg.intermediate_dim,
             vocab_size=cfg.vocab_size, seqlens=seqlens)
-        pbytes = cfg.n_params() * 2.0
+        pbytes = n_params(cfg) * 2.0
         out.append(MFCWorkload(
             name=node.name, role=node.role,
             interface_type=node.interface_type,
             fwd_flops=float(fwd), param_bytes=pbytes,
-            train_state_bytes=cfg.n_params() * 18.0,
+            train_state_bytes=n_params(cfg) * 18.0,
             n_layers=cfg.n_layers,
             gen_tokens=(gen_tokens if node.interface_type
                         == ModelInterfaceType.GENERATE else 0)))

@@ -91,6 +91,18 @@ def test_both_drift_directions(tmp_path):
     assert len(fs) == 2
 
 
+def test_a_name_handed_to_a_table_is_an_emit_site(tmp_path):
+    """``Record(token_counter="x_total")``: the record names the counter its
+    caller feeds (``metrics.inc(rec.token_counter, ...)``), both directions."""
+    code = CODE + 'TABLE = dict(a=Record(token_counter="stale_total"),\n' \
+        '             b=Record(token_counter="unlisted_total", o="a_total"))\n'
+    fs = seed(tmp_path, code=code).check_project(str(tmp_path))
+    assert {f.message.split("`")[1] for f in fs} == {
+        "undocumented_total", "unlisted_total"}
+    [f] = [f for f in fs if "unlisted_total" in f.message]
+    assert (f.path, f.line) == ("pkg/mod.py", len(code.splitlines()))
+
+
 def test_clean_tree_and_missing_doc(tmp_path):
     checker = seed(tmp_path, doc=DOC.replace(
         "| `stale_total` | counter | nothing emits this |\n", ""),

@@ -14,6 +14,7 @@ from realhf_tpu.experiments.heuristic import (
 )
 from realhf_tpu.experiments.ppo_exp import PPOConfig
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.models.operators import n_params
 
 LLAMA_7B = dict(n_layers=32, n_kv_heads=32, n_q_heads=32, hidden_dim=4096,
                 intermediate_dim=11008, vocab_size=32000, n_positions=4096,
@@ -52,7 +53,7 @@ def test_choose_layout_7b():
     # state (18 B/param) exceeds 8 v5e chips even at full TP, so the
     # planner clamps to max TP (more chips or remat/offload needed)
     for lay, mult in ((gen, 3.0), (inf, 2.4)):
-        per_chip = cfg.n_params() * mult / lay.tensor_parallel_size
+        per_chip = n_params(cfg) * mult / lay.tensor_parallel_size
         assert per_chip <= DEFAULT_HBM_BUDGET
 
 
@@ -102,7 +103,7 @@ def test_choose_layout_70b_uses_pipeline():
     assert train.tensor_parallel_size <= 8
     assert train.pipeline_parallel_size > 1
     assert cfg.n_layers % train.pipeline_parallel_size == 0
-    state_bytes = cfg.n_params() * 18
+    state_bytes = n_params(cfg) * 18
     per_chip = state_bytes / (train.tensor_parallel_size
                               * train.pipeline_parallel_size)
     assert per_chip <= DEFAULT_HBM_BUDGET

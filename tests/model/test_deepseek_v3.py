@@ -40,6 +40,7 @@ from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.parallel import mesh as mesh_lib
+from realhf_tpu.models.operators import n_params
 
 #: max |delta logit| allowed between the program and the reference
 LOGIT_TOL = 1e-5
@@ -128,9 +129,9 @@ def test_config_is_read_from_the_published_keys(model):
     assert cfg.layer_pattern == (("latent", "dense"),) \
         + (("latent", "moe"),) * 4
     assert cfg.pattern_string == "l l l l l"
-    assert (cfg.attention_layers, cfg.latent_layers, cfg.window_layers,
-            cfg.conv_layers, cfg.n_moe_layers) == (
-        (0, 1, 2, 3, 4), (0, 1, 2, 3, 4), (), (), 4)
+    assert (cfg.kv_layers, cfg.layers_of("latent"), cfg.layers_of("window"),
+            cfg.layers_of("conv"), cfg.n_moe_layers) == (
+        5, (0, 1, 2, 3, 4), (), (), 4)
     lat = cfg.latent
     from realhf_tpu.models.config import LATENT_NORM_EPS
     assert (cfg.head_dim, cfg.v_head_dim, lat.kv_rank, lat.rope_dim,
@@ -159,7 +160,7 @@ def test_config_is_read_from_the_published_keys(model):
     # the program's estimate leaves the layer norms' scales out; it
     # counts the latent's five leaves (its norm among them), the
     # selection bias and the shared experts
-    assert cfg.n_params() == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
+    assert n_params(cfg) == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
     init = T.init_params(cfg, jax.random.PRNGKey(0))
     assert jax.tree.map(jnp.shape, init) == jax.tree.map(
         jnp.shape, model["params"])
@@ -575,8 +576,7 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
     cfg, params = model["cfg"], model["params"]
     g = GenerationHyperparameters(max_new_tokens=2, greedy=True,
                                   force_no_logits_mask=True)
-    named = (r"layer pattern \(layer_pattern 'l l l l l': 0 conv and 5 "
-             r"attention layers, 0 of those with a window, 5 latent")
+    named = r"layer pattern \(layer_pattern 'l l l l l': 5 latent layers"
     with pytest.raises(NotImplementedError, match="slot engine.*" + named):
         inflight.InflightBatchingGenerator(
             cfg, params, g, n_slots=2, max_prompt_len=8,

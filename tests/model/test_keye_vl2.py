@@ -27,6 +27,8 @@ from realhf_tpu.engine.engine import Engine
 from realhf_tpu.engine.optim import OptimizerConfig
 from realhf_tpu.interfaces import sft
 from realhf_tpu.models import hf as hf_models
+from realhf_tpu.models import operators
+from realhf_tpu.models.config import ATTENTION_OPERATORS
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.ops import sparse_index
@@ -121,7 +123,8 @@ def test_config_is_read_from_the_published_keys(model):
     cfg, hf = model["cfg"], model["hf"]
     assert cfg.layer_pattern == (("sparse", "moe"),) * 3
     assert cfg.pattern_string == "s s s"
-    assert cfg.sparse_layers == cfg.attention_layers == (0, 1, 2)
+    assert cfg.layers_of("sparse") == cfg.layers_of(*ATTENTION_OPERATORS) \
+        == (0, 1, 2)
     assert (cfg.indexer.heads, cfg.indexer.head_dim,
             cfg.indexer.topk) == (4, 8, TOPK)
     assert (cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim, cfg.qk_norm,
@@ -136,7 +139,8 @@ def test_config_is_read_from_the_published_keys(model):
     params = family.n_params(hf)
     assert params == sum(v.size for v in model["tensors"].values())
     assert params == sum(a.size for a in jax.tree.leaves(model["params"]))
-    assert cfg.n_params() == family.n_matrix_params(hf) + 3 * (2 * 16 + 8)
+    assert operators.n_params(cfg) \
+        == family.n_matrix_params(hf) + 3 * (2 * 16 + 8)
     index = model["params"]["layers"]["1"]["index"]
     assert {k: v.shape for k, v in index.items()} == dict(
         wq=(64, 32), wk=(64, 8), k_norm=(8,), k_norm_bias=(8,),
@@ -586,10 +590,8 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
     cfg, params = model["cfg"], model["params"]
     g = GenerationHyperparameters(max_new_tokens=2, greedy=True,
                                   force_no_logits_mask=True)
-    named = (r"layer pattern \(layer_pattern 's s s': 0 conv and 3 "
-             r"attention layers, 0 of those with a window, 0 latent, "
-             r"0 delta layers that keep a state a head, 3 whose keys an "
-             r"indexer picks, 3 layers with experts")
+    named = (r"layer pattern \(layer_pattern 's s s': 3 sparse layers, "
+             r"3 layers with experts")
     with pytest.raises(NotImplementedError, match="slot engine.*" + named):
         inflight.InflightBatchingGenerator(
             cfg, params, g, n_slots=2, max_prompt_len=8,
@@ -623,8 +625,9 @@ def test_the_config_says_what_a_sparse_layer_may_be():
     ix = IndexerConfig(heads=4, head_dim=8, topk=6)
     two = (("sparse", "dense"),) * 2
     cfg = TransformerConfig(**base, layer_pattern=two, indexer=ix)
-    assert cfg.sparse_layers == cfg.attention_layers == (0, 1)
-    assert cfg.pattern_string == "s s" and cfg.window_layers == ()
+    assert cfg.layers_of("sparse") == cfg.layers_of(*ATTENTION_OPERATORS) \
+        == (0, 1)
+    assert cfg.pattern_string == "s s" and cfg.layers_of("window") == ()
     with pytest.raises(ValueError, match="indexer is None"):
         TransformerConfig(**base, layer_pattern=two)
     with pytest.raises(ValueError, match="0 sparse layers"):
@@ -643,7 +646,7 @@ def test_the_config_says_what_a_sparse_layer_may_be():
     mixed = TransformerConfig(
         **base, indexer=ix,
         layer_pattern=(("attention", "dense"), ("sparse", "dense")))
-    assert mixed.sparse_layers == (1,) and mixed.pattern_string == "a s"
+    assert mixed.layers_of("sparse") == (1,) and mixed.pattern_string == "a s"
 
 
 def test_select_topk_is_exact_and_breaks_ties_low():
@@ -841,9 +844,9 @@ def test_sparse_stack_through_the_flash_kernels(interpreted_kernels):
     # what block skipping by the selection would save here
     _, states = T.forward(cfg, params, jnp.asarray(ids), jnp.asarray(seg),
                           return_kv=True)
-    select = np.asarray(T._index_select(
+    select = np.asarray(operators._index_select(
         cfg, params["layers"]["0"]["index"],
-        T._norm(cfg, params["embed"]["wte"][ids],
+        operators._norm(cfg, params["embed"]["wte"][ids],
                 params["layers"]["0"]["ln1"]["scale"], None),
         jnp.asarray(seg),
         *T._rotary_tables(cfg, T.positions_from_segments(

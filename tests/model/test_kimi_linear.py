@@ -40,6 +40,7 @@ from realhf_tpu.interfaces import sft
 from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
+from realhf_tpu.models.operators import OPERATORS
 from realhf_tpu.parallel import mesh as mesh_lib
 
 #: max |delta logit| allowed between the program and the reference
@@ -248,7 +249,8 @@ def test_prefill_then_decode_matches_full_forward(model, n_pre,
     empty = T.init_kv_cache(cfg, len(docs), total)
     assert {k: (v.shape, v.dtype) for k, v in empty.items()} == \
         {k: (v.shape, v.dtype) for k, v in cache.items()}
-    assert T.delta_state_shapes(cfg, len(docs)) == (
+    assert tuple((4, *st.shape(cfg, len(docs), total))
+                 for st in OPERATORS["delta"].state) == (
         cache["delta_conv"].shape, cache["delta"].shape)
     got = [np.asarray(T.lm_logits(cfg, params, hidden))]
     for t in range(n_pre, total):

@@ -27,6 +27,7 @@ from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.parallel import mesh as mesh_lib
+from realhf_tpu.models.operators import n_params
 
 from test_kimi_linear import (  # noqa: F401 - ``built`` is a fixture
     _BASE,
@@ -49,9 +50,9 @@ def test_config_is_read_from_the_published_keys(built):
         ("delta", "dense"), ("delta", "moe"), ("delta", "moe"),
         ("latent", "moe"), ("delta", "moe"))
     assert cfg.pattern_string == "d d d l d"
-    assert (cfg.delta_layers, cfg.attention_layers, cfg.latent_layers,
-            cfg.conv_layers, cfg.window_layers, cfg.n_moe_layers) == (
-        (0, 1, 2, 4), (3,), (3,), (), (), 4)
+    assert (cfg.layers_of("delta"), cfg.kv_layers, cfg.layers_of("latent"),
+            cfg.layers_of("conv", "window"), cfg.n_moe_layers) == (
+        (0, 1, 2, 4), 1, (3,), (), 4)
     assert cfg.delta == DeltaConfig(n_heads=4, head_dim=16, conv_kernel=4)
     assert cfg.delta.gate_rank == 16
     assert cfg.latent == LatentConfig(kv_rank=24, rope_dim=8, v_dim=12)
@@ -77,7 +78,7 @@ def test_config_is_read_from_the_published_keys(built):
     # the layers' norms and the final one
     held = sum(v.size for v in model["tensors"].values())
     assert family.n_params(hf) == held
-    assert cfg.n_params() == held - (2 * 5 + 1) * 64
+    assert n_params(cfg) == held - (2 * 5 + 1) * 64
 
 
 @pytest.mark.parametrize("key,value", [
@@ -122,7 +123,7 @@ def test_train_step_spans_say_what_ran(built):
                                loss_fn_key="sft")
     capture = tracing.stop()
     after = jax.tree.map(np.asarray, engine.params)
-    for i in cfg.delta_layers:
+    for i in cfg.layers_of("delta"):
         d0, d1 = (p["layers"][str(i)]["delta"] for p in (before, after))
         assert sorted(d0) == sorted(
             "wq wk wv conv_q conv_k conv_v a_log w_fa w_fb dt_bias w_b "
@@ -256,9 +257,8 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
     cfg, params = model["cfg"], model["params"]
     g = GenerationHyperparameters(max_new_tokens=2, greedy=True,
                                   force_no_logits_mask=True)
-    named = (r"layer pattern \(layer_pattern 'd d d l d': 0 conv and 1 "
-             r"attention layers, 0 of those with a window, 1 latent, 4 "
-             r"delta layers that keep a state a head")
+    named = (r"layer pattern \(layer_pattern 'd d d l d': 4 delta layers, "
+             r"1 latent layers")
     with pytest.raises(NotImplementedError, match="slot engine.*" + named):
         inflight.InflightBatchingGenerator(
             cfg, params, g, n_slots=2, max_prompt_len=8,
@@ -291,7 +291,7 @@ def test_the_config_says_what_a_delta_layer_may_be():
     cfg = TransformerConfig(
         **base, layer_pattern=(("delta", "dense"), ("attention", "dense")),
         delta=delta)
-    assert (cfg.delta_layers, cfg.attention_layers) == ((0,), (1,))
+    assert (cfg.layers_of("delta"), cfg.layers_of("attention")) == ((0,), (1,))
     assert delta.width == 32 and delta.conv_kernel == 4
     with pytest.raises(ValueError, match="1 delta layers, delta is None"):
         TransformerConfig(**base, layer_pattern=(

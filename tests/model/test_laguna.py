@@ -39,6 +39,7 @@ from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.parallel import mesh as mesh_lib
+from realhf_tpu.models.operators import n_params
 
 #: max |delta logit| allowed between the program and the reference
 LOGIT_TOL = 1e-5
@@ -136,8 +137,9 @@ def test_config_is_read_from_the_published_keys(model):
         ("attention", "dense"), ("window", "moe"), ("window", "moe"),
         ("window", "moe"), ("attention", "moe"))
     assert cfg.pattern_string == "a w w w a"
-    assert (cfg.attention_layers, cfg.window_layers, cfg.conv_layers,
-            cfg.n_moe_layers) == ((0, 1, 2, 3, 4), (1, 2, 3), (), 4)
+    assert (cfg.layers_of("attention", "window"), cfg.layers_of("window"),
+            cfg.layers_of("conv"), cfg.kv_layers, cfg.n_moe_layers) == (
+        (0, 1, 2, 3, 4), (1, 2, 3), (), 5, 4)
     assert [cfg.layer_window(i) for i in range(5)] == [None, 8, 8, 8, None]
     assert [cfg.q_heads(i) for i in range(5)] == [4, 6, 6, 6, 4]
     assert cfg.attn_output_gate and cfg.qk_norm is None
@@ -167,7 +169,7 @@ def test_config_is_read_from_the_published_keys(model):
     assert n == family.n_params(hf)
     # the program's estimate leaves the layer norms' scales out; it
     # counts heads by layer, the gate and the shared expert
-    assert cfg.n_params() == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
+    assert n_params(cfg) == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
     init = T.init_params(cfg, jax.random.PRNGKey(0))
     assert jax.tree.map(jnp.shape, init) == jax.tree.map(
         jnp.shape, model["params"])
@@ -473,8 +475,8 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
     cfg, params = model["cfg"], model["params"]
     g = GenerationHyperparameters(max_new_tokens=2, greedy=True,
                                   force_no_logits_mask=True)
-    named = (r"layer pattern \(layer_pattern 'a w w w a': 0 conv and 5 "
-             r"attention layers, 3 of those with a window")
+    named = (r"layer pattern \(layer_pattern 'a w w w a': 2 attention "
+             r"layers, 3 window layers")
     with pytest.raises(NotImplementedError, match="slot engine.*" + named):
         inflight.InflightBatchingGenerator(
             cfg, params, g, n_slots=2, max_prompt_len=8,
@@ -491,7 +493,8 @@ def test_one_block_models_refuse_the_per_layer_fields():
     from realhf_tpu.models.config import TransformerConfig
     base = dict(n_layers=2, n_kv_heads=2, n_q_heads=4, hidden_dim=64,
                 intermediate_dim=96, vocab_size=128)
-    assert TransformerConfig(**base, sliding_window=8).window_layers == (0, 1)
+    windowed = TransformerConfig(**base, sliding_window=8)
+    assert [windowed.layer_window(i) for i in range(2)] == [8, 8]
     for extra in (dict(layer_q_heads=(4, 6)), dict(attn_output_gate=True)):
         with pytest.raises(NotImplementedError, match="layer_pattern"):
             TransformerConfig(**base, **extra)

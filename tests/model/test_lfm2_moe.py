@@ -36,6 +36,7 @@ from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.parallel import mesh as mesh_lib
+from realhf_tpu.models.operators import n_params
 
 #: max |delta logit| allowed between the program and the reference
 LOGIT_TOL = 1e-5
@@ -120,8 +121,8 @@ def test_config_is_read_from_the_published_keys(model):
         ("conv", "dense"), ("attention", "moe"), ("conv", "moe"),
         ("conv", "moe"), ("conv", "moe"))
     assert cfg.pattern_string == "c a c c c"
-    assert (cfg.conv_layers, cfg.attention_layers, cfg.n_moe_layers) == (
-        (0, 2, 3, 4), (1,), 4)
+    assert (cfg.layers_of("conv"), cfg.layers_of("attention"), cfg.kv_layers,
+            cfg.n_moe_layers) == ((0, 2, 3, 4), (1,), 1, 4)
     assert cfg.qk_norm == "head" and cfg.mlp_type == "llama"
     assert cfg.tied_embedding and cfg.rotary_base == 1e6
     moe = cfg.moe
@@ -137,7 +138,7 @@ def test_config_is_read_from_the_published_keys(model):
     n = sum(x.size for x in jax.tree.leaves(model["params"]))
     assert n == family.n_params(hf)
     # the program's estimate leaves the layer norms' scales out
-    assert cfg.n_params() == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
+    assert n_params(cfg) == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
     init = T.init_params(cfg, jax.random.PRNGKey(0))
     assert jax.tree.map(jnp.shape, init) == jax.tree.map(
         jnp.shape, model["params"])

@@ -41,6 +41,7 @@ from realhf_tpu.interfaces import sft
 from realhf_tpu.models import hf as hf_models
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import ABSENT, SsmConfig
+from realhf_tpu.models.operators import OPERATORS, Ctx, n_params
 from realhf_tpu.models.hf import registry
 from realhf_tpu.parallel import mesh as mesh_lib
 
@@ -233,7 +234,8 @@ def test_prefill_then_decode_matches_full_forward(model, n_pre):
     empty = T.init_kv_cache(cfg, len(docs), total)
     assert {k: (v.shape, v.dtype) for k, v in empty.items()} == \
         {k: (v.shape, v.dtype) for k, v in cache.items()}
-    assert T.ssm_state_shapes(cfg, len(docs)) == (
+    assert tuple((3, *st.shape(cfg, len(docs), total))
+                 for st in OPERATORS["ssm"].state) == (
         cache["ssm_conv"].shape, cache["ssm"].shape)
     got = [np.asarray(T.lm_logits(cfg, params, hidden))]
     for t in range(n_pre, total):
@@ -427,8 +429,9 @@ def test_mixer_is_zamba2s(built):
         ref = np.asarray(family._mamba(
             d, jnp.asarray(u), {k: jnp.asarray(v) for k, v in w.items()},
             jnp.asarray(family.positions(seg)), jnp.asarray(seg)))
-        got, _ = T._ssm_op(cfg, model["params"]["layers"]["1"]["ssm"],
-                           jnp.asarray(u), jnp.asarray(seg))
+        got, _ = OPERATORS["ssm"].apply(
+            cfg, model["params"]["layers"]["1"], jnp.asarray(u),
+            Ctx({}, seg_ids=jnp.asarray(seg)))
     scale = np.abs(want).max()
     assert scale > 1e-3
     assert np.abs(ref - want).max() < 2e-5 * scale
@@ -491,8 +494,8 @@ def test_the_whole_pattern_parses_at_tiny_widths():
     hf = dict(CONFIGS["whole"], num_hidden_layers=52,
               hybrid_override_pattern=FULL_PATTERN)
     cfg = hf_models.config_from_hf(NAME, hf)
-    assert (len(cfg.ssm_layers), cfg.n_moe_layers,
-            len(cfg.attention_layers)) == (23, 23, 6)
+    assert (len(cfg.layers_of("ssm")), cfg.n_moe_layers,
+            len(cfg.layers_of("attention"))) == (23, 23, 6)
     assert cfg.pattern_string.split() == [
         {"M": "m", "E": "-", "*": "a"}[c] for c in FULL_PATTERN]
     assert all((op == ABSENT) != (ff == ABSENT)
@@ -509,7 +512,7 @@ def test_the_whole_pattern_parses_at_tiny_widths():
         "expert_bias", "router", "shared", "wd", "wu"]
     n_leaves = sum(a.size for a in jax.tree.leaves(params))
     # n_params leaves the 52 + 1 norms' scales out
-    assert cfg.n_params() == n_leaves - 53 * 64 == family.n_params(hf) \
+    assert n_params(cfg) == n_leaves - 53 * 64 == family.n_params(hf) \
         - 53 * 64
     cfg.compute_dtype = "float32"
     ids = jnp.ones((1, 8), jnp.int32)
@@ -533,12 +536,12 @@ def test_the_uncut_model_is_the_published_size():
     assert whole["hybrid_override_pattern"] == FULL_PATTERN
     assert abs(family.n_params(whole) / 31.6e9 - 1) < 0.01
     cfg = hf_models.config_from_hf(NAME, whole)
-    assert abs(cfg.n_params() / 31.6e9 - 1) < 0.01
-    assert (len(cfg.ssm_layers), cfg.n_moe_layers,
-            len(cfg.attention_layers)) == (23, 23, 6)
+    assert abs(n_params(cfg) / 31.6e9 - 1) < 0.01
+    assert (len(cfg.layers_of("ssm")), cfg.n_moe_layers,
+            len(cfg.layers_of("attention"))) == (23, 23, 6)
     cut = hf_models.config_from_hf(NAME, hf)
     assert cut.pattern_string == "- m - m - m a"
-    assert cut.n_params() == 528_093_120 - 8 * 2688
+    assert n_params(cut) == 528_093_120 - 8 * 2688
     assert (cut.ssm.width, cut.ssm.conv_dim, cut.ssm.in_dim) == (
         4096, 6144, 10304)
     assert (cut.moe.num_experts, cut.moe.n_held, cut.moe.top_k,

@@ -36,6 +36,7 @@ from realhf_tpu.models import transformer as T
 from realhf_tpu.models.hf import registry
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel import mesh as mesh_lib
+from realhf_tpu.models.operators import n_params
 
 #: max |delta logit| allowed between the program and the reference
 LOGIT_TOL = 1e-5
@@ -141,7 +142,7 @@ def test_config_is_read_from_the_published_keys(model):
     n = sum(x.size for x in jax.tree.leaves(model["params"]))
     assert n == family.n_params(hf)
     # the program's estimate leaves the layer norms' scales out
-    assert cfg.n_params() == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
+    assert n_params(cfg) == n - (2 * cfg.n_layers + 1) * cfg.hidden_dim
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))

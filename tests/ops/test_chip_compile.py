@@ -972,11 +972,12 @@ def test_decode_loop_touches_the_stacked_cache_in_place(
 
     import numpy as np
 
+    from realhf_tpu.models import operators
     from realhf_tpu.models import transformer as T
     from realhf_tpu.models.config import TransformerConfig
     from realhf_tpu.parallel.mesh import DATA_AXIS
 
-    monkeypatch.setattr(T, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(operators, "pallas_enabled", lambda: True)
     cfg = TransformerConfig(
         n_layers=2, n_kv_heads=nkv, n_q_heads=nq, hidden_dim=hidden,
         head_dim=hd, intermediate_dim=1024, vocab_size=1024,
