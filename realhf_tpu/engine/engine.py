@@ -575,7 +575,13 @@ class Engine:
         ``attn_proj_remat_products`` (``obs.parts.count_products``:
         the attention projections' products the backward runs a second
         time; k's and v's where the blocks keep what the two wide ones
-        made, 4 a layer where they keep nothing), of a
+        made, 4 a layer where they keep nothing) and
+        ``head_remat_products`` (the same count under part
+        ``vocab_head``: 1, the loop body's, where the backward makes a
+        chunk's logits a second time, as every loss over
+        ``shifted_logprobs_from_hidden`` does; 0 where the head
+        computes its gradient in the chunk that has the logits,
+        ``ops/functional.py:weighted_logprob_sum``: SFT), of a
         generate program :meth:`_decode_facts`, and of every program
         of a model in the ragged dispatch mode ``moe_products``,
         ``moe_gmm_calls``, ``moe_ragged_dot_calls``
@@ -607,7 +613,9 @@ class Engine:
                     return dict(
                         flash_fwd_per_bwd=flash_fwd_per_bwd(text),
                         attn_proj_remat_products=parts.count_products(
-                            text, parts.ATTN_PROJ, parts.REMAT))
+                            text, parts.ATTN_PROJ, parts.REMAT),
+                        head_remat_products=parts.count_products(
+                            text, parts.VOCAB_HEAD, parts.REMAT))
             else:
                 mine = None
             ragged = moe_ops.dispatch_mode(self.cfg) == "ragged"
@@ -674,8 +682,8 @@ class Engine:
         inside set-up), and set on the ``engine:*`` span that has just
         ended its fingerprint and what else came of the text
         (``decode_kernel``, ``decode_layer_copies``,
-        ``flash_fwd_per_bwd``, ``attn_proj_remat_products``), as on
-        every later span of it."""
+        ``flash_fwd_per_bwd``, ``attn_proj_remat_products``,
+        ``head_remat_products``), as on every later span of it."""
         facts = self._program_facts(name, key)
         self._unread.pop(getattr(self._last_span, "span_id", None), None)
         self._last_span.set_attribute("program_fingerprint",

@@ -41,11 +41,16 @@ def _make_loss_fn(cfg):
         return _make_looped_loss_fn(cfg)
 
     def loss_fn(params, h, mb):
-        lp = F.shifted_logprobs_from_hidden(
-            cfg, params, h, mb["input_ids"], mb["seg_ids"])
         mask = _answer_mask(mb)
         denom = jnp.maximum(mask.sum(), 1)
-        nll = -(lp * mask).sum() / denom
+        # the loss is a weighted sum of log-probabilities, its weights
+        # known before the head runs: the head computes its gradient
+        # where it has the logits. The weights carry the sign, so the
+        # sum IS the loss and its cotangent is the constant 1: the
+        # compiler drops the head's scalings (-1 costs a pass over dW)
+        nll, _ = F.weighted_logprob_sum(
+            cfg, params, h, mb["input_ids"], mb["seg_ids"],
+            -(mask / denom))
         return nll, {"nll": nll, "n_tokens": denom.astype(jnp.float32)}
 
     return loss_fn
@@ -72,20 +77,26 @@ def _make_looped_loss_fn(cfg):
     beta = cfg.exit_entropy_coeff
 
     def loss_fn(params, states, mb):
-        nll = -F.passes_logprobs_from_hidden(
-            cfg, params, states.hidden, mb["input_ids"], mb["seg_ids"])
         log_p = F.exit_log_distribution(states.gate)  # [T, S, L]
         with jax.named_scope(parts.EXIT):
             mask = _answer_mask(mb)
             denom = jnp.maximum(mask.sum(), 1)
-
+            p = jnp.exp(log_p)
+        # sum_t mean(p_t nll_t): the weights are known before the head
+        # runs (the gate reads the states), so every pass's head
+        # computes its gradient where it has the logits; the gate is
+        # reached through the weights, the statistics read ``lp``. The
+        # weights carry the sign, as in the plain loss
+        weighted_nll, lp = F.weighted_logprob_sum(
+            cfg, params, states.hidden, mb["input_ids"], mb["seg_ids"],
+            -p * mask / denom)
+        with jax.named_scope(parts.EXIT):
             def mean(x):  # [..., S, L] -> [...] over the answer tokens
                 return (x * mask).sum((-2, -1)) / denom
 
-            p = jnp.exp(log_p)
             entropy = mean(-(p * log_p).sum(0))
-            p_mean, nll_mean = mean(p), mean(nll)
-            loss = mean((p * nll).sum(0)) - beta * entropy
+            p_mean, nll_mean = mean(p), mean(-lp)
+            loss = weighted_nll - beta * entropy
             passes = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
             stats = {"nll": nll_mean[-1],
                      "n_tokens": denom.astype(jnp.float32),

@@ -106,6 +106,12 @@ SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,),
              SSM: (SCAN,), INDEX: INDEX_STEPS, LAYERS: (LOOP,)}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
+#: scope of gradient arithmetic that runs in a FORWARD rule (the head
+#: of a weighted sum forms ``dlogits`` and runs its two gradient
+#: products in the chunk that has the logits,
+#: ``ops/functional.py:weighted_logprob_sum``): pass ``bwd``, whatever
+#: the path says of transposition
+GRADIENT = "gradient"
 #: the pass of an operation whose ``op_name`` the compiler wrote
 UNKNOWN = "?"
 #: opcodes that hold the device's operation line for communication
@@ -189,7 +195,8 @@ def classify(op_name: str) -> Tuple[Optional[str], str, Optional[str]]:
     them and there is one: ``experts/route``, ``attn_proj/latent``);
     None where no part claims the operation. ``pass``: ``remat`` in a
     rematerialised forward, else
-    ``bwd`` where the path holds a ``transpose(``, else ``fwd``; ``?``
+    ``bwd`` where the path holds a ``transpose(`` or the scope
+    :data:`GRADIENT`, else ``fwd``; ``?``
     for an ``op_name`` of :data:`COMPILER_MADE`.
     ``phase``: the outermost component of :data:`PHASES`, or None."""
     if op_name in COMPILER_MADE:  # the path, and the pass with it, is lost
@@ -209,7 +216,7 @@ def classify(op_name: str) -> Tuple[Optional[str], str, Optional[str]]:
             phase = comp
     if _REMAT_MARK in comps:
         pass_ = REMAT
-    elif "transpose(" in op_name:
+    elif "transpose(" in op_name or GRADIENT in comps:
         pass_ = BWD
     else:
         pass_ = FWD
@@ -249,13 +256,15 @@ def parse_program(text: str) -> Dict[str, Tuple]:
     buffer jax fills a conditional's unused residuals with) takes the
     part and phase of the first operation that uses it, through tuples
     and out of a branch into its conditional, at most
-    :data:`FEEDS_HOPS` steps away. (What lies in a phase and in no
-    part stays so: the draw of ``sample`` is not the next step's
-    embedding lookup.)"""
+    :data:`FEEDS_HOPS` steps away; where no user has a part either (a
+    copy out of fast memory into a loop's carry: its user is the
+    body's tuple), those of the first operation it was made FROM, as
+    far back. (What lies in a phase and in no part stays so: the draw
+    of ``sample`` is not the next step's embedding lookup.)"""
     rows, inner, computation = [], set(), None
     inside = {}  # computation -> the last op_name among its lines
     known = {}   # instruction -> (part, pass, phase), operations or not
-    users = collections.defaultdict(list)
+    users, makers = collections.defaultdict(list), {}
     roots, callers = {}, collections.defaultdict(list)
     for line in text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -280,7 +289,8 @@ def parse_program(text: str) -> Dict[str, Tuple]:
         if line.lstrip().startswith("ROOT "):
             roots[computation] = name
         head = line.partition(" = ")[2].partition(", metadata=")[0]
-        for operand in _OPERAND.findall(head):
+        makers[name] = _OPERAND.findall(head)
+        for operand in makers[name]:
             users[operand].append(name)
         rows.append((computation, name, opcode, op_name,
                      () if op_name else calls))
@@ -295,21 +305,23 @@ def parse_program(text: str) -> Dict[str, Tuple]:
             ops.setdefault(name, known[name] + (
                 opcode, "/".join(op_name.split("/")[-2:])[-TAIL:]))
 
-    def fed(name, hops):
-        """(part, phase) of the first user of ``name`` that has a
-        part, breadth first."""
+    def near(name, towards):
+        """(part, phase) of the first operation ``towards`` (the users
+        or the makers of) ``name`` that has a part, breadth first."""
         level = [name]
-        for _ in range(hops):
-            level = [u for n in level for u in users.get(n, ())]
-            for user in level:
-                if known.get(user, (None,))[0] is not None:
-                    return known[user][0], known[user][2]
+        for _ in range(FEEDS_HOPS):
+            level = [u for n in level for u in towards.get(n, ())]
+            for other in level:
+                if known.get(other, (None,))[0] is not None:
+                    return known[other][0], known[other][2]
         return None, None
 
     out = {}
     for name, (part, pass_, phase, opcode, tail) in ops.items():
         if part is None and phase is None:  # no name of ours at all
-            part, phase = fed(name, FEEDS_HOPS)
+            part, phase = near(name, users)
+            if part is None:
+                part, phase = near(name, makers)
         out[name] = (part, pass_, opcode, phase, tail)
     return out
 

@@ -9,7 +9,8 @@ log-sum-exp and the label's select under GSPMD shard the vocab dim and
 XLA inserts the reductions (all-reduces of one number a position).
 """
 
-from typing import Optional
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,29 @@ def _shift_and_sum_exp(logits):
     whole (its operations in its order, the max held constant)."""
     z = logits - jax.lax.stop_gradient(logits.max(-1, keepdims=True))
     return z, jnp.exp(z).sum(-1)
+
+
+def _label_logprob(z, s_exp, lc):
+    """Where the labels ``lc`` [S, C] stand in ``z`` [S, C, V] (bool)
+    and their log-probabilities [S, C]. The label's entry by a select,
+    not a gather: a gather makes the compiler write the whole
+    log-softmax for it to read, and its transpose scatters a one-hot of
+    the chunk's size and copies it into the logits' layout."""
+    hit = (jax.lax.broadcasted_iota(lc.dtype, z.shape, 2)
+           == lc[..., None])
+    return hit, jnp.where(hit, z, 0.0).sum(-1) - jnp.log(s_exp)
+
+
+def _next_tokens(input_ids, seg_ids):
+    """Position t's label (token t+1) and whether it has one: t+1 lies
+    in the same document and is no padding. Both [S, L]."""
+    s = input_ids.shape[0]
+    labels = jnp.concatenate(
+        [input_ids[:, 1:], jnp.zeros((s, 1), input_ids.dtype)], axis=1)
+    valid = jnp.concatenate(
+        [(seg_ids[:, 1:] == seg_ids[:, :-1]) & (seg_ids[:, 1:] != 0),
+         jnp.zeros((s, 1), bool)], axis=1)
+    return labels, valid
 
 
 def shifted_logprobs_from_hidden(
@@ -80,11 +104,7 @@ def _shifted_logprobs(cfg, params, hidden, input_ids, seg_ids, chunk,
     s, l, h = hidden.shape
     w = head_weight(cfg, params).astype(hidden.dtype)
 
-    labels = jnp.concatenate(
-        [input_ids[:, 1:], jnp.zeros((s, 1), input_ids.dtype)], axis=1)
-    valid = jnp.concatenate(
-        [(seg_ids[:, 1:] == seg_ids[:, :-1]) & (seg_ids[:, 1:] != 0),
-         jnp.zeros((s, 1), bool)], axis=1)
+    labels, valid = _next_tokens(input_ids, seg_ids)
 
     n_chunks = max(1, (l + chunk - 1) // chunk)
     pad_l = n_chunks * chunk - l
@@ -113,34 +133,125 @@ def _shifted_logprobs(cfg, params, hidden, input_ids, seg_ids, chunk,
         if mc is not None:
             logits = jnp.where(mc, logits, -1e30)
         z, s_exp = _shift_and_sum_exp(logits)
-        # the label's entry by a select, not a gather: a gather makes
-        # the compiler write the whole log-softmax for it to read, and
-        # its transpose scatters a one-hot of the chunk's size and
-        # copies it into the logits' layout
-        hit = (jax.lax.broadcasted_iota(lc.dtype, z.shape, 2)
-               == lc[..., None])
-        return None, jnp.where(hit, z, 0.0).sum(-1) - jnp.log(s_exp)
+        return None, _label_logprob(z, s_exp, lc)[1]
 
     _, lp = jax.lax.scan(jax.checkpoint(body), None, xs)
     lp = lp.swapaxes(0, 1).reshape(s, n_chunks * chunk)[:, :l]
     return jnp.where(valid, lp, 0.0)
 
 
-def passes_logprobs_from_hidden(cfg: TransformerConfig, params,
-                                hidden: jnp.ndarray,  # [T, S, L, H]
-                                input_ids: jnp.ndarray,
-                                seg_ids: jnp.ndarray, *, chunk: int = 1024,
-                                temperature: float = 1.0) -> jnp.ndarray:
-    """:func:`shifted_logprobs_from_hidden` of every pass of a looped
-    model: [T, S, L] float32. The chunked head runs once a pass, one
-    pass after the other (a scan), so what is alive at a time is ONE
-    chunk's logits of ONE pass, never T logit arrays; the head's
-    gradient is the sum over T x chunks bodies, added in the weight's
-    dtype as a scan's transpose does."""
-    return jax.lax.map(
-        lambda h: shifted_logprobs_from_hidden(
-            cfg, params, h, input_ids, seg_ids, chunk=chunk,
-            temperature=temperature), hidden)
+def weighted_logprob_sum(
+    cfg: TransformerConfig,
+    params,
+    hidden: jnp.ndarray,      # [S, L, H], or [T, S, L, H]: T passes' states
+    input_ids: jnp.ndarray,   # [S, L]
+    seg_ids: jnp.ndarray,     # [S, L]
+    c: jnp.ndarray,           # [S, L] or [T, S, L] float32 weights
+    *,
+    chunk: int = 1024,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``sum_i c_i lp_i`` (a float32 scalar) and, as an output that
+    carries no gradient, ``lp`` itself, float32 in ``c``'s shape: what
+    :func:`shifted_logprobs_from_hidden` returns (of every pass of a
+    looped model, where ``hidden`` and ``c`` have a leading axis of
+    passes).
+
+    The head of a loss that IS a weighted sum of log-probabilities,
+    its weights known before the head runs (SFT: ``mask / denom``; a
+    looped model's: times the exit distribution). Its cotangent
+    ``c (onehot - softmax)`` is then known in the chunk that has the
+    logits, so the forward rule of the ``custom_vjp`` forms it there
+    and runs the two gradient products at once: three products a chunk
+    where :func:`shifted_logprobs_from_hidden` under ``jax.grad`` runs
+    four (forward, the rematerialised forward, two transposed), and a
+    chunk's float32 logits are still never kept. The chunks of all
+    passes go through ONE scan, one pass after the other: what is alive
+    at a time is ONE chunk's logits of ONE pass, and the head's
+    gradient is summed over T x chunks bodies in the weight's dtype, as
+    that function's scan transposes sum it. A loss that needs ``lp``
+    itself (a clipped ratio, a KL term, a sequence's log-sigmoid)
+    calls that function. A call nobody differentiates runs no gradient
+    product."""
+    with jax.named_scope(HEAD_SCOPE):
+        w = head_weight(cfg, params).astype(hidden.dtype)
+        labels, valid = _next_tokens(input_ids, seg_ids)
+        lead, (s, l) = hidden.shape[:-3], hidden.shape[-3:-1]
+        n_chunks = max(1, (l + chunk - 1) // chunk)
+
+        def chunks(x):  # lead + [S, L, ...] -> [passes * n_chunks, S, chunk, ...]
+            x = x.reshape((-1,) + x.shape[len(lead):])
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, n_chunks * chunk - l))
+                        + ((0, 0),) * (x.ndim - 3))
+            # (reshape, then swap the rows behind the chunks, as
+            # _shifted_logprobs does: XLA:CPU at optimization level 0,
+            # the tests' flag, miscompiles [P, S, n, C] -> [P, n, S, C]
+            # of a padded bf16 row)
+            x = x.swapaxes(0, 1)  # [S, passes, L, ...]
+            return x.reshape((s, -1, chunk) + x.shape[3:]).swapaxes(0, 1)
+
+        total, lp = _weighted_sum(
+            cfg, w, chunks(hidden), chunks(jnp.where(valid, c, 0.0)),
+            chunks(jnp.broadcast_to(labels, lead + labels.shape)))
+        lp = lp.swapaxes(0, 1).reshape((s, -1, n_chunks * chunk))
+        lp = lp.swapaxes(0, 1)[..., :l].reshape(lead + (s, l))
+        return total, jax.lax.stop_gradient(jnp.where(valid, lp, 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_sum(cfg, w, hidden_c, c_c, labels_c):
+    """``(sum(c_c * lp), lp [N, S, C])`` over chunks ``hidden_c`` [N, S,
+    C, H]. The primal: today's forward, no gradient product."""
+    def body(_, x):
+        hc, lc = x
+        z, s_exp = _shift_and_sum_exp(_chunk_logits(cfg, w, hc, 1.0))
+        return None, _label_logprob(z, s_exp, lc)[1]
+
+    _, lp = jax.lax.scan(body, None, (hidden_c, labels_c))
+    return (c_c * lp).sum(), lp
+
+
+def _weighted_sum_fwd(cfg, w, hidden_c, c_c, labels_c):
+    """The chunk that has the logits forms ``dlogits`` and runs both
+    gradient products: ``dh`` a chunk is the scan's output, ``dW`` its
+    carry. Operands as the transposed program of
+    :func:`_shifted_logprobs` has them (read off its compiled text):
+    float32 ``dlogits`` against the bf16 weight and states, float32
+    out of the product, then the operand's dtype."""
+    def body(dw, x):
+        hc, cc, lc = x
+        z, s_exp = _shift_and_sum_exp(_chunk_logits(cfg, w, hc, 1.0))
+        hit, lp = _label_logprob(z, s_exp, lc)
+        with jax.named_scope(parts.GRADIENT):
+            dlogits = (jnp.where(hit, cc[..., None], 0.0)
+                       - (cc / s_exp)[..., None] * jnp.exp(z))
+            if dlogits.shape[-1] != w.shape[-1]:  # tp-padded vocab
+                dlogits = jnp.pad(dlogits, ((0, 0), (0, 0), (
+                    0, w.shape[-1] - dlogits.shape[-1])))
+            dh = jax.lax.dot_general(
+                dlogits, w, (((2,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(hc.dtype)
+            dw_c = jax.lax.dot_general(
+                hc, dlogits, (((0, 1), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32).astype(w.dtype)
+        return dw + dw_c, (lp, dh)
+
+    dw, (lp, dh) = jax.lax.scan(body, jnp.zeros_like(w),
+                                (hidden_c, c_c, labels_c))
+    return ((c_c * lp).sum(), lp), (dw, dh, lp)
+
+
+def _weighted_sum_bwd(cfg, residuals, cotangents):
+    """Three scalings by the sum's cotangent ``g``; ``lp``'s own is
+    dropped, the output carries no gradient. The SFT losses hand in
+    weights that carry the loss's sign, so their ``g`` is the constant
+    1 and the compiler drops the scalings (``dW``'s is a pass over the
+    whole matrix)."""
+    g = cotangents[0]
+    dw, dh, lp = ((g * x).astype(x.dtype) for x in residuals)
+    return dw, dh, lp, None
+
+
+_weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
 
 
 def exit_log_distribution(gate_logits: jnp.ndarray) -> jnp.ndarray:

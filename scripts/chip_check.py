@@ -1267,8 +1267,12 @@ def main():
                         "nothing else: no packed row, no decode, no "
                         "long document")
     p.add_argument("--only", nargs="+", default=None,
-                   help="kimi_linear: after the first seed's engine "
-                        "reading, these of PUBLISHED_PHASES alone")
+                   help="after the first seed's engine reading, "
+                        "these rows alone: of PUBLISHED_PHASES for a "
+                        "family with a published decay (kimi_linear, "
+                        "nemotron_h), else of packed, decode, "
+                        "slow_path, uncovered_rows, selection, exact, "
+                        "objective")
     p.add_argument("--against", default=None,
                    help="kimi_linear's row scan: another checkout of "
                         "this repo (the parent's, unpacked under a "
@@ -1322,31 +1326,40 @@ def main():
                 lens = spec["packed_docs"]
                 if args.rehearse and lens:  # toy rows: an eighth
                     lens = tuple(n // 8 for n in lens)
-            if i == 0 and not args.only and not args.table_only:
-                packed(cell, engine, tensors, list(ids) if lens is None
-                       else [rng.integers(0, vocab, n) for n in lens], ckpt)
-                if spec["decode"] is None:
+            def asked(row):
+                return not args.only or row in args.only
+
+            # (a family with a published decay spends --only below)
+            if i == 0 and not args.table_only and not (
+                    args.only and spec.get("published_decay")):
+                if asked("packed"):
+                    packed(cell, engine, tensors, list(ids) if lens is None
+                           else [rng.integers(0, vocab, n) for n in lens],
+                           ckpt)
+                if asked("decode") and spec["decode"] is None:
                     decode(cell, engine, ids, want,
                            n_pre=ids.shape[1] * 3 // 4)
-                else:
+                elif asked("decode"):
                     b, n, n_pre = spec["decode"]
                     if args.rehearse:
                         n, n_pre = n // 8, n_pre // 8
                     long = generate.fixed_batch(cell["hf"], seed + 3, b, n)
                     decode(cell, engine, long, cell["family"].logprobs(
                         cell["hf"], tensors, long), n_pre=n_pre)
-                if spec.get("slow_path"):
+                if spec.get("slow_path") and asked("slow_path"):
                     slow_path(cell, ckpt, ids, want, engine, seed)
-                if spec.get("uncovered_rows"):
+                if spec.get("uncovered_rows") and asked("uncovered_rows"):
                     uncovered_rows(cell, engine, seed)
-                if spec.get("exact_doc"):
+                if spec.get("exact_doc") and (asked("exact")
+                                              or asked("selection")):
                     n = spec["exact_doc"] // (8 if args.rehearse else 1)
                     long = rng.integers(0, vocab, n)
-                    if spec.get("selection"):
+                    if spec.get("selection") and asked("selection"):
                         selection_live(cell, engine, tensors, long)
                     engine = None  # the bf16 weights go before float32's come
-                    exact(cell, ckpt, tensors, long)
-                if spec.get("objective"):
+                    if asked("exact"):
+                        exact(cell, ckpt, tensors, long)
+                if spec.get("objective") and asked("objective"):
                     engine = None
                     objective(cell, ckpt, tensors, seed,
                               spec["objective"] // (8 if args.rehearse

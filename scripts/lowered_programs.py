@@ -13,7 +13,10 @@ The SAME file (this one) runs against both trees: it imports
 ``realhf_tpu`` and ``benchmark`` from the tree given first. At the
 cells' real widths, with abstract parameters: one microbatch's SFT
 forward and backward (``T.forward``, the interface's head and loss, a
-sparse model's auxiliary terms) and the whole ``generate`` program;
+sparse model's auxiliary terms), of the two GRPO families also the
+gradient of a weighted sum over ``shifted_logprobs_from_hidden`` (the
+head of every loss that needs the log-probabilities themselves) and
+the whole ``generate`` program;
 and the engine's own ``train`` and ``logprobs`` programs
 (``Engine._train_step_body``: accumulation, optimizer, statistics) at
 the tests' tiny widths, where ``Engine`` can hold real arrays. Equal
@@ -85,6 +88,11 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
         return loss + moe_ops.aux_loss(aux), {**stats, **aux}
     dump(f"{cfgname}.train_grad", lambda p, mb: jax.value_and_grad(objective, has_aux=True)(p, mb), params, mb)
     if fam in ("qwen2", "mistral"):
+        # the head a loss over the log-probabilities THEMSELVES runs (GRPO, PPO, DPO: a clipped ratio, a KL term), under any weights and a temperature
+        from realhf_tpu.ops import functional as F
+        def lp_objective(p, mb, w):
+            return (w * F.shifted_logprobs_from_hidden(cfg, p, T.forward(cfg, p, mb["input_ids"], mb["seg_ids"])[0], mb["input_ids"], mb["seg_ids"], temperature=0.7)).sum()
+        dump(f"{cfgname}.logprobs_grad", jax.value_and_grad(lp_objective), params, mb, sds((1, L), jnp.float32))
         g = GenerationHyperparameters(max_new_tokens=256, min_new_tokens=256, greedy=False, force_no_logits_mask=True)
         b = 128 if fam == "qwen2" else 32
         dump(f"{cfgname}.generate",

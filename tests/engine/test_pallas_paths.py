@@ -21,6 +21,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from realhf_tpu.models import transformer as T
 from realhf_tpu.models.config import TransformerConfig
+from realhf_tpu.obs import parts
 from realhf_tpu.parallel.mesh import ParallelismConfig, make_mesh
 
 # Capability detect: interpret-mode coverage of the Pallas decode
@@ -417,6 +418,48 @@ def test_train_span_counts_the_projections_run_a_second_time(
     assert [attrs["attn_proj_remat_products"] for attrs in on] \
         == [products] * 2
     assert all("flash_fwd_per_bwd" not in attrs for attrs in on)
+
+
+@pytest.mark.parametrize("program,loss,products", [
+    ("train", "sft", 0), ("train", "grpo", 1), ("train_seq", "sft", 0)])
+def test_train_span_counts_the_heads_logits_made_a_second_time(
+        program, loss, products, monkeypatch, interpreted_kernels,
+        xla_path_spans):
+    """``head_remat_products`` beside it, from the same read: the
+    products under part ``vocab_head`` in the rematerialised forward.
+    The ``op_name``s are the chip's compiled texts' own
+    (``tests/ops/test_chip_compile.py`` reads them off the real
+    programs): a loss over ``shifted_logprobs_from_hidden`` (GRPO's)
+    makes a chunk's logits again in its backward, 1, the loop body's;
+    the SFT head (``weighted_logprob_sum``) runs its two gradient
+    products in the chunk that has the logits, scope ``gradient``: 0,
+    which is said too."""
+    fb = "jit(train_step)/jit(main)/forward_backward"
+    body = "while/body/closed_call"
+    names = {
+        "sft": [f"{fb}/jvp(vocab_head)/{body}/slh,hv->slv/dot_general",
+                f"{fb}/jvp(vocab_head)/{body}/gradient/dot_general",
+                f"{fb}/jvp(vocab_head)/{body}/gradient/dot_general"],
+        "grpo": [f"{fb}/jvp(vocab_head)/{body}/slh,hv->slv/dot_general",
+                 f"{fb}/transpose(jvp(vocab_head))/{body}/checkpoint/"
+                 "rematted_computation/slh,hv->slv/dot_general",
+                 f"{fb}/transpose(jvp(vocab_head))/{body}/checkpoint/"
+                 "slh,hv->slv/dot_general",
+                 f"{fb}/transpose(jvp(vocab_head))/{body}/checkpoint/"
+                 "slh,hv->slv/dot_general"]}[loss]
+    text = "%body (q: f32[8,8]) -> f32[8,8] {\n" + "".join(
+        f"  %convolution.{i} = f32[8,8]{{1,0}} convolution(%a, %b), "
+        f"dim_labels=bf_io->bf, metadata={{op_name=\"{name}\"}}\n"
+        for i, name in enumerate(names)) + "}\n"
+    assert [parts.classify(name)[:2] for name in names] == [
+        ("vocab_head", "fwd")] + {
+            "sft": [("vocab_head", "bwd")] * 2,
+            "grpo": [("vocab_head", "remat")] + [("vocab_head", "bwd")] * 2
+        }[loss]
+    off, on = _train_spans(program, text, monkeypatch,
+                           interpreted_kernels, xla_path_spans)
+    assert all("head_remat_products" not in attrs for attrs in off)
+    assert [attrs["head_remat_products"] for attrs in on] == [products] * 2
 
 
 def test_train_span_says_which_grouped_matmul_the_experts_run(

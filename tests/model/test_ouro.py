@@ -298,6 +298,86 @@ def test_objective_and_gradients_match_reference(built, remat, passes):
         assert stats["exit_entropy"] > 0.5
 
 
+def _looped_loss_over_every_logprob(cfg):
+    """``interfaces/sft.py:_make_looped_loss_fn`` as it stood until PR
+    61, kept here as the reference: every pass's log-probabilities by
+    ``shifted_logprobs_from_hidden`` (a pass at a time), and the
+    objective written over them, so that ``jax.grad`` reaches the head
+    through the rematerialised chunks and the gate through ``p``."""
+    from realhf_tpu.obs import parts
+    beta = cfg.exit_entropy_coeff
+
+    def loss_fn(params, states, mb):
+        nll = -jax.lax.map(
+            lambda h: F.shifted_logprobs_from_hidden(
+                cfg, params, h, mb["input_ids"], mb["seg_ids"]),
+            states.hidden)
+        log_p = F.exit_log_distribution(states.gate)  # [T, S, L]
+        with jax.named_scope(parts.EXIT):
+            mask = sft._answer_mask(mb)
+            denom = jnp.maximum(mask.sum(), 1)
+
+            def mean(x):  # [..., S, L] -> [...] over the answer tokens
+                return (x * mask).sum((-2, -1)) / denom
+
+            p = jnp.exp(log_p)
+            entropy = mean(-(p * log_p).sum(0))
+            p_mean, nll_mean = mean(p), mean(nll)
+            loss = mean((p * nll).sum(0)) - beta * entropy
+            passes = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+            stats = {"nll": nll_mean[-1],
+                     "n_tokens": denom.astype(jnp.float32),
+                     "expected_exit_pass": (passes * p_mean).sum(),
+                     "exit_entropy": entropy}
+            for t in range(p.shape[0]):
+                stats[f"exit_p{t + 1}"] = p_mean[t]
+                stats[f"nll_pass{t + 1}"] = nll_mean[t]
+        return loss, stats
+
+    loss_fn.every_pass = True
+    return loss_fn
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_heads_own_gradient_leaves_the_objective_as_it_was(
+        built, monkeypatch, dtype):
+    """The looped objective over ``weighted_logprob_sum`` (every pass's
+    head computes its gradient where it has the logits, the gate is
+    reached through the weights ``p mask / denom``) against the
+    objective over every pass's log-probabilities, at T = 4,
+    rematerialised: the loss, every statistic and the gradient of every
+    leaf, the head's, the layers' and the exit gate's among them. In
+    float32 to 2e-6 of a tensor's largest entry (read: 6e-7); in bf16
+    both are one program's roundings in two orders, a norm apart of
+    under 1% a tensor (read: 0.36%; the reference's float32 gradient is
+    1 to 2% from either: ``BF16_GRADIENT_BOUND``)."""
+    model = built(4)
+    got, _ = _objective_case(model, True, dtype=dtype)
+    monkeypatch.setattr(sft, "_make_loss_fn",
+                        _looped_loss_over_every_logprob)
+    want, _ = _objective_case(model, True, dtype=dtype)
+    (loss, stats, grads), (loss0, stats0, grads0) = got, want
+    exact = dtype == "float32"
+    # (the head's sums run in float32 in either dtype)
+    assert abs(loss - loss0) < 2e-6 * abs(loss0)
+    assert set(stats) == set(stats0) and len(stats) == 4 + 2 * 4
+    for name in stats0:
+        assert abs(stats[name] - stats0[name]) \
+            <= 2e-6 * abs(stats0[name]), name
+    assert set(grads) == set(grads0)
+    assert {"lm_head.weight", "model.early_exit_gate.weight",
+            "model.early_exit_gate.bias",
+            "model.layers.0.mlp.down_proj.weight"} <= set(grads)
+    for name in sorted(grads0):
+        assert np.abs(grads0[name]).max() > 0, name
+        if exact:
+            assert np.abs(grads[name] - grads0[name]).max() \
+                <= 2e-6 * np.abs(grads0[name]).max(), name
+        else:
+            assert np.linalg.norm(grads[name] - grads0[name]) \
+                <= 0.01 * np.linalg.norm(grads0[name]), name
+
+
 #: How far a bf16 engine's gradient may be from the float32 reference's,
 #: as ``|g - ref|_2 / |ref|_2`` over a tensor. A shared weight's
 #: gradient is the SUM of four passes' gradients, which the carried

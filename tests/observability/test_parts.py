@@ -44,6 +44,19 @@ def line(name, opcode="fusion", op_name=None, shape="bf16[4096,896]{1,0}",
      "dot_general", ("vocab_head", "fwd", "forward_backward")),
     (f"{FB}/jvp()/experts/shared_expert/dot_general",
      ("shared_expert", "fwd", "forward_backward")),
+    # the head of a weighted sum (ops/functional.py:
+    # weighted_logprob_sum): its forward rule makes the logits (fwd)
+    # and runs the two gradient products, scope "gradient" (bwd); its
+    # backward rule's scalings are traced under the call's scope
+    (f"{FB}/jvp(loss)/vocab_head/while/body/closed_call/slh,hv->slv/"
+     "dot_general", ("vocab_head", "fwd", "forward_backward")),
+    (f"{FB}/jvp(loss)/vocab_head/while/body/closed_call/gradient/"
+     "dot_general", ("vocab_head", "bwd", "forward_backward")),
+    (f"{FB}/transpose(jvp(loss))/vocab_head/mul",
+     ("vocab_head", "bwd", "forward_backward")),
+    # (a primitive of a like name is no scope)
+    (f"{FB}/jvp(loss)/vocab_head/stop_gradient",
+     ("vocab_head", "fwd", "forward_backward")),
     # the experts' steps
     (f"{FB}/jvp()/experts/route/top_k",
      ("experts/route", "fwd", "forward_backward")),
@@ -151,7 +164,16 @@ PROGRAM = "\n".join([
     line("rs-done.1", "reduce-scatter-done",
          f"{FB}/transpose(jvp(loss))/vocab_head/dot_general"),
     line("copy.5", "copy"),
-    "  ROOT " + line("tuple.2", "tuple").strip(),
+    # a copy out of fast memory into the loop's carry: its one user is
+    # the body's tuple, so it takes the part of what it was made FROM
+    # (the head's dW, which a chunk's fusion leaves in fast memory)
+    line("fusion.11", "fusion", f"{FB}/jvp(loss)/vocab_head/while/body/"
+         "gradient/dot_general",
+         extra=", kind=kOutput, calls=%fused_computation.1"),
+    "  %copy-start.8 = (bf16[8]{0}, bf16[8]{0:S(1)}, u32[]) "
+    "copy-start(%fusion.11)",
+    "  %copy-done.8 = bf16[8]{0} copy-done(%copy-start.8)",
+    "  ROOT %tuple.2 = (s32[], bf16[8]{0}) tuple(%p.1, %copy-done.8)",
     "}",
     "",
     "ENTRY %main.9 (a: bf16[8]) -> bf16[8] {",
@@ -176,8 +198,9 @@ def test_parse_program_keeps_what_the_device_runs():
     # a while body's operations by their own names; nothing of a fused
     # computation, of an applied scalar function, or of the plumbing
     assert sorted(ops) == [
-        "broadcast.4", "cond.6", "copy-done.3", "copy-start.3", "copy.5",
-        "flash_fwd.3", "fusion.7", "fusion.8", "fusion.9", "gather.1",
+        "broadcast.4", "cond.6", "copy-done.3", "copy-done.8",
+        "copy-start.3", "copy-start.8", "copy.5", "flash_fwd.3",
+        "fusion.11", "fusion.7", "fusion.8", "fusion.9", "gather.1",
         "ragged-dot-none.2", "reduce.4", "rs-done.1", "rs-start.1",
         "sort.1", "while.1"]
     assert ops["fusion.7"][:4] == ("attn_proj", "fwd", "fusion",
@@ -199,6 +222,10 @@ def test_parse_program_keeps_what_the_device_runs():
     assert ops["copy-done.3"][:4] == ("attn_proj", "fwd", "copy-done",
                                       "forward_backward")
     assert ops["copy-start.3"][0] == "attn_proj"  # two steps away
+    assert ops["fusion.11"][:2] == ("vocab_head", "bwd")
+    assert ops["copy-done.8"][:4] == ("vocab_head", "fwd", "copy-done",
+                                      "forward_backward")
+    assert ops["copy-start.8"][0] == "vocab_head"
     assert ops["broadcast.4"][:4] == ("experts", "fwd", "broadcast",
                                       "forward_backward")
     assert ops["cond.6"][:3] == ("experts", "fwd", "conditional")

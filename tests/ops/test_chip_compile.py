@@ -501,7 +501,11 @@ def test_moonlights_whole_train_step_compiles(one_chip):
     assert moe_ops.grouped_product_calls(compiled.as_text()) == dict(
         moe_products="gmm", moe_gmm_calls=4 * 2 * 12,
         moe_ragged_dot_calls=0)
+    # the SFT head makes a chunk's logits once (head_remat_products)
+    assert _head_products(compiled.as_text())["remat"] == 0
     memory = compiled.memory_analysis()
+    print("moonlight whole step GB", (memory.argument_size_in_bytes
+                                      + memory.temp_size_in_bytes) / 1e9)
     assert 13.0e9 < (memory.argument_size_in_bytes
                      + memory.temp_size_in_bytes) < 13.6e9
 
@@ -664,6 +668,8 @@ def test_kimis_whole_train_step_compiles(one_chip):
     assert delta_rule.scan_kernel_calls(text) == 4 * 2
     assert delta_rule.scan_handed(text) == len(delta_rule.RESIDUAL_NAMES)
     memory = compiled.memory_analysis()
+    print("kimi whole step GB", (memory.argument_size_in_bytes
+                                 + memory.temp_size_in_bytes) / 1e9)
     assert 12.8e9 < (memory.argument_size_in_bytes
                      + memory.temp_size_in_bytes) < 13.58e9
 
@@ -907,17 +913,11 @@ def _float32_arrays_made(text, elems):
     return products, others
 
 
-def test_head_makes_no_chunk_of_logits_but_its_products(one_chip):
-    """Loss and gradients over ``shifted_logprobs_from_hidden`` at
-    Qwen2.5-0.5B's head (896 x 151,936, tied, one row of 4096, chunks
-    of 1024): the label is picked by a select, so the program holds no
-    scatter, and outside fusion bodies only the forward's product and
-    the rematerialised one make a chunk's float32 logits (622 MB). With
-    ``log_softmax`` + ``take_along_axis`` (before PR 32) the forward
-    wrote the whole log-softmax for a gather to read, and the backward
-    a broadcast of zeros, a scatter into them and a relayout copy."""
+def _qwens_head(one_chip, loss):
+    """The compiled text of ``jax.value_and_grad(loss(cfg, chunk))``
+    with respect to the weight and the states at Qwen2.5-0.5B's head
+    (896 x 151,936, tied, one row of 4096, chunks of 1024)."""
     from realhf_tpu.models.config import TransformerConfig
-    from realhf_tpu.ops.functional import shifted_logprobs_from_hidden
 
     hidden, vocab, chunk = 896, 151936, 1024
     cfg = TransformerConfig(
@@ -929,20 +929,76 @@ def test_head_makes_no_chunk_of_logits_but_its_products(one_chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def loss(params, h, ids, seg):
-        lp = shifted_logprobs_from_hidden(cfg, params, h, ids, seg,
-                                          chunk=chunk)
-        return -lp.sum() / ROW_LEN
-
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+    compiled = jax.jit(jax.value_and_grad(
+        loss(cfg, chunk), argnums=(0, 1))).lower(
         {"embed": {"wte": sds((vocab, hidden), jnp.bfloat16)}},
         sds((1, ROW_LEN, hidden), jnp.bfloat16),
         sds((1, ROW_LEN), jnp.int32), sds((1, ROW_LEN), jnp.int32)).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), chunk * vocab
+
+
+def _head_products(text):
+    """The head's matrix products by pass, as the engine's
+    ``head_remat_products`` counts them."""
+    from realhf_tpu.obs import parts
+    return {p: parts.count_products(text, parts.VOCAB_HEAD, p)
+            for p in (parts.FWD, parts.REMAT, parts.BWD)}
+
+
+def test_head_makes_no_chunk_of_logits_but_its_products(one_chip):
+    """Loss and gradients over ``shifted_logprobs_from_hidden`` at
+    Qwen2.5-0.5B's head: the label is picked by a select, so the
+    program holds no scatter, and outside fusion bodies only the
+    forward's product and the rematerialised one make a chunk's
+    float32 logits (622 MB): four products a chunk, one of them in
+    pass ``remat`` (the engine's ``head_remat_products`` 1, as GRPO's
+    train program reads). With ``log_softmax`` + ``take_along_axis``
+    (before PR 32) the forward wrote the whole log-softmax for a gather
+    to read, and the backward a broadcast of zeros, a scatter into them
+    and a relayout copy."""
+    from realhf_tpu.ops.functional import shifted_logprobs_from_hidden
+
+    def loss(cfg, chunk):
+        return lambda params, h, ids, seg: -shifted_logprobs_from_hidden(
+            cfg, params, h, ids, seg, chunk=chunk).sum() / ROW_LEN
+
+    text, logits = _qwens_head(one_chip, loss)
     assert "scatter" not in text
-    products, others = _float32_arrays_made(text, chunk * vocab)
+    products, others = _float32_arrays_made(text, logits)
     assert others == []
     assert len(products) == 2
+    assert _head_products(text) == {"fwd": 1, "remat": 1, "bwd": 2}
+
+
+def test_weighted_head_makes_a_chunk_of_logits_once(one_chip):
+    """The same head under ``weighted_logprob_sum``, as the SFT losses
+    call it (weights ``1 / 4096`` a position): no scatter, and outside
+    fusion bodies ONE product makes a chunk's float32 logits: three
+    products a chunk, none in pass ``remat`` (``head_remat_products``
+    0), the two gradient products, which the forward rule runs, in pass
+    ``bwd`` (scope ``gradient``); and what the backward rule does, three
+    scalings traced apart from the call, lies under the head's scope
+    too, so ``train.unscoped_s`` does not grow."""
+    import re
+
+    from realhf_tpu.obs import parts
+    from realhf_tpu.ops.functional import weighted_logprob_sum
+
+    def loss(cfg, chunk):
+        return lambda params, h, ids, seg: -weighted_logprob_sum(
+            cfg, params, h, ids, seg,
+            jnp.full(ids.shape, 1 / ROW_LEN, jnp.float32), chunk=chunk)[0]
+
+    text, logits = _qwens_head(one_chip, loss)
+    assert "scatter" not in text
+    products, others = _float32_arrays_made(text, logits)
+    assert others == []
+    assert len(products) == 1
+    assert _head_products(text) == {"fwd": 1, "remat": 0, "bwd": 2}
+    transposed = [name for name in re.findall(r'op_name="([^"]*)"', text)
+                  if "transpose(" in name]
+    assert transposed and all(
+        parts.classify(name)[0] == parts.VOCAB_HEAD for name in transposed)
 
 
 def test_decode_stacked_compiles(one_chip):
@@ -1166,6 +1222,8 @@ def test_ouros_whole_train_step_compiles(one_chip):
     by_part = {part for part, *_ in ops.values()}
     assert {"layers", "layers/loop", "exit", "attn", "attn_proj", "mlp",
             "vocab_head", "loss", "grad_accum", "optimizer"} <= by_part
+    # four passes' heads, each chunk's logits made once
+    assert _head_products(text) == {"fwd": 1, "remat": 0, "bwd": 2}
     memory = compiled.memory_analysis()
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print("ouro whole step GB", total / 1e9)

@@ -296,6 +296,150 @@ def test_head_select_traces_no_gather_or_scatter():
     assert "select_n" in text
 
 
+# ----------------------------------------------------------------------
+# The head of a loss that IS a weighted sum of log-probabilities
+# computes its gradient where it has the logits: held to jax.grad of
+# sum(c * shifted_logprobs_from_hidden(...))
+# ----------------------------------------------------------------------
+def _weighted_case(tied, padded, dtype, passes):
+    """``_head_case`` on a packed row with three segment boundaries and
+    a padded tail (no chunk of 8 divides its 21 tokens), weights of
+    both signs, and ``passes`` states a token where a number is
+    given."""
+    cfg, params, hidden, ids, _, _ = _head_case(tied, padded, dtype, False)
+    seg = jnp.asarray(np.concatenate(
+        [np.full((2, n), d) for d, n in ((1, 5), (2, 5), (3, 4), (4, 4),
+                                         (0, 3))], 1), jnp.int32)
+    rng = np.random.default_rng(7)
+    lead = () if passes is None else (passes,)
+    hidden = jnp.asarray(rng.standard_normal(lead + hidden.shape), dtype)
+    c = jnp.asarray(rng.standard_normal(lead + ids.shape), jnp.float32)
+    return cfg, params, hidden, ids, seg, c
+
+
+@pytest.mark.parametrize("cotangent", [1.0, -0.37])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("padded", [False, True], ids=["exact", "tp_padded"])
+@pytest.mark.parametrize("passes", [None, 3], ids=["one_pass", "passes"])
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_weighted_logprob_sum_matches_grad_of_the_logprobs(
+        tied, passes, padded, dtype, cotangent):
+    """Value, ``lp`` and the gradients with respect to the head's
+    weight, the states and the weights ``c`` against ``jax.grad`` of
+    ``sum(c * shifted_logprobs_from_hidden(...))`` (a pass at a time
+    where there are several), under an incoming cotangent of 1 and of
+    another number; and the primal, called without a gradient. In
+    float32 to 1e-6 of a tensor's largest entry; in bf16 the tolerance
+    of ``test_head_select_matches_gather``."""
+    cfg, params, hidden, ids, seg, c = _weighted_case(
+        tied, padded, dtype, passes)
+
+    def reference(params, hidden, c):
+        one = lambda h: F.shifted_logprobs_from_hidden(
+            cfg, params, h, ids, seg, chunk=8)
+        lp = one(hidden) if passes is None else jax.lax.map(one, hidden)
+        return cotangent * (c * lp).sum(), lp
+
+    def weighted(params, hidden, c):
+        total, lp = F.weighted_logprob_sum(cfg, params, hidden, ids, seg,
+                                           c, chunk=8)
+        return cotangent * total, lp
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(
+            fn, argnums=(0, 1, 2), has_aux=True))(params, hidden, c)
+
+    (value, lp), (gp, gh, gc) = run(weighted)
+    (value0, lp0), (gp0, gh0, gc0) = run(reference)
+    assert lp.dtype == jnp.float32 and lp.shape == c.shape
+    assert gh.dtype == hidden.dtype and gc.dtype == jnp.float32
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+
+    def close(got, want, exact=False):
+        got, want = f32(got), f32(want)
+        assert got.shape == want.shape
+        if dtype == "float32" or exact:
+            np.testing.assert_allclose(
+                got, want, rtol=0, atol=1e-6 * np.abs(want).max())
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+    close(lp, lp0, exact=True)
+    assert (f32(lp)[..., np.asarray(seg) == 0] == 0).all()
+    assert (f32(lp)[..., [4, 9, 13, 17]] == 0).all()  # a document's last
+    close(value, value0, exact=True)
+    close(gc, gc0, exact=True)   # lp itself, times the cotangent
+    close(gh, gh0)
+    head = (lambda p: p["embed"]["wte"]) if tied else (lambda p: p["head"]["w"])
+    gw = head(gp)
+    assert gw.dtype == head(params).dtype
+    close(gw, head(gp0))
+    if padded:  # the padded columns take no gradient
+        pad = f32(gw)[HEAD_V:] if tied else f32(gw)[:, HEAD_V:]
+        assert (pad == 0).all()
+    # nobody differentiates this call: today's forward
+    total, lp1 = jax.jit(lambda: F.weighted_logprob_sum(
+        cfg, params, hidden, ids, seg, c, chunk=8))()
+    close(cotangent * total, value0, exact=True)
+    close(lp1, lp0, exact=True)
+
+
+def test_weighted_logprob_sum_runs_three_products_a_chunk():
+    """The differentiated program holds three matrix products, all in
+    the ONE scan over the chunks, and neither a gather nor a scatter
+    (the label's entry and its cotangent are selects); ``jax.grad`` of
+    the log-probabilities holds four (forward, the rematerialised
+    forward, two transposed); the call nobody differentiates holds the
+    forward's alone; and ``lp`` carries no gradient."""
+    cfg, params, hidden, ids, seg, c = _weighted_case(
+        True, False, "float32", None)
+
+    def weighted(p, h):
+        return F.weighted_logprob_sum(cfg, p, h, ids, seg, c, chunk=8)
+
+    grad = str(jax.make_jaxpr(jax.grad(
+        lambda p, h: weighted(p, h)[0], argnums=(0, 1)))(params, hidden))
+    assert "gather" not in grad and "scatter" not in grad
+    assert "select_n" in grad
+    assert grad.count("dot_general") == 3 and grad.count("scan[") == 1
+    old = str(jax.make_jaxpr(jax.grad(
+        lambda p, h: (c * F.shifted_logprobs_from_hidden(
+            cfg, p, h, ids, seg, chunk=8)).sum(), argnums=(0, 1)))(
+                params, hidden))
+    assert old.count("dot_general") == 4
+    primal = str(jax.make_jaxpr(weighted)(params, hidden))
+    assert primal.count("dot_general") == 1
+    through_lp = jax.grad(lambda h: weighted(params, h)[1].sum())(hidden)
+    assert not np.asarray(through_lp).any()
+
+
+def test_weighted_logprob_sum_over_a_sharded_vocabulary():
+    """On a mesh of two devices with the head's vocabulary axis sharded
+    (and tp-padded): value, ``lp`` and gradients as on one device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    cfg, params, hidden, ids, seg, c = _weighted_case(
+        False, True, "float32", None)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
+    sharded = dict(params, head={"w": jax.device_put(
+        params["head"]["w"], NamedSharding(mesh, P(None, "model")))})
+
+    def run(params):
+        def loss(p, h, c):
+            return F.weighted_logprob_sum(cfg, p, h, ids, seg, c, chunk=8)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(params, hidden, c)
+
+    (value, lp), grads = run(sharded)
+    (value0, lp0), grads0 = run(params)
+    assert len(grads[0]["head"]["w"].sharding.device_set) == 2
+    np.testing.assert_allclose(value, value0, rtol=1e-6)
+    np.testing.assert_allclose(lp, lp0, rtol=1e-6, atol=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grads0)):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-6 * np.abs(want).max() + 1e-12)
+
+
 def test_entropy_matches_log_softmax_form():
     cfg, params, hidden, *_ = _head_case(True, True, "float32", False)
     ent = F.entropy_from_hidden(cfg, params, hidden, chunk=8,
