@@ -50,7 +50,7 @@ from realhf_tpu.ops.decode_attention import (
 from realhf_tpu.ops.delta_rule import scan_handed, scan_kernel_calls
 from realhf_tpu.ops.ssm_scan import scan_kernel_calls as ssm_kernel_calls
 from realhf_tpu.ops.flash_attention import (block_counts, flash_fwd_per_bwd,
-                                            flash_mask_calls)
+                                            flash_mask_calls, row_streams)
 from realhf_tpu.ops.sampling import GenerationHyperparameters
 from realhf_tpu.parallel.mesh import MeshContext
 from realhf_tpu.parallel.realloc import offload_to_host, tree_bytes
@@ -273,6 +273,9 @@ class Engine:
                 if cfg.moe.shared_intermediate_dim is not None:
                     self._model_attrs.update(
                         shared_expert=cfg.moe.shared_intermediate_dim)
+                if cfg.moe.router_input != "ffn_input":
+                    self._model_attrs.update(
+                        router_input=cfg.moe.router_input)
             if mode is not None and not cfg.gated_mlp:
                 self._model_attrs.update(
                     expert_ff=f"{cfg.activation_function}/ungated")
@@ -281,6 +284,11 @@ class Engine:
                     str(cfg.q_heads(i))
                     for i in cfg.layers_of(*ATTENTION_OPERATORS)))
             if cfg.rotary_by_operator is not None:
+                nope = [op for op, rc in cfg.rotary_by_operator.items()
+                        if rc is None]
+                if nope:  # layers that rotate nothing
+                    self._model_attrs.update(
+                        nope_layers=len(cfg.layers_of(*nope)))
                 self._model_attrs.update(rotary=" ".join(
                     f"{op[0]}:{'none' if rc is None else rc.describe()}"
                     for op, rc in sorted(
@@ -289,7 +297,8 @@ class Engine:
             self._model_attrs.update(
                 passes=cfg.n_passes, kv_layers=cfg.kv_layers,
                 post_norm=cfg.post_norm, exit_gate=cfg.exit_gate)
-        if mode == "dense" and cfg.moe.num_experts > 4:
+        if mode == "dense" and cfg.moe.num_experts > 4 \
+                and cfg.moe.experts_held is None:
             logger.warning(
                 "MoE model running in dense dispatch (capacity_factor "
                 "unset, grouped GEMM disabled): every expert processes "
@@ -459,13 +468,19 @@ class Engine:
             for kind, pairs in zip(counts, block_counts(
                     seg_ids, sliding_window=window)):
                 counts[kind] += n * pairs
+        role = str(self.ctx.model_name.role)
         for kind, n in counts.items():
-            metrics.inc("flash_kv_blocks_total", n,
-                        role=str(self.ctx.model_name.role), kind=kind)
+            metrics.inc("flash_kv_blocks_total", n, role=role, kind=kind)
+        # rows past what the kernels hold whole go to those that stream
+        # K and V by block (flash_*_stream): the same ranges, fetched
+        stream_rows = int(np.prod(seg_ids.shape[:-1])) \
+            if row_streams(seg_ids.shape[-1]) else 0
+        metrics.inc("flash_stream_rows_total", stream_rows, role=role)
         return dict(
             flash_block_share=counts["visited"] / counts["causal"],
             flash_unmasked_share=counts["unmasked"] / max(
-                counts["visited"], 1))
+                counts["visited"], 1),
+            flash_stream_rows=stream_rows)
 
     def _count_routed_pairs(self, seg_ids, decode_tokens: int = 0):
         """``moe_routed_pairs_total{role,dispatch}``: the (token,

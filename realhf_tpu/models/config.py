@@ -257,6 +257,13 @@ class MoEConfig:
     # divided by (their sum + 1e-6) under ``norm_topk_prob`` and
     # multiplied by ``routed_scaling_factor`` (LFM2-MoE).
     score_fn: str = "softmax"
+    # What the router's product reads. "ffn_input": what the experts
+    # read, the normed residual after the layer's operator.
+    # "layer_input": the layer's INPUT, before its first norm and its
+    # operator (SmallThinker's pre-attention router: ``z = x W_r``,
+    # so that an expert's weights can be fetched while attention
+    # runs); the experts still read the normed residual after it.
+    router_input: str = "ffn_input"
     use_expert_bias: bool = False
     routed_scaling_factor: float = 1.0
     # what the sigmoid router adds to the k scores' sum before it
@@ -298,6 +305,9 @@ class MoEConfig:
     def __post_init__(self):
         if self.score_fn not in ("softmax", "sigmoid"):
             raise NotImplementedError(f"score_fn={self.score_fn!r}")
+        if self.router_input not in ("ffn_input", "layer_input"):
+            raise NotImplementedError(
+                f"router_input={self.router_input!r}")
         if self.experts_held is not None:
             first, count = self.experts_held
             self.experts_held = (int(first), int(count))
@@ -335,7 +345,7 @@ class TransformerConfig:
     resid_pdrop: float = 0.0
     attn_pdrop: float = 0.0
     layer_norm_epsilon: float = 1e-5
-    activation_function: str = "gelu"  # gelu | gelu_new | silu | relu2
+    activation_function: str = "gelu"  # gelu | gelu_new | silu | relu | relu2
     scale_attn_by_inverse_layer_idx: bool = False
     scale_attn_weights: bool = True
     use_attention_bias: bool = True
@@ -504,6 +514,15 @@ class TransformerConfig:
                 "layer_q_heads, rotary_by_operator, attn_output_gate, "
                 "latent, delta, indexer and ssm belong to a model with "
                 "a layer_pattern")
+        if self.moe is not None \
+                and self.moe.router_input == "layer_input" \
+                and self.layer_pattern is None:
+            raise NotImplementedError(
+                "a router that reads the layer's input (MoEConfig."
+                "router_input='layer_input') belongs to a model with a "
+                "layer_pattern: the pipeline's stages and the slot "
+                "engine's step (engine/inflight.py) hand a "
+                "feed-forward its own input alone")
         if self.n_passes < 1:
             raise ValueError(f"n_passes={self.n_passes}")
         if (self.n_passes > 1 or self.post_norm or self.exit_gate) and (

@@ -16,6 +16,7 @@ import realhf_tpu.models.hf.kimi_linear  # noqa: F401
 import realhf_tpu.models.hf.keye_vl2  # noqa: F401
 import realhf_tpu.models.hf.nemotron_h  # noqa: F401
 import realhf_tpu.models.hf.ouro  # noqa: F401
+import realhf_tpu.models.hf.smallthinker  # noqa: F401
 
 from realhf_tpu.models.hf.registry import (  # noqa: F401
     HF_FAMILIES,

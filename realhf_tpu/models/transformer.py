@@ -242,22 +242,26 @@ def _init_pattern_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 # Building blocks
 # ----------------------------------------------------------------------
 def _mlp(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
-         moe_constraint=None, sparse: Optional[bool] = None
-         ) -> jnp.ndarray:
-    out, _ = _mlp_with_aux(cfg, lp, x, None, moe_constraint, sparse)
+         moe_constraint=None, sparse: Optional[bool] = None,
+         layer_input: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    out, _ = _mlp_with_aux(cfg, lp, x, None, moe_constraint, sparse,
+                           layer_input)
     return out
 
 
 def _mlp_with_aux(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
                   seg_ids: Optional[jnp.ndarray] = None,
-                  moe_constraint=None, sparse: Optional[bool] = None):
+                  moe_constraint=None, sparse: Optional[bool] = None,
+                  layer_input: Optional[jnp.ndarray] = None):
     """MLP returning (output, aux dict) -- non-empty only for MoE
     (router load-balancing / z losses, reference utils/moe.py:395,
     and the statistics of ``ops.moe.STATS``).
     ``seg_ids`` masks padding out of MoE routing/capacity/losses.
     ``sparse``: whether THIS layer's feed-forward is the mixture of
     experts; a patterned model says it a layer, a model of one block
-    by ``mlp_type``."""
+    by ``mlp_type``. ``layer_input``: what the layer took in, before
+    its first norm and its operator, which a router reads where
+    ``MoEConfig.router_input`` says so (the experts read ``x``)."""
     cdt = jnp.dtype(cfg.compute_dtype)
     m = lp["mlp"]
     if sparse is None:
@@ -267,8 +271,13 @@ def _mlp_with_aux(cfg: TransformerConfig, lp: Params, x: jnp.ndarray,
             squeeze = x.ndim == 2  # decode step: [B, H]
             x3 = x[:, None, :] if squeeze else x
             valid = None if seg_ids is None else (seg_ids != 0)
+            route_on = None
+            if cfg.moe.router_input == "layer_input":
+                route_on = layer_input[:, None, :] if squeeze \
+                    else layer_input
             out, aux = moe_mlp_with_losses(cfg, m, x3, valid_mask=valid,
-                                           ep_constraint=moe_constraint)
+                                           ep_constraint=moe_constraint,
+                                           route_on=route_on)
             return (out[:, 0] if squeeze else out), aux
     with jax.named_scope(P.MLP):
         return _dense_mlp(cfg, m, x, cdt), {}
@@ -297,7 +306,7 @@ def _block(cfg: TransformerConfig, lp: Params, x: jnp.ndarray, ctx: Ctx,
     part's norm and one residual add."""
     op, sparse = ("attention", None) if kind is None \
         else (kind[0], kind[1] == "moe")
-    state = ()
+    state, layer_input = (), x
     if op != ABSENT:
         # the norm before an operator and the residual's add after it
         # go with the operator's projections, those around the
@@ -314,7 +323,7 @@ def _block(cfg: TransformerConfig, lp: Params, x: jnp.ndarray, ctx: Ctx,
     with jax.named_scope(ff):
         ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
     mlp_out, aux = _mlp_with_aux(cfg, lp, ln2, ctx.seg_ids, moe_constraint,
-                                 sparse)
+                                 sparse, layer_input)
     with jax.named_scope(ff):
         x = constrain(x + _post_norm(cfg, lp, "ln2_post", mlp_out))
     return x, state, aux
@@ -1052,16 +1061,18 @@ def decode_step(
                                     ctx._replace(l=l))
         return _ff_step(x, lp, None), k_all, v_all
 
-    def _ff_step(x, lp, sparse):
+    def _ff_step(x, lp, sparse, layer_input=None):
         # the norm, the feed-forward and the residual's add, one part
-        # (a layer that is a mixer alone holds no "mlp" and has none)
+        # (a layer that is a mixer alone holds no "mlp" and has none);
+        # ``layer_input``: the token before the layer's operator, which
+        # a router may read (``MoEConfig.router_input``)
         if "mlp" not in lp:
             return x
         with jax.named_scope(_ff_part(cfg, sparse)):
             ln2 = _norm(cfg, x, lp["ln2"]["scale"], lp["ln2"].get("bias"))
             return x + _post_norm(cfg, lp, "ln2_post",
                                   _mlp(cfg, lp, ln2, moe_constraint,
-                                       sparse))
+                                       sparse, layer_input))
 
     k_all, v_all = cache["k"], cache["v"]
     # what the layers keep beside K and V: a state that grows with the
@@ -1080,6 +1091,7 @@ def decode_step(
         at, n_kv = collections.Counter(), 0  # an operator's layers so far
         for i, (op, ff) in enumerate(cfg.layer_pattern):
             lp, rec = params["layers"][str(i)], OPERATORS[op]
+            layer_input = x
             if rec.step is not None:
                 mine = [st.key for st in rec.state]
                 with jax.named_scope(rec.scope):  # (its slices are its)
@@ -1097,7 +1109,7 @@ def decode_step(
                     else:
                         fresh[key].append(new)
                 at[op] += 1
-            x = _ff_step(x, lp, ff == "moe")
+            x = _ff_step(x, lp, ff == "moe", layer_input)
     elif cfg.n_passes > 1:
         # a looped model: every pass over the same weights, each with
         # ITS rows of the K/V stack (pass t layer l at t x n_layers +

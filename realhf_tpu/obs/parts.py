@@ -76,8 +76,16 @@ PREFILL, DECODE, SAMPLE = "prefill", "decode", "sample"
 PHASES = (FORWARD_BACKWARD, PREFILL, DECODE, SAMPLE)
 
 ROUTE, GATHER, PRODUCTS, COMBINE = "route", "gather", "products", "combine"
+#: the router's product ALONE, where it reads the layer's input and
+#: so runs before the layer's operator (``MoEConfig.router_input``:
+#: ``ops/moe.py``); elsewhere it is part of ``experts/route``
+ROUTER = "router"
 #: sub-scopes of ``experts``: the part then reads ``experts/route``
-EXPERT_STEPS = (ROUTE, GATHER, PRODUCTS, COMBINE)
+EXPERT_STEPS = (ROUTE, GATHER, PRODUCTS, COMBINE, ROUTER)
+#: sub-scope of ``attn``: the flash kernels that stream their blocks
+#: (a row past ``ops/flash_attention.py:FLASH_MAX_LEN``) and what the
+#: program does around them; the part then reads ``attn/stream``
+STREAM = "stream"
 #: sub-scope of ``attn_proj`` in a latent layer: what makes keys and
 #: values from the compressed row (its projection, the norm, the
 #: expansion a head, the shared rotary key's rotation and broadcast);
@@ -102,8 +110,9 @@ PROJECT, SCORES, SELECT = "project", "scores", "select"
 #: (``index/select``; ``ops/sparse_index.py``)
 INDEX_STEPS = (PROJECT, SCORES, SELECT)
 #: part -> the sub-scopes that may stand inside it
-SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), DELTA: (SCAN,),
-             SSM: (SCAN,), INDEX: INDEX_STEPS, LAYERS: (LOOP,)}
+SUB_STEPS = {EXPERTS: EXPERT_STEPS, ATTN_PROJ: (LATENT,), ATTN: (STREAM,),
+             DELTA: (SCAN,), SSM: (SCAN,), INDEX: INDEX_STEPS,
+             LAYERS: (LOOP,)}
 
 FWD, REMAT, BWD = "fwd", "remat", "bwd"
 #: scope of gradient arithmetic that runs in a FORWARD rule (the head

@@ -104,7 +104,8 @@ CAPTURE_COUNTERS = ("realloc_bytes_total", "realloc_puts_total",
                     "moe_held_pairs_total", "moe_share_overflow_total",
                     "sparse_pairs_total", "index_tokens_total",
                     "index_blocks_total", "engine_stage_secs_total",
-                    "engine_cache_total", "loop_token_passes_total")
+                    "engine_cache_total", "loop_token_passes_total",
+                    "flash_stream_rows_total")
 #: gauges whose last values a capture reports, where they were
 #: written while it ran
 CAPTURE_GAUGES = ("moe_load_max_over_mean",

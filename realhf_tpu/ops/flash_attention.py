@@ -78,10 +78,11 @@ first row's oldest visible key, ``first row - (W - 1)``, and a key
 block ends no later than the block of the last query that sees its
 last column, ``last column + (W - 1)``; the kernels' masks add ``row
 - col < W``. With ``sliding_window=None`` ranges, masks and programs
-are what they are without this paragraph. The window takes nothing off
-VMEM: K and V are still whole a head, so ``FLASH_MAX_LEN`` stands; it
-takes blocks off the loops (a row of 4096 at W = 512 visits 30 of its
-72 causal block pairs).
+are what they are without this paragraph. In a row of
+``FLASH_MAX_LEN`` or less the window takes nothing off VMEM (K and V
+are whole a head there); it takes blocks off the loops (a row of 4096
+at W = 512 visits 30 of its 72 causal block pairs). In a longer row
+the same ranges say which blocks are FETCHED (below).
 
 A learned selection (``select=`` int8 ``[B, L, L]``, 0 = not attended:
 a sparse layer's, ``ops/sparse_index.py``) is ONE MORE blocked operand
@@ -99,13 +100,42 @@ skipping it is ROADMAP R4c (b)). A kernel that takes one is named
 has a ``custom_vjp`` of its own; a call without one is, to the byte of
 its jaxpr, the call it was.
 
-What is still whole in VMEM. K and V (forward, dq) and Q, dO, lse,
-delta (dkv) are kept whole per (batch, head) whatever the ranges say,
-which bounds L: ``FLASH_MAX_LEN`` below is what the v5e compiler
-accepts for forward AND backward at the head sizes of the supported
-families; ``flash_attention`` raises above it. Longer rows need more
-microbatches (shorter packed rows) or a context-parallel mesh (ring
-attention); streaming KV by DMA is future work.
+What is resident in VMEM. In a row of ``FLASH_MAX_LEN`` tokens or
+less, K and V (forward, dq) and Q, dO, lse, delta (dkv) are whole per
+(batch, head) whatever the ranges say: one DMA a head, and the loops
+above slice them. That bounds L: ``FLASH_MAX_LEN`` is what the v5e
+compiler accepts for forward AND backward at the head sizes of the
+supported families. A LONGER row goes to three kernels of their own,
+``flash_fwd_stream`` / ``flash_bwd_dq_stream`` /
+``flash_bwd_dkv_stream``, whose VMEM does not grow with L: the loop
+over a block's visited pairs is the LAST GRID AXIS there, and Mosaic's
+pipeline fetches one block of the streamed side a grid step, double
+buffered, while the step before it computes. Forward and dq hold one
+block of Q (dq: and its dO, lse, delta, and the float32 dq it
+accumulates) of ``STREAM_HEADS`` query heads that share ONE key/value
+head, and step over the key blocks ``[kv_lo, kv_hi)`` of the query
+block, the block index taken from the prefetched range in the index
+map; the dkv pass holds one block of K and V and its float32 dK and
+dV and steps over the query blocks ``[q_lo, q_hi)`` of those heads.
+The axis is as long as the longest range any block of a row of ONE
+document has (every packed row's ranges lie inside those: 32 key
+blocks of 512 in a full layer at 16,384, 9 under a window of 4096);
+a step past a block's own ``hi`` repeats the last block's index, so
+nothing is fetched, and computes nothing. A fetched block of K and V
+serves all the query heads of the step (7 of SmallThinker's 28 over 4:
+one fetch of 262 KB against 7 x 67 MFLOP in the forward, seven times
+the chip's ridge), and the pair's mask is built once for them; the
+running maximum, sum and accumulator of the heads live in VMEM scratch
+between steps. The dkv pass computes TRANSPOSED scores, ``K Q^T``
+[BK, BQ]: lse and delta are then rows, read as they are kept, one
+float32 a (row, head), and no product contracts over its operands'
+first axes. What bounds L there is ``FLASH_STREAM_MAX_LEN``, the
+longest row the compile test asks the v5e compiler for; a selection
+(``select=``) or a key wider than its value past ``FLASH_MAX_LEN``
+raises by name, as does a row past either bound: a row the compiler
+would refuse never drops to the O(L^2) XLA path in silence. Longer
+rows still need more microbatches or a context-parallel mesh (ring
+attention).
 
 Mosaic requires the last two dims of every block to be (8, 128)-tile
 aligned, so 1D row metadata rides wider layouts: q-side segment ids
@@ -125,20 +155,36 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from realhf_tpu.obs import parts as P
 from realhf_tpu.ops.hlo_text import device_instructions
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
-#: Longest packed row the kernel takes. Asked of the v5e compiler
-#: (libtpu 0.0.34, bf16, tests/ops/test_chip_compile.py): the backward
-#: compiles to L = 5120 at (14 q, 2 kv, hd 64) and to 6144 at
-#: (32, 8, 128) and runs out of VMEM one kilotoken above either; the
-#: forward alone compiles to 8192 and is refused at 16384. A sliding
-#: window changes none of this (K and V stay whole a head in VMEM);
-#: the windowed backward at (64, 8, 128) x 4096 compiles too, as does
-#: the backward at a key's width of 192 and a value's of 128 (16, 16
-#: heads) x 4096.
+#: Longest packed row whose K and V (and, in the dkv pass, Q, dO, lse
+#: and delta) the kernels hold WHOLE a head in VMEM; a longer row takes
+#: the ``*_stream`` kernels, which hold blocks. Asked of the v5e
+#: compiler (libtpu 0.0.34, bf16, tests/ops/test_chip_compile.py): the
+#: whole-row backward compiles to L = 5120 at (14 q, 2 kv, hd 64) and
+#: to 6144 at (32, 8, 128) and runs out of VMEM one kilotoken above
+#: either; the whole-row forward alone compiles to 8192 and is refused
+#: at 16384, with or without a window; the windowed backward at
+#: (64, 8, 128) x 4096 compiles too, as does the backward at a key's
+#: width of 192 and a value's of 128 (16, 16 heads) x 4096.
 FLASH_MAX_LEN = 4096
+#: Longest packed row the ``*_stream`` kernels take: their VMEM does
+#: not grow with L (the compile test asks the v5e compiler for (28 q, 4
+#: kv, 128) x 16,384 and x 32,768, forward and backward, with and
+#: without a window of 4096); what grows is the grid and the
+#: prefetched ranges in SMEM, four int32 a block
+FLASH_STREAM_MAX_LEN = 32768
+#: what the name of a kernel that streams its blocks ends in
+STREAM_SUFFIX = "_stream"
+#: most query heads (of ONE key/value head) a grid step of a stream
+#: kernel serves from one fetched block of K and V: the largest divisor
+#: of the group up to this (7 of 28 over 4, 8 of 64 over 8, 1 where
+#: every head has keys of its own). Their blocks and scratch are what
+#: the kernels hold: 8 MB at 7 heads of 128 in bf16
+STREAM_HEADS = 8
 NEG_INF = -2.0 ** 30
 LANES = 128
 SUBLANES = 8
@@ -440,13 +486,19 @@ def _vmem_limit(in_specs, out_specs, out_shape, args):
     values. None where they fit the default, which is every shape the
     kernels had before a row of 4096 at heads of 128: such a call's
     program is untouched."""
+    held = _held_twice(in_specs, out_specs, out_shape, args)
+    return None if held <= DEFAULT_SCOPED_VMEM else int(held * 1.25)
+
+
+def _held_twice(in_specs, out_specs, out_shape, args) -> int:
+    """Bytes of a call's blocks, each held twice (Mosaic's pipeline
+    double-buffers every operand)."""
     outs = jax.tree.leaves(out_shape)
     specs = list(in_specs) + list(jax.tree.leaves(
         out_specs, is_leaf=lambda x: isinstance(x, pl.BlockSpec)))
     dtypes = [a.dtype for a in args] + [o.dtype for o in outs]
-    held = 2 * sum(int(np.prod(spec.block_shape)) * np.dtype(dt).itemsize
+    return 2 * sum(int(np.prod(spec.block_shape)) * np.dtype(dt).itemsize
                    for spec, dt in zip(specs, dtypes))
-    return None if held <= DEFAULT_SCOPED_VMEM else int(held * 1.25)
 
 
 def _ranged_call(kernel, name, grid, bounds, in_specs, out_specs,
@@ -475,6 +527,9 @@ def _flash_fwd(q, k, v, seg_ids, scale, causal, bq, bk, window=None,
     selection ``[B, L, L]`` int8, one more blocked operand (the rows of
     the step's query block, every column)."""
     b, l, nq, hd = q.shape
+    if row_streams(l):
+        return _flash_fwd_stream(q, k, v, seg_ids, scale, causal, bq, bk,
+                                 window)
     nkv, hv = k.shape[2], v.shape[3]
     group = nq // nkv
     bq, bk = _blocks(l, bq, bk)
@@ -626,6 +681,8 @@ def _bwd_dkv_kernel(q_lo_ref, q_hi_ref, full_lo_ref, full_hi_ref,
 
 def _flash_bwd(res, g, scale, causal, bq, bk, window=None, select=None):
     q, k, v, seg_ids, ot, lse = res
+    if row_streams(q.shape[1]):
+        return _flash_bwd_stream(res, g, scale, causal, bq, bk, window)
     do = g
     b, l, nq, hd = q.shape
     hv = v.shape[3]
@@ -731,6 +788,421 @@ def flash_mask_calls(hlo_text: str) -> int:
 
 
 # ----------------------------------------------------------------------
+# Rows past FLASH_MAX_LEN: the streamed side fetched a block a grid step
+# ----------------------------------------------------------------------
+def row_streams(row_len: int) -> bool:
+    """Whether a packed row of ``row_len`` tokens takes the kernels
+    that stream their blocks (it is past what the whole-row kernels
+    hold): the one rule of the kernels' dispatch and of the engine's
+    ``flash_stream_rows``."""
+    return row_len > FLASH_MAX_LEN
+
+
+def stream_heads(group: int) -> int:
+    """Query heads a grid step of a stream kernel serves: the largest
+    divisor of the key/value head's ``group`` up to ``STREAM_HEADS``."""
+    return max(n for n in range(1, min(group, STREAM_HEADS) + 1)
+               if group % n == 0)
+
+
+def _stream_steps(l, bq, bk, causal, window):
+    """How long the stream kernels' last grid axis is: the most key
+    blocks a query block visits and the most query blocks a key block
+    visits, in a row of ONE document (every packed row's ranges lie
+    inside that row's: a segment starts no earlier and ends no later
+    than the row)."""
+    (lo, hi), (q_lo, q_hi), _ = block_ranges(
+        np.ones((1, l), np.int32), bq, bk, causal, xp=np,
+        sliding_window=window)
+    return int((hi - lo).max()), int((q_hi - q_lo).max())
+
+
+def _stream_pair(bounds, pair, window, bq, bk):
+    """This grid step's block of the streamed side and its body:
+    ``pair(block, edges)`` runs where the step lies inside the resident
+    block's range ``[lo, hi)`` (``bounds``: the four prefetched arrays
+    of :func:`_loop_pairs`), without a mask over ``[full_lo, full_hi)``
+    and with one outside it (two bodies under ``pl.when``: nothing is
+    carried in registers between steps, so a branch merges nothing). A
+    window narrower than a pair's two blocks leaves no pair without an
+    edge, and one body."""
+    n = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
+    lo, hi, full_lo, full_hi = (ref[n] for ref in bounds)
+    block = lo + pl.program_id(3)
+    active = block < hi
+    if window is not None and window < bq + bk:
+        pl.when(active)(lambda: pair(block, True))
+        return
+    interior = (block >= full_lo) & (block < full_hi)
+    pl.when(active & interior)(lambda: pair(block, False))
+    pl.when(active & jnp.logical_not(interior))(lambda: pair(block, True))
+
+
+def _pair_mask(seg_q, seg_k, q0, k0, bq, bk, causal, window,
+               keys_down=False):
+    """The mask of the block pair whose first query row is ``q0`` and
+    first key column ``k0``, built once for all the heads of a step:
+    ``[BQ, BK]``, or ``[BK, BQ]`` (``keys_down``: the dkv pass's
+    transposed scores). ``seg_q`` / ``seg_k``: the blocks' segment
+    ids, ``[BQ]`` and ``[BK]``."""
+    shape, q_axis = ((bk, bq), 1) if keys_down else ((bq, bk), 0)
+    q_idx = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_idx = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    down, across = (seg_k, seg_q) if keys_down else (seg_q, seg_k)
+    return _edge_mask(down, across, q_idx, k_idx, causal, window)
+
+
+def _first_step():
+    return pl.program_id(3) == 0
+
+
+def _last_step():
+    return pl.program_id(3) == pl.num_programs(3) - 1
+
+
+def _lanes(x):
+    """[BQ] -> [BQ, LANES], as the scratch and the lse keep a number a
+    row."""
+    return jnp.broadcast_to(x[:, None], (x.shape[0], LANES))
+
+
+def _fwd_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
+                       q_ref, k_ref, v_ref, segq_ref, segk_ref,
+                       o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                       scale: float, causal: bool,
+                       window: Optional[int] = None):
+    """One (query block of ``heads`` heads, key block) pair a grid
+    step: the online softmax of :func:`_fwd_kernel`, its running
+    maximum, sum and accumulator in VMEM scratch ``[heads, BQ, .]``
+    between the steps of a query block."""
+    qi = pl.program_id(2)
+    _, heads, bq, _ = q_ref.shape
+    bk = k_ref.shape[2]
+
+    @pl.when(_first_step())
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def pair(kb, edges):
+        mask = _pair_mask(segq_ref[0, :, 0], segk_ref[0, 0, :], qi * bq,
+                          kb * bk, bq, bk, causal, window) if edges else None
+        k = k_ref[0, 0].astype(jnp.float32)  # [BK, hd]
+        v = v_ref[0, 0]  # [BK, hv]
+
+        def head(g, carry):
+            q = q_ref[0, g].astype(jnp.float32) * scale  # [BQ, hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [BQ, BK]
+            if mask is not None:
+                s = jnp.where(mask, s, NEG_INF)
+            m, l_sum = m_ref[g, :, 0], l_ref[g, :, 0]
+            m_new = jnp.maximum(m, s.max(axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m - m_new)
+            m_ref[g] = _lanes(m_new)
+            l_ref[g] = _lanes(l_sum * alpha + p.sum(axis=1))
+            acc_ref[g] = acc_ref[g] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    _stream_pair((kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref), pair,
+                 window, bq, bk)
+
+    @pl.when(_last_step())
+    def _():
+        def head(g, carry):
+            # (rows that saw no key: :func:`_fwd_kernel`'s comment)
+            m, l_sum = m_ref[g, :, 0], l_ref[g, :, 0]
+            row_valid = m > NEG_INF / 2
+            safe_l = jnp.where(l_sum > 0, l_sum, 1.0)
+            o_ref[0, g] = jnp.where(
+                row_valid[:, None], acc_ref[g] / safe_l[:, None],
+                0.0).astype(o_ref.dtype)
+            lse_ref[0, g] = _lanes(
+                jnp.where(row_valid, m + jnp.log(safe_l), NEG_INF))
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+
+def _bwd_dq_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
+                          q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
+                          lse_ref, delta_ref, dq_ref, *,
+                          scale: float, causal: bool,
+                          window: Optional[int] = None):
+    """:func:`_bwd_dq_kernel` a pair a grid step: dQ of the step's
+    heads accumulates in its float32 output block, which stays in VMEM
+    over the steps of a query block."""
+    qi = pl.program_id(2)
+    _, heads, bq, _ = q_ref.shape
+    bk = k_ref.shape[2]
+
+    @pl.when(_first_step())
+    def _():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
+
+    def pair(kb, edges):
+        mask = _pair_mask(segq_ref[0, :, 0], segk_ref[0, 0, :], qi * bq,
+                          kb * bk, bq, bk, causal, window) if edges else None
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+
+        def head(g, carry):
+            q = q_ref[0, g].astype(jnp.float32) * scale
+            do = do_ref[0, g].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(s - lse_ref[0, g, :, 0][:, None])
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[0, g, :, 0][:, None])
+            dq_ref[0, g] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    _stream_pair((kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref), pair,
+                 window, bq, bk)
+
+    @pl.when(_last_step())
+    def _():
+        dq_ref[...] = dq_ref[...] * scale
+
+
+def _bwd_dkv_stream_kernel(q_lo_ref, q_hi_ref, full_lo_ref, full_hi_ref,
+                           q_ref, k_ref, v_ref, segq_ref, segk_ref, do_ref,
+                           lse_ref, delta_ref, dk_ref, dv_ref, *,
+                           scale: float, causal: bool,
+                           window: Optional[int] = None):
+    """:func:`_bwd_dkv_kernel` a pair a grid step, TRANSPOSED: scores
+    ``K Q^T`` [BK, BQ], so that lse and delta are rows ``[1, BQ]`` (one
+    float32 a (row, head), as they are kept: a lane-broadcast copy
+    would be 128 times the bytes of a block fetched EVERY step) and
+    dV = P^T dO, dK = dS^T Q contract over the second axis of their
+    first operand. ``segk_ref`` is the LANE view of the key block's
+    segments (a column), ``segq_ref`` the sublane view of the query
+    block's (a row). dK and dV of the step's heads add up in the
+    float32 output blocks."""
+    ki = pl.program_id(2)
+    _, heads, bq, _ = q_ref.shape
+    bk = k_ref.shape[2]
+
+    @pl.when(_first_step())
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
+    def pair(qb, edges):
+        mask = _pair_mask(segq_ref[0, 0, :], segk_ref[0, :, 0], qb * bq,
+                          ki * bk, bq, bk, causal, window,
+                          keys_down=True) if edges else None
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+
+        def head(g, carry):
+            q = q_ref[0, g].astype(jnp.float32) * scale  # [BQ, hd]
+            do = do_ref[0, g].astype(jnp.float32)  # [BQ, hv]
+            s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(s - lse_ref[0, g])  # [BK, BQ] - [1, BQ]
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dv_ref[0, 0] += jax.lax.dot_general(
+                p, do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[0, g])
+            dk_ref[0, 0] += jax.lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    _stream_pair((q_lo_ref, q_hi_ref, full_lo_ref, full_hi_ref), pair,
+                 window, bq, bk)
+
+
+def _stream_call(kernel, name, grid, bounds, in_specs, out_specs, out_shape,
+                 *args, scratch=()):
+    """:func:`_ranged_call` over a grid of four axes, the last one the
+    steps of a block's range. What the call holds does not grow with
+    the row: its blocks twice (the pipeline's two buffers), its
+    scratch, and room for a pair's float32 scores, probabilities and
+    mask; the compiler is asked for that much."""
+    held = _held_twice(in_specs, out_specs, out_shape, args) + sum(
+        int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize for s in scratch)
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(bounds), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=list(scratch)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(DEFAULT_SCOPED_VMEM,
+                                 int(held * 1.25) + 8 * 2 ** 20)),
+        name=name,
+    )(*(x.reshape(-1) for x in bounds), *args)
+
+
+def _stream_maps(nb: int, n_streamed: int, heads: int, group: int):
+    """Index maps of a (batch, head group, resident block, step) grid
+    step, each taking the four prefetched ranges after the grid
+    indices. ``res``: the resident block of a ``[B, nq, L, .]`` array
+    (``heads`` heads from ``h x heads``); ``kv_res``: of a ``[B, nkv,
+    L, .]`` one; ``seg_res`` / ``seg_res_row``: of the lane / sublane
+    view of the segments. ``step`` and its kin: the same of the
+    STREAMED block, ``lo + step`` held inside ``[lo, hi)`` and the
+    array: past a block's own range the index repeats, and the pipeline
+    fetches nothing."""
+    def streamed(bi, i, j, lo, hi):
+        n = bi * nb + i
+        return jnp.clip(jnp.minimum(lo[n] + j, hi[n] - 1), 0,
+                        n_streamed - 1)
+
+    def kv_head(h):
+        return (h * heads) // group
+
+    return dict(
+        res=lambda bi, h, i, j, *_: (bi, h, i, 0),
+        kv_res=lambda bi, h, i, j, *_: (bi, kv_head(h), i, 0),
+        seg_res=lambda bi, h, i, j, *_: (bi, i, 0),
+        seg_res_row=lambda bi, h, i, j, *_: (bi, 0, i),
+        step=lambda bi, h, i, j, lo, hi, *_: (
+            bi, h, streamed(bi, i, j, lo, hi), 0),
+        step_row=lambda bi, h, i, j, lo, hi, *_: (
+            bi, h, 0, streamed(bi, i, j, lo, hi)),
+        kv_step=lambda bi, h, i, j, lo, hi, *_: (
+            bi, kv_head(h), streamed(bi, i, j, lo, hi), 0),
+        seg_step=lambda bi, h, i, j, lo, hi, *_: (
+            bi, streamed(bi, i, j, lo, hi), 0),
+        seg_step_row=lambda bi, h, i, j, lo, hi, *_: (
+            bi, 0, streamed(bi, i, j, lo, hi)))
+
+
+@jax.named_scope(P.STREAM)
+def _flash_fwd_stream(q, k, v, seg_ids, scale, causal, bq, bk, window):
+    """:func:`_flash_fwd` for a row past ``FLASH_MAX_LEN``: the same
+    two outputs, K and V a block a grid step. (Sub-part ``attn/stream``
+    of a device trace: ``obs/parts.py``.)"""
+    b, l, nq, hd = q.shape
+    nkv, hv = k.shape[2], v.shape[3]
+    group = nq // nkv
+    heads = stream_heads(group)
+    bq, bk = _blocks(l, bq, bk)
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    segq, segk = _expand_segments(seg_ids)
+    kv_range, _, (kv_full, _) = block_ranges(seg_ids, bq, bk, causal,
+                                             sliding_window=window)
+    steps, _ = _stream_steps(l, bq, bk, causal, window)
+    at = _stream_maps(l // bq, l // bk, heads, group)
+    return _stream_call(
+        functools.partial(_fwd_stream_kernel, scale=scale, causal=causal,
+                          window=window),
+        "flash_fwd" + STREAM_SUFFIX, (b, nq // heads, l // bq, steps),
+        kv_range + kv_full,
+        [
+            pl.BlockSpec((1, heads, bq, hd), at["res"]),
+            pl.BlockSpec((1, 1, bk, hd), at["kv_step"]),
+            pl.BlockSpec((1, 1, bk, hv), at["kv_step"]),
+            pl.BlockSpec((1, bq, LANES), at["seg_res"]),
+            pl.BlockSpec((1, SUBLANES, bk), at["seg_step_row"]),
+        ],
+        (pl.BlockSpec((1, heads, bq, hv), at["res"]),
+         pl.BlockSpec((1, heads, bq, LANES), at["res"])),
+        (jax.ShapeDtypeStruct((b, nq, l, hv), q.dtype),
+         jax.ShapeDtypeStruct((b, nq, l, LANES), jnp.float32)),
+        qt, kt, vt, segq, segk,
+        scratch=[pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                 pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                 pltpu.VMEM((heads, bq, hv), jnp.float32)])
+
+
+@jax.named_scope(P.STREAM)
+def _flash_bwd_stream(res, g, scale, causal, bq, bk, window):
+    """:func:`_flash_bwd` for a row past ``FLASH_MAX_LEN``."""
+    q, k, v, seg_ids, ot, lse = res
+    b, l, nq, hd = q.shape
+    nkv, hv = k.shape[2], v.shape[3]
+    group = nq // nkv
+    heads = stream_heads(group)
+    bq, bk = _blocks(l, bq, bk)
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    dot = g.transpose(0, 2, 1, 3)
+    segq, segk = _expand_segments(seg_ids)
+    delta = (ot.astype(jnp.float32) * dot.astype(jnp.float32)).sum(-1)
+    kv_range, q_range, (kv_full, q_full) = block_ranges(
+        seg_ids, bq, bk, causal, sliding_window=window)
+    kv_steps, q_steps = _stream_steps(l, bq, bk, causal, window)
+    kernel = dict(scale=scale, causal=causal, window=window)
+
+    at = _stream_maps(l // bq, l // bk, heads, group)
+    dq = _stream_call(
+        functools.partial(_bwd_dq_stream_kernel, **kernel),
+        "flash_bwd_dq" + STREAM_SUFFIX,
+        (b, nq // heads, l // bq, kv_steps), kv_range + kv_full,
+        [
+            pl.BlockSpec((1, heads, bq, hd), at["res"]),
+            pl.BlockSpec((1, 1, bk, hd), at["kv_step"]),
+            pl.BlockSpec((1, 1, bk, hv), at["kv_step"]),
+            pl.BlockSpec((1, bq, LANES), at["seg_res"]),
+            pl.BlockSpec((1, SUBLANES, bk), at["seg_step_row"]),
+            pl.BlockSpec((1, heads, bq, hv), at["res"]),
+            pl.BlockSpec((1, heads, bq, LANES), at["res"]),
+            pl.BlockSpec((1, heads, bq, LANES), at["res"]),
+        ],
+        pl.BlockSpec((1, heads, bq, hd), at["res"]),
+        jax.ShapeDtypeStruct(qt.shape, jnp.float32),
+        qt, kt, vt, segq, segk, dot,
+        jnp.broadcast_to(lse[..., None], (b, nq, l, LANES)),
+        jnp.broadcast_to(delta[..., None], (b, nq, l, LANES)))
+
+    at = _stream_maps(l // bk, l // bq, heads, group)
+    dk_partial, dv_partial = _stream_call(
+        functools.partial(_bwd_dkv_stream_kernel, **kernel),
+        "flash_bwd_dkv" + STREAM_SUFFIX,
+        (b, nq // heads, l // bk, q_steps), q_range + q_full,
+        [
+            pl.BlockSpec((1, heads, bq, hd), at["step"]),
+            pl.BlockSpec((1, 1, bk, hd), at["kv_res"]),
+            pl.BlockSpec((1, 1, bk, hv), at["kv_res"]),
+            pl.BlockSpec((1, SUBLANES, bq), at["seg_step_row"]),
+            pl.BlockSpec((1, bk, LANES), at["seg_res"]),
+            pl.BlockSpec((1, heads, bq, hv), at["step"]),
+            pl.BlockSpec((1, heads, 1, bq), at["step_row"]),
+            pl.BlockSpec((1, heads, 1, bq), at["step_row"]),
+        ],
+        (pl.BlockSpec((1, 1, bk, hd), at["res"]),
+         pl.BlockSpec((1, 1, bk, hv), at["res"])),
+        (jax.ShapeDtypeStruct((b, nq // heads, l, hd), jnp.float32),
+         jax.ShapeDtypeStruct((b, nq // heads, l, hv), jnp.float32)),
+        qt, kt, vt, segk, segq, dot, lse[:, :, None, :],
+        delta[:, :, None, :])
+
+    # one partial a step's group of heads: summed where a key/value
+    # head's query heads took several steps
+    per_kv = group // heads
+    dk = dk_partial.reshape(b, nkv, per_kv, l, hd).sum(2).transpose(0, 2, 1, 3)
+    dv = dv_partial.reshape(b, nkv, per_kv, l, hv).sum(2).transpose(0, 2, 1, 3)
+    return (dq.transpose(0, 2, 1, 3).astype(q.dtype), dk.astype(k.dtype),
+            dv.astype(v.dtype), None)
+
+
+# ----------------------------------------------------------------------
 # Public API
 # ----------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -806,15 +1278,27 @@ def flash_attention(q, k, v, seg_ids, *, causal: bool = True,
         raise NotImplementedError(
             "soft cap not yet supported by the flash kernel; use the XLA "
             "path (packed_attention(..., use_flash=False)).")
-    if q.shape[1] > FLASH_MAX_LEN:
+    l = q.shape[1]
+    if l > FLASH_STREAM_MAX_LEN:
         raise ValueError(
-            f"flash_attention: packed row of {q.shape[1]} tokens exceeds "
-            f"FLASH_MAX_LEN={FLASH_MAX_LEN}, the longest row whose "
-            "forward and backward kernels hold whole in the chip's "
-            f"VMEM at a key's width of {q.shape[-1]}. Split "
-            "the batch into more microbatches (the MFC's n_mbs) so "
-            "packed rows get shorter, or shard the sequence over a "
-            "context-parallel mesh (ring attention).")
+            f"flash_attention: packed row of {l} tokens exceeds "
+            f"FLASH_STREAM_MAX_LEN={FLASH_STREAM_MAX_LEN}, the longest "
+            "row the kernels that stream K and V by block are compiled "
+            f"for (rows up to FLASH_MAX_LEN={FLASH_MAX_LEN} hold K and V "
+            "whole a head in the chip's VMEM). Split the batch into "
+            "more microbatches (the MFC's n_mbs) so packed rows get "
+            "shorter, or shard the sequence over a context-parallel "
+            "mesh (ring attention).")
+    if row_streams(l) and (select is not None
+                           or q.shape[-1] != v.shape[-1]):
+        raise NotImplementedError(
+            f"flash_attention: packed row of {l} tokens exceeds "
+            f"FLASH_MAX_LEN={FLASH_MAX_LEN}, and the kernels that "
+            "stream K and V by block (flash_*_stream) take no learned "
+            "selection (select=, a sparse layer's) and no key wider "
+            f"than its value (key {q.shape[-1]}, value {v.shape[-1]}: "
+            "latent attention). Split the batch into more microbatches "
+            "(the MFC's n_mbs) so packed rows get shorter.")
     if sliding_window is not None and not (causal and sliding_window >= 1):
         raise ValueError(
             f"sliding_window={sliding_window} needs causal attention "

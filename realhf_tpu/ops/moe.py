@@ -20,7 +20,8 @@ Three dispatch modes (``dispatch_mode``):
 - ``dense`` (``capacity_factor=None`` + ``use_grouped_gemm=False``):
   every expert sees every token through one batched einsum a
   projection, weighted by its gate (exact; E/topk times the FLOPs;
-  the correctness reference for tests).
+  the correctness reference for tests; of a rank's share, below,
+  every HELD expert).
 - ``capacity`` (``capacity_factor=c``): dispatch by dense one-hot
   einsums over a static expert-capacity axis -- each expert processes
   at most c * T * topk / E tokens through the batched einsums;
@@ -36,7 +37,12 @@ statistics.
 **A rank's share** (``MoEConfig.experts_held = (first, count)``): the
 layer holds ``count`` experts' weights, routes over all
 ``num_experts`` and computes the part of the result its own experts
-give, in the ragged mode only (``_ragged_share``). The (token, k)
+give, in the ragged mode (``_ragged_share``) or, where a config says
+``use_grouped_gemm=False``, in the dense mode over the HELD stacks
+(every held expert over every token, a gate of 0 where it is not among
+the token's k: ``E / k`` times the FLOPs of even routing, and a cost
+that does not move with the routing). A capacity
+that drops pairs is refused. In the ragged mode the (token, k)
 pairs are sorted with the held experts' first; the first ``rows`` of
 them are gathered, multiplied in ``count`` groups and scattered back;
 pairs of absent experts are never multiplied: what those experts would
@@ -211,6 +217,8 @@ def _activation(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
         return jax.nn.gelu(x, approximate=False)
     if cfg.activation_function == "gelu_new":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.activation_function == "relu":
+        return jax.nn.relu(x)
     if cfg.activation_function == "relu2":
         return jnp.square(jax.nn.relu(x))
     raise NotImplementedError(cfg.activation_function)
@@ -445,11 +453,18 @@ def _ragged_share(cfg: TransformerConfig, m: Dict, xt: jnp.ndarray,
 def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                         rng: Optional[jax.Array] = None,
                         valid_mask: Optional[jnp.ndarray] = None,
-                        ep_constraint=None
+                        ep_constraint=None,
+                        route_on: Optional[jnp.ndarray] = None
                         ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """MoE feed-forward over [B, L, H]; ``valid_mask`` [B, L] excludes
     padding tokens from routing, expert capacity, and the aux losses
     (pad positions carry real hidden states in the packed layout).
+    ``route_on`` [B, L, H]: what the ROUTER's product reads where that
+    is another tensor than the experts' input ``x``
+    (``MoEConfig.router_input``: the layer's input, before its
+    operator); the product then stands under sub-part
+    ``experts/router`` (it runs before attention, and the compiler may
+    put it there). None: ``x``, and the program it always was.
 
     ``ep_constraint`` (models/sharding.py moe_ep_constraint) pins the
     expert-major intermediates to the expert-parallel axis so GSPMD
@@ -471,10 +486,18 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     else:
         valid = valid_mask.reshape(t).astype(jnp.float32)
     e = moe.num_experts
+
+    def router_logits(rows):
+        return (rows.astype(jnp.float32)
+                @ m["router"].astype(jnp.float32))  # [T, E]
+
+    if route_on is not None:
+        with jax.named_scope(P.ROUTER):
+            logits = router_logits(route_on.reshape(t, h))
     with jax.named_scope(P.ROUTE):
         n_valid = jnp.maximum(valid.sum(), 1.0)
-        logits = (xt.astype(jnp.float32)
-                  @ m["router"].astype(jnp.float32))  # [T, E]
+        if route_on is None:
+            logits = router_logits(xt)
         probs_full = jax.nn.softmax(logits, axis=-1)
         top_probs, top_idx = router_probs(moe, logits, rng,
                                           m.get("expert_bias"))
@@ -486,10 +509,10 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
     ep = ep_constraint if ep_constraint is not None else (lambda a: a)
     mode = dispatch_mode(cfg)
     held = moe.experts_held
-    if held is not None and mode != "ragged":
+    if held is not None and mode not in ("ragged", "dense"):
         raise NotImplementedError(
-            f"experts_held={held} of {e} needs the ragged dispatch "
-            f"mode, not {mode!r}")
+            f"experts_held={held} of {e} needs the ragged or the dense "
+            f"dispatch mode, not {mode!r}")
     if mode == "ragged":
         kernel = ep_constraint != SHARDED_STACKS and pallas_enabled()
         if callable(ep_constraint):
@@ -506,13 +529,21 @@ def moe_mlp_with_losses(cfg: TransformerConfig, m: Dict, x: jnp.ndarray,
                                 top_idx, load[held[0]:held[0] + held[1]],
                                 kernel)
     elif mode == "dense":
-        # Dense mode: every expert over all tokens, gate-weighted.
-        xs = ep(jnp.broadcast_to(xt[None], (e, t, h)).astype(x.dtype))
-        expert_out = ep(_expert_ffn(cfg, m, xs))  # [E, T, H]
-        gates = jnp.zeros((t, e), jnp.float32)
-        gates = jax.vmap(lambda g, idx, p: g.at[idx].add(p))(
-            gates, top_idx, top_probs)
-        out = jnp.einsum("eth,te->th", expert_out.astype(jnp.float32), gates)
+        # Dense mode: every expert over all tokens, gate-weighted (a
+        # share: every HELD expert, the stacks' own; what the absent
+        # ones would have added is left out, as in ``_ragged_share``).
+        n = moe.n_held
+        with jax.named_scope(P.PRODUCTS):
+            xs = ep(jnp.broadcast_to(xt[None], (n, t, h)).astype(x.dtype))
+            expert_out = ep(_expert_ffn(cfg, m, xs))  # [E, T, H]
+        with jax.named_scope(P.COMBINE):
+            gates = jnp.zeros((t, e), jnp.float32)
+            gates = jax.vmap(lambda g, idx, p: g.at[idx].add(p))(
+                gates, top_idx, top_probs)
+            if held is not None:
+                gates = gates[:, held[0]:held[0] + n]
+            out = jnp.einsum("eth,te->th",
+                             expert_out.astype(jnp.float32), gates)
     else:
         cap = max(1, int(moe.capacity_factor * t * moe.top_k / e))
         # position of each (token, k) within its expert's capacity;
@@ -564,8 +595,10 @@ def _losses(cfg: TransformerConfig, logits, probs_full, top_idx, load,
         losses[HELD_PAIRS_STAT] = mine.sum().astype(jnp.float32)
         losses[HELD_LOAD_STAT] = mine.max().astype(jnp.float32) \
             * (e / (t * moe.top_k))
+        # (the dense mode has no rows to overflow)
         losses[SHARE_OVERFLOW_STAT] = (
-            mine.sum() > share_rows(cfg, t)).astype(jnp.float32)
+            mine.sum() > share_rows(cfg, t)).astype(jnp.float32) \
+            if dispatch_mode(cfg) == "ragged" else jnp.zeros(())
     if moe.routing_type == "aux_loss" and moe.aux_loss_coeff:
         losses["moe_aux_loss"] = moe.aux_loss_coeff * load_balancing_loss(
             probs_full, top_idx, e, moe.top_k, valid=valid)

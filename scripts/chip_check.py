@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's run on the chip for a patterned sparse family
-(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``, ``nemotron_h``) or a looped one (``ouro``), outside the benchmark: what sizes the
+(``lfm2_moe``, ``laguna``, ``deepseek_v3``, ``kimi_linear``, ``keye_vl2``, ``nemotron_h``, ``smallthinker``) or a looped one (``ouro``), outside the benchmark: what sizes the
 family's ``TOLERANCE`` in ``benchmark/families/<family>.py``, packed
 rows and the decode path held to the reference at its cell's widths,
 and one ``quickstart gen`` run on the same checkpoint.
@@ -161,6 +161,16 @@ FAMILIES = {
         cell="ouro-2.6b-l6.sft-4k-x4", tiny=("ouro", "tiny-ouro.sft"),
         wrong_keys={}, packed_docs=(1536, 1024, 1024, 512),
         decode=(2, 768, 640), exact_doc=4096, objective=512),
+    # rows past 4096: the packed row (16,384) and the long document go
+    # through the flash kernels that STREAM K and V by block; the decode
+    # check prefills 8,192 tokens (the stream forward) and decodes 127;
+    # gradient: one SFT microbatch of that many tokens against
+    # ``jax.grad`` of the reference
+    "smallthinker": dict(
+        cell="smallthinker-21b-a3b-l4-ep8.sft-16k",
+        tiny=("smallthinker", "tiny-smallthinker.sft"), wrong_keys={},
+        packed_docs=(4096, 8192, 2048, 2048), decode=(1, 8320, 8192),
+        exact_doc=16384, gradient=256),
 }
 FAMILY = None  # set by main: the family's name, for say's file
 
@@ -408,12 +418,13 @@ def packed(cell, engine, tensors, docs, ckpt=None):
                           for j, d in enumerate(docs)])[None]
     got = np.asarray(engine.forward_logprobs(row, seg), np.float32)[0]
     rows, at = {}, 0
+    window = hf.get("sliding_window") or hf.get("sliding_window_size")
+    longest = max(docs, key=len)
     for j, doc in enumerate(docs):
         want = family.logprobs(hf, tensors, doc[None].astype(np.int32))[0]
         mine = got[at:at + len(doc) - 1]
         rows[f"document_{j}_of_{len(doc)}"] = dict(
             all=share(mine, want), first_two_tokens=share(mine[:2], want[:2]))
-        window = hf.get("sliding_window")
         if window and len(doc) > window + 1:
             rows[f"document_{j}_of_{len(doc)}"]["past_the_window"] = share(
                 mine[window:], want[window:])
@@ -421,9 +432,8 @@ def packed(cell, engine, tensors, docs, ckpt=None):
     # the fixed batch's documents (256 tokens) are shorter than a
     # sliding window: what a window off by one, and every other wrong
     # equation, does to a document LONGER than the window is read here
-    window = hf.get("sliding_window")
-    if window and len(docs[0]) > window + 1:
-        doc = docs[0][None].astype(np.int32)
+    if window and len(longest) > window + 1:
+        doc = longest[None].astype(np.int32)
         want = family.logprobs(hf, tensors, doc)
         rows["wrong_on_the_longest_document"] = {
             wrong: share(family.logprobs(hf, tensors, doc, wrong=(wrong,)),
@@ -455,10 +465,13 @@ def exact(cell, ckpt, tensors, doc):
         engine = one_chip_engine(ckpt, "float32")
         got = np.asarray(engine.forward_logprobs(doc, np.ones_like(doc)),
                          np.float32)[:, :-1]
-        kernel = "tpu_custom_call" in engine.compiled_text("logprobs")
+        text = engine.compiled_text("logprobs")
+        kernel = "tpu_custom_call" in text
     del engine
     say(phase="exact", document=doc.shape[1], flash_kernels=kernel,
+        stream_kernels="flash_fwd_stream" in text,
         engine_float32=share(got, want),
+        engine_float32_on_the_longest_document=share(got, want),
         wrong={wrong: share(family.logprobs(hf, tensors, doc,
                                             wrong=(wrong,)), want)
                for wrong in family.WRONG},
@@ -1272,7 +1285,7 @@ def main():
                         "family with a published decay (kimi_linear, "
                         "nemotron_h), else of packed, decode, "
                         "slow_path, uncovered_rows, selection, exact, "
-                        "objective")
+                        "gradient, objective")
     p.add_argument("--against", default=None,
                    help="kimi_linear's row scan: another checkout of "
                         "this repo (the parent's, unpacked under a "
@@ -1359,6 +1372,11 @@ def main():
                     engine = None  # the bf16 weights go before float32's come
                     if asked("exact"):
                         exact(cell, ckpt, tensors, long)
+                if spec.get("gradient") and asked("gradient"):
+                    engine = None
+                    gradient(cell, ckpt, tensors, seed,
+                             spec["gradient"] // (4 if args.rehearse
+                                                  else 1))
                 if spec.get("objective") and asked("objective"):
                     engine = None
                     objective(cell, ckpt, tensors, seed,

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """A builder's tool, no chip: lower the programs of the benchmark's
-accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2, nemotron_h, ouro) under a checkout and write
+accepted families (qwen2, mistral, olmoe, lfm2_moe, laguna, deepseek_v3, kimi_linear, keye_vl2, nemotron_h, ouro, smallthinker) under a checkout and write
 their StableHLO texts, to show that a change to shared model code left
 a model of one block the programs it had.
 
@@ -65,7 +65,7 @@ def dump(name, fn, *args, **kw):
     open(os.path.join(out, name + ".txt"), "w").write(txt)
     print(name, len(txt))
 
-for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096), ("nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 4096), ("ouro-2.6b-l6", "ouro", 4096)):
+for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", "mistral", 2048), ("olmoe-1b-7b-0125-l1", "olmoe", 2048), ("lfm2-24b-a2b-l5-ep8", "lfm2_moe", 4096), ("laguna-xs.2-l5-ep16", "laguna", 4096), ("moonlight-16b-a3b-l5-ep8", "deepseek_v3", 4096), ("kimi-linear-48b-a3b-l5-ep32", "kimi_linear", 2048), ("keye-vl-2.0-30b-a3b-l5-ep8", "keye_vl2", 4096), ("nemotron-3-nano-30b-a3b-l7-ep16", "nemotron_h", 4096), ("ouro-2.6b-l6", "ouro", 4096), ("smallthinker-21b-a3b-l4-ep8", "smallthinker", 16384)):
     if fam not in hf_models.HF_FAMILIES:
         print(cfgname, "left out: this tree has no family", fam)
         continue
@@ -100,18 +100,23 @@ for cfgname, fam, L in (("qwen2.5-0.5b", "qwen2", 4096), ("mistral-7b-v0.3-l4", 
              params, sds((b, 256), jnp.int32), sds((b, 256), jnp.int32), sds((b, 256), jnp.int32),
              jax.eval_shape(lambda: jax.random.PRNGKey(0)))
 
-# the flash kernels by themselves: (query heads, key/value heads, key's width, value's width, row) of cells 1-2, 3, 4, 5, 6's full layers, 7
+# the flash kernels by themselves: (query heads, key/value heads, key's width, value's width, row, window) of cells 1-2, 3, 4, 5, 6's full layers, 7, and 12's two kinds of layer (a row past 4096: the kernels that stream K and V by block, which a tree before them refuses)
 from realhf_tpu.ops.flash_attention import flash_attention  # noqa: E402
-for nq, nkv, hd, hv, L in ((14, 2, 64, 64, 4096), (32, 8, 128, 128, 2048), (16, 16, 128, 128, 2048), (32, 8, 64, 64, 4096), (48, 8, 128, 128, 4096), (16, 16, 192, 128, 4096)):
+for nq, nkv, hd, hv, L, window in ((14, 2, 64, 64, 4096, None), (32, 8, 128, 128, 2048, None), (16, 16, 128, 128, 2048, None), (32, 8, 64, 64, 4096, None), (48, 8, 128, 128, 4096, None), (16, 16, 192, 128, 4096, None), (28, 4, 128, 128, 16384, None), (28, 4, 128, 128, 16384, 4096)):
     sds = jax.ShapeDtypeStruct
     q, k, v = (sds((1, L, n, w), jnp.bfloat16) for n, w in ((nq, hd), (nkv, hd), (nkv, hv)))
     heads = f"{nq}x{nkv}x{hd}" + ("" if hv == hd else f"x{hv}")
+    # (a call without a window is the call it always was: no keyword)
+    more = {} if window is None else dict(sliding_window=window)
+    L = f"{L}" + ("" if window is None else f"w{window}")
+    def forward(q, k, v, seg):
+        return flash_attention(q, k, v, seg, **more)
     def grads(q, k, v, seg):
-        return jax.value_and_grad(lambda q, k, v: flash_attention(q, k, v, seg).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+        return jax.value_and_grad(lambda q, k, v: forward(q, k, v, seg).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
     # (.fwd: the forward alone, what a program without a gradient runs)
-    for name, fn in ((f"flash.{heads}x{L}.jaxpr", grads), (f"flash.{heads}x{L}.fwd.jaxpr", flash_attention)):
+    for name, fn in ((f"flash.{heads}x{L}.jaxpr", grads), (f"flash.{heads}x{L}.fwd.jaxpr", flash_attention if window is None else forward)):
         try:
-            txt = str(jax.make_jaxpr(fn)(q, k, v, sds((1, L), jnp.int32)))
+            txt = str(jax.make_jaxpr(fn)(q, k, v, sds((1, q.shape[1]), jnp.int32)))
         except Exception as e:  # noqa: BLE001 - a tree before two widths
             print(name, "left out:", type(e).__name__, str(e)[:80])
             continue

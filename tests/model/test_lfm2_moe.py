@@ -480,10 +480,18 @@ def test_what_does_not_run_a_pattern_refuses_by_name(built):
     with pytest.raises(NotImplementedError, match="pipeline.*" + named):
         T.forward(cfg, params, jnp.zeros((1, 8), jnp.int32),
                   jnp.ones((1, 8), jnp.int32), pipeline=_Stages())
-    # a share needs the exact ragged mode
+    # a share needs an EXACT mode, the ragged one or (PR 62) the dense
+    # one over its held experts; a capacity that drops pairs is refused
     import dataclasses
+    capacity = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, capacity_factor=1.25))
+    with pytest.raises(NotImplementedError, match="experts_held"):
+        T.forward(capacity, params, jnp.zeros((1, 8), jnp.int32),
+                  jnp.ones((1, 8), jnp.int32))
     dense = dataclasses.replace(cfg, moe=dataclasses.replace(
         cfg.moe, use_grouped_gemm=False))
-    with pytest.raises(NotImplementedError, match="experts_held"):
-        T.forward(dense, params, jnp.zeros((1, 8), jnp.int32),
-                  jnp.ones((1, 8), jnp.int32))
+    ids = jnp.arange(8, dtype=jnp.int32)[None] + 2
+    seg = jnp.ones((1, 8), jnp.int32)
+    want, got = (np.asarray(T.forward(c, params, ids, seg)[0], np.float32)
+                 for c in (cfg, dense))
+    assert np.abs(got - want).max() <= 1e-5 * max(np.abs(want).max(), 1.0)

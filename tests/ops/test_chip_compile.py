@@ -30,7 +30,10 @@ from realhf_tpu.ops.decode_attention import (
     decode_layer_copies,
     flash_decode_attention_stacked,
 )
-from realhf_tpu.ops.flash_attention import FLASH_MAX_LEN, flash_attention
+from realhf_tpu.ops.flash_attention import (FLASH_MAX_LEN,
+                                            FLASH_STREAM_MAX_LEN,
+                                            flash_attention)
+from realhf_tpu.ops.hlo_text import device_instructions
 
 NQ, NKV, HD = 14, 2, 64       # Qwen2.5-0.5B attention widths
 N_LAYERS = 24
@@ -703,10 +706,15 @@ def test_kimis_float32_forward_compiles(one_chip):
     assert delta_rule.scan_kernel_calls(text) == 4
 
 
-def test_flash_compiles_under_shard_map(topo):
+@pytest.mark.parametrize("row,suffix", [(2048, ""), (8192, "_stream")],
+                         ids=["cell_3s_rows", "rows_of_8192_streamed"])
+def test_flash_compiles_under_shard_map(topo, row, suffix):
     """Cell 3's layout: rows over "data", heads over "model" on a 2x2
     mesh, each shard's kernels taking their ranges from the local
-    segment ids (Mistral's heads, the cell's rows of 2048)."""
+    segment ids (Mistral's heads, the cell's rows of 2048); and rows
+    of 8192 there, which each shard hands the kernels that stream K and
+    V by block (4 of a shard's 16 query heads a key/value head: one
+    fetch serves four)."""
     import numpy as np
 
     from realhf_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -714,7 +722,7 @@ def test_flash_compiles_under_shard_map(topo):
                 (DATA_AXIS, MODEL_AXIS))
     heads = NamedSharding(mesh, P(DATA_AXIS, None, MODEL_AXIS, None))
     rows = NamedSharding(mesh, P(DATA_AXIS, None))
-    q, k, v, seg = _qkv(None, 2, 2048, 32, 8, 128)
+    q, k, v, seg = _qkv(None, 2, row, 32, 8, 128)
     q, k, v = (jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=heads)
                for x in (q, k, v))
     seg = jax.ShapeDtypeStruct(seg.shape, seg.dtype, sharding=rows)
@@ -730,7 +738,8 @@ def test_flash_compiles_under_shard_map(topo):
 
     text = _compile(grads, q, k, v, seg).as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert kernel in text
+        assert kernel + suffix in text
+    assert ("_stream" in text) == bool(suffix)
 
 
 def _mesh_of_four(topo):
@@ -865,12 +874,77 @@ def test_kimis_whole_microbatch_compiles_on_a_mesh(topo):
 
 
 def test_row_above_the_limit_raises_not_xla():
-    """One bucket above the limit, ``packed_attention`` raises a clear
-    error; it does not drop to the O(L^2) XLA path in silence."""
-    q, k, v, seg = _qkv(None, 1, FLASH_MAX_LEN + 128)
-    with pytest.raises(ValueError, match="FLASH_MAX_LEN"):
+    """One bucket above the limit of the kernels that stream K and V,
+    ``packed_attention`` raises a clear error; it does not drop to the
+    O(L^2) XLA path in silence. Nor does a row past ``FLASH_MAX_LEN``
+    that those kernels do not take: one with a learned selection, one
+    whose key is wider than its value."""
+    q, k, v, seg = _qkv(None, 1, FLASH_STREAM_MAX_LEN + 128)
+    with pytest.raises(ValueError, match="FLASH_STREAM_MAX_LEN"):
         jax.eval_shape(
             lambda *a: packed_attention(*a, use_flash=True), q, k, v, seg)
+    n = FLASH_MAX_LEN + 128
+    q, k, v, seg = _qkv(None, 1, n)
+    select = jax.ShapeDtypeStruct((1, n, n), jnp.int8)
+    with pytest.raises(NotImplementedError, match="no learned selection"):
+        jax.eval_shape(lambda *a: packed_attention(
+            *a[:4], use_flash=True, select=a[4]), q, k, v, seg, select)
+    wide = jax.ShapeDtypeStruct((1, n, NQ, 192), jnp.bfloat16)
+    with pytest.raises(NotImplementedError, match="key 192, value 64"):
+        jax.eval_shape(
+            lambda *a: packed_attention(*a, use_flash=True),
+            wide, jax.ShapeDtypeStruct((1, n, NKV, 192), jnp.bfloat16),
+            v, seg)
+
+
+#: SmallThinker-21BA3B's attention widths and its own context
+ST_HEADS, ST_ROW, ST_WINDOW = (28, 4, 128), 16384, 4096
+
+
+@pytest.mark.parametrize("l,window,dtype", [
+    (ST_ROW, None, jnp.bfloat16), (ST_ROW, ST_WINDOW, jnp.bfloat16),
+    (ST_ROW, ST_WINDOW, jnp.float32),
+    (FLASH_STREAM_MAX_LEN, None, jnp.bfloat16),
+    (FLASH_MAX_LEN + 256, 512, jnp.bfloat16)],
+    ids=["full_16k", "window_16k", "window_16k_float32", "at_the_limit",
+         "one_block_past_the_whole_row_kernels"])
+def test_stream_kernels_compile_past_the_longest_held_row(one_chip, l,
+                                                          window, dtype):
+    """The three kernels that stream their blocks
+    (``flash_fwd_stream``, ``flash_bwd_dq_stream``,
+    ``flash_bwd_dkv_stream``) for the described chip at SmallThinker's
+    (28 q, 4 kv, 128): a row of 16,384 with and without the window of
+    4096 (the twelfth cell's two kinds of layer), in float32 too (the
+    gate ``chip_check.py smallthinker``'s long document runs), at
+    ``FLASH_STREAM_MAX_LEN``, which is a promise about the compiler as
+    ``FLASH_MAX_LEN`` is, and one key block past the whole-row
+    kernels' limit under a window narrower than a pair (one body). No
+    whole-row kernel is in the program, and what the program keeps
+    beside the kernels does not hold K and V a query head."""
+    q, k, v, seg = (jax.ShapeDtypeStruct(a.shape, dtype if a.dtype
+                                         == jnp.bfloat16 else a.dtype,
+                                         sharding=one_chip)
+                    for a in _qkv(one_chip, 1, l, *ST_HEADS))
+
+    def fwd(q, k, v, seg):
+        return flash_attention(q, k, v, seg, sliding_window=window)
+
+    def grads(q, k, v, seg):
+        return jax.grad(lambda q, k, v: fwd(q, k, v, seg).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(fwd, q, k, v, seg).as_text()
+    assert "flash_fwd_stream" in text
+    compiled = _compile(grads, q, k, v, seg)
+    calls = [name for name, _, opcode in device_instructions(
+        compiled.as_text()) if opcode == "custom-call"]
+    assert sorted(c.rstrip(".0123456789") for c in calls
+                  if "flash" in c) == [
+        "flash_bwd_dkv_stream", "flash_bwd_dq_stream", "flash_fwd_stream"]
+    # q, k, v, the output, three gradients and the two lane-broadcast
+    # float32 copies of lse and delta: nothing a (query head, key)
+    per_token = compiled.memory_analysis().temp_size_in_bytes / l
+    assert per_token < 60e3, per_token
 
 
 def _float32_arrays_made(text, elems):
@@ -1228,3 +1302,47 @@ def test_ouros_whole_train_step_compiles(one_chip):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print("ouro whole step GB", total / 1e9)
     assert 13.2e9 < total < 13.9e9
+
+
+@pytest.mark.slow
+def test_smallthinkers_whole_train_step_compiles(one_chip):
+    """The twelfth cell's WHOLE train step for the described chip:
+    eight microbatches of ONE 16,384-token row through SmallThinker's
+    four layers (a NoPE full layer and three rotary layers under a
+    window of 4096, the router on the layer's input, 8 of 64 ReLU-gated
+    experts held, each run over every token, as the cell's file asks:
+    ``expert_dispatch: "dense"``),
+    accumulated in float32, Adam on float32 masters, parameters and
+    optimizer state donated. Every attention layer runs the three
+    STREAM kernels once (a rematerialised block keeps the forward
+    kernel's residuals) and no whole-row kernel; the router's product
+    stands under a part of its own. The compiler's count of the step's
+    memory is what the cell's size hangs on (370.5 M parameters are
+    7.4 GB at 20 bytes; a 16,384-token microbatch's residuals and the
+    backward's temporaries on top)."""
+    from realhf_tpu.obs import parts
+    from realhf_tpu.ops.flash_attention import flash_fwd_per_bwd
+
+    step, *args = _sft_train_step(
+        one_chip, "smallthinker-21b-a3b-l4-ep8", "smallthinker", 8,
+        row_len=ST_ROW)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [name for name, _, opcode in device_instructions(text)
+             if opcode == "custom-call" and "flash" in name]
+    assert len(calls) == 3 * 4 and all("_stream" in c for c in calls)
+    # (the dense mode over the held stacks: no sort, no gather and no
+    # grouped-matmul kernel)
+    from realhf_tpu.ops.moe import grouped_product_calls
+    assert flash_fwd_per_bwd(text) == 1.0
+    assert grouped_product_calls(text)["moe_gmm_calls"] == 0
+    by_part = {part for part, *_ in parts.parse_program(text).values()}
+    assert {"attn/stream", "attn_proj", "experts/router", "experts/route",
+            "experts/products", "experts/combine", "vocab_head",
+            "grad_accum", "optimizer"} <= by_part
+    assert "experts/gather" not in by_part
+    assert _head_products(text) == {"fwd": 1, "remat": 0, "bwd": 2}
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("smallthinker whole step GB", total / 1e9)
+    assert 8e9 < total < 13.9e9

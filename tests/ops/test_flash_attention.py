@@ -722,7 +722,9 @@ def test_flash_takes_and_the_limit_speak_of_the_keys_width():
     assert not attention.flash_takes(4096, key_dim=32)
     q = jnp.zeros((1, fa.FLASH_MAX_LEN + 128, 1, 192), jnp.bfloat16)
     v = jnp.zeros((1, fa.FLASH_MAX_LEN + 128, 1, 128), jnp.bfloat16)
-    with pytest.raises(ValueError, match="key's width of 192"):
+    # (past the rows whose K and V the kernels hold whole, two widths
+    # are refused by name: the kernels that stream them take one)
+    with pytest.raises(NotImplementedError, match="key 192, value 128"):
         fa.flash_attention(q, q, v, jnp.ones(q.shape[:2], jnp.int32))
 
 
@@ -744,3 +746,188 @@ def test_the_dkv_pass_at_moonlights_widths_asks_for_more_vmem(monkeypatch):
     fwd, dq, dkv = limits
     assert fwd is None and dq is None
     assert fa.DEFAULT_SCOPED_VMEM < dkv < 2 * fa.DEFAULT_SCOPED_VMEM
+
+
+# ----------------------------------------------------------------------
+# Rows past FLASH_MAX_LEN: the kernels that stream their blocks
+# ----------------------------------------------------------------------
+@pytest.fixture
+def stream_above(monkeypatch):
+    """Lower the longest row the whole-row kernels take, so that a row
+    the interpreter can afford goes to the ``*_stream`` kernels (the
+    dispatch reads the module's constant when a call is traced)."""
+    def at(limit):
+        monkeypatch.setattr(fa, "FLASH_MAX_LEN", limit)
+    return at
+
+
+def _stream_rows(kind, l):
+    """``[2, l]`` segment ids: ``one`` document a row; ``packed``:
+    documents off the block grid and padding at the end; ``padding``:
+    whole blocks of padding between and after two documents (a query
+    block and a key block that visit nothing)."""
+    seg = np.ones((2, l), np.int32)
+    if kind == "packed":
+        edges = [0, l // 5 + 3, l // 2 - 7, l - l // 8 - 1]
+        seg[:] = 0
+        for j, (a, b) in enumerate(zip(edges, edges[1:])):
+            seg[0, a:b] = j + 1
+        seg[1, :l // 3 + 1], seg[1, l // 3 + 1:] = 5, 2
+    if kind == "padding":
+        seg[:] = 0
+        seg[0, :l // 4], seg[0, l // 2:l // 2 + l // 8] = 1, 2
+        seg[1, l // 4:l // 2] = 3
+    return seg
+
+
+_GQA = (4, 2, 32, 512, (64, 128))
+_HEADS = {"smallthinkers_28x4": (28, 4, 32, 256, (32, 64)),
+          "mha": (8, 8, 32, 256, (64, 32)),
+          "two_steps_a_kv_head": (16, 1, 32, 256, (32, 64))}
+
+
+@pytest.mark.parametrize("nq,nkv,hd,l,blocks,kind,window", [
+    pytest.param(*_GQA, kind, window, id=f"gqa_4x2-{kind}-{window}")
+    for kind, window in (("one", None), ("packed", None),
+                         ("padding", None), ("one", 100), ("packed", 70),
+                         ("packed", 300))] + [
+    pytest.param(*heads, "packed", window, id=f"{name}-packed-{window}")
+    for name, heads in _HEADS.items() for window in (None, 70)])
+def test_stream_kernels_match_the_xla_mask(nq, nkv, hd, l, blocks, kind,
+                                           window, stream_above,
+                                           interpreted_kernels):
+    """Forward and all three gradients of the kernels that stream their
+    blocks (interpret mode; the whole-row limit lowered to 64) against
+    the XLA path's explicit mask: one document, packed documents off
+    the block grid, whole blocks of padding, windows under and over a
+    pair of blocks, SmallThinker's 28 query heads over 4 (seven heads a
+    grid step from one fetched block), every head with keys of its own
+    (one head a step) and 16 heads over ONE key/value head (two steps
+    of 8, whose partial dK and dV are summed outside)."""
+    stream_above(64)
+    rng = np.random.default_rng(l + nq)
+    seg_np = _stream_rows(kind, l)
+    seg = jnp.asarray(seg_np)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((2, l, n, hd)),
+                              jnp.float32) for n in (nq, nkv, nkv, nq))
+    valid = jnp.asarray(seg_np != 0)[..., None, None]
+    assert fa.stream_heads(nq // nkv) == {2: 2, 7: 7, 1: 1, 16: 8}[nq // nkv]
+
+    def run(attn):
+        def loss(q, k, v):
+            out = attn(q, k, v, seg, sliding_window=window)
+            return (jnp.where(valid, out, 0.0) * w).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return [np.asarray(x) for x in (out,) + grads]
+
+    with interpreted_kernels():
+        got = run(functools.partial(fa.flash_attention, block_q=blocks[0],
+                                    block_k=blocks[1]))
+    want = run(packed_attention_xla)
+    for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
+        keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
+        np.testing.assert_allclose(a[keep], ref[keep], rtol=5e-3,
+                                   atol=5e-3, err_msg=name)
+    # padding rows are exactly zero, as the whole-row kernels leave them
+    if (seg_np == 0).any():
+        assert np.abs(got[0][seg_np == 0]).max() == 0.0
+
+
+def test_stream_kernels_on_a_row_of_8192(interpreted_kernels):
+    """At the module's own limit: a row of 8192 (small heads, blocks of
+    256 x 512 as on the chip) takes the stream kernels with nothing
+    lowered, under a window of 4096 that bites, and gives the XLA
+    path's output and gradients."""
+    rng = np.random.default_rng(8)
+    l, nq, nkv, hd, window = 8192, 2, 1, 8, 4096
+    assert fa.row_streams(l) and not fa.row_streams(fa.FLASH_MAX_LEN)
+    seg_np = np.ones((1, l), np.int32)
+    seg_np[0, 6000:] = 2
+    seg = jnp.asarray(seg_np)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((1, l, n, hd)),
+                              jnp.float32) for n in (nq, nkv, nkv, nq))
+
+    def run(attn):
+        def loss(q, k, v):
+            out = attn(q, k, v, seg, sliding_window=window)
+            return (out * w).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return [np.asarray(x) for x in (out,) + grads]
+
+    with interpreted_kernels():
+        got = run(fa.flash_attention)
+    want = run(packed_attention_xla)
+    visited, causal, _ = fa.block_counts(seg_np, sliding_window=window)
+    assert visited < fa.block_counts(seg_np)[0] < causal
+    for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, ref, rtol=5e-3, atol=5e-3,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("l,window,steps", [
+    (16384, None, (32, 64)), (16384, 4096, (9, 18)), (8192, 4096, (9, 18)),
+    (8192, 100, (2, 3))])
+def test_the_stream_grids_last_axis_is_the_longest_range(l, window, steps):
+    """The kernels' last grid axis is as long as the longest range a
+    block of a row of ONE document has: 32 key blocks of 512 a query
+    block in a full layer at 16,384 and 9 under a window of 4096 (the
+    twelfth cell's two kinds of layer); and every packed row's ranges
+    lie inside those, block by block."""
+    assert fa._stream_steps(l, 256, 512, True, window) == steps
+    rng = np.random.default_rng(l)
+    seg = packed_rows(rng, l, 1, "both")
+    (lo, hi), (q_lo, q_hi), _ = fa.block_ranges(
+        seg, 256, 512, xp=np, sliding_window=window)
+    assert np.maximum(hi - lo, 0).max() <= steps[0]
+    assert np.maximum(q_hi - q_lo, 0).max() <= steps[1]
+
+
+def test_stream_kernels_are_three_calls_named_apart():
+    """A row past ``FLASH_MAX_LEN`` lowers to three ``pallas_call``s
+    named ``flash_fwd_stream``, ``flash_bwd_dq_stream`` and
+    ``flash_bwd_dkv_stream`` on a grid of FOUR axes with no loop inside
+    (the range is the last axis); a row at the limit keeps the kernels
+    it had, their three loops and their names; the residuals a
+    rematerialised block keeps are the same two, by the same names."""
+    def jaxpr(l, window=None):
+        q, k, v = (jax.ShapeDtypeStruct((1, l, n, 128), jnp.bfloat16)
+                   for n in (28, 4, 4))
+        seg = jax.ShapeDtypeStruct((1, l), jnp.int32)
+        return str(jax.make_jaxpr(lambda q, k, v, s: jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, s, sliding_window=window).astype(
+                    jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v))(q, k, v, seg))
+
+    long = jaxpr(16384, 4096)
+    assert long.count("pallas_call") == 3 and " while[" not in long
+    for name in ("flash_fwd_stream", "flash_bwd_dq_stream",
+                 "flash_bwd_dkv_stream"):
+        assert f"name={name}" in long
+    assert "grid=(1, 4, 64, 9)" in long and "grid=(1, 4, 32, 18)" in long
+    for name in fa.RESIDUAL_NAMES:
+        assert f"name={name}" in long
+    # K and V a key block of 512, Q seven heads of a query block of 256
+    assert "bf16[1,1,512,128]" in long or "(1, 1, 512, 128)" in long
+    short = jaxpr(fa.FLASH_MAX_LEN)
+    assert short.count("pallas_call") == 3 and "_stream" not in short
+    assert short.count(" while[") == 3 * 3
+
+
+def test_what_the_stream_kernels_do_not_take_raises_by_name():
+    q, k, v = (jnp.zeros((1, fa.FLASH_MAX_LEN + 512, n, 64), jnp.bfloat16)
+               for n in (2, 1, 1))
+    seg = jnp.ones((1, q.shape[1]), jnp.int32)
+    with pytest.raises(NotImplementedError, match="flash_\\*_stream"):
+        fa.flash_attention(q, k, v, seg, select=jnp.ones(
+            (1, q.shape[1], q.shape[1]), jnp.int8))
+    with pytest.raises(NotImplementedError, match="key 64, value 128"):
+        fa.flash_attention(q, k, jnp.zeros(
+            (1, q.shape[1], 1, 128), jnp.bfloat16), seg)
+    long = jnp.zeros((1, fa.FLASH_STREAM_MAX_LEN + 512, 1, 64),
+                     jnp.bfloat16)
+    with pytest.raises(ValueError, match="FLASH_STREAM_MAX_LEN=32768"):
+        fa.flash_attention(long, long, long, jnp.ones(
+            (1, long.shape[1]), jnp.int32))
