@@ -126,11 +126,13 @@ serves all the query heads of the step (7 of SmallThinker's 28 over 4:
 one fetch of 262 KB against 7 x 67 MFLOP in the forward, seven times
 the chip's ridge), and the pair's mask is built once for them; the
 running maximum, sum and accumulator of the heads live in VMEM scratch
-between steps. The dkv pass computes TRANSPOSED scores, ``K Q^T``
-[BK, BQ]: lse and delta are then rows, read as they are kept, one
-float32 a (row, head), and no product contracts over its operands'
-first axes. What bounds L there is ``FLASH_STREAM_MAX_LEN``, the
-longest row the compile test asks the v5e compiler for; a selection
+between steps, maximum and sum lane-broadcast there and through the
+forward's arithmetic (``_fwd_stream_kernel``). The dkv pass computes
+TRANSPOSED scores, ``K Q^T`` [BK, BQ]: lse and delta are then rows,
+read as they are kept, one float32 a (row, head), and no product
+contracts over its operands' first axes. What bounds L there is
+``FLASH_STREAM_MAX_LEN``, the longest row the compile test asks the
+v5e compiler for; a selection
 (``select=``) or a key wider than its value past ``FLASH_MAX_LEN``
 raises by name, as does a row past either bound: a row the compiler
 would refuse never drops to the O(L^2) XLA path in silence. Longer
@@ -183,7 +185,9 @@ STREAM_SUFFIX = "_stream"
 #: kernel serves from one fetched block of K and V: the largest divisor
 #: of the group up to this (7 of 28 over 4, 8 of 64 over 8, 1 where
 #: every head has keys of its own). Their blocks and scratch are what
-#: the kernels hold: 8 MB at 7 heads of 128 in bf16
+#: the kernels hold: 8 MB at 7 heads of 128 in bf16; and the forward's
+#: pair body is these heads written out in one straight line (about
+#: 550 bundles a head: its size and its compile grow with this)
 STREAM_HEADS = 8
 NEG_INF = -2.0 ** 30
 LANES = 128
@@ -860,10 +864,17 @@ def _last_step():
     return pl.program_id(3) == pl.num_programs(3) - 1
 
 
-def _lanes(x):
-    """[BQ] -> [BQ, LANES], as the scratch and the lse keep a number a
-    row."""
-    return jnp.broadcast_to(x[:, None], (x.shape[0], LANES))
+def _across(x, width: int):
+    """A lane-broadcast ``[BQ, LANES]`` (one number a row, the same in
+    every lane) over ``width`` lanes: tiled where ``width`` is past
+    ``LANES`` (key blocks of 512, a value of 128 or 256), its first
+    lanes where it is under (a test's blocks of 64, a value of 64).
+    Decided by the shapes the kernel is given; no lane of ``x`` is
+    moved either way, which is why the stream forward keeps its
+    softmax's state in this form."""
+    reps = -(-width // LANES)
+    wide = jnp.tile(x, (1, reps)) if reps > 1 else x
+    return wide if wide.shape[1] == width else wide[:, :width]
 
 
 def _fwd_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
@@ -874,10 +885,26 @@ def _fwd_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
     """One (query block of ``heads`` heads, key block) pair a grid
     step: the online softmax of :func:`_fwd_kernel`, its running
     maximum, sum and accumulator in VMEM scratch ``[heads, BQ, .]``
-    between the steps of a query block."""
+    between the steps of a query block.
+
+    The maximum and the sum are one number a row kept over ``LANES``
+    lanes, and the body never takes them out of that form: the row
+    maximum and the row sum of a pair come out of their reductions with
+    the lanes they had (``keepdims``), ``alpha`` is ``[BQ, LANES]``,
+    and what scales ``[BQ, BK]`` scores or a ``[BQ, hv]`` accumulator
+    is that array tiled or cut to their width (:func:`_across`), which
+    moves no lane. As ``[BQ]`` vectors (``m_ref[g, :, 0]``, as the
+    whole-row kernel carries them) every (pair, head) paid two
+    extractions and four broadcasts through the cross-lane units: 1,088
+    bundles of the compiler's schedule against 646 at (7 heads, 128)
+    (PERF.md, PR 63). The heads of a step are written out one after
+    another, not looped over: a head's chain ``Q K^T -> max -> exp ->
+    sum -> P V`` is serial, and in ONE straight line the scheduler
+    overlaps a head's products with its neighbours' softmax (547
+    bundles a head). ``STREAM_HEADS`` bounds how many that is."""
     qi = pl.program_id(2)
     _, heads, bq, _ = q_ref.shape
-    bk = k_ref.shape[2]
+    bk, hv = k_ref.shape[2], v_ref.shape[3]
 
     @pl.when(_first_step())
     def _():
@@ -891,25 +918,23 @@ def _fwd_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
         k = k_ref[0, 0].astype(jnp.float32)  # [BK, hd]
         v = v_ref[0, 0]  # [BK, hv]
 
-        def head(g, carry):
+        for g in range(heads):  # written out, not looped over: above
             q = q_ref[0, g].astype(jnp.float32) * scale  # [BQ, hd]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [BQ, BK]
             if mask is not None:
                 s = jnp.where(mask, s, NEG_INF)
-            m, l_sum = m_ref[g, :, 0], l_ref[g, :, 0]
-            m_new = jnp.maximum(m, s.max(axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m - m_new)
-            m_ref[g] = _lanes(m_new)
-            l_ref[g] = _lanes(l_sum * alpha + p.sum(axis=1))
-            acc_ref[g] = acc_ref[g] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return carry
-
-        jax.lax.fori_loop(0, heads, head, 0)
+            m_prev, l_prev = m_ref[g], l_ref[g]  # [BQ, LANES]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - _across(m_new, bk))
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[g] = m_new
+            l_ref[g] = alpha * l_prev + p.sum(axis=1, keepdims=True)
+            acc_ref[g] = (
+                acc_ref[g] * _across(alpha, hv) + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
 
     _stream_pair((kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref), pair,
                  window, bq, bk)
@@ -918,14 +943,13 @@ def _fwd_stream_kernel(kv_lo_ref, kv_hi_ref, full_lo_ref, full_hi_ref,
     def _():
         def head(g, carry):
             # (rows that saw no key: :func:`_fwd_kernel`'s comment)
-            m, l_sum = m_ref[g, :, 0], l_ref[g, :, 0]
+            m, l_sum = m_ref[g], l_ref[g]
             row_valid = m > NEG_INF / 2
             safe_l = jnp.where(l_sum > 0, l_sum, 1.0)
             o_ref[0, g] = jnp.where(
-                row_valid[:, None], acc_ref[g] / safe_l[:, None],
-                0.0).astype(o_ref.dtype)
-            lse_ref[0, g] = _lanes(
-                jnp.where(row_valid, m + jnp.log(safe_l), NEG_INF))
+                _across(m, hv) > NEG_INF / 2,
+                acc_ref[g] / _across(safe_l, hv), 0.0).astype(o_ref.dtype)
+            lse_ref[0, g] = jnp.where(row_valid, m + jnp.log(safe_l), NEG_INF)
             return carry
 
         jax.lax.fori_loop(0, heads, head, 0)

@@ -11,7 +11,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from realhf_tpu.ops.attention import packed_attention, packed_attention_xla
+from realhf_tpu.ops.attention import (_segment_mask, packed_attention,
+                                      packed_attention_xla)
 from realhf_tpu.ops import flash_attention as fa
 
 
@@ -765,8 +766,18 @@ def _stream_rows(kind, l):
     """``[2, l]`` segment ids: ``one`` document a row; ``packed``:
     documents off the block grid and padding at the end; ``padding``:
     whole blocks of padding between and after two documents (a query
-    block and a key block that visit nothing)."""
+    block and a key block that visit nothing); ``late``: a document
+    that starts inside a query block and past that block's first
+    visited key block (rows whose FIRST block of keys is masked whole
+    and a later one is not: the running maximum stays ``NEG_INF``
+    there, and the next block's ``alpha`` wipes what it gathered), and
+    a row whose padding ends inside a query block (rows that see no
+    key beside rows that do)."""
     seg = np.ones((2, l), np.int32)
+    if kind == "late":
+        start = l // 2 + l // 16 + 6
+        seg[0, start:l - l // 8], seg[0, l - l // 8:] = 2, 0
+        seg[1, :l // 4 + 5], seg[1, l // 4 + 5:] = 0, 3
     if kind == "packed":
         edges = [0, l // 5 + 3, l // 2 - 7, l - l // 8 - 1]
         seg[:] = 0
@@ -784,15 +795,39 @@ _GQA = (4, 2, 32, 512, (64, 128))
 _HEADS = {"smallthinkers_28x4": (28, 4, 32, 256, (32, 64)),
           "mha": (8, 8, 32, 256, (64, 32)),
           "two_steps_a_kv_head": (16, 1, 32, 256, (32, 64))}
+#: the chip's lane arithmetic: a value of 128 lanes and key blocks of
+#: 512, where the forward TILES its lane-broadcast maximum over the
+#: scores (under 128 it takes the first lanes)
+_CHIP_LANES = (2, 1, 128, 1024, (256, 512))
+
+
+def _reference_lse(q, k, seg, window):
+    """The log-sum-exp a (row, head) of the masked scores, ``[B, nq,
+    L]``, ``NEG_INF`` where a row sees no key: what the forward kernel
+    hands its backward."""
+    b, l, nq, hd = q.shape
+    mask = np.asarray(_segment_mask(seg, seg, True, window))[:, None]
+    scores = np.einsum("blhd,bmhd->bhlm", np.asarray(q, np.float64),
+                       np.repeat(np.asarray(k, np.float64),
+                                 nq // k.shape[2], axis=2)) * hd ** -0.5
+    scores = np.where(mask, scores, -np.inf)
+    top = np.where(mask.any(-1), scores.max(-1), 0.0)
+    with np.errstate(divide="ignore"):
+        lse = top + np.log(np.exp(scores - top[..., None]).sum(-1))
+    return np.where(mask.any(-1), lse, fa.NEG_INF)
 
 
 @pytest.mark.parametrize("nq,nkv,hd,l,blocks,kind,window", [
     pytest.param(*_GQA, kind, window, id=f"gqa_4x2-{kind}-{window}")
     for kind, window in (("one", None), ("packed", None),
                          ("padding", None), ("one", 100), ("packed", 70),
-                         ("packed", 300))] + [
-    pytest.param(*heads, "packed", window, id=f"{name}-packed-{window}")
-    for name, heads in _HEADS.items() for window in (None, 70)])
+                         ("packed", 300), ("late", None), ("late", 70))] + [
+    pytest.param(*heads, kind, window, id=f"{name}-{kind}-{window}")
+    for name, heads in _HEADS.items()
+    for kind, window in (("packed", None), ("packed", 70), ("late", None))
+] + [pytest.param(*_CHIP_LANES, kind, window,
+                  id=f"chip_lanes-{kind}-{window}")
+     for kind, window in (("late", None), ("padding", 600))])
 def test_stream_kernels_match_the_xla_mask(nq, nkv, hd, l, blocks, kind,
                                            window, stream_above,
                                            interpreted_kernels):
@@ -803,7 +838,10 @@ def test_stream_kernels_match_the_xla_mask(nq, nkv, hd, l, blocks, kind,
     pair of blocks, SmallThinker's 28 query heads over 4 (seven heads a
     grid step from one fetched block), every head with keys of its own
     (one head a step) and 16 heads over ONE key/value head (two steps
-    of 8, whose partial dK and dV are summed outside)."""
+    of 8, whose partial dK and dV are summed outside). The forward's
+    second output too, the log-sum-exp its online softmax ends on
+    (every lane of a row the same number), and what a row that sees no
+    key is left with: output 0, ``NEG_INF`` there, finite gradients."""
     stream_above(64)
     rng = np.random.default_rng(l + nq)
     seg_np = _stream_rows(kind, l)
@@ -812,6 +850,11 @@ def test_stream_kernels_match_the_xla_mask(nq, nkv, hd, l, blocks, kind,
                               jnp.float32) for n in (nq, nkv, nkv, nq))
     valid = jnp.asarray(seg_np != 0)[..., None, None]
     assert fa.stream_heads(nq // nkv) == {2: 2, 7: 7, 1: 1, 16: 8}[nq // nkv]
+    if kind == "late":  # the late document's first key block is masked whole
+        late = int(np.argmax(seg_np[0] == 2))
+        (lo, _), _, _ = fa.block_ranges(seg_np, *blocks, xp=np,
+                                        sliding_window=window)
+        assert (lo[0, late // blocks[0]] + 1) * blocks[1] <= late
 
     def run(attn):
         def loss(q, k, v):
@@ -824,7 +867,13 @@ def test_stream_kernels_match_the_xla_mask(nq, nkv, hd, l, blocks, kind,
     with interpreted_kernels():
         got = run(functools.partial(fa.flash_attention, block_q=blocks[0],
                                     block_k=blocks[1]))
+        lse = np.asarray(jax.jit(lambda q, k, v: fa._flash_fwd(
+            q, k, v, seg, hd ** -0.5, True, *blocks, window)[1])(q, k, v))
     want = run(packed_attention_xla)
+    assert all(np.isfinite(a).all() for a in got)
+    assert (lse == lse[..., :1]).all()
+    np.testing.assert_allclose(lse[..., 0], _reference_lse(q, k, seg, window),
+                               rtol=1e-4, atol=1e-4)
     for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
         keep = (seg_np != 0) if name == "out" else np.ones_like(seg_np, bool)
         np.testing.assert_allclose(a[keep], ref[keep], rtol=5e-3,
